@@ -16,18 +16,57 @@ from __future__ import annotations
 
 import glob
 import os
-from typing import Dict, Optional
+from typing import Dict, List, Optional
 
 TPU_RESOURCE = "TPU"
+
+_DEV_ROOT = "/dev"  # tests point this at a fake tree
 
 
 def num_tpu_chips() -> int:
     env = os.environ.get("TPU_VISIBLE_CHIPS")
     if env is not None:
         return 0 if env in ("", "none") else len(env.split(","))
-    # PCI accel device files (reference: tpu.py:110 _glob_tpu_acclerator_devices)
-    devices = glob.glob("/dev/accel*") or glob.glob("/dev/vfio/*")
-    return len(devices)
+    # PCI accel device files (reference: tpu.py:110
+    # _glob_tpu_acclerator_devices): one numbered node per chip.
+    # /dev/vfio also holds the `vfio` control node, which is no chip.
+    for pattern, prefix in (("accel*", "accel"), ("vfio/*", "")):
+        names = [os.path.basename(p)[len(prefix):]
+                 for p in glob.glob(os.path.join(_DEV_ROOT, pattern))]
+        chips = sum(1 for n in names if n.isdigit())
+        if chips:
+            return chips
+    return 0
+
+
+# chips in one process -> the per-process topology libtpu has to be told
+# when processes share a host (reference: tpu.py
+# set_current_process_visible_accelerator_ids sets the same pair under
+# their older *_HOST_BOUNDS names)
+_PROCESS_BOUNDS = {1: "1,1,1", 2: "1,2,1"}
+
+
+def chip_env(tpu_chips: List[int], host_chips: int) -> Dict[str, str]:
+    """Environment for a worker about to run a lease that holds
+    `tpu_chips` on a host with `host_chips`.  Applied before the worker's
+    first `import jax`: a TPU lease always gets a fresh worker
+    (node_agent._pop_worker), so the backend comes up on exactly these
+    chips.  A lease with no chips on a host that has some must come up on
+    the CPU backend, or its jax would open every chip.
+
+    A process given fewer chips than the host has gets the per-process
+    bounds too.  Seen on a four-chip v5e host with libtpu 0.0.34:
+    TPU_VISIBLE_CHIPS alone brings ONE such process up on its chip, but
+    the second one beside it aborts on libtpu's single-process lockfile;
+    with the bounds four ran side by side."""
+    if not tpu_chips:
+        return {"JAX_PLATFORMS": "cpu"} if host_chips > 0 else {}
+    env = {"TPU_VISIBLE_CHIPS": ",".join(map(str, tpu_chips))}
+    bounds = _PROCESS_BOUNDS.get(len(tpu_chips))
+    if bounds and len(tpu_chips) < host_chips:
+        env.update(TPU_CHIPS_PER_PROCESS_BOUNDS=bounds,
+                   TPU_PROCESS_BOUNDS="1,1,1")
+    return env
 
 
 _metadata_cache: Dict[str, Optional[str]] = {}

@@ -1,4 +1,4 @@
-"""User-facing exception taxonomy.
+"""User-facing exception hierarchy.
 
 Equivalent of the reference's exception set
 (reference: python/ray/exceptions.py — RayError, RayTaskError,

@@ -8,6 +8,12 @@ large to inline are represented by an IN_PLASMA sentinel that redirects
 
 Thread model: the user thread blocks in `wait_ready`; the RPC IO thread
 calls `set_*` — coordination is a per-entry threading.Event.
+
+`evict` is on the `ObjectRef.__del__` path, and a cycle collection can
+run `__del__` on whichever thread happens to enter a Python function —
+including one that is inside a critical section here.  So the lock is
+re-entrant, nothing is constructed while it is held, and every critical
+section stays valid if `evict` of another id runs in its middle.
 """
 
 from __future__ import annotations
@@ -32,16 +38,17 @@ class _Entry:
 
 class MemoryStore:
     def __init__(self):
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()
         self._entries: Dict[str, _Entry] = {}
         self._waiter_tokens = 0
 
     def _entry(self, oid: str) -> _Entry:
-        with self._lock:
-            e = self._entries.get(oid)
-            if e is None:
-                e = self._entries[oid] = _Entry()
-            return e
+        e = self._entries.get(oid)
+        if e is None:
+            new = _Entry()  # built outside the lock (see module docstring)
+            with self._lock:
+                e = self._entries.setdefault(oid, new)
+        return e
 
     def _fire(self, e: _Entry) -> None:
         e.event.set()

@@ -72,7 +72,7 @@ def default_resources(num_cpus: Optional[float] = None,
 
 def start_head(session_dir: str, env: Optional[Dict[str, str]] = None,
                port: int = 0) -> Tuple[ProcessHandle, Tuple[str, int]]:
-    from ray_tpu._private.spawn import fast_python_cmd
+    from ray_tpu._private.spawn import python_module_cmd
 
     port_file = os.path.join(session_dir, f"head-{time.monotonic_ns()}.port")
     state_path = os.path.join(session_dir, "head.state")
@@ -80,7 +80,7 @@ def start_head(session_dir: str, env: Optional[Dict[str, str]] = None,
     penv = dict(os.environ)
     if env:
         penv.update(env)
-    cmd, env_up = fast_python_cmd(
+    cmd, env_up = python_module_cmd(
         "ray_tpu._private.head",
         ["--port-file", port_file, "--state-path", state_path,
          "--port", str(port)])
@@ -99,7 +99,7 @@ def start_node_agent(session_dir: str, head_addr: Tuple[str, int],
                      env: Optional[Dict[str, str]] = None,
                      labels: Optional[Dict[str, str]] = None,
                      tag: str = "agent") -> Tuple[ProcessHandle, Dict[str, Any]]:
-    from ray_tpu._private.spawn import fast_python_cmd
+    from ray_tpu._private.spawn import python_module_cmd
 
     port_file = os.path.join(session_dir, f"{tag}-{os.getpid()}-{time.monotonic_ns()}.port")
     log = open(os.path.join(session_dir, "logs", f"{tag}.log"), "ab")
@@ -116,7 +116,7 @@ def start_node_agent(session_dir: str, head_addr: Tuple[str, int],
         argv += ["--is-head-node"]
     if labels:
         argv += ["--labels", json.dumps(labels)]
-    cmd, env_up = fast_python_cmd("ray_tpu._private.node_agent", argv)
+    cmd, env_up = python_module_cmd("ray_tpu._private.node_agent", argv)
     penv.update(env_up)
     proc = subprocess.Popen(cmd, stdout=log, stderr=subprocess.STDOUT,
                             env=penv, start_new_session=True)

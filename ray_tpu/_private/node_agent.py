@@ -51,7 +51,7 @@ from ray_tpu._private.task_spec import NORMAL_TASK, TaskSpec
 class _Worker:
     __slots__ = ("worker_id", "pid", "proc", "port", "ready", "lease_id",
                  "started_at", "env_key", "idle_since", "iclient",
-                 "pinned", "saving")
+                 "pinned", "saving", "used")
 
     def __init__(self, worker_id: str, proc: subprocess.Popen,
                  env_key: str = ""):
@@ -76,6 +76,7 @@ class _Worker:
         # idle workers by runtime env hash)
         self.env_key = env_key
         self.idle_since = time.monotonic()
+        self.used = False  # has been leased at least once
 
 
 def _is_hard_strategy(strategy: Dict[str, Any]) -> bool:
@@ -1326,10 +1327,12 @@ class NodeAgent(IntrospectionRpcMixin, RpcHost):
         os.makedirs(log_dir, exist_ok=True)
         log_path = os.path.join(log_dir, f"worker-{worker_id[:12]}.log")
         out = open(log_path, "ab")
-        from ray_tpu._private.spawn import fast_python_cmd, set_pdeathsig
+        from ray_tpu._private.spawn import (compile_cache_env,
+                                            python_module_cmd, set_pdeathsig)
 
-        cmd, env_up = fast_python_cmd("ray_tpu._private.worker_main")
+        cmd, env_up = python_module_cmd("ray_tpu._private.worker_main")
         env.update(env_up)
+        env.update(compile_cache_env())
         proc = subprocess.Popen(
             cmd, env=env, stdout=out, stderr=subprocess.STDOUT,
             start_new_session=True, preexec_fn=set_pdeathsig)
@@ -1645,12 +1648,10 @@ class NodeAgent(IntrospectionRpcMixin, RpcHost):
         # removal kills its tasks/actors)
         for lease_id, lease in list(self._leases.items()):
             if lease.bundle_key == key:
-                self._leases.pop(lease_id, None)
-                lease.worker.lease_id = None
-                try:
-                    lease.worker.proc.terminate()
-                except Exception:
-                    pass
+                if not lease.tpu_chips:  # else: freed by _on_worker_dead
+                    self._leases.pop(lease_id, None)
+                    lease.worker.lease_id = None
+                self._terminate_worker(lease.worker)
         for tok in self.local.release(sched.resources.total):
             self._grant_token(tok)
         self._hb_wake.set()
@@ -2100,8 +2101,12 @@ class NodeAgent(IntrospectionRpcMixin, RpcHost):
                      conn=None):
         # `demand` resources are already acquired from `sched`
         renv = ts.runtime_env if ts is not None else {}
+        n_tpu = int(demand.to_dict().get("TPU", 0))
         try:
-            worker = await self._pop_worker(renv)
+            # a TPU lease gets a worker no task has run in: one that
+            # already imported jax has its backend up and would ignore
+            # the chips this lease assigns (worker._apply_chip_env)
+            worker = await self._pop_worker(renv, fresh=n_tpu > 0)
         except RuntimeEnvSetupError as exc:
             worker = None
             for tok in sched.release(demand):
@@ -2129,12 +2134,12 @@ class NodeAgent(IntrospectionRpcMixin, RpcHost):
                        fid=ts.function_id if ts is not None else "",
                        task_name=(ts.name or ts.method_name)
                        if ts is not None else "")
-        n_tpu = int(demand.to_dict().get("TPU", 0))
         take = min(n_tpu, len(self._free_tpu_chips))
         if take > 0:
             lease.tpu_chips = self._free_tpu_chips[:take]
             del self._free_tpu_chips[:take]
         worker.lease_id = lease_id
+        worker.used = True
         self._leases[lease_id] = lease
         if conn is not None and conn.writer.is_closing():
             # the owner's connection died while the worker spawned: the
@@ -2159,8 +2164,8 @@ class NodeAgent(IntrospectionRpcMixin, RpcHost):
                 max(1, int(config.worker_startup_parallelism)))
         return self._spawn_sem
 
-    async def _pop_worker(self, renv: Optional[Dict[str, Any]] = None
-                          ) -> Optional[_Worker]:
+    async def _pop_worker(self, renv: Optional[Dict[str, Any]] = None,
+                          fresh: bool = False) -> Optional[_Worker]:
         from ray_tpu._private.runtime_env import env_key as _env_key
 
         renv = renv or {}
@@ -2183,7 +2188,7 @@ class NodeAgent(IntrospectionRpcMixin, RpcHost):
         def pop_idle() -> Optional[_Worker]:
             for i in range(len(self._idle) - 1, -1, -1):
                 w = self._idle[i]
-                if w.env_key != key:
+                if w.env_key != key or (fresh and w.used):
                     continue
                 del self._idle[i]
                 if w.proc.poll() is None:
@@ -2239,11 +2244,19 @@ class NodeAgent(IntrospectionRpcMixin, RpcHost):
         return self.local
 
     async def rpc_return_lease(self, lease_id: str, kill_worker: bool = False):
-        lease = self._leases.pop(lease_id, None)
+        lease = self._leases.get(lease_id)
         if lease is None:
             return {"ok": False}
-        self._free_tpu_chips.extend(lease.tpu_chips)
         w = lease.worker
+        if lease.tpu_chips and w.proc.poll() is None:
+            # a process that opened chips holds them until it exits, so
+            # it is neither reused nor outlived by its lease: the chips
+            # and the TPU resource go back when the reaper has seen it
+            # dead (_on_worker_dead), not before
+            self._terminate_worker(w)
+            return {"ok": True}
+        del self._leases[lease_id]
+        self._free_tpu_chips.extend(lease.tpu_chips)
         w.lease_id = None
         if kill_worker or w.proc.poll() is not None:
             try:
@@ -2255,6 +2268,21 @@ class NodeAgent(IntrospectionRpcMixin, RpcHost):
             self._idle.append(w)
         self._release_lease_resources(lease)
         return {"ok": True}
+
+    def _terminate_worker(self, w: _Worker, grace_s: float = 5.0) -> None:
+        """SIGTERM, then SIGKILL if it has not exited after `grace_s`."""
+        def kill_if_alive():
+            if w.proc.poll() is None:
+                try:
+                    w.proc.kill()
+                except Exception:
+                    pass
+
+        try:
+            w.proc.terminate()
+        except Exception:
+            pass
+        asyncio.get_running_loop().call_later(grace_s, kill_if_alive)
 
     def _release_lease_resources(self, lease: _Lease) -> None:
         """Return a finished lease's still-held resources to the pool —

@@ -45,10 +45,15 @@ class _Ref:
 
 class ReferenceCounter:
     """Thread-safe; `on_release(oid)` fires (outside the lock) when an
-    *owned* object's count reaches zero."""
+    *owned* object's count reaches zero.
+
+    `remove_local` is what `ObjectRef.__del__` calls, and a cycle
+    collection can run that on a thread that is inside a critical
+    section here.  So the lock is re-entrant, `_Ref`s are built outside
+    it, and no section iterates `_refs` in Python while holding it."""
 
     def __init__(self, on_release: Callable[[str], None]):
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()
         self._refs: Dict[str, _Ref] = {}
         self._on_release = on_release
 
@@ -57,9 +62,12 @@ class ReferenceCounter:
     def add_local(self, oid: str, owned: bool) -> None:
         with self._lock:
             ref = self._refs.get(oid)
-            if ref is None:
-                ref = self._refs[oid] = _Ref(owned)
-            ref.local += 1
+            if ref is not None:
+                ref.local += 1
+                return
+        new = _Ref(owned)
+        with self._lock:
+            self._refs.setdefault(oid, new).local += 1
 
     def remove_local(self, oid: str) -> bool:
         """Returns True if this was a *borrowed* ref whose count hit zero
@@ -88,9 +96,12 @@ class ReferenceCounter:
     def add_submitted(self, oid: str) -> None:
         with self._lock:
             ref = self._refs.get(oid)
-            if ref is None:
-                ref = self._refs[oid] = _Ref(owned=True)
-            ref.submitted += 1
+            if ref is not None:
+                ref.submitted += 1
+                return
+        new = _Ref(owned=True)
+        with self._lock:
+            self._refs.setdefault(oid, new).submitted += 1
 
     def remove_submitted(self, oid: str) -> bool:
         release = False
@@ -115,11 +126,15 @@ class ReferenceCounter:
     # ---- borrower protocol (owner side) ------------------------------------
 
     def add_borrower(self, oid: str, borrower: Tuple[str, int]) -> None:
+        borrower = tuple(borrower)
         with self._lock:
             ref = self._refs.get(oid)
-            if ref is None:
-                ref = self._refs[oid] = _Ref(owned=True)
-            ref.borrowers.add(tuple(borrower))
+            if ref is not None:
+                ref.borrowers.add(borrower)
+                return
+        new = _Ref(owned=True)
+        with self._lock:
+            self._refs.setdefault(oid, new).borrowers.add(borrower)
 
     def remove_borrower(self, oid: str, borrower: Tuple[str, int]) -> None:
         release = False
@@ -154,9 +169,10 @@ class ReferenceCounter:
         (reference: CoreWorker's ownership-table dump behind `ray
         memory`).  Snapshot under the lock, dict-building outside it."""
         with self._lock:
-            snap = [(oid, r.owned, r.local, r.submitted, len(r.borrowers),
-                     r.lineage_pinned, r.call_site, r.name, r.created)
-                    for oid, r in self._refs.items() if not r.freed]
+            refs = list(self._refs.items())
+        snap = [(oid, r.owned, r.local, r.submitted, len(r.borrowers),
+                 r.lineage_pinned, r.call_site, r.name, r.created)
+                for oid, r in refs if not r.freed]
         now = time.monotonic()
         return [{"oid": oid, "owned": owned, "local": local,
                  "submitted": submitted, "borrowers": borrowers,
@@ -172,7 +188,8 @@ class ReferenceCounter:
 
     def owned_ids(self) -> List[str]:
         with self._lock:
-            return [oid for oid, r in self._refs.items() if r.owned and not r.freed]
+            refs = list(self._refs.items())
+        return [oid for oid, r in refs if r.owned and not r.freed]
 
     def is_freed(self, oid: str) -> bool:
         with self._lock:

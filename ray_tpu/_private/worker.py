@@ -464,7 +464,9 @@ class CoreWorker(IntrospectionRpcMixin, RpcHost):
         # task_manager.h resubmit): while a plasma-stored return of an owned
         # normal task has live refs, keep its TaskSpec (and pin its arg
         # refs) so a lost primary copy can be recomputed
-        self._lineage_lock = threading.Lock()
+        # re-entrant: _drop_lineage is on the ObjectRef.__del__ path, which
+        # a cycle collection can run inside another section of this lock
+        self._lineage_lock = threading.RLock()
         self._lineage: Dict[str, _LineageEntry] = {}      # task_id -> entry
         self._lineage_by_oid: Dict[str, str] = {}         # oid -> task_id
         self._reconstructing: Set[str] = set()            # task_ids in flight
@@ -3522,19 +3524,21 @@ class CoreWorker(IntrospectionRpcMixin, RpcHost):
 
     # ------------------------------------------------------- task execution
 
+    # chips on this host, counted once: this runs on every task push
+    _host_tpu_chips: Optional[int] = None
+
     def _apply_chip_env(self, tpu_chips: Optional[List[int]]) -> None:
-        if tpu_chips:
-            # the lease's node agent assigned these chips; jax reads
-            # TPU_VISIBLE_CHIPS at (lazy) plugin init so tasks sharing a
-            # node each see only their own chips (reference:
-            # accelerators/tpu.py set_current_process_visible_accelerator_ids)
-            os.environ["TPU_VISIBLE_CHIPS"] = ",".join(map(str, tpu_chips))
-        elif tpu_chips is not None:
-            # an explicit empty assignment (a CPU-task lease on a reused
-            # worker) must not leak the previous lease's chips.  None —
-            # actor METHOD pushes — leaves the constructor's assignment
-            # intact for the actor's lifetime.
-            os.environ.pop("TPU_VISIBLE_CHIPS", None)
+        """None — actor METHOD pushes — leaves the constructor's
+        assignment intact for the actor's lifetime (jax typically
+        initializes lazily in the first method, not __init__)."""
+        if tpu_chips is None:
+            return
+        from ray_tpu._private import accelerators
+
+        if self._host_tpu_chips is None:
+            self._host_tpu_chips = accelerators.num_tpu_chips()
+        os.environ.update(
+            accelerators.chip_env(tpu_chips, self._host_tpu_chips))
 
     def _enqueue_exec(self, spec: Dict[str, Any], conn) -> "asyncio.Future":
         fut = self._loop().create_future()
@@ -3572,7 +3576,8 @@ class CoreWorker(IntrospectionRpcMixin, RpcHost):
             fut = self._enqueue_exec(spec, _conn)
             if _conn is not None:
                 def _send(f, tid=spec.get("tid", "")):
-                    self._queue_batch_result(_conn, tid, f.result())
+                    if not f.cancelled():  # worker exiting mid-task
+                        self._queue_batch_result(_conn, tid, f.result())
                 fut.add_done_callback(_send)
             futs.append(fut)
         await _aio.gather(*futs)

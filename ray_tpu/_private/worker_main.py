@@ -58,10 +58,6 @@ def main():
     if not reply.get("ok"):
         sys.stderr.write("agent rejected worker registration\n")
         sys.exit(1)
-    # first `import jax` in a task will register the TPU PJRT plugin
-    from ray_tpu._private.spawn import install_jax_site_hook
-
-    install_jax_site_hook()
     try:
         worker.exec_loop()
     finally:

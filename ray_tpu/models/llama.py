@@ -130,6 +130,26 @@ def default_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return dense_attention(q, k, v, causal)
 
 
+def make_mesh_attention(mesh) -> Callable:
+    """`default_attention` as an attention kernel for a step partitioned
+    over `mesh`: batch over (dp, fsdp), heads over tp, each device
+    attending over its own shard.  The partitioner cannot split a Pallas
+    kernel by itself ("Mosaic kernels cannot be automatically
+    partitioned"), so the flash route has to be wrapped where it is
+    called; attention needs no communication along these axes."""
+    from jax.sharding import PartitionSpec as P
+
+    spec = P(("dp", "fsdp"), None, "tp", None)
+
+    def attention(q, k, v, causal: bool = True):
+        return jax.shard_map(
+            partial(default_attention, causal=causal), mesh=mesh,
+            in_specs=(spec, spec, spec), out_specs=spec,
+            check_vma=False)(q, k, v)
+
+    return attention
+
+
 def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True) -> jax.Array:
     """The dense softmax-attention math itself — kept separate from

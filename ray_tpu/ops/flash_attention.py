@@ -126,7 +126,9 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
                     block_k: int = 128, interpret: Optional[bool] = None):
     """Flash attention with a dense-recompute backward."""
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        from ray_tpu.ops import kernel_mode
+
+        interpret = kernel_mode() == "interpret"
     return _flash_forward(q, k, v, causal, block_q, block_k, interpret)
 
 

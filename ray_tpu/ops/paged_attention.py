@@ -113,7 +113,9 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     from jax.experimental.pallas import tpu as pltpu
 
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        from ray_tpu.ops import kernel_mode
+
+        interpret = kernel_mode() == "interpret"
     b, s, h, d = q.shape
     assert s == 1, f"paged_attention is decode-only (S=1), got S={s}"
     num_slots, hkv, _ = pool_k.shape

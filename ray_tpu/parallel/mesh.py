@@ -104,9 +104,10 @@ def shard_batch(mesh, batch):
     return jax.tree_util.tree_map(put, batch)
 
 
-def shard_params(mesh, params, rules: Optional[Dict[str, Any]] = None):
-    """Apply fsdp sharding to a parameter pytree: the largest dim of each
-    leaf is sharded over 'fsdp' (plus explicit per-path rules for tp).
+def param_shardings(mesh, params, rules: Optional[Dict[str, Any]] = None):
+    """NamedShardings for a parameter pytree (arrays or the shapes
+    `jax.eval_shape` gives): the largest dim of each leaf is sharded over
+    'fsdp', unless an explicit per-path rule (tp) names the leaf.
 
     This is the generic fallback; models ship precise PartitionSpec rules
     (see ray_tpu/models/llama.py param_pspecs) that this function accepts
@@ -132,7 +133,22 @@ def shard_params(mesh, params, rules: Optional[Dict[str, Any]] = None):
                 return P(*spec)
         return P()
 
-    def put(path, x):
-        return jax.device_put(x, NamedSharding(mesh, spec_for(path, x)))
+    return jax.tree_util.tree_map_with_path(
+        lambda path, x: NamedSharding(mesh, spec_for(path, x)), params)
 
-    return jax.tree_util.tree_map_with_path(put, params)
+
+def shard_params(mesh, params, rules: Optional[Dict[str, Any]] = None):
+    """Place an existing parameter pytree per `param_shardings`."""
+    import jax
+
+    return jax.device_put(params, param_shardings(mesh, params, rules))
+
+
+def init_sharded(mesh, init_fn, rng, rules: Optional[Dict[str, Any]] = None):
+    """Run `init_fn(rng) -> params` with every leaf created in its target
+    sharding: no device ever holds the whole tree, so a model that fits
+    only across the mesh can be initialised at all."""
+    import jax
+
+    shardings = param_shardings(mesh, jax.eval_shape(init_fn, rng), rules)
+    return jax.jit(init_fn, out_shardings=shardings)(rng)

@@ -129,6 +129,17 @@ def _jit_forward(model, params, k, v, tokens, slots, ctx, ctx_pos,
     jax.random.categorical.  Each distinct (temperature, top_k) pair is
     its own executable; lanes within one engine always share the knobs
     (per-lane temperatures would force them to be traced values)."""
+    fn = _jitted_forward(temperature, top_k)
+    if rng is None:
+        import jax.numpy as jnp
+
+        rng = jnp.zeros((2,), dtype="uint32")  # unused when greedy
+    return fn(model, params, k, v, tokens, slots, ctx, ctx_pos, ctx_mask,
+              q_pos, last_idx, rng, block_tables, context_lens)
+
+
+def _jitted_forward(temperature=0.0, top_k=0):
+    """The process-wide jitted stepper for one pair of sampling knobs."""
     import jax
 
     key = (float(temperature), int(top_k))
@@ -160,12 +171,7 @@ def _jit_forward(model, params, k, v, tokens, slots, ctx, ctx_pos,
 
         fn = _forward_cache[key] = jax.jit(
             _fwd, static_argnums=0, donate_argnums=(2, 3))
-    if rng is None:
-        import jax.numpy as jnp
-
-        rng = jnp.zeros((2,), dtype="uint32")  # unused when greedy
-    return fn(model, params, k, v, tokens, slots, ctx, ctx_pos, ctx_mask,
-              q_pos, last_idx, rng, block_tables, context_lens)
+    return fn
 
 
 class _Seq:
@@ -260,8 +266,14 @@ class LLMEngine:
         from ray_tpu._private.config import config
         from ray_tpu.models.llama import LlamaConfig, LlamaModel, \
             make_kv_pools
+        from ray_tpu.ops import kernel_mode
 
         self._np = np
+        # where this engine runs, found once and reported by stats(): a
+        # replica that did not find its chip serves from the CPU and the
+        # Pallas interpreter, and must say so
+        self.platform = jax.devices()[0].platform
+        self.kernel_mode = kernel_mode()
         if cfg is None:
             if isinstance(model, LlamaConfig):
                 cfg = model
@@ -600,15 +612,60 @@ class LLMEngine:
         lands here).  The dummy forwards run garbage lanes only (slot
         0, context length 0); the jit cache is process-wide, so engines
         sharing a config/geometry pay once."""
+        for width in self._paged_width_buckets():
+            args, kwargs = self._garbage_decode_args(width)
+            _tok, self._pools = self._forward(*args, **kwargs)
+
+    def _garbage_decode_args(self, width: int):
+        """`_forward` arguments for a paged decode step of garbage lanes
+        only (slot 0, context length 0) at block-table `width`."""
         np = self._np
         b = self.max_batch
         zeros1 = np.zeros((b, 1), np.int32)
-        for width in self._paged_width_buckets():
-            _tok, self._pools = self._forward(
-                zeros1, zeros1, None, None, None, zeros1,
-                np.zeros((b,), np.int32),
-                block_tables=np.zeros((b, width), np.int32),
-                context_lens=np.zeros((b,), np.int32))
+        return ((zeros1, zeros1, None, None, None, zeros1,
+                 np.zeros((b,), np.int32)),
+                {"block_tables": np.zeros((b, width), np.int32),
+                 "context_lens": np.zeros((b,), np.int32)})
+
+    def device_report(self) -> Dict[str, Any]:
+        """`ops.device_report()` plus what this engine put on the device
+        and how its decode step lowered: the Pallas kernel is a
+        `tpu_custom_call` when compiled for the chip, and absent from the
+        text under the interpreter or `attention_impl="dense"`.  Traces
+        the decode step once more (nothing runs); not for a hot path."""
+        import dataclasses
+
+        import jax
+
+        from ray_tpu.ops import device_report
+
+        def nbytes(tree) -> int:
+            return sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(tree))
+
+        rep = device_report()
+        rep.update(attention_impl=self.attention_impl,
+                   model={f.name: getattr(self.cfg, f.name)
+                          for f in dataclasses.fields(self.cfg)
+                          if f.name != "dtype"},
+                   dtype=str(jax.numpy.dtype(self.cfg.dtype)),
+                   page_size=self.page_size,
+                   param_bytes=nbytes(self._params),
+                   kv_pool_bytes=nbytes(self._pools),
+                   # executables behind the jitted stepper, all engines
+                   # of this process: constant once warm-up is done
+                   compiled_steps=sum(fn._cache_size()
+                                      for fn in _forward_cache.values()))
+        rep["decode_has_tpu_custom_call"] = False
+        if self.attention_impl == "paged":
+            args, kwargs = self._garbage_decode_args(
+                self._paged_width_buckets()[0])
+            text = _jitted_forward(self.temperature, self.top_k).lower(
+                self._model, self._params, self._pools["k"],
+                self._pools["v"], *args,
+                jax.numpy.zeros((2,), dtype="uint32"),  # rng, unused
+                kwargs["block_tables"], kwargs["context_lens"]).as_text()
+            rep["decode_has_tpu_custom_call"] = "tpu_custom_call" in text
+        return rep
 
     def _alloc_pages(self, n: int) -> List[int]:
         pages = self._free_pages[:n]
@@ -1225,6 +1282,8 @@ class LLMEngine:
     def stats(self) -> Dict[str, Any]:
         with self._lock:
             return {"steps": self._steps,
+                    "platform": self.platform,
+                    "kernel_mode": self.kernel_mode,
                     "attention_impl": self.attention_impl,
                     "decode_steps": self._decode_steps,
                     "decode_secs": self._decode_secs,
@@ -1368,6 +1427,9 @@ class _LLMCallable:
 
     def stats(self):
         return self._engine.stats()
+
+    def device_report(self):
+        return self._engine.device_report()
 
     def __rt_save__(self):
         return self._engine.save_state()

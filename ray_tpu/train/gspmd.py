@@ -13,6 +13,42 @@ from __future__ import annotations
 from typing import Any, Callable, Dict, Optional, Tuple
 
 
+def _mesh_attention_kernel(mesh) -> Optional[Callable]:
+    """The attention kernel a step partitioned over `mesh` needs; None
+    (the model's default) on a one-device mesh."""
+    if mesh.shape.get("sp", 1) > 1:
+        # sequence-parallel mesh: ring attention rotates KV over ICI
+        from ray_tpu.ops.ring_attention import make_ring_attention
+
+        return make_ring_attention(mesh)
+    if mesh.size > 1:
+        from ray_tpu.models.llama import make_mesh_attention
+
+        return make_mesh_attention(mesh)
+    return None
+
+
+def _init_opt_state(mesh, tx, params):
+    """`tx.init(params)` with every subtree that mirrors the params
+    (adam's moments) sharded as the params are, the rest replicated.
+    Left to itself the partitioner replicates all of it: zeros depend on
+    no input, so no sharding propagates to them."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    shardings = jax.tree_util.tree_map(lambda x: x.sharding, params)
+    pdef = jax.tree_util.tree_structure(params)
+
+    def mirrors_params(x) -> bool:
+        return jax.tree_util.tree_structure(x) == pdef
+
+    out = jax.tree_util.tree_map(
+        lambda x: shardings if mirrors_params(x)
+        else NamedSharding(mesh, P()),
+        jax.eval_shape(tx.init, params), is_leaf=mirrors_params)
+    return jax.jit(tx.init, out_shardings=out)(params)
+
+
 def build_llama_train_state(cfg, mesh, rng_seed: int = 0,
                             learning_rate: float = 3e-4,
                             batch_size: int = 8, seq_len: int = 128,
@@ -20,7 +56,9 @@ def build_llama_train_state(cfg, mesh, rng_seed: int = 0,
     """Init sharded (params, opt_state) and a jitted train step.
 
     Returns (params, opt_state, step_fn, model) where
-    step_fn(params, opt_state, tokens) -> (params, opt_state, loss).
+    step_fn(params, opt_state, tokens) -> (params, opt_state, loss) and
+    step_fn.device is `ops.device_report()` as of construction: the
+    platform and the Pallas kernel mode the step was built for.
     """
     import jax
     import jax.numpy as jnp
@@ -28,22 +66,19 @@ def build_llama_train_state(cfg, mesh, rng_seed: int = 0,
 
     from ray_tpu.models.llama import (LlamaModel, causal_lm_loss,
                                       llama_param_rules)
-    from ray_tpu.parallel.mesh import shard_batch, shard_params
+    from ray_tpu.parallel.mesh import init_sharded, shard_batch
 
-    if attention_kernel is None and mesh.shape.get("sp", 1) > 1:
-        # sequence-parallel mesh: ring attention rotates KV over ICI
-        from ray_tpu.ops.ring_attention import make_ring_attention
-
-        attention_kernel = make_ring_attention(mesh)
+    if attention_kernel is None:
+        attention_kernel = _mesh_attention_kernel(mesh)
     model = LlamaModel(cfg, kernel=attention_kernel)
     rng = jax.random.PRNGKey(rng_seed)
     sample = jnp.zeros((batch_size, seq_len), dtype=jnp.int32)
 
     with mesh:
-        params = jax.jit(lambda r: model.init(r, sample))(rng)["params"]
-        params = shard_params(mesh, params, llama_param_rules())
+        params = init_sharded(mesh, lambda r: model.init(r, sample)["params"],
+                              rng, llama_param_rules())
         tx = optax.adamw(learning_rate)
-        opt_state = jax.jit(tx.init)(params)
+        opt_state = _init_opt_state(mesh, tx, params)
 
         def loss_fn(p, tokens):
             logits = model.apply({"params": p}, tokens)
@@ -63,6 +98,9 @@ def build_llama_train_state(cfg, mesh, rng_seed: int = 0,
         with mesh:
             return step(p, o, tokens)
 
+    from ray_tpu.ops import device_report
+
+    step_fn.device = device_report()
     return params, opt_state, step_fn, model
 
 
@@ -95,12 +133,10 @@ def build_llama_stage_state(cfg, mesh, layer_range, *, first: bool,
 
     from ray_tpu.models.llama import (LlamaStage, causal_lm_loss,
                                       llama_param_rules)
-    from ray_tpu.parallel.mesh import shard_batch, shard_params
+    from ray_tpu.parallel.mesh import init_sharded, shard_batch
 
-    if attention_kernel is None and mesh.shape.get("sp", 1) > 1:
-        from ray_tpu.ops.ring_attention import make_ring_attention
-
-        attention_kernel = make_ring_attention(mesh)
+    if attention_kernel is None:
+        attention_kernel = _mesh_attention_kernel(mesh)
     start, end = layer_range
     model = LlamaStage(cfg, start=start, end=end, first=first, last=last,
                        kernel=attention_kernel)
@@ -114,10 +150,10 @@ def build_llama_stage_state(cfg, mesh, layer_range, *, first: bool,
     from functools import partial
 
     with mesh:
-        params = jax.jit(lambda r: model.init(r, sample))(rng)["params"]
-        params = shard_params(mesh, params, llama_param_rules())
+        params = init_sharded(mesh, lambda r: model.init(r, sample)["params"],
+                              rng, llama_param_rules())
         tx = optax.adamw(learning_rate)
-        opt_state = jax.jit(tx.init)(params)
+        opt_state = _init_opt_state(mesh, tx, params)
 
         def apply_fn(p, x):
             return model.apply({"params": p}, x)

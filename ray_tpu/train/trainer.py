@@ -40,7 +40,18 @@ class ScalingConfig:
     def worker_resources(self) -> Dict[str, float]:
         if self.resources_per_worker is not None:
             return dict(self.resources_per_worker)
-        return {"TPU": 4} if self.use_tpu else {}
+        return {"TPU": _chips_per_host()} if self.use_tpu else {}
+
+
+def _chips_per_host() -> float:
+    """One Train worker drives one host's chips: what the cluster's TPU
+    nodes report (the smallest, so every worker schedules), or 4 — a
+    v5e/v4 host — when no TPU node has joined yet and the demand is the
+    autoscaler's to meet."""
+    import ray_tpu
+
+    counts = [n["resources"]["total"].get("TPU", 0) for n in ray_tpu.nodes()]
+    return min((c for c in counts if c > 0), default=4)
 
 
 @dataclass

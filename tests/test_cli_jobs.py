@@ -16,7 +16,7 @@ import time
 import pytest
 
 import ray_tpu
-from ray_tpu._private.spawn import fast_python_cmd
+from ray_tpu._private.spawn import python_module_cmd
 
 
 @pytest.fixture
@@ -26,7 +26,7 @@ def isolated_tmpdir(tmp_path, monkeypatch):
 
 
 def _cli(args, tmpdir, timeout=120):
-    cmd, env_up = fast_python_cmd("ray_tpu.scripts", list(args))
+    cmd, env_up = python_module_cmd("ray_tpu.scripts", list(args))
     env = dict(os.environ)
     env.update(env_up)
     env["RT_TMPDIR"] = tmpdir
@@ -56,7 +56,7 @@ def test_cli_start_status_job_stop(isolated_tmpdir):
                 "[sq.remote(i) for i in range(4)], timeout=60))\n"
                 "ray_tpu.shutdown()\n")
         r = _cli(["job", "submit", "--wait", "--",
-                  sys.executable, "-S", script], tmp, timeout=240)
+                  sys.executable, script], tmp, timeout=240)
         assert r.returncode == 0, r.stdout + r.stderr
         assert "SUCCEEDED" in r.stdout
         assert "job result: [0, 1, 4, 9]" in r.stdout
@@ -104,7 +104,7 @@ def test_job_api_stop_and_logs(isolated_tmpdir):
         client = JobSubmissionClient(address)
         try:
             job_id = client.submit_job(
-                f"{sys.executable} -S -c \"import time\n"
+                f"{sys.executable} -c \"import time\n"
                 "print('spinning', flush=True)\n"
                 "time.sleep(600)\"")
             deadline = time.monotonic() + 60

@@ -3,8 +3,8 @@ scalability envelope (reference: release/benchmarks/README.md — queued
 tasks, many actors, many objects), escalated toward the reference
 numbers now that dispatch is batched (PR 8): 50k tasks queued at once,
 a single 10k-ref get, 200 concurrent actors (the actor envelope runs
-under the `slow` marker; tier-1 keeps a 24-actor version sized for the
-870s budget)."""
+under the `slow` marker; tier-1 keeps a 24-actor version sized for its
+wall budget)."""
 
 import pytest
 
@@ -30,9 +30,9 @@ def test_many_queued_tasks_drain(cluster):
     batched submit path (one push_tasks frame per lease pass, batched
     lease asks) is what makes this a queueing test instead of a
     frame-count test.  Moved behind `slow` with the 50k envelope (which
-    subsumes it) when the LLM serving tests joined tier-1 — the 870s
-    budget was at ~796s; tier-1 keeps the 10k-ref single-get and the
-    24-actor envelope below as its scale gates."""
+    subsumes it) when the LLM serving tests joined tier-1 and a serial
+    run came close to its wall; tier-1 keeps the 10k-ref single-get and
+    the 24-actor envelope below as its scale gates."""
     @ray_tpu.remote
     def unit(i):
         return i

@@ -1,0 +1,103 @@
+"""The main path's Pallas kernels, compiled at real widths by the TPU
+compiler for a described (not attached) v5e:2x2.
+
+A compile that passes is not a chip run: nothing executes, so this says
+nothing of results or times.  It does refuse what interpret mode lets
+through — a misaligned slice, too much VMEM, a kernel the chip's
+compiler will not lower — at no chip time, for every later PR.
+
+The topology is described inside a fixture of THIS file only (one
+process at a time may load the TPU library; see the on-chip-measurement
+guide §2) and every compile runs in the test's own process.  Whole-step
+compiles (engine steps, train step, the 2x2 mesh) take a minute each and
+belong to a rehearsal script, not to tier-1.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.ops.paged_attention import paged_attention
+
+# chip_smoke.py's serve phase: Llama-3-8B heads, page 16, a bf16 pool for
+# max_batch 8 sequences of 8192 tokens plus the garbage page
+B, H, HKV, D, PAGE = 8, 32, 8, 128, 16
+POOL_SLOTS = (1 + B * (8192 // PAGE)) * PAGE
+# its train phase: bench_1b heads at batch 8 x seq 1024
+TRAIN_SHAPE = (8, 1024, 12, 4, 128)
+LLAMA3_8B_SHAPE = (1, 1024, 32, 8, 128)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """A sharding on the described chip, with jax's persistent compile
+    cache off while this file runs: such a compile can be written to the
+    cache but not read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _qkv(shape, sharding):
+    b, s, h, hkv, d = shape
+    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=sharding)
+    kv = jax.ShapeDtypeStruct((b, s, hkv, d), jnp.bfloat16, sharding=sharding)
+    return q, kv, kv
+
+
+@pytest.mark.parametrize("width", [4, 512], ids=["narrowest", "widest"])
+def test_paged_decode_compiles_at_serve_shapes(one_chip, width):
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, k, v, bt, cl: paged_attention(
+            q, k, v, bt, cl, page_size=PAGE, interpret=False)
+    ).lower(spec((B, 1, H, D), jnp.bfloat16),
+            spec((POOL_SLOTS, HKV, D), jnp.bfloat16),
+            spec((POOL_SLOTS, HKV, D), jnp.bfloat16),
+            spec((B, width), jnp.int32), spec((B,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("shape", [TRAIN_SHAPE, LLAMA3_8B_SHAPE],
+                         ids=["train", "llama3_8b"])
+def test_flash_forward_compiles(one_chip, shape):
+    compiled = jax.jit(
+        lambda q, k, v: flash_attention(q, k, v, True, 128, 128, False)
+    ).lower(*_qkv(shape, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_gradient_compiles_at_train_shape(one_chip):
+    """The forward kernel plus the dense-recompute backward, as the train
+    step takes them: value and gradient (the backward reads only q, k
+    and v, so a bare gradient would drop the forward kernel as dead)."""
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, True, 128, 128, False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        *_qkv(TRAIN_SHAPE, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
