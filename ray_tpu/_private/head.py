@@ -1812,16 +1812,22 @@ class HeadService(IntrospectionRpcMixin, RpcHost):
             node = self.nodes.get(nid)
             if node is None:
                 continue
+            results: List[Dict[str, Any]] = []
             try:
-                await self._node_client(node).call(
-                    "return_bundles", pg_id=pg_id, indices=idxs)
+                results = (await self._node_client(node).call(
+                    "return_bundles", pg_id=pg_id,
+                    indices=idxs)).get("results", [])
             except Exception:
                 pass
             # update the cached view immediately — the next PG create
             # must not wait out a heartbeat period to see the freed
-            # capacity (heartbeats remain authoritative and overwrite)
+            # capacity (heartbeats remain authoritative and overwrite).
+            # `held`: the TPU share the agent keeps until the process
+            # that holds those chips has exited
+            held = {idx: r.get("held", {}) for idx, r in zip(idxs, results)}
             for idx in idxs:
-                node.resources.release(ResourceSet(entry.bundles[idx]))
+                node.resources.release(ResourceSet(entry.bundles[idx]).subtract(
+                    ResourceSet(held.get(idx, {}))))
         self._wake_pending_pgs()
         return {"ok": True}
 

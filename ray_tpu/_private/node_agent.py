@@ -1646,16 +1646,28 @@ class NodeAgent(IntrospectionRpcMixin, RpcHost):
             self._grant_token(token)
         # kill leases still running against the bundle (reference: PG
         # removal kills its tasks/actors)
+        back = sched.resources.total
         for lease_id, lease in list(self._leases.items()):
             if lease.bundle_key == key:
-                if not lease.tpu_chips:  # else: freed by _on_worker_dead
+                if lease.tpu_chips and lease.worker.proc.poll() is None:
+                    # the process holds its chips until it exits: the
+                    # lease lives on against the node pool, holding only
+                    # its TPU share, which goes back with the chip
+                    # indices in _on_worker_dead and not before
+                    held = ResourceSet({"TPU": lease.resources.get("TPU")})
+                    back = back.subtract(held)
+                    lease.bundle_key, lease.resources = "", held
+                    lease.blocked, lease.donated = False, None
+                else:
                     self._leases.pop(lease_id, None)
+                    self._free_tpu_chips.extend(lease.tpu_chips)
                     lease.worker.lease_id = None
                 self._terminate_worker(lease.worker)
-        for tok in self.local.release(sched.resources.total):
+        for tok in self.local.release(back):
             self._grant_token(tok)
         self._hb_wake.set()
-        return {"ok": True}
+        return {"ok": True,
+                "held": sched.resources.total.subtract(back).to_dict()}
 
     def _sched_for(self, ts: TaskSpec):
         """(scheduler, bundle_key) for a task; bundle-targeted tasks draw
@@ -2101,23 +2113,41 @@ class NodeAgent(IntrospectionRpcMixin, RpcHost):
                      conn=None):
         # `demand` resources are already acquired from `sched`
         renv = ts.runtime_env if ts is not None else {}
-        n_tpu = int(demand.to_dict().get("TPU", 0))
+        chips: List[int] = []
+
+        def refuse(error: str, error_str: str):
+            self._free_tpu_chips.extend(chips)
+            for tok in sched.release(demand):
+                self._grant_token(tok)
+            return {"error": error, "error_str": error_str}
+
+        tpu = demand.get("TPU")
+        n_tpu = int(tpu)
+        if tpu != n_tpu or n_tpu > len(self._free_tpu_chips):
+            # a chip belongs to one process, so a share of one cannot be
+            # leased; and the TPU count and the chip indices are freed
+            # together (_on_worker_dead), so a shortage here is a fault.
+            # Either way fail the lease: with fewer chips than it asked
+            # for, its worker would come up on the CPU backend
+            return refuse("infeasible", (
+                f"a lease asked for TPU: {tpu:g}; chips are leased whole, "
+                f"one process each, and node {self.node_id[:12]} has "
+                f"{len(self._free_tpu_chips)} free"))
+        chips = self._free_tpu_chips[:n_tpu]
+        del self._free_tpu_chips[:n_tpu]
         try:
             # a TPU lease gets a worker no task has run in: one that
             # already imported jax has its backend up and would ignore
             # the chips this lease assigns (worker._apply_chip_env)
             worker = await self._pop_worker(renv, fresh=n_tpu > 0)
         except RuntimeEnvSetupError as exc:
-            worker = None
-            for tok in sched.release(demand):
-                self._grant_token(tok)
-            return {"error": "runtime env setup failed",
-                    "error_str": str(exc)}
+            return refuse("runtime env setup failed", str(exc))
+        except BaseException:  # _grant_lease releases the demand
+            self._free_tpu_chips.extend(chips)
+            raise
         if worker is None:
-            for tok in sched.release(demand):
-                self._grant_token(tok)
-            return {"error": "worker spawn failed",
-                    "error_str": "could not start a worker process"}
+            return refuse("worker spawn failed",
+                          "could not start a worker process")
         self._lease_counter += 1
         lease_id = f"{self.node_id[:12]}-{self._lease_counter}"
         lease = _Lease(lease_id, worker, demand, bundle_key,
@@ -2134,10 +2164,7 @@ class NodeAgent(IntrospectionRpcMixin, RpcHost):
                        fid=ts.function_id if ts is not None else "",
                        task_name=(ts.name or ts.method_name)
                        if ts is not None else "")
-        take = min(n_tpu, len(self._free_tpu_chips))
-        if take > 0:
-            lease.tpu_chips = self._free_tpu_chips[:take]
-            del self._free_tpu_chips[:take]
+        lease.tpu_chips = chips
         worker.lease_id = lease_id
         worker.used = True
         self._leases[lease_id] = lease

@@ -49,8 +49,9 @@ class ReferenceCounter:
 
     `remove_local` is what `ObjectRef.__del__` calls, and a cycle
     collection can run that on a thread that is inside a critical
-    section here.  So the lock is re-entrant, `_Ref`s are built outside
-    it, and no section iterates `_refs` in Python while holding it."""
+    section here.  So the lock is re-entrant, `_Ref`s are built before
+    it is taken (each operation is ONE critical section), and no section
+    iterates `_refs` in Python while holding it."""
 
     def __init__(self, on_release: Callable[[str], None]):
         self._lock = threading.RLock()
@@ -59,15 +60,27 @@ class ReferenceCounter:
 
     # ---- local references --------------------------------------------------
 
+    def _spare(self, oid: str, owned: bool) -> Optional[_Ref]:
+        """Before taking the lock: a `_Ref` for an oid that looks new, so
+        the one critical section that follows constructs nothing (this
+        is every `.remote()`; a dict lookup is atomic under the GIL)."""
+        return None if oid in self._refs else _Ref(owned)
+
+    def _get_or_add(self, oid: str, spare: Optional[_Ref],
+                    owned: bool) -> _Ref:
+        """Lock held.  Builds a `_Ref` here only when the oid vanished
+        between `_spare`'s look and the lock; `setdefault` runs after
+        any `__del__` that construction let in, so it stays correct."""
+        ref = self._refs.get(oid)
+        if ref is None:
+            ref = self._refs.setdefault(
+                oid, spare if spare is not None else _Ref(owned))
+        return ref
+
     def add_local(self, oid: str, owned: bool) -> None:
+        spare = self._spare(oid, owned)
         with self._lock:
-            ref = self._refs.get(oid)
-            if ref is not None:
-                ref.local += 1
-                return
-        new = _Ref(owned)
-        with self._lock:
-            self._refs.setdefault(oid, new).local += 1
+            self._get_or_add(oid, spare, owned).local += 1
 
     def remove_local(self, oid: str) -> bool:
         """Returns True if this was a *borrowed* ref whose count hit zero
@@ -94,14 +107,9 @@ class ReferenceCounter:
     # ---- submission pins ---------------------------------------------------
 
     def add_submitted(self, oid: str) -> None:
+        spare = self._spare(oid, True)
         with self._lock:
-            ref = self._refs.get(oid)
-            if ref is not None:
-                ref.submitted += 1
-                return
-        new = _Ref(owned=True)
-        with self._lock:
-            self._refs.setdefault(oid, new).submitted += 1
+            self._get_or_add(oid, spare, True).submitted += 1
 
     def remove_submitted(self, oid: str) -> bool:
         release = False
@@ -127,14 +135,9 @@ class ReferenceCounter:
 
     def add_borrower(self, oid: str, borrower: Tuple[str, int]) -> None:
         borrower = tuple(borrower)
+        spare = self._spare(oid, True)
         with self._lock:
-            ref = self._refs.get(oid)
-            if ref is not None:
-                ref.borrowers.add(borrower)
-                return
-        new = _Ref(owned=True)
-        with self._lock:
-            self._refs.setdefault(oid, new).borrowers.add(borrower)
+            self._get_or_add(oid, spare, True).borrowers.add(borrower)
 
     def remove_borrower(self, oid: str, borrower: Tuple[str, int]) -> None:
         release = False

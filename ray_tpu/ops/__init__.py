@@ -19,20 +19,28 @@ import jax
 from ray_tpu.ops.ring_attention import make_ring_attention, ring_attention
 
 __all__ = ["ring_attention", "make_ring_attention", "kernel_mode",
-           "device_report"]
+           "device_report", "count_compile_cache_events"]
 
 # persistent-compile-cache hits and misses of this process, counted from
-# the import of this package on (registering touches no backend)
-_cache_events = {"/jax/compilation_cache/cache_hits": 0,
-                 "/jax/compilation_cache/cache_misses": 0}
+# the first call of `count_compile_cache_events` on
+_cache_events: Dict[str, int] = {}
 
 
-def _on_event(event: str, **_kw) -> None:
-    if event in _cache_events:
-        _cache_events[event] += 1
+def count_compile_cache_events() -> None:
+    """Start counting this process's compile-cache hits and misses (once;
+    touches no backend).  `device_report` calls it, so whoever wants the
+    compiles of a start-up counted calls it before them: the serving
+    engine does at construction, a train loop at its top."""
+    if _cache_events:
+        return
+    _cache_events.update({"/jax/compilation_cache/cache_hits": 0,
+                          "/jax/compilation_cache/cache_misses": 0})
 
+    def on_event(event: str, **_kw) -> None:
+        if event in _cache_events:
+            _cache_events[event] += 1
 
-jax.monitoring.register_event_listener(_on_event)
+    jax.monitoring.register_event_listener(on_event)
 
 
 def kernel_mode() -> str:
@@ -44,7 +52,8 @@ def device_report() -> Dict[str, Any]:
     """What this process's jax runs on, as jax reports it: the device,
     the Pallas kernel mode, device memory in use and at peak, the chips
     its lease made visible, where the compile cache lives and how often
-    it has hit since this package was imported."""
+    it has hit since `count_compile_cache_events` was first called."""
+    count_compile_cache_events()
     dev = jax.devices()[0]
     try:
         mem = dev.memory_stats() or {}

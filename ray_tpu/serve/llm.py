@@ -106,7 +106,8 @@ def _chain_hash(parent: bytes, block) -> bytes:
 
 def _jit_forward(model, params, k, v, tokens, slots, ctx, ctx_pos,
                  ctx_mask, q_pos, last_idx, temperature=0.0, top_k=0,
-                 rng=None, block_tables=None, context_lens=None):
+                 rng=None, block_tables=None, context_lens=None,
+                 top2=False):
     """One forward over the paged cache -> (next tokens at ``last_idx``,
     updated pools).  Jitted ONCE per (model, shapes, sampling knobs) —
     the flax module AND the sampling knobs are hashable static
@@ -128,8 +129,9 @@ def _jit_forward(model, params, k, v, tokens, slots, ctx, ctx_pos,
     0`` compiles logits/temperature + optional static top-k mask +
     jax.random.categorical.  Each distinct (temperature, top_k) pair is
     its own executable; lanes within one engine always share the knobs
-    (per-lane temperatures would force them to be traced values)."""
-    fn = _jitted_forward(temperature, top_k)
+    (per-lane temperatures would force them to be traced values).
+    ``top2`` is a third one, off in serving: see `_jitted_forward`."""
+    fn = _jitted_forward(temperature, top_k, top2)
     if rng is None:
         import jax.numpy as jnp
 
@@ -138,18 +140,23 @@ def _jit_forward(model, params, k, v, tokens, slots, ctx, ctx_pos,
               q_pos, last_idx, rng, block_tables, context_lens)
 
 
-def _jitted_forward(temperature=0.0, top_k=0):
-    """The process-wide jitted stepper for one pair of sampling knobs."""
+def _jitted_forward(temperature=0.0, top_k=0, top2=False):
+    """The process-wide jitted stepper for one set of static knobs.
+    With ``top2`` it returns a third value: each lane's two largest
+    logits and their ids ([B, 2] float32, [B, 2] int32) — what a
+    comparison of two engines needs to tell a rounding flip from a
+    wrong read (`LLMEngine(logit_trace=True)`)."""
     import jax
 
-    key = (float(temperature), int(top_k))
+    key = (float(temperature), int(top_k), bool(top2))
     fn = _forward_cache.get(key)
     if fn is None:
         import jax.numpy as jnp
 
         def _fwd(model, params, k, v, tokens, slots, ctx, ctx_pos,
                  ctx_mask, q_pos, last_idx, rng, block_tables,
-                 context_lens, temperature=key[0], top_k=key[1]):
+                 context_lens, temperature=key[0], top_k=key[1],
+                 top2=key[2]):
             cache = {"k": k, "v": v, "slots": slots, "q_pos": q_pos}
             if block_tables is not None:
                 cache["block_tables"] = block_tables
@@ -162,12 +169,23 @@ def _jitted_forward(temperature=0.0, top_k=0):
             picked = jnp.take_along_axis(
                 logits, last_idx[:, None, None], axis=1)[:, 0]
             if temperature <= 0.0:
-                return jnp.argmax(picked, axis=-1), pools
-            scaled = picked / temperature
-            if top_k > 0:
-                kth = jnp.sort(scaled, axis=-1)[:, -top_k][:, None]
-                scaled = jnp.where(scaled < kth, -jnp.inf, scaled)
-            return jax.random.categorical(rng, scaled, axis=-1), pools
+                tok = jnp.argmax(picked, axis=-1)
+            else:
+                scaled = picked / temperature
+                if top_k > 0:
+                    kth = jnp.sort(scaled, axis=-1)[:, -top_k][:, None]
+                    scaled = jnp.where(scaled < kth, -jnp.inf, scaled)
+                tok = jax.random.categorical(rng, scaled, axis=-1)
+            if not top2:
+                return tok, pools
+            f = picked.astype(jnp.float32)
+            i1 = jnp.argmax(f, axis=-1)
+            rest = jnp.where(jnp.arange(f.shape[-1]) == i1[:, None],
+                             -jnp.inf, f)
+            i2 = jnp.argmax(rest, axis=-1)
+            return tok, pools, (
+                jnp.stack([f.max(axis=-1), rest.max(axis=-1)], axis=-1),
+                jnp.stack([i1, i2], axis=-1).astype(jnp.int32))
 
         fn = _forward_cache[key] = jax.jit(
             _fwd, static_argnums=0, donate_argnums=(2, 3))
@@ -258,7 +276,8 @@ class LLMEngine:
                  temperature: Optional[float] = None,
                  top_k: Optional[int] = None,
                  prefix_sharing: Optional[bool] = None,
-                 attention_impl: Optional[str] = None):
+                 attention_impl: Optional[str] = None,
+                 logit_trace: bool = False):
         import jax
         import jax.numpy as jnp
         import numpy as np
@@ -266,7 +285,7 @@ class LLMEngine:
         from ray_tpu._private.config import config
         from ray_tpu.models.llama import LlamaConfig, LlamaModel, \
             make_kv_pools
-        from ray_tpu.ops import kernel_mode
+        from ray_tpu.ops import count_compile_cache_events, kernel_mode
 
         self._np = np
         # where this engine runs, found once and reported by stats(): a
@@ -274,6 +293,14 @@ class LLMEngine:
         # Pallas interpreter, and must say so
         self.platform = jax.devices()[0].platform
         self.kernel_mode = kernel_mode()
+        count_compile_cache_events()  # before this engine's compiles
+        # debugging aid, off in serving: keep the two largest logits
+        # behind every generated token (device_report()["logit_trace"]),
+        # request id -> [[index in generated, logit1, id1, logit2,
+        # id2], ...].  Grows with every token; costs a host copy a step.
+        self.logit_trace = bool(logit_trace)
+        self._logit_trace: Dict[str, List[list]] = {}
+        self._last_top2 = None
         if cfg is None:
             if isinstance(model, LlamaConfig):
                 cfg = model
@@ -582,11 +609,26 @@ class LLMEngine:
             import jax
 
             self._sample_rng, rng = jax.random.split(self._sample_rng)
-        return self._step_fn(
+        out = self._step_fn(
             self._model, self._params, self._pools["k"], self._pools["v"],
             tokens, slot_arr, ctx, ctx_pos, ctx_mask, q_pos, last_idx,
             temperature=self.temperature, top_k=self.top_k, rng=rng,
-            block_tables=block_tables, context_lens=context_lens)
+            block_tables=block_tables, context_lens=context_lens,
+            top2=self.logit_trace)
+        if self.logit_trace:
+            tok, pools, (vals, ids) = out
+            self._last_top2 = (self._np.asarray(vals), self._np.asarray(ids))
+            return tok, pools
+        return out
+
+    def _trace_top2(self, seq: _Seq, lane: int) -> None:
+        """Lock held, just before `_emit_token`: the last forward's two
+        largest logits of `lane`, for the token about to be emitted."""
+        if self._last_top2 is not None:
+            vals, ids = self._last_top2
+            self._logit_trace.setdefault(seq.request_id, []).append(
+                [len(seq.generated), float(vals[lane, 0]), int(ids[lane, 0]),
+                 float(vals[lane, 1]), int(ids[lane, 1])])
 
     def _paged_width_buckets(self) -> List[int]:
         """Block-table width buckets the paged decode path can emit:
@@ -655,11 +697,16 @@ class LLMEngine:
                    # of this process: constant once warm-up is done
                    compiled_steps=sum(fn._cache_size()
                                       for fn in _forward_cache.values()))
+        if self.logit_trace:
+            with self._lock:
+                rep["logit_trace"] = {rid: list(rows) for rid, rows
+                                      in self._logit_trace.items()}
         rep["decode_has_tpu_custom_call"] = False
         if self.attention_impl == "paged":
             args, kwargs = self._garbage_decode_args(
                 self._paged_width_buckets()[0])
-            text = _jitted_forward(self.temperature, self.top_k).lower(
+            text = _jitted_forward(self.temperature, self.top_k,
+                                   self.logit_trace).lower(
                 self._model, self._params, self._pools["k"],
                 self._pools["v"], *args,
                 jax.numpy.zeros((2,), dtype="uint32"),  # rng, unused
@@ -1093,6 +1140,7 @@ class LLMEngine:
                                 seq, int(next_tok[lane]))
                         else:
                             seq.state = _DECODE
+                            self._trace_top2(seq, lane)
                             self._emit_token(seq, int(next_tok[lane]))
             m = self.metrics()
             if m is not None:
@@ -1156,6 +1204,7 @@ class LLMEngine:
                     if seq.done:
                         continue  # cancelled while we computed
                     seq.pos += 1
+                    self._trace_top2(seq, lane)
                     self._emit_token(seq, int(next_tok[lane]))
             step_tokens += len(decode_args)
             m = self.metrics()
@@ -1499,8 +1548,9 @@ def llm_deployment(name: str = "llm", *, num_replicas: Any = 1,
     :class:`LLMEngine` and the controller installs the pinned decode
     loop on each one.  ``engine_kwargs`` go to :class:`LLMEngine`
     (model=, page_size=, num_pages=, max_batch=, prefill_chunk=,
-    max_queue=, seed=, detach_grace_s=, prefix_sharing=); unset knobs
-    fall back to the ``llm_*`` config defaults.
+    max_queue=, seed=, detach_grace_s=, prefix_sharing=, and the
+    debugging aid logit_trace=); unset knobs fall back to the ``llm_*``
+    config defaults.
 
     ``prefill_replicas > 0`` disaggregates the two serving phases: a
     sibling ``{name}-prefill`` pool (same engine config) runs chunked

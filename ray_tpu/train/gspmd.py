@@ -56,9 +56,7 @@ def build_llama_train_state(cfg, mesh, rng_seed: int = 0,
     """Init sharded (params, opt_state) and a jitted train step.
 
     Returns (params, opt_state, step_fn, model) where
-    step_fn(params, opt_state, tokens) -> (params, opt_state, loss) and
-    step_fn.device is `ops.device_report()` as of construction: the
-    platform and the Pallas kernel mode the step was built for.
+    step_fn(params, opt_state, tokens) -> (params, opt_state, loss).
     """
     import jax
     import jax.numpy as jnp
@@ -98,9 +96,6 @@ def build_llama_train_state(cfg, mesh, rng_seed: int = 0,
         with mesh:
             return step(p, o, tokens)
 
-    from ray_tpu.ops import device_report
-
-    step_fn.device = device_report()
     return params, opt_state, step_fn, model
 
 

@@ -226,7 +226,7 @@ def test_burst_then_async_is_history_independent(local_cluster):
     for _ in range(200):  # the history pollution
         ray_tpu.get(e.remote(), timeout=60)
     post = 0.0
-    for _ in range(8):
+    for _ in range(3):
         post = max(post, async_rate())
         if post >= 0.75 * fresh:
             break

@@ -117,6 +117,35 @@ def test_decode_matches_full_forward():
     assert st["used_pages"] == 0 and st["free_pages"] == 32, st
 
 
+@pytest.mark.parametrize("impl", ["paged", "dense"])
+def test_logit_trace_rows_are_the_full_forwards_top_two(impl):
+    """`logit_trace=True` keeps, for every generated token, the two
+    largest logits and their ids; they are the full forward's at that
+    position, for the prefill's token and for every decode step's."""
+    eng = _engine(logit_trace=True, attention_impl=impl)
+    prompts = {"a": [5, 9, 3], "b": [7, 11, 2, 4, 8, 1, 9, 10, 3, 2]}
+    seqs = {rid: eng.submit({"tokens": p, "max_new_tokens": 6,
+                             "request_id": rid})
+            for rid, p in prompts.items()}
+    _drain(eng)
+    trace = eng.device_report()["logit_trace"]
+    for rid, p in prompts.items():
+        gen = seqs[rid].generated
+        rows = trace[rid]
+        assert [r[0] for r in rows] == list(range(6))
+        assert [r[2] for r in rows] == gen  # greedy: id1 is the token
+        lg = np.asarray(eng._model.apply(
+            {"params": eng._params}, np.array([p + gen], np.int32))[0])
+        for j, (_i, l1, i1, l2, i2) in enumerate(rows):
+            at = lg[len(p) + j - 1]
+            order = np.argsort(at)
+            assert (i1, i2) == (order[-1], order[-2])
+            np.testing.assert_allclose([l1, l2], at[[i1, i2]],
+                                       rtol=1e-4, atol=1e-4)
+    # off (the default): the serving program and report are unchanged
+    assert "logit_trace" not in _engine().device_report()
+
+
 def test_sampling_knobs_are_static_and_seeded():
     """temperature/top_k ride the decode as jit-STATIC knobs (ISSUE 13
     satellite): a sampled engine draws valid tokens deterministically

@@ -4,6 +4,7 @@ resources a Train worker asks for.  No TPU needed: chips are faked by
 `resources={"TPU": n}`, as everywhere in tier-1."""
 
 import os
+import signal
 import time
 
 import pytest
@@ -113,6 +114,78 @@ def test_returned_tpu_lease_worker_is_not_idled():
               seconds=60)
         _wait(lambda: ray_tpu.available_resources().get("TPU", 0) == 1,
               "the chip to come back")
+    finally:
+        ray_tpu.shutdown()
+
+
+def _gone(pid):
+    try:
+        os.kill(pid, 0)
+    except ProcessLookupError:
+        return True
+    return False
+
+
+def test_removed_bundle_keeps_its_tpu_until_the_holder_is_gone(tmp_path):
+    """Removing a placement group hands the bundle back at once, but not
+    the TPU share of a lease whose worker still holds the chip: a TPU
+    lease granted in that window would find no chip index free."""
+    from ray_tpu.util.placement_group import (placement_group,
+                                              remove_placement_group)
+
+    ray_tpu.init(num_cpus=2, resources={"TPU": 1},
+                 object_store_memory=64 * 1024 * 1024)
+    try:
+        pg = placement_group([{"TPU": 1, "CPU": 1}]).ready(timeout=30)
+        pid_file = tmp_path / "pid"
+
+        @ray_tpu.remote(num_tpus=1, max_retries=0)
+        def hold(path):
+            with open(path, "w") as f:
+                f.write(str(os.getpid()))
+            time.sleep(120)
+
+        @ray_tpu.remote(num_tpus=1)
+        def chip():
+            return os.getpid(), os.environ.get("TPU_VISIBLE_CHIPS")
+
+        hold.options(placement_group=pg).remote(str(pid_file))
+        _wait(lambda: pid_file.exists() and pid_file.read_text(),
+              "the bundle's TPU task to start")
+        pid1 = int(pid_file.read_text())
+        # a stopped process takes no SIGTERM: it lives until the agent's
+        # SIGKILL, 5 s after the bundle's removal
+        os.kill(pid1, signal.SIGSTOP)
+        remove_placement_group(pg)
+        _wait(lambda: ray_tpu.available_resources().get("CPU", 0) == 2,
+              "the bundle's CPU to come back")
+        ready = chip.remote()  # asks the node pool for the one chip
+        for _ in range(20):
+            assert _gone(pid1) or \
+                ray_tpu.available_resources().get("TPU", 0) == 0, \
+                "the TPU resource came back while its holder lives"
+            time.sleep(0.1)
+        pid2, chips2 = ray_tpu.get(ready, timeout=60)
+        assert chips2 == "0" and pid2 != pid1 and _gone(pid1)
+    finally:
+        ray_tpu.shutdown()
+
+
+@pytest.mark.parametrize("tpu", [0.5, 1.5])
+def test_a_share_of_a_chip_fails_the_lease(tpu):
+    """A chip belongs to one process: a fractional TPU demand is refused
+    with its reason, not granted a worker on the CPU backend."""
+    ray_tpu.init(num_cpus=2, resources={"TPU": 2},
+                 object_store_memory=64 * 1024 * 1024)
+    try:
+        @ray_tpu.remote(resources={"TPU": tpu}, max_retries=0)
+        def backend():
+            return os.environ.get("JAX_PLATFORMS"), \
+                os.environ.get("TPU_VISIBLE_CHIPS")
+
+        with pytest.raises(ray_tpu.SchedulingError,
+                           match="chips are leased whole"):
+            ray_tpu.get(backend.remote(), timeout=60)
     finally:
         ray_tpu.shutdown()
 
