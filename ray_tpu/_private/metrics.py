@@ -747,7 +747,8 @@ def llm_metrics() -> Tuple[Counter, Gauge, Gauge, Histogram, Gauge, Gauge,
     phase=prefill|decode (decode rate IS the serving throughput);
     ``ray_tpu_llm_kv_pages`` — paged KV-cache pages by state=used|free
     (used pinned at capacity + queue depth rising = scale out);
-    ``ray_tpu_llm_batch_size`` — decode lanes in the last engine step;
+    ``ray_tpu_llm_batch_size`` — decode lanes in the last engine step
+    (zero for a step that found nothing to do);
     ``ray_tpu_llm_ttft_seconds`` — submit-to-first-token latency
     (admission queueing + chunked prefill, the serving SLO histogram);
     ``ray_tpu_llm_queue_depth`` — sequences waiting in the admission
@@ -769,8 +770,9 @@ def llm_metrics() -> Tuple[Counter, Gauge, Gauge, Histogram, Gauge, Gauge,
                   "decode lanes in the last continuous-batching step"),
             Histogram("ray_tpu_llm_ttft_seconds",
                       "LLM time-to-first-token (submit to first emit)",
-                      boundaries=[0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
-                                  0.5, 1, 2.5, 5, 10, 30]),
+                      boundaries=[0.005, 0.01, 0.025, 0.05, 0.1, 0.15,
+                                  0.2, 0.25, 0.3, 0.4, 0.5, 0.75, 1, 2.5,
+                                  5, 10, 30]),
             Gauge("ray_tpu_llm_queue_depth",
                   "sequences waiting in the LLM admission queue"),
             Gauge("ray_tpu_llm_tokens_per_step",
@@ -778,7 +780,8 @@ def llm_metrics() -> Tuple[Counter, Gauge, Gauge, Histogram, Gauge, Gauge,
             Histogram("ray_tpu_llm_decode_step_seconds",
                       "wall time of one batched LLM decode forward",
                       boundaries=[0.0005, 0.001, 0.0025, 0.005, 0.01,
-                                  0.025, 0.05, 0.1, 0.25, 0.5, 1, 2.5]),
+                                  0.015, 0.02, 0.025, 0.03, 0.04, 0.05,
+                                  0.1, 0.25, 0.5, 1, 2.5]),
         )
     return _llm_metrics
 

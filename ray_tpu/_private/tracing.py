@@ -135,8 +135,8 @@ class Span:
             self.attributes = {}
         self.attributes[key] = value
 
-    def end(self, error: str = "") -> None:
-        self.end_ts = time.time()
+    def end(self, error: str = "", at: Optional[float] = None) -> None:
+        self.end_ts = time.time() if at is None else at
         if error:
             self.status = str(error)[:200]
         _record(self.to_wire())
@@ -268,6 +268,22 @@ def start_span(name: str, kind: str = KIND_INTERNAL, parent=_UNSET,
             return None
         trace_id, parent_id = parent.trace_id, parent.span_id
     return Span(trace_id, new_span_id(), parent_id, name, kind, attributes)
+
+
+def record_span(name: str, start: float, end: float,
+                parent: Optional[SpanContext], kind: str = KIND_INTERNAL,
+                attributes: Optional[Dict[str, Any]] = None,
+                error: str = "") -> None:
+    """A span whose interval the caller measured itself (wall
+    timestamps), recorded under `parent` — for work that does not run on
+    the thread of the request it belongs to, like a sequence's stages in
+    the serving engine's loop.  Nothing without a sampled parent."""
+    if parent is None:
+        return
+    span = start_span(name, kind, parent, attributes)
+    if span is not None:
+        span.start = start
+        span.end(error, at=end)
 
 
 def _record(wire_span: Dict[str, Any]) -> None:

@@ -117,6 +117,7 @@ def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((block_q, 128), jnp.float32),  # running denom
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)
 

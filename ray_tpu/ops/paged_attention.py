@@ -177,5 +177,6 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="paged_attention_decode",
     )(bt, cl, qr, kp, vp)
     return out.reshape(b, 1, h, d)

@@ -70,7 +70,7 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
-from ray_tpu._private import deadlines
+from ray_tpu._private import deadlines, tracing
 from ray_tpu._private.errors import DeadlineExceededError
 
 __all__ = ["LLMEngine", "LLMOverloadedError", "llm_deployment",
@@ -89,6 +89,22 @@ _DECODE = "decode"
 _SHIP = "ship"  # prefill-only sequence whose pages were just exported
 
 _forward_cache: Dict[int, Any] = {}
+
+# the phases of one engine step, in the order a step passes them: the
+# key of `stats()["phase_secs"]` -> the span on the profiler's clock.
+# `admit` is the first lock section; of each pass, `build` is the numpy
+# arrays, `dispatch` the jitted call until it returns, `sync` the
+# `np.asarray` of its tokens (the wait for the device), `emit` the
+# second lock section and what follows it
+_PHASES = {"admit": "llm.admit",
+           "prefill_build": "llm.prefill.build",
+           "prefill_dispatch": "llm.prefill.dispatch",
+           "prefill_sync": "llm.prefill.sync",
+           "prefill_emit": "llm.prefill.emit",
+           "decode_build": "llm.decode.build",
+           "decode_dispatch": "llm.decode.dispatch",
+           "decode_sync": "llm.decode.sync",
+           "decode_emit": "llm.decode.emit"}
 
 # prefix-index chain seed: block k's key hashes (parent key || block
 # tokens), so one digest equality implies the WHOLE prefix matches
@@ -192,13 +208,67 @@ def _jitted_forward(temperature=0.0, top_k=0, top2=False):
     return fn
 
 
+class _StepClock:
+    """Where the stepping thread's time goes, from ONE clock read a
+    boundary: `begin` opens a step and its first phase, `phase` closes
+    the open phase and opens the next, `end` closes both, so a step's
+    phases add up to the step exactly (`phase_secs`, `step_secs`:
+    cumulative seconds).  Every call of `LLMEngine.step` counts, also
+    one that found nothing to do.
+
+    The same boundaries open and close spans on the profiler's clock
+    (`jax.profiler.TraceAnnotation`: `llm.step` with the step's number
+    `n`, and the phase's span inside it), recorded while a profile is
+    being taken and a flag test otherwise, and name the step and phase
+    to `ops.note_phase` for the record of a compile they set off."""
+
+    def __init__(self):
+        import jax
+
+        from ray_tpu.ops import note_phase
+
+        self.span = jax.profiler.TraceAnnotation
+        self._note = note_phase
+        self.step_secs = 0.0
+        self.phase_secs = dict.fromkeys(_PHASES, 0.0)
+        self._n = 0
+        self._key: Optional[str] = None
+        self._t0 = self._t_step = 0.0
+        self._step_span = self._phase_span = None
+
+    def phase(self, key: Optional[str]) -> float:
+        now = time.perf_counter()
+        if self._key is not None:
+            self.phase_secs[self._key] += now - self._t0
+            self._phase_span.__exit__(None, None, None)
+        self._key, self._t0 = key, now
+        if key is not None:
+            self._phase_span = self.span(_PHASES[key])
+            self._phase_span.__enter__()
+        self._note(key, self._n)
+        return now
+
+    def begin(self, n: int) -> float:
+        self._n = n
+        self._step_span = self.span("llm.step", n=n)
+        self._step_span.__enter__()
+        self._t_step = self.phase("admit")
+        return self._t_step
+
+    def end(self) -> None:
+        self.step_secs += self.phase(None) - self._t_step
+        self._step_span.__exit__(None, None, None)
+
+
 class _Seq:
     __slots__ = ("request_id", "prompt", "prefill_tokens", "generated",
                  "max_new", "eos", "block_table", "pos", "state", "done",
                  "error", "attach_count", "detached_at", "done_at",
-                 "submitted_at", "first_token_at", "cancelled",
-                 "slot_cache", "cond", "deadline", "kv_import",
-                 "prefill_export", "export_payload")
+                 "submitted_at", "admitted_at", "first_token_at",
+                 "cancelled", "slot_cache", "cond", "deadline", "kv_import",
+                 "prefill_export", "export_payload", "trace_ctx",
+                 "prefix_tokens", "submit_step", "admit_step",
+                 "first_token_step")
 
     def __init__(self, request_id: str, prompt: List[int], max_new: int,
                  eos: Optional[int], preknown: Optional[List[int]] = None):
@@ -225,7 +295,14 @@ class _Seq:
         self.detached_at: Optional[float] = None
         self.done_at: Optional[float] = None
         self.submitted_at = time.monotonic()
+        self.admitted_at: Optional[float] = None
         self.first_token_at: Optional[float] = None
+        # for the request's spans (`_record_request_spans`): the caller's
+        # trace context, the prompt tokens found in shared pages, and the
+        # engine step at which each stage ended
+        self.trace_ctx: Optional[tracing.SpanContext] = None
+        self.prefix_tokens = 0
+        self.submit_step = self.admit_step = self.first_token_step = -1
         self.cancelled = False
         # absolute wall-clock deadline (epoch seconds; 0 = unbounded):
         # the sweep cancels expired in-flight sequences and recycles
@@ -346,12 +423,29 @@ class LLMEngine:
         self.attention_impl = impl
         self._model = LlamaModel(
             cfg, page_size=self.page_size if impl == "paged" else 0)
+        # seconds of this replica's start-up, by part: until the weights
+        # were on the device, the call that allocates the KV pools, and
+        # `warm_up`'s compiles.  The device fills the weights while the
+        # host goes on to the pools and the warm-up, so a waiter thread
+        # notes when they were there and nothing is held up for the clock.
+        t0 = time.perf_counter()
         if params is None:
             dummy = np.zeros((1, 8), np.int32)
             params = self._model.init(
                 jax.random.PRNGKey(int(seed)), dummy)["params"]
         self._params = params
+        t1 = time.perf_counter()
         self._pools = make_kv_pools(cfg, self.num_pages * self.page_size)
+        self.startup_secs = {"params": 0.0,
+                             "pools": time.perf_counter() - t1, "warm": 0.0}
+
+        def weights_ready() -> None:
+            jax.block_until_ready(params)
+            self.startup_secs["params"] = time.perf_counter() - t0
+
+        self._weights_waiter = threading.Thread(
+            target=weights_ready, daemon=True, name="llm-weights-ready")
+        self._weights_waiter.start()
         # sampling knobs are jit-STATIC: temperature=0 (the default)
         # compiles the exact greedy program the decode-identity gate
         # covers; >0 adds temperature scaling + optional top-k masking
@@ -403,14 +497,29 @@ class LLMEngine:
         self._steps = 0
         self._cancelled_total = 0
         self._last_batch = 0
-        self._last_step_tokens = 0
         self._metrics = None
         self._warm = False
         self._paged_warm = False
-        # decode-step accumulators (bench A/B reads mean step cost as
-        # a delta between two stats() snapshots)
+        # pass accumulators (bench A/B reads mean step cost as a delta
+        # between two stats() snapshots): a pass's seconds are its build,
+        # dispatch and sync; `_clock` has every phase and the whole step
         self._decode_steps = 0
         self._decode_secs = 0.0
+        self._prefill_steps = 0
+        self._prefill_secs = 0.0
+        self._clock = _StepClock()
+        # cumulative, as `stats()` gives them.  Work: prompt tokens
+        # prefilled, the token slots (lanes x chunk) the prefill passes
+        # had for them, decode lanes stepped (over decode_steps: the mean
+        # batch).  Requests by stage (finished = ended and not
+        # cancelled), and the seconds they waited for the next one.
+        self._totals = {"prefill_tokens_total": 0,
+                        "prefill_slots_total": 0,
+                        "decode_lane_steps_total": 0,
+                        "submitted_total": 0, "admitted_total": 0,
+                        "first_tokens_total": 0, "finished_total": 0,
+                        "queue_wait_secs_total": 0.0,
+                        "prefill_wait_secs_total": 0.0}
         # EWMA of one engine step's wall time — the deadline-admission
         # estimate of "prefill + one decode step" cost (0 until the
         # first measured step; cold engines only refuse already-expired
@@ -520,6 +629,9 @@ class LLMEngine:
                 raise LLMOverloadedError(
                     f"admission queue full ({self.max_queue})")
             seq = _Seq(rid, prompt, max_new, eos)
+            seq.trace_ctx = tracing.current_context()
+            seq.submit_step = self._steps
+            self._totals["submitted_total"] += 1
             seq.deadline = dl
             seq.cond = threading.Condition(self._lock)
             seq.attach_count = 1
@@ -753,7 +865,11 @@ class LLMEngine:
         seq.cancelled = cancelled
         if cancelled:
             self._cancelled_total += 1
+        else:
+            self._totals["finished_total"] += 1
         seq.done_at = time.monotonic()
+        if seq.trace_ctx is not None and seq.trace_ctx.sampled:
+            self._record_request_spans(seq)
         if seq.cond is not None:
             seq.cond.notify_all()
         self._release_pages(seq.block_table)
@@ -765,6 +881,36 @@ class LLMEngine:
             self._queued.remove(seq)
         except ValueError:
             pass
+
+    def _record_request_spans(self, seq: _Seq) -> None:
+        """Lock held, the sequence just ended: its stages as spans under
+        the caller's trace — `llm.queue` (submit to admission),
+        `llm.prefill` (to the first token), `llm.decode` (to the end);
+        a stage it never reached has none, the one it ended in carries
+        the error.  Once a request, not once a token.  `first_step` and
+        `last_step` are `llm.step`'s `n` on the profiler's timeline."""
+        wall = time.time() - time.monotonic()  # monotonic -> epoch
+        attrs = {"request_id": seq.request_id,
+                 "prompt_tokens": len(seq.prompt),
+                 "prefix_tokens_shared": seq.prefix_tokens,
+                 "tokens_generated": len(seq.generated)}
+        stages = (
+            ("llm.queue", seq.submitted_at, seq.admitted_at,
+             seq.submit_step, seq.admit_step),
+            ("llm.prefill", seq.admitted_at, seq.first_token_at,
+             seq.admit_step, seq.first_token_step),
+            ("llm.decode", seq.first_token_at, seq.done_at,
+             seq.first_token_step, self._steps))
+        for name, start, end, first, last in stages:
+            if start is None:
+                break
+            ended_here = end is None or name == "llm.decode"
+            if end is None:
+                end, last = seq.done_at, self._steps
+            tracing.record_span(
+                name, wall + start, wall + end, seq.trace_ctx,
+                attributes=dict(attrs, first_step=first, last_step=last),
+                error="cancelled" if ended_here and seq.cancelled else "")
 
     def _slot(self, seq: _Seq, pos: int) -> int:
         return (seq.block_table[pos // self.page_size] * self.page_size
@@ -927,7 +1073,7 @@ class LLMEngine:
             if shared_tok:
                 # prefill starts at the first unshared token: the
                 # attached pages already hold this prefix's KV
-                seq.pos = shared_tok
+                seq.pos = seq.prefix_tokens = shared_tok
                 self._prefix_hits += 1
                 self._prefix_tokens_shared += shared_tok
                 m = self.metrics()
@@ -935,7 +1081,26 @@ class LLMEngine:
                     m["prefix_hits"].inc(
                         tags={"kind": "cow" if cow else "page"})
             seq.state = _PREFILL
+            seq.admitted_at = time.monotonic()
+            seq.admit_step = self._steps
+            self._totals["admitted_total"] += 1
+            self._totals["queue_wait_secs_total"] += \
+                seq.admitted_at - seq.submitted_at
             self._active.append(seq)
+
+    def _note_first_token(self, seq: _Seq) -> None:
+        """Lock held: the sequence has a token; on its first, count it
+        and the time since admission and since submit."""
+        if seq.first_token_at is not None:
+            return
+        seq.first_token_at = time.monotonic()
+        seq.first_token_step = self._steps
+        self._totals["first_tokens_total"] += 1
+        self._totals["prefill_wait_secs_total"] += \
+            seq.first_token_at - seq.admitted_at
+        m = self.metrics()
+        if m is not None:
+            m["ttft"].observe(seq.first_token_at - seq.submitted_at)
 
     def _emit_token(self, seq: _Seq, token: int) -> None:
         """Lock held: append one generated token, finish on EOS/budget,
@@ -944,11 +1109,7 @@ class LLMEngine:
         parked stream thread per token."""
         seq.generated.append(int(token))
         n = len(seq.generated)
-        if seq.first_token_at is None:
-            seq.first_token_at = time.monotonic()
-            m = self.metrics()
-            if m is not None:
-                m["ttft"].observe(seq.first_token_at - seq.submitted_at)
+        self._note_first_token(seq)
         if (seq.eos is not None and int(token) == seq.eos) \
                 or n >= seq.max_new:
             self._finish_seq(seq)
@@ -1002,11 +1163,7 @@ class LLMEngine:
         copy).  ``prefill_request`` wakes on the finish notify."""
         from ray_tpu.models.llama import gather_kv_slots
 
-        if seq.first_token_at is None:
-            seq.first_token_at = time.monotonic()
-            m = self.metrics()
-            if m is not None:
-                m["ttft"].observe(seq.first_token_at - seq.submitted_at)
+        self._note_first_token(seq)
         seq.generated.append(int(first_token))
         n = seq.pos
         n_pages = -(-n // self.page_size)
@@ -1061,9 +1218,18 @@ class LLMEngine:
         """One engine iteration: admit, one prefill chunk, one decode
         pass over every decoding sequence.  Returns False when there was
         nothing to do (the loop then parks on the condition)."""
+        t_step = self._clock.begin(self._steps)
+        try:
+            return self._step(t_step)
+        finally:
+            self._clock.end()
+
+    def _step(self, t_step: float) -> bool:
+        """`step`'s body.  `phase(key)` is the clock's boundary: it ends
+        the open phase, opens `key` and returns its one clock read."""
         np = self._np
+        phase = self._clock.phase
         now = time.monotonic()
-        t_step = time.perf_counter()
         with self._lock:
             self._sweep(now)
             self._admit_locked()
@@ -1073,7 +1239,6 @@ class LLMEngine:
             decode = [s for s in self._active if s.state == _DECODE]
             if not prefills and not decode:
                 self._last_batch = 0
-                self._last_step_tokens = 0
                 self._set_gauges()  # idle must publish zeros, not
                 # freeze the last busy step's values into the ring
                 return imported  # an import that finished immediately
@@ -1102,6 +1267,7 @@ class LLMEngine:
         # prompt still shares the loop with in-flight decodes instead
         # of monopolizing it
         if prefill_args:
+            t_pre = phase("prefill_build")
             lanes = self.prefill_lanes
             c = self.prefill_chunk
             tokens = np.zeros((lanes, c), np.int32)
@@ -1120,11 +1286,17 @@ class LLMEngine:
                 ctx_mask[lane, :hi] = True
                 q_pos[lane, :hi - lo] = self._arange[lo:hi]
                 last_idx[lane] = hi - lo - 1
+            phase("prefill_dispatch")
             next_tok, self._pools = self._forward(
                 tokens, slot_arr, ctx, ctx_pos, ctx_mask, q_pos, last_idx)
+            phase("prefill_sync")
             next_tok = np.asarray(next_tok)
+            self._prefill_secs += phase("prefill_emit") - t_pre
+            self._prefill_steps += 1
             chunk_tokens = sum(hi - lo for _s, lo, hi, *_r in prefill_args)
             step_tokens += chunk_tokens
+            self._totals["prefill_tokens_total"] += chunk_tokens
+            self._totals["prefill_slots_total"] += lanes * c
             with self._lock:
                 for lane, (seq, lo, hi, *_rest) in enumerate(prefill_args):
                     if seq.done:
@@ -1147,6 +1319,7 @@ class LLMEngine:
                 m["tokens"].inc(chunk_tokens, tags={"phase": "prefill"})
         # ---- token-level decode batch
         if decode_args:
+            t_dec = phase("decode_build")
             b = self.max_batch
             tokens = np.zeros((b, 1), np.int32)
             slot_arr = np.zeros((b, 1), np.int32)
@@ -1155,7 +1328,8 @@ class LLMEngine:
             if self.attention_impl == "paged" and not self._paged_warm:
                 self._paged_warm = True
                 self._warm_paged_buckets()
-            t_dec = time.perf_counter()
+                # the one-time warm-up is this phase's, not decode_secs'
+                t_dec = time.perf_counter()
             if self.attention_impl == "paged":
                 # page-granular context: block tables + context lengths
                 # instead of [B, ctx_len] gather/mask arrays.  The table
@@ -1177,6 +1351,7 @@ class LLMEngine:
                     block_tables[lane, :used] = table[:used]
                     context_lens[lane] = n
                     q_pos[lane, 0] = seq.pos
+                phase("decode_dispatch")
                 next_tok, self._pools = self._forward(
                     tokens, slot_arr, None, None, None, q_pos, last_idx,
                     block_tables=block_tables, context_lens=context_lens)
@@ -1192,13 +1367,16 @@ class LLMEngine:
                     ctx_pos[lane, :n] = self._arange[:n]
                     ctx_mask[lane, :n] = True
                     q_pos[lane, 0] = seq.pos
+                phase("decode_dispatch")
                 next_tok, self._pools = self._forward(
                     tokens, slot_arr, ctx, ctx_pos, ctx_mask, q_pos,
                     last_idx)
+            phase("decode_sync")
             next_tok = np.asarray(next_tok)  # device sync: real step cost
-            decode_dt = time.perf_counter() - t_dec
+            decode_dt = phase("decode_emit") - t_dec
             self._decode_steps += 1
             self._decode_secs += decode_dt
+            self._totals["decode_lane_steps_total"] += len(decode_args)
             with self._lock:
                 for lane, (seq, *_rest) in enumerate(decode_args):
                     if seq.done:
@@ -1213,7 +1391,6 @@ class LLMEngine:
                 m["decode_step"].observe(decode_dt)
         self._steps += 1
         self._last_batch = len(decode_args)
-        self._last_step_tokens = step_tokens
         # step-cost estimate for deadline admission (prefill + one
         # decode step).  Admission wants "can this POSSIBLY finish", so
         # the estimate must be a floor-ish typical cost: a faster step
@@ -1227,8 +1404,16 @@ class LLMEngine:
         else:
             self._step_ewma = 0.9 * self._step_ewma \
                 + 0.1 * min(dt, 5.0 * self._step_ewma)
-        self._set_gauges()
+        self._set_gauges(len(decode_args), step_tokens)
         return True
+
+    def warm_up(self) -> None:
+        """Compile both jitted shapes (prefill chunk + decode) by running
+        one tiny request inline, before any loop or traffic."""
+        t0 = time.perf_counter()
+        self.generate_batch([{"tokens": [1], "max_new_tokens": 2}])
+        self.startup_secs["warm"] = time.perf_counter() - t0
+        self._weights_waiter.join(60.0)  # a forward ran: they are there
 
     def run_loop(self) -> Dict[str, Any]:
         """The pinned decode loop: step while there is work, park on the
@@ -1241,7 +1426,7 @@ class LLMEngine:
         try:
             while not self._stopped.is_set():
                 if not self.step():
-                    with self._cond:
+                    with self._clock.span("llm.park"), self._cond:
                         if not self._queued and not self._active:
                             self._cond.wait(0.05)
             return {"steps": self._steps}
@@ -1316,7 +1501,8 @@ class LLMEngine:
         """Lock held: pages referenced by more than one sequence."""
         return sum(1 for r in self._page_refs if r > 1)
 
-    def _set_gauges(self) -> None:
+    def _set_gauges(self, batch: int = 0, step_tokens: int = 0) -> None:
+        """Publish the step's own counts (an idle step's are zero)."""
         m = self.metrics()
         if m is None:
             return
@@ -1324,11 +1510,16 @@ class LLMEngine:
                        tags={"state": "used"})
         m["pages"].set(len(self._free_pages), tags={"state": "free"})
         m["pages"].set(self._shared_page_count(), tags={"state": "shared"})
-        m["batch"].set(self._last_batch)
+        m["batch"].set(batch)
         m["queue"].set(len(self._queued))
-        m["tps"].set(self._last_step_tokens)
+        m["tps"].set(step_tokens)
 
     def stats(self) -> Dict[str, Any]:
+        """Counters and gauges of this engine.  Every `*_total`,
+        `*_secs` and `*_steps` key is cumulative and never falls: a
+        reader takes the change between two calls."""
+        from ray_tpu.ops import compile_counts
+
         with self._lock:
             return {"steps": self._steps,
                     "platform": self.platform,
@@ -1336,6 +1527,12 @@ class LLMEngine:
                     "attention_impl": self.attention_impl,
                     "decode_steps": self._decode_steps,
                     "decode_secs": self._decode_secs,
+                    "prefill_steps": self._prefill_steps,
+                    "prefill_secs": self._prefill_secs,
+                    "step_secs": self._clock.step_secs,
+                    "phase_secs": dict(self._clock.phase_secs),
+                    **self._totals, **compile_counts(),
+                    "startup_secs": dict(self.startup_secs),
                     "queued": len(self._queued),
                     "active": len(self._active),
                     "cancelled": self._cancelled_total,
@@ -1392,6 +1589,7 @@ class LLMEngine:
                 if len(seq.generated) >= seq.max_new:
                     continue  # finished before the snapshot landed
                 seq.detached_at = now  # grace window for re-attach
+                self._totals["submitted_total"] += 1
                 self._by_rid[rid] = seq
                 self._queued.append(seq)
             self._cond.notify_all()
@@ -1418,8 +1616,7 @@ class _LLMCallable:
             # (serve_replica_health_timeout_s) covers it, so the first
             # real request never pays ~seconds of XLA compile while
             # reconcile health probes run against their 5s timeout
-            self._engine.generate_batch(
-                [{"tokens": [1], "max_new_tokens": 2}])
+            self._engine.warm_up()
 
     def __call__(self, request):
         emit_from = 0
@@ -1480,6 +1677,26 @@ class _LLMCallable:
     def device_report(self):
         return self._engine.device_report()
 
+    def profile_start(self, trace_dir: str) -> None:
+        """Start a `jax.profiler` trace of this replica's process into
+        `trace_dir`: the device's operations and, on the same clock, the
+        engine's `llm.*` spans and jax's own host spans (no Python
+        frames).  Only the process that holds the chip can trace it."""
+        import os
+
+        import jax
+
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        os.makedirs(trace_dir, exist_ok=True)
+        jax.profiler.start_trace(trace_dir, profiler_options=opts)
+
+    def profile_stop(self) -> None:
+        import jax
+
+        jax.profiler.stop_trace()
+
     def __rt_save__(self):
         return self._engine.save_state()
 
@@ -1506,8 +1723,7 @@ class _LLMBatchCallable:
 
         self._engine = LLMEngine(**engine_kwargs)
         if warm:
-            self._engine.generate_batch(
-                [{"tokens": [1], "max_new_tokens": 2}])
+            self._engine.warm_up()
         self._gen = batch(self._run_batch,
                           max_batch_size=max_batch_size,
                           batch_wait_timeout_s=batch_wait_timeout_s)

@@ -715,6 +715,44 @@ def llm_big(llm_cluster):
                                  detach_grace_s=0.3)
 
 
+def test_llm_stream_trace_reaches_into_the_engine(llm_cluster, llm_big):
+    """One trace from the caller to the decode loop: `serve.stream`
+    (client) -> the replica's execute span (server) -> the engine's
+    `llm.queue`, `llm.prefill`, `llm.decode`, flushed worker -> head
+    like any other span."""
+    from ray_tpu._private import tracing
+    from ray_tpu.util.state import get_trace
+
+    caller = tracing.start_span("test caller", parent=None)
+    token = tracing.activate(caller.context())
+    try:
+        items = [ray_tpu.get(ref, timeout=60) for ref in llm_big.stream(
+            {"tokens": [7, 2, 9, 4], "max_new_tokens": 5})]
+    finally:
+        tracing.restore(token)
+    assert sum(len(it["tokens"]) for it in items) == 5
+    deadline = time.monotonic() + 60
+    while True:   # spans flush on the task-event cadence
+        try:
+            spans = get_trace(caller.trace_id)["spans"]
+        except ValueError:
+            spans = []
+        by_name = {s["name"]: s for s in spans}
+        if {"serve.stream llm_big", "llm.queue", "llm.prefill",
+                "llm.decode"} <= set(by_name):
+            break   # the driver's span and the worker's flush apart
+        assert time.monotonic() < deadline, sorted(by_name)
+        time.sleep(0.3)
+    stream = by_name["serve.stream llm_big"]
+    assert stream["parent_id"] == caller.span_id
+    execute = next(s for s in spans if s["kind"] == tracing.KIND_SERVER)
+    assert execute["parent_id"] in {s["span_id"] for s in spans}
+    for name in ("llm.queue", "llm.prefill", "llm.decode"):
+        assert by_name[name]["parent_id"] == execute["span_id"], name
+        assert by_name[name]["attrs"]["prompt_tokens"] == 4
+    assert by_name["llm.decode"]["attrs"]["tokens_generated"] == 5
+
+
 def test_llm_queue_full_sheds_503(llm_cluster, llm_big):
     """Admission past the bounded queue answers 503 BEFORE any SSE
     bytes (the first-item prefetch maps LLMOverloadedError to the shed
