@@ -120,6 +120,17 @@ def _chain_hash(parent: bytes, block) -> bytes:
     return h.digest()
 
 
+def _pow4_widths(first: int, cap: int) -> List[int]:
+    """`first`, 4 x `first`, ... up to (and capped at) `cap`: the widths
+    a pass's context arrays snap to, one compiled program each."""
+    widths, w = [], first
+    while True:
+        widths.append(min(w, cap))
+        if w >= cap:
+            return widths
+        w *= 4
+
+
 def _jit_forward(model, params, k, v, tokens, slots, ctx, ctx_pos,
                  ctx_mask, q_pos, last_idx, temperature=0.0, top_k=0,
                  rng=None, block_tables=None, context_lens=None,
@@ -236,14 +247,14 @@ class _StepClock:
         self._t0 = self._t_step = 0.0
         self._step_span = self._phase_span = None
 
-    def phase(self, key: Optional[str]) -> float:
+    def phase(self, key: Optional[str], **args) -> float:
         now = time.perf_counter()
         if self._key is not None:
             self.phase_secs[self._key] += now - self._t0
             self._phase_span.__exit__(None, None, None)
         self._key, self._t0 = key, now
         if key is not None:
-            self._phase_span = self.span(_PHASES[key])
+            self._phase_span = self.span(_PHASES[key], **args)
             self._phase_span.__enter__()
         self._note(key, self._n)
         return now
@@ -500,6 +511,7 @@ class LLMEngine:
         self._metrics = None
         self._warm = False
         self._paged_warm = False
+        self._prefill_warm = False
         # pass accumulators (bench A/B reads mean step cost as a delta
         # between two stats() snapshots): a pass's seconds are its build,
         # dispatch and sync; `_clock` has every phase and the whole step
@@ -513,13 +525,21 @@ class LLMEngine:
         # had for them, decode lanes stepped (over decode_steps: the mean
         # batch).  Requests by stage (finished = ended and not
         # cancelled), and the seconds they waited for the next one.
+        # Context: the rows the prefill passes' real lanes read, the
+        # columns (lanes x width) the passes gathered for them, and the
+        # passes by the width they took.
         self._totals = {"prefill_tokens_total": 0,
                         "prefill_slots_total": 0,
+                        "prefill_ctx_rows_total": 0,
+                        "prefill_ctx_cols_total": 0,
                         "decode_lane_steps_total": 0,
                         "submitted_total": 0, "admitted_total": 0,
                         "first_tokens_total": 0, "finished_total": 0,
                         "queue_wait_secs_total": 0.0,
                         "prefill_wait_secs_total": 0.0}
+        self._prefill_widths = self._prefill_ctx_buckets()
+        self._prefill_passes_by_width = dict.fromkeys(
+            self._prefill_widths, 0)
         # EWMA of one engine step's wall time — the deadline-admission
         # estimate of "prefill + one decode step" cost (0 until the
         # first measured step; cold engines only refuse already-expired
@@ -749,12 +769,35 @@ class LLMEngine:
         at small contexts (cheap: unused pages are predicated off and
         their copies deduped) for half the per-bucket jit compiles the
         warm-up burst has to pay."""
-        widths, w = [], 4
-        while True:
-            widths.append(min(w, self.pages_per_seq))
-            if w >= self.pages_per_seq:
-                return widths
-            w *= 4
+        return _pow4_widths(4, self.pages_per_seq)
+
+    def _prefill_ctx_buckets(self) -> List[int]:
+        """Context widths a prefill pass can gather: powers of four from
+        4 x prefill_chunk up to (and capped at) ctx_len, the shape of
+        `_paged_width_buckets`.  A pass takes the smallest that covers
+        the longest context any of its lanes reads; the columns it drops
+        are masked in every lane.  Below 4 chunks a narrower program
+        saves less than its compile costs every replica's start-up, so
+        an engine whose ctx_len is at most that has one: its ctx_len."""
+        return _pow4_widths(4 * self.prefill_chunk, self.ctx_len)
+
+    def _warm_prefill_buckets(self, but: int) -> None:
+        """Compile every prefill context width up front, at the FIRST
+        prefill pass, for the reason `_warm_paged_buckets` gives for
+        decode (the deployment warm-up request lands here): garbage
+        lanes only (slot 0, every context column masked).  `but` is the
+        width that pass is about to run itself, so an engine with one
+        width runs nothing here."""
+        np = self._np
+        lanes, c = self.prefill_lanes, self.prefill_chunk
+        zeros = np.zeros((lanes, c), np.int32)
+        for width in self._prefill_widths:
+            if width == but:
+                continue
+            ctx = np.zeros((lanes, width), np.int32)
+            _tok, self._pools = self._forward(
+                zeros, zeros, ctx, ctx, np.zeros((lanes, width), bool),
+                zeros, np.zeros((lanes,), np.int32))
 
     def _warm_paged_buckets(self) -> None:
         """Compile every paged block-table width bucket up front, at
@@ -1262,19 +1305,31 @@ class LLMEngine:
                      list(seq.block_table), seq.pos + 1))
         step_tokens = 0
         # ---- chunked prefill, batched across lanes: up to
-        # prefill_lanes sequences advance one chunk each per step — a
-        # burst of N admissions costs N/lanes steps, while a LONG
-        # prompt still shares the loop with in-flight decodes instead
-        # of monopolizing it
+        # prefill_lanes sequences advance one chunk each in ONE pass of
+        # fixed shape (lanes x chunk, empty lanes are garbage), in the
+        # steps where a prompt waits — a burst of N admissions costs
+        # N/lanes passes, while a LONG prompt still shares the loop with
+        # in-flight decodes instead of monopolizing it.  The context the
+        # pass gathers is as wide as the smallest _prefill_ctx_buckets()
+        # entry covering its longest lane: its cost tracks USED context,
+        # at one program a bucket.
         if prefill_args:
             t_pre = phase("prefill_build")
+            ctx_rows = [hi for _s, _lo, hi, *_r in prefill_args]
+            longest = max(ctx_rows)
+            width = next(w for w in self._prefill_widths if w >= longest)
+            if not self._prefill_warm:
+                self._prefill_warm = True
+                self._warm_prefill_buckets(but=width)
+                # the one-time warm-up is this phase's, not prefill_secs'
+                t_pre = time.perf_counter()
             lanes = self.prefill_lanes
             c = self.prefill_chunk
             tokens = np.zeros((lanes, c), np.int32)
             slot_arr = np.zeros((lanes, c), np.int32)
-            ctx = np.zeros((lanes, self.ctx_len), np.int32)
-            ctx_pos = np.zeros((lanes, self.ctx_len), np.int32)
-            ctx_mask = np.zeros((lanes, self.ctx_len), bool)
+            ctx = np.zeros((lanes, width), np.int32)
+            ctx_pos = np.zeros((lanes, width), np.int32)
+            ctx_mask = np.zeros((lanes, width), bool)
             q_pos = np.zeros((lanes, c), np.int32)
             last_idx = np.zeros((lanes,), np.int32)
             for lane, (seq, lo, hi, toks, slots, ctx_slots) \
@@ -1286,7 +1341,7 @@ class LLMEngine:
                 ctx_mask[lane, :hi] = True
                 q_pos[lane, :hi - lo] = self._arange[lo:hi]
                 last_idx[lane] = hi - lo - 1
-            phase("prefill_dispatch")
+            phase("prefill_dispatch", width=width)
             next_tok, self._pools = self._forward(
                 tokens, slot_arr, ctx, ctx_pos, ctx_mask, q_pos, last_idx)
             phase("prefill_sync")
@@ -1297,6 +1352,9 @@ class LLMEngine:
             step_tokens += chunk_tokens
             self._totals["prefill_tokens_total"] += chunk_tokens
             self._totals["prefill_slots_total"] += lanes * c
+            self._totals["prefill_ctx_rows_total"] += sum(ctx_rows)
+            self._totals["prefill_ctx_cols_total"] += lanes * width
+            self._prefill_passes_by_width[width] += 1
             with self._lock:
                 for lane, (seq, lo, hi, *_rest) in enumerate(prefill_args):
                     if seq.done:
@@ -1408,8 +1466,10 @@ class LLMEngine:
         return True
 
     def warm_up(self) -> None:
-        """Compile both jitted shapes (prefill chunk + decode) by running
-        one tiny request inline, before any loop or traffic."""
+        """Compile every program traffic can reach, before any loop or
+        traffic, by running one tiny request inline: its first prefill
+        pass compiles every prefill context width, its first decode step
+        every decode width."""
         t0 = time.perf_counter()
         self.generate_batch([{"tokens": [1], "max_new_tokens": 2}])
         self.startup_secs["warm"] = time.perf_counter() - t0
@@ -1531,7 +1591,10 @@ class LLMEngine:
                     "prefill_secs": self._prefill_secs,
                     "step_secs": self._clock.step_secs,
                     "phase_secs": dict(self._clock.phase_secs),
-                    **self._totals, **compile_counts(),
+                    **self._totals,
+                    "prefill_passes_by_width":
+                        dict(self._prefill_passes_by_width),
+                    **compile_counts(),
                     "startup_secs": dict(self.startup_secs),
                     "queued": len(self._queued),
                     "active": len(self._active),
@@ -1611,11 +1674,12 @@ class _LLMCallable:
     def __init__(self, warm: bool = True, **engine_kwargs):
         self._engine = LLMEngine(**engine_kwargs)
         if warm:
-            # compile both jitted shapes (prefill chunk + decode) HERE,
-            # inside the replica constructor: the deploy health gate
-            # (serve_replica_health_timeout_s) covers it, so the first
-            # real request never pays ~seconds of XLA compile while
-            # reconcile health probes run against their 5s timeout
+            # compile every jitted shape (each prefill context width,
+            # each decode width) HERE, inside the replica constructor:
+            # the deploy health gate (serve_replica_health_timeout_s)
+            # covers it, so the first real request never pays ~seconds
+            # of XLA compile while reconcile health probes run against
+            # their 5s timeout
             self._engine.warm_up()
 
     def __call__(self, request):

@@ -13,6 +13,7 @@ import socket
 import threading
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -91,6 +92,28 @@ def _drain(engine, rounds=200):
             break
 
 
+def _eager_logits(eng, toks):
+    return np.asarray(eng._model.apply(
+        {"params": eng._params}, np.array([toks], np.int32))[0])
+
+
+def _assert_trace_is_the_full_forwards(eng, seq, full=_eager_logits):
+    """Tokens and top-two logits of `seq` against ONE no-cache forward
+    over prompt + generated (`full`), to the tolerance paged holds
+    against dense."""
+    p, gen = list(seq.prompt), list(seq.generated)
+    rows = eng.device_report()["logit_trace"][seq.request_id]
+    assert [r[0] for r in rows] == list(range(len(gen)))
+    assert [r[2] for r in rows] == gen  # greedy: id1 is the token
+    lg = full(eng, p + gen)
+    for j, (_i, l1, i1, l2, i2) in enumerate(rows):
+        at = lg[len(p) + j - 1]
+        order = np.argsort(at)
+        assert (i1, i2) == (order[-1], order[-2]), (len(p), j)
+        np.testing.assert_allclose([l1, l2], at[[i1, i2]],
+                                   rtol=1e-4, atol=1e-4)
+
+
 # ----------------------------------------------------------- engine units
 
 
@@ -128,22 +151,182 @@ def test_logit_trace_rows_are_the_full_forwards_top_two(impl):
                              "request_id": rid})
             for rid, p in prompts.items()}
     _drain(eng)
-    trace = eng.device_report()["logit_trace"]
-    for rid, p in prompts.items():
-        gen = seqs[rid].generated
-        rows = trace[rid]
-        assert [r[0] for r in rows] == list(range(6))
-        assert [r[2] for r in rows] == gen  # greedy: id1 is the token
-        lg = np.asarray(eng._model.apply(
-            {"params": eng._params}, np.array([p + gen], np.int32))[0])
-        for j, (_i, l1, i1, l2, i2) in enumerate(rows):
-            at = lg[len(p) + j - 1]
-            order = np.argsort(at)
-            assert (i1, i2) == (order[-1], order[-2])
-            np.testing.assert_allclose([l1, l2], at[[i1, i2]],
-                                       rtol=1e-4, atol=1e-4)
+    for seq in seqs.values():
+        assert len(seq.generated) == 6
+        _assert_trace_is_the_full_forwards(eng, seq)
     # off (the default): the serving program and report are unchanged
     assert "logit_trace" not in _engine().device_report()
+
+
+# ------------------------------------------- prefill context-width buckets
+# One geometry for every test below, so the process-wide jit cache holds
+# its seven programs once: chunk 16 under a context of 1024 gives the
+# prefill pass three widths (64, 256, 1024), page 8 the decode pass four.
+
+WIDE_CHUNK, WIDE_CTX = 16, 1024
+WIDE_BUCKETS = [64, 256, 1024]
+
+
+def _wide_engine(**kw):
+    kw.setdefault("cfg", _cfg(max_seq_len=WIDE_CTX))
+    kw.setdefault("num_pages", 1 + 3 * (WIDE_CTX // 8))
+    kw.setdefault("prefill_chunk", WIDE_CHUNK)
+    kw.setdefault("logit_trace", True)
+    return _engine(**kw)
+
+
+def _wide_prompt(n, salt=0):
+    return [1 + (7 * i + i // 5 + salt) % 60 for i in range(n)]
+
+
+@pytest.fixture(scope="module")
+def wide_eng():
+    """One warmed engine for the cases below: each drains it, and gives
+    its prompts a first token of their own so none hits another's
+    prefix."""
+    eng = _wide_engine()
+    eng.warm_up()
+    return eng
+
+
+_wide_full = {}
+
+
+def _wide_logits(eng, toks):
+    """The no-cache forward at ONE jitted shape for every length:
+    causal, so the padding behind `toks` moves no row before it.  1016
+    is a length the flash routing (multiples of 128) leaves dense."""
+    if "fn" not in _wide_full:
+        _wide_full["fn"] = jax.jit(
+            lambda params, t: eng._model.apply({"params": params}, t)[0])
+    padded = np.zeros((1, WIDE_CTX - 8), np.int32)
+    padded[0, :len(toks)] = toks
+    return np.asarray(_wide_full["fn"](eng._params, padded))
+
+
+@pytest.mark.parametrize("n_prompt,company", [
+    (63, "alone"), (64, "alone"), (65, "alone"),
+    (255, "alone"), (256, "alone"), (257, "alone"), (1000, "alone"),
+    (64, "with_a_short_prompt"), (256, "with_a_short_prompt"),
+    (1000, "with_a_short_prompt")])
+def test_prefill_width_buckets_keep_the_full_forwards_tokens(
+        wide_eng, n_prompt, company):
+    """A prompt ending one row under, on and over each bucket edge, and
+    one deep in the last bucket: the pass that holds its last chunk
+    takes the bucket that covers it, also when a short prompt shares
+    that pass and reads the same wider context, and every token is the
+    no-cache forward's."""
+    eng = wide_eng
+    assert eng._prefill_ctx_buckets() == WIDE_BUCKETS
+    salt = n_prompt + 2 * (company != "alone")
+    long = eng.submit({"tokens": _wide_prompt(n_prompt, salt),
+                       "max_new_tokens": 3})
+    seqs = [long]
+    for _ in range(-(-n_prompt // WIDE_CHUNK) - 1):
+        eng.step()
+    assert long.pos == (n_prompt - 1) // WIDE_CHUNK * WIDE_CHUNK
+    if company == "with_a_short_prompt":
+        seqs.append(eng.submit({"tokens": _wide_prompt(5, salt + 1),
+                                "max_new_tokens": 3}))
+    before = eng.stats()["prefill_passes_by_width"]
+    eng.step()   # the long prompt's last chunk, beside the short prompt
+    assert all(s.generated for s in seqs)
+    after = eng.stats()["prefill_passes_by_width"]
+    width = next(w for w in WIDE_BUCKETS if w >= n_prompt)
+    assert {w: after[w] - before[w] for w in WIDE_BUCKETS} == {
+        w: int(w == width) for w in WIDE_BUCKETS}
+    _drain(eng)
+    for s in seqs:
+        assert len(s.generated) == 3
+        _assert_trace_is_the_full_forwards(eng, s, full=_wide_logits)
+    assert eng.stats()["used_pages"] == 0
+
+
+@pytest.mark.parametrize("warmed_by", ["warm_up", "its_first_pass"])
+def test_every_prefill_width_is_compiled_before_the_second_pass(warmed_by):
+    """After `warm_up()`, and just as well after the first prefill pass
+    and decode step of an engine nobody warmed, prompts that reach every
+    bucket, alone and together, compile nothing: no new executable
+    behind the stepper and no backend compile in the process."""
+    eng = _wide_engine()
+    widths, forward = [], eng._forward
+
+    def spy(tokens, slot_arr, ctx, *rest, **kw):
+        if ctx is not None:
+            widths.append(ctx.shape[1])
+        return forward(tokens, slot_arr, ctx, *rest, **kw)
+
+    eng._forward = spy
+    if warmed_by == "warm_up":
+        eng.warm_up()
+    else:
+        eng.submit({"tokens": _wide_prompt(70), "max_new_tokens": 2})
+        eng.step()   # the first pass: the other widths, then its own
+        assert widths == [256, 1024, 64]
+        _drain(eng)  # the first decode step warms decode's widths
+    assert sorted(set(widths)) == WIDE_BUCKETS
+    assert eng.stats()["prefill_passes_by_width"][1024] == 0  # not counted
+    steps = eng.device_report()["compiled_steps"]
+    compiles = eng.stats()["compiles_total"]
+    assert steps >= len(WIDE_BUCKETS) + len(eng._paged_width_buckets())
+    for lengths in ([20], [63, 64, 65], [255, 5], [256, 257, 300],
+                    [1000, 40, 7]):
+        seqs = [eng.submit({"tokens": _wide_prompt(n, salt=n),
+                            "max_new_tokens": 2}) for n in lengths]
+        _drain(eng, rounds=400)
+        assert all(s.done and len(s.generated) == 2 for s in seqs)
+    by_width = eng.stats()["prefill_passes_by_width"]
+    assert all(by_width[w] > 0 for w in WIDE_BUCKETS), by_width
+    assert eng.device_report()["compiled_steps"] == steps
+    assert eng.stats()["compiles_total"] == compiles
+
+
+def test_prefill_context_counters_say_what_was_gathered():
+    eng = _wide_engine()
+    lanes = eng.prefill_lanes
+    st0 = eng.stats()
+    assert st0["prefill_passes_by_width"] == dict.fromkeys(WIDE_BUCKETS, 0)
+    # a 20-token prompt: two passes (16 + 4 tokens) reading 16 and 20
+    # rows, each gathering lanes x the FIRST bucket, not x ctx_len
+    eng.generate_batch([{"tokens": _wide_prompt(20), "max_new_tokens": 2}])
+    st = eng.stats()
+    assert st["prefill_steps"] == 2
+    assert st["prefill_ctx_rows_total"] == 16 + 20
+    assert st["prefill_ctx_cols_total"] == 2 * lanes * WIDE_BUCKETS[0]
+    assert st["prefill_passes_by_width"] == {64: 2, 256: 0, 1024: 0}
+    # two lanes of one pass: the rows of both, the columns of the wider
+    eng.generate_batch([{"tokens": _wide_prompt(70), "max_new_tokens": 2},
+                        {"tokens": _wide_prompt(9, 1), "max_new_tokens": 2}])
+    st = eng.stats()
+    assert st["prefill_steps"] == 2 + 5
+    assert sum(st["prefill_passes_by_width"].values()) == st["prefill_steps"]
+    assert st["prefill_passes_by_width"] == {64: 6, 256: 1, 1024: 0}
+    assert st["prefill_ctx_rows_total"] == 36 + 9 + 16 + 32 + 48 + 64 + 70
+    assert st["prefill_ctx_cols_total"] == lanes * (6 * 64 + 256)
+    assert st["prefill_ctx_rows_total"] <= st["prefill_ctx_cols_total"]
+
+
+@pytest.mark.parametrize("max_seq_len,chunk,buckets", [
+    (4096, 64, [256, 1024, 4096]),   # the benchmark's serving cells
+    (WIDE_CTX, WIDE_CHUNK, WIDE_BUCKETS),
+    (2048, 64, [256, 1024, 2048]),   # capped at ctx_len, not a power
+    (512, 64, [256, 512]),
+    (256, 64, [256]),                # the bench rehearsal: one program
+    (64, 16, [64]),
+    (64, 64, [64]),
+    (1024, 512, [1024]),
+])
+def test_prefill_ctx_buckets_by_geometry(max_seq_len, chunk, buckets):
+    """Powers of four from 4 x chunk, capped at ctx_len; an engine whose
+    ctx_len is at most 4 x chunk has one width, its ctx_len: the program
+    it ran before there were buckets."""
+    eng = _engine(cfg=_cfg(max_seq_len=max_seq_len), prefill_chunk=chunk,
+                  num_pages=3)
+    assert eng.ctx_len == max_seq_len
+    assert eng._prefill_ctx_buckets() == buckets
+    assert list(eng.stats()["prefill_passes_by_width"]) == buckets
+    if max_seq_len <= 4 * chunk:
+        assert buckets == [eng.ctx_len]
 
 
 def test_sampling_knobs_are_static_and_seeded():
