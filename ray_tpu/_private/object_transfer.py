@@ -618,7 +618,7 @@ def pack_kv_pages(meta: Dict, rows: Dict) -> bytes:
     self-checksummed blob.  ``meta`` is a small picklable dict (request
     id, prompt tokens, first generated token, slot count, page size);
     ``rows`` is {"k": [per-layer host arrays], "v": [...]} as returned
-    by models.llama.gather_kv_slots."""
+    by models.cache.gather_slots."""
     import pickle
     import zlib
 

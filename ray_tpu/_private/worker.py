@@ -38,7 +38,8 @@ import threading
 import time
 import traceback
 from collections import deque
-from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
+from typing import (Any, Callable, Dict, List, Optional, Sequence, Set,
+                    Tuple)
 
 import cloudpickle
 from concurrent.futures import CancelledError as _futures_cancelled
@@ -591,6 +592,9 @@ class CoreWorker(IntrospectionRpcMixin, RpcHost):
         self._actor_calls_since_save = 0
         self._pending_acks: Dict[str, Any] = {}  # task_id -> held values
         self._exec_threads: List[threading.Thread] = []
+        # how to make the pinned system loop of this worker return (set
+        # by the loop while it runs): see rpc_exit_worker
+        self._pinned_stop: Optional[Callable[[], None]] = None
 
     @property
     def _exec(self):
@@ -3722,6 +3726,14 @@ class CoreWorker(IntrospectionRpcMixin, RpcHost):
 
     async def rpc_exit_worker(self):
         self._task_queue.put(None)
+        # a pinned system loop never goes back to the queue.  Where it
+        # holds the MAIN exec thread (whichever thread dequeued the
+        # loop's task holds it: a race), the sentinel ends the
+        # concurrency threads only and the process outlives its kill,
+        # lease and chip with it: ask the loop to return
+        stop = self._pinned_stop
+        if stop is not None:
+            stop()
 
     async def rpc_persist_actor_state(self):
         """Drain hook: flush this worker's actor state via ``__rt_save__``

@@ -1,5 +1,53 @@
-"""Model zoo for the TPU-native stack (flagship: Llama-family decoder)."""
+"""Model zoo for the TPU-native stack, and what a model is to the rest
+of it.
+
+A model FAMILY is a module of this package that defines
+
+    config(model)          the value of `LLMEngine(model=...)` — a config
+                           instance, a dictionary of sizes, a preset's
+                           name — as the family's frozen config dataclass
+                           (`max_seq_len`, `dtype`, `cache_spec()`)
+    build(cfg, page_size)  the flax module: `init(rng, tokens)` and
+                           `apply(params, tokens, cache)` -> (logits,
+                           pools[, counters]); `counters` names the
+                           entries of the counter vector, if it has one
+
+and whose config states its cache by layer (models/cache.py).  The plain
+reference of a family lives with the benchmark (`benchmarks/reference*`)
+and reads nothing but the parameter tree.
+
+`resolve(model)` picks the family: a dictionary's `model_type`, or the
+family whose config class the instance is; a dictionary without
+`model_type`, and a preset's name, are Llama's.
+"""
+
+from importlib import import_module
+from typing import Any, Tuple
 
 from ray_tpu.models.llama import LlamaConfig, LlamaModel, llama_param_rules
 
-__all__ = ["LlamaConfig", "LlamaModel", "llama_param_rules"]
+__all__ = ["LlamaConfig", "LlamaModel", "llama_param_rules", "resolve",
+           "FAMILIES"]
+
+# `model_type` -> the module of this package that implements it
+FAMILIES = {"llama": "llama", "mistral": "llama", "laguna": "laguna"}
+
+
+def resolve(model: Any) -> Tuple[Any, Any]:
+    """(the family's module, the config) of an engine's `model=`."""
+    if isinstance(model, dict):
+        model = dict(model)
+        model_type = model.pop("model_type", "llama")
+        if model_type not in FAMILIES:
+            raise ValueError(f"no model family for model_type "
+                             f"{model_type!r} (have {sorted(FAMILIES)})")
+        name = FAMILIES[model_type]
+    elif isinstance(model, str):
+        name = "llama"
+    else:
+        # a config instance: its class lives in its family's module
+        name = type(model).__module__.rsplit(".", 1)[-1]
+        if name not in FAMILIES.values():
+            raise ValueError(f"no model family takes {model!r}")
+    family = import_module(f"ray_tpu.models.{name}")
+    return family, family.config(model)

@@ -1,0 +1,438 @@
+"""Laguna-family decoder: gated attention whose head count and kind
+(full or sliding window) change by layer, a dense lead, then layers of
+routed experts with one shared expert.
+
+Written from the published `config.json` keys (`model_type: laguna`):
+`layer_types`, `num_attention_heads_per_layer`, `mlp_layer_types`,
+`sliding_window`, `rope_parameters` by layer kind, `num_experts`,
+`num_experts_per_tok`, `moe_routed_scaling_factor`, `gating`.  For layer
+l with input x [S, D], H_l heads and h = RMSNorm(x):
+
+    q = h Wq [S, H_l, hd]; k = h Wk, v = h Wv [S, Hkv, hd]
+    rotary by kind: a sliding layer rotates the whole head at its theta;
+      a full layer rotates the first `partial_rotary_factor` of the head
+      with YaRN's frequencies, cos and sin times `attention_factor`
+    causal scores / sqrt(hd), a sliding layer sees i - W < j <= i
+    gate g = sigmoid(h Wg) [S, H_l]; x += (g_h * a_h concatenated) Wo
+    h' = RMSNorm(x); a dense layer: x += SwiGLU(h')
+    a sparse layer: x += SwiGLU_shared(h') + factor * routed(h')
+
+What the config does not say is ONE function each, so that a reader with
+the model's own code corrects it in one place (the configuration file
+lists them under `assumed`): `gate_activation`, `router_scores`,
+`combine_shared` and `qk_normalize`.
+
+This chip may hold a share of a layer: `experts_held` = (lo, hi) of the
+router's `num_experts` (ops/moe.py computes that share's part of the
+routed sum) and `vocab_size` rows of the vocabulary.  The matrices are
+stored in `param_dtype`, bfloat16 as the configuration states.
+
+The cache is by layer (`cache_spec`): a full layer keeps every position,
+a sliding layer the last `sliding_window`; the engine hands each kind
+its own slots, context and block tables under `cache["groups"][kind]`.
+"""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass, fields
+from typing import Any, Dict, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ray_tpu.models.cache import LayerCache
+from ray_tpu.models.llama import RMSNorm, cached_attention
+from ray_tpu.ops import moe
+
+FULL, SLIDING = "full_attention", "sliding_attention"
+
+
+def _frozen(value):
+    """Lists and dicts of a config.json as hashable tuples."""
+    if isinstance(value, dict):
+        return tuple(sorted((k, _frozen(v)) for k, v in value.items()))
+    if isinstance(value, (list, tuple)):
+        return tuple(_frozen(v) for v in value)
+    return value
+
+
+@dataclass(frozen=True)
+class LagunaConfig:
+    vocab_size: int = 100352           # the rows held here
+    hidden_size: int = 3072
+    intermediate_size: int = 12288     # the dense layers' width
+    num_hidden_layers: int = 48
+    num_key_value_heads: int = 8
+    head_dim: int = 128
+    max_position_embeddings: int = 8192
+    rms_norm_eps: float = 1e-6
+    num_experts: int = 256             # the router's width
+    num_experts_per_tok: int = 10
+    moe_intermediate_size: int = 1024
+    shared_expert_intermediate_size: int = 1024
+    norm_topk_prob: bool = True
+    moe_routed_scaling_factor: float = 2.5
+    sliding_window: int = 512
+    layer_types: Tuple[str, ...] = ()
+    mlp_layer_types: Tuple[str, ...] = ()
+    num_attention_heads_per_layer: Tuple[int, ...] = ()
+    rope_parameters: Tuple = ()        # `_frozen` of the published group
+    experts_held: Tuple[int, int] = (0, 256)
+    dtype: Any = jnp.bfloat16          # activations and the KV cache
+    param_dtype: Any = jnp.bfloat16    # the stored matrices
+
+    @classmethod
+    def from_dict(cls, model: Dict[str, Any]) -> "LagunaConfig":
+        """The published keys (and `experts_held`) as a config; keys that
+        say nothing of the shape (`model_type`, `gating`, ...) are read
+        by nobody and left out."""
+        names = {f.name for f in fields(cls)}
+        return cls(**{k: _frozen(v) for k, v in model.items()
+                      if k in names})
+
+    @classmethod
+    def tiny(cls) -> "LagunaConfig":
+        """Test size: the five leading layer kinds, 8 experts, 4 held."""
+        return cls.from_dict(dict(
+            vocab_size=256, hidden_size=64, intermediate_size=128,
+            num_hidden_layers=5, num_key_value_heads=2, head_dim=16,
+            max_position_embeddings=256, num_experts=8,
+            num_experts_per_tok=2, moe_intermediate_size=32,
+            shared_expert_intermediate_size=32, sliding_window=32,
+            layer_types=[FULL] + [SLIDING] * 3 + [FULL],
+            mlp_layer_types=["dense"] + ["sparse"] * 4,
+            num_attention_heads_per_layer=[4, 6, 6, 6, 4],
+            rope_parameters={
+                "full_attention": {
+                    "rope_type": "yarn", "rope_theta": 500000,
+                    "factor": 128, "original_max_position_embeddings": 64,
+                    "beta_fast": 32, "beta_slow": 1,
+                    "attention_factor": 1.4852030263919618,
+                    "partial_rotary_factor": 0.5},
+                "sliding_attention": {
+                    "rope_type": "default", "rope_theta": 10000,
+                    "partial_rotary_factor": 1}},
+            experts_held=[0, 4]))
+
+    @property
+    def max_seq_len(self) -> int:
+        return self.max_position_embeddings
+
+    def rope(self, layer_type: str) -> Dict[str, Any]:
+        return dict(dict(self.rope_parameters)[layer_type])
+
+    def cache_spec(self) -> Tuple[LayerCache, ...]:
+        return tuple(
+            LayerCache("window" if t == SLIDING else "full",
+                       self.sliding_window if t == SLIDING else 0,
+                       self.num_key_value_heads, self.head_dim)
+            for t in self.layer_types)
+
+    def share(self) -> Dict[str, Any]:
+        """What of each layer this chip holds (`device_report`)."""
+        return {"experts_held": list(self.experts_held),
+                "num_experts": self.num_experts,
+                "vocab_rows": self.vocab_size}
+
+
+# ------------------------------------------------- the assumed conventions
+
+
+def gate_activation(z: jax.Array) -> jax.Array:
+    """assumed (1): the head-wise gate is the logistic sigmoid."""
+    return jax.nn.sigmoid(z)
+
+
+def router_scores(logits: jax.Array) -> jax.Array:
+    """assumed (2): softmax over all experts, before the top-k."""
+    return jax.nn.softmax(logits, axis=-1)
+
+
+def combine_shared(shared: jax.Array, routed: jax.Array,
+                   factor: float) -> jax.Array:
+    """assumed (3): the shared expert is added ungated and unscaled;
+    the factor multiplies the routed sum only."""
+    return shared + factor * routed
+
+
+def qk_normalize(q: jax.Array, k: jax.Array):
+    """assumed (4): no normalisation of q or k."""
+    return q, k
+
+
+# ------------------------------------------------------------------ rotary
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's inverse frequencies over `dim` rotated dimensions, as
+    `transformers`' `_compute_yarn_parameters`: interpolated by 1 /
+    factor, extrapolated, blended by the linear ramp between the
+    correction dimensions of beta_fast and beta_slow."""
+
+    def correction_dim(rotations: float) -> float:
+        return (dim * math.log(original / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    pos_freqs = theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    extrapolation = 1.0 - ramp
+    return ((1.0 / (factor * pos_freqs)) * (1.0 - extrapolation)
+            + (1.0 / pos_freqs) * extrapolation).astype(np.float32)
+
+
+def rope_tables(rope: Dict[str, Any], head_dim: int):
+    """(rotated dimensions, inverse frequencies, cos/sin factor) of one
+    layer kind's `rope_parameters` entry."""
+    dim = int(head_dim * float(rope.get("partial_rotary_factor", 1)))
+    theta = float(rope["rope_theta"])
+    if rope.get("rope_type", "default") == "yarn":
+        inv = yarn_inv_freq(dim, theta, float(rope["factor"]),
+                            int(rope["original_max_position_embeddings"]),
+                            float(rope["beta_fast"]),
+                            float(rope["beta_slow"]))
+        return dim, inv, float(rope["attention_factor"])
+    inv = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    return dim, inv.astype(np.float32), 1.0
+
+
+def _rotary(x: jax.Array, positions: jax.Array, dim: int,
+            inv_freq: np.ndarray, factor: float) -> jax.Array:
+    """Half-split rotation of the first `dim` dimensions of x [B, S, H,
+    hd]; the rest pass through."""
+    angles = positions[..., None].astype(jnp.float32) * inv_freq
+    cos = (jnp.cos(angles) * factor)[:, :, None, :]
+    sin = (jnp.sin(angles) * factor)[:, :, None, :]
+    half = dim // 2
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:dim].astype(jnp.float32)
+    out = jnp.concatenate(
+        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
+    if dim == x.shape[-1]:
+        return out
+    return jnp.concatenate([out, x[..., dim:]], axis=-1)
+
+
+def masked_attention(q, k, v, window: int) -> jax.Array:
+    """Causal attention of a whole sequence (no cache), over the last
+    `window` positions where window > 0.  q [B, S, H, D]; k, v [B, S,
+    Hkv, D]."""
+    b, s, h, d = q.shape
+    hkv = k.shape[2]
+    q5 = q.reshape(b, s, hkv, h // hkv, d)
+    logits = jnp.einsum("bshgd,bthd->bhgst", q5, k).astype(jnp.float32)
+    logits = logits / jnp.sqrt(d).astype(jnp.float32)
+    i = jnp.arange(s)[:, None]
+    j = jnp.arange(s)[None, :]
+    mask = j <= i
+    if window > 0:
+        mask = mask & (j > i - window)
+    logits = jnp.where(mask[None, None, None], logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhgst,bthd->bshgd", probs, v).reshape(b, s, h, d)
+
+
+# ----------------------------------------------------------------- modules
+
+
+class SwiGLU(nn.Module):
+    cfg: LagunaConfig
+    width: int
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        dense = lambda n, name: nn.Dense(  # noqa: E731
+            n, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            name=name)
+        gate = dense(self.width, "w1")(x)
+        up = dense(self.width, "w3")(x)
+        return dense(cfg.hidden_size, "w2")(nn.silu(gate) * up)
+
+
+class GatedAttention(nn.Module):
+    cfg: LagunaConfig
+    layer: int
+    page_size: int = 0
+
+    @nn.compact
+    def __call__(self, x, positions, cache=None):
+        cfg = self.cfg
+        kind = cfg.layer_types[self.layer]
+        n_heads = cfg.num_attention_heads_per_layer[self.layer]
+        window = cfg.sliding_window if kind == SLIDING else 0
+        dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
+            features=feats, use_bias=False, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, name=name)
+        q = dense((n_heads, cfg.head_dim), "wq")(x)
+        k = dense((cfg.num_key_value_heads, cfg.head_dim), "wk")(x)
+        v = dense((cfg.num_key_value_heads, cfg.head_dim), "wv")(x)
+        q, k = qk_normalize(q, k)
+        rot = rope_tables(cfg.rope(kind), cfg.head_dim)
+        q = _rotary(q, positions, *rot)
+        k = _rotary(k, positions, *rot)
+        with jax.named_scope("attn_gate"):
+            gate = gate_activation(
+                dense(n_heads, "attn_gate")(x).astype(jnp.float32))
+        wo = nn.DenseGeneral(
+            features=cfg.hidden_size, axis=(-2, -1), use_bias=False,
+            dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="wo")
+        pools = None
+        if cache is None:
+            out = masked_attention(q, k, v, window)
+        else:
+            b, s = k.shape[0], k.shape[1]
+            flat = cache["slots"].reshape(-1)
+            pool_k = cache["k"].at[flat].set(k.reshape(b * s, *k.shape[2:]))
+            pool_v = cache["v"].at[flat].set(v.reshape(b * s, *v.shape[2:]))
+            pools = (pool_k, pool_v)
+            if cache.get("block_tables") is not None and s == 1 \
+                    and self.page_size > 0:
+                from ray_tpu.ops.paged_attention import paged_attention
+
+                out = paged_attention(
+                    q, pool_k, pool_v, cache["block_tables"],
+                    cache["context_lens"], page_size=self.page_size,
+                    window=window or None, starts=cache.get("starts"))
+            else:
+                out = cached_attention(
+                    q, pool_k, pool_v, cache["ctx"], cache["ctx_pos"],
+                    cache["ctx_mask"], positions, window=window or None)
+        with jax.named_scope("attn_gate"):
+            out = (out.astype(jnp.float32) * gate[..., None]).astype(
+                cfg.dtype)
+        return wo(out), pools
+
+
+class ExpertLayer(nn.Module):
+    """The shared expert plus this share's part of the routed sum."""
+    cfg: LagunaConfig
+
+    @nn.compact
+    def __call__(self, x, valid):
+        cfg = self.cfg
+        b, s, d = x.shape
+        lo, hi = cfg.experts_held
+        e, f = hi - lo, cfg.moe_intermediate_size
+        flat = x.reshape(b * s, d)
+        w_router = self.param(
+            "moe_router", nn.initializers.lecun_normal(),
+            (d, cfg.num_experts), cfg.param_dtype)
+
+        def experts(shape, fan_in):
+            return nn.initializers.normal(fan_in ** -0.5), shape, \
+                cfg.param_dtype
+
+        w1 = self.param("moe_experts_w1", *experts((e, d, f), d))
+        w3 = self.param("moe_experts_w3", *experts((e, d, f), d))
+        w2 = self.param("moe_experts_w2", *experts((e, f, d), f))
+        routed, counters = moe.moe_layer(
+            flat, w_router, w1, w3, w2, top_k=cfg.num_experts_per_tok,
+            held=(lo, hi), valid=valid.reshape(b * s),
+            normalize=cfg.norm_topk_prob, scores=router_scores)
+        shared = SwiGLU(cfg, cfg.shared_expert_intermediate_size,
+                        name="moe_shared")(x)
+        y = combine_shared(shared.astype(jnp.float32),
+                           routed.reshape(b, s, d),
+                           cfg.moe_routed_scaling_factor)
+        return y.astype(cfg.dtype), counters
+
+
+class LagunaBlock(nn.Module):
+    cfg: LagunaConfig
+    layer: int
+    page_size: int = 0
+
+    @nn.compact
+    def __call__(self, x, positions, valid, cache=None):
+        cfg = self.cfg
+        h = RMSNorm(cfg.rms_norm_eps, name="attn_norm")(x)
+        a, pools = GatedAttention(cfg, self.layer, self.page_size,
+                                  name="attn")(h, positions, cache)
+        x = x + a
+        h = RMSNorm(cfg.rms_norm_eps, name="mlp_norm")(x)
+        counters = None
+        if cfg.mlp_layer_types[self.layer] == "dense":
+            x = x + SwiGLU(cfg, cfg.intermediate_size, name="mlp")(h)
+        else:
+            y, counters = ExpertLayer(cfg, name="moe")(h, valid)
+            x = x + y
+        return x, pools, counters
+
+
+class LagunaModel(nn.Module):
+    """`forward(tokens, cache)`: with a cache, (logits, pools, counters
+    [len(moe.COUNTERS) + 1] int32: `moe.COUNTERS` summed over the sparse
+    layers, then the sparse layers passed); without, the logits of the
+    whole sequence."""
+    cfg: LagunaConfig
+    page_size: int = 0
+
+    # names of the counter vector's entries, for the engine's stats()
+    counters = tuple(f"moe_{n}_total" for n in moe.COUNTERS) \
+        + ("moe_layer_passes_total", "moe_expert_slots_total")
+
+    @nn.compact
+    def __call__(self, tokens, cache=None):
+        cfg = self.cfg
+        x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
+                     param_dtype=cfg.param_dtype, name="embed")(tokens)
+        if cache is None:
+            positions = jnp.broadcast_to(
+                jnp.arange(tokens.shape[1]), tokens.shape)
+            valid = jnp.ones(tokens.shape, bool)
+        else:
+            positions = cache["q_pos"]
+            # slot 0 is the engine's garbage slot: a token written there
+            # is padding and is routed to no expert
+            valid = cache["groups"]["full"]["slots"] != 0
+        new_k, new_v, totals, passes = [], [], None, 0
+        for i, spec in enumerate(cfg.cache_spec()):
+            layer_cache = None
+            if cache is not None:
+                layer_cache = {"k": cache["k"][i], "v": cache["v"][i],
+                               **cache["groups"][spec.kind]}
+            x, pools, counters = LagunaBlock(
+                cfg, i, self.page_size, name=f"layer_{i}")(
+                x, positions, valid, layer_cache)
+            if pools is not None:
+                new_k.append(pools[0])
+                new_v.append(pools[1])
+            if counters is not None:
+                passes += 1
+                vec = jnp.stack([counters[n] for n in moe.COUNTERS])
+                totals = vec if totals is None else totals + vec
+        x = RMSNorm(cfg.rms_norm_eps, name="final_norm")(x)
+        logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
+                          param_dtype=cfg.param_dtype, name="lm_head")(x)
+        if cache is None:
+            return logits
+        held = cfg.experts_held[1] - cfg.experts_held[0]
+        if totals is None:
+            totals = jnp.zeros((len(moe.COUNTERS),), jnp.int32)
+        vec = jnp.concatenate([
+            totals.astype(jnp.int32),
+            jnp.asarray([passes, passes * held], jnp.int32)])
+        return logits, {"k": new_k, "v": new_v}, vec
+
+
+def build(cfg: LagunaConfig, page_size: int = 0) -> LagunaModel:
+    return LagunaModel(cfg, page_size=page_size)
+
+
+def config(model: Any) -> LagunaConfig:
+    """`LLMEngine(model=...)`'s value as a config: a config, the
+    published keys as a dictionary, or a preset's name."""
+    if isinstance(model, LagunaConfig):
+        return model
+    if isinstance(model, dict):
+        return LagunaConfig.from_dict(model)
+    return getattr(LagunaConfig, str(model))()

@@ -34,11 +34,13 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from ray_tpu.models.cache import LayerCache
 
 
 @dataclass(frozen=True)
@@ -83,6 +85,11 @@ class LlamaConfig:
     @property
     def head_dim(self) -> int:
         return self.dim // self.n_heads
+
+    def cache_spec(self) -> Tuple[LayerCache, ...]:
+        """Every layer keeps every position (models/cache.py)."""
+        return (LayerCache("full", 0, self.n_kv_heads,
+                           self.head_dim),) * self.n_layers
 
     def num_params(self) -> int:
         embed = self.vocab_size * self.dim
@@ -171,7 +178,8 @@ def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def cached_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                      ctx: jax.Array, ctx_pos: jax.Array,
-                     ctx_mask: jax.Array, q_pos: jax.Array) -> jax.Array:
+                     ctx_mask: jax.Array, q_pos: jax.Array,
+                     window: Optional[int] = None) -> jax.Array:
     """Attention over a slot-pool KV cache.
 
     q: [B,S,H,D] (post-rope); pool_k/pool_v: [T,Hkv,D] flat slot pools
@@ -179,7 +187,9 @@ def cached_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     slot index of each context entry (garbage entries point at slot 0);
     ctx_pos: [B,L] the token position each entry holds; ctx_mask: [B,L]
     validity; q_pos: [B,S] query positions.  Causality = position mask,
-    so one kernel serves chunked prefill (S>1) and decode (S=1)."""
+    so one kernel serves chunked prefill (S>1) and decode (S=1).  With
+    ``window``, a query sees the last ``window`` positions up to its
+    own only."""
     b, s, h, d = q.shape
     hkv = pool_k.shape[1]
     group = h // hkv
@@ -190,6 +200,8 @@ def cached_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     logits = logits / jnp.sqrt(d).astype(jnp.float32)
     mask = (ctx_pos[:, None, :] <= q_pos[:, :, None]) \
         & ctx_mask[:, None, :]                      # [B,S,L]
+    if window is not None:
+        mask = mask & (ctx_pos[:, None, :] > q_pos[:, :, None] - window)
     logits = jnp.where(mask[:, None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(cv.dtype)
     out = jnp.einsum("bhgsl,blhd->bshgd", probs, cv)
@@ -303,23 +315,16 @@ class LlamaModel(nn.Module):
         if cache is not None:
             # incremental decode/prefill over the paged KV cache: query
             # positions come from the engine, per-layer pools are
-            # threaded through and returned updated.  The cache carries
-            # EITHER dense gather arrays (ctx/ctx_pos/ctx_mask — chunked
-            # prefill, or dense decode) OR page-granular block tables +
-            # context lengths (paged decode kernel).
+            # threaded through and returned updated.  Every layer is of
+            # the cache kind "full", whose group carries the write slots
+            # and EITHER dense gather arrays (ctx/ctx_pos/ctx_mask —
+            # chunked prefill, or dense decode) OR page-granular block
+            # tables + context lengths (paged decode kernel).
             positions = cache["q_pos"]
-            paged = cache.get("block_tables") is not None
             new_k, new_v = [], []
             for i in range(cfg.n_layers):
                 layer_cache = {"k": cache["k"][i], "v": cache["v"][i],
-                               "slots": cache["slots"]}
-                if paged:
-                    layer_cache["block_tables"] = cache["block_tables"]
-                    layer_cache["context_lens"] = cache["context_lens"]
-                else:
-                    layer_cache.update(
-                        ctx=cache["ctx"], ctx_pos=cache["ctx_pos"],
-                        ctx_mask=cache["ctx_mask"])
+                               **cache["groups"]["full"]}
                 x, pk, pv = Block(cfg, self.kernel, self.page_size,
                                   name=f"layer_{i}")(
                     x, positions, layer_cache)
@@ -391,61 +396,26 @@ class LlamaStage(nn.Module):
         return x
 
 
-def make_kv_pools(cfg: LlamaConfig, num_slots: int,
-                  dtype: Any = None) -> Dict[str, Any]:
-    """Allocate flat per-layer KV slot pools for incremental decoding.
-
-    ``num_slots`` = pages x page_size; slot 0 is reserved as the
-    garbage slot for inactive batch lanes (serve/llm.py never hands it
-    to a sequence).  Sized from ``n_kv_heads``/``head_dim`` — the GQA
-    shrink is exactly what makes a resident cache affordable."""
-    dtype = dtype or cfg.dtype
-    shape = (num_slots, cfg.n_kv_heads, cfg.head_dim)
-    return {"k": [jnp.zeros(shape, dtype) for _ in range(cfg.n_layers)],
-            "v": [jnp.zeros(shape, dtype) for _ in range(cfg.n_layers)]}
-
-
-def gather_kv_slots(pools: Dict[str, Any], slots: Any) -> Dict[str, Any]:
-    """Read the KV rows at ``slots`` out of every layer's pool as host
-    numpy arrays — the export half of KV-page shipping (serve/llm.py
-    disaggregated prefill).  Paging-agnostic: ``slots`` is whatever flat
-    slot indices the caller's block tables resolve to."""
-    import numpy as np
-
-    idx = np.asarray(slots, np.int32)
-    return {"k": [np.asarray(p[idx]) for p in pools["k"]],
-            "v": [np.asarray(p[idx]) for p in pools["v"]]}
-
-
-def scatter_kv_slots(pools: Dict[str, Any], slots: Any,
-                     rows: Dict[str, Any]) -> Dict[str, Any]:
-    """Write previously-gathered KV rows into ``slots`` of every
-    layer's pool (the import half of KV-page shipping).  Returns the
-    updated pools — jax arrays are immutable, so callers must adopt the
-    result."""
-    idx = jnp.asarray(slots, jnp.int32)
-    return {"k": [p.at[idx].set(jnp.asarray(r, p.dtype))
-                  for p, r in zip(pools["k"], rows["k"])],
-            "v": [p.at[idx].set(jnp.asarray(r, p.dtype))
-                  for p, r in zip(pools["v"], rows["v"])]}
-
-
-def copy_kv_slots(pools: Dict[str, Any], src_slots: Any,
-                  dst_slots: Any) -> Dict[str, Any]:
-    """Copy KV rows ``src_slots`` -> ``dst_slots`` within every layer's
-    pool — the copy-on-write split when a sequence diverges mid-page
-    from a shared prefix page.  Returns the updated pools."""
-    src = jnp.asarray(src_slots, jnp.int32)
-    dst = jnp.asarray(dst_slots, jnp.int32)
-    return {"k": [p.at[dst].set(p[src]) for p in pools["k"]],
-            "v": [p.at[dst].set(p[src]) for p in pools["v"]]}
-
-
 def kv_pool_bytes(cfg: LlamaConfig, num_slots: int) -> int:
     """Resident bytes of one replica's KV pools (both k and v)."""
     itemsize = jnp.dtype(cfg.dtype).itemsize
     return (2 * cfg.n_layers * num_slots * cfg.n_kv_heads
             * cfg.head_dim * itemsize)
+
+
+def build(cfg: LlamaConfig, page_size: int = 0) -> LlamaModel:
+    """The serving module of this family (models/__init__.py)."""
+    return LlamaModel(cfg, page_size=page_size)
+
+
+def config(model: Any) -> LlamaConfig:
+    """`LLMEngine(model=...)`'s value as a config: a config, its fields
+    as a dictionary, or a preset's name."""
+    if isinstance(model, LlamaConfig):
+        return model
+    if isinstance(model, dict):
+        return LlamaConfig(**model)
+    return getattr(LlamaConfig, str(model))()
 
 
 def llama_param_rules() -> Dict[str, Any]:

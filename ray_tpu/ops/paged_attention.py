@@ -31,6 +31,14 @@ T = num_pages * page_size; block_tables [B, W] physical page ids
 (unused entries may point anywhere valid, e.g. the garbage page 0);
 context_lens [B] tokens of live context per lane (0 = inactive lane,
 output is zeros).
+
+A layer that attends over a WINDOW of the last `window` positions
+(`paged_attention(..., window=W, starts=...)`) hands the kernel a table
+of the pages that cover that window only: `starts[b]` is the position
+the table's first page begins at, and a key at position p counts when
+`context_lens[b] - window <= p < context_lens[b]`.  The kernel is then
+named `paged_attention_decode_window`, so a trace tells the two kinds of
+layer apart.
 """
 
 from __future__ import annotations
@@ -44,16 +52,23 @@ import jax.numpy as jnp
 _NEG_INF = -1e30
 
 
-def _paged_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, page_size: int,
-                  scale: float):
+def _paged_kernel(bt_ref, cl_ref, *refs, page_size: int, scale: float,
+                  window: Optional[int] = None):
+    """`refs`: with a window the scalar `starts`, then q, k, v, o and
+    the three scratch buffers."""
     from jax.experimental import pallas as pl
 
     b = pl.program_id(0)
     pi = pl.program_id(1)
     n_p = pl.num_programs(1)
     ctx = cl_ref[b]
-    used = (ctx + page_size - 1) // page_size
+    if window is None:
+        q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
+        start = 0
+    else:
+        st_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
+        start = st_ref[b]
+    used = (ctx - start + page_size - 1) // page_size
 
     @pl.when(pi == 0)
     def _init():
@@ -71,9 +86,11 @@ def _paged_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
             preferred_element_type=jnp.float32) * scale  # [Hkv, G, P]
         # rows of the last used page beyond the context length hold
         # garbage (or another sequence's data on a shared page tail)
-        pos = pi * page_size + jax.lax.broadcasted_iota(
+        pos = start + pi * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, 1, page_size), 2)
         valid = pos < ctx                               # [1, 1, P]
+        if window is not None:
+            valid = valid & (pos >= ctx - window)
         s = jnp.where(valid, s, _NEG_INF)
         m_prev = m_ref[:, :, :1]                        # [Hkv, G, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -96,7 +113,9 @@ def _paged_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
 def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                     block_tables: jax.Array, context_lens: jax.Array,
                     *, page_size: int,
-                    interpret: Optional[bool] = None) -> jax.Array:
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None,
+                    starts: Optional[jax.Array] = None) -> jax.Array:
     """Single-token decode attention over paged KV pools.
 
     q: [B, 1, H, D] post-rope queries (the current token's k/v must
@@ -108,6 +127,10 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
 
     Cost scales with ``W`` (the block-table width), not the pool or max
     context: callers shrink W to the max used pages across the batch.
+
+    With ``window``, ``block_tables[b]`` lists the pages from position
+    ``starts[b]`` (a multiple of the page size) on, and only the last
+    ``window`` positions before ``context_lens[b]`` attend.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -146,26 +169,31 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
         vp = vp[flat]
         bt = jnp.arange(b * w, dtype=jnp.int32).reshape(b, w)
 
-    def _kv_index(bi, pi, bt, cl):
+    scalars = (bt, cl) if window is None \
+        else (bt, cl, starts.astype(jnp.int32))
+
+    def _kv_index(bi, pi, bt, cl, *st):
         # clamp unused grid steps to the last used page: same index as
         # the previous step, so the pipeline skips the redundant copy
-        used = (cl[bi] + page_size - 1) // page_size
+        first = st[0][bi] if st else 0
+        used = (cl[bi] - first + page_size - 1) // page_size
         p = jnp.minimum(pi, jnp.maximum(used - 1, 0))
         return (bt[bi, p], 0, 0, 0)
 
+    def _q_index(bi, pi, *_scalars):
+        return (bi, 0, 0, 0)
+
     kernel = functools.partial(_paged_kernel, page_size=page_size,
-                               scale=scale)
+                               scale=scale, window=window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(scalars),
         grid=(b, w),
         in_specs=[
-            pl.BlockSpec((1, hkv, g, d),
-                         lambda bi, pi, bt, cl: (bi, 0, 0, 0)),
+            pl.BlockSpec((1, hkv, g, d), _q_index),
             pl.BlockSpec((1, page_size, hkv, d), _kv_index),
             pl.BlockSpec((1, page_size, hkv, d), _kv_index),
         ],
-        out_specs=pl.BlockSpec((1, hkv, g, d),
-                               lambda bi, pi, bt, cl: (bi, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, hkv, g, d), _q_index),
         scratch_shapes=[
             pltpu.VMEM((hkv, g, d), jnp.float32),     # acc
             pltpu.VMEM((hkv, g, 128), jnp.float32),   # running max
@@ -177,6 +205,7 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
-        name="paged_attention_decode",
-    )(bt, cl, qr, kp, vp)
+        name="paged_attention_decode" if window is None
+        else "paged_attention_decode_window",
+    )(*scalars, qr, kp, vp)
     return out.reshape(b, 1, h, d)

@@ -53,7 +53,7 @@ every sequence write past it has happened.
 
 Disaggregated prefill (``llm_deployment(prefill_replicas=N)``): a
 sibling replica pool runs ONLY chunked prefill (``prefill_request``),
-exports the finished KV pages via models.llama.gather_kv_slots +
+exports the finished KV pages via models.cache.gather_slots +
 object_transfer.pack_kv_pages, and ships them to decode replicas as a
 sealed store object over the PR-4 bulk transfer plane (seal-time CRC32,
 alternate-holder retry on a corrupt pull).  The decode replica attaches
@@ -61,6 +61,21 @@ the pages by request_id (``submit(kv_pack=...)``) and starts at its
 first decode step — long prompts never occupy decode-lane steps, and
 the deadline admission gate prices the two phases separately
 (prefill-only: chunk cost; attach: one decode step).
+
+The model and its cache: ``model=`` resolves to a family of
+``ray_tpu.models`` (a dictionary's ``model_type``; Llama's without one)
+and the family's config states its cache LAYER BY LAYER
+(models/cache.py).  The engine keeps one page group a kind that occurs:
+every model has ``full`` layers, which keep a page for every position —
+the pages, block table and prefix index described above are that
+group's.  A ``window`` layer attends over its last W positions only, so
+its group (`_WindowPages`) holds, for each sequence, the pages that
+cover the last W positions plus the chunk being written, and gives the
+rest back as the sequence moves on: a second pool, sized for that, and
+a second block table a sequence.  Prefix sharing is REFUSED for a model
+with window layers (a shared prefix would need the window layers' pages
+before its end, which the sequence that wrote them has given back);
+``stats()["prefix_sharing"]`` says so.
 """
 
 from __future__ import annotations
@@ -131,23 +146,21 @@ def _pow4_widths(first: int, cap: int) -> List[int]:
         w *= 4
 
 
-def _jit_forward(model, params, k, v, tokens, slots, ctx, ctx_pos,
-                 ctx_mask, q_pos, last_idx, temperature=0.0, top_k=0,
-                 rng=None, block_tables=None, context_lens=None,
-                 top2=False):
+def _jit_forward(model, params, k, v, tokens, q_pos, last_idx, groups,
+                 temperature=0.0, top_k=0, rng=None, top2=False):
     """One forward over the paged cache -> (next tokens at ``last_idx``,
     updated pools).  Jitted ONCE per (model, shapes, sampling knobs) —
     the flax module AND the sampling knobs are hashable static
     arguments, so every engine instance with the same config shares the
     compiled executable (k/v pools donated: in-place cache updates).
 
-    Context comes in one of two forms, selected by whether
-    ``block_tables`` is an array or None — a pytree-structure change,
-    so each form is its own trace: dense ``ctx``/``ctx_pos``/
-    ``ctx_mask`` gather arrays (chunked prefill, dense decode), or
-    page-granular ``block_tables`` + ``context_lens`` routing decode
-    through the Pallas paged-attention kernel (pass ctx/ctx_pos/
-    ctx_mask as None then).
+    ``groups`` maps each cache kind of the model (models/cache.py) to
+    that kind's arrays: the write ``slots`` and the context in one of
+    two forms — a pytree-structure change, so each form is its own
+    trace: dense ``ctx``/``ctx_pos``/``ctx_mask`` gather arrays (chunked
+    prefill, dense decode), or page-granular ``block_tables`` +
+    ``context_lens`` (a window kind also ``starts``) routing decode
+    through the Pallas paged-attention kernel.
 
     Sampling is a pair of jit-STATIC knobs (ISSUE 13 satellite / PR-11
     declared headroom (d)): ``temperature == 0`` compiles the exact
@@ -163,12 +176,13 @@ def _jit_forward(model, params, k, v, tokens, slots, ctx, ctx_pos,
         import jax.numpy as jnp
 
         rng = jnp.zeros((2,), dtype="uint32")  # unused when greedy
-    return fn(model, params, k, v, tokens, slots, ctx, ctx_pos, ctx_mask,
-              q_pos, last_idx, rng, block_tables, context_lens)
+    return fn(model, params, k, v, tokens, q_pos, last_idx, rng, groups)
 
 
 def _jitted_forward(temperature=0.0, top_k=0, top2=False):
     """The process-wide jitted stepper for one set of static knobs.
+    A model that counts on the device (`model.counters`) gets its
+    counter vector appended to the tokens, one array and one transfer.
     With ``top2`` it returns a third value: each lane's two largest
     logits and their ids ([B, 2] float32, [B, 2] int32) — what a
     comparison of two engines needs to tell a rounding flip from a
@@ -180,18 +194,10 @@ def _jitted_forward(temperature=0.0, top_k=0, top2=False):
     if fn is None:
         import jax.numpy as jnp
 
-        def _fwd(model, params, k, v, tokens, slots, ctx, ctx_pos,
-                 ctx_mask, q_pos, last_idx, rng, block_tables,
-                 context_lens, temperature=key[0], top_k=key[1],
-                 top2=key[2]):
-            cache = {"k": k, "v": v, "slots": slots, "q_pos": q_pos}
-            if block_tables is not None:
-                cache["block_tables"] = block_tables
-                cache["context_lens"] = context_lens
-            else:
-                cache.update(ctx=ctx, ctx_pos=ctx_pos,
-                             ctx_mask=ctx_mask)
-            logits, pools = model.apply(
+        def _fwd(model, params, k, v, tokens, q_pos, last_idx, rng,
+                 groups, temperature=key[0], top_k=key[1], top2=key[2]):
+            cache = {"k": k, "v": v, "q_pos": q_pos, "groups": groups}
+            logits, pools, *counted = model.apply(
                 {"params": params}, tokens, cache)
             picked = jnp.take_along_axis(
                 logits, last_idx[:, None, None], axis=1)[:, 0]
@@ -203,6 +209,9 @@ def _jitted_forward(temperature=0.0, top_k=0, top2=False):
                     kth = jnp.sort(scaled, axis=-1)[:, -top_k][:, None]
                     scaled = jnp.where(scaled < kth, -jnp.inf, scaled)
                 tok = jax.random.categorical(rng, scaled, axis=-1)
+            if counted:
+                tok = jnp.concatenate(
+                    [tok.astype(jnp.int32), counted[0].astype(jnp.int32)])
             if not top2:
                 return tok, pools
             f = picked.astype(jnp.float32)
@@ -279,7 +288,7 @@ class _Seq:
                  "cancelled", "slot_cache", "cond", "deadline", "kv_import",
                  "prefill_export", "export_payload", "trace_ctx",
                  "prefix_tokens", "submit_step", "admit_step",
-                 "first_token_step")
+                 "first_token_step", "windows")
 
     def __init__(self, request_id: str, prompt: List[int], max_new: int,
                  eos: Optional[int], preknown: Optional[List[int]] = None):
@@ -297,7 +306,9 @@ class _Seq:
         self.prefill_tokens = self.prompt + self.generated
         self.max_new = int(max_new)
         self.eos = eos
-        self.block_table: List[int] = []
+        self.block_table: List[int] = []   # the "full" group's pages
+        # cache kind -> _SeqWindow, for each window kind of the model
+        self.windows: Dict[str, "_SeqWindow"] = {}
         self.pos = 0                  # tokens whose KV is in the cache
         self.state = _QUEUED
         self.done = False
@@ -330,6 +341,92 @@ class _Seq:
     @property
     def total_len(self) -> int:
         return len(self.prompt) + self.max_new
+
+
+class _SeqWindow:
+    """One sequence's pages in one window group: `pages[p]` is the
+    physical page of logical page p (0 where it holds none: given back,
+    or not reached yet), `slots[i]` the slot of position i under the
+    same proviso; live are the logical pages [first, next)."""
+
+    __slots__ = ("pages", "slots", "first", "next")
+
+    def __init__(self, np, n_pages: int, page_size: int):
+        self.pages = np.zeros((n_pages,), np.int32)
+        self.slots = np.zeros((n_pages * page_size,), np.int32)
+        self.first = self.next = 0
+
+
+class _WindowPages:
+    """The page group of a `window` cache kind: layers that attend over
+    their last `window` positions.  A sequence holds the pages covering
+    the positions its next pass can read or write — from the oldest a
+    query of the pass still sees to the last it writes — and `advance`
+    gives the older ones back.  The pool holds `per_seq` pages for each
+    of `max_batch` sequences (`per_seq` = window + one prefill chunk,
+    in pages, + 2: a window and a chunk each start mid-page), so an
+    active sequence always finds its next page and admission never
+    waits on this group."""
+
+    def __init__(self, np, kind: str, window: int, page_size: int,
+                 chunk: int, max_batch: int, pages_per_seq: int):
+        self._np = np
+        self.kind, self.window, self.page_size = kind, window, page_size
+        self.per_seq = min(pages_per_seq,
+                           -(-(window + chunk) // page_size) + 2)
+        self.num_pages = 1 + max_batch * self.per_seq   # page 0: garbage
+        self.free: List[int] = list(range(1, self.num_pages))
+        self.allocated_total = 0
+        self.released_total = 0
+        # the widest context a pass reads here: a chunk's last query
+        # sees `window` positions back from itself, its first as many
+        # back from ITSELF, so window + chunk - 1; in whole pages
+        self.ctx_width = -(-(window + chunk) // page_size) * page_size
+        # pages a decode step's table lists: the window may start
+        # mid-page
+        self.table_width = -(-window // page_size) + 1
+
+    def new_seq(self, pages: int) -> _SeqWindow:
+        return _SeqWindow(self._np, pages, self.page_size)
+
+    def advance(self, st: _SeqWindow, lo: int, hi: int) -> None:
+        """Engine lock held.  The sequence's next pass has queries at
+        positions [lo, hi) and writes their rows: give back the pages
+        no query of it sees (all positions <= lo - window), take pages
+        up to the one `hi - 1` lies on."""
+        ps = self.page_size
+        dead = max(0, lo - self.window + 1) // ps
+        for p in range(st.first, min(dead, st.next)):
+            self.free.append(int(st.pages[p]))
+            st.pages[p] = 0
+            self.released_total += 1
+        st.first = max(st.first, dead)
+        st.next = max(st.next, st.first)
+        last = (hi - 1) // ps
+        while st.next <= last:
+            page = self.free.pop()
+            self.allocated_total += 1
+            st.pages[st.next] = page
+            st.slots[st.next * ps:(st.next + 1) * ps] = \
+                page * ps + self._np.arange(ps, dtype=self._np.int32)
+            st.next += 1
+
+    def release(self, st: _SeqWindow) -> None:
+        """Engine lock held: the sequence ended, all its pages back."""
+        for p in range(st.first, st.next):
+            self.free.append(int(st.pages[p]))
+        st.pages[:] = 0
+        st.first = st.next = 0
+
+    def table(self, st: _SeqWindow, n: int, width: int):
+        """(start position, the pages from there) a decode step with
+        `n` tokens of context lists, at most `width` of them."""
+        first = max(0, n - self.window) // self.page_size
+        last = (n - 1) // self.page_size
+        return first * self.page_size, st.pages[first:last + 1][:width]
+
+    def used(self) -> int:
+        return self.num_pages - 1 - len(self.free)
 
 
 class LLMEngine:
@@ -371,8 +468,7 @@ class LLMEngine:
         import numpy as np
 
         from ray_tpu._private.config import config
-        from ray_tpu.models.llama import LlamaConfig, LlamaModel, \
-            make_kv_pools
+        from ray_tpu.models import cache as kv_cache, resolve
         from ray_tpu.ops import count_compile_cache_events, kernel_mode
 
         self._np = np
@@ -389,13 +485,9 @@ class LLMEngine:
         self.logit_trace = bool(logit_trace)
         self._logit_trace: Dict[str, List[list]] = {}
         self._last_top2 = None
-        if cfg is None:
-            if isinstance(model, LlamaConfig):
-                cfg = model
-            elif isinstance(model, dict):
-                cfg = LlamaConfig(**model)
-            else:
-                cfg = getattr(LlamaConfig, str(model))()
+        # the model: its family's module (config, build) and its config,
+        # which states the cache layer by layer (ray_tpu/models)
+        self.family, cfg = resolve(cfg if cfg is not None else model)
         if dtype is not None:
             import dataclasses
 
@@ -432,8 +524,22 @@ class LLMEngine:
             raise ValueError(
                 f"llm_attention_impl must be auto|paged|dense, got {impl!r}")
         self.attention_impl = impl
-        self._model = LlamaModel(
+        self._model = self.family.build(
             cfg, page_size=self.page_size if impl == "paged" else 0)
+        # the cache, by what the model's specification says: the kind of
+        # each layer, and a page group for each window kind (the "full"
+        # group is this engine's own pages, block tables and index)
+        spec = cfg.cache_spec()
+        self._kinds = [layer.kind for layer in spec]
+        windows = kv_cache.kinds_of(spec)
+        if windows.pop("full", None) is None:
+            raise ValueError("a model with no full-attention layer: the "
+                             "engine's page budget is the full kind's")
+        self._windows = {
+            kind: _WindowPages(np, kind, window, self.page_size,
+                               self.prefill_chunk, self.max_batch,
+                               self.pages_per_seq)
+            for kind, window in windows.items()}
         # seconds of this replica's start-up, by part: until the weights
         # were on the device, the call that allocates the KV pools, and
         # `warm_up`'s compiles.  The device fills the weights while the
@@ -446,7 +552,10 @@ class LLMEngine:
                 jax.random.PRNGKey(int(seed)), dummy)["params"]
         self._params = params
         t1 = time.perf_counter()
-        self._pools = make_kv_pools(cfg, self.num_pages * self.page_size)
+        self._pools = kv_cache.make_pools(
+            spec, {"full": self.num_pages * self.page_size,
+                   **{kind: g.num_pages * self.page_size
+                      for kind, g in self._windows.items()}}, cfg.dtype)
         self.startup_secs = {"params": 0.0,
                              "pools": time.perf_counter() - t1, "warm": 0.0}
 
@@ -488,6 +597,15 @@ class LLMEngine:
         self.prefix_sharing = bool(
             prefix_sharing if prefix_sharing is not None
             else config.llm_prefix_sharing)
+        # a prefix is reusable only where the window layers still hold
+        # the positions before its end, and they have given them back:
+        # refused, not silently wrong (stats()["prefix_sharing"])
+        self._sharing_refused = ""
+        if self.prefix_sharing and self._windows:
+            self.prefix_sharing = False
+            self._sharing_refused = (
+                "the model has window layers, whose pages before a "
+                "prefix's end are given back")
         self._page_refs = [0] * self.num_pages
         self._prefix_index: Dict[bytes, int] = {}
         self._children: Dict[bytes, set] = {}
@@ -540,6 +658,11 @@ class LLMEngine:
         self._prefill_widths = self._prefill_ctx_buckets()
         self._prefill_passes_by_width = dict.fromkeys(
             self._prefill_widths, 0)
+        # what the model counts on the device (`model.counters`: names
+        # of the vector it returns beside its logits), summed by pass
+        self._model_counters = {
+            name: {"decode": 0, "prefill": 0}
+            for name in getattr(self._model, "counters", ())}
         # EWMA of one engine step's wall time — the deadline-admission
         # estimate of "prefill + one decode step" cost (0 until the
         # first measured step; cold engines only refuse already-expired
@@ -732,25 +855,82 @@ class LLMEngine:
     # ------------------------------------------------------------- stepping
 
     def _forward(self, tokens, slot_arr, ctx, ctx_pos, ctx_mask, q_pos,
-                 last_idx, block_tables=None, context_lens=None):
+                 last_idx, block_tables=None, context_lens=None,
+                 windows=None):
         """One jitted forward with this engine's static sampling knobs;
         the per-call rng split only happens on the sampling path, so
-        greedy engines run the exact pre-sampling program."""
+        greedy engines run the exact pre-sampling program.  The
+        positional arrays are the full kind's; `windows` has the other
+        kinds' (`_window_arrays`).  Returns (the tokens, with the model's
+        counter vector behind them if it counts, and the pools)."""
         rng = None
         if self._sample_rng is not None:
             import jax
 
             self._sample_rng, rng = jax.random.split(self._sample_rng)
+        full = {"slots": slot_arr}
+        if block_tables is not None:
+            full.update(block_tables=block_tables,
+                        context_lens=context_lens)
+        else:
+            full.update(ctx=ctx, ctx_pos=ctx_pos, ctx_mask=ctx_mask)
         out = self._step_fn(
             self._model, self._params, self._pools["k"], self._pools["v"],
-            tokens, slot_arr, ctx, ctx_pos, ctx_mask, q_pos, last_idx,
+            tokens, q_pos, last_idx, {"full": full, **(windows or {})},
             temperature=self.temperature, top_k=self.top_k, rng=rng,
-            block_tables=block_tables, context_lens=context_lens,
             top2=self.logit_trace)
         if self.logit_trace:
             tok, pools, (vals, ids) = out
             self._last_top2 = (self._np.asarray(vals), self._np.asarray(ids))
             return tok, pools
+        return out
+
+    def _split_counters(self, next_tok, lanes: int, phase: str):
+        """The host copy of a pass's output: its `lanes` tokens, and the
+        model's counter vector behind them added to `phase`'s sums."""
+        for name, value in zip(self._model_counters, next_tok[lanes:]):
+            self._model_counters[name][phase] += int(value)
+        return next_tok[:lanes]
+
+    def _window_arrays(self, rows, lanes: int, cols: int, width: int,
+                       paged: bool = False):
+        """The window kinds' arrays of one pass of `lanes` x `cols`
+        queries.  `rows` = [(lane, the sequence's windows, lo, hi)]: the
+        lane's queries are at [lo, hi); a lane without a row is garbage
+        (slot 0, nothing to see).  Dense form: the context is the last
+        `min(width, group.ctx_width)` positions before `hi`; paged form
+        (decode, hi = lo + 1): the window's pages, `min(width,
+        group.table_width)` of them, from position `starts` on."""
+        np = self._np
+        out = {}
+        for kind, group in self._windows.items():
+            slots = np.zeros((lanes, cols), np.int32)
+            if paged:
+                w = min(width, group.table_width)
+                tables = np.zeros((lanes, w), np.int32)
+                starts = np.zeros((lanes,), np.int32)
+                lens = np.zeros((lanes,), np.int32)
+                for lane, wins, lo, hi in rows:
+                    slots[lane, 0] = wins[kind].slots[lo]
+                    starts[lane], pages = group.table(wins[kind], hi, w)
+                    tables[lane, :len(pages)] = pages
+                    lens[lane] = hi
+                out[kind] = {"slots": slots, "block_tables": tables,
+                             "context_lens": lens, "starts": starts}
+                continue
+            w = min(width, group.ctx_width)
+            ctx = np.zeros((lanes, w), np.int32)
+            ctx_pos = np.zeros((lanes, w), np.int32)
+            ctx_mask = np.zeros((lanes, w), bool)
+            for lane, wins, lo, hi in rows:
+                st = wins[kind]
+                slots[lane, :hi - lo] = st.slots[lo:hi]
+                start = max(0, hi - w)
+                ctx[lane, :hi - start] = st.slots[start:hi]
+                ctx_pos[lane, :hi - start] = self._arange[start:hi]
+                ctx_mask[lane, :hi - start] = True
+            out[kind] = {"slots": slots, "ctx": ctx, "ctx_pos": ctx_pos,
+                         "ctx_mask": ctx_mask}
         return out
 
     def _trace_top2(self, seq: _Seq, lane: int) -> None:
@@ -797,7 +977,8 @@ class LLMEngine:
             ctx = np.zeros((lanes, width), np.int32)
             _tok, self._pools = self._forward(
                 zeros, zeros, ctx, ctx, np.zeros((lanes, width), bool),
-                zeros, np.zeros((lanes,), np.int32))
+                zeros, np.zeros((lanes,), np.int32),
+                windows=self._window_arrays([], lanes, c, width))
 
     def _warm_paged_buckets(self) -> None:
         """Compile every paged block-table width bucket up front, at
@@ -822,7 +1003,9 @@ class LLMEngine:
         return ((zeros1, zeros1, None, None, None, zeros1,
                  np.zeros((b,), np.int32)),
                 {"block_tables": np.zeros((b, width), np.int32),
-                 "context_lens": np.zeros((b,), np.int32)})
+                 "context_lens": np.zeros((b,), np.int32),
+                 "windows": self._window_arrays([], b, 1, width,
+                                                paged=True)})
 
     def device_report(self) -> Dict[str, Any]:
         """`ops.device_report()` plus what this engine put on the device
@@ -840,10 +1023,18 @@ class LLMEngine:
             return sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(tree))
 
         rep = device_report()
+        share = getattr(self.cfg, "share", None)
         rep.update(attention_impl=self.attention_impl,
-                   model={f.name: getattr(self.cfg, f.name)
-                          for f in dataclasses.fields(self.cfg)
-                          if f.name != "dtype"},
+                   # the config's fields, and: the family, what of each
+                   # layer this chip holds (a config that is a share
+                   # says), the cache specification by layer
+                   model={**{f.name: getattr(self.cfg, f.name)
+                             for f in dataclasses.fields(self.cfg)
+                             if "dtype" not in f.name},
+                          "family": self.family.__name__.rsplit(".", 1)[-1],
+                          "share": share() if share else None,
+                          "cache_spec": [list(layer) for layer
+                                         in self.cfg.cache_spec()]},
                    dtype=str(jax.numpy.dtype(self.cfg.dtype)),
                    page_size=self.page_size,
                    param_bytes=nbytes(self._params),
@@ -860,12 +1051,16 @@ class LLMEngine:
         if self.attention_impl == "paged":
             args, kwargs = self._garbage_decode_args(
                 self._paged_width_buckets()[0])
+            tokens, slots, _c, _p, _m, q_pos, last_idx = args
             text = _jitted_forward(self.temperature, self.top_k,
                                    self.logit_trace).lower(
                 self._model, self._params, self._pools["k"],
-                self._pools["v"], *args,
+                self._pools["v"], tokens, q_pos, last_idx,
                 jax.numpy.zeros((2,), dtype="uint32"),  # rng, unused
-                kwargs["block_tables"], kwargs["context_lens"]).as_text()
+                {"full": {"slots": slots,
+                          "block_tables": kwargs["block_tables"],
+                          "context_lens": kwargs["context_lens"]},
+                 **kwargs["windows"]}).as_text()
             rep["decode_has_tpu_custom_call"] = "tpu_custom_call" in text
         return rep
 
@@ -917,6 +1112,8 @@ class LLMEngine:
             seq.cond.notify_all()
         self._release_pages(seq.block_table)
         seq.block_table = []
+        for kind, st in seq.windows.items():
+            self._windows[kind].release(st)
         seq.kv_import = None
         if seq in self._active:
             self._active.remove(seq)
@@ -1076,13 +1273,14 @@ class LLMEngine:
         """Lock held, loop-synchronized (only ever called from within a
         step, never concurrent with a forward): copy the first
         ``n_tok`` KV rows of ``src_page`` into ``dst_page``."""
-        from ray_tpu.models.llama import copy_kv_slots
+        from ray_tpu.models.cache import copy_slots
 
         np = self._np
         ps = self.page_size
         src = np.arange(n_tok, dtype=np.int32) + src_page * ps
         dst = np.arange(n_tok, dtype=np.int32) + dst_page * ps
-        self._pools = copy_kv_slots(self._pools, src, dst)
+        self._pools = copy_slots(self._pools, self._kinds, "full", src,
+                                 dst)
 
     def _admit_locked(self) -> None:
         while self._queued and len(self._active) < self.max_batch:
@@ -1106,6 +1304,8 @@ class LLMEngine:
                                         self.page_size)
                               + np.tile(np.arange(self.page_size),
                                         len(bt))).astype(np.int32)
+            seq.windows = {kind: g.new_seq(pages)
+                           for kind, g in self._windows.items()}
             shared_tok = len(shared) * self.page_size
             if cow is not None:
                 src_page, n_tok = cow
@@ -1169,6 +1369,20 @@ class LLMEngine:
     # forward (whose donated pool buffers would be invalidated under a
     # concurrent reader/writer).
 
+    def _kv_row_slots(self, seq: _Seq, n: int, take: bool = False):
+        """Lock held.  The slots, by cache kind, of the rows a sequence
+        with `n` tokens in the cache ships or receives: every position
+        of the full kind, of a window kind the positions its next query
+        (at `n`) still sees.  `take`: the receiving side first takes the
+        window pages those rows go to."""
+        slots = {"full": seq.slot_cache[:n]}
+        for kind, group in self._windows.items():
+            if take:
+                group.advance(seq.windows[kind], n, n)
+            start = max(0, n - group.window + 1)
+            slots[kind] = seq.windows[kind].slots[start:n]
+        return slots
+
     def _attach_imports_locked(self) -> bool:
         """Scatter shipped KV rows for freshly-admitted sequences into
         this engine's pools; the sequence enters decode at the shipped
@@ -1180,11 +1394,11 @@ class LLMEngine:
             pack, seq.kv_import = seq.kv_import, None
             n = int(pack["meta"]["n"])
             first_tok = int(pack["meta"]["first_token"])
-            from ray_tpu.models.llama import scatter_kv_slots
+            from ray_tpu.models.cache import scatter_slots
 
-            self._pools = scatter_kv_slots(self._pools,
-                                           seq.slot_cache[:n],
-                                           pack["rows"])
+            self._pools = scatter_slots(
+                self._pools, self._kinds,
+                self._kv_row_slots(seq, n, take=True), pack["rows"])
             seq.pos = n
             n_pages = -(-n // self.page_size)
             self._kv_pages_shipped_in += n_pages
@@ -1204,7 +1418,7 @@ class LLMEngine:
         rows to host memory, stash them as the export payload, and
         finish the sequence (pages recycle NOW — the payload is a host
         copy).  ``prefill_request`` wakes on the finish notify."""
-        from ray_tpu.models.llama import gather_kv_slots
+        from ray_tpu.models.cache import gather_slots
 
         self._note_first_token(seq)
         seq.generated.append(int(first_token))
@@ -1216,7 +1430,8 @@ class LLMEngine:
                      "first_token": int(first_token),
                      "n": n, "pages": n_pages,
                      "page_size": self.page_size},
-            "rows": gather_kv_slots(self._pools, seq.slot_cache[:n]),
+            "rows": gather_slots(self._pools, self._kinds,
+                                 self._kv_row_slots(seq, n)),
         }
         self._kv_pages_shipped_out += n_pages
         m = self.metrics()
@@ -1290,6 +1505,8 @@ class LLMEngine:
             for seq in prefills:
                 lo = seq.pos
                 hi = min(lo + self.prefill_chunk, len(seq.prefill_tokens))
+                for kind, st in seq.windows.items():
+                    self._windows[kind].advance(st, lo, hi)
                 prefill_args.append(
                     (seq, lo, hi, seq.prefill_tokens[lo:hi],
                      seq.slot_cache[lo:hi], seq.slot_cache[:hi]))
@@ -1297,6 +1514,8 @@ class LLMEngine:
             for seq in decode[:self.max_batch]:
                 last = (seq.generated[-1] if seq.generated
                         else seq.prefill_tokens[-1])
+                for kind, st in seq.windows.items():
+                    self._windows[kind].advance(st, seq.pos, seq.pos + 1)
                 # snapshot the block table under the lock: a concurrent
                 # CoW split may rewrite entries after we release it
                 decode_args.append(
@@ -1341,11 +1560,17 @@ class LLMEngine:
                 ctx_mask[lane, :hi] = True
                 q_pos[lane, :hi - lo] = self._arange[lo:hi]
                 last_idx[lane] = hi - lo - 1
+            windows = self._window_arrays(
+                [(lane, seq.windows, lo, hi) for lane, (seq, lo, hi, *_r)
+                 in enumerate(prefill_args)], lanes, c, width) \
+                if self._windows else None
             phase("prefill_dispatch", width=width)
             next_tok, self._pools = self._forward(
-                tokens, slot_arr, ctx, ctx_pos, ctx_mask, q_pos, last_idx)
+                tokens, slot_arr, ctx, ctx_pos, ctx_mask, q_pos, last_idx,
+                windows=windows)
             phase("prefill_sync")
-            next_tok = np.asarray(next_tok)
+            next_tok = self._split_counters(np.asarray(next_tok), lanes,
+                                            "prefill")
             self._prefill_secs += phase("prefill_emit") - t_pre
             self._prefill_steps += 1
             chunk_tokens = sum(hi - lo for _s, lo, hi, *_r in prefill_args)
@@ -1409,10 +1634,15 @@ class LLMEngine:
                     block_tables[lane, :used] = table[:used]
                     context_lens[lane] = n
                     q_pos[lane, 0] = seq.pos
+                windows = self._window_arrays(
+                    [(lane, seq.windows, n - 1, n) for lane, (seq, *_a, n)
+                     in enumerate(decode_args)], b, 1, width, paged=True) \
+                    if self._windows else None
                 phase("decode_dispatch")
                 next_tok, self._pools = self._forward(
                     tokens, slot_arr, None, None, None, q_pos, last_idx,
-                    block_tables=block_tables, context_lens=context_lens)
+                    block_tables=block_tables, context_lens=context_lens,
+                    windows=windows)
             else:
                 ctx = np.zeros((b, self.ctx_len), np.int32)
                 ctx_pos = np.zeros((b, self.ctx_len), np.int32)
@@ -1425,12 +1655,18 @@ class LLMEngine:
                     ctx_pos[lane, :n] = self._arange[:n]
                     ctx_mask[lane, :n] = True
                     q_pos[lane, 0] = seq.pos
+                windows = self._window_arrays(
+                    [(lane, seq.windows, n - 1, n) for lane, (seq, *_a, n)
+                     in enumerate(decode_args)], b, 1, self.ctx_len) \
+                    if self._windows else None
                 phase("decode_dispatch")
                 next_tok, self._pools = self._forward(
                     tokens, slot_arr, ctx, ctx_pos, ctx_mask, q_pos,
-                    last_idx)
+                    last_idx, windows=windows)
             phase("decode_sync")
-            next_tok = np.asarray(next_tok)  # device sync: real step cost
+            # device sync: real step cost
+            next_tok = self._split_counters(np.asarray(next_tok), b,
+                                            "decode")
             decode_dt = phase("decode_emit") - t_dec
             self._decode_steps += 1
             self._decode_secs += decode_dt
@@ -1592,6 +1828,8 @@ class LLMEngine:
                     "step_secs": self._clock.step_secs,
                     "phase_secs": dict(self._clock.phase_secs),
                     **self._totals,
+                    **{name: dict(by_pass) for name, by_pass
+                       in self._model_counters.items()},
                     "prefill_passes_by_width":
                         dict(self._prefill_passes_by_width),
                     **compile_counts(),
@@ -1603,15 +1841,24 @@ class LLMEngine:
                     "live_seqs": len(self._by_rid),
                     "free_pages": len(self._free_pages),
                     "used_pages": self.num_pages - 1 - len(self._free_pages),
+                    "kv_pages_in_use": {
+                        "full": self.num_pages - 1 - len(self._free_pages),
+                        **{kind: g.used()
+                           for kind, g in self._windows.items()}},
+                    "kv_window_pages_released_total": sum(
+                        g.released_total for g in self._windows.values()),
                     "shared_pages": self._shared_page_count(),
+                    "prefix_sharing": self.prefix_sharing,
+                    "prefix_sharing_refused": self._sharing_refused,
                     "prefix_hits": self._prefix_hits,
                     "prefix_tokens_shared": self._prefix_tokens_shared,
                     "cow_splits": self._cow_splits,
                     "pages_allocated_total": self._pages_alloc_total,
-                    "kv_page_bytes": (
-                        sum(int(p.nbytes) for p in self._pools["k"])
-                        + sum(int(p.nbytes) for p in self._pools["v"]))
-                        // self.num_pages,
+                    # of one page of the full kind, over its layers
+                    "kv_page_bytes": sum(
+                        int(p.nbytes) for name in ("k", "v")
+                        for p, kind in zip(self._pools[name], self._kinds)
+                        if kind == "full") // self.num_pages,
                     "kv_pages_shipped_out": self._kv_pages_shipped_out,
                     "kv_pages_shipped_in": self._kv_pages_shipped_in,
                     "loop_running": self._loop_running,
@@ -1813,6 +2060,11 @@ def run_llm_loop(worker, instance, *_args) -> Dict[str, Any]:
         raise TypeError(
             "__rt_dag_llm_loop__ requires an llm_deployment replica "
             f"(got {type(target).__name__})")
+    # `exit_worker` must be able to end a loop that holds the worker's
+    # main exec thread (CoreWorker.rpc_exit_worker).  Never cleared: the
+    # engine lives as long as its worker, and a second install returns
+    # at once while the first still runs
+    worker._pinned_stop = engine.stop
     return engine.run_loop()
 
 

@@ -1,0 +1,206 @@
+"""A Laguna-family model served by LLMEngine: chunked prefill and decode
+through a cache of two kinds (full layers and window layers) against the
+plain reference's full forward pass; the window group's pages; prefix
+sharing refused; save and restore; and a Llama engine left as it was.
+
+One small config (float32 activations over the stored bfloat16
+matrices, so the engine's tokens ARE the reference's argmax and a wrong
+page, mask or slot shows as a wrong token), page 8, chunk 16, window 32.
+"""
+
+import dataclasses
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks import reference_laguna as ref
+from ray_tpu.models.laguna import LagunaConfig
+from ray_tpu.serve.llm import LLMEngine, _jitted_forward
+
+CFG = dataclasses.replace(LagunaConfig.tiny(), dtype=jnp.float32,
+                          max_position_embeddings=384)
+SIZES = dict(layer_types=list(CFG.layer_types),
+             mlp_layer_types=list(CFG.mlp_layer_types),
+             sliding_window=CFG.sliding_window,
+             rope_parameters={k: dict(v)
+                              for k, v in dict(CFG.rope_parameters).items()},
+             num_experts_per_tok=CFG.num_experts_per_tok,
+             norm_topk_prob=True, moe_routed_scaling_factor=2.5,
+             rms_norm_eps=CFG.rms_norm_eps, experts_held=[0, 4])
+PAGE, CHUNK, WINDOW = 8, 16, 32
+
+
+def _engine(**kw):
+    kw.setdefault("attention_impl", "paged")
+    return LLMEngine(CFG, page_size=PAGE, max_batch=4, prefill_chunk=CHUNK,
+                     seed=3, **kw)
+
+
+def _prompt(n, salt=0):
+    rs = np.random.RandomState(1000 + 7 * n + salt)
+    return [int(t) for t in rs.randint(1, 256, n)]
+
+
+def _drain(eng, rounds=600):
+    for _ in range(rounds):
+        if not eng.step():
+            return
+    raise AssertionError("the engine did not go idle")
+
+
+@pytest.mark.parametrize("impl", ["paged", "dense"])
+def test_prefill_in_chunks_then_decode_is_the_references_forward(impl):
+    """Prompts shorter than (5, 20), equal to (32) and several times the
+    window (100, 150), with chunks of 16 that straddle it (40 = 2 chunks
+    and a half), four lanes at once; 12 tokens each through the decode
+    kernel (windowed for three layers of five).  Every token is the
+    reference's argmax given the engine's own earlier tokens."""
+    eng = _engine(attention_impl=impl)
+    prompts = [_prompt(n) for n in (5, 20, 32, 40, 100, 150)]
+    outs = eng.generate_batch([{"tokens": p, "max_new_tokens": 12}
+                               for p in prompts])
+    refs = ref.teacher_forced(eng._params, prompts, outs, SIZES)
+    for p, out, r in zip(prompts, outs, refs):
+        assert out == r["top_id"], f"prompt of {len(p)}"
+    st = eng.stats()
+    assert st["kv_pages_in_use"] == {"full": 0, "window": 0}
+    assert st["used_pages"] == 0
+    # the experts' work was counted, by pass, and followed the routing
+    calls, slots = st["moe_expert_calls_total"], st["moe_expert_slots_total"]
+    for which in ("decode", "prefill"):
+        assert 0 < calls[which] < slots[which]
+        assert st["moe_assignments_total"][which] >= calls[which]
+        assert slots[which] == 4 * st["moe_layer_passes_total"][which]
+    assert st["moe_layer_passes_total"]["decode"] == 4 * st["decode_steps"]
+
+
+def test_window_pages_come_back_while_a_sequence_runs():
+    """A context of 40 pages (320 tokens) holds the window's pages, not
+    40: at most `per_seq` = (32 + 16) / 8 + 2 = 8 of the window kind,
+    while the full kind holds all 40 from admission; what the sequence
+    moved past is given back as it goes, and its end leaves both groups
+    as it found them."""
+    eng = _engine()
+    group = eng._windows["window"]
+    assert group.per_seq == 8 and group.num_pages == 1 + 4 * 8
+    free_full, free_win = len(eng._free_pages), len(group.free)
+    seq = eng.submit({"tokens": _prompt(300), "max_new_tokens": 20})
+    held = []
+    while not seq.done:
+        eng.step()
+        in_use = eng.stats()["kv_pages_in_use"]
+        held.append(in_use["window"])
+        if not seq.done:
+            assert in_use["full"] == 40
+    assert max(held) <= group.per_seq
+    assert max(held) >= WINDOW // PAGE      # it did hold the window
+    st = eng.stats()
+    assert st["kv_window_pages_released_total"] >= 40 - group.per_seq
+    assert st["kv_pages_in_use"] == {"full": 0, "window": 0}
+    assert len(eng._free_pages) == free_full
+    assert sorted(group.free) == list(range(1, group.num_pages))
+    assert len(group.free) == free_win
+    # cancelled mid-flight: the same
+    seq = eng.submit({"tokens": _prompt(200), "max_new_tokens": 20})
+    for _ in range(4):
+        eng.step()
+    assert eng.stats()["kv_pages_in_use"]["window"] > 0
+    assert eng.cancel(seq.request_id)
+    assert eng.stats()["kv_pages_in_use"] == {"full": 0, "window": 0}
+
+
+def test_prefix_sharing_is_refused_for_window_layers_and_says_so():
+    eng = _engine(prefix_sharing=True)
+    st = eng.stats()
+    assert st["prefix_sharing"] is False
+    assert "window layers" in st["prefix_sharing_refused"]
+    shared = _prompt(64)
+    a, b = eng.generate_batch(
+        [{"tokens": shared + [7], "max_new_tokens": 4},
+         {"tokens": shared + [9], "max_new_tokens": 4}])
+    st = eng.stats()
+    assert st["prefix_hits"] == 0 and st["shared_pages"] == 0
+    refs = ref.teacher_forced(eng._params, [shared + [7], shared + [9]],
+                              [a, b], SIZES)
+    assert [a, b] == [r["top_id"] for r in refs]
+    # a Llama engine shares as before and reports no refusal
+    llama = LLMEngine(model="tiny", page_size=PAGE, max_batch=4,
+                      prefix_sharing=True)
+    st = llama.stats()
+    assert st["prefix_sharing"] is True and st["prefix_sharing_refused"] == ""
+    assert st["kv_pages_in_use"] == {"full": 0}
+    assert st["kv_window_pages_released_total"] == 0
+
+
+def test_save_and_restore_round_trip_through_the_two_kind_cache():
+    """A sequence saved mid-decode (past the window) and restored into a
+    fresh engine re-prefills prompt + known tokens through both page
+    groups and goes on to the same tokens."""
+    whole = _engine().generate_batch(
+        [{"tokens": _prompt(70), "max_new_tokens": 16}])[0]
+    eng = _engine()
+    seq = eng.submit({"tokens": _prompt(70), "max_new_tokens": 16,
+                      "request_id": "r1"})
+    while len(seq.generated) < 6:
+        eng.step()
+    state = eng.save_state()
+    assert state["seqs"][0]["generated"] == whole[:len(seq.generated)]
+    fresh = _engine()
+    fresh.restore_state(state)
+    again = fresh.submit({"tokens": _prompt(70), "max_new_tokens": 16,
+                          "request_id": "r1"})     # re-attaches
+    _drain(fresh)
+    assert again.generated == whole
+    assert fresh.stats()["kv_pages_in_use"] == {"full": 0, "window": 0}
+
+
+def test_shipped_kv_rows_carry_the_window_layers_live_rows():
+    """Disaggregated prefill through a cache of two kinds: the export
+    holds every row of a full layer and the rows a window layer's next
+    query still sees; the import takes window pages for them and decodes
+    on to the tokens a local prefill gives."""
+    prompt = _prompt(90)
+    local = _engine().generate_batch(
+        [{"tokens": prompt, "max_new_tokens": 8}])[0]
+    payload = _engine().prefill_request({"tokens": prompt,
+                                         "max_new_tokens": 8,
+                                         "request_id": "ship"})
+    assert [r.shape[0] for r in payload["rows"]["k"]] == \
+        [90, WINDOW - 1, WINDOW - 1, WINDOW - 1, 90]
+    dec = _engine()
+    seq = dec.submit({"tokens": prompt, "max_new_tokens": 8,
+                      "request_id": "ship"},
+                     kv_pack=(payload["meta"], payload["rows"]))
+    _drain(dec)
+    assert seq.generated == local
+    assert dec.stats()["kv_pages_in_use"] == {"full": 0, "window": 0}
+    assert dec.stats()["prefill_steps"] == 0
+
+
+def test_a_llama_engine_is_the_parents():
+    """ISSUE 28 point 2: for a model whose cache has one kind the engine
+    allocates the pools and compiles the programs the parent did.  The
+    decode step of `LlamaConfig.tiny` (page 8, 4 lanes, width 4) costs
+    what it cost at the parent commit 103a787, by the compiler's own
+    analysis, and the bytes held are the parent's (numbers read there
+    with this jax)."""
+    import jax
+
+    eng = LLMEngine(model="tiny", page_size=8, max_batch=4)
+    rep = eng.device_report()
+    assert (rep["param_bytes"], rep["kv_pool_bytes"]) == (427264, 133120)
+    assert rep["model"]["family"] == "llama" and rep["model"]["share"] is None
+    assert eng._windows == {} and eng.prefix_sharing
+    (tokens, slots, _c, _p, _m, q_pos, last), kw = \
+        eng._garbage_decode_args(4)
+    assert kw["windows"] == {}
+    cost = _jitted_forward(0.0, 0, False).lower(
+        eng._model, eng._params, eng._pools["k"], eng._pools["v"], tokens,
+        q_pos, last, jax.numpy.zeros((2,), "uint32"),
+        {"full": {"slots": slots, "block_tables": kw["block_tables"],
+                  "context_lens": kw["context_lens"]}}
+    ).compile().cost_analysis()
+    assert (cost["flops"], cost["bytes accessed"],
+            cost["transcendentals"]) == (1340640.0, 3119880.0, 1380.0)
+    assert "moe_assignments_total" not in eng.stats()

@@ -1,0 +1,117 @@
+"""ops/moe.py: an expert layer that holds a share of the experts, at a
+small size on the CPU (the Pallas kernels run in the interpreter)."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import moe
+
+D, F, E, K = 32, 16, 8, 2
+
+
+def _weights(seed=0, experts=E):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(ks[0], (D, experts), jnp.float32) * D ** -0.5,
+            jax.random.normal(ks[1], (experts, D, F), jnp.float32) * D ** -0.5,
+            jax.random.normal(ks[2], (experts, D, F), jnp.float32) * D ** -0.5,
+            jax.random.normal(ks[3], (experts, F, D), jnp.float32) * F ** -0.5)
+
+
+def _naive(x, w_router, w1, w3, w2, held, top_k=K, valid=None):
+    """Every held expert on every token, times its routing weight or 0."""
+    ids, weights = moe.route(x, w_router, top_k)
+    out = jnp.zeros(x.shape, jnp.float32)
+    for local, e in enumerate(range(*held)):
+        w = jnp.sum(jnp.where(ids == e, weights, 0.0), axis=-1)
+        if valid is not None:
+            w = jnp.where(valid, w, 0.0)
+        y = (jax.nn.silu(x @ w1[local]) * (x @ w3[local])) @ w2[local]
+        out = out + w[:, None] * y
+    return out
+
+
+def _layer(x, wr, w1, w3, w2, held, **kw):
+    lo, hi = held
+    return moe.moe_layer(x, wr, w1[lo:hi], w3[lo:hi], w2[lo:hi], top_k=K,
+                         held=held, **kw)
+
+
+@pytest.mark.parametrize("tokens", [1, 7, 40], ids=lambda t: f"{t}tok")
+def test_layer_is_every_expert_on_every_token_weighted(tokens):
+    wr, w1, w3, w2 = _weights()
+    x = jax.random.normal(jax.random.PRNGKey(1), (tokens, D), jnp.float32)
+    y, counters = _layer(x, wr, w1, w3, w2, (0, E))
+    np.testing.assert_allclose(y, _naive(x, wr, w1, w3, w2, (0, E)),
+                               rtol=1e-5, atol=1e-5)
+    assert int(counters["assignments"]) == tokens * K   # none dropped
+
+
+def test_every_token_on_one_expert_and_experts_nobody_chose():
+    """A router that sends every token to experts 3 and 5: those two
+    hold every assignment (40 each, three row tiles of 16), the other
+    six are never touched."""
+    _wr, w1, w3, w2 = _weights()
+    wr = jnp.zeros((D, E)).at[:, 3].set(1.0).at[:, 5].set(0.5)
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(2), (40, D))) + 0.1
+    ids, _w = moe.route(x, wr, K)
+    assert set(np.asarray(ids).ravel()) == {3, 5}
+    y, counters = _layer(x, wr, w1, w3, w2, (0, E))
+    np.testing.assert_allclose(y, _naive(x, wr, w1, w3, w2, (0, E)),
+                               rtol=1e-5, atol=1e-5)
+    assert int(counters["expert_calls"]) == 2
+    assert int(counters["max_load"]) == 40
+    d = moe.dispatch(ids, jnp.ones((40,), bool), (0, E), 16)
+    assert int(d.active_tiles) == 6
+    assert list(np.asarray(d.tile_expert[:6])) == [3, 3, 3, 5, 5, 5]
+    # a share that holds neither computes nothing and says so
+    y0, c0 = _layer(x, wr, w1, w3, w2, (6, 8))
+    assert float(jnp.abs(y0).max()) == 0.0
+    assert int(c0["assignments"]) == int(c0["expert_calls"]) == 0
+
+
+def test_padding_tokens_are_routed_nowhere():
+    wr, w1, w3, w2 = _weights()
+    x = jax.random.normal(jax.random.PRNGKey(3), (12, D), jnp.float32)
+    valid = jnp.arange(12) < 5
+    y, counters = _layer(x, wr, w1, w3, w2, (0, E), valid=valid)
+    want = _naive(x, wr, w1, w3, w2, (0, E), valid=valid)
+    np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-5)
+    assert float(jnp.abs(y[5:]).max()) == 0.0
+    assert int(counters["assignments"]) == 5 * K
+
+
+@pytest.mark.parametrize("shares", [2, 4], ids=lambda n: f"{n}shares")
+def test_the_shares_add_up_to_the_uncut_reference_layer(shares):
+    """ISSUE 28 point 5: the routed parts every share's `ops/moe.py`
+    gives, plus the shared expert counted once, equal the uncut plain
+    reference's expert layer for the same tokens."""
+    from benchmarks import reference_laguna as ref
+
+    wr, w1, w3, w2 = _weights(seed=5)
+    ks = jax.random.split(jax.random.PRNGKey(6), 4)
+    shared = {"w1": {"kernel": jax.random.normal(ks[0], (D, F)) * D ** -0.5},
+              "w3": {"kernel": jax.random.normal(ks[1], (D, F)) * D ** -0.5},
+              "w2": {"kernel": jax.random.normal(ks[2], (F, D)) * F ** -0.5}}
+    x = jax.random.normal(ks[3], (24, D), jnp.float32)
+    per = E // shares
+    parts = [_layer(x, wr, w1, w3, w2, (s * per, (s + 1) * per))
+             for s in range(shares)]
+    assert sum(int(c["assignments"]) for _y, c in parts) == 24 * K
+    shared_y = ref._swiglu(x, *(shared[n]["kernel"]
+                                for n in ("w1", "w3", "w2")))
+    ours = ref.combine_shared(shared_y, sum(y for y, _c in parts), 2.5)
+    with jax.default_matmul_precision("highest"):
+        routed, _margin = ref._routed(
+            x, {"moe_router": wr, "moe_experts_w1": w1,
+                "moe_experts_w3": w3, "moe_experts_w2": w2},
+            top_k=K, normalize=True, lo=0)
+        uncut = ref.combine_shared(shared_y, routed, 2.5)
+    np.testing.assert_allclose(ours, uncut, rtol=1e-4, atol=1e-5)
+
+
+def test_row_tile_follows_the_mean_group():
+    assert moe.row_tile(32, 10, 256) == 16      # a decode batch
+    assert moe.row_tile(512, 10, 256) == 32     # a prefill pass
+    assert moe.row_tile(8192, 10, 256) == 128   # never over the MXU's side

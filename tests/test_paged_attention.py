@@ -14,9 +14,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.llama import (LlamaConfig, cached_attention,
-                                  copy_kv_slots, gather_kv_slots,
-                                  make_kv_pools, scatter_kv_slots)
+from ray_tpu.models import cache as kv_cache
+from ray_tpu.models.llama import LlamaConfig, cached_attention
 from ray_tpu.ops.paged_attention import paged_attention
 
 
@@ -154,6 +153,21 @@ def test_gather_scatter_copy_round_trip():
     cfg = LlamaConfig(vocab_size=16, dim=16, n_layers=2, n_heads=4,
                       n_kv_heads=2, hidden_dim=16, max_seq_len=32,
                       dtype=jnp.float32)
+    spec = cfg.cache_spec()
+    kinds = [layer.kind for layer in spec]
+
+    def make_kv_pools(cfg, num_slots):
+        return kv_cache.make_pools(spec, {"full": num_slots}, cfg.dtype)
+
+    def gather_kv_slots(pools, slots):
+        return kv_cache.gather_slots(pools, kinds, {"full": slots})
+
+    def scatter_kv_slots(pools, slots, rows):
+        return kv_cache.scatter_slots(pools, kinds, {"full": slots}, rows)
+
+    def copy_kv_slots(pools, src, dst):
+        return kv_cache.copy_slots(pools, kinds, "full", src, dst)
+
     rng = np.random.default_rng(13)
     for trial in range(5):
         num_slots = 40

@@ -553,6 +553,47 @@ def test_loop_single_flight_and_stop():
     assert not t.is_alive()
 
 
+def test_exit_worker_ends_a_loop_that_holds_the_main_exec_thread():
+    """Whichever exec thread dequeues `__rt_dag_llm_loop__` is pinned; if
+    that is the worker's MAIN thread, `exit_worker`'s sentinel is read by
+    the concurrency threads only and the replica outlived its kill (one
+    rehearsal in ten waited 60 s for the chip, PR 28).  `run_llm_loop`
+    leaves the worker the way to end the loop, `rpc_exit_worker` uses
+    it, and the thread goes back to the queue, where the sentinel is."""
+    import asyncio
+    import queue
+    import types
+
+    from ray_tpu._private.worker import CoreWorker
+    from ray_tpu.serve.llm import run_llm_loop
+
+    eng = _engine()
+    worker = types.SimpleNamespace(_task_queue=queue.Queue(),
+                                   _pinned_stop=None)
+    replica = types.SimpleNamespace(_engine=eng)
+    out = {}
+    t = threading.Thread(
+        target=lambda: out.update(run_llm_loop(worker, replica)),
+        daemon=True)
+    t.start()
+    deadline = time.time() + 5
+    while not eng.stats()["loop_running"] and time.time() < deadline:
+        time.sleep(0.01)
+    assert eng.stats()["loop_running"] and worker._pinned_stop is not None
+    # a second install (the controller re-ensuring loops) returns at once
+    # and must leave the way to end the FIRST loop in place
+    assert run_llm_loop(worker, replica) == {"already_running": True}
+    asyncio.run(CoreWorker.rpc_exit_worker(worker))
+    t.join(5)
+    assert not t.is_alive() and "steps" in out
+    assert worker._task_queue.get_nowait() is None
+    # a worker with no pinned loop: the sentinel and nothing else
+    plain = types.SimpleNamespace(_task_queue=queue.Queue(),
+                                  _pinned_stop=None)
+    asyncio.run(CoreWorker.rpc_exit_worker(plain))
+    assert plain._task_queue.get_nowait() is None
+
+
 # --------------------------------------------- prefix sharing (CoW pages)
 
 

@@ -101,3 +101,57 @@ def test_flash_gradient_compiles_at_train_shape(one_chip):
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
         *_qkv(TRAIN_SHAPE, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# ------------------------------------------- the Laguna cell's kernels
+# benchmarks/configs/laguna-s-2.1-serve.json: 32 lanes, 48 heads on a
+# full layer and 72 on a sliding one over 8 KV heads (groups 6 and 9), a
+# window of 512 (a table of 33 pages from `starts`), 128 of 256 experts
+# of width 1024 at hidden 3072
+
+
+@pytest.mark.parametrize("heads,window", [(48, None), (72, 512)],
+                         ids=["full-group6", "window-group9"])
+def test_paged_decode_compiles_at_laguna_shapes(one_chip, heads, window):
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lanes = 32
+    width, pages = (33, 38) if window else (512, 512)
+    slots = (1 + lanes * pages) * PAGE
+    compiled = jax.jit(
+        lambda q, k, v, bt, cl, st: paged_attention(
+            q, k, v, bt, cl, page_size=PAGE, interpret=False,
+            window=window, starts=st if window else None)
+    ).lower(spec((lanes, 1, heads, D), jnp.bfloat16),
+            spec((slots, HKV, D), jnp.bfloat16),
+            spec((slots, HKV, D), jnp.bfloat16),
+            spec((lanes, width), jnp.int32), spec((lanes,), jnp.int32),
+            spec((lanes,), jnp.int32)).compile()
+    name = "paged_attention_decode_window" if window \
+        else "paged_attention_decode"
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and name in text
+
+
+@pytest.mark.parametrize("tokens", [32, 512], ids=["decode", "prefill"])
+def test_expert_layer_compiles_at_laguna_shapes(one_chip, tokens):
+    """`ops.moe.moe_layer` for a decode batch (row tiles of 16) and a
+    prefill pass (8 lanes x 64 tokens, tiles of 32): both grouped
+    matmuls lower with an expert's whole matrices as one block."""
+    from ray_tpu.ops import moe
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda x, wr, w1, w3, w2, valid: moe.moe_layer(
+            x, wr, w1, w3, w2, top_k=10, held=(0, 128), valid=valid,
+            interpret=False)
+    ).lower(spec((tokens, 3072), jnp.bfloat16),
+            spec((3072, 256), jnp.bfloat16),
+            spec((128, 3072, 1024), jnp.bfloat16),
+            spec((128, 3072, 1024), jnp.bfloat16),
+            spec((128, 1024, 3072), jnp.bfloat16),
+            spec((tokens,), jnp.bool_)).compile()
+    assert compiled.as_text().count("moe_experts") >= 2
