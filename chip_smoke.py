@@ -73,10 +73,11 @@ class Sizes:
 REAL = Sizes(
     rehearsal=False,
     # Serve: Llama-3-8B widths, none changed.  Depth from the rehearsal
-    # compile's memory_analysis(): float32 weights (as the engine stores
-    # them) are 0.81 GiB a layer plus 3.9 GiB of embedding and head; with
-    # a bf16 KV pool for max_batch 8 x 8192 tokens (0.25 GiB a layer) the
-    # decode step totals 13.4 GiB with 8 layers.
+    # compile's memory_analysis() of the days the engine stored float32
+    # weights (0.81 GiB a layer plus 3.9 GiB of embedding and head; with
+    # a bf16 KV pool for max_batch 8 x 8192 tokens, 0.25 GiB a layer, the
+    # decode step totalled 13.4 GiB with 8 layers).  It stores bfloat16
+    # since PR 29, half of that; the depth is kept (a smoke, not a cell).
     serve_model="llama3_8b", serve_layers=8, long_prompts=(100, 150),
     # Train: the repo's one-chip training config (bench.py).
     train_model="bench_1b", train_batch=8, train_seq=1024,

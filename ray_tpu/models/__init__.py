@@ -7,10 +7,13 @@ A model FAMILY is a module of this package that defines
                            instance, a dictionary of sizes, a preset's
                            name — as the family's frozen config dataclass
                            (`max_seq_len`, `dtype`, `cache_spec()`)
-    build(cfg, page_size)  the flax module: `init(rng, tokens)` and
+    build(cfg, page_size)  the serving module: `init(rng, tokens)` and
                            `apply(params, tokens, cache)` -> (logits,
                            pools[, counters]); `counters` names the
-                           entries of the counter vector, if it has one
+                           entries of the counter vector, if it has one.
+                           It declares every parameter in the dtype its
+                           forward reads it in (no master weights), and
+                           the engine holds its tree in those dtypes
 
 and whose config states its cache by layer (models/cache.py).  The plain
 reference of a family lives with the benchmark (`benchmarks/reference*`)

@@ -6,7 +6,9 @@ The reference has no model code of its own — Train wraps user torch
 models (reference: python/ray/train/torch/train_loop_utils.py) — so this
 is green-field, designed for the MXU and GSPMD from the start:
 
-  - bfloat16 activations/compute, fp32 params + optimizer state
+  - bfloat16 activations/compute; a trainer stores fp32 params +
+    optimizer state, the serving module (`build`) stores the bfloat16
+    matrices the forward multiplies by (`LlamaConfig.param_dtype`)
   - GQA attention with rotary embeddings; attention runs through a
     pluggable kernel hook so the Pallas flash/ring kernels (ray_tpu/ops)
     swap in without touching the model
@@ -32,7 +34,7 @@ shapes.
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from functools import partial
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -56,6 +58,12 @@ class LlamaConfig:
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     remat: bool = False  # rematerialize each block (activation checkpointing)
+    # what the matrices (q, k, v, o, w1, w2, w3, embedding, lm_head) are
+    # stored in.  float32 is a trainer's: masters and moments.  The
+    # forward rounds them to `dtype` before every product, so the
+    # serving module (`build`) stores them in `dtype`, rounded once.
+    # RMSNorm scales multiply in float32 and stay float32 either way.
+    param_dtype: Any = jnp.float32
 
     @classmethod
     def tiny(cls) -> "LlamaConfig":
@@ -208,6 +216,20 @@ def cached_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     return out.reshape(b, s, h, d)
 
 
+def _drawn_in_float32(init: Callable) -> Callable:
+    """`init`, drawn in float32 and rounded once to the stored dtype:
+    the matrices of a module that stores `cfg.dtype` are number for
+    number a float32 master's `.astype(cfg.dtype)` (a bfloat16 draw
+    would be another model), and a float32 module's are flax's own."""
+    def rounded(key, shape, dtype=jnp.float32):
+        return init(key, shape, jnp.float32).astype(dtype)
+    return rounded
+
+
+_kernel_init = _drawn_in_float32(nn.linear.default_kernel_init)
+_embed_init = _drawn_in_float32(nn.linear.default_embed_init)
+
+
 class RMSNorm(nn.Module):
     eps: float = 1e-5
 
@@ -228,15 +250,14 @@ class Attention(nn.Module):
     def __call__(self, x, positions, cache=None):
         cfg = self.cfg
         dense = partial(nn.DenseGeneral, use_bias=False, dtype=cfg.dtype,
-                        param_dtype=jnp.float32)
+                        param_dtype=cfg.param_dtype,
+                        kernel_init=_kernel_init)
         q = dense(features=(cfg.n_heads, cfg.head_dim), name="wq")(x)
         k = dense(features=(cfg.n_kv_heads, cfg.head_dim), name="wk")(x)
         v = dense(features=(cfg.n_kv_heads, cfg.head_dim), name="wv")(x)
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)
-        wo = nn.DenseGeneral(features=cfg.dim, axis=(-2, -1), use_bias=False,
-                             dtype=cfg.dtype, param_dtype=jnp.float32,
-                             name="wo")
+        wo = dense(features=cfg.dim, axis=(-2, -1), name="wo")
         if cache is not None:
             # incremental path: write post-rope k/v into this layer's
             # flat slot pools, attend over the gathered context.  Slot 0
@@ -274,7 +295,8 @@ class Mlp(nn.Module):
     def __call__(self, x):
         cfg = self.cfg
         dense = partial(nn.Dense, use_bias=False, dtype=cfg.dtype,
-                        param_dtype=jnp.float32)
+                        param_dtype=cfg.param_dtype,
+                        kernel_init=_kernel_init)
         gate = dense(cfg.hidden_dim, name="w1")(x)
         up = dense(cfg.hidden_dim, name="w3")(x)
         return dense(cfg.dim, name="w2")(nn.silu(gate) * up)
@@ -302,6 +324,20 @@ class Block(nn.Module):
         return x
 
 
+def _embed(cfg: LlamaConfig) -> nn.Embed:
+    return nn.Embed(cfg.vocab_size, cfg.dim, dtype=cfg.dtype,
+                    param_dtype=cfg.param_dtype, embedding_init=_embed_init,
+                    name="embed")
+
+
+def _lm_head(cfg: LlamaConfig) -> nn.Dense:
+    # bf16 matmul with fp32 accumulation: the biggest single matmul of
+    # the model must ride the MXU fast path (loss math upcasts after)
+    return nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
+                    param_dtype=cfg.param_dtype, kernel_init=_kernel_init,
+                    name="lm_head")
+
+
 class LlamaModel(nn.Module):
     cfg: LlamaConfig
     kernel: Optional[Callable] = None
@@ -310,8 +346,7 @@ class LlamaModel(nn.Module):
     @nn.compact
     def __call__(self, tokens, cache=None):
         cfg = self.cfg
-        x = nn.Embed(cfg.vocab_size, cfg.dim, dtype=cfg.dtype,
-                     param_dtype=jnp.float32, name="embed")(tokens)
+        x = _embed(cfg)(tokens)
         if cache is not None:
             # incremental decode/prefill over the paged KV cache: query
             # positions come from the engine, per-layer pools are
@@ -331,10 +366,7 @@ class LlamaModel(nn.Module):
                 new_k.append(pk)
                 new_v.append(pv)
             x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
-            logits = nn.Dense(cfg.vocab_size, use_bias=False,
-                              dtype=cfg.dtype, param_dtype=jnp.float32,
-                              name="lm_head")(x)
-            return logits, {"k": new_k, "v": new_v}
+            return _lm_head(cfg)(x), {"k": new_k, "v": new_v}
         positions = jnp.broadcast_to(
             jnp.arange(tokens.shape[1]), tokens.shape)
         block_cls = Block
@@ -345,11 +377,7 @@ class LlamaModel(nn.Module):
         for i in range(cfg.n_layers):
             x = block_cls(cfg, self.kernel, name=f"layer_{i}")(x, positions)
         x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
-        # bf16 matmul with fp32 accumulation: the biggest single matmul of
-        # the model must ride the MXU fast path (loss math upcasts after)
-        logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                          param_dtype=jnp.float32, name="lm_head")(x)
-        return logits
+        return _lm_head(cfg)(x)
 
 
 class LlamaStage(nn.Module):
@@ -376,8 +404,7 @@ class LlamaStage(nn.Module):
     def __call__(self, x):
         cfg = self.cfg
         if self.first:
-            x = nn.Embed(cfg.vocab_size, cfg.dim, dtype=cfg.dtype,
-                         param_dtype=jnp.float32, name="embed")(x)
+            x = _embed(cfg)(x)
             seq_len = x.shape[1]
             positions = jnp.broadcast_to(jnp.arange(seq_len),
                                          x.shape[:2])
@@ -391,21 +418,25 @@ class LlamaStage(nn.Module):
             x = block_cls(cfg, self.kernel, name=f"layer_{i}")(x, positions)
         if self.last:
             x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
-            x = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                         param_dtype=jnp.float32, name="lm_head")(x)
+            x = _lm_head(cfg)(x)
         return x
 
 
 def kv_pool_bytes(cfg: LlamaConfig, num_slots: int) -> int:
-    """Resident bytes of one replica's KV pools (both k and v)."""
+    """Resident bytes of one replica's KV pools (both k and v), which
+    like the serving module's matrices are held in `cfg.dtype`."""
     itemsize = jnp.dtype(cfg.dtype).itemsize
     return (2 * cfg.n_layers * num_slots * cfg.n_kv_heads
             * cfg.head_dim * itemsize)
 
 
 def build(cfg: LlamaConfig, page_size: int = 0) -> LlamaModel:
-    """The serving module of this family (models/__init__.py)."""
-    return LlamaModel(cfg, page_size=page_size)
+    """The serving module of this family (models/__init__.py).  It keeps
+    no master weights: its matrices are declared in `cfg.dtype`, what
+    the forward multiplies by, and `init` draws them as a float32
+    module's rounded once."""
+    return LlamaModel(replace(cfg, param_dtype=cfg.dtype),
+                      page_size=page_size)
 
 
 def config(model: Any) -> LlamaConfig:

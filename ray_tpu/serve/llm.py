@@ -541,15 +541,27 @@ class LLMEngine:
                                self.pages_per_seq)
             for kind, window in windows.items()}
         # seconds of this replica's start-up, by part: until the weights
-        # were on the device, the call that allocates the KV pools, and
-        # `warm_up`'s compiles.  The device fills the weights while the
-        # host goes on to the pools and the warm-up, so a waiter thread
-        # notes when they were there and nothing is held up for the clock.
+        # the engine serves from were on the device, the call that
+        # allocates the KV pools, and `warm_up`'s compiles.  The device
+        # fills the weights while the host goes on to the pools and the
+        # warm-up, so a waiter thread notes when they were there and
+        # nothing is held up for the clock.
+        #
+        # The engine holds its tree in the dtypes its serving module
+        # declares, what the forward multiplies by.  `init` draws it so,
+        # a leaf at a time.  A tree passed in (a trainer's float32
+        # checkpoint) is brought to them leaf by leaf, rounded once and
+        # not once a pass; a leaf already as declared is taken as it is.
         t0 = time.perf_counter()
+        init_args = (jax.random.PRNGKey(int(seed)),
+                     np.zeros((1, 8), np.int32))
         if params is None:
-            dummy = np.zeros((1, 8), np.int32)
-            params = self._model.init(
-                jax.random.PRNGKey(int(seed)), dummy)["params"]
+            params = self._model.init(*init_args)["params"]
+        else:
+            params = jax.tree.map(
+                lambda leaf, declared: leaf if leaf.dtype == declared.dtype
+                else jnp.asarray(leaf, declared.dtype), params,
+                jax.eval_shape(self._model.init, *init_args)["params"])
         self._params = params
         t1 = time.perf_counter()
         self._pools = kv_cache.make_pools(
@@ -1009,7 +1021,8 @@ class LLMEngine:
 
     def device_report(self) -> Dict[str, Any]:
         """`ops.device_report()` plus what this engine put on the device
-        and how its decode step lowered: the Pallas kernel is a
+        (`param_bytes`: the tree as held, in the dtypes the serving module
+        declares) and how its decode step lowered: the Pallas kernel is a
         `tpu_custom_call` when compiled for the chip, and absent from the
         text under the interpreter or `attention_impl="dense"`.  Traces
         the decode step once more (nothing runs); not for a hot path."""

@@ -182,14 +182,19 @@ def test_a_llama_engine_is_the_parents():
     """ISSUE 28 point 2: for a model whose cache has one kind the engine
     allocates the pools and compiles the programs the parent did.  The
     decode step of `LlamaConfig.tiny` (page 8, 4 lanes, width 4) costs
-    what it cost at the parent commit 103a787, by the compiler's own
-    analysis, and the bytes held are the parent's (numbers read there
-    with this jax)."""
+    what it cost at the parent commit, by the compiler's own analysis,
+    and the bytes held are the parent's (numbers read there with this
+    jax).  Re-pinned by ISSUE 29, which changed them by design: the
+    engine stores its 106,496 matrix entries in bfloat16, so
+    `param_bytes` 427,264 -> 214,272, and the step no longer rounds them
+    (a flop an entry by the compiler's count): flops 1,340,640 ->
+    1,234,144, bytes accessed 3,119,880 -> 2,906,888, transcendentals
+    and the pools as they were (8d027c8 -> ISSUE 29's commit)."""
     import jax
 
     eng = LLMEngine(model="tiny", page_size=8, max_batch=4)
     rep = eng.device_report()
-    assert (rep["param_bytes"], rep["kv_pool_bytes"]) == (427264, 133120)
+    assert (rep["param_bytes"], rep["kv_pool_bytes"]) == (214272, 133120)
     assert rep["model"]["family"] == "llama" and rep["model"]["share"] is None
     assert eng._windows == {} and eng.prefix_sharing
     (tokens, slots, _c, _p, _m, q_pos, last), kw = \
@@ -202,5 +207,5 @@ def test_a_llama_engine_is_the_parents():
                   "context_lens": kw["context_lens"]}}
     ).compile().cost_analysis()
     assert (cost["flops"], cost["bytes accessed"],
-            cost["transcendentals"]) == (1340640.0, 3119880.0, 1380.0)
+            cost["transcendentals"]) == (1234144.0, 2906888.0, 1380.0)
     assert "moe_assignments_total" not in eng.stats()
