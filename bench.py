@@ -8,15 +8,12 @@ reference: python/ray/_private/ray_perf.py:174; recorded value 1006.9
 tasks/s in release/release_logs/2.9.3/microbenchmark.json).
 
 Also measured (extras): async task throughput, actor call throughput,
-object-store put bandwidth, and a Llama train-step MFU benchmark.
+object-store put bandwidth, and the other host phases.  What the chip
+does is `benchmarks/run.py`'s to measure (BENCHMARK.json).
 
 Robustness contract (the driver runs this unattended):
   * every host phase is individually try/except'ed with its own timeout —
     one hang or crash cannot erase numbers already measured;
-  * the train phase runs in a watchdogged subprocess, killed after a hard
-    deadline, on the chip and nowhere else: it fails where jax finds no
-    TPU or a device_kind with no entry in PEAK_BF16_FLOPS — there is no
-    CPU retry and no toy-size stand-in;
   * the JSON line is ALWAYS printed, with per-phase errors in
     extras["errors"]; a run with errors exits 1.
 """
@@ -30,11 +27,6 @@ import sys
 import time
 
 REPO = os.path.dirname(os.path.abspath(__file__))
-
-# Peak dense bf16 FLOP/s of one chip, by the device_kind jax reports.
-# Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB
-# HBM at 819 GB/s).  A kind that is not here is an error, not a default.
-PEAK_BF16_FLOPS = {"TPU v5 lite": 197e12, "TPU v5e": 197e12}
 
 
 def bench_tasks_sync(ray_tpu, n=300):
@@ -578,348 +570,6 @@ def bench_serve(ray_tpu, pairs=2, conns=64, total=1200):
         except Exception:
             pass
         for name in ("echo_bench", "sse_bench"):
-            try:
-                serve.delete(name)
-            except Exception:
-                pass
-    return out
-
-def _llm_stream_load(host, port, path, n_streams, payload_fn,
-                     timeout_s=600):
-    """Drive `n_streams` concurrent SSE generation requests; returns
-    (total_token_items, wall_s, per-stream TTFT list, error_count)."""
-    import asyncio
-
-    ttfts = []
-    tokens = [0]
-    errors = [0]
-
-    async def client(i):
-        body = json.dumps(payload_fn(i)).encode()
-        req = (f"POST {path} HTTP/1.1\r\nHost: bench\r\n"
-               f"Content-Type: application/json\r\n"
-               f"Accept: text/event-stream\r\n"
-               f"Content-Length: {len(body)}\r\n\r\n").encode() + body
-        try:
-            reader, writer = await asyncio.open_connection(host, port)
-        except OSError:
-            errors[0] += 1
-            return
-        try:
-            t0 = time.perf_counter()
-            writer.write(req)
-            await writer.drain()
-            status = await reader.readline()
-            if b"200" not in status:
-                errors[0] += 1
-                return
-            while True:  # headers
-                h = await reader.readline()
-                if h in (b"\r\n", b"\n", b""):
-                    break
-            first = None
-            while True:  # chunks
-                size = int((await reader.readline()).strip() or b"0", 16)
-                if size == 0:
-                    await reader.readline()
-                    break
-                data = await reader.readexactly(size + 2)
-                if first is None:
-                    first = time.perf_counter() - t0
-                try:
-                    tokens[0] += len(json.loads(data[:-2]).get("tokens")
-                                     or [])
-                except ValueError:
-                    pass
-            if first is not None:
-                ttfts.append(first)
-        except (OSError, asyncio.IncompleteReadError, ValueError):
-            errors[0] += 1
-        finally:
-            try:
-                writer.close()
-            except Exception:
-                pass
-
-    async def run():
-        await asyncio.wait_for(
-            asyncio.gather(*[client(i) for i in range(n_streams)]),
-            timeout=timeout_s)
-
-    t0 = time.perf_counter()
-    asyncio.run(run())
-    return tokens[0], time.perf_counter() - t0, ttfts, errors[0]
-
-def bench_llm_serve(ray_tpu, pairs=2, streams=64, big_streams=256):
-    """LLM serving-tier A/B (ISSUE 11): continuous batching (ONE pinned
-    decode loop, token-boundary lane refill, paged KV) vs the
-    ``@serve.batch`` static-batching baseline (fixed 8-wide batch runs
-    to its longest member, disbands, re-dispatches), same model +
-    params + SSE streaming contract + item chunking on both sides,
-    BEST-OF ALTERNATING PAIRS per the slow-box protocol.
-
-    The A/B runs at ``big_streams`` (256) concurrent streams — 4x the
-    continuous path's 64 decode lanes, so lanes REFILL at token
-    boundaries while the baseline pays padding-to-longest and
-    batch-boundary re-dispatch; a decode-heavy variable-length
-    workload (32..96 new tokens, mean ~64).  Contract:
-    ``llm_continuous_vs_batch_x`` >= 2 at 64+ concurrent streams with
-    zero shed-gate 503s below KV-page capacity.  A 64-stream
-    continuous run reports unqueued TTFT."""
-    from ray_tpu import serve
-    from ray_tpu.serve.api import Deployment
-    from ray_tpu.serve.llm import _LLMBatchCallable
-
-    model = {"vocab_size": 128, "dim": 64, "n_layers": 2, "n_heads": 4,
-             "n_kv_heads": 2, "hidden_dim": 128, "max_seq_len": 128}
-    engine_kw = dict(model=model, page_size=16, prefill_chunk=32, seed=7)
-    prompt = [7, 3, 11, 5]
-    pages_per_seq = 8  # ceil(128/16)
-
-    def payload(i):
-        return {"tokens": prompt, "max_new_tokens": 32 + (i * 37) % 65,
-                "request_id": f"bench-{i}-{time.monotonic_ns()}"}
-
-    def expected(n_streams):
-        return sum(32 + (i * 37) % 65 for i in range(n_streams))
-
-    out = {}
-
-    def p99(vals):
-        vals = sorted(vals)
-        return vals[min(len(vals) - 1, int(0.99 * len(vals)))]
-
-    try:
-        # continuous: 64 decode lanes, pages for all of them at worst
-        # case; everything beyond queues and refills lanes at token
-        # boundaries
-        serve.run(serve.llm_deployment(
-            "llm_cb", max_ongoing_requests=big_streams + 8,
-            max_batch=64, num_pages=1 + 64 * pages_per_seq,
-            max_queue=big_streams, stream_flush_tokens=16, **engine_kw))
-        # baseline gets RIGHT-SIZED shapes for its batch (a
-        # static-batching server would compile [8,*], not [64,*]) — the
-        # A/B measures the batching policy, not a shape handicap
-        base = Deployment(_LLMBatchCallable, "llm_sb",
-                          max_ongoing_requests=big_streams + 8)
-        serve.run(base.bind(max_batch_size=8, batch_wait_timeout_s=0.005,
-                            num_pages=1 + 8 * pages_per_seq, max_batch=8,
-                            prefill_lanes=8, stream_flush_tokens=16,
-                            **engine_kw))
-
-        # ---- engine-level A/B (in-process, no serving transport):
-        # isolates the BATCHING POLICY — in this sandbox the
-        # serving-level numbers below are dominated by per-syscall
-        # transport costs shared by both sides, which pins their ratio
-        # toward 1 regardless of policy (see BENCH_r07 notes; the
-        # driver box collapses transport ~1000x, pulling the serving
-        # ratio toward this engine ratio)
-        from ray_tpu.serve.llm import LLMEngine
-
-        def eng_reqs(r, n):
-            return [{"tokens": prompt,
-                     "max_new_tokens": 32 + (i * 37) % 65,
-                     "request_id": f"eng-{r}-{i}"} for i in range(n)]
-
-        e_cont = LLMEngine(num_pages=1 + 64 * pages_per_seq, max_batch=64,
-                           prefill_lanes=8, max_queue=300, **engine_kw)
-        e_stat = LLMEngine(num_pages=1 + 8 * pages_per_seq, max_batch=8,
-                           prefill_lanes=8, max_queue=300, **engine_kw)
-        e_cont.generate_batch(eng_reqs("w", 2))
-        e_stat.generate_batch(eng_reqs("x", 2))
-        ec, es = [], []
-        n_eng = big_streams
-        etotal = sum(32 + (i * 37) % 65 for i in range(n_eng))
-        for r in range(pairs):
-            t0 = time.perf_counter()
-            e_cont.generate_batch(eng_reqs(f"c{r}", n_eng))
-            ec.append(etotal / (time.perf_counter() - t0))
-            reqs = eng_reqs(f"s{r}", n_eng)
-            t0 = time.perf_counter()
-            for b in range(0, n_eng, 8):
-                e_stat.generate_batch(reqs[b:b + 8])
-            es.append(etotal / (time.perf_counter() - t0))
-        out["llm_engine_tokens_per_s"] = round(max(ec), 1)
-        out["llm_engine_batch_tokens_per_s"] = round(max(es), 1)
-        out["llm_engine_continuous_vs_batch_x"] = round(
-            max(ec) / max(es), 2)
-        host, port = serve.start_http()
-        # warm both paths (jit compiles on first request)
-        _llm_stream_load(host, port, "/llm_cb", 2, payload)
-        _llm_stream_load(host, port, "/llm_sb", 2, payload)
-        cont, batch, ttft99, bttft99 = [], [], [], []
-        for _ in range(pairs):
-            toks, wall, ttfts, errs = _llm_stream_load(
-                host, port, "/llm_cb", big_streams, payload)
-            if errs or toks < expected(big_streams):
-                raise RuntimeError(
-                    f"continuous run incomplete: {toks} tokens, "
-                    f"{errs} errors (shed below capacity?)")
-            cont.append(toks / wall)
-            ttft99.append(p99(ttfts))
-            btoks, bwall, bttfts, berrs = _llm_stream_load(
-                host, port, "/llm_sb", big_streams, payload)
-            if berrs or btoks < expected(big_streams):
-                raise RuntimeError(
-                    f"baseline run incomplete: {btoks} tokens, "
-                    f"{berrs} errors")
-            batch.append(btoks / bwall)
-            bttft99.append(p99(bttfts))
-        out["llm_tokens_per_s"] = round(max(cont), 1)
-        out["llm_batch_tokens_per_s"] = round(max(batch), 1)
-        out["llm_continuous_vs_batch_x"] = round(max(cont) / max(batch), 2)
-        out["llm_ttft_p99_ms"] = round(min(ttft99) * 1000.0, 1)
-        # the latency half of the story: a static batch's first token
-        # waits for its WHOLE batch to finish
-        out["llm_batch_ttft_p99_ms"] = round(min(bttft99) * 1000.0, 1)
-        # at-capacity TTFT: 64 streams fit the 64 lanes outright on
-        # the continuous path, while the static baseline's first token
-        # still waits out its batch — the latency half of the win
-        toks, wall, ttfts, errs = _llm_stream_load(
-            host, port, "/llm_cb", streams, payload)
-        out["llm_tokens_per_s_64"] = round(toks / wall, 1)
-        out["llm_sse_errors"] = errs
-        if ttfts:
-            out["llm_ttft_p99_ms_64"] = round(p99(ttfts) * 1000.0, 1)
-        btoks, bwall, bttfts, berrs = _llm_stream_load(
-            host, port, "/llm_sb", streams, payload)
-        if bttfts and not berrs:
-            out["llm_batch_ttft_p99_ms_64"] = round(
-                p99(bttfts) * 1000.0, 1)
-
-        # ---- 80%-shared-prefix workload (ISSUE 16): copy-on-write
-        # prefix sharing A/B at `streams` concurrent SSE streams.  80%
-        # of requests carry the same 64-token system prompt + a 4-token
-        # unique tail; 20% are fully unique 68-token prompts.  Same
-        # engine shape both sides, only llm_prefix_sharing differs —
-        # the ratios isolate the sharing policy (sandbox protocol:
-        # ratios-only for timings; byte/percent counts are exact).
-        sys_prompt = [((i * 13) % 120) + 1 for i in range(64)]
-        plen = 68
-
-        def px_payload(kind):
-            def make(i):
-                if (i % 10) < 8:
-                    toks = sys_prompt + [1 + (i % 11), 2 + ((i * 3) % 13),
-                                         3 + ((i * 7) % 17), 4 + (i % 5)]
-                else:
-                    toks = [((i * 29 + j * 7) % 120) + 1
-                            for j in range(plen)]
-                return {"tokens": toks,
-                        "max_new_tokens": 16 + (i * 37) % 17,
-                        "request_id": f"{kind}{i}-{time.monotonic_ns()}"}
-            return make
-
-        for name, share in (("llm_px", True), ("llm_npx", False)):
-            serve.run(serve.llm_deployment(
-                name, max_ongoing_requests=streams + 8, max_batch=8,
-                num_pages=1 + 64 * pages_per_seq, max_queue=streams,
-                stream_flush_tokens=16, prefix_sharing=share,
-                **engine_kw))
-        _llm_stream_load(host, port, "/llm_px", 2, px_payload("w"))
-        _llm_stream_load(host, port, "/llm_npx", 2, px_payload("w"))
-        px_ttft, npx_ttft, n_req = [], [], 2  # warm streams count too
-        for _ in range(pairs):
-            toks, wall, ttfts, errs = _llm_stream_load(
-                host, port, "/llm_px", streams, px_payload("p"))
-            if errs:
-                raise RuntimeError(f"prefix-sharing run: {errs} errors")
-            px_ttft.append(p99(ttfts))
-            btoks, bwall, bttfts, berrs = _llm_stream_load(
-                host, port, "/llm_npx", streams, px_payload("n"))
-            if berrs:
-                raise RuntimeError(f"no-sharing run: {berrs} errors")
-            npx_ttft.append(p99(bttfts))
-            n_req += streams
-        px = ray_tpu.get(
-            serve.get_handle("llm_px").method("stats")(), timeout=30)
-        npx = ray_tpu.get(
-            serve.get_handle("llm_npx").method("stats")(), timeout=30)
-        # prefill tokens COMPUTED per request = prompt tokens submitted
-        # minus tokens attached from shared pages (acceptance: >= 2x
-        # drop vs the no-sharing engine at 80% shared)
-        px_prefill = (plen * n_req - px["prefix_tokens_shared"]) / n_req
-        npx_prefill = (plen * n_req - npx["prefix_tokens_shared"]) / n_req
-        out["llm_prefix_hit_pct"] = round(
-            100.0 * px["prefix_hits"] / n_req, 1)
-        out["llm_prefix_prefill_drop_x"] = round(
-            npx_prefill / px_prefill, 2)
-        out["llm_prefix_kv_bytes_per_stream"] = int(
-            px["pages_allocated_total"] * px["kv_page_bytes"] / n_req)
-        out["llm_nosharing_kv_bytes_per_stream"] = int(
-            npx["pages_allocated_total"] * npx["kv_page_bytes"] / n_req)
-        out["llm_prefix_kv_pages_drop_x"] = round(
-            npx["pages_allocated_total"] / px["pages_allocated_total"], 2)
-        out["llm_prefix_ttft_p99_vs_nosharing_x"] = round(
-            min(npx_ttft) / min(px_ttft), 2)
-
-        # ---- paged decode A/B (ISSUE 19): decode-step cost vs context.
-        # The Pallas paged kernel walks USED pages only, so (a) growing
-        # a config's max_seq_len 4x leaves short-context step cost
-        # ~flat, while the dense reference gathers + softmaxes the full
-        # [B, max_seq] context every step; (b) within one config, paged
-        # step cost follows the sequence's actual context length.
-        # In-process engines (no transport), alternating pairs,
-        # best-of; ratios only per the sandbox protocol — the driver
-        # box is authoritative for absolute step times.
-        ab_model = {"vocab_size": 128, "dim": 128, "n_layers": 2,
-                    "n_heads": 8, "n_kv_heads": 4, "hidden_dim": 256}
-
-        def mk_eng(impl, max_seq):
-            pps = -(-max_seq // 16)
-            return LLMEngine(model=dict(ab_model, max_seq_len=max_seq),
-                             page_size=16, prefill_chunk=32, seed=7,
-                             num_pages=1 + 8 * pps, max_batch=8,
-                             prefill_lanes=8, max_queue=64,
-                             attention_impl=impl)
-
-        def step_cost(eng, prompt_len, new_toks, tag):
-            p = [((i * 13) % 120) + 1 for i in range(prompt_len)]
-            reqs = [{"tokens": p, "max_new_tokens": new_toks,
-                     "request_id": f"{tag}-{i}"} for i in range(8)]
-            s0 = eng.stats()
-            eng.generate_batch(reqs)
-            s1 = eng.stats()
-            steps = s1["decode_steps"] - s0["decode_steps"]
-            return (s1["decode_secs"] - s0["decode_secs"]) / max(steps, 1)
-
-        grid = [(impl, ms) for impl in ("paged", "dense")
-                for ms in (128, 512)]
-        engines = {key: mk_eng(*key) for key in grid}
-        for key, eng in engines.items():
-            step_cost(eng, 16, 4, f"ab-warm-{key[0]}-{key[1]}")
-        cost = {key: min(step_cost(engines[key], 16, 32,
-                                   f"ab{r}-{key[0]}-{key[1]}")
-                         for r in range(pairs))
-                for key in grid}
-        pg = cost[("paged", 512)] / cost[("paged", 128)]
-        dg = cost[("dense", 512)] / cost[("dense", 128)]
-        # max context grew 4x: paged should be ~1x (sub-linear), dense
-        # heads toward 4x (linear in max context)
-        out["llm_decode_maxctx_growth_paged_x"] = round(pg, 2)
-        out["llm_decode_maxctx_growth_dense_x"] = round(dg, 2)
-        out["llm_decode_paged_vs_dense_growth_x"] = round(dg / pg, 2)
-        # step-latency-vs-USED-context curve at max_seq_len=512, each
-        # impl normalized to its own shortest-context point: paged
-        # follows used pages, dense sits at full-context cost from the
-        # first token
-        curve = {}
-        for impl in ("paged", "dense"):
-            pts = {plen: min(step_cost(engines[(impl, 512)], plen, 8,
-                                       f"cv{r}-{impl}-{plen}")
-                             for r in range(pairs))
-                   for plen in (16, 64, 160, 320)}
-            base = pts[16]
-            curve[impl] = {str(k): round(v / base, 2)
-                           for k, v in pts.items()}
-        out["llm_decode_step_vs_ctx_paged_x"] = curve["paged"]
-        out["llm_decode_step_vs_ctx_dense_x"] = curve["dense"]
-    finally:
-        try:
-            serve.shutdown_http()
-        except Exception:
-            pass
-        for name in ("llm_cb", "llm_sb", "llm_px", "llm_npx"):
             try:
                 serve.delete(name)
             except Exception:
@@ -1773,52 +1423,6 @@ def bench_chaos_subprocess():
         f"chaos bench rc={proc.returncode}: {proc.stderr[-400:]}")
 
 
-def _train_bench_loop():
-    """Runs in a watchdogged subprocess; prints one JSON line."""
-    import dataclasses
-
-    import jax
-
-    device = jax.devices()[0]
-    if device.platform != "tpu":
-        raise SystemExit(f"train bench needs a TPU; jax runs on "
-                         f"{device.platform!r}")
-    if device.device_kind not in PEAK_BF16_FLOPS:
-        raise SystemExit(f"no peak FLOP/s on record for device_kind "
-                         f"{device.device_kind!r}: add it to PEAK_BF16_FLOPS "
-                         f"with its source")
-    from ray_tpu.models.llama import LlamaConfig
-    from ray_tpu.parallel.mesh import MeshSpec, make_mesh, shard_batch
-    from ray_tpu.train.gspmd import build_llama_train_state, param_count
-
-    # ~600M params fills the v5e MXU; remat leaves HBM headroom
-    cfg = dataclasses.replace(LlamaConfig.bench_1b(), remat=True)
-    batch, seq, steps = 8, 1024, 20
-    mesh = make_mesh(MeshSpec(dp=-1), devices=jax.devices()[:1])
-    params, opt, step_fn, _ = build_llama_train_state(
-        cfg, mesh, batch_size=batch, seq_len=seq)
-    tokens = jax.random.randint(jax.random.PRNGKey(0), (batch, seq), 0,
-                                cfg.vocab_size, dtype="int32")
-    tokens = shard_batch(mesh, tokens)  # place once, outside the loop
-    for _ in range(3):  # compile + settle donation aliasing
-        params, opt, loss = step_fn(params, opt, tokens)
-    jax.block_until_ready(loss)
-    t0 = time.perf_counter()
-    for _ in range(steps):
-        params, opt, loss = step_fn(params, opt, tokens)
-    jax.block_until_ready(loss)
-    dt = time.perf_counter() - t0
-    tokens_per_s = steps * batch * seq / dt
-    n_params = param_count(params)
-    # MFU: 6 * params * tokens/s over the chip's peak bf16 FLOP/s
-    mfu = 6 * n_params * tokens_per_s / PEAK_BF16_FLOPS[device.device_kind]
-    print("TRAINJSON " + json.dumps(
-        {"platform": device.platform, "device_kind": device.device_kind,
-         "device_count": len(jax.devices()),
-         "train_tokens_per_s": round(tokens_per_s, 1),
-         "params": n_params, "mfu_pct": round(100 * mfu, 2),
-         "loss": float(loss)}))
-
 def _pipeline_bench_loop():
     """MPMD pipeline bench body: runs in a CPU-only subprocess
     (its own in-process cluster + 2 stage actors), prints one JSON line.
@@ -1923,26 +1527,6 @@ def bench_pipeline_subprocess():
         f"pipeline bench rc={proc.returncode}: {proc.stderr[-400:]}")
 
 
-def _run_train_subprocess(extras, errors):
-    """The train phase on the chip, under a hard deadline.  No fallback:
-    where it cannot run, the error is recorded and the run exits 1."""
-    from ray_tpu._private.spawn import compile_cache_env
-
-    try:
-        proc = subprocess.run(
-            [sys.executable, os.path.join(REPO, "bench.py"), "--train-bench"],
-            env={**os.environ, **compile_cache_env()}, capture_output=True,
-            text=True, timeout=480, cwd=REPO)
-        for line in proc.stdout.splitlines():
-            if line.startswith("TRAINJSON "):
-                extras.update(json.loads(line[len("TRAINJSON "):]))
-                return
-        raise RuntimeError(
-            f"train bench rc={proc.returncode}: {proc.stderr[-400:]}")
-    except Exception as exc:  # noqa: BLE001 — timeout, crash, no chip
-        errors["train"] = f"{type(exc).__name__}: {exc}"[:300]
-
-
 def main():
     sys.path.insert(0, REPO)
     import ray_tpu
@@ -2016,10 +1600,6 @@ def main():
         # phase() catches it and the internal asyncio drivers carry
         # their own hard timeouts
         phase("serve", lambda: extras.update(bench_serve(ray_tpu)))
-        # LLM serving tier LAST among in-cluster phases: its replicas
-        # hold resident KV pools + hundreds of exec threads, and the
-        # phase() guard keeps any serving wedge from zeroing the rest
-        phase("llm_serve", lambda: extras.update(bench_llm_serve(ray_tpu)))
         try:
             ray_tpu.shutdown()
         except Exception as exc:  # noqa: BLE001
@@ -2065,9 +1645,6 @@ def main():
     # channels vs the single-program baseline, best-of alternating pairs)
     phase("pipeline", lambda: extras.update(bench_pipeline_subprocess()))
 
-    # train runs AFTER shutdown so the chip is free for the subprocess
-    _run_train_subprocess(extras, errors)
-
     if errors:
         extras["errors"] = errors
     print(json.dumps({
@@ -2082,10 +1659,7 @@ def main():
 
 
 if __name__ == "__main__":
-    if "--train-bench" in sys.argv:
-        sys.path.insert(0, REPO)
-        _train_bench_loop()
-    elif "--pipeline-bench" in sys.argv:
+    if "--pipeline-bench" in sys.argv:
         sys.path.insert(0, REPO)
         _pipeline_bench_loop()
     elif "--locality-bench" in sys.argv:

@@ -6,8 +6,7 @@ full width with random weights from a seed:
 
   serve   ray_tpu.init() -> serve.llm_deployment(LlamaConfig at
           Llama-3-8B widths, depth cut to one 16 GB chip) -> serve.run ->
-          streamed requests, `paged` attention; then the same engine
-          under `attention_impl="dense"` on the same prompts.
+          streamed requests, decoded through the paged kernel.
   train   ray_tpu.init() -> JaxTrainer(ScalingConfig(use_tpu=True)) ->
           one Train worker actor -> build_llama_train_state -> steps.
 
@@ -263,11 +262,13 @@ class Streams:
         return self.tokens
 
 
-def stream_requests(handle, first, second, what: str):
+def stream_requests(handle, first, second, what: str) -> list:
     """The first wave all at once.  Then the first prompt of the second
     wave, decoding long enough to still be alive (pages are shared among
     live sequences) when the others, which share its prefix, arrive.
-    Request ids are "0", "1", ... in the order of `first + second`."""
+    Request ids are "0", "1", ... in the order of `first + second`;
+    returns their seconds to the first token, every request having come
+    back with the tokens it asked for (`Streams.join`)."""
     streams = Streams(handle, what)
     for i, p in enumerate(first):
         streams.start(str(i), p)
@@ -277,37 +278,8 @@ def stream_requests(handle, first, second, what: str):
     streams.wait_first_token(holder)
     for i, p in enumerate(second[1:], len(first) + 1):
         streams.start(str(i), p)
-    tokens = streams.join()
-    order = [str(i) for i in range(len(first) + len(second))]
-    return [tokens[r] for r in order], [streams.ttft[r] for r in order]
-
-
-def force_dense(handle, prompts, paged_tokens, dense_tokens) -> list:
-    """Teacher-force the dense engine with the paged engine's tokens.
-    One flipped argmax changes every token after it, so past the first
-    disagreement of a request nothing compares.  Ask the dense engine
-    again, the prompt now ending in paged's tokens up to and including
-    the one it disagreed with, until every paged token has a dense
-    counterpart computed from the same context.  Returns, for each
-    request, its segments `(start, rid, rows)`: rows `[0, rows)` of dense
-    request `rid` stand against paged's tokens from `start` on."""
-    segments = [[] for _ in prompts]
-    todo = [(i, 0, str(i), d) for i, d in enumerate(dense_tokens)]
-    while todo:
-        streams, again = Streams(handle, "llm-dense (forced)"), []
-        for i, start, rid, dense in todo:
-            want = paged_tokens[i][start:]
-            same = next((k for k, (a, b) in enumerate(zip(want, dense))
-                         if a != b), len(want))
-            segments[i].append((start, rid, min(same + 1, len(want))))
-            nxt = start + same + 1
-            if nxt < len(paged_tokens[i]):
-                again.append((i, nxt, f"{i}+{nxt}"))
-                streams.start(again[-1][2], prompts[i] + paged_tokens[i][:nxt],
-                              max_new=len(paged_tokens[i]) - nxt)
-        tokens = streams.join()
-        todo = [(i, nxt, rid, tokens[rid]) for i, nxt, rid in again]
-    return segments
+    streams.join()
+    return [streams.ttft[str(i)] for i in range(len(first) + len(second))]
 
 
 @ray_tpu.remote
@@ -322,70 +294,55 @@ def published_config(name: str) -> dict:
     cfg = getattr(LlamaConfig, name)()
     return {"platform": jax.devices()[0].platform,
             "model": {f.name: getattr(cfg, f.name)
-                      for f in dataclasses.fields(cfg) if f.name != "dtype"}}
+                      for f in dataclasses.fields(cfg)
+                      if "dtype" not in f.name}}
 
 
-def engine_kwargs(sz: Sizes, seed: int, impl: str, widths: dict) -> dict:
-    # logit_trace: the two largest logits behind every token, for
-    # compare_logits (two reductions and a host copy a step more than
-    # the serving default)
+def engine_kwargs(sz: Sizes, seed: int, widths: dict) -> dict:
     return dict(model={**widths, "n_layers": sz.serve_layers}, seed=seed,
-                max_batch=SERVE_MAX_BATCH, attention_impl=impl,
-                logit_trace=True)
+                max_batch=SERVE_MAX_BATCH)
 
 
-def check_engine(sz: Sizes, rep: dict, impl: str, widths: dict,
-                 where: str) -> None:
+def check_engine(sz: Sizes, rep: dict, widths: dict, where: str) -> None:
     check_device(sz, rep, 1, where, also=[
-        (rep["decode_has_tpu_custom_call"] == (impl == "paged"),
-         f"attention_impl={impl!r} and the lowered decode step "
-         f"{'holds a' if rep['decode_has_tpu_custom_call'] else 'holds no'} "
-         f"tpu_custom_call")])
+        (rep["decode_has_tpu_custom_call"],
+         "the lowered decode step holds no tpu_custom_call")])
     check({k: rep["model"][k] for k in widths} == widths
           and rep["model"]["n_layers"] == sz.serve_layers
           and rep["page_size"] == 16 and rep["dtype"] == "bfloat16",
           f"{where}: the engine runs {rep['model']}, page "
           f"{rep['page_size']}, {rep['dtype']} — not {sz.serve_model}'s "
           f"widths at {sz.serve_layers} layers")
-    check(rep["attention_impl"] == impl,
-          f"{where}: attention_impl={rep['attention_impl']!r}")
 
 
 def replica_call(replica, method: str, what: str, seconds: float = 300.0):
     return get(replica.handle_request.remote(method, (), {}), what, seconds)
 
 
-def serve_phase(sz: Sizes, seed: int, impl: str, widths: dict,
-                paged: dict = None) -> dict:
-    """One replica of the engine under `impl`, the requests, its checks;
-    gone when this returns.  Given the `paged` phase's result, the dense
-    engine is then teacher-forced with paged's tokens (`force_dense`)."""
-    name = f"llm-{impl}"
+def serve_phase(sz: Sizes, seed: int, widths: dict) -> None:
+    """One replica of the engine, the requests, its checks; gone when
+    this returns."""
+    name = "llm"
     t0 = time.monotonic()
     app = serve.llm_deployment(
         name, ray_actor_options={"resources": {"TPU": 1}},
-        **engine_kwargs(sz, seed, impl, widths))
+        **engine_kwargs(sz, seed, widths))
     handle = bounded(f"serve.run({name}): a TPU:1 replica to be scheduled, "
                      f"build its engine and warm up", 900, serve.run, app)
     ready_s = time.monotonic() - t0
     replica = handle._replicas[0]
     rep0 = replica_call(replica, "device_report", f"{name} device_report")
-    check_engine(sz, rep0, impl, widths, name)
+    check_engine(sz, rep0, widths, name)
     stats0 = replica_call(replica, "stats", f"{name} stats")
     first, second = serve_requests(sz, seed, widths["vocab_size"])
     t1 = time.monotonic()
-    tokens, ttft = stream_requests(handle, first, second, name)
+    ttft = stream_requests(handle, first, second, name)
     requests_s = time.monotonic() - t1
     stats = replica_call(replica, "stats", f"{name} stats")
-    segments = None
-    if paged is not None:
-        segments = force_dense(handle, first + second, paged["tokens"],
-                               tokens)
     rep1 = replica_call(replica, "device_report", f"{name} device_report")
-    check((stats["attention_impl"], stats["platform"], stats["kernel_mode"])
-          == (impl, rep0["platform"], rep0["kernel_mode"]),
-          f"{name}: stats() reports attention_impl="
-          f"{stats['attention_impl']!r} on {stats['platform']!r}, kernels "
+    check((stats["platform"], stats["kernel_mode"])
+          == (rep0["platform"], rep0["kernel_mode"]),
+          f"{name}: stats() reports {stats['platform']!r}, kernels "
           f"{stats['kernel_mode']!r}; device_report() {rep0['platform']!r}, "
           f"{rep0['kernel_mode']!r}")
     check(stats["prefix_hits"] >= len(second) - 1,
@@ -398,12 +355,10 @@ def serve_phase(sz: Sizes, seed: int, impl: str, widths: dict,
     serve.delete(name)
     wait_chips_free(1, f"the {name} replica (pid {pid})")
     check(pid_gone(pid), f"{name}: replica process {pid} outlived its lease")
-    emit(f"serve_{impl}", model=sz.serve_model, layers=sz.serve_layers,
+    emit("serve", model=sz.serve_model, layers=sz.serve_layers,
          max_batch=SERVE_MAX_BATCH,
          requests=len(first) + len(second),
          prompt_lens=[len(p) for p in first + second],
-         forced_requests=None if segments is None
-         else sum(len(s) - 1 for s in segments),
          param_bytes=rep1["param_bytes"], kv_pool_bytes=rep1["kv_pool_bytes"],
          peak_bytes_in_use=rep1["peak_bytes_in_use"],
          compiled_steps=rep1["compiled_steps"],
@@ -421,90 +376,6 @@ def serve_phase(sz: Sizes, seed: int, impl: str, widths: dict,
          wall_s=time.monotonic() - t0,
          device={"platform": rep1["platform"], "kind": rep1["device_kind"],
                  "count": rep1["device_count"]})
-    return {"tokens": tokens, "trace": rep1["logit_trace"],
-            "segments": segments, "report": rep1}
-
-
-# How far two bfloat16 programs that compute the same thing may part, in
-# units of bfloat16's spacing at the logit's size (the lm_head's output
-# is bfloat16, so the logits lie on that grid).  `paged` keeps softmax
-# and its sum in float32 inside the kernel, `dense` rounds probabilities
-# to bfloat16 before the value matmul: about 2^-9 relative noise a layer
-# in the residual stream, sqrt(8) layers of it, is well under one
-# spacing at a top logit's size, and rounding to the grid adds at most
-# one.  A read of the wrong page or a wrong mask moves the attention
-# output by the share of the context misread, not by a rounding.
-LOGIT_TOL_ULPS = 4.0
-LOGIT_MEAN_ULPS = 1.0
-
-
-def bf16_ulp(x: float) -> float:
-    """The spacing of bfloat16 (8 significant bits) at the size of x."""
-    return 2.0 ** (math.floor(math.log2(max(abs(x), 2.0 ** -100))) - 7)
-
-
-def compare_logits(paged: dict, dense: dict) -> None:
-    """Every token the paged engine emitted against the dense engine's
-    step from the same context (`force_dense`): where both picked the
-    same token its logit, and the runner-up's when that agrees too, must
-    lie within LOGIT_TOL_ULPS; where they picked differently, each one's
-    pick must be the other's runner-up and both logits within that
-    tolerance — which bounds the top-two margin by twice the tolerance.
-    A flip the rounding cannot explain fails here."""
-    ulps, flips, identical = [], [], 0
-    for i, toks in enumerate(paged["tokens"]):
-        rows_p = paged["trace"][str(i)]
-        check([r[0] for r in rows_p] == list(range(len(toks)))
-              and [r[2] for r in rows_p] == toks,
-              f"request {i}: the paged engine's trace {rows_p} does not "
-              f"follow its tokens {toks}")
-        identical += len(dense["segments"][i]) == 1
-        covered = 0
-        for start, rid, n in dense["segments"][i]:
-            check(start == covered, f"request {i}: dense segments "
-                                    f"{dense['segments'][i]} leave a gap")
-            for k, rd in enumerate(dense["trace"][rid][:n]):
-                at = f"request {i}, token {start + k}"
-                _, lp1, ip1, lp2, ip2 = rows_p[start + k]
-                _, ld1, id1, ld2, id2 = rd
-                if ip1 == id1:
-                    pairs = [(lp1, ld1)] + ([(lp2, ld2)] if ip2 == id2 else [])
-                else:
-                    check((ip1, ip2) == (id2, id1),
-                          f"{at}: paged picks {ip1} over {ip2} and dense "
-                          f"{id1} over {id2}: not a swap of the top two")
-                    pairs = [(lp1, ld2), (lp2, ld1)]
-                    flips.append({"request": i, "token": start + k,
-                                  "paged_margin_ulps":
-                                      (lp1 - lp2) / bf16_ulp(lp1),
-                                  "dense_margin_ulps":
-                                      (ld1 - ld2) / bf16_ulp(ld1)})
-                for a, b in pairs:
-                    ulps.append(abs(a - b) / bf16_ulp(max(abs(a), abs(b))))
-                    check(ulps[-1] <= LOGIT_TOL_ULPS,
-                          f"{at}: paged logit {a!r} and dense logit {b!r} "
-                          f"for one token lie {ulps[-1]:.1f} bfloat16 "
-                          f"spacings apart (tolerance {LOGIT_TOL_ULPS:g})")
-            covered = start + n
-        check(covered == len(toks), f"request {i}: {covered} of {len(toks)} "
-                                    f"paged tokens have a dense counterpart")
-    mean = sum(ulps) / len(ulps)
-    emit("serve_compare", requests=len(paged["tokens"]),
-         identical_requests=identical,
-         tokens_compared=sum(len(t) for t in paged["tokens"]),
-         logits_compared=len(ulps), flips=flips,
-         tolerance_ulps=LOGIT_TOL_ULPS, max_ulps=max(ulps), mean_ulps=mean,
-         ulps_histogram={str(b): sum(1 for u in ulps if round(u) == b)
-                         for b in sorted({round(u) for u in ulps})})
-    for f in flips:
-        check(max(f["paged_margin_ulps"], f["dense_margin_ulps"])
-              <= 2 * LOGIT_TOL_ULPS, f"a flip past the tolerance: {f}")
-    check(mean <= LOGIT_MEAN_ULPS,
-          f"paged and dense logits lie {mean:.2f} bfloat16 spacings apart "
-          f"on average over {len(ulps)} (bound {LOGIT_MEAN_ULPS:g}): a "
-          f"bias, not a rounding")
-
-
 # ------------------------------------------------------------------ train
 
 
@@ -677,7 +548,7 @@ def four_chip_serve(sz: Sizes, seed: int, widths: dict) -> dict:
     app = serve.llm_deployment(
         name, num_replicas=4,
         ray_actor_options={"resources": {"TPU": 1}},
-        **engine_kwargs(sz, seed, "paged", widths))
+        **engine_kwargs(sz, seed, widths))
     handle = bounded(f"serve.run({name}): four TPU:1 replicas to be "
                      f"scheduled, build their engines and warm up",
                      600, serve.run, app)
@@ -687,7 +558,7 @@ def four_chip_serve(sz: Sizes, seed: int, widths: dict) -> dict:
     reps = [replica_call(r, "device_report", f"{name} device_report")
             for r in handle._replicas]
     for rep in reps:
-        check_engine(sz, rep, "paged", widths, name)
+        check_engine(sz, rep, widths, name)
     chips = [rep["visible_chips"] for rep in reps]
     check(len(set(chips)) == 4 and len({rep["pid"] for rep in reps}) == 4,
           f"{name}: replicas hold chips {chips}")
@@ -766,9 +637,7 @@ def main() -> int:
                  "JAX_COMPILATION_CACHE_DIR"))
         probe_phase(sz, args.chips)
         if args.chips == 1:
-            paged = serve_phase(sz, args.seed, "paged", widths)
-            dense = serve_phase(sz, args.seed, "dense", widths, paged)
-            compare_logits(paged, dense)
+            serve_phase(sz, args.seed, widths)
             rep = train_phase(sz, args.seed)
         else:
             rep = four_chip_train(sz, args.seed)

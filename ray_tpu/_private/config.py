@@ -195,31 +195,8 @@ _def("actor_state_keep", 2)
 _def("serve_replica_health_timeout_s", 120.0)
 _def("serve_dead_replica_retries", 3)
 # --- LLM serving tier (see serve/llm.py) -------------------------------------
-_def("llm_page_size", 16)           # KV-cache tokens per page
-_def("llm_kv_pages", 0)             # pages per replica; 0 = sized so
-# max_batch sequences can run at max_seq_len simultaneously
-_def("llm_max_batch_size", 32)      # decode lanes per engine step
-_def("llm_prefill_chunk", 64)       # prompt tokens prefetched per step —
-# bounds how long one long prompt can stall in-flight decodes
-_def("llm_prefill_lanes", 8)        # sequences prefilling one chunk each
-# per step (batched prefill: admitting N streams costs N/lanes steps)
-_def("llm_stream_flush_tokens", 4)  # tokens coalesced per stream item
-# after the first (the first token flushes immediately for TTFT); each
-# item costs a stream push + a ref resolution + an SSE chunk, so this
-# is the per-token transport amortizer
-_def("llm_admission_queue", 256)    # queued sequences before 503 shed
-_def("llm_detach_grace_s", 2.0)     # KV pages survive a vanished consumer
-# this long (the re-attach window for proxy resume) before recycling
 _def("llm_done_seq_ttl_s", 30.0)    # finished sequences replayable (by
 # request_id) this long for duplicate/late retries
-_def("llm_prefix_sharing", True)    # copy-on-write prefix sharing: admit
-# sequences whose page-aligned prompt prefix matches a live sequence's
-# onto the SAME physical KV pages (refcounted; recycled at refcount 0),
-# prefilling only from the first unshared token
-_def("llm_attention_impl", "auto")  # decode attention: "paged" = Pallas
-# paged-attention kernel over block tables (cost tracks USED context),
-# "dense" = gather-then-dense reference (cost tracks max context),
-# "auto" = paged
 _def("llm_disagg_min_prompt", 0)    # disaggregated prefill: prompts at
 # least this long route their prefill to the dedicated prefill pool
 # (when llm_deployment(prefill_replicas=N) created one); shorter
@@ -252,9 +229,6 @@ _def("serve_autoscale_max_replicas", 8)
 # consecutive reconcile rounds; downscale needs it below for this long
 _def("serve_autoscale_up_consecutive", 2)
 _def("serve_autoscale_down_delay_s", 10.0)
-# --- LLM sampling (jit-static decode knobs; see serve/llm.py) ----------------
-_def("llm_temperature", 0.0)  # 0 = greedy argmax (the decode-identity tier)
-_def("llm_top_k", 0)          # 0 = full vocab; >0 = sample among top-k
 # --- end-to-end deadlines (see _private/deadlines.py) ------------------------
 # owner-side deadline sweep cadence: how often queued/in-flight tasks
 # with deadlines are checked (the sweep only runs while any exist)

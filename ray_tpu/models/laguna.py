@@ -294,8 +294,8 @@ class GatedAttention(nn.Module):
             pool_k = cache["k"].at[flat].set(k.reshape(b * s, *k.shape[2:]))
             pool_v = cache["v"].at[flat].set(v.reshape(b * s, *v.shape[2:]))
             pools = (pool_k, pool_v)
-            if cache.get("block_tables") is not None and s == 1 \
-                    and self.page_size > 0:
+            if cache.get("block_tables") is not None:
+                # the form a decode pass's group carries (llama.py)
                 from ray_tpu.ops.paged_attention import paged_attention
 
                 out = paged_attention(
@@ -424,7 +424,7 @@ class LagunaModel(nn.Module):
         return logits, {"k": new_k, "v": new_v}, vec
 
 
-def build(cfg: LagunaConfig, page_size: int = 0) -> LagunaModel:
+def build(cfg: LagunaConfig, page_size: int) -> LagunaModel:
     return LagunaModel(cfg, page_size=page_size)
 
 

@@ -25,11 +25,11 @@ context-gather index arrays; the serving engine owns the block tables
 that map sequence positions to physical page slots.  New keys/values
 are written post-rope at their absolute positions.  Chunked prefill
 gathers context dense per sequence with a position mask
-(``ctx_pos <= q_pos``) for causality; single-token decode can instead
-carry page-granular block tables + context lengths and route through
-the Pallas paged-attention kernel (ray_tpu/ops/paged_attention.py),
-which reads used pages only — no dense gather.  Both ride static
-shapes.
+(``ctx_pos <= q_pos``) for causality; single-token decode carries
+page-granular block tables + context lengths and goes through the
+Pallas paged-attention kernel (ray_tpu/ops/paged_attention.py), which
+reads used pages only — no dense gather.  The model takes the form its
+cache group carries.  Both ride static shapes.
 """
 
 from __future__ import annotations
@@ -244,7 +244,8 @@ class RMSNorm(nn.Module):
 class Attention(nn.Module):
     cfg: LlamaConfig
     kernel: Optional[Callable] = None  # pluggable (flash/ring) attention
-    page_size: int = 0  # > 0 enables the paged decode kernel
+    page_size: int = 0  # the engine's: what the paged kernel cuts the
+    # pools by.  A trainer's module has no cache and leaves it unset
 
     @nn.compact
     def __call__(self, x, positions, cache=None):
@@ -269,10 +270,10 @@ class Attention(nn.Module):
                 k.reshape(b * s, *k.shape[2:]))
             pool_v = cache["v"].at[flat].set(
                 v.reshape(b * s, *v.shape[2:]))
-            if cache.get("block_tables") is not None and s == 1 \
-                    and self.page_size > 0:
-                # decode via the Pallas paged kernel: page-granular
-                # block tables + context lengths, no dense gather
+            if cache.get("block_tables") is not None:
+                # the form a decode pass's group carries: page-granular
+                # block tables + context lengths, through the Pallas
+                # paged kernel (one query a lane), no gather
                 from ray_tpu.ops.paged_attention import paged_attention
 
                 out = paged_attention(q, pool_k, pool_v,
@@ -352,9 +353,9 @@ class LlamaModel(nn.Module):
             # positions come from the engine, per-layer pools are
             # threaded through and returned updated.  Every layer is of
             # the cache kind "full", whose group carries the write slots
-            # and EITHER dense gather arrays (ctx/ctx_pos/ctx_mask —
-            # chunked prefill, or dense decode) OR page-granular block
-            # tables + context lengths (paged decode kernel).
+            # and EITHER gather arrays (ctx/ctx_pos/ctx_mask — chunked
+            # prefill) OR page-granular block tables + context lengths
+            # (decode, through the paged kernel).
             positions = cache["q_pos"]
             new_k, new_v = [], []
             for i in range(cfg.n_layers):
@@ -430,7 +431,7 @@ def kv_pool_bytes(cfg: LlamaConfig, num_slots: int) -> int:
             * cfg.head_dim * itemsize)
 
 
-def build(cfg: LlamaConfig, page_size: int = 0) -> LlamaModel:
+def build(cfg: LlamaConfig, page_size: int) -> LlamaModel:
     """The serving module of this family (models/__init__.py).  It keeps
     no master weights: its matrices are declared in `cfg.dtype`, what
     the forward multiplies by, and `init` draws them as a float32

@@ -37,10 +37,10 @@ Admission is a bounded head-of-line queue: a full queue (or a prompt
 that can never fit the page budget) raises :class:`LLMOverloadedError`,
 which the proxy maps to the PR-3 503 shed gate.  Sequences whose
 consumer vanished (SSE disconnect -> generator cancel) keep their pages
-only for ``llm_detach_grace_s`` — the re-attach window for transparent
+only for ``detach_grace_s`` — the re-attach window for transparent
 resume — then are cancelled and recycled.
 
-Copy-on-write prefix sharing (``llm_prefix_sharing``): page-aligned
+Copy-on-write prefix sharing (``prefix_sharing``): page-aligned
 token-prefix blocks are hashed into a per-engine refcounted prefix
 index as prefill completes them; a new sequence whose prompt prefix
 matches attaches to the SAME physical pages (refcount + 1, recycled
@@ -105,6 +105,26 @@ _SHIP = "ship"  # prefill-only sequence whose pages were just exported
 
 _forward_cache: Dict[int, Any] = {}
 
+# The defaults of `LLMEngine`'s arguments, the one place they are set.
+PAGE_SIZE = 16          # KV-cache tokens per page
+MAX_BATCH = 32          # decode lanes per engine step
+PREFILL_CHUNK = 64      # prompt tokens prefilled per step: bounds how long
+# one long prompt can stall in-flight decodes
+PREFILL_LANES = 8       # sequences prefilling one chunk each per step
+# (batched prefill: admitting N streams costs N/lanes steps)
+STREAM_FLUSH_TOKENS = 4  # tokens coalesced per stream item after the
+# first (the first token flushes immediately for TTFT); each item costs a
+# stream push + a ref resolution + an SSE chunk, so this is the per-token
+# transport amortizer
+MAX_QUEUE = 256         # queued sequences before 503 shed
+DETACH_GRACE_S = 2.0    # KV pages survive a vanished consumer this long
+# (the re-attach window for proxy resume) before recycling
+# The rest are literals in the signature: `num_pages=None` is sized so
+# that max_batch sequences can run at max_seq_len at once;
+# `prefix_sharing=True` admits a sequence whose page-aligned prompt prefix
+# matches a live one's onto the SAME physical pages; `temperature=0.0`,
+# `top_k=0` are greedy argmax over the full vocabulary.
+
 # the phases of one engine step, in the order a step passes them: the
 # key of `stats()["phase_secs"]` -> the span on the profiler's clock.
 # `admit` is the first lock section; of each pass, `build` is the numpy
@@ -157,10 +177,10 @@ def _jit_forward(model, params, k, v, tokens, q_pos, last_idx, groups,
     ``groups`` maps each cache kind of the model (models/cache.py) to
     that kind's arrays: the write ``slots`` and the context in one of
     two forms — a pytree-structure change, so each form is its own
-    trace: dense ``ctx``/``ctx_pos``/``ctx_mask`` gather arrays (chunked
-    prefill, dense decode), or page-granular ``block_tables`` +
-    ``context_lens`` (a window kind also ``starts``) routing decode
-    through the Pallas paged-attention kernel.
+    trace: ``ctx``/``ctx_pos``/``ctx_mask`` gather arrays (chunked
+    prefill), or page-granular ``block_tables`` + ``context_lens`` (a
+    window kind also ``starts``), which take decode through the Pallas
+    paged-attention kernel.
 
     Sampling is a pair of jit-STATIC knobs (ISSUE 13 satellite / PR-11
     declared headroom (d)): ``temperature == 0`` compiles the exact
@@ -435,7 +455,7 @@ class LLMEngine:
     One engine per replica.  The pinned loop (``run_loop``) is the ONLY
     caller of ``step()`` in serving; request threads touch the engine
     only through ``submit``/``iter_tokens``/``release`` under the
-    engine lock.  (The static-batching bench baseline instead drives
+    engine lock.  (``warm_up`` and the tests instead drive
     ``generate_batch`` inline — an engine is stepped by its loop OR
     inline, never both.)
 
@@ -449,25 +469,23 @@ class LLMEngine:
 
     def __init__(self, cfg=None, *, model: Any = "tiny",
                  params: Any = None, seed: int = 0,
-                 page_size: Optional[int] = None,
+                 page_size: int = PAGE_SIZE,
                  num_pages: Optional[int] = None,
-                 max_batch: Optional[int] = None,
-                 prefill_chunk: Optional[int] = None,
-                 max_queue: Optional[int] = None,
-                 detach_grace_s: Optional[float] = None,
-                 prefill_lanes: Optional[int] = None,
-                 stream_flush_tokens: Optional[int] = None,
+                 max_batch: int = MAX_BATCH,
+                 prefill_chunk: int = PREFILL_CHUNK,
+                 max_queue: int = MAX_QUEUE,
+                 detach_grace_s: float = DETACH_GRACE_S,
+                 prefill_lanes: int = PREFILL_LANES,
+                 stream_flush_tokens: int = STREAM_FLUSH_TOKENS,
                  dtype: Any = None,
-                 temperature: Optional[float] = None,
-                 top_k: Optional[int] = None,
-                 prefix_sharing: Optional[bool] = None,
-                 attention_impl: Optional[str] = None,
+                 temperature: float = 0.0,
+                 top_k: int = 0,
+                 prefix_sharing: bool = True,
                  logit_trace: bool = False):
         import jax
         import jax.numpy as jnp
         import numpy as np
 
-        from ray_tpu._private.config import config
         from ray_tpu.models import cache as kv_cache, resolve
         from ray_tpu.ops import count_compile_cache_events, kernel_mode
 
@@ -493,39 +511,21 @@ class LLMEngine:
 
             cfg = dataclasses.replace(cfg, dtype=dtype)
         self.cfg = cfg
-        self.page_size = int(page_size or config.llm_page_size)
-        self.max_batch = int(max_batch or config.llm_max_batch_size)
-        self.prefill_chunk = int(prefill_chunk or config.llm_prefill_chunk)
-        self.max_queue = int(max_queue or config.llm_admission_queue)
-        self.detach_grace_s = float(
-            detach_grace_s if detach_grace_s is not None
-            else config.llm_detach_grace_s)
-        self.prefill_lanes = max(1, min(
-            int(prefill_lanes or config.llm_prefill_lanes),
-            self.max_batch))
-        self.stream_flush_tokens = max(1, int(
-            stream_flush_tokens or config.llm_stream_flush_tokens))
+        self.page_size = int(page_size)
+        self.max_batch = int(max_batch)
+        self.prefill_chunk = int(prefill_chunk)
+        self.max_queue = int(max_queue)
+        self.detach_grace_s = float(detach_grace_s)
+        self.prefill_lanes = max(1, min(int(prefill_lanes), self.max_batch))
+        self.stream_flush_tokens = max(1, int(stream_flush_tokens))
         self.pages_per_seq = -(-cfg.max_seq_len // self.page_size)
         if num_pages is None:
-            num_pages = int(config.llm_kv_pages) or (
-                1 + self.max_batch * self.pages_per_seq)
-        # +1: page 0 is the garbage page, never allocated
+            # sized so max_batch sequences can run at max_seq_len at
+            # once; +1: page 0 is the garbage page, never allocated
+            num_pages = 1 + self.max_batch * self.pages_per_seq
         self.num_pages = max(int(num_pages), 2)
         self.ctx_len = self.pages_per_seq * self.page_size
-
-        # decode attention implementation: "paged" routes decode steps
-        # through the Pallas paged-attention kernel (block tables +
-        # context lengths, cost tracks used context); "dense" keeps the
-        # gather-then-dense reference (cost tracks max context).
-        impl = str(attention_impl or config.llm_attention_impl).lower()
-        if impl == "auto":
-            impl = "paged"
-        if impl not in ("paged", "dense"):
-            raise ValueError(
-                f"llm_attention_impl must be auto|paged|dense, got {impl!r}")
-        self.attention_impl = impl
-        self._model = self.family.build(
-            cfg, page_size=self.page_size if impl == "paged" else 0)
+        self._model = self.family.build(cfg, self.page_size)
         # the cache, by what the model's specification says: the kind of
         # each layer, and a page group for each window kind (the "full"
         # group is this engine's own pages, block tables and index)
@@ -581,12 +581,10 @@ class LLMEngine:
         # sampling knobs are jit-STATIC: temperature=0 (the default)
         # compiles the exact greedy program the decode-identity gate
         # covers; >0 adds temperature scaling + optional top-k masking
-        # + categorical sampling, seeded per engine so a fixed seed
-        # replays the same stream
-        self.temperature = float(
-            temperature if temperature is not None
-            else config.llm_temperature)
-        self.top_k = int(top_k if top_k is not None else config.llm_top_k)
+        # (0 = the full vocabulary) + categorical sampling, seeded per
+        # engine so a fixed seed replays the same stream
+        self.temperature = float(temperature)
+        self.top_k = int(top_k)
         self._sample_rng = (jax.random.PRNGKey(int(seed))
                             if self.temperature > 0 else None)
         # the jitted stepper is shared process-wide (_jit_forward keys
@@ -606,9 +604,7 @@ class LLMEngine:
         # immutable page holding that block's KV; _children groups
         # registered pages under their parent-chain hash so a mid-page
         # divergence can find its copy-on-write source.
-        self.prefix_sharing = bool(
-            prefix_sharing if prefix_sharing is not None
-            else config.llm_prefix_sharing)
+        self.prefix_sharing = bool(prefix_sharing)
         # a prefix is reusable only where the window layers still hold
         # the positions before its end, and they have given them back:
         # refused, not silently wrong (stats()["prefix_sharing"])
@@ -642,7 +638,7 @@ class LLMEngine:
         self._warm = False
         self._paged_warm = False
         self._prefill_warm = False
-        # pass accumulators (bench A/B reads mean step cost as a delta
+        # pass accumulators (a reader takes mean step cost as a delta
         # between two stats() snapshots): a pass's seconds are its build,
         # dispatch and sync; `_clock` has every phase and the whole step
         self._decode_steps = 0
@@ -905,19 +901,20 @@ class LLMEngine:
         return next_tok[:lanes]
 
     def _window_arrays(self, rows, lanes: int, cols: int, width: int,
-                       paged: bool = False):
+                       decode: bool = False):
         """The window kinds' arrays of one pass of `lanes` x `cols`
-        queries.  `rows` = [(lane, the sequence's windows, lo, hi)]: the
-        lane's queries are at [lo, hi); a lane without a row is garbage
-        (slot 0, nothing to see).  Dense form: the context is the last
-        `min(width, group.ctx_width)` positions before `hi`; paged form
-        (decode, hi = lo + 1): the window's pages, `min(width,
+        queries, in the form of its kind of pass.  `rows` = [(lane, the
+        sequence's windows, lo, hi)]: the lane's queries are at [lo, hi);
+        a lane without a row is garbage (slot 0, nothing to see).  A
+        prefill pass gathers: the context is the last `min(width,
+        group.ctx_width)` positions before `hi`.  A decode pass (hi = lo
+        + 1) takes block tables: the window's pages, `min(width,
         group.table_width)` of them, from position `starts` on."""
         np = self._np
         out = {}
         for kind, group in self._windows.items():
             slots = np.zeros((lanes, cols), np.int32)
-            if paged:
+            if decode:
                 w = min(width, group.table_width)
                 tables = np.zeros((lanes, w), np.int32)
                 starts = np.zeros((lanes,), np.int32)
@@ -955,7 +952,7 @@ class LLMEngine:
                  float(vals[lane, 1]), int(ids[lane, 1])])
 
     def _paged_width_buckets(self) -> List[int]:
-        """Block-table width buckets the paged decode path can emit:
+        """Block-table width buckets the decode pass can emit:
         powers of four from 4 up to (and capped at) pages_per_seq.
         Coarser-than-pow-2 buckets trade at most a 4x width overshoot
         at small contexts (cheap: unused pages are predicated off and
@@ -1007,8 +1004,8 @@ class LLMEngine:
             _tok, self._pools = self._forward(*args, **kwargs)
 
     def _garbage_decode_args(self, width: int):
-        """`_forward` arguments for a paged decode step of garbage lanes
-        only (slot 0, context length 0) at block-table `width`."""
+        """`_forward` arguments for a decode step of garbage lanes only
+        (slot 0, context length 0) at block-table `width`."""
         np = self._np
         b = self.max_batch
         zeros1 = np.zeros((b, 1), np.int32)
@@ -1017,15 +1014,15 @@ class LLMEngine:
                 {"block_tables": np.zeros((b, width), np.int32),
                  "context_lens": np.zeros((b,), np.int32),
                  "windows": self._window_arrays([], b, 1, width,
-                                                paged=True)})
+                                                decode=True)})
 
     def device_report(self) -> Dict[str, Any]:
         """`ops.device_report()` plus what this engine put on the device
         (`param_bytes`: the tree as held, in the dtypes the serving module
         declares) and how its decode step lowered: the Pallas kernel is a
         `tpu_custom_call` when compiled for the chip, and absent from the
-        text under the interpreter or `attention_impl="dense"`.  Traces
-        the decode step once more (nothing runs); not for a hot path."""
+        text under the interpreter.  Traces the decode step once more
+        (nothing runs); not for a hot path."""
         import dataclasses
 
         import jax
@@ -1037,7 +1034,10 @@ class LLMEngine:
 
         rep = device_report()
         share = getattr(self.cfg, "share", None)
-        rep.update(attention_impl=self.attention_impl,
+        # `attention_impl` is a constant: the engine has one decode path.
+        # benchmarks/kinds/serve.py and serve_laguna.py index it; theirs
+        # to drop (the next `benchmark` PR), then this line's too
+        rep.update(attention_impl="paged",
                    # the config's fields, and: the family, what of each
                    # layer this chip holds (a config that is a share
                    # says), the cache specification by layer
@@ -1060,21 +1060,19 @@ class LLMEngine:
             with self._lock:
                 rep["logit_trace"] = {rid: list(rows) for rid, rows
                                       in self._logit_trace.items()}
-        rep["decode_has_tpu_custom_call"] = False
-        if self.attention_impl == "paged":
-            args, kwargs = self._garbage_decode_args(
-                self._paged_width_buckets()[0])
-            tokens, slots, _c, _p, _m, q_pos, last_idx = args
-            text = _jitted_forward(self.temperature, self.top_k,
-                                   self.logit_trace).lower(
-                self._model, self._params, self._pools["k"],
-                self._pools["v"], tokens, q_pos, last_idx,
-                jax.numpy.zeros((2,), dtype="uint32"),  # rng, unused
-                {"full": {"slots": slots,
-                          "block_tables": kwargs["block_tables"],
-                          "context_lens": kwargs["context_lens"]},
-                 **kwargs["windows"]}).as_text()
-            rep["decode_has_tpu_custom_call"] = "tpu_custom_call" in text
+        args, kwargs = self._garbage_decode_args(
+            self._paged_width_buckets()[0])
+        tokens, slots, _c, _p, _m, q_pos, last_idx = args
+        text = _jitted_forward(self.temperature, self.top_k,
+                               self.logit_trace).lower(
+            self._model, self._params, self._pools["k"],
+            self._pools["v"], tokens, q_pos, last_idx,
+            jax.numpy.zeros((2,), dtype="uint32"),  # rng, unused
+            {"full": {"slots": slots,
+                      "block_tables": kwargs["block_tables"],
+                      "context_lens": kwargs["context_lens"]},
+             **kwargs["windows"]}).as_text()
+        rep["decode_has_tpu_custom_call"] = "tpu_custom_call" in text
         return rep
 
     def _alloc_pages(self, n: int) -> List[int]:
@@ -1533,7 +1531,6 @@ class LLMEngine:
                 # CoW split may rewrite entries after we release it
                 decode_args.append(
                     (seq, last, seq.slot_cache[seq.pos],
-                     seq.slot_cache[:seq.pos + 1],
                      list(seq.block_table), seq.pos + 1))
         step_tokens = 0
         # ---- chunked prefill, batched across lanes: up to
@@ -1621,61 +1618,39 @@ class LLMEngine:
             slot_arr = np.zeros((b, 1), np.int32)
             q_pos = np.zeros((b, 1), np.int32)
             last_idx = np.zeros((b,), np.int32)
-            if self.attention_impl == "paged" and not self._paged_warm:
+            if not self._paged_warm:
                 self._paged_warm = True
                 self._warm_paged_buckets()
                 # the one-time warm-up is this phase's, not decode_secs'
                 t_dec = time.perf_counter()
-            if self.attention_impl == "paged":
-                # page-granular context: block tables + context lengths
-                # instead of [B, ctx_len] gather/mask arrays.  The table
-                # width snaps to the smallest _paged_width_buckets()
-                # entry covering the max used pages across lanes:
-                # decode cost tracks USED context, and the jit retrace
-                # per bucket is O(log pages_per_seq) traces total.
-                max_used = max(-(-n // self.page_size)
-                               for *_a, n in decode_args)
-                width = next(w for w in self._paged_width_buckets()
-                             if w >= max_used)
-                block_tables = np.zeros((b, width), np.int32)
-                context_lens = np.zeros((b,), np.int32)
-                for lane, (seq, last, slot, _ctx, table, n) \
-                        in enumerate(decode_args):
-                    tokens[lane, 0] = last
-                    slot_arr[lane, 0] = slot
-                    used = -(-n // self.page_size)
-                    block_tables[lane, :used] = table[:used]
-                    context_lens[lane] = n
-                    q_pos[lane, 0] = seq.pos
-                windows = self._window_arrays(
-                    [(lane, seq.windows, n - 1, n) for lane, (seq, *_a, n)
-                     in enumerate(decode_args)], b, 1, width, paged=True) \
-                    if self._windows else None
-                phase("decode_dispatch")
-                next_tok, self._pools = self._forward(
-                    tokens, slot_arr, None, None, None, q_pos, last_idx,
-                    block_tables=block_tables, context_lens=context_lens,
-                    windows=windows)
-            else:
-                ctx = np.zeros((b, self.ctx_len), np.int32)
-                ctx_pos = np.zeros((b, self.ctx_len), np.int32)
-                ctx_mask = np.zeros((b, self.ctx_len), bool)
-                for lane, (seq, last, slot, ctx_slots, _table, n) \
-                        in enumerate(decode_args):
-                    tokens[lane, 0] = last
-                    slot_arr[lane, 0] = slot
-                    ctx[lane, :n] = ctx_slots
-                    ctx_pos[lane, :n] = self._arange[:n]
-                    ctx_mask[lane, :n] = True
-                    q_pos[lane, 0] = seq.pos
-                windows = self._window_arrays(
-                    [(lane, seq.windows, n - 1, n) for lane, (seq, *_a, n)
-                     in enumerate(decode_args)], b, 1, self.ctx_len) \
-                    if self._windows else None
-                phase("decode_dispatch")
-                next_tok, self._pools = self._forward(
-                    tokens, slot_arr, ctx, ctx_pos, ctx_mask, q_pos,
-                    last_idx, windows=windows)
+            # page-granular context: block tables + context lengths.
+            # The table width snaps to the smallest
+            # _paged_width_buckets() entry covering the max used pages
+            # across lanes: decode cost tracks USED context, and the jit
+            # retrace per bucket is O(log pages_per_seq) traces total.
+            max_used = max(-(-n // self.page_size)
+                           for *_a, n in decode_args)
+            width = next(w for w in self._paged_width_buckets()
+                         if w >= max_used)
+            block_tables = np.zeros((b, width), np.int32)
+            context_lens = np.zeros((b,), np.int32)
+            for lane, (seq, last, slot, table, n) \
+                    in enumerate(decode_args):
+                tokens[lane, 0] = last
+                slot_arr[lane, 0] = slot
+                used = -(-n // self.page_size)
+                block_tables[lane, :used] = table[:used]
+                context_lens[lane] = n
+                q_pos[lane, 0] = seq.pos
+            windows = self._window_arrays(
+                [(lane, seq.windows, n - 1, n) for lane, (seq, *_a, n)
+                 in enumerate(decode_args)], b, 1, width, decode=True) \
+                if self._windows else None
+            phase("decode_dispatch")
+            next_tok, self._pools = self._forward(
+                tokens, slot_arr, None, None, None, q_pos, last_idx,
+                block_tables=block_tables, context_lens=context_lens,
+                windows=windows)
             phase("decode_sync")
             # device sync: real step cost
             next_tok = self._split_counters(np.asarray(next_tok), b,
@@ -1759,13 +1734,13 @@ class LLMEngine:
         with self._cond:
             self._cond.notify_all()
 
-    # ------------------------------------------------- sync (bench baseline)
+    # ------------------------------------------------------- inline driving
 
     def generate_batch(self, requests: List[Dict[str, Any]]
                        ) -> List[List[int]]:
-        """Static batching: admit the whole batch, run it to completion,
-        disband — the ``@serve.batch`` baseline the continuous path is
-        benched against.  Only for engines with no pinned loop."""
+        """Admit the whole batch and step the engine inline until every
+        sequence of it is done: `warm_up`'s request and the tests'
+        driver.  Only for engines with no pinned loop."""
         seqs = []
         try:
             for r in requests:
@@ -1833,7 +1808,6 @@ class LLMEngine:
             return {"steps": self._steps,
                     "platform": self.platform,
                     "kernel_mode": self.kernel_mode,
-                    "attention_impl": self.attention_impl,
                     "decode_steps": self._decode_steps,
                     "decode_secs": self._decode_secs,
                     "prefill_steps": self._prefill_steps,
@@ -2028,41 +2002,6 @@ class _LLMCallable:
         self._engine.restore_state(state)
 
 
-class _LLMBatchCallable:
-    """The ``@serve.batch`` STATIC-batching baseline for bench A/B:
-    requests coalesce into a fixed batch, the whole batch generates to
-    completion in one call, then disbands — the exact re-dispatching
-    shape continuous batching replaces.
-
-    ``__call__`` serves the SAME streaming contract as the continuous
-    path (SSE items of <= stream_flush_tokens tokens) so the A/B
-    measures the batching policy, not response framing — but a static
-    batch can only start emitting once the WHOLE batch finished, which
-    is precisely the TTFT/utilization gap continuous batching closes."""
-
-    def __init__(self, max_batch_size: int = 8,
-                 batch_wait_timeout_s: float = 0.005, warm: bool = True,
-                 **engine_kwargs):
-        from ray_tpu.serve.api import batch
-
-        self._engine = LLMEngine(**engine_kwargs)
-        if warm:
-            self._engine.warm_up()
-        self._gen = batch(self._run_batch,
-                          max_batch_size=max_batch_size,
-                          batch_wait_timeout_s=batch_wait_timeout_s)
-
-    def _run_batch(self, requests):
-        return self._engine.generate_batch(requests)
-
-    def __call__(self, request):
-        toks = self._gen(request)  # blocks until this request's batch ends
-        flush = self._engine.stream_flush_tokens
-        for i in range(0, len(toks), flush):
-            yield {"i": i, "tokens": toks[i:i + flush],
-                   "done": i + flush >= len(toks)}
-
-
 def run_llm_loop(worker, instance, *_args) -> Dict[str, Any]:
     """Worker-side entry for the ``__rt_dag_llm_loop__`` system method
     (see CoreWorker._execute_inner): pins this exec thread to the
@@ -2094,8 +2033,7 @@ def llm_deployment(name: str = "llm", *, num_replicas: Any = 1,
     loop on each one.  ``engine_kwargs`` go to :class:`LLMEngine`
     (model=, page_size=, num_pages=, max_batch=, prefill_chunk=,
     max_queue=, seed=, detach_grace_s=, prefix_sharing=, and the
-    debugging aid logit_trace=); unset knobs fall back to the ``llm_*``
-    config defaults.
+    debugging aid logit_trace=).
 
     ``prefill_replicas > 0`` disaggregates the two serving phases: a
     sibling ``{name}-prefill`` pool (same engine config) runs chunked

@@ -12,7 +12,6 @@ import dataclasses
 
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from benchmarks import reference_laguna as ref
 from ray_tpu.models.laguna import LagunaConfig
@@ -32,7 +31,6 @@ PAGE, CHUNK, WINDOW = 8, 16, 32
 
 
 def _engine(**kw):
-    kw.setdefault("attention_impl", "paged")
     return LLMEngine(CFG, page_size=PAGE, max_batch=4, prefill_chunk=CHUNK,
                      seed=3, **kw)
 
@@ -49,14 +47,13 @@ def _drain(eng, rounds=600):
     raise AssertionError("the engine did not go idle")
 
 
-@pytest.mark.parametrize("impl", ["paged", "dense"])
-def test_prefill_in_chunks_then_decode_is_the_references_forward(impl):
+def test_prefill_in_chunks_then_decode_is_the_references_forward():
     """Prompts shorter than (5, 20), equal to (32) and several times the
     window (100, 150), with chunks of 16 that straddle it (40 = 2 chunks
     and a half), four lanes at once; 12 tokens each through the decode
     kernel (windowed for three layers of five).  Every token is the
     reference's argmax given the engine's own earlier tokens."""
-    eng = _engine(attention_impl=impl)
+    eng = _engine()
     prompts = [_prompt(n) for n in (5, 20, 32, 40, 100, 150)]
     outs = eng.generate_batch([{"tokens": p, "max_new_tokens": 12}
                                for p in prompts])
@@ -73,6 +70,36 @@ def test_prefill_in_chunks_then_decode_is_the_references_forward(impl):
         assert st["moe_assignments_total"][which] >= calls[which]
         assert slots[which] == 4 * st["moe_layer_passes_total"][which]
     assert st["moe_layer_passes_total"]["decode"] == 4 * st["decode_steps"]
+
+
+def test_window_arrays_take_the_form_of_their_pass():
+    """One form a kind of pass: a prefill pass gathers the window's
+    positions, a decode pass lists its pages from `starts` on; a lane
+    without a row is garbage in both."""
+    eng = _engine()
+    group = eng._windows["window"]
+    seq = eng.submit({"tokens": _prompt(100), "max_new_tokens": 8})
+    while len(seq.generated) < 3:
+        eng.step()
+    n, st = seq.pos, seq.windows["window"]
+    row = [(1, seq.windows, n - 1, n)]
+    dec = eng._window_arrays(row, 4, 1, 16, decode=True)["window"]
+    assert set(dec) == {"slots", "block_tables", "context_lens", "starts"}
+    assert dec["block_tables"].shape == (4, group.table_width)
+    start = int(dec["starts"][1])
+    assert start % PAGE == 0 and start <= n - WINDOW < start + PAGE
+    live = -(-(n - start) // PAGE)
+    assert dec["block_tables"][1, :live].tolist() \
+        == st.pages[start // PAGE:start // PAGE + live].tolist()
+    assert dec["context_lens"].tolist() == [0, n, 0, 0]
+    assert dec["slots"][:, 0].tolist() == [0, st.slots[n - 1], 0, 0]
+    pre = eng._window_arrays(row, 4, 1, 256)["window"]
+    assert set(pre) == {"slots", "ctx", "ctx_pos", "ctx_mask"}
+    assert pre["ctx"].shape == (4, group.ctx_width)
+    assert pre["ctx_mask"].sum(axis=1).tolist() == [0, group.ctx_width, 0, 0]
+    assert pre["ctx_pos"][1, :group.ctx_width].tolist() \
+        == list(range(n - group.ctx_width, n))
+    assert eng.cancel(seq.request_id)
 
 
 def test_window_pages_come_back_while_a_sequence_runs():

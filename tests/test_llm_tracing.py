@@ -279,7 +279,10 @@ def test_a_profile_holds_the_step_its_phases_and_the_park(tmp_path):
                        for s0, e0 in steps), n
     reduced = trace_reduce.reduce_events(**rows, unattributed=unattributed)
     gaps = dict(reduced["idle_gaps"])
-    assert unattributed not in gaps, gaps
+    # the step in flight when the profile stops has no `llm.step` span in
+    # the capture, so the device's gap under it has no name: one step's
+    # worth at most, once in some 25 runs
+    assert gaps.get(unattributed, 0.0) <= max(e - s for s, e in steps), gaps
     assert gaps["llm.park"] >= 0.02, gaps
 
 

@@ -5,8 +5,9 @@ TPU compilation — so these tests pin the decode kernel against the
 dense gather-then-softmax reference (models/llama.py cached_attention)
 across batch, context length, GQA grouping, and page size, including
 ragged lengths, all-garbage lanes, and non-contiguous / shuffled
-physical page assignment.  The engine-level A/B at the bottom proves
-the two attention_impl settings generate token-identical streams.
+physical page assignment.  The engine-level tests at the bottom hold
+the engine's decode, which goes through the kernel, to the module's
+no-cache forward.
 """
 
 import numpy as np
@@ -44,8 +45,7 @@ def _rand_paged_case(rng, batch, ctx_lens, n_heads, n_kv_heads, head_dim,
 
 def _dense_reference(q, pool_k, pool_v, bt, ctx_lens, page_size):
     """cached_attention over ctx/ctx_pos/ctx_mask arrays derived from
-    the same block tables — the exact arrays the dense engine path
-    builds each decode step."""
+    the same block tables — the form a prefill pass's group carries."""
     batch = q.shape[0]
     length = bt.shape[1] * page_size
     ctx = np.zeros((batch, length), np.int32)
@@ -197,37 +197,91 @@ def test_gather_scatter_copy_round_trip():
                 np.testing.assert_array_equal(a, b)
 
 
-# ------------------------------------------------ engine-level A/B
+# ------------------------------------------------ the engine's decode
 
 
-def _make_engine(impl, params=None):
+def _engine_cfg(max_seq_len=64):
+    return LlamaConfig(vocab_size=64, dim=32, n_layers=2, n_heads=4,
+                       n_kv_heads=2, hidden_dim=64, max_seq_len=max_seq_len,
+                       dtype=jnp.float32)
+
+
+@pytest.mark.parametrize("page,ctx,long_prompt,widths", [
+    (8, 64, 28, [4, 8]),         # 28 + 8 tokens cross 4 pages of 8
+    (4, 128, 60, [4, 16, 32]),   # 60 + 8 cross 16 pages of 4; 13 + 6, 4
+])
+def test_engine_decode_is_the_no_cache_forwards_argmax(page, ctx,
+                                                       long_prompt, widths):
+    """Every token the engine decodes through block tables is the argmax
+    of the module's no-cache forward over the prompt and the tokens so
+    far (float32 keeps the argmax bit-stable): three short prompts, and
+    one whose decode crosses a block-table width bucket while the others
+    share its pass."""
+    from ray_tpu.models.llama import LlamaModel
     from ray_tpu.serve.llm import LLMEngine
 
-    cfg = LlamaConfig(vocab_size=64, dim=32, n_layers=2, n_heads=4,
-                      n_kv_heads=2, hidden_dim=64, max_seq_len=64,
-                      dtype=jnp.float32)
-    return LLMEngine(cfg, page_size=8, num_pages=33, max_batch=4,
-                     prefill_chunk=8, max_queue=8,
-                     attention_impl=impl, params=params)
-
-
-def test_engine_paged_vs_dense_identical_tokens():
-    """The serving A/B: the same prompts decoded greedily through the
-    paged kernel and through the dense reference produce identical
-    token streams (fp32 keeps argmax bit-stable)."""
-    paged = _make_engine("paged")
-    dense = _make_engine("dense", params=paged._params)
-    assert paged.stats()["attention_impl"] == "paged"
-    assert dense.stats()["attention_impl"] == "dense"
+    cfg = _engine_cfg(ctx)
+    eng = LLMEngine(cfg, page_size=page, max_batch=4, prefill_chunk=8,
+                    max_queue=8)
+    assert eng._paged_width_buckets() == widths
     reqs = [{"tokens": [5, 9, 3], "max_new_tokens": 6},
             {"tokens": [7, 11, 2, 4, 8, 1, 9, 10, 3, 2],
              "max_new_tokens": 6},
-            {"tokens": [3] * 13, "max_new_tokens": 6}]
-    out_p = paged.generate_batch([dict(r) for r in reqs])
-    out_d = dense.generate_batch([dict(r) for r in reqs])
-    assert out_p == out_d, (out_p, out_d)
+            {"tokens": [3] * 13, "max_new_tokens": 6},
+            {"tokens": [1 + (7 * i) % 60 for i in range(long_prompt)],
+             "max_new_tokens": 8}]
+    out = eng.generate_batch([dict(r) for r in reqs])
+    full = jax.jit(LlamaModel(cfg).apply)
+    for req, gen in zip(reqs, out):
+        assert len(gen) == req["max_new_tokens"]
+        # one causal forward: row len(prompt) - 1 + j saw prompt + gen[:j]
+        toks = req["tokens"] + gen
+        padded = np.zeros((1, ctx), np.int32)
+        padded[0, :len(toks)] = toks
+        logits = np.asarray(full({"params": eng._params}, padded))[0]
+        want = logits[len(req["tokens"]) - 1:len(toks) - 1].argmax(-1)
+        assert gen == want.tolist(), (req["tokens"][:4], gen, want)
 
 
-def test_attention_impl_validation():
-    with pytest.raises(ValueError, match="auto\\|paged\\|dense"):
-        _make_engine("flashier")
+def test_the_engine_has_one_decode_path():
+    """No selector, one report field kept for the benchmark's files, and
+    the form is the cache group's: a group with block tables goes through
+    the kernel, a group without takes the gathered form, in both
+    families' modules."""
+    from ray_tpu.models import laguna, llama
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg = _engine_cfg()
+    with pytest.raises(TypeError, match="attention_impl"):
+        LLMEngine(cfg, attention_impl="dense")
+    eng = LLMEngine(cfg, page_size=8, max_batch=2, prefill_chunk=8)
+    assert eng.device_report()["attention_impl"] == "paged"
+    assert "attention_impl" not in eng.stats()
+    for family, fcfg in ((llama, cfg), (laguna, laguna.LagunaConfig.tiny())):
+        model = family.build(fcfg, 8)
+        params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                                jnp.zeros((1, 8), jnp.int32))["params"]
+        spec = fcfg.cache_spec()
+        pools = jax.eval_shape(lambda: kv_cache.make_pools(
+            spec, {kind: 64 for kind in kv_cache.kinds_of(spec)},
+            fcfg.dtype))
+        one = jnp.zeros((2, 1), jnp.int32)
+        lens = jnp.ones((2,), jnp.int32)
+        tables = {"block_tables": jnp.zeros((2, 4), jnp.int32),
+                  "context_lens": lens}
+        gathered = {"ctx": jnp.zeros((2, 16), jnp.int32),
+                    "ctx_pos": jnp.zeros((2, 16), jnp.int32),
+                    "ctx_mask": jnp.ones((2, 16), bool)}
+        for through_kernel, full, window in (
+                (True, tables, {**tables, "starts": lens}),
+                (False, gathered, gathered)):
+            groups = {kind: {"slots": one,
+                             **(full if kind == "full" else window)}
+                      for kind in kv_cache.kinds_of(spec)}
+            text = str(jax.make_jaxpr(
+                lambda p, k, v, groups=groups: model.apply(
+                    {"params": p}, one,
+                    {"k": k, "v": v, "q_pos": one, "groups": groups}))(
+                params, pools["k"], pools["v"]))
+            assert ("paged_attention_decode" in text) == through_kernel, \
+                family.__name__

@@ -99,8 +99,8 @@ def _eager_logits(eng, toks):
 
 def _assert_trace_is_the_full_forwards(eng, seq, full=_eager_logits):
     """Tokens and top-two logits of `seq` against ONE no-cache forward
-    over prompt + generated (`full`), to the tolerance paged holds
-    against dense."""
+    over prompt + generated (`full`), to the tolerance two float32
+    programs of one forward hold."""
     p, gen = list(seq.prompt), list(seq.generated)
     rows = eng.device_report()["logit_trace"][seq.request_id]
     assert [r[0] for r in rows] == list(range(len(gen)))
@@ -140,12 +140,11 @@ def test_decode_matches_full_forward():
     assert st["used_pages"] == 0 and st["free_pages"] == 32, st
 
 
-@pytest.mark.parametrize("impl", ["paged", "dense"])
-def test_logit_trace_rows_are_the_full_forwards_top_two(impl):
+def test_logit_trace_rows_are_the_full_forwards_top_two():
     """`logit_trace=True` keeps, for every generated token, the two
     largest logits and their ids; they are the full forward's at that
     position, for the prefill's token and for every decode step's."""
-    eng = _engine(logit_trace=True, attention_impl=impl)
+    eng = _engine(logit_trace=True)
     prompts = {"a": [5, 9, 3], "b": [7, 11, 2, 4, 8, 1, 9, 10, 3, 2]}
     seqs = {rid: eng.submit({"tokens": p, "max_new_tokens": 6,
                              "request_id": rid})
@@ -156,6 +155,33 @@ def test_logit_trace_rows_are_the_full_forwards_top_two(impl):
         _assert_trace_is_the_full_forwards(eng, seq)
     # off (the default): the serving program and report are unchanged
     assert "logit_trace" not in _engine().device_report()
+
+
+def test_engine_defaults():
+    """An engine built with no sizing argument has the values the twelve
+    `llm_*` config knobs held, and the process-wide config has none of
+    them left: the constructor is the one way to set an engine."""
+    from ray_tpu._private.config import config
+
+    eng = LLMEngine(_cfg())
+    pages_per_seq = MODEL["max_seq_len"] // 16
+    assert (eng.page_size, eng.max_batch, eng.prefill_chunk,
+            eng.prefill_lanes, eng.stream_flush_tokens, eng.max_queue,
+            eng.detach_grace_s, eng.prefix_sharing, eng.temperature,
+            eng.top_k) == (16, 32, 64, 8, 4, 256, 2.0, True, 0.0, 0)
+    # llm_kv_pages 0: sized for max_batch sequences at max_seq_len
+    assert eng.num_pages == 1 + 32 * pages_per_seq
+    assert eng.device_report()["attention_impl"] == "paged"  # was "auto"
+    for name in ("llm_page_size", "llm_kv_pages", "llm_max_batch_size",
+                 "llm_prefill_chunk", "llm_prefill_lanes",
+                 "llm_stream_flush_tokens", "llm_admission_queue",
+                 "llm_detach_grace_s", "llm_prefix_sharing",
+                 "llm_attention_impl", "llm_temperature", "llm_top_k"):
+        with pytest.raises(AttributeError):
+            getattr(config, name)
+    # the two without a constructor twin stay
+    assert config.llm_done_seq_ttl_s == 30.0
+    assert config.llm_disagg_min_prompt == 0
 
 
 # ------------------------------------------- prefill context-width buckets
