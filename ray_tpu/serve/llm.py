@@ -125,12 +125,14 @@ DETACH_GRACE_S = 2.0    # KV pages survive a vanished consumer this long
 # matches a live one's onto the SAME physical pages; `temperature=0.0`,
 # `top_k=0` are greedy argmax over the full vocabulary.
 
-# the phases of one engine step, in the order a step passes them: the
-# key of `stats()["phase_secs"]` -> the span on the profiler's clock.
-# `admit` is the first lock section; of each pass, `build` is the numpy
-# arrays, `dispatch` the jitted call until it returns, `sync` the
-# `np.asarray` of its tokens (the wait for the device), `emit` the
-# second lock section and what follows it
+# the phases of one engine step: the key of `stats()["phase_secs"]` ->
+# the span on the profiler's clock.  `admit` is the first lock section.
+# The engine runs one step ahead of its read-backs, so of each kind of
+# pass `build` (the numpy arrays) and `dispatch` (the jitted call until
+# it returns, and the host state it advances) are THIS step's, and come
+# first; `sync` is the `np.asarray` of the PREVIOUS step's output of that
+# kind (the wait for the device; the span carries `of`, that step's
+# number), `emit` the lock section that hands its tokens out
 _PHASES = {"admit": "llm.admit",
            "prefill_build": "llm.prefill.build",
            "prefill_dispatch": "llm.prefill.dispatch",
@@ -167,7 +169,7 @@ def _pow4_widths(first: int, cap: int) -> List[int]:
 
 
 def _jit_forward(model, params, k, v, tokens, q_pos, last_idx, groups,
-                 temperature=0.0, top_k=0, rng=None, top2=False):
+                 temperature=0.0, top_k=0, rng=None, top2=False, feed=None):
     """One forward over the paged cache -> (next tokens at ``last_idx``,
     updated pools).  Jitted ONCE per (model, shapes, sampling knobs) —
     the flax module AND the sampling knobs are hashable static
@@ -181,6 +183,12 @@ def _jit_forward(model, params, k, v, tokens, q_pos, last_idx, groups,
     prefill), or page-granular ``block_tables`` + ``context_lens`` (a
     window kind also ``starts``), which take decode through the Pallas
     paged-attention kernel.
+
+    ``feed`` is a decode pass's: ``(src, outputs)``, the previous step's
+    output arrays as the device holds them and, a lane, the place of its
+    input token in their concatenation (-1: the host's ``tokens``).  The
+    engine dispatches a step before it has read the one before, so a
+    sequence's newest token is on the device only (`LLMEngine.step`).
 
     Sampling is a pair of jit-STATIC knobs (ISSUE 13 satellite / PR-11
     declared headroom (d)): ``temperature == 0`` compiles the exact
@@ -196,7 +204,8 @@ def _jit_forward(model, params, k, v, tokens, q_pos, last_idx, groups,
         import jax.numpy as jnp
 
         rng = jnp.zeros((2,), dtype="uint32")  # unused when greedy
-    return fn(model, params, k, v, tokens, q_pos, last_idx, rng, groups)
+    return fn(model, params, k, v, tokens, q_pos, last_idx, rng, groups,
+              feed)
 
 
 def _jitted_forward(temperature=0.0, top_k=0, top2=False):
@@ -215,7 +224,14 @@ def _jitted_forward(temperature=0.0, top_k=0, top2=False):
         import jax.numpy as jnp
 
         def _fwd(model, params, k, v, tokens, q_pos, last_idx, rng,
-                 groups, temperature=key[0], top_k=key[1], top2=key[2]):
+                 groups, feed=None, temperature=key[0], top_k=key[1],
+                 top2=key[2]):
+            if feed is not None:
+                src, outputs = feed
+                late = jnp.concatenate(outputs)[jnp.maximum(src, 0)]
+                tokens = jnp.where(src[:, None] >= 0,
+                                   late[:, None].astype(tokens.dtype),
+                                   tokens)
             cache = {"k": k, "v": v, "q_pos": q_pos, "groups": groups}
             logits, pools, *counted = model.apply(
                 {"params": params}, tokens, cache)
@@ -229,9 +245,10 @@ def _jitted_forward(temperature=0.0, top_k=0, top2=False):
                     kth = jnp.sort(scaled, axis=-1)[:, -top_k][:, None]
                     scaled = jnp.where(scaled < kth, -jnp.inf, scaled)
                 tok = jax.random.categorical(rng, scaled, axis=-1)
+            tok = tok.astype(jnp.int32)
             if counted:
                 tok = jnp.concatenate(
-                    [tok.astype(jnp.int32), counted[0].astype(jnp.int32)])
+                    [tok, counted[0].astype(jnp.int32)])
             if not top2:
                 return tok, pools
             f = picked.astype(jnp.float32)
@@ -299,6 +316,24 @@ class _StepClock:
         self.step_secs += self.phase(None) - self._t_step
         self._step_span.__exit__(None, None, None)
 
+    def pass_secs(self, kind: str) -> float:
+        """The seconds of one kind of pass: its four phases."""
+        return sum(self.phase_secs[f"{kind}_{part}"]
+                   for part in ("build", "dispatch", "sync", "emit"))
+
+
+class _Flight:
+    """A dispatched pass whose output the host has not read: the step
+    that dispatched it, the output as the device holds it, and who is
+    owed a token of it — `lanes` = [(lane, sequence)]: every lane of a
+    decode pass, of a prefill pass the lanes whose prompt ended in it."""
+
+    __slots__ = ("kind", "step", "out", "top2", "lanes")
+
+    def __init__(self, kind: str, step: int, out, top2, lanes):
+        self.kind, self.step, self.out = kind, step, out
+        self.top2, self.lanes = top2, lanes
+
 
 class _Seq:
     __slots__ = ("request_id", "prompt", "prefill_tokens", "generated",
@@ -308,7 +343,7 @@ class _Seq:
                  "cancelled", "slot_cache", "cond", "deadline", "kv_import",
                  "prefill_export", "export_payload", "trace_ctx",
                  "prefix_tokens", "submit_step", "admit_step",
-                 "first_token_step", "windows")
+                 "first_token_step", "windows", "ahead", "feed")
 
     def __init__(self, request_id: str, prompt: List[int], max_new: int,
                  eos: Optional[int], preknown: Optional[List[int]] = None):
@@ -329,7 +364,16 @@ class _Seq:
         self.block_table: List[int] = []   # the "full" group's pages
         # cache kind -> _SeqWindow, for each window kind of the model
         self.windows: Dict[str, "_SeqWindow"] = {}
-        self.pos = 0                  # tokens whose KV is in the cache
+        # tokens whose KV a DISPATCHED pass has written or will write:
+        # the device runs passes in the order they were dispatched, so
+        # to every later pass they are in the cache
+        self.pos = 0
+        # tokens of this sequence computed by dispatched passes and not
+        # read back yet (`_Flight`), and the place of the newest in the
+        # last step's outputs (`_jit_forward`'s `feed`), valid while
+        # `ahead` > 0
+        self.ahead = 0
+        self.feed = -1
         self.state = _QUEUED
         self.done = False
         self.error: Optional[BaseException] = None
@@ -502,7 +546,6 @@ class LLMEngine:
         # id2], ...].  Grows with every token; costs a host copy a step.
         self.logit_trace = bool(logit_trace)
         self._logit_trace: Dict[str, List[list]] = {}
-        self._last_top2 = None
         # the model: its family's module (config, build) and its config,
         # which states the cache layer by layer (ray_tpu/models)
         self.family, cfg = resolve(cfg if cfg is not None else model)
@@ -639,13 +682,24 @@ class LLMEngine:
         self._paged_warm = False
         self._prefill_warm = False
         # pass accumulators (a reader takes mean step cost as a delta
-        # between two stats() snapshots): a pass's seconds are its build,
-        # dispatch and sync; `_clock` has every phase and the whole step
+        # between two stats() snapshots): the passes dispatched, and of
+        # `_clock`, which has every phase and the whole step, a kind's
+        # four phases less its one-time warm-up (`_warm_secs`)
         self._decode_steps = 0
-        self._decode_secs = 0.0
         self._prefill_steps = 0
-        self._prefill_secs = 0.0
+        self._warm_secs = {"prefill": 0.0, "decode": 0.0}
         self._clock = _StepClock()
+        # what is dispatched and not read (the stepping thread's own: an
+        # engine is stepped by its loop OR inline), and the last step's
+        # outputs, the decode pass's and the prefill pass's, for the
+        # next decode pass to take its tokens from: zeros where the step
+        # had no such pass, the same program
+        self._flight: deque = deque()
+        counters = len(getattr(self._model, "counters", ()))
+        self._no_feed = (jnp.zeros((self.max_batch + counters,), jnp.int32),
+                         jnp.zeros((self.prefill_lanes + counters,),
+                                   jnp.int32))
+        self._feed = list(self._no_feed)
         # cumulative, as `stats()` gives them.  Work: prompt tokens
         # prefilled, the token slots (lanes x chunk) the prefill passes
         # had for them, decode lanes stepped (over decode_steps: the mean
@@ -653,12 +707,19 @@ class LLMEngine:
         # cancelled), and the seconds they waited for the next one.
         # Context: the rows the prefill passes' real lanes read, the
         # columns (lanes x width) the passes gathered for them, and the
-        # passes by the width they took.
+        # passes by the width they took.  Run-ahead: decode passes
+        # dispatched while the step before was unread (over
+        # decode_steps: how often the device had its next pass queued),
+        # and lane-steps computed for a sequence that had ended by the
+        # time they were read (its `eos`, a cancel or its deadline came
+        # while they were in flight; over decode_lane_steps_total).
         self._totals = {"prefill_tokens_total": 0,
                         "prefill_slots_total": 0,
                         "prefill_ctx_rows_total": 0,
                         "prefill_ctx_cols_total": 0,
                         "decode_lane_steps_total": 0,
+                        "decode_lane_steps_wasted_total": 0,
+                        "runahead_decode_steps_total": 0,
                         "submitted_total": 0, "admitted_total": 0,
                         "first_tokens_total": 0, "finished_total": 0,
                         "queue_wait_secs_total": 0.0,
@@ -864,13 +925,16 @@ class LLMEngine:
 
     def _forward(self, tokens, slot_arr, ctx, ctx_pos, ctx_mask, q_pos,
                  last_idx, block_tables=None, context_lens=None,
-                 windows=None):
-        """One jitted forward with this engine's static sampling knobs;
-        the per-call rng split only happens on the sampling path, so
-        greedy engines run the exact pre-sampling program.  The
+                 windows=None, feed=None):
+        """Dispatch one jitted forward with this engine's static sampling
+        knobs; the per-call rng split only happens on the sampling path,
+        so greedy engines run the exact pre-sampling program.  The
         positional arrays are the full kind's; `windows` has the other
-        kinds' (`_window_arrays`).  Returns (the tokens, with the model's
-        counter vector behind them if it counts, and the pools)."""
+        kinds' (`_window_arrays`); `feed` is a decode pass's
+        (`_jit_forward`).  The pools become the pass's; returns what
+        stays on the device until it is read back: (the tokens, with the
+        model's counter vector behind them if it counts; under
+        `logit_trace` the two largest logits and their ids, else None)."""
         rng = None
         if self._sample_rng is not None:
             import jax
@@ -882,16 +946,12 @@ class LLMEngine:
                         context_lens=context_lens)
         else:
             full.update(ctx=ctx, ctx_pos=ctx_pos, ctx_mask=ctx_mask)
-        out = self._step_fn(
+        tok, self._pools, *top2 = self._step_fn(
             self._model, self._params, self._pools["k"], self._pools["v"],
             tokens, q_pos, last_idx, {"full": full, **(windows or {})},
             temperature=self.temperature, top_k=self.top_k, rng=rng,
-            top2=self.logit_trace)
-        if self.logit_trace:
-            tok, pools, (vals, ids) = out
-            self._last_top2 = (self._np.asarray(vals), self._np.asarray(ids))
-            return tok, pools
-        return out
+            top2=self.logit_trace, feed=feed)
+        return tok, (top2[0] if top2 else None)
 
     def _split_counters(self, next_tok, lanes: int, phase: str):
         """The host copy of a pass's output: its `lanes` tokens, and the
@@ -942,11 +1002,12 @@ class LLMEngine:
                          "ctx_mask": ctx_mask}
         return out
 
-    def _trace_top2(self, seq: _Seq, lane: int) -> None:
-        """Lock held, just before `_emit_token`: the last forward's two
-        largest logits of `lane`, for the token about to be emitted."""
-        if self._last_top2 is not None:
-            vals, ids = self._last_top2
+    def _trace_top2(self, seq: _Seq, lane: int, top2) -> None:
+        """Lock held, just before `_emit_token`: the two largest logits
+        of `lane` in the pass read back (`top2`: its host copy, None
+        without `logit_trace`), for the token about to be emitted."""
+        if top2 is not None:
+            vals, ids = top2
             self._logit_trace.setdefault(seq.request_id, []).append(
                 [len(seq.generated), float(vals[lane, 0]), int(ids[lane, 0]),
                  float(vals[lane, 1]), int(ids[lane, 1])])
@@ -984,7 +1045,7 @@ class LLMEngine:
             if width == but:
                 continue
             ctx = np.zeros((lanes, width), np.int32)
-            _tok, self._pools = self._forward(
+            self._forward(
                 zeros, zeros, ctx, ctx, np.zeros((lanes, width), bool),
                 zeros, np.zeros((lanes,), np.int32),
                 windows=self._window_arrays([], lanes, c, width))
@@ -1001,11 +1062,12 @@ class LLMEngine:
         sharing a config/geometry pay once."""
         for width in self._paged_width_buckets():
             args, kwargs = self._garbage_decode_args(width)
-            _tok, self._pools = self._forward(*args, **kwargs)
+            self._forward(*args, **kwargs)
 
     def _garbage_decode_args(self, width: int):
         """`_forward` arguments for a decode step of garbage lanes only
-        (slot 0, context length 0) at block-table `width`."""
+        (slot 0, context length 0, every token the host's) at
+        block-table `width`."""
         np = self._np
         b = self.max_batch
         zeros1 = np.zeros((b, 1), np.int32)
@@ -1014,7 +1076,25 @@ class LLMEngine:
                 {"block_tables": np.zeros((b, width), np.int32),
                  "context_lens": np.zeros((b,), np.int32),
                  "windows": self._window_arrays([], b, 1, width,
-                                                decode=True)})
+                                                decode=True),
+                 "feed": (np.full((b,), -1, np.int32), self._no_feed)})
+
+    def _lower_decode(self, width: int):
+        """The decode step at block-table `width`, lowered and not run:
+        for its text (`device_report`) or the compiler's analysis."""
+        import jax
+
+        (tokens, slots, _c, _p, _m, q_pos, last_idx), kwargs = \
+            self._garbage_decode_args(width)
+        return _jitted_forward(self.temperature, self.top_k,
+                               self.logit_trace).lower(
+            self._model, self._params, self._pools["k"],
+            self._pools["v"], tokens, q_pos, last_idx,
+            jax.numpy.zeros((2,), dtype="uint32"),  # rng, unused
+            {"full": {"slots": slots,
+                      "block_tables": kwargs["block_tables"],
+                      "context_lens": kwargs["context_lens"]},
+             **kwargs["windows"]}, kwargs["feed"])
 
     def device_report(self) -> Dict[str, Any]:
         """`ops.device_report()` plus what this engine put on the device
@@ -1060,18 +1140,7 @@ class LLMEngine:
             with self._lock:
                 rep["logit_trace"] = {rid: list(rows) for rid, rows
                                       in self._logit_trace.items()}
-        args, kwargs = self._garbage_decode_args(
-            self._paged_width_buckets()[0])
-        tokens, slots, _c, _p, _m, q_pos, last_idx = args
-        text = _jitted_forward(self.temperature, self.top_k,
-                               self.logit_trace).lower(
-            self._model, self._params, self._pools["k"],
-            self._pools["v"], tokens, q_pos, last_idx,
-            jax.numpy.zeros((2,), dtype="uint32"),  # rng, unused
-            {"full": {"slots": slots,
-                      "block_tables": kwargs["block_tables"],
-                      "context_lens": kwargs["context_lens"]},
-             **kwargs["windows"]}).as_text()
+        text = self._lower_decode(self._paged_width_buckets()[0]).as_text()
         rep["decode_has_tpu_custom_call"] = "tpu_custom_call" in text
         return rep
 
@@ -1484,36 +1553,72 @@ class LLMEngine:
             self.release(seq)
 
     def step(self) -> bool:
-        """One engine iteration: admit, one prefill chunk, one decode
-        pass over every decoding sequence.  Returns False when there was
-        nothing to do (the loop then parks on the condition)."""
-        t_step = self._clock.begin(self._steps)
+        """One engine iteration, ONE STEP AHEAD of its read-backs: admit;
+        build and dispatch this step's prefill pass (one chunk of each
+        waiting prompt) and decode pass (every decoding sequence); THEN
+        read back the previous step's outputs and emit their tokens.
+        The device starts step n+1 the moment step n ends, and the
+        host's read-back, emission, admission, build and dispatch run
+        while it computes.
+
+        Nothing the host needs for the next pass depends on the last
+        one's result but the token itself, which the next decode pass
+        takes on the device (`_jit_forward`'s `feed`): positions, slots,
+        tables and window pages advance at dispatch, and a sequence is
+        never dispatched past its `max_new` count.  What the host learns
+        a step late is an `eos`, a cancel or an expiry: that sequence's
+        lane-step in flight is dropped when read
+        (`decode_lane_steps_wasted_total`); it wrote one row into a page
+        of the sequence's own reservation, and whoever takes the page
+        next writes after it, the device running passes in the order
+        they were dispatched.
+
+        Returns False when there was nothing to dispatch and nothing to
+        read (the loop then parks on the condition)."""
+        clock = self._clock
+        decode_before = clock.pass_secs("decode")
+        t_step = clock.begin(self._steps)
         try:
             return self._step(t_step)
         finally:
+            clock.end()
+            decode_dt = clock.pass_secs("decode") - decode_before
+            m = self.metrics()
+            if m is not None and decode_dt > 0.0:
+                m["decode_step"].observe(decode_dt)
+
+    def drain(self) -> bool:
+        """Read back and emit whatever is in flight, dispatching nothing:
+        for whoever needs the engine's results to be the sequences'
+        (`generate_batch`'s end, `save_state`, `stop`).  The stepping
+        thread's: an engine with a pinned loop is drained by its loop.
+        A step of its own on the clock.  True when something was read."""
+        self._clock.begin(self._steps)
+        try:
+            return self._read_back()
+        finally:
             self._clock.end()
+
+    def _drain_inline(self) -> None:
+        """`drain` for a caller that is not the stepping thread's loop:
+        an engine stepped inline is drained here, a pinned loop reads
+        what it has in flight itself."""
+        with self._lock:
+            inline = not self._loop_running
+        if inline:
+            self.drain()
 
     def _step(self, t_step: float) -> bool:
         """`step`'s body.  `phase(key)` is the clock's boundary: it ends
         the open phase, opens `key` and returns its one clock read."""
-        np = self._np
-        phase = self._clock.phase
         now = time.monotonic()
         with self._lock:
             self._sweep(now)
             self._admit_locked()
             imported = self._attach_imports_locked()
-            prefills = [s for s in self._active
-                        if s.state == _PREFILL][:self.prefill_lanes]
-            decode = [s for s in self._active if s.state == _DECODE]
-            if not prefills and not decode:
-                self._last_batch = 0
-                self._set_gauges()  # idle must publish zeros, not
-                # freeze the last busy step's values into the ring
-                return imported  # an import that finished immediately
-                # (max_new=1 / eos) still counts as work done
             prefill_args = []
-            for seq in prefills:
+            for seq in [s for s in self._active
+                        if s.state == _PREFILL][:self.prefill_lanes]:
                 lo = seq.pos
                 hi = min(lo + self.prefill_chunk, len(seq.prefill_tokens))
                 for kind, st in seq.windows.items():
@@ -1521,8 +1626,16 @@ class LLMEngine:
                 prefill_args.append(
                     (seq, lo, hi, seq.prefill_tokens[lo:hi],
                      seq.slot_cache[lo:hi], seq.slot_cache[:hi]))
+            # the decoding sequences as this step found them (one whose
+            # prompt ends in this step's prefill pass decodes from the
+            # next on), but for those whose every token is dispatched
             decode_args = []
-            for seq in decode[:self.max_batch]:
+            for seq in self._active:
+                if seq.state != _DECODE \
+                        or len(seq.generated) + seq.ahead >= seq.max_new:
+                    continue
+                # the newest token: on the device while unread (`last`
+                # is then not looked at), else the host's
                 last = (seq.generated[-1] if seq.generated
                         else seq.prefill_tokens[-1])
                 for kind, st in seq.windows.items():
@@ -1530,147 +1643,33 @@ class LLMEngine:
                 # snapshot the block table under the lock: a concurrent
                 # CoW split may rewrite entries after we release it
                 decode_args.append(
-                    (seq, last, seq.slot_cache[seq.pos],
-                     list(seq.block_table), seq.pos + 1))
+                    (seq, last, seq.feed if seq.ahead else -1,
+                     seq.slot_cache[seq.pos], list(seq.block_table),
+                     seq.pos + 1))
+        step = self._steps
+        # an unread step before this one: its tokens are on the device
+        ahead = bool(self._flight)
+        feed, self._feed = self._feed, list(self._no_feed)
         step_tokens = 0
-        # ---- chunked prefill, batched across lanes: up to
-        # prefill_lanes sequences advance one chunk each in ONE pass of
-        # fixed shape (lanes x chunk, empty lanes are garbage), in the
-        # steps where a prompt waits — a burst of N admissions costs
-        # N/lanes passes, while a LONG prompt still shares the loop with
-        # in-flight decodes instead of monopolizing it.  The context the
-        # pass gathers is as wide as the smallest _prefill_ctx_buckets()
-        # entry covering its longest lane: its cost tracks USED context,
-        # at one program a bucket.
         if prefill_args:
-            t_pre = phase("prefill_build")
-            ctx_rows = [hi for _s, _lo, hi, *_r in prefill_args]
-            longest = max(ctx_rows)
-            width = next(w for w in self._prefill_widths if w >= longest)
-            if not self._prefill_warm:
-                self._prefill_warm = True
-                self._warm_prefill_buckets(but=width)
-                # the one-time warm-up is this phase's, not prefill_secs'
-                t_pre = time.perf_counter()
-            lanes = self.prefill_lanes
-            c = self.prefill_chunk
-            tokens = np.zeros((lanes, c), np.int32)
-            slot_arr = np.zeros((lanes, c), np.int32)
-            ctx = np.zeros((lanes, width), np.int32)
-            ctx_pos = np.zeros((lanes, width), np.int32)
-            ctx_mask = np.zeros((lanes, width), bool)
-            q_pos = np.zeros((lanes, c), np.int32)
-            last_idx = np.zeros((lanes,), np.int32)
-            for lane, (seq, lo, hi, toks, slots, ctx_slots) \
-                    in enumerate(prefill_args):
-                tokens[lane, :hi - lo] = toks
-                slot_arr[lane, :hi - lo] = slots
-                ctx[lane, :hi] = ctx_slots
-                ctx_pos[lane, :hi] = self._arange[:hi]
-                ctx_mask[lane, :hi] = True
-                q_pos[lane, :hi - lo] = self._arange[lo:hi]
-                last_idx[lane] = hi - lo - 1
-            windows = self._window_arrays(
-                [(lane, seq.windows, lo, hi) for lane, (seq, lo, hi, *_r)
-                 in enumerate(prefill_args)], lanes, c, width) \
-                if self._windows else None
-            phase("prefill_dispatch", width=width)
-            next_tok, self._pools = self._forward(
-                tokens, slot_arr, ctx, ctx_pos, ctx_mask, q_pos, last_idx,
-                windows=windows)
-            phase("prefill_sync")
-            next_tok = self._split_counters(np.asarray(next_tok), lanes,
-                                            "prefill")
-            self._prefill_secs += phase("prefill_emit") - t_pre
-            self._prefill_steps += 1
-            chunk_tokens = sum(hi - lo for _s, lo, hi, *_r in prefill_args)
-            step_tokens += chunk_tokens
-            self._totals["prefill_tokens_total"] += chunk_tokens
-            self._totals["prefill_slots_total"] += lanes * c
-            self._totals["prefill_ctx_rows_total"] += sum(ctx_rows)
-            self._totals["prefill_ctx_cols_total"] += lanes * width
-            self._prefill_passes_by_width[width] += 1
-            with self._lock:
-                for lane, (seq, lo, hi, *_rest) in enumerate(prefill_args):
-                    if seq.done:
-                        continue  # cancelled mid-chunk: pages already back
-                    seq.pos = hi
-                    # pages this chunk completed are immutable now —
-                    # enter them into the prefix index so later
-                    # admissions with the same prompt prefix share them
-                    self._register_prefix_pages(seq)
-                    if hi == len(seq.prefill_tokens):
-                        if seq.prefill_export:
-                            self._export_seq_locked(
-                                seq, int(next_tok[lane]))
-                        else:
-                            seq.state = _DECODE
-                            self._trace_top2(seq, lane)
-                            self._emit_token(seq, int(next_tok[lane]))
-            m = self.metrics()
-            if m is not None:
-                m["tokens"].inc(chunk_tokens, tags={"phase": "prefill"})
-        # ---- token-level decode batch
+            step_tokens += self._dispatch_prefill(step, prefill_args)
         if decode_args:
-            t_dec = phase("decode_build")
-            b = self.max_batch
-            tokens = np.zeros((b, 1), np.int32)
-            slot_arr = np.zeros((b, 1), np.int32)
-            q_pos = np.zeros((b, 1), np.int32)
-            last_idx = np.zeros((b,), np.int32)
-            if not self._paged_warm:
-                self._paged_warm = True
-                self._warm_paged_buckets()
-                # the one-time warm-up is this phase's, not decode_secs'
-                t_dec = time.perf_counter()
-            # page-granular context: block tables + context lengths.
-            # The table width snaps to the smallest
-            # _paged_width_buckets() entry covering the max used pages
-            # across lanes: decode cost tracks USED context, and the jit
-            # retrace per bucket is O(log pages_per_seq) traces total.
-            max_used = max(-(-n // self.page_size)
-                           for *_a, n in decode_args)
-            width = next(w for w in self._paged_width_buckets()
-                         if w >= max_used)
-            block_tables = np.zeros((b, width), np.int32)
-            context_lens = np.zeros((b,), np.int32)
-            for lane, (seq, last, slot, table, n) \
-                    in enumerate(decode_args):
-                tokens[lane, 0] = last
-                slot_arr[lane, 0] = slot
-                used = -(-n // self.page_size)
-                block_tables[lane, :used] = table[:used]
-                context_lens[lane] = n
-                q_pos[lane, 0] = seq.pos
-            windows = self._window_arrays(
-                [(lane, seq.windows, n - 1, n) for lane, (seq, *_a, n)
-                 in enumerate(decode_args)], b, 1, width, decode=True) \
-                if self._windows else None
-            phase("decode_dispatch")
-            next_tok, self._pools = self._forward(
-                tokens, slot_arr, None, None, None, q_pos, last_idx,
-                block_tables=block_tables, context_lens=context_lens,
-                windows=windows)
-            phase("decode_sync")
-            # device sync: real step cost
-            next_tok = self._split_counters(np.asarray(next_tok), b,
-                                            "decode")
-            decode_dt = phase("decode_emit") - t_dec
-            self._decode_steps += 1
-            self._decode_secs += decode_dt
-            self._totals["decode_lane_steps_total"] += len(decode_args)
-            with self._lock:
-                for lane, (seq, *_rest) in enumerate(decode_args):
-                    if seq.done:
-                        continue  # cancelled while we computed
-                    seq.pos += 1
-                    self._trace_top2(seq, lane)
-                    self._emit_token(seq, int(next_tok[lane]))
+            self._dispatch_decode(step, decode_args, feed)
+            self._totals["runahead_decode_steps_total"] += ahead
             step_tokens += len(decode_args)
-            m = self.metrics()
-            if m is not None:
-                m["tokens"].inc(len(decode_args), tags={"phase": "decode"})
-                m["decode_step"].observe(decode_dt)
+        # the previous step's outputs; this step's too where a prompt
+        # ended whose pages are to be shipped (`prefill_request` waits
+        # for them, and the export reads the pools behind every pass)
+        exports = any(seq.prefill_export and hi == len(seq.prefill_tokens)
+                      for seq, _lo, hi, *_r in prefill_args)
+        read = self._read_back(None if exports else step)
+        if not (prefill_args or decode_args or read):
+            with self._lock:
+                self._last_batch = 0
+                self._set_gauges()  # idle must publish zeros, not
+                # freeze the last busy step's values into the ring
+            return imported  # an import that finished immediately
+            # (max_new=1 / eos) still counts as work done
         self._steps += 1
         self._last_batch = len(decode_args)
         # step-cost estimate for deadline admission (prefill + one
@@ -1688,6 +1687,180 @@ class LLMEngine:
                 + 0.1 * min(dt, 5.0 * self._step_ewma)
         self._set_gauges(len(decode_args), step_tokens)
         return True
+
+    def _dispatch_prefill(self, step: int, prefill_args) -> int:
+        """Chunked prefill, batched across lanes: up to prefill_lanes
+        sequences advance one chunk each in ONE pass of fixed shape
+        (lanes x chunk, empty lanes are garbage), in the steps where a
+        prompt waits — a burst of N admissions costs N/lanes passes,
+        while a LONG prompt still shares the loop with in-flight decodes
+        instead of monopolizing it.  The context the pass gathers is as
+        wide as the smallest _prefill_ctx_buckets() entry covering its
+        longest lane: its cost tracks USED context, at one program a
+        bucket.  Returns the prompt tokens the pass holds."""
+        np = self._np
+        phase = self._clock.phase
+        phase("prefill_build")
+        ctx_rows = [hi for _s, _lo, hi, *_r in prefill_args]
+        longest = max(ctx_rows)
+        width = next(w for w in self._prefill_widths if w >= longest)
+        if not self._prefill_warm:
+            self._prefill_warm = True
+            t0 = time.perf_counter()
+            self._warm_prefill_buckets(but=width)
+            self._warm_secs["prefill"] += time.perf_counter() - t0
+        lanes = self.prefill_lanes
+        c = self.prefill_chunk
+        tokens = np.zeros((lanes, c), np.int32)
+        slot_arr = np.zeros((lanes, c), np.int32)
+        ctx = np.zeros((lanes, width), np.int32)
+        ctx_pos = np.zeros((lanes, width), np.int32)
+        ctx_mask = np.zeros((lanes, width), bool)
+        q_pos = np.zeros((lanes, c), np.int32)
+        last_idx = np.zeros((lanes,), np.int32)
+        for lane, (seq, lo, hi, toks, slots, ctx_slots) \
+                in enumerate(prefill_args):
+            tokens[lane, :hi - lo] = toks
+            slot_arr[lane, :hi - lo] = slots
+            ctx[lane, :hi] = ctx_slots
+            ctx_pos[lane, :hi] = self._arange[:hi]
+            ctx_mask[lane, :hi] = True
+            q_pos[lane, :hi - lo] = self._arange[lo:hi]
+            last_idx[lane] = hi - lo - 1
+        windows = self._window_arrays(
+            [(lane, seq.windows, lo, hi) for lane, (seq, lo, hi, *_r)
+             in enumerate(prefill_args)], lanes, c, width) \
+            if self._windows else None
+        phase("prefill_dispatch", width=width)
+        out, top2 = self._forward(
+            tokens, slot_arr, ctx, ctx_pos, ctx_mask, q_pos, last_idx,
+            windows=windows)
+        self._feed[1] = out
+        self._prefill_steps += 1
+        chunk_tokens = sum(hi - lo for _s, lo, hi, *_r in prefill_args)
+        self._totals["prefill_tokens_total"] += chunk_tokens
+        self._totals["prefill_slots_total"] += lanes * c
+        self._totals["prefill_ctx_rows_total"] += sum(ctx_rows)
+        self._totals["prefill_ctx_cols_total"] += lanes * width
+        self._prefill_passes_by_width[width] += 1
+        owed = []
+        with self._lock:
+            for lane, (seq, lo, hi, *_rest) in enumerate(prefill_args):
+                if seq.done:
+                    continue  # cancelled mid-build: pages already back
+                seq.pos = hi
+                # the pages this chunk completes are immutable from this
+                # pass on, and the pass is dispatched: enter them into
+                # the prefix index, so later admissions with the same
+                # prompt prefix share them (their passes run after it)
+                self._register_prefix_pages(seq)
+                if hi == len(seq.prefill_tokens):
+                    seq.state = _SHIP if seq.prefill_export else _DECODE
+                    seq.ahead += 1
+                    seq.feed = self._no_feed[0].shape[0] + lane
+                    owed.append((lane, seq))
+        self._flight.append(_Flight("prefill", step, out, top2, owed))
+        m = self.metrics()
+        if m is not None:
+            m["tokens"].inc(chunk_tokens, tags={"phase": "prefill"})
+        return chunk_tokens
+
+    def _dispatch_decode(self, step: int, decode_args, feed) -> None:
+        """The token-level decode batch: one position of every decoding
+        sequence, its input token taken from `feed` (the last step's
+        outputs, on the device) where the host has not read it yet."""
+        np = self._np
+        phase = self._clock.phase
+        phase("decode_build")
+        b = self.max_batch
+        tokens = np.zeros((b, 1), np.int32)
+        src = np.full((b,), -1, np.int32)
+        slot_arr = np.zeros((b, 1), np.int32)
+        q_pos = np.zeros((b, 1), np.int32)
+        last_idx = np.zeros((b,), np.int32)
+        if not self._paged_warm:
+            self._paged_warm = True
+            t0 = time.perf_counter()
+            self._warm_paged_buckets()
+            self._warm_secs["decode"] += time.perf_counter() - t0
+        # page-granular context: block tables + context lengths.  The
+        # table width snaps to the smallest _paged_width_buckets() entry
+        # covering the max used pages across lanes: decode cost tracks
+        # USED context, and the jit retrace per bucket is
+        # O(log pages_per_seq) traces total.
+        max_used = max(-(-n // self.page_size) for *_a, n in decode_args)
+        width = next(w for w in self._paged_width_buckets()
+                     if w >= max_used)
+        block_tables = np.zeros((b, width), np.int32)
+        context_lens = np.zeros((b,), np.int32)
+        for lane, (seq, last, late, slot, table, n) \
+                in enumerate(decode_args):
+            tokens[lane, 0] = last
+            src[lane] = late
+            slot_arr[lane, 0] = slot
+            used = -(-n // self.page_size)
+            block_tables[lane, :used] = table[:used]
+            context_lens[lane] = n
+            q_pos[lane, 0] = n - 1
+        windows = self._window_arrays(
+            [(lane, seq.windows, n - 1, n) for lane, (seq, *_a, n)
+             in enumerate(decode_args)], b, 1, width, decode=True) \
+            if self._windows else None
+        phase("decode_dispatch")
+        out, top2 = self._forward(
+            tokens, slot_arr, None, None, None, q_pos, last_idx,
+            block_tables=block_tables, context_lens=context_lens,
+            windows=windows, feed=(src, tuple(feed)))
+        self._feed[0] = out
+        self._decode_steps += 1
+        self._totals["decode_lane_steps_total"] += len(decode_args)
+        owed = []
+        with self._lock:
+            for lane, (seq, *_rest) in enumerate(decode_args):
+                if seq.done:
+                    continue  # cancelled while we built
+                seq.pos += 1
+                seq.ahead += 1
+                seq.feed = lane
+                owed.append((lane, seq))
+        self._flight.append(_Flight("decode", step, out, top2, owed))
+        m = self.metrics()
+        if m is not None:
+            m["tokens"].inc(len(decode_args), tags={"phase": "decode"})
+
+    def _read_back(self, before: Optional[int] = None) -> bool:
+        """Read the passes in flight that steps before `before`
+        dispatched (all of them without it), oldest first, and emit
+        their tokens: the `sync` phase is the wait for the device, the
+        `emit` phase the lock section.  A sequence that ended while its
+        token was in flight gets none.  True when a pass was read."""
+        np = self._np
+        phase = self._clock.phase
+        read = False
+        while self._flight and (before is None
+                                or self._flight[0].step < before):
+            rec = self._flight.popleft()
+            read = True
+            phase(rec.kind + "_sync", of=rec.step)
+            host = np.asarray(rec.out)  # device sync: the pass is done
+            top2 = rec.top2 and tuple(np.asarray(x) for x in rec.top2)
+            phase(rec.kind + "_emit")
+            lanes = host.shape[0] - len(self._model_counters)
+            toks = self._split_counters(host, lanes, rec.kind)
+            with self._lock:
+                for lane, seq in rec.lanes:
+                    seq.ahead -= 1
+                    if seq.done:
+                        # it ended (eos, cancel, expiry) while this
+                        # token was in flight; its pages are back
+                        self._totals["decode_lane_steps_wasted_total"] \
+                            += rec.kind == "decode"
+                    elif seq.prefill_export:
+                        self._export_seq_locked(seq, int(toks[lane]))
+                    else:
+                        self._trace_top2(seq, lane, top2)
+                        self._emit_token(seq, int(toks[lane]))
+        return read
 
     def warm_up(self) -> None:
         """Compile every program traffic can reach, before any loop or
@@ -1713,9 +1886,11 @@ class LLMEngine:
                     with self._clock.span("llm.park"), self._cond:
                         if not self._queued and not self._active:
                             self._cond.wait(0.05)
+            self.drain()  # stopped with a step in flight: its tokens
             return {"steps": self._steps}
         except BaseException as e:
             # a broken engine must fail its consumers, not hang them
+            self._flight.clear()
             with self._lock:
                 for seq in list(self._active) + list(self._queued):
                     if not seq.done:
@@ -1730,9 +1905,12 @@ class LLMEngine:
                 self._loop_running = False
 
     def stop(self) -> None:
+        """End the pinned loop, which reads what it has in flight on its
+        way out; an engine stepped inline is drained here."""
         self._stopped.set()
         with self._cond:
             self._cond.notify_all()
+        self._drain_inline()
 
     # ------------------------------------------------------- inline driving
 
@@ -1756,6 +1934,7 @@ class LLMEngine:
         while any(not s.done for s in seqs):
             if not self.step():
                 time.sleep(0.001)
+        self.drain()  # a lane-step behind an `eos`: nothing stays unread
         for s in seqs:
             self.release(s)
         return [list(s.generated) for s in seqs]
@@ -1809,9 +1988,11 @@ class LLMEngine:
                     "platform": self.platform,
                     "kernel_mode": self.kernel_mode,
                     "decode_steps": self._decode_steps,
-                    "decode_secs": self._decode_secs,
+                    "decode_secs": self._clock.pass_secs("decode")
+                    - self._warm_secs["decode"],
                     "prefill_steps": self._prefill_steps,
-                    "prefill_secs": self._prefill_secs,
+                    "prefill_secs": self._clock.pass_secs("prefill")
+                    - self._warm_secs["prefill"],
                     "step_secs": self._clock.step_secs,
                     "phase_secs": dict(self._clock.phase_secs),
                     **self._totals,
@@ -1856,7 +2037,11 @@ class LLMEngine:
     def save_state(self) -> Dict[str, Any]:
         """Snapshot of in-flight sequences for ``__rt_save__``: prompt +
         tokens generated so far.  Tiny (token ids only) — params and KV
-        pages are reconstructed, not saved."""
+        pages are reconstructed, not saved.  An engine stepped inline is
+        drained first; under a pinned loop a token in flight is the
+        loop's to read, and one the snapshot misses is computed again by
+        the engine that restores it."""
+        self._drain_inline()
         with self._lock:
             seqs = []
             for seq in list(self._active) + list(self._queued):

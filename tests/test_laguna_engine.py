@@ -15,7 +15,7 @@ import numpy as np
 
 from benchmarks import reference_laguna as ref
 from ray_tpu.models.laguna import LagunaConfig
-from ray_tpu.serve.llm import LLMEngine, _jitted_forward
+from ray_tpu.serve.llm import LLMEngine
 
 CFG = dataclasses.replace(LagunaConfig.tiny(), dtype=jnp.float32,
                           max_position_embeddings=384)
@@ -205,6 +205,38 @@ def test_shipped_kv_rows_carry_the_window_layers_live_rows():
     assert dec.stats()["prefill_steps"] == 0
 
 
+def test_window_pages_released_and_reused_under_runahead():
+    """Decoding far past the window with a step always in flight:
+    `advance` gives a window page back while the pass that last read it
+    may still be queued, and another sequence's later pass takes it —
+    safe because the device runs passes in the order they were
+    dispatched.  Every token is the reference's argmax."""
+    eng = _engine()
+    prompts = [_prompt(n, salt=9) for n in (30, 45, 70, 12)]
+    seqs = [eng.submit({"tokens": p, "max_new_tokens": m})
+            for p, m in zip(prompts, (60, 40, 50, 70))]
+    while any(s.state != "decode" for s in seqs):
+        eng.step()
+    before = eng.stats()
+    group = eng._windows["window"]
+    allocated = group.allocated_total
+    _drain(eng)
+    st = eng.stats()
+    # decoding alone gave pages back and took pages that had been held
+    assert st["kv_window_pages_released_total"] \
+        - before["kv_window_pages_released_total"] >= 12
+    assert group.allocated_total - allocated >= 12
+    steps = st["decode_steps"] - before["decode_steps"]
+    assert (st["runahead_decode_steps_total"]
+            - before["runahead_decode_steps_total"]) / steps > 0.9
+    assert st["decode_lane_steps_wasted_total"] == 0
+    outs = [list(s.generated) for s in seqs]
+    refs = ref.teacher_forced(eng._params, prompts, outs, SIZES)
+    for p, out, r in zip(prompts, outs, refs):
+        assert out == r["top_id"], f"prompt of {len(p)}"
+    assert st["kv_pages_in_use"] == {"full": 0, "window": 0}
+
+
 def test_a_llama_engine_is_the_parents():
     """ISSUE 28 point 2: for a model whose cache has one kind the engine
     allocates the pools and compiles the programs the parent did.  The
@@ -216,23 +248,16 @@ def test_a_llama_engine_is_the_parents():
     `param_bytes` 427,264 -> 214,272, and the step no longer rounds them
     (a flop an entry by the compiler's count): flops 1,340,640 ->
     1,234,144, bytes accessed 3,119,880 -> 2,906,888, transcendentals
-    and the pools as they were (8d027c8 -> ISSUE 29's commit)."""
-    import jax
-
+    and the pools as they were (8d027c8 -> ISSUE 29's commit).  ISSUE 31
+    added the token feed (a gather of 4 lanes from the last step's
+    outputs and a select): flops + 36, bytes accessed + 192."""
     eng = LLMEngine(model="tiny", page_size=8, max_batch=4)
     rep = eng.device_report()
     assert (rep["param_bytes"], rep["kv_pool_bytes"]) == (214272, 133120)
     assert rep["model"]["family"] == "llama" and rep["model"]["share"] is None
     assert eng._windows == {} and eng.prefix_sharing
-    (tokens, slots, _c, _p, _m, q_pos, last), kw = \
-        eng._garbage_decode_args(4)
-    assert kw["windows"] == {}
-    cost = _jitted_forward(0.0, 0, False).lower(
-        eng._model, eng._params, eng._pools["k"], eng._pools["v"], tokens,
-        q_pos, last, jax.numpy.zeros((2,), "uint32"),
-        {"full": {"slots": slots, "block_tables": kw["block_tables"],
-                  "context_lens": kw["context_lens"]}}
-    ).compile().cost_analysis()
+    assert eng._garbage_decode_args(4)[1]["windows"] == {}
+    cost = eng._lower_decode(4).compile().cost_analysis()
     assert (cost["flops"], cost["bytes accessed"],
-            cost["transcendentals"]) == (1234144.0, 2906888.0, 1380.0)
+            cost["transcendentals"]) == (1234180.0, 2907080.0, 1380.0)
     assert "moe_assignments_total" not in eng.stats()

@@ -77,10 +77,12 @@ def test_phases_add_up_to_the_step_and_the_passes_keep_their_meaning():
     p = d["phase_secs"]
     assert all(v > 0 for v in p.values()), p
     assert sum(p.values()) == pytest.approx(d["step_secs"], rel=1e-9)
-    assert p["decode_build"] + p["decode_dispatch"] + p["decode_sync"] \
-        == pytest.approx(d["decode_secs"], rel=1e-9)
-    assert p["prefill_build"] + p["prefill_dispatch"] + p["prefill_sync"] \
-        == pytest.approx(d["prefill_secs"], rel=1e-9)
+    # a pass's seconds are its four phases: this step's build and
+    # dispatch, the wait for the step before and its emission
+    for kind in ("decode", "prefill"):
+        assert sum(p[f"{kind}_{part}"] for part in
+                   ("build", "dispatch", "sync", "emit")) \
+            == pytest.approx(d[f"{kind}_secs"], rel=1e-9)
     assert d["decode_steps"] > 0 and d["prefill_steps"] > 0
     # a step that finds nothing to do is a step too: all of it is `admit`
     idle = eng.stats()
@@ -92,6 +94,34 @@ def test_phases_add_up_to_the_step_and_the_passes_keep_their_meaning():
     # start-up: the weights, the pools, the warm-up's compiles
     assert set(eng.startup_secs) == {"params", "pools", "warm"}
     assert all(v > 0 for v in eng.stats()["startup_secs"].values())
+
+
+def test_a_sync_span_names_the_step_it_waits_for():
+    """The engine reads step n's outputs after it dispatched step n+1:
+    `llm.prefill.sync` and `llm.decode.sync` inside `llm.step` n+1 carry
+    `of` = n, and every pass dispatched is read back once."""
+    eng = _engine()
+    spans, real = [], eng._clock.span
+
+    def spy(name, **args):
+        spans.append((name, args))
+        return real(name, **args)
+
+    eng._clock.span = spy
+    before = eng.stats()
+    _drain(eng, [eng.submit(_request(i, max_new=6)) for i in range(3)])
+    d = _delta(eng.stats(), before)
+    step, waited = None, {"llm.prefill.sync": [], "llm.decode.sync": []}
+    for name, args in spans:
+        if name == "llm.step":
+            step = args["n"]
+        elif name in waited:
+            assert args["of"] == step - 1, (name, args, step)
+            waited[name].append(args["of"])
+    assert len(waited["llm.decode.sync"]) == d["decode_steps"] > 0
+    assert len(waited["llm.prefill.sync"]) == d["prefill_steps"] > 0
+    for ofs in waited.values():
+        assert ofs == sorted(set(ofs))
 
 
 def test_request_and_work_counters_equal_what_was_sent():
@@ -226,9 +256,15 @@ def test_compiles_are_counted_and_say_which_phase_and_step():
     assert after["compile_secs_total"] > warm["compile_secs_total"]
     report = eng.device_report()
     assert report["compiles_total"] >= after["compiles_total"]
-    last = report["recent_compiles"][-1]
-    assert last["phase"] == "prefill_dispatch" and last["step"] == step
-    assert last["secs"] > 0
+    # (the decode pass behind it takes that pass's output, of another
+    # length, as its token feed: a new program too, in ITS dispatch)
+    new = report["recent_compiles"][
+        warm["compiles_total"] - after["compiles_total"]:]
+    first = new[0]
+    assert first["phase"] == "prefill_dispatch" and first["step"] == step
+    assert first["secs"] > 0
+    assert {c["phase"] for c in new} == {"prefill_dispatch",
+                                         "decode_dispatch"}
     # a thread that named nothing: its compiles carry no phase
     ops.note_phase(None)
     jnp.zeros((3, 5, 7)).block_until_ready()

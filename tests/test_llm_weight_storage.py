@@ -19,7 +19,7 @@ import pytest
 from ray_tpu.models import cache as kv_cache
 from ray_tpu.models import llama
 from ray_tpu.models.llama import LlamaConfig, LlamaModel
-from ray_tpu.serve.llm import LLMEngine, _jitted_forward
+from ray_tpu.serve.llm import LLMEngine
 
 SEED, PAGE, LANES = 5, 8, 4
 TOKENS = np.zeros((1, 8), np.int32)
@@ -255,14 +255,7 @@ def test_a_stage_state_is_float32_leaf_for_leaf():
 
 
 def _decode_text(engine):
-    (tokens, slots, _c, _p, _m, q_pos, last), kw = \
-        engine._garbage_decode_args(4)
-    return _jitted_forward(0.0, 0, False).lower(
-        engine._model, engine._params, engine._pools["k"],
-        engine._pools["v"], tokens, q_pos, last,
-        jnp.zeros((2,), "uint32"),
-        {"full": {"slots": slots, "block_tables": kw["block_tables"],
-                  "context_lens": kw["context_lens"]}}).as_text()
+    return engine._lower_decode(4).as_text()
 
 
 def _matrix_converts(text, params):

@@ -256,6 +256,7 @@ def test_prefill_width_buckets_keep_the_full_forwards_tokens(
                                 "max_new_tokens": 3}))
     before = eng.stats()["prefill_passes_by_width"]
     eng.step()   # the long prompt's last chunk, beside the short prompt
+    eng.drain()  # its tokens are read a step late
     assert all(s.generated for s in seqs)
     after = eng.stats()["prefill_passes_by_width"]
     width = next(w for w in WIDE_BUCKETS if w >= n_prompt)
@@ -956,7 +957,7 @@ def test_llm_sse_end_to_end(llm_cluster, llm_big):
 
 @pytest.fixture(scope="module")
 def llm_big(llm_cluster):
-    """One bigger-context deployment shared by the shed and disconnect
+    """One bigger-context deployment shared by the end-to-end and trace
     tests (replica processes pay ~10s of eager flax init here — one
     deployment, two tests)."""
     return llm_cluster["deploy"]("llm_big",
@@ -1003,26 +1004,43 @@ def test_llm_stream_trace_reaches_into_the_engine(llm_cluster, llm_big):
     assert by_name["llm.decode"]["attrs"]["tokens_generated"] == 5
 
 
-def test_llm_queue_full_sheds_503(llm_cluster, llm_big):
+@pytest.fixture(scope="module")
+def llm_slow_steps(llm_cluster):
+    """A deliberately BIGGER model (~15-40ms/step vs ~2ms for the tiny
+    config) shared by the shed, disconnect and deadline tests: all need
+    the decode to still be RUNNING when their trigger lands — the tiny
+    config's 240 tokens can finish before a second request, a disconnect
+    RST or a sub-second deadline is even noticed.  One queue slot (the
+    shed test's; the others send one request at a time)."""
+    return llm_cluster["deploy"]("llm_drop",
+                                 model=dict(MODEL, dim=192, n_layers=4,
+                                            hidden_dim=512,
+                                            max_seq_len=256),
+                                 num_pages=33, max_queue=1,
+                                 detach_grace_s=0.3)
+
+
+def test_llm_queue_full_sheds_503(llm_cluster, llm_slow_steps):
     """Admission past the bounded queue answers 503 BEFORE any SSE
     bytes (the first-item prefetch maps LLMOverloadedError to the shed
     gate) — and below capacity a queued request gets 200, not shed."""
-    h = llm_big
+    h = llm_slow_steps
     host, port = llm_cluster["host"], llm_cluster["port"]
-    # hold most of the page budget with a long generation (26 of 32
-    # usable pages)...
-    c1, r1 = _sse_request(host, port, "llm_big",
-                          {"tokens": [1, 2, 3], "max_new_tokens": 200})
+    # hold EVERY page (32 of 8 slots for 253 tokens) with a generation as
+    # long as the context allows, on the slow model: 250 steps of 15 ms
+    # and more cannot end before the two requests below have landed...
+    c1, r1 = _sse_request(host, port, "llm_drop",
+                          {"tokens": [1, 2, 3], "max_new_tokens": 250})
     assert r1.status == 200
     r1.read(1)  # first token arrived: sequence is active
-    # ...then a request too big for the REMAINING pages parks in the
-    # single queue slot (on a thread: its response line only arrives
-    # once its first token does, i.e. after r1 finishes)
+    # ...so a second request parks in the single queue slot (on a
+    # thread: its response line only arrives once its first token does,
+    # i.e. after the holder's pages are back)
     q_result = {}
 
     def _queued_request():
-        c2, r2 = _sse_request(host, port, "llm_big",
-                              {"tokens": [4, 5], "max_new_tokens": 60},
+        c2, r2 = _sse_request(host, port, "llm_drop",
+                              {"tokens": [4, 5], "max_new_tokens": 20},
                               timeout=120)
         q_result["status"] = r2.status
         q_result["items"] = _read_items(r2)
@@ -1031,37 +1049,27 @@ def test_llm_queue_full_sheds_503(llm_cluster, llm_big):
     t = threading.Thread(target=_queued_request)
     t.start()
     deadline = time.time() + 60
+    st = {}
     while time.time() < deadline:
-        if ray_tpu.get(h.method("stats")(), timeout=30)["queued"] >= 1:
+        st = ray_tpu.get(h.method("stats")(), timeout=30)
+        if st["queued"] >= 1:
             break
         time.sleep(0.05)
-    assert ray_tpu.get(h.method("stats")(), timeout=30)["queued"] >= 1
+    assert st["queued"] >= 1 and st["active"] == 1, st
     # the third concurrent stream sheds with a real status code
-    c3, r3 = _sse_request(host, port, "llm_big",
+    c3, r3 = _sse_request(host, port, "llm_drop",
                           {"tokens": [6], "max_new_tokens": 2})
     assert r3.status == 503, r3.status
     c3.close()
-    r1.read()  # drain the long stream: frees pages for r2
+    # drop the holder (RST): its pages come back after the grace window
+    c1.sock.setsockopt(socket.SOL_SOCKET, socket.SO_LINGER,
+                       b"\x01\x00\x00\x00\x00\x00\x00\x00")
     c1.close()
     t.join(120)
     assert not t.is_alive()
     # below capacity = no shed: the queued request completed normally
     assert q_result["status"] == 200
-    assert sum(len(it["tokens"]) for it in q_result["items"]) == 60
-
-
-@pytest.fixture(scope="module")
-def llm_slow_steps(llm_cluster):
-    """A deliberately BIGGER model (~15-40ms/step vs ~2ms for the tiny
-    config) shared by the disconnect and deadline tests: both need the
-    decode to still be RUNNING when their trigger lands — the tiny
-    config's 240 tokens can finish before a disconnect RST or a
-    sub-second deadline is even noticed."""
-    return llm_cluster["deploy"]("llm_drop",
-                                 model=dict(MODEL, dim=192, n_layers=4,
-                                            hidden_dim=512,
-                                            max_seq_len=256),
-                                 num_pages=33, detach_grace_s=0.3)
+    assert sum(len(it["tokens"]) for it in q_result["items"]) == 20
 
 
 def test_llm_disconnect_frees_kv_pages(llm_cluster, llm_slow_steps):
