@@ -786,6 +786,26 @@ def llm_metrics() -> Tuple[Counter, Gauge, Gauge, Histogram, Gauge, Gauge,
     return _llm_metrics
 
 
+_train_step_counters: Dict[str, Counter] = {}
+
+
+def train_step_counters(name: str) -> Counter:
+    """Process-singleton counters of a training step's own counts
+    (train/gspmd.py `TrainState.read`): ``ray_tpu_<name>``, one for each
+    entry the model family's training module names in `train_counters`
+    — the expert layers' ``train_moe_assignments_total``,
+    ``train_moe_expert_calls_total``, ``train_moe_max_load_total``,
+    ``train_moe_row_tiles_active_total`` / ``train_moe_row_tiles_total``
+    and ``train_moe_layer_passes_total``, summed on the device over the
+    step's layers and read back with the loss."""
+    counter = _train_step_counters.get(name)
+    if counter is None:
+        counter = _train_step_counters[name] = Counter(
+            f"ray_tpu_{name}", "summed over a training step's layers "
+            "on the device, read back with the loss")
+    return counter
+
+
 _llm_prefix_metrics: Optional[Tuple[Counter, Counter]] = None
 
 

@@ -19,6 +19,25 @@ and whose config states its cache by layer (models/cache.py).  The plain
 reference of a family lives with the benchmark (`benchmarks/reference*`)
 and reads nothing but the parameter tree.
 
+The TRAIN side (`train/gspmd.build_train_state(model=...)`) is three
+more names of the same module:
+
+    train_build(cfg, kernel)  the training module: `init(rng, tokens)`
+                           and `apply(params, tokens)` over a whole
+                           sequence, no cache -> logits, or (logits,
+                           counters, aux) where `train_counters` names
+                           the entries of the int32 counter vector that
+                           the step sums on the device and hands out
+                           beside the loss.  `kernel` is the mesh's
+                           attention (None: `default_attention`).  Unlike
+                           `build` it keeps float32 masters where the
+                           config's `param_dtype` says so, rounds them to
+                           `dtype` for each product, and recomputes each
+                           block in the backward
+    train_loss(logits, tokens)  the scalar the step differentiates
+    param_rules()          PartitionSpecs by parameter-path substring for
+                           `parallel.mesh.init_sharded`
+
 `resolve(model)` picks the family: a dictionary's `model_type`, or the
 family whose config class the instance is; a dictionary without
 `model_type`, and a preset's name, are Llama's.
@@ -33,7 +52,14 @@ __all__ = ["LlamaConfig", "LlamaModel", "llama_param_rules", "resolve",
            "FAMILIES"]
 
 # `model_type` -> the module of this package that implements it
-FAMILIES = {"llama": "llama", "mistral": "llama", "laguna": "laguna"}
+FAMILIES = {"llama": "llama", "mistral": "llama", "laguna": "laguna",
+            "mellum": "laguna"}
+# keys that a `model_type`'s published config class defaults, so that a
+# dictionary of that type may leave them out (a family's `from_dict`
+# takes an absent key for an absent mechanism).  `benchmarks/kinds/
+# serve_laguna.py` hands the engine its keys without `gating`; once it
+# lists the key this entry can go.
+CLASS_DEFAULTS = {"laguna": {"gating": "per-head"}}
 
 
 def resolve(model: Any) -> Tuple[Any, Any]:
@@ -45,6 +71,7 @@ def resolve(model: Any) -> Tuple[Any, Any]:
             raise ValueError(f"no model family for model_type "
                              f"{model_type!r} (have {sorted(FAMILIES)})")
         name = FAMILIES[model_type]
+        model = {**CLASS_DEFAULTS.get(model_type, {}), **model}
     elif isinstance(model, str):
         name = "llama"
     else:

@@ -1,21 +1,33 @@
-"""Laguna-family decoder: gated attention whose head count and kind
-(full or sliding window) change by layer, a dense lead, then layers of
-routed experts with one shared expert.
+"""The expert-layer decoder family: attention whose kind (full or
+sliding window) and head count change by layer, rotary tables by layer
+kind, and MLPs that are dense or routed experts by layer.  Two published
+models are settings of it, told apart by the keys their `config.json`
+has and never by name:
 
-Written from the published `config.json` keys (`model_type: laguna`):
-`layer_types`, `num_attention_heads_per_layer`, `mlp_layer_types`,
+    `model_type: laguna`  a head-wise attention gate (`gating`; `gated`
+        here), a dense leading layer, a shared expert beside the routed ones
+        (`shared_expert_intermediate_size`), a factor on the routed sum
+        (`moe_routed_scaling_factor`), 48 or 72 heads by layer
+        (`num_attention_heads_per_layer`) — served through LLMEngine
+    `model_type: mellum`  none of those keys: no gate, no
+        shared expert, every layer sparse, one head count
+        (`num_attention_heads`) — trained through train/gspmd.py
+
+A mechanism whose key is absent is not there (`LagunaConfig.from_dict`).
+Written from the published keys: `layer_types`, `mlp_layer_types`,
 `sliding_window`, `rope_parameters` by layer kind, `num_experts`,
-`num_experts_per_tok`, `moe_routed_scaling_factor`, `gating`.  For layer
-l with input x [S, D], H_l heads and h = RMSNorm(x):
+`num_experts_per_tok`, `norm_topk_prob`.  For layer l with input x
+[S, D], H_l heads and h = RMSNorm(x):
 
     q = h Wq [S, H_l, hd]; k = h Wk, v = h Wv [S, Hkv, hd]
     rotary by kind: a sliding layer rotates the whole head at its theta;
       a full layer rotates the first `partial_rotary_factor` of the head
       with YaRN's frequencies, cos and sin times `attention_factor`
     causal scores / sqrt(hd), a sliding layer sees i - W < j <= i
-    gate g = sigmoid(h Wg) [S, H_l]; x += (g_h * a_h concatenated) Wo
+    gated: g = sigmoid(h Wg) [S, H_l]; x += (g_h * a_h concatenated) Wo
+    ungated: x += (a_h concatenated) Wo
     h' = RMSNorm(x); a dense layer: x += SwiGLU(h')
-    a sparse layer: x += SwiGLU_shared(h') + factor * routed(h')
+    a sparse layer: x += [SwiGLU_shared(h') +] factor * routed(h')
 
 What the config does not say is ONE function each, so that a reader with
 the model's own code corrects it in one place (the configuration file
@@ -24,8 +36,17 @@ lists them under `assumed`): `gate_activation`, `router_scores`,
 
 This chip may hold a share of a layer: `experts_held` = (lo, hi) of the
 router's `num_experts` (ops/moe.py computes that share's part of the
-routed sum) and `vocab_size` rows of the vocabulary.  The matrices are
-stored in `param_dtype`, bfloat16 as the configuration states.
+routed sum), `vocab_size` rows of the vocabulary, and the heads its
+config counts (`num_attention_heads_per_layer`, `num_key_value_heads`:
+a share of the heads is a model with fewer heads whose `wo` adds a
+partial result).  The matrices are stored in `param_dtype`: bfloat16
+for the serving module (`build`), float32 masters for the training one
+(`train_build`).
+
+Without a cache the module runs a whole sequence through
+`llama.default_attention` — the one route, flash kernels with a window
+for long sequences — and, in training mode, returns the expert layers'
+counters and chosen experts beside the logits.
 
 The cache is by layer (`cache_spec`): a full layer keeps every position,
 a sliding layer the last `sliding_window`; the engine hands each kind
@@ -36,6 +57,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, fields
+from functools import partial
 from typing import Any, Dict, Tuple
 
 import flax.linen as nn
@@ -44,7 +66,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models.cache import LayerCache
-from ray_tpu.models.llama import RMSNorm, cached_attention
+from ray_tpu.models.llama import (RMSNorm, cached_attention,
+                                  causal_lm_loss, default_attention)
 from ray_tpu.ops import moe
 
 FULL, SLIDING = "full_attention", "sliding_attention"
@@ -81,22 +104,44 @@ class LagunaConfig:
     num_attention_heads_per_layer: Tuple[int, ...] = ()
     rope_parameters: Tuple = ()        # `_frozen` of the published group
     experts_held: Tuple[int, int] = (0, 256)
+    gated: bool = True                 # the head-wise attention gate
     dtype: Any = jnp.bfloat16          # activations and the KV cache
     param_dtype: Any = jnp.bfloat16    # the stored matrices
 
     @classmethod
     def from_dict(cls, model: Dict[str, Any]) -> "LagunaConfig":
-        """The published keys (and `experts_held`) as a config; keys that
-        say nothing of the shape (`model_type`, `gating`, ...) are read
-        by nobody and left out."""
+        """The published keys (and `experts_held`) as a config.  A
+        mechanism whose key the dictionary lacks is not there: no
+        `gating`, no attention gate; no
+        `shared_expert_intermediate_size`, no shared expert; no
+        `moe_routed_scaling_factor`, factor 1; no
+        `num_attention_heads_per_layer`, `num_attention_heads` on every
+        layer; no `mlp_layer_types`, every layer sparse; no
+        `experts_held`, all of `num_experts`.  (The constructor's own
+        defaults are the gated model's; `models.resolve` gives a
+        dictionary the keys its `model_type`'s config class defaults.)
+        Keys that say nothing of the shape (`model_type`, ...) are read
+        by nobody."""
         names = {f.name for f in fields(cls)}
-        return cls(**{k: _frozen(v) for k, v in model.items()
-                      if k in names})
+        layers = int(model.get("num_hidden_layers", cls.num_hidden_layers))
+        absent = {
+            "gated": "gating" in model,
+            "shared_expert_intermediate_size": 0,
+            "moe_routed_scaling_factor": 1.0,
+            "mlp_layer_types": ("sparse",) * layers,
+            "experts_held": (0, int(model.get("num_experts",
+                                              cls.num_experts)))}
+        if "num_attention_heads" in model:
+            absent["num_attention_heads_per_layer"] = (
+                int(model["num_attention_heads"]),) * layers
+        given = {k: _frozen(v) for k, v in model.items() if k in names}
+        return cls(**{**absent, **given})
 
     @classmethod
     def tiny(cls) -> "LagunaConfig":
         """Test size: the five leading layer kinds, 8 experts, 4 held."""
         return cls.from_dict(dict(
+            gating="per-head", moe_routed_scaling_factor=2.5,
             vocab_size=256, hidden_size=64, intermediate_size=128,
             num_hidden_layers=5, num_key_value_heads=2, head_dim=16,
             max_position_embeddings=256, num_experts=8,
@@ -136,6 +181,28 @@ class LagunaConfig:
         return {"experts_held": list(self.experts_held),
                 "num_experts": self.num_experts,
                 "vocab_rows": self.vocab_size}
+
+    @classmethod
+    def tiny_ungated(cls) -> "LagunaConfig":
+        """Test size of the other setting: two periods of sliding x3 +
+        full, no gate, no shared expert, one head count, 4 of 8 experts
+        held, float32 masters."""
+        return cls.from_dict(dict(
+            vocab_size=256, hidden_size=64, num_hidden_layers=8,
+            num_attention_heads=8, num_key_value_heads=1, head_dim=16,
+            max_position_embeddings=256, num_experts=8,
+            num_experts_per_tok=2, moe_intermediate_size=32,
+            sliding_window=32,
+            layer_types=([SLIDING] * 3 + [FULL]) * 2,
+            rope_parameters={
+                "full_attention": {
+                    "rope_type": "yarn", "rope_theta": 500000,
+                    "factor": 16, "original_max_position_embeddings": 64,
+                    "beta_fast": 32, "beta_slow": 1,
+                    "attention_factor": 1.2772588722239782},
+                "sliding_attention": {
+                    "rope_type": "default", "rope_theta": 500000}},
+            experts_held=[0, 4], param_dtype=jnp.float32))
 
 
 # ------------------------------------------------- the assumed conventions
@@ -221,25 +288,6 @@ def _rotary(x: jax.Array, positions: jax.Array, dim: int,
     return jnp.concatenate([out, x[..., dim:]], axis=-1)
 
 
-def masked_attention(q, k, v, window: int) -> jax.Array:
-    """Causal attention of a whole sequence (no cache), over the last
-    `window` positions where window > 0.  q [B, S, H, D]; k, v [B, S,
-    Hkv, D]."""
-    b, s, h, d = q.shape
-    hkv = k.shape[2]
-    q5 = q.reshape(b, s, hkv, h // hkv, d)
-    logits = jnp.einsum("bshgd,bthd->bhgst", q5, k).astype(jnp.float32)
-    logits = logits / jnp.sqrt(d).astype(jnp.float32)
-    i = jnp.arange(s)[:, None]
-    j = jnp.arange(s)[None, :]
-    mask = j <= i
-    if window > 0:
-        mask = mask & (j > i - window)
-    logits = jnp.where(mask[None, None, None], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
-    return jnp.einsum("bhgst,bthd->bshgd", probs, v).reshape(b, s, h, d)
-
-
 # ----------------------------------------------------------------- modules
 
 
@@ -259,9 +307,12 @@ class SwiGLU(nn.Module):
 
 
 class GatedAttention(nn.Module):
+    """Attention of one layer, with the head-wise gate where the config
+    has one."""
     cfg: LagunaConfig
     layer: int
     page_size: int = 0
+    kernel: Any = None     # a mesh's attention (train/gspmd.py)
 
     @nn.compact
     def __call__(self, x, positions, cache=None):
@@ -279,15 +330,17 @@ class GatedAttention(nn.Module):
         rot = rope_tables(cfg.rope(kind), cfg.head_dim)
         q = _rotary(q, positions, *rot)
         k = _rotary(k, positions, *rot)
-        with jax.named_scope("attn_gate"):
-            gate = gate_activation(
-                dense(n_heads, "attn_gate")(x).astype(jnp.float32))
+        if cfg.gated:
+            with jax.named_scope("attn_gate"):
+                gate = gate_activation(
+                    dense(n_heads, "attn_gate")(x).astype(jnp.float32))
         wo = nn.DenseGeneral(
             features=cfg.hidden_size, axis=(-2, -1), use_bias=False,
             dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="wo")
         pools = None
         if cache is None:
-            out = masked_attention(q, k, v, window)
+            out = (self.kernel or default_attention)(
+                q, k, v, causal=True, window=window)
         else:
             b, s = k.shape[0], k.shape[1]
             flat = cache["slots"].reshape(-1)
@@ -306,14 +359,16 @@ class GatedAttention(nn.Module):
                 out = cached_attention(
                     q, pool_k, pool_v, cache["ctx"], cache["ctx_pos"],
                     cache["ctx_mask"], positions, window=window or None)
-        with jax.named_scope("attn_gate"):
-            out = (out.astype(jnp.float32) * gate[..., None]).astype(
-                cfg.dtype)
+        if cfg.gated:
+            with jax.named_scope("attn_gate"):
+                out = (out.astype(jnp.float32) * gate[..., None]).astype(
+                    cfg.dtype)
         return wo(out), pools
 
 
 class ExpertLayer(nn.Module):
-    """The shared expert plus this share's part of the routed sum."""
+    """This share's part of the routed sum, plus the shared expert where
+    the config has one."""
     cfg: LagunaConfig
 
     @nn.compact
@@ -338,11 +393,14 @@ class ExpertLayer(nn.Module):
             flat, w_router, w1, w3, w2, top_k=cfg.num_experts_per_tok,
             held=(lo, hi), valid=valid.reshape(b * s),
             normalize=cfg.norm_topk_prob, scores=router_scores)
-        shared = SwiGLU(cfg, cfg.shared_expert_intermediate_size,
-                        name="moe_shared")(x)
-        y = combine_shared(shared.astype(jnp.float32),
-                           routed.reshape(b, s, d),
-                           cfg.moe_routed_scaling_factor)
+        routed = routed.reshape(b, s, d)
+        if cfg.shared_expert_intermediate_size:
+            shared = SwiGLU(cfg, cfg.shared_expert_intermediate_size,
+                            name="moe_shared")(x)
+            y = combine_shared(shared.astype(jnp.float32), routed,
+                               cfg.moe_routed_scaling_factor)
+        else:
+            y = cfg.moe_routed_scaling_factor * routed
         return y.astype(cfg.dtype), counters
 
 
@@ -350,13 +408,15 @@ class LagunaBlock(nn.Module):
     cfg: LagunaConfig
     layer: int
     page_size: int = 0
+    kernel: Any = None
 
     @nn.compact
     def __call__(self, x, positions, valid, cache=None):
         cfg = self.cfg
         h = RMSNorm(cfg.rms_norm_eps, name="attn_norm")(x)
         a, pools = GatedAttention(cfg, self.layer, self.page_size,
-                                  name="attn")(h, positions, cache)
+                                  self.kernel, name="attn")(
+            h, positions, cache)
         x = x + a
         h = RMSNorm(cfg.rms_norm_eps, name="mlp_norm")(x)
         counters = None
@@ -372,13 +432,20 @@ class LagunaModel(nn.Module):
     """`forward(tokens, cache)`: with a cache, (logits, pools, counters
     [len(moe.COUNTERS) + 1] int32: `moe.COUNTERS` summed over the sparse
     layers, then the sparse layers passed); without, the logits of the
-    whole sequence."""
+    whole sequence — and, where `train`, (logits, the vector named by
+    `train_counters`, the chosen experts [T, k] of each sparse layer)."""
     cfg: LagunaConfig
     page_size: int = 0
+    kernel: Any = None
+    train: bool = False
 
     # names of the counter vector's entries, for the engine's stats()
     counters = tuple(f"moe_{n}_total" for n in moe.COUNTERS) \
         + ("moe_layer_passes_total", "moe_expert_slots_total")
+    # and of a training step's (train/gspmd.py hands them out)
+    train_counters = tuple(f"train_moe_{n}_total"
+                           for n in moe.TRAIN_COUNTERS) \
+        + ("train_moe_layer_passes_total",)
 
     @nn.compact
     def __call__(self, tokens, cache=None):
@@ -394,30 +461,48 @@ class LagunaModel(nn.Module):
             # slot 0 is the engine's garbage slot: a token written there
             # is padding and is routed to no expert
             valid = cache["groups"]["full"]["slots"] != 0
-        new_k, new_v, totals, passes = [], [], None, 0
+        new_k, new_v, totals, passes, routing = [], [], None, 0, []
+        counted = moe.TRAIN_COUNTERS if self.train else moe.COUNTERS
+        block_cls = LagunaBlock
+        if self.train:
+            # keep only block boundaries; a block's internals (the row
+            # buffers of its expert layer among them) are recomputed
+            block_cls = nn.remat(LagunaBlock, prevent_cse=True)
         for i, spec in enumerate(cfg.cache_spec()):
             layer_cache = None
             if cache is not None:
                 layer_cache = {"k": cache["k"][i], "v": cache["v"][i],
                                **cache["groups"][spec.kind]}
-            x, pools, counters = LagunaBlock(
-                cfg, i, self.page_size, name=f"layer_{i}")(
+            x, pools, counters = block_cls(
+                cfg, i, self.page_size, self.kernel, name=f"layer_{i}")(
                 x, positions, valid, layer_cache)
             if pools is not None:
                 new_k.append(pools[0])
                 new_v.append(pools[1])
             if counters is not None:
                 passes += 1
-                vec = jnp.stack([counters[n] for n in moe.COUNTERS])
+                routing.append(counters["ids"])
+                vec = jnp.stack([counters[n] for n in counted])
                 totals = vec if totals is None else totals + vec
         x = RMSNorm(cfg.rms_norm_eps, name="final_norm")(x)
+        head = {}
+        if self.train:
+            # the loss reads float32 logits: the product of the rounded
+            # operands, accumulated and left in float32
+            head["dot_general"] = partial(
+                jax.lax.dot_general, preferred_element_type=jnp.float32)
         logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                          param_dtype=cfg.param_dtype, name="lm_head")(x)
-        if cache is None:
-            return logits
-        held = cfg.experts_held[1] - cfg.experts_held[0]
+                          param_dtype=cfg.param_dtype, name="lm_head",
+                          **head)(x)
         if totals is None:
-            totals = jnp.zeros((len(moe.COUNTERS),), jnp.int32)
+            totals = jnp.zeros((len(counted),), jnp.int32)
+        if cache is None:
+            if not self.train:
+                return logits
+            return logits, jnp.concatenate([
+                totals.astype(jnp.int32),
+                jnp.asarray([passes], jnp.int32)]), routing
+        held = cfg.experts_held[1] - cfg.experts_held[0]
         vec = jnp.concatenate([
             totals.astype(jnp.int32),
             jnp.asarray([passes, passes * held], jnp.int32)])
@@ -426,6 +511,22 @@ class LagunaModel(nn.Module):
 
 def build(cfg: LagunaConfig, page_size: int) -> LagunaModel:
     return LagunaModel(cfg, page_size=page_size)
+
+
+# the train side of the family interface (models/__init__.py)
+train_loss = causal_lm_loss
+
+
+def train_build(cfg: LagunaConfig, kernel=None) -> LagunaModel:
+    return LagunaModel(cfg, kernel=kernel, train=True)
+
+
+def param_rules() -> Dict[str, Any]:
+    """What `parallel.mesh.param_shardings` would not do by itself
+    (largest dimension over fsdp): norm scales stay whole."""
+    from jax.sharding import PartitionSpec as P
+
+    return {"norm": P(None)}
 
 
 def config(model: Any) -> LagunaConfig:

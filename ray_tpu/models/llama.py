@@ -131,18 +131,21 @@ FLASH_PREFILL_MIN_SEQ = 512
 
 
 def default_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                      causal: bool = True) -> jax.Array:
-    """Reference attention path: XLA fuses this well on its own; the
-    Pallas flash kernel (ray_tpu/ops/flash_attention.py) replaces it for
-    long sequences (>= FLASH_PREFILL_MIN_SEQ, multiple of 128).
+                      causal: bool = True, window: int = 0) -> jax.Array:
+    """The one attention route of a whole sequence, for every family,
+    serving prefill and training alike: XLA fuses the dense math well on
+    its own; the Pallas flash kernels (ray_tpu/ops/flash_attention.py,
+    forward and backward) replace it for long sequences
+    (>= FLASH_PREFILL_MIN_SEQ, multiple of 128).  `window` > 0: a query
+    sees the last `window` positions up to its own (causal only).
     q: [B,S,H,D], k/v: [B,S,Hkv,D]."""
     s, t = q.shape[1], k.shape[1]
     if (causal and s == t and s >= FLASH_PREFILL_MIN_SEQ
             and s % 128 == 0):
         from ray_tpu.ops.flash_attention import flash_attention
 
-        return flash_attention(q, k, v, True)
-    return dense_attention(q, k, v, causal)
+        return flash_attention(q, k, v, True, window=window)
+    return dense_attention(q, k, v, causal, window)
 
 
 def make_mesh_attention(mesh) -> Callable:
@@ -156,9 +159,10 @@ def make_mesh_attention(mesh) -> Callable:
 
     spec = P(("dp", "fsdp"), None, "tp", None)
 
-    def attention(q, k, v, causal: bool = True):
+    def attention(q, k, v, causal: bool = True, window: int = 0):
         return jax.shard_map(
-            partial(default_attention, causal=causal), mesh=mesh,
+            partial(default_attention, causal=causal, window=window),
+            mesh=mesh,
             in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False)(q, k, v)
 
@@ -166,10 +170,10 @@ def make_mesh_attention(mesh) -> Callable:
 
 
 def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                    causal: bool = True) -> jax.Array:
-    """The dense softmax-attention math itself — kept separate from
-    :func:`default_attention` so the flash kernel's recompute backward
-    can target it without re-entering the length-based routing."""
+                    causal: bool = True, window: int = 0) -> jax.Array:
+    """The dense softmax-attention math itself, [S, S] scores and all:
+    what :func:`default_attention` runs below the flash threshold, and
+    what the flash kernels are tested against."""
     b, s, h, d = q.shape
     hkv = k.shape[2]
     group = h // hkv
@@ -178,6 +182,8 @@ def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     logits = logits / jnp.sqrt(d).astype(jnp.float32)
     if causal:
         mask = jnp.tril(jnp.ones((s, s), dtype=bool))
+        if window:
+            mask = mask & ~jnp.tril(jnp.ones((s, s), dtype=bool), -window)
         logits = jnp.where(mask[None, None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhgst,bthd->bshgd", probs, v)
@@ -450,6 +456,14 @@ def config(model: Any) -> LlamaConfig:
     return getattr(LlamaConfig, str(model))()
 
 
+# the train side of the family interface (models/__init__.py)
+
+
+def train_build(cfg: LlamaConfig, kernel: Optional[Callable] = None
+                ) -> LlamaModel:
+    return LlamaModel(cfg, kernel=kernel)
+
+
 def llama_param_rules() -> Dict[str, Any]:
     """PartitionSpec rules by parameter-path substring.
 
@@ -472,6 +486,9 @@ def llama_param_rules() -> Dict[str, Any]:
     }
 
 
+param_rules = llama_param_rules
+
+
 def causal_lm_loss(logits: jax.Array, tokens: jax.Array) -> jax.Array:
     """Next-token cross entropy with shifted targets.
 
@@ -483,3 +500,6 @@ def causal_lm_loss(logits: jax.Array, tokens: jax.Array) -> jax.Array:
     lse = jax.nn.logsumexp(logits, axis=-1)
     picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
     return jnp.mean(lse - picked)
+
+
+train_loss = causal_lm_loss

@@ -83,6 +83,11 @@ def kernel_mode() -> str:
     return "compiled" if jax.devices()[0].platform == "tpu" else "interpret"
 
 
+def interpret_default(interpret: Optional[bool]) -> bool:
+    """A kernel's `interpret` argument: as given, or by the backend."""
+    return kernel_mode() == "interpret" if interpret is None else interpret
+
+
 def device_report() -> Dict[str, Any]:
     """What this process's jax runs on, as jax reports it: the device,
     the Pallas kernel mode, device memory in use and at peak, the chips

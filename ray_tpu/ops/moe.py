@@ -28,20 +28,44 @@ lanes chose, not every expert held.
 `valid` masks tokens that are padding (an empty decode lane, the tail of
 a prefill chunk): they are routed nowhere, touch no expert and count in
 no counter.
+
+The layer is differentiable in x, the router and the three expert
+tensors, so a training step runs the same `moe_layer` as the engine.
+`route` is plain jax and differentiates itself (float32, through the
+top-k's chosen probabilities and the softmax).  Dispatch, experts and
+combine are one `jax.custom_vjp` (`_routed`) whose backward is written
+by hand: a row belongs to one assignment, so the transpose of the
+combine's gather is itself a gather (each row takes its token's dy times
+its routing weight) and that of the dispatch's gather a sum of each
+token's k rows — no scatter-add of wide rows.  Between them two Pallas
+calls, named `moe_experts_bwd_dx` and `moe_experts_bwd_dw`, visit the
+active tiles as the forward does: the first recomputes gate and up and
+gives dh, dgate, dup and dx a row tile; the second accumulates dW1,
+dW3 and dW2 over each expert's row tiles in a block that stays in VMEM
+while the expert lasts.  An expert no token chose is not visited and
+its gradient is a written zero (a select on its count, not an unwritten
+block).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from ray_tpu.ops import interpret_default
 
 __all__ = ["route", "dispatch", "grouped_swiglu", "combine", "moe_layer",
-           "row_tile", "COUNTERS"]
+           "row_tile", "COUNTERS", "TRAIN_COUNTERS"]
 
-# what `moe_layer` counts, in the order of its counter vector
+# what `moe_layer` counts for the engine, in the order of its counter
+# vector; a training step also reads the last two of TRAIN_COUNTERS,
+# the row tiles its dropless buffer filled and had
 COUNTERS = ("assignments", "expert_calls", "max_load")
+TRAIN_COUNTERS = COUNTERS + ("row_tiles_active", "row_tiles")
 
 
 class Dispatch(NamedTuple):
@@ -144,6 +168,31 @@ def _down_kernel(te_ref, na_ref, h_ref, w2_ref, y_ref):
             preferred_element_type=jnp.float32).astype(y_ref.dtype)
 
 
+def _tile_plumbing(tile_expert: jax.Array, active_tiles: jax.Array):
+    """The scalar-prefetch operands, index maps and compiler parameters
+    every grouped kernel shares: grid step i is row tile i; past the
+    active tiles a step keeps the last active tile's indices (no fetch,
+    no write-back) and its body is predicated off."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    na = jnp.reshape(active_tiles, (1,)).astype(jnp.int32)
+    te = tile_expert.astype(jnp.int32)
+
+    def tile(i, te, na):
+        return jnp.minimum(i, jnp.maximum(na[0] - 1, 0))
+
+    def row_map(i, te, na):
+        return (tile(i, te, na), 0)
+
+    def w_map(i, te, na):
+        return (te[tile(i, te, na)], 0, 0)
+
+    params = pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",),
+        vmem_limit_bytes=96 * 1024 * 1024)
+    return te, na, row_map, w_map, params
+
+
 def grouped_swiglu(xs: jax.Array, w1: jax.Array, w3: jax.Array,
                    w2: jax.Array, tile_expert: jax.Array,
                    active_tiles: jax.Array, *, tm: int,
@@ -162,28 +211,12 @@ def grouped_swiglu(xs: jax.Array, w1: jax.Array, w3: jax.Array,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    if interpret is None:
-        from ray_tpu.ops import kernel_mode
-
-        interpret = kernel_mode() == "interpret"
+    interpret = interpret_default(interpret)
     rows, d = xs.shape
     f = w1.shape[-1]
     n_tiles = rows // tm
-    na = jnp.reshape(active_tiles, (1,)).astype(jnp.int32)
-    te = tile_expert.astype(jnp.int32)
-
-    def tile(i, te, na):
-        return jnp.minimum(i, jnp.maximum(na[0] - 1, 0))
-
-    def row_map(i, te, na):
-        return (tile(i, te, na), 0)
-
-    def w_map(i, te, na):
-        return (te[tile(i, te, na)], 0, 0)
-
-    params = pltpu.CompilerParams(
-        dimension_semantics=("arbitrary",),
-        vmem_limit_bytes=96 * 1024 * 1024)
+    te, na, row_map, w_map, params = _tile_plumbing(tile_expert,
+                                                    active_tiles)
     h = pl.pallas_call(
         _up_kernel,
         out_shape=jax.ShapeDtypeStruct((rows, f), xs.dtype),
@@ -209,6 +242,112 @@ def grouped_swiglu(xs: jax.Array, w1: jax.Array, w3: jax.Array,
     )(te, na, h, w2)
 
 
+_NT = (((1,), (1,)), ((), ()))   # a b^T
+_TN = (((0,), (0,)), ((), ()))   # a^T b
+
+
+def _bwd_dx_kernel(te_ref, na_ref, x_ref, dy_ref, w1_ref, w3_ref, w2_ref,
+                   dx_ref, h_ref, dgate_ref, dup_ref):
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(0) < na_ref[0])
+    def _():
+        x, dy = x_ref[...], dy_ref[...]
+        w1, w3 = w1_ref[0], w3_ref[0]
+        gate = jnp.dot(x, w1, preferred_element_type=jnp.float32)
+        up = jnp.dot(x, w3, preferred_element_type=jnp.float32)
+        sig = jax.nn.sigmoid(gate)
+        act = gate * sig                                   # silu(gate)
+        dh = jax.lax.dot_general(dy, w2_ref[0], _NT,
+                                 preferred_element_type=jnp.float32)
+        dgate = (dh * up * sig * (1.0 + gate * (1.0 - sig))).astype(x.dtype)
+        dup = (dh * act).astype(x.dtype)
+        h_ref[...] = (act * up).astype(h_ref.dtype)
+        dgate_ref[...] = dgate
+        dup_ref[...] = dup
+        dx_ref[...] = (
+            jax.lax.dot_general(dgate, w1, _NT,
+                                preferred_element_type=jnp.float32)
+            + jax.lax.dot_general(dup, w3, _NT,
+                                  preferred_element_type=jnp.float32)
+        ).astype(dx_ref.dtype)
+
+
+def _bwd_dw_kernel(te_ref, na_ref, x_ref, dy_ref, h_ref, dgate_ref, dup_ref,
+                   dw1_ref, dw3_ref, dw2_ref):
+    from jax.experimental import pallas as pl
+
+    i = pl.program_id(0)
+
+    @pl.when(i < na_ref[0])
+    def _():
+        # the first tile of an expert's group zeroes the block that then
+        # stays in VMEM until the expert changes
+        @pl.when((i == 0) | (te_ref[i] != te_ref[jnp.maximum(i - 1, 0)]))
+        def _():
+            dw1_ref[...] = jnp.zeros_like(dw1_ref)
+            dw3_ref[...] = jnp.zeros_like(dw3_ref)
+            dw2_ref[...] = jnp.zeros_like(dw2_ref)
+
+        x = x_ref[...]
+        dw1_ref[0] += jax.lax.dot_general(
+            x, dgate_ref[...], _TN, preferred_element_type=jnp.float32)
+        dw3_ref[0] += jax.lax.dot_general(
+            x, dup_ref[...], _TN, preferred_element_type=jnp.float32)
+        dw2_ref[0] += jax.lax.dot_general(
+            h_ref[...], dy_ref[...], _TN,
+            preferred_element_type=jnp.float32)
+
+
+def grouped_swiglu_bwd(xs: jax.Array, dy_rows: jax.Array, w1: jax.Array,
+                       w3: jax.Array, w2: jax.Array, tile_expert: jax.Array,
+                       active_tiles: jax.Array, *, tm: int,
+                       interpret: Optional[bool] = None):
+    """The transpose of `grouped_swiglu` at rows xs [R, D] for the
+    cotangent dy_rows [R, D] (both in the dtype the products run in):
+    (dxs [R, D], dW1, dW3 [E, D, F], dW2 [E, F, D] float32).  Rows of
+    inactive tiles and the blocks of experts with no tile are left
+    unwritten, as in the forward: the caller selects them away."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    interpret = interpret_default(interpret)
+    rows, d = xs.shape
+    e, _, f = w1.shape
+    n_tiles = rows // tm
+    te, na, row_map, w_map, params = _tile_plumbing(tile_expert,
+                                                    active_tiles)
+    wide = pl.BlockSpec((tm, d), row_map)
+    narrow = pl.BlockSpec((tm, f), row_map)
+    up_w = pl.BlockSpec((1, d, f), w_map)
+    down_w = pl.BlockSpec((1, f, d), w_map)
+    hidden = jax.ShapeDtypeStruct((rows, f), xs.dtype)
+    dxs, h, dgate, dup = pl.pallas_call(
+        _bwd_dx_kernel,
+        out_shape=[jax.ShapeDtypeStruct((rows, d), xs.dtype),
+                   hidden, hidden, hidden],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(n_tiles,),
+            in_specs=[wide, wide, up_w, up_w, down_w],
+            out_specs=[wide, narrow, narrow, narrow]),
+        compiler_params=params, interpret=interpret,
+        name="moe_experts_bwd_dx",
+    )(te, na, xs, dy_rows, w1, w3, w2)
+    dw1, dw3, dw2 = pl.pallas_call(
+        _bwd_dw_kernel,
+        out_shape=[jax.ShapeDtypeStruct((e, d, f), jnp.float32),
+                   jax.ShapeDtypeStruct((e, d, f), jnp.float32),
+                   jax.ShapeDtypeStruct((e, f, d), jnp.float32)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(n_tiles,),
+            in_specs=[wide, wide, narrow, narrow, narrow],
+            out_specs=[up_w, up_w, down_w]),
+        compiler_params=params, interpret=interpret,
+        name="moe_experts_bwd_dw",
+    )(te, na, xs, dy_rows, h, dgate, dup)
+    return dxs, dw1, dw3, dw2
+
+
 def combine(y_rows: jax.Array, dest: jax.Array, weights: jax.Array
             ) -> jax.Array:
     """y_rows [R, D]; dest, weights [T, k] -> [T, D] float32: each
@@ -221,6 +360,72 @@ def combine(y_rows: jax.Array, dest: jax.Array, weights: jax.Array
         w = jnp.where(here, weights, 0.0)[..., None]
         # a row of an inactive tile was never written: select, not scale
         return jnp.sum(jnp.where(here[..., None], picked, 0.0) * w, axis=1)
+
+
+def _gather_rows(x: jax.Array, row_token: jax.Array) -> jax.Array:
+    """x [T, D] -> [R, D]: each row's token; a padding row (token T)
+    is zeros."""
+    xpad = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])
+    return xpad[row_token]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _routed(x, weights, w1, w3, w2, d: Dispatch, tm: int,
+            interpret: Optional[bool]):
+    """Dispatch's gather, the experts and the combine: x [T, D], routing
+    weights [T, k] -> y [T, D] float32.  The matrices are multiplied in
+    x's dtype whatever they are stored in (a trainer's float32 masters
+    are rounded here, and their gradients come back float32)."""
+    return _routed_fwd(x, weights, w1, w3, w2, d, tm, interpret)[0]
+
+
+def _routed_fwd(x, weights, w1, w3, w2, d, tm, interpret):
+    w1c, w3c, w2c = (w.astype(x.dtype) for w in (w1, w3, w2))
+    with jax.named_scope("moe_dispatch"):
+        xs = _gather_rows(x, d.row_token)
+    with jax.named_scope("moe_experts"):
+        y_rows = grouped_swiglu(xs, w1c, w3c, w2c, d.tile_expert,
+                                d.active_tiles, tm=tm, interpret=interpret)
+    y = combine(y_rows, d.dest, weights)
+    # residuals are arrays: the masters' dtypes ride as empty ones
+    masters = tuple(jnp.zeros((0,), w.dtype) for w in (w1, w3, w2))
+    return y, (xs, weights, w1c, w3c, w2c, d, y_rows, masters)
+
+
+def _routed_bwd(tm, interpret, res, dy):
+    xs, weights, w1c, w3c, w2c, d, y_rows, masters = res
+    rows = xs.shape[0]
+    with jax.named_scope("moe_combine"):
+        here = d.dest < rows
+        # a row belongs to one assignment: its weight, and its token's dy
+        row_w = jnp.zeros((rows + 1,), jnp.float32).at[d.dest].set(
+            jnp.where(here, weights, 0.0))[:rows]
+        # gathered in the dtype the products run in: half the bytes of
+        # the worst-case buffer where that is bfloat16
+        dy_tok = _gather_rows(dy.astype(xs.dtype), d.row_token).astype(
+            jnp.float32)
+        # rows of inactive tiles were never written: only rows an
+        # assignment points at are read back
+        row_dot = jnp.sum(dy_tok * y_rows, axis=-1)
+        d_weights = jnp.where(
+            here, row_dot[jnp.minimum(d.dest, rows - 1)], 0.0)
+        dy_rows = (dy_tok * row_w[:, None]).astype(xs.dtype)
+    with jax.named_scope("moe_experts"):
+        dxs, dw1, dw3, dw2 = grouped_swiglu_bwd(
+            xs, dy_rows, w1c, w3c, w2c, d.tile_expert, d.active_tiles,
+            tm=tm, interpret=interpret)
+        chosen = (d.counts > 0)[:, None, None]
+        dw1, dw3, dw2 = (
+            jnp.where(chosen, dw, 0.0).astype(like.dtype)
+            for dw, like in zip((dw1, dw3, dw2), masters))
+    with jax.named_scope("moe_dispatch"):
+        dx = combine(dxs, d.dest, jnp.ones_like(weights)).astype(xs.dtype)
+    no_grad = jax.tree_util.tree_map(
+        lambda a: np.zeros(a.shape, jax.dtypes.float0), d)
+    return dx, d_weights.astype(weights.dtype), dw1, dw3, dw2, no_grad
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
 
 
 def moe_layer(x: jax.Array, w_router: jax.Array, w1: jax.Array,
@@ -237,7 +442,8 @@ def moe_layer(x: jax.Array, w_router: jax.Array, w1: jax.Array,
     float32 — the sum over this share's chosen experts of routing weight
     x SwiGLU_e(x), unscaled; the caller applies the model's factor and
     adds what every share computes alike — and the counters of
-    `COUNTERS` as int32 scalars)."""
+    `TRAIN_COUNTERS` as int32 scalars).  The chosen experts `ids` [T, k]
+    ride along under "ids" for a caller that compares routings."""
     t = x.shape[0]
     num_experts = w_router.shape[-1]
     if valid is None:
@@ -245,14 +451,11 @@ def moe_layer(x: jax.Array, w_router: jax.Array, w1: jax.Array,
     ids, weights = route(x, w_router, top_k, normalize, scores)
     tm = row_tile(t, top_k, num_experts)
     d = dispatch(ids, valid, held, tm)
-    with jax.named_scope("moe_dispatch"):
-        xpad = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])
-        xs = xpad[d.row_token]
-    with jax.named_scope("moe_experts"):
-        y_rows = grouped_swiglu(xs, w1, w3, w2, d.tile_expert,
-                                d.active_tiles, tm=tm, interpret=interpret)
-    y = combine(y_rows, d.dest, weights)
+    y = _routed(x, weights, w1, w3, w2, d, tm, interpret)
     counters = {"assignments": jnp.sum(d.counts),
                 "expert_calls": jnp.sum(d.counts > 0).astype(jnp.int32),
-                "max_load": jnp.max(d.counts)}
+                "max_load": jnp.max(d.counts),
+                "row_tiles_active": d.active_tiles,
+                "row_tiles": jnp.int32(d.tile_expert.shape[0]),
+                "ids": ids}
     return y, counters

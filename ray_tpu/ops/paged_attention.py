@@ -135,10 +135,9 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    if interpret is None:
-        from ray_tpu.ops import kernel_mode
+    from ray_tpu.ops import interpret_default
 
-        interpret = kernel_mode() == "interpret"
+    interpret = interpret_default(interpret)
     b, s, h, d = q.shape
     assert s == 1, f"paged_attention is decode-only (S=1), got S={s}"
     num_slots, hkv, _ = pool_k.shape

@@ -1,4 +1,4 @@
-"""GSPMD training-step construction for the flagship model.
+"""GSPMD training-step construction for every model family.
 
 This is the TPU-native equivalent of the reference's prepare_model
 DDP/FSDP wrapping (reference: python/ray/train/torch/train_loop_utils.py
@@ -6,11 +6,19 @@ DDP/FSDP wrapping (reference: python/ray/train/torch/train_loop_utils.py
 PartitionSpecs on a named mesh and jit one train step; XLA inserts the
 all-gathers/reduce-scatters (fsdp), all-reduces (dp) and collective
 matmuls (tp) over ICI.
+
+`build_train_state(model=...)` builds the step of whatever family
+`ray_tpu.models.resolve` finds (the dense Llama block, the expert-layer
+family), from the family's train side; it hands out the step's counters
+beside the loss and the jitted value_and_grad the step differentiates.
+`build_llama_train_state` is the same for a LlamaConfig in the older
+three-value form; `build_llama_stage_state` builds one MPMD pipeline
+stage of the Llama block (the expert family has no stage module yet).
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 
 def _mesh_attention_kernel(mesh) -> Optional[Callable]:
@@ -49,54 +57,125 @@ def _init_opt_state(mesh, tx, params):
     return jax.jit(tx.init, out_shardings=out)(params)
 
 
-def build_llama_train_state(cfg, mesh, rng_seed: int = 0,
-                            learning_rate: float = 3e-4,
-                            batch_size: int = 8, seq_len: int = 128,
-                            attention_kernel: Optional[Callable] = None):
-    """Init sharded (params, opt_state) and a jitted train step.
+class TrainState(NamedTuple):
+    """What `build_train_state` returns."""
+    params: Any
+    opt_state: Any
+    # step_fn(params, opt_state, tokens) -> (params, opt_state, loss,
+    # counters): dispatches the jitted step (span `train.step.dispatch`)
+    # and returns device arrays; params and opt_state are donated
+    step_fn: Callable
+    model: Any               # the family's training module
+    # grads_fn(params, tokens) -> ((loss, (counters, aux)), grads): the
+    # jitted value_and_grad of the very loss the step differentiates, at
+    # the step's shapes — what a test or a correctness sample reads the
+    # gradients of the timed path from
+    grads_fn: Callable
+    counter_names: Tuple[str, ...]   # of `counters`' entries
+    # read(loss, counters) -> (float, {name: int}): one transfer for both
+    # (span `train.step.sync`), the counters added to this process's
+    # `ray_tpu_train_*` metrics
+    read: Callable
 
-    Returns (params, opt_state, step_fn, model) where
-    step_fn(params, opt_state, tokens) -> (params, opt_state, loss).
-    """
+
+def build_train_state(model, mesh, rng_seed: int = 0,
+                      learning_rate: float = 3e-4, batch_size: int = 8,
+                      seq_len: int = 128,
+                      attention_kernel: Optional[Callable] = None
+                      ) -> TrainState:
+    """Init sharded (params, opt_state) and a jitted adamw train step
+    for any model family: `model` is what `LLMEngine(model=...)` takes —
+    a family's config instance or a dictionary of published keys with
+    its `model_type` — resolved by `ray_tpu.models.resolve`, and the
+    family's train side (`train_build`, `train_loss`, `param_rules`;
+    models/__init__.py) gives the module, the loss and the layout."""
+    from functools import partial
+
     import jax
     import jax.numpy as jnp
     import optax
 
-    from ray_tpu.models.llama import (LlamaModel, causal_lm_loss,
-                                      llama_param_rules)
+    from ray_tpu import models
     from ray_tpu.parallel.mesh import init_sharded, shard_batch
 
+    family, cfg = models.resolve(model)
     if attention_kernel is None:
         attention_kernel = _mesh_attention_kernel(mesh)
-    model = LlamaModel(cfg, kernel=attention_kernel)
+    module = family.train_build(cfg, attention_kernel)
+    names = tuple(getattr(module, "train_counters", ()))
     rng = jax.random.PRNGKey(rng_seed)
     sample = jnp.zeros((batch_size, seq_len), dtype=jnp.int32)
 
     with mesh:
-        params = init_sharded(mesh, lambda r: model.init(r, sample)["params"],
-                              rng, llama_param_rules())
+        params = init_sharded(
+            mesh, lambda r: module.init(r, sample)["params"], rng,
+            family.param_rules())
         tx = optax.adamw(learning_rate)
         opt_state = _init_opt_state(mesh, tx, params)
 
         def loss_fn(p, tokens):
-            logits = model.apply({"params": p}, tokens)
-            return causal_lm_loss(logits, tokens)
+            out = module.apply({"params": p}, tokens)
+            logits, counters, aux = (
+                out if isinstance(out, tuple)
+                else (out, jnp.zeros((0,), jnp.int32), ()))
+            return family.train_loss(logits, tokens), (counters, aux)
 
-        from functools import partial
+        value_and_grad = jax.value_and_grad(loss_fn, has_aux=True)
 
         @partial(jax.jit, donate_argnums=(0, 1))
         def step(p, o, tokens):
-            loss, grads = jax.value_and_grad(loss_fn)(p, tokens)
+            (loss, (counters, _)), grads = value_and_grad(p, tokens)
             updates, o = tx.update(grads, o, p)
             p = optax.apply_updates(p, updates)
-            return p, o, loss
+            return p, o, loss, counters
+
+        grads_jit = jax.jit(value_and_grad)
+
+    span = jax.profiler.TraceAnnotation
 
     def step_fn(p, o, tokens):
-        tokens = shard_batch(mesh, tokens)
-        with mesh:
-            return step(p, o, tokens)
+        with span("train.step.dispatch"):
+            tokens = shard_batch(mesh, tokens)
+            with mesh:
+                return step(p, o, tokens)
 
-    return params, opt_state, step_fn, model
+    def grads_fn(p, tokens):
+        with mesh:
+            return grads_jit(p, shard_batch(mesh, tokens))
+
+    def read(loss, counters):
+        with span("train.step.sync"):
+            loss, counters = jax.device_get((loss, counters))
+        counted = {n: int(v) for n, v in zip(names, counters)}
+        if counted:
+            from ray_tpu._private.metrics import train_step_counters
+
+            for name, value in counted.items():
+                train_step_counters(name).inc(value)
+        return float(loss), counted
+
+    return TrainState(params, opt_state, step_fn, module, grads_fn, names,
+                      read)
+
+
+def build_llama_train_state(cfg, mesh, rng_seed: int = 0,
+                            learning_rate: float = 3e-4,
+                            batch_size: int = 8, seq_len: int = 128,
+                            attention_kernel: Optional[Callable] = None):
+    """`build_train_state` for a LlamaConfig, in the older form.
+
+    Returns (params, opt_state, step_fn, model) where
+    step_fn(params, opt_state, tokens) -> (params, opt_state, loss).
+    """
+    state = build_train_state(cfg, mesh, rng_seed, learning_rate,
+                              batch_size, seq_len, attention_kernel)
+
+    four_values = state.step_fn     # not `state`: it holds the trees
+
+    def step_fn(p, o, tokens):
+        return four_values(p, o, tokens)[:3]
+
+    return state.params, state.opt_state, step_fn, state.model
 
 
 def build_llama_stage_state(cfg, mesh, layer_range, *, first: bool,

@@ -115,3 +115,4 @@ def test_row_tile_follows_the_mean_group():
     assert moe.row_tile(32, 10, 256) == 16      # a decode batch
     assert moe.row_tile(512, 10, 256) == 32     # a prefill pass
     assert moe.row_tile(8192, 10, 256) == 128   # never over the MXU's side
+

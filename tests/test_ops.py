@@ -120,12 +120,9 @@ def test_default_attention_routes_long_prefill_through_flash(monkeypatch):
     dense = llama.dense_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(routed), np.asarray(dense),
                                atol=2e-5, rtol=2e-5)
-    # grad still traces through the routed path: flash carries a
-    # dense-recompute custom_vjp that targets dense_attention directly
-    # — if it routed back through default_attention this trace would
-    # recurse forever.  (Backward NUMERICS are covered by
-    # test_flash_attention_backward; tracing alone proves the wiring
-    # without paying a second kernel compile.)
+    # grad traces through the routed path: flash carries its own
+    # blockwise backward kernels (numerics: tests/test_flash_backward.py;
+    # tracing alone proves the wiring without a second kernel compile)
     jax.make_jaxpr(
         jax.grad(lambda q: llama.default_attention(q, k, v).sum()))(q)
 

@@ -90,17 +90,34 @@ def test_flash_forward_compiles(one_chip, shape):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_flash_gradient_compiles_at_train_shape(one_chip):
-    """The forward kernel plus the dense-recompute backward, as the train
-    step takes them: value and gradient (the backward reads only q, k
-    and v, so a bare gradient would drop the forward kernel as dead)."""
+# benchmarks/configs/mellum2-12b-a2.5b-train.json: one sequence of 8192,
+# 8 query heads on 1 KV head (a group of 8), window 1024 or none; and
+# train-2l-8k's 4 x 2048 with Mistral's 32 heads on 8
+MELLUM_SHAPE = (1, 8192, 8, 1, 128)
+MISTRAL_TRAIN_SHAPE = (4, 2048, 32, 8, 128)
+
+
+@pytest.mark.parametrize("shape,window", [
+    (TRAIN_SHAPE, 0), (MISTRAL_TRAIN_SHAPE, 0), (MELLUM_SHAPE, 0),
+    (MELLUM_SHAPE, 1024)],
+    ids=["bench_1b", "mistral-train", "mellum-full", "mellum-window"])
+def test_flash_gradient_compiles_at_train_shapes(one_chip, shape, window):
+    """The forward kernel (with the rows' log-sum-exp) and the two
+    blockwise backward kernels, as a train step takes them; the names
+    tell a window call from a full one."""
     def loss(q, k, v):
-        out = flash_attention(q, k, v, True, 128, 128, False)
+        # the blocks the train steps run with: `default_block`'s
+        out = flash_attention(q, k, v, True, None, None, False, window)
         return jnp.sum(out.astype(jnp.float32))
 
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
-        *_qkv(TRAIN_SHAPE, one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+        *_qkv(shape, one_chip)).compile()
+    text = compiled.as_text()
+    tail = "_window" if window else ""
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                 "flash_attention_bwd_dkv"):
+        assert name + tail in text
+    assert text.count("tpu_custom_call") >= 3
 
 
 # ------------------------------------------- the Laguna cell's kernels
@@ -155,3 +172,30 @@ def test_expert_layer_compiles_at_laguna_shapes(one_chip, tokens):
             spec((128, 1024, 3072), jnp.bfloat16),
             spec((tokens,), jnp.bool_)).compile()
     assert compiled.as_text().count("moe_experts") >= 2
+
+
+def test_expert_layer_gradient_compiles_at_mellum_shapes(one_chip):
+    """`jax.grad` through `ops.moe.moe_layer` as the Mellum cell's step
+    takes it: 8192 bfloat16 tokens of width 2304, 16 of 64 experts of
+    width 896 held as float32 masters, top 8, row tiles of 128 — the
+    forward's two calls and the backward's two, an expert's three float32
+    gradient blocks resident in VMEM while its tiles last."""
+    from ray_tpu.ops import moe
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert moe.row_tile(8192, 8, 64) == 128
+
+    def loss(x, wr, w1, w3, w2):
+        y, _ = moe.moe_layer(x, wr, w1, w3, w2, top_k=8, held=(0, 16),
+                             interpret=False)
+        return jnp.sum(y)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        spec((8192, 2304), jnp.bfloat16), spec((2304, 64), jnp.float32),
+        spec((16, 2304, 896), jnp.float32),
+        spec((16, 2304, 896), jnp.float32),
+        spec((16, 896, 2304), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "moe_experts_bwd_dx" in text and "moe_experts_bwd_dw" in text
