@@ -4,24 +4,32 @@ Decode attention over the serving tier's paged KV cache
 (serve/llm.py): instead of gathering the whole ``[B, L]`` slot-table
 context out of the flat pools and softmaxing over ``-1e30``-masked
 garbage (``models/llama.py cached_attention``), the kernel walks each
-sequence's **used pages only** — the grid's sequential page dimension
+sequence's **used pages only** — the grid's sequential block dimension
 carries flash-style online-softmax scratch (running max / denominator)
 so no dense context copy or score matrix ever materializes.
 
-Page indirection happens in the BlockSpec index maps via scalar
-prefetch: the block table and context lengths arrive as
-``PrefetchScalarGridSpec`` scalar operands, so the KV block fetched at
-grid step ``(b, p)`` is the *physical* page ``block_tables[b, p]``
-read straight from the flat pool.  Pages past a sequence's used count
-are clamped to its last used page — the same index as the previous
-grid step, which Pallas recognizes and skips the redundant copy — and
-their compute is predicated off with ``pl.when``.  All KV heads ride
-in one block (the grid is ``(B, W)``, not ``(B * Hkv, W)``): one page
-fetch serves every head, and the per-head attention math batches over
-the leading head dim inside the kernel.  Prefix-shared and CoW-split
-pages need no special handling: the kernel only ever addresses
-physical pages through the table, exactly like the dense gather it
-replaces.
+A grid step covers ``K = pages_per_step(W, page_size)`` pages of one
+lane, not one: the grid is ``(B, ceil(W / K))``.  A grid step costs a
+tenth of a microsecond and up whatever it computes, and a page is 16
+keys, so at one page a step a call was its grid (1.63 ms at 32 lanes x
+256 pages on a v5e, 0.10 ms with 32 pages a step).  A lane's pages are
+not adjacent in the pool, so the pools stay in HBM (``pl.ANY``) and a
+step copies its K pages itself (`make_async_copy`, the physical page
+read from the scalar-prefetched block table) into one half of a
+double-buffered VMEM scratch, after starting the NEXT block's copies
+into the other half; a lane's first block is fetched in its first step.
+A step past a lane's last block fetches and computes nothing.  The K
+block specs of the pipelined alternative were measured and dropped:
+their 2K index maps a step are scalar work the block table's whole
+width pays again (1.21 ms for the same call).
+
+A block's pages past the lane's last are the last used page again (a
+valid address, finite rows) and are masked by position.  All KV heads
+ride in one block: one page fetch serves every head, and the per-head
+attention math batches over the leading head dim inside the kernel.
+Prefix-shared and CoW-split pages need no special handling: the kernel
+only ever addresses physical pages through the table, exactly like the
+dense gather it replaces.
 
 Compiled on TPU, ``interpret=True`` on CPU (same numerics, pure jax)
 so tier-1 validates the kernel path end to end.
@@ -52,23 +60,68 @@ import jax.numpy as jnp
 _NEG_INF = -1e30
 
 
-def _paged_kernel(bt_ref, cl_ref, *refs, page_size: int, scale: float,
-                  window: Optional[int] = None):
-    """`refs`: with a window the scalar `starts`, then q, k, v, o and
-    the three scratch buffers."""
+def pages_per_step(width: int, page_size: int) -> int:
+    """K, the pages of a lane one grid step covers, from the table's
+    static width: a quarter of the table, held between 128 and 512 keys,
+    and never more than the table.  Measured on the v5e at every width
+    the engine asks for (PERF.md, PR 33): fewer pages a step and a wide
+    table is grid overhead again; more and a lane's first block, which
+    nothing overlaps, and the copies past its last page cost more than
+    the steps saved."""
+    least, most = max(1, 128 // page_size), max(1, 512 // page_size)
+    return min(width, max(least, min(most, width // 4)))
+
+
+def _paged_kernel(bt_ref, cl_ref, *refs, page_size: int, pages: int,
+                  scale: float, window: Optional[int] = None):
+    """`refs`: with a window the scalar `starts`, then q, the k and v
+    pools (in HBM), o, the k and v page buffers `[2, pages, page_size,
+    Hkv, D]`, their DMA semaphores `[2 (k, v), 2 (buffer half)]` and
+    the three softmax scratch buffers."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     b = pl.program_id(0)
     pi = pl.program_id(1)
     n_p = pl.num_programs(1)
     ctx = cl_ref[b]
     if window is None:
-        q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
         start = 0
     else:
-        st_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
-        start = st_ref[b]
-    used = (ctx - start + page_size - 1) // page_size
+        start, refs = refs[0][b], refs[1:]
+    (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, acc_ref, m_ref,
+     l_ref) = refs
+    # never past the table, whatever the lengths say: a page id read
+    # beyond it would be an address
+    used = jnp.minimum((ctx - start + page_size - 1) // page_size,
+                       bt_ref.shape[1])
+    blocks = (used + pages - 1) // pages
+    keys = pages * page_size
+
+    def fetch(block, half):
+        """Start the copies of `block`'s pages into buffer `half`; a
+        page past the lane's last is its last again.  A loop and not K
+        copies written out: the same time on the chip, a fraction of
+        the program for its compiler and for the interpreter."""
+        def page(j, carry):
+            at = bt_ref[b, jnp.minimum(block * pages + j, used - 1)]
+            pltpu.make_async_copy(k_hbm.at[at], k_buf.at[half, j],
+                                  sem.at[0, half]).start()
+            pltpu.make_async_copy(v_hbm.at[at], v_buf.at[half, j],
+                                  sem.at[1, half]).start()
+            return carry
+        jax.lax.fori_loop(0, pages, page, 0)
+
+    def wait(half):
+        """One wait a copy started into `half`; a wait takes its size
+        from the descriptor, so any page stands for the source."""
+        def page(j, carry):
+            pltpu.make_async_copy(k_hbm.at[0], k_buf.at[half, j],
+                                  sem.at[0, half]).wait()
+            pltpu.make_async_copy(v_hbm.at[0], v_buf.at[half, j],
+                                  sem.at[1, half]).wait()
+            return carry
+        jax.lax.fori_loop(0, pages, page, 0)
 
     @pl.when(pi == 0)
     def _init():
@@ -76,19 +129,34 @@ def _paged_kernel(bt_ref, cl_ref, *refs, page_size: int, scale: float,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when(pi < used)
+    @pl.when(pi < blocks)
     def _update():
+        half = pi % 2
+
+        @pl.when(pi == 0)
+        def _first():
+            fetch(0, 0)
+
+        @pl.when(pi + 1 < blocks)
+        def _next():
+            fetch(pi + 1, 1 - half)
+
+        wait(half)
+        rows = (keys,) + k_buf.shape[3:]
         q = q_ref[0].astype(jnp.float32)               # [Hkv, G, D]
-        k = k_ref[0].transpose(1, 0, 2).astype(jnp.float32)  # [Hkv, P, D]
-        v = v_ref[0].transpose(1, 0, 2).astype(jnp.float32)
+        k = k_buf[half].reshape(rows).transpose(1, 0, 2).astype(
+            jnp.float32)                                # [Hkv, keys, D]
+        v = v_buf[half].reshape(rows).transpose(1, 0, 2).astype(
+            jnp.float32)
         s = jax.lax.dot_general(
             q, k, dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale  # [Hkv, G, P]
+            preferred_element_type=jnp.float32) * scale  # [Hkv, G, keys]
         # rows of the last used page beyond the context length hold
-        # garbage (or another sequence's data on a shared page tail)
-        pos = start + pi * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, page_size), 2)
-        valid = pos < ctx                               # [1, 1, P]
+        # garbage (or another sequence's data on a shared page tail),
+        # and the block's pages past it are that page again
+        pos = start + pi * keys + jax.lax.broadcasted_iota(
+            jnp.int32, (1, 1, keys), 2)
+        valid = pos < ctx                               # [1, 1, keys]
         if window is not None:
             valid = valid & (pos >= ctx - window)
         s = jnp.where(valid, s, _NEG_INF)
@@ -125,19 +193,33 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     attends — causality for decode, since the query sits at position
     context_lens[b] - 1).  Returns [B, 1, H, D] in q's dtype.
 
-    Cost scales with ``W`` (the block-table width), not the pool or max
-    context: callers shrink W to the max used pages across the batch.
+    Cost scales with the pages the lanes use and, a tenth of a
+    microsecond a step, with ``B * ceil(W / pages_per_step(W,
+    page_size))``: callers shrink W to the max used pages across the
+    batch.
 
     With ``window``, ``block_tables[b]`` lists the pages from position
     ``starts[b]`` (a multiple of the page size) on, and only the last
     ``window`` positions before ``context_lens[b]`` attend.
     """
+    from ray_tpu.ops import interpret_default
+
+    return _paged_call(q, pool_k, pool_v, block_tables, context_lens,
+                       starts, page_size=page_size, window=window,
+                       interpret=interpret_default(interpret))
+
+
+# A jit of its own: a model's layers call with the same shapes, and so
+# the kernel is traced and lowered once a program, not once a layer (a
+# tenth of a second each, 48 times in the warm-up of a 12-layer engine's
+# four table widths: PERF.md, PR 33).
+@functools.partial(jax.jit,
+                   static_argnames=("page_size", "window", "interpret"))
+def _paged_call(q, pool_k, pool_v, block_tables, context_lens, starts, *,
+                page_size: int, window: Optional[int], interpret: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from ray_tpu.ops import interpret_default
-
-    interpret = interpret_default(interpret)
     b, s, h, d = q.shape
     assert s == 1, f"paged_attention is decode-only (S=1), got S={s}"
     num_slots, hkv, _ = pool_k.shape
@@ -161,8 +243,8 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
         # content (shared pages arrive as duplicated rows — same
         # numerics), the gather itself is O(used context), and step
         # cost stays independent of the pool/max-context capacity.
-        # The compiled TPU path never takes this branch — it DMAs
-        # single pages straight from the flat pool via the index map.
+        # The compiled TPU path never takes this branch — it copies
+        # single pages straight from the flat pool.
         flat = bt.reshape(-1)
         kp = kp[flat]                                 # [B*W, P, Hkv, D]
         vp = vp[flat]
@@ -171,29 +253,25 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     scalars = (bt, cl) if window is None \
         else (bt, cl, starts.astype(jnp.int32))
 
-    def _kv_index(bi, pi, bt, cl, *st):
-        # clamp unused grid steps to the last used page: same index as
-        # the previous step, so the pipeline skips the redundant copy
-        first = st[0][bi] if st else 0
-        used = (cl[bi] - first + page_size - 1) // page_size
-        p = jnp.minimum(pi, jnp.maximum(used - 1, 0))
-        return (bt[bi, p], 0, 0, 0)
-
     def _q_index(bi, pi, *_scalars):
         return (bi, 0, 0, 0)
 
+    pages = pages_per_step(w, page_size)
     kernel = functools.partial(_paged_kernel, page_size=page_size,
-                               scale=scale, window=window)
+                               pages=pages, scale=scale, window=window)
+    page_buf = pltpu.VMEM((2, pages, page_size, hkv, d), pool_k.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(b, w),
+        grid=(b, -(-w // pages)),
         in_specs=[
             pl.BlockSpec((1, hkv, g, d), _q_index),
-            pl.BlockSpec((1, page_size, hkv, d), _kv_index),
-            pl.BlockSpec((1, page_size, hkv, d), _kv_index),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, hkv, g, d), _q_index),
         scratch_shapes=[
+            page_buf, page_buf,
+            pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.VMEM((hkv, g, d), jnp.float32),     # acc
             pltpu.VMEM((hkv, g, 128), jnp.float32),   # running max
             pltpu.VMEM((hkv, g, 128), jnp.float32),   # running denom

@@ -713,6 +713,9 @@ class LLMEngine:
         # and lane-steps computed for a sequence that had ended by the
         # time they were read (its `eos`, a cancel or its deadline came
         # while they were in flight; over decode_lane_steps_total).
+        # The paged kernel's grid: the steps the decode passes' calls
+        # had (lanes x blocks of `pages_per_step` pages of the table, a
+        # kind of layer each pass) and those that held a page.
         self._totals = {"prefill_tokens_total": 0,
                         "prefill_slots_total": 0,
                         "prefill_ctx_rows_total": 0,
@@ -720,6 +723,8 @@ class LLMEngine:
                         "decode_lane_steps_total": 0,
                         "decode_lane_steps_wasted_total": 0,
                         "runahead_decode_steps_total": 0,
+                        "paged_grid_steps_total": 0,
+                        "paged_grid_steps_live_total": 0,
                         "submitted_total": 0, "admitted_total": 0,
                         "first_tokens_total": 0, "finished_total": 0,
                         "queue_wait_secs_total": 0.0,
@@ -1016,9 +1021,9 @@ class LLMEngine:
         """Block-table width buckets the decode pass can emit:
         powers of four from 4 up to (and capped at) pages_per_seq.
         Coarser-than-pow-2 buckets trade at most a 4x width overshoot
-        at small contexts (cheap: unused pages are predicated off and
-        their copies deduped) for half the per-bucket jit compiles the
-        warm-up burst has to pay."""
+        at small contexts (cheap: a grid step past a lane's last page
+        fetches and computes nothing) for half the per-bucket jit
+        compiles the warm-up burst has to pay."""
         return _pow4_widths(4, self.pages_per_seq)
 
     def _prefill_ctx_buckets(self) -> List[int]:
@@ -1806,6 +1811,11 @@ class LLMEngine:
             [(lane, seq.windows, n - 1, n) for lane, (seq, *_a, n)
              in enumerate(decode_args)], b, 1, width, decode=True) \
             if self._windows else None
+        self._count_paged_grid(block_tables, context_lens)
+        for arrays in (windows or {}).values():
+            self._count_paged_grid(
+                arrays["block_tables"],
+                arrays["context_lens"] - arrays["starts"])
         phase("decode_dispatch")
         out, top2 = self._forward(
             tokens, slot_arr, None, None, None, q_pos, last_idx,
@@ -1827,6 +1837,18 @@ class LLMEngine:
         m = self.metrics()
         if m is not None:
             m["tokens"].inc(len(decode_args), tags={"phase": "decode"})
+
+    def _count_paged_grid(self, tables, tokens) -> None:
+        """One kind of layer's paged-kernel call of a decode pass:
+        `tables` [lanes, width] as the kernel gets it, `tokens` [lanes]
+        the positions each lane's table covers (0: not held)."""
+        from ray_tpu.ops.paged_attention import pages_per_step
+
+        lanes, width = tables.shape
+        pages = pages_per_step(width, self.page_size)
+        self._totals["paged_grid_steps_total"] += lanes * -(-width // pages)
+        self._totals["paged_grid_steps_live_total"] += \
+            int((-(-tokens // (pages * self.page_size))).sum())
 
     def _read_back(self, before: Optional[int] = None) -> bool:
         """Read the passes in flight that steps before `before`

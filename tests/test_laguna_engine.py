@@ -230,6 +230,16 @@ def test_window_pages_released_and_reused_under_runahead():
     assert (st["runahead_decode_steps_total"]
             - before["runahead_decode_steps_total"]) / steps > 0.9
     assert st["decode_lane_steps_wasted_total"] == 0
+    # the paged kernel's grid, the full kind's call and the window kind's
+    # in the same two sums: at page 8 both tables (4 to 16 pages wide;
+    # 4 or 5) are one block a lane, so a pass has 2 x 4 lanes of steps
+    # and a decoding lane holds one of each kind
+    assert st["paged_grid_steps_total"] \
+        - before["paged_grid_steps_total"] == 2 * 4 * steps
+    assert st["paged_grid_steps_live_total"] \
+        - before["paged_grid_steps_live_total"] \
+        == 2 * (st["decode_lane_steps_total"]
+                - before["decode_lane_steps_total"])
     outs = [list(s.generated) for s in seqs]
     refs = ref.teacher_forced(eng._params, prompts, outs, SIZES)
     for p, out, r in zip(prompts, outs, refs):
@@ -250,7 +260,13 @@ def test_a_llama_engine_is_the_parents():
     1,234,144, bytes accessed 3,119,880 -> 2,906,888, transcendentals
     and the pools as they were (8d027c8 -> ISSUE 29's commit).  ISSUE 31
     added the token feed (a gather of 4 lanes from the last step's
-    outputs and a select): flops + 36, bytes accessed + 192."""
+    outputs and a select): flops + 36, bytes accessed + 192.  ISSUE 33
+    changed the paged kernel's grid by design: at width 4 a lane's pages
+    are ONE grid step (the interpreter's program copies the four pages
+    into the kernel's buffer and takes one softmax over 32 keys a lane
+    where it took four over 8): flops 1,234,180 -> 1,281,390, bytes
+    accessed 2,907,080 -> 3,161,810, transcendentals 1380 -> 1572; the
+    parameters and the pools as they were."""
     eng = LLMEngine(model="tiny", page_size=8, max_batch=4)
     rep = eng.device_report()
     assert (rep["param_bytes"], rep["kv_pool_bytes"]) == (214272, 133120)
@@ -259,5 +275,5 @@ def test_a_llama_engine_is_the_parents():
     assert eng._garbage_decode_args(4)[1]["windows"] == {}
     cost = eng._lower_decode(4).compile().cost_analysis()
     assert (cost["flops"], cost["bytes accessed"],
-            cost["transcendentals"]) == (1234180.0, 2907080.0, 1380.0)
+            cost["transcendentals"]) == (1281390.0, 3161810.0, 1572.0)
     assert "moe_assignments_total" not in eng.stats()

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import cache as kv_cache
 from ray_tpu.models.llama import LlamaConfig, cached_attention
-from ray_tpu.ops.paged_attention import paged_attention
+from ray_tpu.ops.paged_attention import paged_attention, pages_per_step
 
 
 def _rand_paged_case(rng, batch, ctx_lens, n_heads, n_kv_heads, head_dim,
@@ -25,6 +25,8 @@ def _rand_paged_case(rng, batch, ctx_lens, n_heads, n_kv_heads, head_dim,
     """Random pools + a shuffled (non-contiguous) page assignment per
     lane; returns everything both the paged kernel and the dense
     reference need.  Page 0 is the garbage page, never assigned."""
+    used = [-(-c // page_size) for c in ctx_lens]
+    num_pages = max(num_pages, sum(used) + 2)
     t = num_pages * page_size
     pool_k = jnp.asarray(rng.normal(size=(t, n_kv_heads, head_dim)),
                          jnp.float32)
@@ -32,9 +34,7 @@ def _rand_paged_case(rng, batch, ctx_lens, n_heads, n_kv_heads, head_dim,
                          jnp.float32)
     q = jnp.asarray(rng.normal(size=(batch, 1, n_heads, head_dim)),
                     jnp.float32)
-    used = [-(-c // page_size) for c in ctx_lens]
     width = max(max(used), 1)
-    assert sum(used) <= num_pages - 1, "case needs more pages"
     pages = list(rng.permutation(np.arange(1, num_pages)))
     bt = np.zeros((batch, width), np.int32)
     for b in range(batch):
@@ -43,7 +43,8 @@ def _rand_paged_case(rng, batch, ctx_lens, n_heads, n_kv_heads, head_dim,
     return q, pool_k, pool_v, bt, np.asarray(ctx_lens, np.int32)
 
 
-def _dense_reference(q, pool_k, pool_v, bt, ctx_lens, page_size):
+def _dense_reference(q, pool_k, pool_v, bt, ctx_lens, page_size,
+                     window=None):
     """cached_attention over ctx/ctx_pos/ctx_mask arrays derived from
     the same block tables — the form a prefill pass's group carries."""
     batch = q.shape[0]
@@ -60,27 +61,119 @@ def _dense_reference(q, pool_k, pool_v, bt, ctx_lens, page_size):
     q_pos = np.maximum(ctx_lens.astype(np.int32) - 1, 0)[:, None]
     return cached_attention(q, pool_k, pool_v, jnp.asarray(ctx),
                             jnp.asarray(ctx_pos), jnp.asarray(ctx_mask),
-                            jnp.asarray(q_pos))
+                            jnp.asarray(q_pos), window=window)
 
 
-@pytest.mark.parametrize("batch,ctx_lens,heads,kv_heads,page_size", [
-    (1, [1], 4, 2, 8),                 # single token, single lane
-    (2, [5, 16], 4, 4, 8),             # MHA (group=1), page-exact length
-    (3, [13, 1, 9], 4, 2, 4),          # GQA group=2, ragged
-    (4, [31, 8, 17, 2], 8, 2, 8),      # GQA group=4, multi-page ragged
-    (2, [7, 23], 4, 2, 16),            # bigger pages than one context
-])
+def _window_tables(bt, ctx_lens, page_size, window):
+    """What the engine's window group hands a decode pass
+    (`_WindowPages.table`): each lane's pages from the one the window's
+    first position lies on, and the position that page starts at."""
+    first = np.maximum(0, ctx_lens - window) // page_size
+    tables = np.zeros((len(bt), -(-window // page_size) + 1), np.int32)
+    for b, n in enumerate(ctx_lens):
+        live = bt[b, first[b]:-(-int(n) // page_size)]
+        tables[b, :len(live)] = live
+    return tables, (first * page_size).astype(np.int32)
+
+
+# what a grid of K = pages_per_step(width, 16) pages a step can get wrong
+# (width 33: K 8, five blocks, the last of one page; 64: K 16; 4: K 4;
+# 20, a window of 300's table: K 8, three blocks)
+_BLOCK_CASES = [
+    # a table no multiple of K; lanes that end in the middle of a block
+    # (12 pages), on a block's edge (16), after one page, at the table's
+    # whole width (33), and lanes of context 0 between held ones
+    pytest.param(6, [181, 0, 256, 7, 0, 525], 8, 2, 16, 33, None, None,
+                 id="w33-k8-group4"),
+    pytest.param(6, [181, 0, 256, 7, 0, 525], 18, 2, 16, 33, None, None,
+                 id="w33-k8-group9"),
+    # narrower than the least K: one block of the table's width
+    pytest.param(4, [64, 0, 1, 33], 8, 2, 16, 4, None, None,
+                 id="w4-k4"),
+    # one page beside a lane of W pages; an edge (16 pages) and one past it
+    pytest.param(4, [3, 1024, 256, 257], 12, 2, 16, 64, None, None,
+                 id="w64-k16-group6"),
+    # a window whose table starts elsewhere in every lane and whose lower
+    # bound lies inside the first block; a lane shorter than the window,
+    # one of a single page, one not held
+    pytest.param(6, [500, 301, 40, 0, 777, 9], 18, 2, 16, None, 300, None,
+                 id="window300-w20-k8-group9"),
+    # two lanes whose first ten pages are the same physical pages: a
+    # whole block and a part of the next
+    pytest.param(3, [200, 0, 170], 12, 2, 16, 16, None, (0, 2, 10),
+                 id="w16-k8-shared-pages"),
+]
+
+
+@pytest.mark.parametrize(
+    "batch,ctx_lens,heads,kv_heads,page_size,width,window,share", [
+        (1, [1], 4, 2, 8, None, None, None),   # single token, single lane
+        (2, [5, 16], 4, 4, 8, None, None, None),   # MHA, page-exact length
+        (3, [13, 1, 9], 4, 2, 4, None, None, None),   # GQA group=2, ragged
+        (4, [31, 8, 17, 2], 8, 2, 8, None, None, None),   # group=4, ragged
+        (2, [7, 23], 4, 2, 16, None, None, None),   # pages > one context
+    ] + _BLOCK_CASES)
 def test_kernel_matches_dense_reference(batch, ctx_lens, heads, kv_heads,
-                                        page_size):
+                                        page_size, width, window, share):
+    """`width`: the table's (the longest lane's pages without it), the
+    columns past a lane's pages holding the garbage page 0; `window`: a
+    window layer's call, its tables cut as the engine cuts them;
+    `share` = (lane, lane, pages): the second lane's first pages are the
+    first lane's."""
     rng = np.random.default_rng(hash((batch, heads, page_size)) % 2**32)
     q, pk, pv, bt, cl = _rand_paged_case(
         rng, batch, ctx_lens, heads, kv_heads, head_dim=16,
         page_size=page_size, num_pages=24)
-    out = paged_attention(q, pk, pv, jnp.asarray(bt), jnp.asarray(cl),
-                          page_size=page_size)
-    ref = _dense_reference(q, pk, pv, bt, cl, page_size)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+    if share:
+        src, dst, n = share
+        bt[dst, :n] = bt[src, :n]
+    ref = _dense_reference(q, pk, pv, bt, cl, page_size, window=window)
+    tables, starts = bt, None
+    if window:
+        tables, starts = _window_tables(bt, cl, page_size, window)
+    elif width:
+        tables = np.zeros((batch, width), np.int32)
+        tables[:, :bt.shape[1]] = bt
+    if width or window:
+        steps = -(-tables.shape[1]
+                  // pages_per_step(tables.shape[1], page_size))
+        assert steps > 1 or tables.shape[1] <= 8
+    out = paged_attention(q, pk, pv, jnp.asarray(tables), jnp.asarray(cl),
+                          page_size=page_size, window=window,
+                          starts=None if starts is None
+                          else jnp.asarray(starts))
+    # (a lane of context 0 gives zeros; the dense softmax over nothing
+    # gives a mean)
+    np.testing.assert_allclose(np.asarray(out)[cl > 0],
+                               np.asarray(ref)[cl > 0],
                                rtol=1e-5, atol=1e-5)
+    assert np.all(np.asarray(out)[cl == 0] == 0)
+
+
+# every table width the two serving configurations can ask for, at page
+# 16: the chat engine's buckets (contexts to 4096), Laguna's full kind
+# (to 8192) and its window kind (`min(bucket, 33)`)
+@pytest.mark.parametrize("width,pages", [
+    (4, 4), (16, 8), (64, 16), (256, 32), (512, 32), (33, 8)])
+def test_pages_per_step_at_the_engines_widths(width, pages):
+    from ray_tpu.serve.llm import _pow4_widths
+
+    asked = set(_pow4_widths(4, 256)) | set(_pow4_widths(4, 512))
+    asked |= {min(w, 512 // 16 + 1) for w in asked}
+    assert width in asked and asked == {4, 16, 64, 256, 512, 33}
+    assert pages_per_step(width, 16) == pages
+    assert -(-width // pages) <= 16      # a lane is at most 16 steps
+    # 128 keys a step or the whole table, never more than 512 keys
+    assert pages == width or 128 <= pages * 16 <= 512
+
+
+def test_pages_per_step_follows_the_page_size():
+    """K is set in keys: a smaller page means more pages a step, and a
+    table is never split below its width."""
+    assert [pages_per_step(64, p) for p in (4, 8, 16, 32, 128, 256)] \
+        == [32, 16, 16, 16, 4, 2]
+    assert all(1 <= pages_per_step(w, p) <= w
+               for w in range(1, 70) for p in (1, 4, 16, 128, 1024))
 
 
 def test_ragged_with_garbage_lanes():

@@ -333,6 +333,38 @@ def test_prefill_context_counters_say_what_was_gathered():
     assert st["prefill_ctx_rows_total"] <= st["prefill_ctx_cols_total"]
 
 
+def test_paged_grid_counters_are_the_hand_count():
+    """Two lanes, page 16: `paged_grid_steps_total` is what the decode
+    passes' kernel calls had as a grid (lanes x blocks of
+    `pages_per_step` pages of the table), `paged_grid_steps_live_total`
+    the steps of it that held a page."""
+    from ray_tpu.ops.paged_attention import pages_per_step
+
+    eng = _engine(cfg=_cfg(max_seq_len=512), page_size=16, num_pages=65,
+                  max_batch=2, prefill_chunk=64)
+    assert eng._paged_width_buckets() == [4, 16, 32]
+    assert [pages_per_step(w, 16) for w in (4, 16)] == [4, 8]
+    assert eng.stats()["paged_grid_steps_total"] == 0   # warm-up: none
+    long = [1 + (5 * i) % 60 for i in range(130)]
+    before = eng.stats()
+    out = eng.generate_batch([{"tokens": long, "max_new_tokens": 4},
+                              {"tokens": [7, 3, 9], "max_new_tokens": 6}])
+    assert [len(o) for o in out] == [4, 6]
+    after = eng.stats()
+    steps, total, live = (after[k] - before[k] for k in (
+        "decode_steps", "paged_grid_steps_total",
+        "paged_grid_steps_live_total"))
+    # the short prompt's first token comes with the long one's first
+    # chunk; it decodes alone while chunks two and three pass (one page
+    # in a table of 4: one block a lane), then both decode three times:
+    # 9 pages beside 1 in a table of 16, two blocks of 8 pages a lane,
+    # both of the long lane's held and one of the short lane's
+    assert steps == 2 + 3
+    assert total == 2 * (2 * 1) + 3 * (2 * 2)
+    assert live == 2 * 1 + 3 * (2 + 1)
+    assert 0 < live < total
+
+
 @pytest.mark.parametrize("max_seq_len,chunk,buckets", [
     (4096, 64, [256, 1024, 4096]),   # the benchmark's serving cells
     (WIDE_CTX, WIDE_CHUNK, WIDE_BUCKETS),

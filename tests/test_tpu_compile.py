@@ -66,19 +66,28 @@ def _qkv(shape, sharding):
     return q, kv, kv
 
 
-@pytest.mark.parametrize("width", [4, 512], ids=["narrowest", "widest"])
-def test_paged_decode_compiles_at_serve_shapes(one_chip, width):
+# chip_smoke.py's 8 lanes at its narrowest and widest table, and the chat
+# cell's 16 lanes (benchmarks/configs/mistral-7b-v0.3-serve.json: contexts
+# to 4096) at every width its engine asks for; each width has its own
+# pages a grid step, whose buffers must fit VMEM
+@pytest.mark.parametrize("lanes,width", [
+    (B, 4), (B, 512), (16, 4), (16, 16), (16, 64), (16, 256)],
+    ids=["narrowest", "widest", "chat-4", "chat-16", "chat-64", "chat-256"])
+def test_paged_decode_compiles_at_serve_shapes(one_chip, lanes, width):
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     compiled = jax.jit(
         lambda q, k, v, bt, cl: paged_attention(
             q, k, v, bt, cl, page_size=PAGE, interpret=False)
-    ).lower(spec((B, 1, H, D), jnp.bfloat16),
+    ).lower(spec((lanes, 1, H, D), jnp.bfloat16),
             spec((POOL_SLOTS, HKV, D), jnp.bfloat16),
             spec((POOL_SLOTS, HKV, D), jnp.bfloat16),
-            spec((B, width), jnp.int32), spec((B,), jnp.int32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+            spec((lanes, width), jnp.int32),
+            spec((lanes,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_attention_decode" in text
+    assert "paged_attention_decode_window" not in text
 
 
 @pytest.mark.parametrize("shape", [TRAIN_SHAPE, LLAMA3_8B_SHAPE],
@@ -127,14 +136,21 @@ def test_flash_gradient_compiles_at_train_shapes(one_chip, shape, window):
 # of width 1024 at hidden 3072
 
 
-@pytest.mark.parametrize("heads,window", [(48, None), (72, 512)],
-                         ids=["full-group6", "window-group9"])
-def test_paged_decode_compiles_at_laguna_shapes(one_chip, heads, window):
+# the full kind's table at every bucket to 8192 tokens, the window kind's
+# at `min(bucket, 33)`
+@pytest.mark.parametrize("heads,window,width", [
+    (48, None, 4), (48, None, 16), (48, None, 64), (48, None, 256),
+    (48, None, 512), (72, 512, 4), (72, 512, 16), (72, 512, 33)],
+    ids=["full-group6-4", "full-group6-16", "full-group6-64",
+         "full-group6-256", "full-group6", "window-group9-4",
+         "window-group9-16", "window-group9"])
+def test_paged_decode_compiles_at_laguna_shapes(one_chip, heads, window,
+                                                width):
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     lanes = 32
-    width, pages = (33, 38) if window else (512, 512)
+    pages = 38 if window else 512
     slots = (1 + lanes * pages) * PAGE
     compiled = jax.jit(
         lambda q, k, v, bt, cl, st: paged_attention(
@@ -145,10 +161,10 @@ def test_paged_decode_compiles_at_laguna_shapes(one_chip, heads, window):
             spec((slots, HKV, D), jnp.bfloat16),
             spec((lanes, width), jnp.int32), spec((lanes,), jnp.int32),
             spec((lanes,), jnp.int32)).compile()
-    name = "paged_attention_decode_window" if window \
-        else "paged_attention_decode"
     text = compiled.as_text()
-    assert "tpu_custom_call" in text and name in text
+    assert "tpu_custom_call" in text
+    assert ("paged_attention_decode_window" in text) == bool(window)
+    assert "paged_attention_decode" in text
 
 
 @pytest.mark.parametrize("tokens", [32, 512], ids=["decode", "prefill"])
