@@ -617,8 +617,8 @@ def pack_kv_pages(meta: Dict, rows: Dict) -> bytes:
     """Serialize one sequence's prefilled KV rows + metadata into a
     self-checksummed blob.  ``meta`` is a small picklable dict (request
     id, prompt tokens, first generated token, slot count, page size);
-    ``rows`` is {"k": [per-layer host arrays], "v": [...]} as returned
-    by models.cache.gather_slots."""
+    ``rows`` is what models.cache.gather_slots returns: by the name of
+    a row's part ("k", "v"; "latent"), per-layer host arrays."""
     import pickle
     import zlib
 

@@ -7,14 +7,28 @@ layer keeps (`LayerCache`): a `full` layer a row for every position, a
 each KIND that occurs, sized by what the kind needs, and hands every
 layer the slots, context and tables of its own kind.
 
-The pools themselves are paging-agnostic flat slot arrays, one `k` and
-one `v` a layer: `[slots of the layer's kind, kv_heads, head_dim]`.
-Slot 0 of every pool is the garbage slot that padding writes to.
+Beside its paging kind a layer states the FORM of its row
+(`LayerCache.rows`): a key and a value of `[kv_heads, head_dim]` each, or
+— `latent` > 0 — ONE vector of that many numbers from which the layer's
+attention makes keys and values itself, and no second pool.  A latent
+pool's rows are `latent_row_width(latent)` wide, the next multiple of
+the chip's 128 lanes, zeros behind the row: the chip stores a `[slots,
+576]` array in tiles of 128 lanes, 640 wide, whatever its shape says,
+and its kernel compiler copies whole tiles only ("Slice shape along
+dimension 2 must be aligned to tiling (128), but is 576" is what a
+page's copy out of a 576-wide pool gets), so the pool's shape states
+what the memory holds and a page of it is one copy.
+
+The pools themselves are paging-agnostic flat slot arrays, by the name
+of the row's part (`"k"`, `"v"`; `"latent"`) a list over the layers:
+`[slots of the layer's kind, *the part's shape]`, None at a layer whose
+row has no such part.  Slot 0 of every pool is the garbage slot that
+padding writes to.  Everything below goes by what a layer holds.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, NamedTuple, Sequence
+from typing import Any, Dict, NamedTuple, Sequence, Tuple
 
 import jax.numpy as jnp
 
@@ -24,6 +38,20 @@ class LayerCache(NamedTuple):
     window: int      # positions a window layer sees (0 for full)
     kv_heads: int
     head_dim: int
+    latent: int = 0  # > 0: the row is one vector of this width
+
+    def rows(self) -> Dict[str, Tuple[int, ...]]:
+        """The parts of this layer's row, name -> shape."""
+        if self.latent:
+            return {"latent": (latent_row_width(self.latent),)}
+        part = (self.kv_heads, self.head_dim)
+        return {"k": part, "v": part}
+
+
+def latent_row_width(latent: int, lanes: int = 128) -> int:
+    """What a latent pool's row is allocated at: the row's `latent`
+    numbers, then zeros up to the next multiple of the chip's lanes."""
+    return -(-latent // lanes) * lanes
 
 
 def kinds_of(spec: Sequence[LayerCache]) -> Dict[str, int]:
@@ -39,22 +67,33 @@ def kinds_of(spec: Sequence[LayerCache]) -> Dict[str, int]:
 
 def make_pools(spec: Sequence[LayerCache], slots: Dict[str, int],
                dtype: Any) -> Dict[str, Any]:
-    """Zeroed pools: layer i holds `slots[spec[i].kind]` rows."""
-    shapes = [(slots[s.kind], s.kv_heads, s.head_dim) for s in spec]
-    return {"k": [jnp.zeros(shape, dtype) for shape in shapes],
-            "v": [jnp.zeros(shape, dtype) for shape in shapes]}
+    """Zeroed pools: layer i holds `slots[spec[i].kind]` rows of each
+    part of its row."""
+    rows = [layer.rows() for layer in spec]
+    return {name: [jnp.zeros((slots[layer.kind], *r[name]), dtype)
+                   if name in r else None
+                   for layer, r in zip(spec, rows)]
+            for name in dict.fromkeys(n for r in rows for n in r)}
+
+
+def pool_bytes(pools: Dict[str, Any], kinds: Sequence[str],
+               kind: str) -> int:
+    """Bytes of every pool of the layers of `kind`."""
+    return sum(int(p.nbytes) for layer_pools in pools.values()
+               for p, k in zip(layer_pools, kinds)
+               if p is not None and k == kind)
 
 
 def gather_slots(pools: Dict[str, Any], kinds: Sequence[str],
                  slots: Dict[str, Any]) -> Dict[str, Any]:
-    """The rows at `slots[kind]` of every layer's pool as host numpy
+    """The rows at `slots[kind]` of every layer's pools as host numpy
     arrays: the export half of KV-page shipping."""
     import numpy as np
 
     idx = {kind: np.asarray(s, np.int32) for kind, s in slots.items()}
-    return {name: [np.asarray(p[idx[kind]])
-                   for p, kind in zip(pools[name], kinds)]
-            for name in ("k", "v")}
+    return {name: [None if p is None else np.asarray(p[idx[kind]])
+                   for p, kind in zip(layer_pools, kinds)]
+            for name, layer_pools in pools.items()}
 
 
 def scatter_slots(pools: Dict[str, Any], kinds: Sequence[str],
@@ -63,9 +102,10 @@ def scatter_slots(pools: Dict[str, Any], kinds: Sequence[str],
     """Write gathered rows back at `slots[kind]` (the import half).
     Returns the updated pools."""
     idx = {kind: jnp.asarray(s, jnp.int32) for kind, s in slots.items()}
-    return {name: [p.at[idx[kind]].set(jnp.asarray(r, p.dtype))
-                   for p, kind, r in zip(pools[name], kinds, rows[name])]
-            for name in ("k", "v")}
+    return {name: [None if p is None
+                   else p.at[idx[kind]].set(jnp.asarray(r, p.dtype))
+                   for p, kind, r in zip(layer_pools, kinds, rows[name])]
+            for name, layer_pools in pools.items()}
 
 
 def copy_slots(pools: Dict[str, Any], kinds: Sequence[str], kind: str,
@@ -74,6 +114,7 @@ def copy_slots(pools: Dict[str, Any], kinds: Sequence[str], kind: str,
     copy-on-write split of a shared page.  Returns the updated pools."""
     src = jnp.asarray(src, jnp.int32)
     dst = jnp.asarray(dst, jnp.int32)
-    return {name: [p.at[dst].set(p[src]) if k == kind else p
-                   for p, k in zip(pools[name], kinds)]
-            for name in ("k", "v")}
+    return {name: [p.at[dst].set(p[src])
+                   if p is not None and k == kind else p
+                   for p, k in zip(layer_pools, kinds)]
+            for name, layer_pools in pools.items()}

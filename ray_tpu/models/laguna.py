@@ -368,8 +368,10 @@ class GatedAttention(nn.Module):
 
 class ExpertLayer(nn.Module):
     """This share's part of the routed sum, plus the shared expert where
-    the config has one."""
+    the config has one.  `scores` is the family's score function
+    (another family's expert layer is this one with its own)."""
     cfg: LagunaConfig
+    scores: Any = router_scores
 
     @nn.compact
     def __call__(self, x, valid):
@@ -392,7 +394,7 @@ class ExpertLayer(nn.Module):
         routed, counters = moe.moe_layer(
             flat, w_router, w1, w3, w2, top_k=cfg.num_experts_per_tok,
             held=(lo, hi), valid=valid.reshape(b * s),
-            normalize=cfg.norm_topk_prob, scores=router_scores)
+            normalize=cfg.norm_topk_prob, scores=self.scores)
         routed = routed.reshape(b, s, d)
         if cfg.shared_expert_intermediate_size:
             shared = SwiGLU(cfg, cfg.shared_expert_intermediate_size,

@@ -158,14 +158,54 @@ def _up_kernel(te_ref, na_ref, x_ref, w1_ref, w3_ref, h_ref):
         h_ref[...] = (jax.nn.silu(gate) * up).astype(h_ref.dtype)
 
 
-def _down_kernel(te_ref, na_ref, h_ref, w2_ref, y_ref):
+def _down_kernel(te_ref, na_ref, h_ref, w2_ref, y_ref, *, slices: int):
+    """`slices` > 1: the grid's second dimension walks the hidden width,
+    and the row tile's output block, which stays where it is meanwhile,
+    takes the sum of the slices' products."""
     from jax.experimental import pallas as pl
+
+    # read out here: the interpreter has no `program_id` inside a branch
+    first = pl.program_id(1) == 0 if slices > 1 else None
 
     @pl.when(pl.program_id(0) < na_ref[0])
     def _():
-        y_ref[...] = jnp.dot(
-            h_ref[...], w2_ref[0],
-            preferred_element_type=jnp.float32).astype(y_ref.dtype)
+        y = jnp.dot(h_ref[...], w2_ref[0],
+                    preferred_element_type=jnp.float32)
+        if slices == 1:
+            y_ref[...] = y.astype(y_ref.dtype)
+            return
+
+        @pl.when(first)
+        def _set():
+            y_ref[...] = y.astype(y_ref.dtype)
+
+        @pl.when(jnp.logical_not(first))
+        def _add():
+            y_ref[...] += y.astype(y_ref.dtype)
+
+
+# VMEM the blocks of an expert's matrices may take in one grouped call,
+# both halves of the pipeline's double buffer together
+_EXPERT_BLOCK_BYTES = 48 * 1024 * 1024
+
+
+def hidden_tile(d: int, f: int, itemsize: int) -> int:
+    """Columns of an expert's hidden width a grid step of the forward
+    covers: all `f` where gate and up blocks of `[d, f]`, double
+    buffered, fit `_EXPERT_BLOCK_BYTES` (3072 x 1024 and every expert
+    the repo ran before the 7680 x 2048 ones: 25 MB), else `f` halved
+    until they do, in multiples of 128 (7680 x 2048: 512, four slices —
+    whole, the up call asked for 120 MB of VMEM under a limit of 96)."""
+    tf = f
+    while 2 * 2 * d * tf * itemsize > _EXPERT_BLOCK_BYTES and tf % 256 == 0:
+        tf //= 2
+    return tf
+
+
+def _tile(i, na):
+    """The row tile grid step i stands on: itself, or past the active
+    tiles the last active one (no fetch, no write-back)."""
+    return jnp.minimum(i, jnp.maximum(na[0] - 1, 0))
 
 
 def _tile_plumbing(tile_expert: jax.Array, active_tiles: jax.Array):
@@ -178,14 +218,11 @@ def _tile_plumbing(tile_expert: jax.Array, active_tiles: jax.Array):
     na = jnp.reshape(active_tiles, (1,)).astype(jnp.int32)
     te = tile_expert.astype(jnp.int32)
 
-    def tile(i, te, na):
-        return jnp.minimum(i, jnp.maximum(na[0] - 1, 0))
-
     def row_map(i, te, na):
-        return (tile(i, te, na), 0)
+        return (_tile(i, na), 0)
 
     def w_map(i, te, na):
-        return (te[tile(i, te, na)], 0, 0)
+        return (te[_tile(i, na)], 0, 0)
 
     params = pltpu.CompilerParams(
         dimension_semantics=("arbitrary",),
@@ -204,10 +241,12 @@ def grouped_swiglu(xs: jax.Array, w1: jax.Array, w3: jax.Array,
     combine never reads them).
 
     Two `pallas_call`s, both named `moe_experts`: gate and up with the
-    activation, then down.  One grid step a row tile; the expert's whole
-    matrices are one block, so consecutive tiles of one expert fetch it
-    once, an inactive tile keeps the last active tile's indices (no
-    fetch) and its body is predicated off."""
+    activation, then down.  One grid step a row tile where an expert's
+    whole matrices are one block, so that consecutive tiles of one
+    expert fetch it once; where they are too large for that
+    (`hidden_tile`) the grid is (row tiles, slices of the hidden width).
+    An inactive tile keeps the last active tile's indices (no fetch)
+    and its body is predicated off."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -215,28 +254,58 @@ def grouped_swiglu(xs: jax.Array, w1: jax.Array, w3: jax.Array,
     rows, d = xs.shape
     f = w1.shape[-1]
     n_tiles = rows // tm
-    te, na, row_map, w_map, params = _tile_plumbing(tile_expert,
-                                                    active_tiles)
+    tf = hidden_tile(d, f, w1.dtype.itemsize)
+    slices = f // tf
+    # one slice: the grid is the row tiles alone, the program every
+    # expert that fits one block has always had
+    grid = (n_tiles, slices) if slices > 1 else (n_tiles,)
+    na = jnp.reshape(active_tiles, (1,)).astype(jnp.int32)
+    te = tile_expert.astype(jnp.int32)
+    params = pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",) * len(grid),
+        vmem_limit_bytes=96 * 1024 * 1024)
+
+    def part(i, j, na):
+        """The slice a step covers (`j`: the grid's second index, if it
+        has one): past the active tiles the last active tile's last."""
+        return jnp.where(i < na[0], j[0], slices - 1) if j else 0
+
+    def x_map(i, *rest):
+        *_j, _te, na = rest
+        return (_tile(i, na), 0)
+
+    def h_map(i, *rest):
+        *j, _te, na = rest
+        return (_tile(i, na), part(i, j, na))
+
+    def w_up_map(i, *rest):
+        *j, te, na = rest
+        return (te[_tile(i, na)], 0, part(i, j, na))
+
+    def w_down_map(i, *rest):
+        *j, te, na = rest
+        return (te[_tile(i, na)], part(i, j, na), 0)
+
     h = pl.pallas_call(
         _up_kernel,
         out_shape=jax.ShapeDtypeStruct((rows, f), xs.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(n_tiles,),
-            in_specs=[pl.BlockSpec((tm, d), row_map),
-                      pl.BlockSpec((1, d, f), w_map),
-                      pl.BlockSpec((1, d, f), w_map)],
-            out_specs=pl.BlockSpec((tm, f), row_map)),
+            num_scalar_prefetch=2, grid=grid,
+            in_specs=[pl.BlockSpec((tm, d), x_map),
+                      pl.BlockSpec((1, d, tf), w_up_map),
+                      pl.BlockSpec((1, d, tf), w_up_map)],
+            out_specs=pl.BlockSpec((tm, tf), h_map)),
         compiler_params=params, interpret=interpret,
         name="moe_experts",
     )(te, na, xs, w1, w3)
     return pl.pallas_call(
-        _down_kernel,
+        functools.partial(_down_kernel, slices=slices),
         out_shape=jax.ShapeDtypeStruct((rows, d), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(n_tiles,),
-            in_specs=[pl.BlockSpec((tm, f), row_map),
-                      pl.BlockSpec((1, f, d), w_map)],
-            out_specs=pl.BlockSpec((tm, d), row_map)),
+            num_scalar_prefetch=2, grid=grid,
+            in_specs=[pl.BlockSpec((tm, tf), h_map),
+                      pl.BlockSpec((1, tf, d), w_down_map)],
+            out_specs=pl.BlockSpec((tm, d), x_map)),
         compiler_params=params, interpret=interpret,
         name="moe_experts",
     )(te, na, h, w2)

@@ -1895,13 +1895,17 @@ def _controller():
     except ValueError:
         pass
     import ray_tpu.api as api
+    from ray_tpu._private.rpc import RpcError
 
     try:
         return api.ActorClass(ServeController, name=CONTROLLER_NAME,
                               lifetime="detached").remote()
-    except ray_tpu.RayError:
-        # lost the creation race to another caller; the winner may not
-        # have registered the name yet — wait it out briefly
+    except (ray_tpu.RayError, RpcError):
+        # lost the creation race to another caller (or to this caller's
+        # own first attempt, answered too late on a loaded host and sent
+        # again: the head then says the name is "already taken", an
+        # RpcError); the winner may not have registered the name yet —
+        # wait it out briefly
         import time as _time
 
         deadline = _time.monotonic() + 30

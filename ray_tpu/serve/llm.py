@@ -168,13 +168,14 @@ def _pow4_widths(first: int, cap: int) -> List[int]:
         w *= 4
 
 
-def _jit_forward(model, params, k, v, tokens, q_pos, last_idx, groups,
+def _jit_forward(model, params, pools, tokens, q_pos, last_idx, groups,
                  temperature=0.0, top_k=0, rng=None, top2=False, feed=None):
     """One forward over the paged cache -> (next tokens at ``last_idx``,
     updated pools).  Jitted ONCE per (model, shapes, sampling knobs) —
     the flax module AND the sampling knobs are hashable static
     arguments, so every engine instance with the same config shares the
-    compiled executable (k/v pools donated: in-place cache updates).
+    compiled executable (`pools`, the cache's slot arrays by the name of
+    a row's part — models/cache.py — donated: in-place cache updates).
 
     ``groups`` maps each cache kind of the model (models/cache.py) to
     that kind's arrays: the write ``slots`` and the context in one of
@@ -204,7 +205,7 @@ def _jit_forward(model, params, k, v, tokens, q_pos, last_idx, groups,
         import jax.numpy as jnp
 
         rng = jnp.zeros((2,), dtype="uint32")  # unused when greedy
-    return fn(model, params, k, v, tokens, q_pos, last_idx, rng, groups,
+    return fn(model, params, pools, tokens, q_pos, last_idx, rng, groups,
               feed)
 
 
@@ -223,7 +224,7 @@ def _jitted_forward(temperature=0.0, top_k=0, top2=False):
     if fn is None:
         import jax.numpy as jnp
 
-        def _fwd(model, params, k, v, tokens, q_pos, last_idx, rng,
+        def _fwd(model, params, pools, tokens, q_pos, last_idx, rng,
                  groups, feed=None, temperature=key[0], top_k=key[1],
                  top2=key[2]):
             if feed is not None:
@@ -232,7 +233,7 @@ def _jitted_forward(temperature=0.0, top_k=0, top2=False):
                 tokens = jnp.where(src[:, None] >= 0,
                                    late[:, None].astype(tokens.dtype),
                                    tokens)
-            cache = {"k": k, "v": v, "q_pos": q_pos, "groups": groups}
+            cache = {**pools, "q_pos": q_pos, "groups": groups}
             logits, pools, *counted = model.apply(
                 {"params": params}, tokens, cache)
             picked = jnp.take_along_axis(
@@ -261,7 +262,7 @@ def _jitted_forward(temperature=0.0, top_k=0, top2=False):
                 jnp.stack([i1, i2], axis=-1).astype(jnp.int32))
 
         fn = _forward_cache[key] = jax.jit(
-            _fwd, static_argnums=0, donate_argnums=(2, 3))
+            _fwd, static_argnums=0, donate_argnums=(2,))
     return fn
 
 
@@ -574,6 +575,9 @@ class LLMEngine:
         # group is this engine's own pages, block tables and index)
         spec = cfg.cache_spec()
         self._kinds = [layer.kind for layer in spec]
+        # layers whose row is one latent vector (models/cache.py): what
+        # their passes read is counted apart, `latent_*` in stats()
+        self._latent_layers = sum(bool(layer.latent) for layer in spec)
         windows = kv_cache.kinds_of(spec)
         if windows.pop("full", None) is None:
             raise ValueError("a model with no full-attention layer: the "
@@ -729,6 +733,13 @@ class LLMEngine:
                         "first_tokens_total": 0, "finished_total": 0,
                         "queue_wait_secs_total": 0.0,
                         "prefill_wait_secs_total": 0.0}
+        if self._latent_layers:
+            # latent rows the decode passes' kernel calls and the
+            # prefill passes' blocks read (a lane's context, a latent
+            # layer), and those kernel calls (a layer a decode pass)
+            self._totals.update(latent_decode_rows_total=0,
+                                latent_prefill_rows_total=0,
+                                latent_decode_calls_total=0)
         self._prefill_widths = self._prefill_ctx_buckets()
         self._prefill_passes_by_width = dict.fromkeys(
             self._prefill_widths, 0)
@@ -952,8 +963,8 @@ class LLMEngine:
         else:
             full.update(ctx=ctx, ctx_pos=ctx_pos, ctx_mask=ctx_mask)
         tok, self._pools, *top2 = self._step_fn(
-            self._model, self._params, self._pools["k"], self._pools["v"],
-            tokens, q_pos, last_idx, {"full": full, **(windows or {})},
+            self._model, self._params, self._pools, tokens, q_pos,
+            last_idx, {"full": full, **(windows or {})},
             temperature=self.temperature, top_k=self.top_k, rng=rng,
             top2=self.logit_trace, feed=feed)
         return tok, (top2[0] if top2 else None)
@@ -1093,8 +1104,7 @@ class LLMEngine:
             self._garbage_decode_args(width)
         return _jitted_forward(self.temperature, self.top_k,
                                self.logit_trace).lower(
-            self._model, self._params, self._pools["k"],
-            self._pools["v"], tokens, q_pos, last_idx,
+            self._model, self._params, self._pools, tokens, q_pos, last_idx,
             jax.numpy.zeros((2,), dtype="uint32"),  # rng, unused
             {"full": {"slots": slots,
                       "block_tables": kwargs["block_tables"],
@@ -1747,6 +1757,9 @@ class LLMEngine:
         self._totals["prefill_slots_total"] += lanes * c
         self._totals["prefill_ctx_rows_total"] += sum(ctx_rows)
         self._totals["prefill_ctx_cols_total"] += lanes * width
+        if self._latent_layers:
+            self._totals["latent_prefill_rows_total"] += \
+                self._latent_layers * sum(ctx_rows)
         self._prefill_passes_by_width[width] += 1
         owed = []
         with self._lock:
@@ -1824,6 +1837,10 @@ class LLMEngine:
         self._feed[0] = out
         self._decode_steps += 1
         self._totals["decode_lane_steps_total"] += len(decode_args)
+        if self._latent_layers:
+            self._totals["latent_decode_rows_total"] += \
+                self._latent_layers * int(context_lens.sum())
+            self._totals["latent_decode_calls_total"] += self._latent_layers
         owed = []
         with self._lock:
             for lane, (seq, *_rest) in enumerate(decode_args):
@@ -2003,6 +2020,7 @@ class LLMEngine:
         """Counters and gauges of this engine.  Every `*_total`,
         `*_secs` and `*_steps` key is cumulative and never falls: a
         reader takes the change between two calls."""
+        from ray_tpu.models import cache as kv_cache
         from ray_tpu.ops import compile_counts
 
         with self._lock:
@@ -2045,12 +2063,16 @@ class LLMEngine:
                     "cow_splits": self._cow_splits,
                     "pages_allocated_total": self._pages_alloc_total,
                     # of one page of the full kind, over its layers
-                    "kv_page_bytes": sum(
-                        int(p.nbytes) for name in ("k", "v")
-                        for p, kind in zip(self._pools[name], self._kinds)
-                        if kind == "full") // self.num_pages,
+                    "kv_page_bytes": kv_cache.pool_bytes(
+                        self._pools, self._kinds, "full") // self.num_pages,
                     "kv_pages_shipped_out": self._kv_pages_shipped_out,
                     "kv_pages_shipped_in": self._kv_pages_shipped_in,
+                    **({"latent_pool_bytes": sum(
+                            int(p.nbytes) for p in self._pools["latent"]
+                            if p is not None),
+                        "latent_pages_in_use":
+                            self.num_pages - 1 - len(self._free_pages)}
+                       if self._latent_layers else {}),
                     "loop_running": self._loop_running,
                     "last_batch": self._last_batch}
 

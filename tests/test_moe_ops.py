@@ -116,3 +116,45 @@ def test_row_tile_follows_the_mean_group():
     assert moe.row_tile(512, 10, 256) == 32     # a prefill pass
     assert moe.row_tile(8192, 10, 256) == 128   # never over the MXU's side
 
+
+
+def test_an_expert_too_wide_for_one_block_is_walked_in_slices(monkeypatch):
+    """`hidden_tile`: an expert whose gate and up blocks do not fit the
+    budget is taken a slice of its hidden width a grid step, the down
+    product summed over the slices — the same layer (an expert nobody
+    chose and padding included), and the sizes the cells run keep one
+    slice or get the four the chip's VMEM allows."""
+    assert moe.hidden_tile(3072, 1024, 2) == 1024      # Laguna's expert
+    assert moe.hidden_tile(2304, 896, 2) == 896
+    assert moe.hidden_tile(7680, 2048, 2) == 512
+    d, f = 32, 512
+    ks = jax.random.split(jax.random.PRNGKey(5), 5)
+    wr = jax.random.normal(ks[0], (d, 4), jnp.float32) * d ** -0.5
+    w1, w3 = (jax.random.normal(k, (4, d, f), jnp.float32) * d ** -0.5
+              for k in ks[1:3])
+    w2 = jax.random.normal(ks[3], (4, f, d), jnp.float32) * f ** -0.5
+    x = jax.random.normal(ks[4], (23, d), jnp.float32)
+    valid = jnp.arange(23) % 5 != 0
+    whole, _ = moe.moe_layer(x, wr, w1, w3, w2, top_k=2, held=(0, 4),
+                             valid=valid)
+    monkeypatch.setattr(moe, "_EXPERT_BLOCK_BYTES", 2 * 2 * d * 128 * 4)
+    assert moe.hidden_tile(d, f, 4) == 128
+    jax.clear_caches()
+    sliced, counters = moe.moe_layer(x, wr, w1, w3, w2, top_k=2,
+                                     held=(0, 4), valid=valid)
+    jax.clear_caches()
+    np.testing.assert_allclose(sliced, whole, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        sliced, _naive_wide(x, wr, w1, w3, w2, valid), rtol=1e-5, atol=1e-5)
+    assert int(counters["assignments"]) == 2 * int(valid.sum())
+
+
+def _naive_wide(x, wr, w1, w3, w2, valid):
+    ids, weights = moe.route(x, wr, 2)
+    out = jnp.zeros(x.shape, jnp.float32)
+    for e in range(w1.shape[0]):
+        w = jnp.where(valid, jnp.sum(jnp.where(ids == e, weights, 0.0),
+                                     axis=-1), 0.0)
+        out = out + w[:, None] * (
+            (jax.nn.silu(x @ w1[e]) * (x @ w3[e])) @ w2[e])
+    return out

@@ -215,3 +215,34 @@ def test_expert_layer_gradient_compiles_at_mellum_shapes(one_chip):
         spec((16, 896, 2304), jnp.float32)).compile()
     text = compiled.as_text()
     assert "moe_experts_bwd_dx" in text and "moe_experts_bwd_dw" in text
+
+
+# ------------------------------------- the latent-attention cell's kernel
+# benchmarks/configs/openpangu-ultra-moe-718b-serve.json: 32 lanes of 128
+# heads over ONE latent row a token (512 + 64 numbers in a pool 640
+# wide), every table width to 8192 tokens
+
+
+@pytest.mark.parametrize("width", [4, 16, 64, 256, 512])
+def test_latent_decode_compiles_at_the_cells_shapes(one_chip, width):
+    from ray_tpu.models.cache import latent_row_width
+    from ray_tpu.ops.latent_attention import latent_paged_attention
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lanes, heads, row = 32, 128, latent_row_width(512 + 64)
+    slots = (1 + lanes * (8192 // PAGE)) * PAGE
+    compiled = jax.jit(
+        lambda q, pool, bt, cl: latent_paged_attention(
+            q, pool, bt, cl, page_size=PAGE, value_width=512,
+            scale=192 ** -0.5, interpret=False)
+    ).lower(spec((lanes, 1, heads, row), jnp.bfloat16),
+            spec((slots, row), jnp.bfloat16),
+            spec((lanes, width), jnp.int32),
+            spec((lanes,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "latent_attention_decode" in text
+    # a trace tells it from the key-and-value kernels, and the patterns
+    # of their busy shares do not take it in
+    assert "paged_attention_decode" not in text
