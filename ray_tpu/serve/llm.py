@@ -112,6 +112,14 @@ PREFILL_CHUNK = 64      # prompt tokens prefilled per step: bounds how long
 # one long prompt can stall in-flight decodes
 PREFILL_LANES = 8       # sequences prefilling one chunk each per step
 # (batched prefill: admitting N streams costs N/lanes steps)
+PREFILL_NARROW_LANES = 2  # lanes of the NARROW prefill pass, which a step
+# with at most that many prompts prefilling runs (`_prefill_shape`): not an
+# argument.  Measured on the v5e (PERF.md section 6, PR 35): below the knee
+# one prompt prefills at a time, and the 8-lane pass cost 16.2 ms for its
+# 512 slots whatever they held; at 2 x 64 slots the pass costs 7.1 ms, its
+# matrix products at the weights' own stream, so fewer lanes would buy
+# nothing, and two keep a second prompt that arrives while a first one
+# prefills in the narrow pass (the chat cells ran no other: 100 %, 99.9 %)
 STREAM_FLUSH_TOKENS = 4  # tokens coalesced per stream item after the
 # first (the first token flushes immediately for TTFT); each item costs a
 # stream push + a ref resolution + an SSE chunk, so this is the per-token
@@ -191,6 +199,11 @@ def _jit_forward(model, params, pools, tokens, q_pos, last_idx, groups,
     engine dispatches a step before it has read the one before, so a
     sequence's newest token is on the device only (`LLMEngine.step`).
 
+    The pass returns a token an entry of ``last_idx``.  ``tokens`` may
+    have fewer lanes than that (the narrow prefill pass): the entries
+    past them read 0, and the output keeps the shape the decode
+    programs' ``feed`` was compiled for.
+
     Sampling is a pair of jit-STATIC knobs (ISSUE 13 satellite / PR-11
     declared headroom (d)): ``temperature == 0`` compiles the exact
     greedy-argmax program the decode-identity tier-1 gate pins down —
@@ -236,8 +249,11 @@ def _jitted_forward(temperature=0.0, top_k=0, top2=False):
             cache = {**pools, "q_pos": q_pos, "groups": groups}
             logits, pools, *counted = model.apply(
                 {"params": params}, tokens, cache)
+            # a pass of fewer lanes than `last_idx` has entries (the
+            # narrow prefill pass) looks at the first of them
+            lanes = tokens.shape[0]
             picked = jnp.take_along_axis(
-                logits, last_idx[:, None, None], axis=1)[:, 0]
+                logits, last_idx[:lanes, None, None], axis=1)[:, 0]
             if temperature <= 0.0:
                 tok = jnp.argmax(picked, axis=-1)
             else:
@@ -247,6 +263,11 @@ def _jitted_forward(temperature=0.0, top_k=0, top2=False):
                     scaled = jnp.where(scaled < kth, -jnp.inf, scaled)
                 tok = jax.random.categorical(rng, scaled, axis=-1)
             tok = tok.astype(jnp.int32)
+            if lanes < last_idx.shape[0]:
+                # ... and returns its tokens in the wide pass's shape,
+                # so the decode programs see ONE `feed` and the counters
+                # stay at one offset
+                tok = jnp.pad(tok, (0, last_idx.shape[0] - lanes))
             if counted:
                 tok = jnp.concatenate(
                     [tok, counted[0].astype(jnp.int32)])
@@ -705,14 +726,15 @@ class LLMEngine:
                                    jnp.int32))
         self._feed = list(self._no_feed)
         # cumulative, as `stats()` gives them.  Work: prompt tokens
-        # prefilled, the token slots (lanes x chunk) the prefill passes
-        # had for them, decode lanes stepped (over decode_steps: the mean
-        # batch).  Requests by stage (finished = ended and not
+        # prefilled, the token slots (the pass's own lanes x chunk) the
+        # prefill passes had for them, the passes that were narrow (over
+        # prefill_steps), decode lanes stepped (over decode_steps: the
+        # mean batch).  Requests by stage (finished = ended and not
         # cancelled), and the seconds they waited for the next one.
         # Context: the rows the prefill passes' real lanes read, the
-        # columns (lanes x width) the passes gathered for them, and the
-        # passes by the width they took.  Run-ahead: decode passes
-        # dispatched while the step before was unread (over
+        # columns (the pass's lanes x width) the passes gathered for
+        # them, and the passes by the width they took.  Run-ahead: decode
+        # passes dispatched while the step before was unread (over
         # decode_steps: how often the device had its next pass queued),
         # and lane-steps computed for a sequence that had ended by the
         # time they were read (its `eos`, a cancel or its deadline came
@@ -722,6 +744,7 @@ class LLMEngine:
         # kind of layer each pass) and those that held a page.
         self._totals = {"prefill_tokens_total": 0,
                         "prefill_slots_total": 0,
+                        "prefill_narrow_passes_total": 0,
                         "prefill_ctx_rows_total": 0,
                         "prefill_ctx_cols_total": 0,
                         "decode_lane_steps_total": 0,
@@ -743,6 +766,7 @@ class LLMEngine:
         self._prefill_widths = self._prefill_ctx_buckets()
         self._prefill_passes_by_width = dict.fromkeys(
             self._prefill_widths, 0)
+        self._narrow_prefill = self._narrow_prefill_shape()
         # what the model counts on the device (`model.counters`: names
         # of the vector it returns beside its logits), summed by pass
         self._model_counters = {
@@ -1047,23 +1071,55 @@ class LLMEngine:
         an engine whose ctx_len is at most that has one: its ctx_len."""
         return _pow4_widths(4 * self.prefill_chunk, self.ctx_len)
 
-    def _warm_prefill_buckets(self, but: int) -> None:
-        """Compile every prefill context width up front, at the FIRST
-        prefill pass, for the reason `_warm_paged_buckets` gives for
-        decode (the deployment warm-up request lands here): garbage
-        lanes only (slot 0, every context column masked).  `but` is the
-        width that pass is about to run itself, so an engine with one
-        width runs nothing here."""
+    def _narrow_prefill_shape(self):
+        """(lanes, width) of the ONE narrow prefill program, or None for
+        an engine that has none.  A pass computes lanes x chunk slots
+        whatever they hold, so a step with few prompts waiting wants few
+        lanes; but every program is a compile at every replica's
+        start-up, so the narrow pass exists at one context width, the
+        SECOND of `_prefill_ctx_buckets`: wide enough for nearly every
+        chat prompt, and at PREFILL_NARROW_LANES lanes it gathers what
+        the wide pass gathers at its narrowest.  An engine with one
+        prefill width has no narrow program, for the reason it has one
+        width; nor has one whose wide pass is no wider."""
+        if len(self._prefill_widths) < 2 \
+                or self.prefill_lanes <= PREFILL_NARROW_LANES:
+            return None
+        return PREFILL_NARROW_LANES, self._prefill_widths[1]
+
+    def _prefill_shape(self, prompts: int, longest: int):
+        """(lanes, width) of the pass that advances `prompts` sequences
+        one chunk each, the longest context among them `longest` rows:
+        the narrow program where both fit it, else `prefill_lanes` lanes
+        at the smallest width that covers `longest`.  From what the step
+        observes alone."""
+        narrow = self._narrow_prefill
+        if narrow and prompts <= narrow[0] and longest <= narrow[1]:
+            return narrow
+        return self.prefill_lanes, next(
+            w for w in self._prefill_widths if w >= longest)
+
+    def _warm_prefill_buckets(self, but) -> None:
+        """Compile every prefill program up front (each context width of
+        the wide pass, and the narrow pass), at the FIRST prefill pass,
+        for the reason `_warm_paged_buckets` gives for decode (the
+        deployment warm-up request lands here): garbage lanes only (slot
+        0, every context column masked).  `but` is the (lanes, width)
+        that pass is about to run itself, so an engine with one program
+        runs nothing here."""
         np = self._np
-        lanes, c = self.prefill_lanes, self.prefill_chunk
-        zeros = np.zeros((lanes, c), np.int32)
-        for width in self._prefill_widths:
-            if width == but:
+        c = self.prefill_chunk
+        shapes = [(self.prefill_lanes, w) for w in self._prefill_widths]
+        if self._narrow_prefill:
+            shapes.append(self._narrow_prefill)
+        for lanes, width in shapes:
+            if (lanes, width) == but:
                 continue
+            zeros = np.zeros((lanes, c), np.int32)
             ctx = np.zeros((lanes, width), np.int32)
             self._forward(
                 zeros, zeros, ctx, ctx, np.zeros((lanes, width), bool),
-                zeros, np.zeros((lanes,), np.int32),
+                zeros, np.zeros((self.prefill_lanes,), np.int32),
                 windows=self._window_arrays([], lanes, c, width))
 
     def _warm_paged_buckets(self) -> None:
@@ -1709,22 +1765,23 @@ class LLMEngine:
         (lanes x chunk, empty lanes are garbage), in the steps where a
         prompt waits — a burst of N admissions costs N/lanes passes,
         while a LONG prompt still shares the loop with in-flight decodes
-        instead of monopolizing it.  The context the pass gathers is as
-        wide as the smallest _prefill_ctx_buckets() entry covering its
-        longest lane: its cost tracks USED context, at one program a
-        bucket.  Returns the prompt tokens the pass holds."""
+        instead of monopolizing it.  The pass is as narrow as what waits
+        (`_prefill_shape`): the narrow program's few lanes where few
+        prompts prefill, else prefill_lanes lanes over a context as wide
+        as the smallest _prefill_ctx_buckets() entry covering its
+        longest lane — its cost tracks USED slots and context, at one
+        program a shape.  Returns the prompt tokens the pass holds."""
         np = self._np
         phase = self._clock.phase
         phase("prefill_build")
         ctx_rows = [hi for _s, _lo, hi, *_r in prefill_args]
-        longest = max(ctx_rows)
-        width = next(w for w in self._prefill_widths if w >= longest)
+        lanes, width = shape = self._prefill_shape(
+            len(prefill_args), max(ctx_rows))
         if not self._prefill_warm:
             self._prefill_warm = True
             t0 = time.perf_counter()
-            self._warm_prefill_buckets(but=width)
+            self._warm_prefill_buckets(but=shape)
             self._warm_secs["prefill"] += time.perf_counter() - t0
-        lanes = self.prefill_lanes
         c = self.prefill_chunk
         tokens = np.zeros((lanes, c), np.int32)
         slot_arr = np.zeros((lanes, c), np.int32)
@@ -1732,7 +1789,9 @@ class LLMEngine:
         ctx_pos = np.zeros((lanes, width), np.int32)
         ctx_mask = np.zeros((lanes, width), bool)
         q_pos = np.zeros((lanes, c), np.int32)
-        last_idx = np.zeros((lanes,), np.int32)
+        # a token an entry comes back (`_jit_forward`): the wide pass's
+        # count whatever the lanes, the shape the decode pass feeds on
+        last_idx = np.zeros((self.prefill_lanes,), np.int32)
         for lane, (seq, lo, hi, toks, slots, ctx_slots) \
                 in enumerate(prefill_args):
             tokens[lane, :hi - lo] = toks
@@ -1746,7 +1805,7 @@ class LLMEngine:
             [(lane, seq.windows, lo, hi) for lane, (seq, lo, hi, *_r)
              in enumerate(prefill_args)], lanes, c, width) \
             if self._windows else None
-        phase("prefill_dispatch", width=width)
+        phase("prefill_dispatch", width=width, lanes=lanes)
         out, top2 = self._forward(
             tokens, slot_arr, ctx, ctx_pos, ctx_mask, q_pos, last_idx,
             windows=windows)
@@ -1755,6 +1814,8 @@ class LLMEngine:
         chunk_tokens = sum(hi - lo for _s, lo, hi, *_r in prefill_args)
         self._totals["prefill_tokens_total"] += chunk_tokens
         self._totals["prefill_slots_total"] += lanes * c
+        self._totals["prefill_narrow_passes_total"] += \
+            lanes < self.prefill_lanes
         self._totals["prefill_ctx_rows_total"] += sum(ctx_rows)
         self._totals["prefill_ctx_cols_total"] += lanes * width
         if self._latent_layers:
@@ -1904,8 +1965,8 @@ class LLMEngine:
     def warm_up(self) -> None:
         """Compile every program traffic can reach, before any loop or
         traffic, by running one tiny request inline: its first prefill
-        pass compiles every prefill context width, its first decode step
-        every decode width."""
+        pass compiles every prefill context width and the narrow pass,
+        its first decode step every decode width."""
         t0 = time.perf_counter()
         self.generate_batch([{"tokens": [1], "max_new_tokens": 2}])
         self.startup_secs["warm"] = time.perf_counter() - t0
@@ -2138,11 +2199,11 @@ class _LLMCallable:
         self._engine = LLMEngine(**engine_kwargs)
         if warm:
             # compile every jitted shape (each prefill context width,
-            # each decode width) HERE, inside the replica constructor:
-            # the deploy health gate (serve_replica_health_timeout_s)
-            # covers it, so the first real request never pays ~seconds
-            # of XLA compile while reconcile health probes run against
-            # their 5s timeout
+            # the narrow prefill pass, each decode width) HERE, inside
+            # the replica constructor: the deploy health gate
+            # (serve_replica_health_timeout_s) covers it, so the first
+            # real request never pays ~seconds of XLA compile while
+            # reconcile health probes run against their 5s timeout
             self._engine.warm_up()
 
     def __call__(self, request):

@@ -11,7 +11,9 @@ page, mask or slot shows as a wrong token), page 8, chunk 16, window 32.
 import dataclasses
 
 import jax.numpy as jnp
+import narrow_prefill_cases
 import numpy as np
+import pytest
 
 from benchmarks import reference_laguna as ref
 from ray_tpu.models.laguna import LagunaConfig
@@ -70,6 +72,30 @@ def test_prefill_in_chunks_then_decode_is_the_references_forward():
         assert st["moe_assignments_total"][which] >= calls[which]
         assert slots[which] == 4 * st["moe_layer_passes_total"][which]
     assert st["moe_layer_passes_total"]["decode"] == 4 * st["decode_steps"]
+
+
+class _NarrowKit:
+    """This family's kit for `narrow_prefill_cases`: a context of 384
+    gives the prefill pass the widths 64, 256 and 384; the window
+    group's arrays follow the pass's lanes."""
+
+    make = staticmethod(_engine)
+    prompt = staticmethod(_prompt)
+
+    @staticmethod
+    def make_one_width():
+        return LLMEngine(
+            dataclasses.replace(CFG, max_position_embeddings=64),
+            page_size=PAGE, max_batch=4, prefill_chunk=CHUNK, seed=3)
+
+    check = staticmethod(narrow_prefill_cases.teacher_forced_check(
+        ref, SIZES))
+
+
+@pytest.mark.parametrize("case", narrow_prefill_cases.CASES,
+                         ids=lambda case: case.__name__)
+def test_narrow_prefill_pass(case):
+    case(_NarrowKit)
 
 
 def test_window_arrays_take_the_form_of_their_pass():

@@ -5,6 +5,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import narrow_prefill_cases
 import numpy as np
 import pytest
 
@@ -228,3 +229,33 @@ def test_latent_pools_ship_copy_and_share(params):
     _drain(decoder)
     assert list(shipped.generated) == want
     assert decoder.stats()["prefill_steps"] == 0
+
+
+class _NarrowKit:
+    """This family's kit for `narrow_prefill_cases`: chunk 16 under a
+    context of 384 gives the prefill pass the widths 64, 256 and 384;
+    the lane-by-lane latent attention follows the pass's lanes."""
+
+    @staticmethod
+    def make(max_len=384, **kw):
+        return LLMEngine(
+            dataclasses.replace(CFG, max_position_embeddings=max_len),
+            seed=5, page_size=PAGE, max_batch=4, prefill_chunk=16, **kw)
+
+    @staticmethod
+    def make_one_width():
+        return _NarrowKit.make(max_len=64)
+
+    @staticmethod
+    def prompt(n, salt=0):
+        rs = np.random.RandomState(2000 + 7 * n + salt)
+        return [int(t) for t in rs.randint(1, 256, n)]
+
+    check = staticmethod(narrow_prefill_cases.teacher_forced_check(
+        ref, SIZES))
+
+
+@pytest.mark.parametrize("case", narrow_prefill_cases.CASES,
+                         ids=lambda case: case.__name__)
+def test_narrow_prefill_pass(case):
+    case(_NarrowKit)

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import narrow_prefill_cases
 import ray_tpu
 from ray_tpu import serve
 from ray_tpu.models.llama import LlamaConfig, LlamaModel
@@ -186,11 +187,13 @@ def test_engine_defaults():
 
 # ------------------------------------------- prefill context-width buckets
 # One geometry for every test below, so the process-wide jit cache holds
-# its seven programs once: chunk 16 under a context of 1024 gives the
-# prefill pass three widths (64, 256, 1024), page 8 the decode pass four.
+# its eight programs once: chunk 16 under a context of 1024 gives the
+# 4-lane prefill pass three widths (64, 256, 1024) and the 2-lane pass its
+# one (256), page 8 the decode pass four.
 
 WIDE_CHUNK, WIDE_CTX = 16, 1024
 WIDE_BUCKETS = [64, 256, 1024]
+NARROW = narrow_prefill_cases.NARROW   # 2 lanes x the second bucket
 
 
 def _wide_engine(**kw):
@@ -239,11 +242,13 @@ def test_prefill_width_buckets_keep_the_full_forwards_tokens(
         wide_eng, n_prompt, company):
     """A prompt ending one row under, on and over each bucket edge, and
     one deep in the last bucket: the pass that holds its last chunk
-    takes the bucket that covers it, also when a short prompt shares
-    that pass and reads the same wider context, and every token is the
-    no-cache forward's."""
+    takes the bucket that covers it (the narrow pass's one width where
+    that covers it: one or two prompts wait), also when a short prompt
+    shares that pass and reads the same wider context, and every token
+    is the no-cache forward's."""
     eng = wide_eng
     assert eng._prefill_ctx_buckets() == WIDE_BUCKETS
+    assert eng._narrow_prefill == NARROW
     salt = n_prompt + 2 * (company != "alone")
     long = eng.submit({"tokens": _wide_prompt(n_prompt, salt),
                        "max_new_tokens": 3})
@@ -259,7 +264,7 @@ def test_prefill_width_buckets_keep_the_full_forwards_tokens(
     eng.drain()  # its tokens are read a step late
     assert all(s.generated for s in seqs)
     after = eng.stats()["prefill_passes_by_width"]
-    width = next(w for w in WIDE_BUCKETS if w >= n_prompt)
+    width = NARROW[1] if n_prompt <= NARROW[1] else WIDE_CTX
     assert {w: after[w] - before[w] for w in WIDE_BUCKETS} == {
         w: int(w == width) for w in WIDE_BUCKETS}
     _drain(eng)
@@ -276,61 +281,106 @@ def test_every_prefill_width_is_compiled_before_the_second_pass(warmed_by):
     bucket, alone and together, compile nothing: no new executable
     behind the stepper and no backend compile in the process."""
     eng = _wide_engine()
-    widths, forward = [], eng._forward
+    shapes, forward = [], eng._forward
 
     def spy(tokens, slot_arr, ctx, *rest, **kw):
         if ctx is not None:
-            widths.append(ctx.shape[1])
+            shapes.append((tokens.shape[0], ctx.shape[1]))
         return forward(tokens, slot_arr, ctx, *rest, **kw)
 
     eng._forward = spy
+    lanes = eng.prefill_lanes
+    wide = [(lanes, w) for w in WIDE_BUCKETS]
     if warmed_by == "warm_up":
-        eng.warm_up()
+        eng.warm_up()   # its one-token prompt runs the narrow pass itself
+        assert shapes == wide + [NARROW]
     else:
-        eng.submit({"tokens": _wide_prompt(70), "max_new_tokens": 2})
-        eng.step()   # the first pass: the other widths, then its own
-        assert widths == [256, 1024, 64]
+        for n in (70, 5, 9):
+            eng.submit({"tokens": _wide_prompt(n, n), "max_new_tokens": 2})
+        eng.step()   # the first pass, wide: the other shapes, then its own
+        assert shapes == wide[1:] + [NARROW, wide[0]]
         _drain(eng)  # the first decode step warms decode's widths
-    assert sorted(set(widths)) == WIDE_BUCKETS
+    assert sorted(set(shapes)) == sorted(wide + [NARROW])
     assert eng.stats()["prefill_passes_by_width"][1024] == 0  # not counted
     steps = eng.device_report()["compiled_steps"]
     compiles = eng.stats()["compiles_total"]
-    assert steps >= len(WIDE_BUCKETS) + len(eng._paged_width_buckets())
+    assert steps >= len(WIDE_BUCKETS) + 1 + len(eng._paged_width_buckets())
     for lengths in ([20], [63, 64, 65], [255, 5], [256, 257, 300],
-                    [1000, 40, 7]):
+                    [1000, 40, 7], [300], [60, 61, 62]):
         seqs = [eng.submit({"tokens": _wide_prompt(n, salt=n),
                             "max_new_tokens": 2}) for n in lengths]
         _drain(eng, rounds=400)
         assert all(s.done and len(s.generated) == 2 for s in seqs)
-    by_width = eng.stats()["prefill_passes_by_width"]
+    st = eng.stats()
+    by_width = st["prefill_passes_by_width"]
     assert all(by_width[w] > 0 for w in WIDE_BUCKETS), by_width
+    assert 0 < st["prefill_narrow_passes_total"] < st["prefill_steps"]
     assert eng.device_report()["compiled_steps"] == steps
     assert eng.stats()["compiles_total"] == compiles
 
 
 def test_prefill_context_counters_say_what_was_gathered():
+    """`prefill_slots_total`, `prefill_ctx_cols_total` and
+    `prefill_narrow_passes_total` against a hand count: a pass adds its
+    OWN lanes x chunk and lanes x width, narrow or wide."""
     eng = _wide_engine()
-    lanes = eng.prefill_lanes
+    lanes, (n_lanes, n_width) = eng.prefill_lanes, NARROW
+    assert lanes == 4
     st0 = eng.stats()
     assert st0["prefill_passes_by_width"] == dict.fromkeys(WIDE_BUCKETS, 0)
-    # a 20-token prompt: two passes (16 + 4 tokens) reading 16 and 20
-    # rows, each gathering lanes x the FIRST bucket, not x ctx_len
+    assert st0["prefill_narrow_passes_total"] == 0
+    # a 20-token prompt alone: two NARROW passes (16 + 4 tokens) reading
+    # 16 and 20 rows, each gathering 2 lanes x the narrow pass's width
     eng.generate_batch([{"tokens": _wide_prompt(20), "max_new_tokens": 2}])
     st = eng.stats()
-    assert st["prefill_steps"] == 2
+    assert st["prefill_steps"] == st["prefill_narrow_passes_total"] == 2
+    assert st["prefill_tokens_total"] == 20
+    assert st["prefill_slots_total"] == 2 * n_lanes * WIDE_CHUNK
     assert st["prefill_ctx_rows_total"] == 16 + 20
-    assert st["prefill_ctx_cols_total"] == 2 * lanes * WIDE_BUCKETS[0]
-    assert st["prefill_passes_by_width"] == {64: 2, 256: 0, 1024: 0}
-    # two lanes of one pass: the rows of both, the columns of the wider
+    assert st["prefill_ctx_cols_total"] == 2 * n_lanes * n_width
+    assert st["prefill_passes_by_width"] == {64: 0, 256: 2, 1024: 0}
+    # three prompts at once: wide passes while all three wait (one: the
+    # short ones end in it), at the width the longest needs (64), then
+    # the long prompt alone in narrow ones: the rows of every live lane,
+    # the columns of the pass
     eng.generate_batch([{"tokens": _wide_prompt(70), "max_new_tokens": 2},
-                        {"tokens": _wide_prompt(9, 1), "max_new_tokens": 2}])
+                        {"tokens": _wide_prompt(9, 1), "max_new_tokens": 2},
+                        {"tokens": _wide_prompt(5, 2), "max_new_tokens": 2}])
     st = eng.stats()
     assert st["prefill_steps"] == 2 + 5
+    assert st["prefill_narrow_passes_total"] == 2 + 4
     assert sum(st["prefill_passes_by_width"].values()) == st["prefill_steps"]
-    assert st["prefill_passes_by_width"] == {64: 6, 256: 1, 1024: 0}
-    assert st["prefill_ctx_rows_total"] == 36 + 9 + 16 + 32 + 48 + 64 + 70
-    assert st["prefill_ctx_cols_total"] == lanes * (6 * 64 + 256)
+    assert st["prefill_passes_by_width"] == {64: 1, 256: 6, 1024: 0}
+    assert st["prefill_tokens_total"] == 20 + 70 + 9 + 5
+    assert st["prefill_slots_total"] == \
+        (6 * n_lanes + 1 * lanes) * WIDE_CHUNK
+    assert st["prefill_ctx_rows_total"] == \
+        36 + 9 + 5 + 16 + 32 + 48 + 64 + 70
+    assert st["prefill_ctx_cols_total"] == 6 * n_lanes * n_width + lanes * 64
     assert st["prefill_ctx_rows_total"] <= st["prefill_ctx_cols_total"]
+
+
+class _NarrowKit:
+    """This family's kit for `narrow_prefill_cases`: the wide geometry
+    above, its tokens held to the no-cache forward's argmax."""
+
+    make = staticmethod(_wide_engine)
+    prompt = staticmethod(_wide_prompt)
+
+    @staticmethod
+    def make_one_width():
+        return _engine(cfg=_cfg(max_seq_len=64), prefill_chunk=WIDE_CHUNK)
+
+    @staticmethod
+    def check(eng, prompts, outs):
+        for p, out in zip(prompts, outs):
+            _assert_greedy(eng, p, out)
+
+
+@pytest.mark.parametrize("case", narrow_prefill_cases.CASES,
+                         ids=lambda case: case.__name__)
+def test_narrow_prefill_pass(case):
+    case(_NarrowKit)
 
 
 def test_paged_grid_counters_are_the_hand_count():

@@ -9,8 +9,9 @@ compiler will not lower — at no chip time, for every later PR.
 The topology is described inside a fixture of THIS file only (one
 process at a time may load the TPU library; see the on-chip-measurement
 guide §2) and every compile runs in the test's own process.  Whole-step
-compiles (engine steps, train step, the 2x2 mesh) take a minute each and
-belong to a rehearsal script, not to tier-1.
+compiles of the decode and train steps and of the 2x2 mesh take a minute
+each and belong to a rehearsal script, not to tier-1; the one whole step
+here is the narrow prefill pass's (6 to 15 s a configuration).
 """
 
 import jax
@@ -246,3 +247,92 @@ def test_latent_decode_compiles_at_the_cells_shapes(one_chip, width):
     # a trace tells it from the key-and-value kernels, and the patterns
     # of their busy shares do not take it in
     assert "paged_attention_decode" not in text
+
+
+# ------------------------------------------ the narrow prefill pass's program
+# serve/llm.py, `_narrow_prefill_shape`: PREFILL_NARROW_LANES lanes of one
+# chunk over the SECOND prefill width, 1024 columns in all three serving
+# configurations, returning a token for each of the wide pass's 8 lanes.
+# A whole step of the engine at the cell's depth and pools, by shapes
+# alone (no weight is made; 6 to 15 s each): the bytes the compiler
+# plans, nothing of results or times.
+
+HBM_BYTES = int(15.75 * 2 ** 30)
+
+
+def _serving_config(name):
+    import json
+    import os
+
+    from benchmarks.kinds import serve_laguna, serve_pangu
+    from benchmarks.model_math import llama_kwargs
+
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                        "configs", name + ".json")
+    with open(path) as f:
+        cfg = json.load(f)
+    model_kwargs = {"laguna": serve_laguna.model_kwargs,
+                    "pangu_ultra_moe": serve_pangu.model_kwargs}.get(
+                        cfg["model_type"], llama_kwargs)
+    return model_kwargs(cfg), cfg["deployment"]["engine"]["max_batch"]
+
+
+@pytest.mark.parametrize("name", [
+    "mistral-7b-v0.3-serve", "laguna-s-2.1-serve",
+    "openpangu-ultra-moe-718b-serve"])
+def test_narrow_prefill_program_compiles_at_the_cells_shapes(
+        one_chip, monkeypatch, name):
+    import numpy as np
+
+    import ray_tpu.ops
+    from ray_tpu.models import cache as kv_cache, resolve
+    from ray_tpu.serve.llm import (PREFILL_CHUNK, PREFILL_LANES,
+                                   PREFILL_NARROW_LANES, _jitted_forward,
+                                   _pow4_widths)
+
+    # the kernels as the chip runs them, not the interpreter's programs
+    monkeypatch.setattr(ray_tpu.ops, "kernel_mode", lambda: "compiled")
+    model_kwargs, max_batch = _serving_config(name)
+    family, cfg = resolve(model_kwargs)
+    lanes, chunk = PREFILL_NARROW_LANES, PREFILL_CHUNK
+    width = _pow4_widths(4 * chunk, cfg.max_seq_len)[1]
+    assert (lanes, width) == (2, 1024)
+    model = family.build(cfg, PAGE)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def placed(tree):
+        return jax.tree.map(lambda x: spec(x.shape, x.dtype), tree)
+
+    params = placed(jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), np.zeros((1, 8), np.int32)
+    )["params"])
+    # the engine's pools: every position of a full kind, of a window kind
+    # the window and a chunk (`_WindowPages`); a window kind gathers that
+    # much context, in whole pages, where the pass's width is wider
+    kinds = kv_cache.kinds_of(cfg.cache_spec())
+    span = {kind: -(-(window + chunk) // PAGE) if window
+            else cfg.max_seq_len // PAGE for kind, window in kinds.items()}
+    pools = placed(jax.eval_shape(lambda: kv_cache.make_pools(
+        cfg.cache_spec(),
+        {kind: (1 + max_batch * (pages + 2 * bool(kinds[kind]))) * PAGE
+         for kind, pages in span.items()}, cfg.dtype)))
+    groups = {}
+    for kind, pages in span.items():
+        w = min(width, pages * PAGE)
+        groups[kind] = {"slots": spec((lanes, chunk), jnp.int32),
+                        "ctx": spec((lanes, w), jnp.int32),
+                        "ctx_pos": spec((lanes, w), jnp.int32),
+                        "ctx_mask": spec((lanes, w), jnp.bool_)}
+    lowered = _jitted_forward(0.0, 0).lower(
+        model, params, pools, spec((lanes, chunk), jnp.int32),
+        spec((lanes, chunk), jnp.int32), spec((PREFILL_LANES,), jnp.int32),
+        spec((2,), jnp.uint32), groups, None)
+    tok, _pools = lowered.out_info
+    assert tok.shape == (PREFILL_LANES + len(getattr(model, "counters", ())),)
+    mem = lowered.compile().memory_analysis()
+    # weights and pools as the cell holds them, and beside them what 2 x
+    # 64 slots need: 38 to 56 MiB where the 8-lane pass plans 108 to 267
+    assert 0 < mem.temp_size_in_bytes < 2 ** 27
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
