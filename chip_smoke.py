@@ -565,7 +565,7 @@ def four_chip_serve(sz: Sizes, seed: int, widths: dict) -> dict:
     first, second = serve_requests(sz, seed, widths["vocab_size"])
     prompts = first + second
     # what one replica answers, asked directly
-    before = [replica_call(r, "stats", "stats")["pages_allocated_total"]
+    before = [replica_call(r, "stats", "stats")["admitted_total"]
               for r in handle._replicas]
     reference = [get(handle._replicas[0].handle_request.remote(
         "generate", ({"tokens": p, "max_new_tokens": SERVE_MAX_NEW},), {}),
@@ -576,11 +576,11 @@ def four_chip_serve(sz: Sizes, seed: int, widths: dict) -> dict:
         streams.start(str(i), p)
     tokens = streams.join()
     routed = [tokens[str(i)] for i in range(3 * len(prompts))]
-    after = [replica_call(r, "stats", "stats")["pages_allocated_total"]
+    after = [replica_call(r, "stats", "stats")["admitted_total"]
              for r in handle._replicas]
     served = [a > b for a, b in zip(after[1:], before[1:])]
-    check(all(served), f"{name}: the router left replicas idle: pages "
-                       f"allocated {before} -> {after}")
+    check(all(served), f"{name}: the router left replicas idle: requests "
+                       f"admitted {before} -> {after}")
     wrong = [i for i, t in enumerate(routed) if t != reference[i % len(prompts)]]
     check(not wrong, f"{name}: routed requests {wrong} differ from replica "
                      f"0's tokens for the same prompt")
@@ -589,7 +589,7 @@ def four_chip_serve(sz: Sizes, seed: int, widths: dict) -> dict:
     wait_chips_free(4, f"the {name} replicas (pids {pids})")
     emit("serve4", replicas=4, chips=chips, ready_s=ready_s,
          bounds=[rep["chips_per_process_bounds"] for rep in reps],
-         requests_routed=len(routed), pages_allocated=after,
+         requests_routed=len(routed), requests_admitted=after,
          identical_to_one_replica=True, wall_s=time.monotonic() - t0)
     return reps[0]
 

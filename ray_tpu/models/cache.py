@@ -76,14 +76,6 @@ def make_pools(spec: Sequence[LayerCache], slots: Dict[str, int],
             for name in dict.fromkeys(n for r in rows for n in r)}
 
 
-def pool_bytes(pools: Dict[str, Any], kinds: Sequence[str],
-               kind: str) -> int:
-    """Bytes of every pool of the layers of `kind`."""
-    return sum(int(p.nbytes) for layer_pools in pools.values()
-               for p, k in zip(layer_pools, kinds)
-               if p is not None and k == kind)
-
-
 def gather_slots(pools: Dict[str, Any], kinds: Sequence[str],
                  slots: Dict[str, Any]) -> Dict[str, Any]:
     """The rows at `slots[kind]` of every layer's pools as host numpy

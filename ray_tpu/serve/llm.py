@@ -150,6 +150,35 @@ _PHASES = {"admit": "llm.admit",
            "decode_dispatch": "llm.decode.dispatch",
            "decode_sync": "llm.decode.sync",
            "decode_emit": "llm.decode.emit"}
+# the stepping thread's time OUTSIDE a step, on the same clock: from a
+# step's end to the next one's beginning, and the wait on the condition
+_OUTSIDE = {"between": "llm.between", "park": "llm.park"}
+_CLOCKED = {**_PHASES, **_OUTSIDE}
+_SYNCS = frozenset(("prefill_sync", "decode_sync"))
+_DISPATCHES = frozenset(("prefill_dispatch", "decode_dispatch"))
+_EMITS = frozenset(("prefill_emit", "decode_emit"))
+# the phases that are the host's: all but the waits, for the device (the
+# two `sync`) and for work (`park`)
+_WAITS = frozenset((*_SYNCS, "park"))
+# what an idle loop passes through: a step that finds nothing is all
+# `admit`
+_IDLING = frozenset(("admit", "between", "park"))
+_HOST_PHASES = tuple(k for k in _CLOCKED if k not in _WAITS)
+# the thread's CPU clock is read at a step's two ends and at an emit's
+# (a system call; under the chip machine's sandbox one of 6 us with a
+# 10 ms tick, so not at every boundary), which tells four stretches
+# apart: an emit, `between` (with the park, which burns next to none),
+# and `front`, the rest of a step (admit, builds, dispatches, and the
+# syncs, which wait)
+_CPU_GROUP = {k: k if k in _EMITS else "between" if k in _OUTSIDE
+              else "front" for k in _CLOCKED}
+# host work inside a phase, by name (`stats()["host_secs"]` -> the span):
+# the lane selection and the window pages' advance under `admit`'s lock,
+# the gauges (inside whichever phase is open), the window kinds' arrays
+# and the paged grid's count inside the builds
+_HOST_SPANS = {"plan": "llm.plan", "gauges": "llm.gauges",
+               "window_arrays": "llm.window_arrays",
+               "grid_count": "llm.grid_count"}
 
 # prefix-index chain seed: block k's key hashes (parent key || block
 # tokens), so one digest equality implies the WHOLE prefix matches
@@ -287,61 +316,221 @@ def _jitted_forward(temperature=0.0, top_k=0, top2=False):
     return fn
 
 
+class _HostSpan:
+    """`with clock.host[key]:` — one named piece of host work inside a
+    phase: its span on the profiler's clock and its seconds in
+    `host_secs[key]`.  Not a boundary: the phases keep theirs."""
+
+    __slots__ = ("_clock", "_key", "_span", "_t0")
+
+    def __init__(self, clock: "_StepClock", key: str):
+        self._clock, self._key = clock, key
+
+    def __enter__(self) -> None:
+        self._span = self._clock.span(_HOST_SPANS[self._key])
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc) -> None:
+        self._clock.host_secs[self._key] += time.perf_counter() - self._t0
+        self._span.__exit__(None, None, None)
+
+
 class _StepClock:
     """Where the stepping thread's time goes, from ONE clock read a
     boundary: `begin` opens a step and its first phase, `phase` closes
     the open phase and opens the next, `end` closes both, so a step's
-    phases add up to the step exactly (`phase_secs`, `step_secs`:
+    phases add up to the step exactly (`secs` by phase, `step_secs`:
     cumulative seconds).  Every call of `LLMEngine.step` counts, also
-    one that found nothing to do.
+    one that found nothing to do.  The clock runs on after `end`: the
+    thread is then `between` two steps, or in `park` (the loop's wait on
+    the condition), so from the first step on no instant is unnamed.
+    A stretch with nothing to do is cut into parks of 50 ms and the
+    steps between them that find nothing; `llm.idle` spans it whole,
+    from its first park to the first phase of a step that found work.
 
     The same boundaries open and close spans on the profiler's clock
     (`jax.profiler.TraceAnnotation`: `llm.step` with the step's number
     `n`, and the phase's span inside it), recorded while a profile is
     being taken and a flag test otherwise, and name the step and phase
-    to `ops.note_phase` for the record of a compile they set off."""
+    to `ops.note_phase` for the record of a compile they set off.
 
-    def __init__(self):
+    Three more readings at the same boundaries:
+
+    - work or waiting: the thread's CPU clock beside the wall clock at
+      a step's two ends and an emit's (`_CPU_GROUP`), so
+      `off_cpu_secs[group]` is what the thread held the group's host
+      phases open without running (the engine lock, the GIL, the
+      machine).  A stretch is on the CPU no longer than its host phases
+      lasted; what the CPU clock read beyond that (its tick can be
+      coarser than a stretch) is owed to the group's next stretches, so
+      a reading is right over many steps, not step by step;
+    - what the device waited for: `flight` is the engine's passes
+      dispatched and unread, and the device runs them in order, so the
+      newest one done means the device has nothing.  From the first
+      boundary that sees it, the host phases the thread passes through,
+      up to and including the next dispatch, go to `starved_secs[phase]`
+      — a LOWER bound: the device ran dry somewhere inside the phase
+      before the boundary that saw it.  Nothing inside a `sync`; nothing
+      where no dispatch follows (an engine with nothing to do is idle);
+    - the turnaround: from the last read-back of a step (`turnaround`,
+      at the boundary behind it) to the return of the next step's first
+      `_forward` (`dispatched`), `llm.turnaround` with `of`, the step
+      whose pass is on the device meanwhile — the host's critical path a
+      step, which has to fit inside one device pass."""
+
+    def __init__(self, flight=()):
         import jax
 
         from ray_tpu.ops import note_phase
 
         self.span = jax.profiler.TraceAnnotation
         self._note = note_phase
+        self._flight = flight
         self.step_secs = 0.0
-        self.phase_secs = dict.fromkeys(_PHASES, 0.0)
+        self.secs = dict.fromkeys(_CLOCKED, 0.0)
+        self.host_cpu_secs = 0.0
+        self.off_cpu_secs = dict.fromkeys(_CPU_GROUP.values(), 0.0)
+        self._cpu_owed = dict.fromkeys(self.off_cpu_secs, 0.0)
+        self._host_dt = 0.0   # the host phases' seconds since a CPU read
+        self.starved_secs = dict.fromkeys(_HOST_PHASES, 0.0)
+        self.starved_steps = 0
+        self.host_secs = dict.fromkeys(_HOST_SPANS, 0.0)
+        self.host = {key: _HostSpan(self, key) for key in _HOST_SPANS}
+        self.turnaround_secs = 0.0
+        self.turnarounds = 0
         self._n = 0
+        self._tid: Optional[int] = None
         self._key: Optional[str] = None
-        self._t0 = self._t_step = 0.0
+        self._t0 = self._c0 = self._t_step = self._t_turn = 0.0
+        self._decode = 0.0   # the open step's decode seconds
+        self._dry = False    # the device was seen with nothing to run
+        self._dry_since: list = []   # [(phase, seconds)] since then
         self._step_span = self._phase_span = None
+        self._turn_span = self._idle_span = None
 
-    def phase(self, key: Optional[str], **args) -> float:
+    def _close(self, cpu: bool) -> float:
+        """End the open phase: its seconds by every reading, the CPU
+        clock's where `cpu` says so or an emit ends."""
         now = time.perf_counter()
-        if self._key is not None:
-            self.phase_secs[self._key] += now - self._t0
-            self._phase_span.__exit__(None, None, None)
-        self._key, self._t0 = key, now
+        key = self._key
         if key is not None:
-            self._phase_span = self.span(_PHASES[key], **args)
-            self._phase_span.__enter__()
-        self._note(key, self._n)
+            dt = now - self._t0
+            self.secs[key] += dt
+            if key not in _WAITS:
+                self._host_dt += dt
+                if self._dry:
+                    self._dry_since.append((key, dt))
+                    if key in _DISPATCHES:   # the device has a pass again
+                        for k, secs in self._dry_since:
+                            self.starved_secs[k] += secs
+                        self.starved_steps += 1
+                        self._dry = False
+                        self._dry_since.clear()
+            if key.startswith("decode_"):
+                self._decode += dt
+            self._phase_span.__exit__(None, None, None)
+            if cpu or key in _EMITS:
+                c, group = time.thread_time(), _CPU_GROUP[key]
+                owed = self._cpu_owed[group] + c - self._c0
+                wall, self._host_dt, self._c0 = self._host_dt, 0.0, c
+                on_cpu = min(max(owed, 0.0), wall)
+                self._cpu_owed[group] = owed - on_cpu
+                self.host_cpu_secs += on_cpu
+                self.off_cpu_secs[group] += wall - on_cpu
+        self._t0 = now
+        return now
+
+    def _open(self, key: str, args: Dict[str, Any]) -> None:
+        if not self._flight:
+            self._dry = False    # nothing unread: idle, not starved
+            self._dry_since.clear()
+        elif not self._dry and key not in _SYNCS:
+            self._dry = self._flight[-1].out.is_ready()
+        if self._turn_span is not None and key in _WAITS:
+            # a wait before any dispatch: no pass came of this turnaround
+            self._turn_span.__exit__(None, None, None)
+            self._turn_span = None
+        if key == "park":
+            if self._idle_span is None:
+                self._idle_span = self.span("llm.idle")
+                self._idle_span.__enter__()
+        elif self._idle_span is not None and key not in _IDLING:
+            self._idle_span.__exit__(None, None, None)
+            self._idle_span = None
+        self._key = key
+        self._phase_span = self.span(_CLOCKED[key], **args)
+        self._phase_span.__enter__()
+        self._note(key if key in _PHASES else None, self._n)
+
+    def phase(self, key: str, **args) -> float:
+        now = self._close(key in _EMITS)
+        self._open(key, args)
         return now
 
     def begin(self, n: int) -> float:
-        self._n = n
+        tid = threading.get_ident()
+        if tid != self._tid:
+            # an engine stepped inline, by another thread than the last
+            # step's: `between` ran on neither's CPU clock
+            self._tid, self._c0 = tid, time.thread_time()
+        self._t_step = self._close(True)
+        self._n, self._decode = n, 0.0
         self._step_span = self.span("llm.step", n=n)
         self._step_span.__enter__()
-        self._t_step = self.phase("admit")
+        self._open("admit", {})
         return self._t_step
 
-    def end(self) -> None:
-        self.step_secs += self.phase(None) - self._t_step
+    def end(self) -> float:
+        """Close the step; returns its decode pass's seconds."""
+        self.step_secs += self._close(True) - self._t_step
         self._step_span.__exit__(None, None, None)
+        self._open("between", {})
+        return self._decode
+
+    def turnaround(self, of: int) -> None:
+        """The step's last read-back has returned (at the boundary just
+        crossed: its clock read), with step `of`'s pass on the device.
+        None is open: a sync, which ends one no dispatch ended, came
+        before."""
+        self._t_turn = self._t0
+        self._turn_span = self.span("llm.turnaround", of=of)
+        self._turn_span.__enter__()
+
+    def dispatched(self) -> None:
+        """A `_forward` returned: the first one behind a turnaround's
+        beginning ends it."""
+        if self._turn_span is not None:
+            self.turnaround_secs += time.perf_counter() - self._t_turn
+            self.turnarounds += 1
+            self._turn_span.__exit__(None, None, None)
+            self._turn_span = None
 
     def pass_secs(self, kind: str) -> float:
         """The seconds of one kind of pass: its four phases."""
-        return sum(self.phase_secs[f"{kind}_{part}"]
+        return sum(self.secs[f"{kind}_{part}"]
                    for part in ("build", "dispatch", "sync", "emit"))
+
+    def stats(self) -> Dict[str, Any]:
+        """The clock's part of `LLMEngine.stats()`: cumulative seconds
+        and counts.  `loop_secs` is everything since the first step;
+        `host_*` are over the host's phases (`_HOST_PHASES`) up to the
+        CPU clock's last reading."""
+        between, park = self.secs["between"], self.secs["park"]
+        cpu, off_cpu = self.host_cpu_secs, sum(self.off_cpu_secs.values())
+        return {"step_secs": self.step_secs,
+                "phase_secs": {k: self.secs[k] for k in _PHASES},
+                "between_secs": between, "park_secs": park,
+                "loop_secs": self.step_secs + between + park,
+                "host_secs": dict(self.host_secs),
+                "starved_secs": dict(self.starved_secs),
+                "starved_secs_total": sum(self.starved_secs.values()),
+                "starved_steps_total": self.starved_steps,
+                "turnaround_secs": self.turnaround_secs,
+                "turnarounds_total": self.turnarounds,
+                "host_wall_secs": cpu + off_cpu, "host_cpu_secs": cpu,
+                "host_off_cpu_secs": off_cpu,
+                "off_cpu_secs": dict(self.off_cpu_secs)}
 
 
 class _Flight:
@@ -690,7 +879,6 @@ class LLMEngine:
         self._prefix_hits = 0
         self._prefix_tokens_shared = 0
         self._cow_splits = 0
-        self._pages_alloc_total = 0
         self._kv_pages_shipped_out = 0
         self._kv_pages_shipped_in = 0
         self._queued: deque = deque()
@@ -713,13 +901,13 @@ class LLMEngine:
         self._decode_steps = 0
         self._prefill_steps = 0
         self._warm_secs = {"prefill": 0.0, "decode": 0.0}
-        self._clock = _StepClock()
         # what is dispatched and not read (the stepping thread's own: an
         # engine is stepped by its loop OR inline), and the last step's
         # outputs, the decode pass's and the prefill pass's, for the
         # next decode pass to take its tokens from: zeros where the step
         # had no such pass, the same program
         self._flight: deque = deque()
+        self._clock = _StepClock(self._flight)
         counters = len(getattr(self._model, "counters", ()))
         self._no_feed = (jnp.zeros((self.max_batch + counters,), jnp.int32),
                          jnp.zeros((self.prefill_lanes + counters,),
@@ -991,6 +1179,7 @@ class LLMEngine:
             last_idx, {"full": full, **(windows or {})},
             temperature=self.temperature, top_k=self.top_k, rng=rng,
             top2=self.logit_trace, feed=feed)
+        self._clock.dispatched()
         return tok, (top2[0] if top2 else None)
 
     def _split_counters(self, next_tok, lanes: int, phase: str):
@@ -1220,7 +1409,6 @@ class LLMEngine:
         del self._free_pages[:n]
         for p in pages:
             self._page_refs[p] = 1
-        self._pages_alloc_total += len(pages)
         return pages
 
     def _release_pages(self, pages: List[int]) -> None:
@@ -1646,14 +1834,11 @@ class LLMEngine:
 
         Returns False when there was nothing to dispatch and nothing to
         read (the loop then parks on the condition)."""
-        clock = self._clock
-        decode_before = clock.pass_secs("decode")
-        t_step = clock.begin(self._steps)
+        t_step = self._clock.begin(self._steps)
         try:
             return self._step(t_step)
         finally:
-            clock.end()
-            decode_dt = clock.pass_secs("decode") - decode_before
+            decode_dt = self._clock.end()
             m = self.metrics()
             if m is not None and decode_dt > 0.0:
                 m["decode_step"].observe(decode_dt)
@@ -1687,36 +1872,8 @@ class LLMEngine:
             self._sweep(now)
             self._admit_locked()
             imported = self._attach_imports_locked()
-            prefill_args = []
-            for seq in [s for s in self._active
-                        if s.state == _PREFILL][:self.prefill_lanes]:
-                lo = seq.pos
-                hi = min(lo + self.prefill_chunk, len(seq.prefill_tokens))
-                for kind, st in seq.windows.items():
-                    self._windows[kind].advance(st, lo, hi)
-                prefill_args.append(
-                    (seq, lo, hi, seq.prefill_tokens[lo:hi],
-                     seq.slot_cache[lo:hi], seq.slot_cache[:hi]))
-            # the decoding sequences as this step found them (one whose
-            # prompt ends in this step's prefill pass decodes from the
-            # next on), but for those whose every token is dispatched
-            decode_args = []
-            for seq in self._active:
-                if seq.state != _DECODE \
-                        or len(seq.generated) + seq.ahead >= seq.max_new:
-                    continue
-                # the newest token: on the device while unread (`last`
-                # is then not looked at), else the host's
-                last = (seq.generated[-1] if seq.generated
-                        else seq.prefill_tokens[-1])
-                for kind, st in seq.windows.items():
-                    self._windows[kind].advance(st, seq.pos, seq.pos + 1)
-                # snapshot the block table under the lock: a concurrent
-                # CoW split may rewrite entries after we release it
-                decode_args.append(
-                    (seq, last, seq.feed if seq.ahead else -1,
-                     seq.slot_cache[seq.pos], list(seq.block_table),
-                     seq.pos + 1))
+            with self._clock.host["plan"]:
+                prefill_args, decode_args = self._plan_locked()
         step = self._steps
         # an unread step before this one: its tokens are on the device
         ahead = bool(self._flight)
@@ -1758,6 +1915,42 @@ class LLMEngine:
                 + 0.1 * min(dt, 5.0 * self._step_ewma)
         self._set_gauges(len(decode_args), step_tokens)
         return True
+
+    def _plan_locked(self):
+        """Lock held: what this step's passes will hold — a chunk of
+        each prefilling sequence a lane can take, a position of each
+        decoding one — with their window pages advanced."""
+        prefill_args = []
+        for seq in [s for s in self._active
+                    if s.state == _PREFILL][:self.prefill_lanes]:
+            lo = seq.pos
+            hi = min(lo + self.prefill_chunk, len(seq.prefill_tokens))
+            for kind, st in seq.windows.items():
+                self._windows[kind].advance(st, lo, hi)
+            prefill_args.append(
+                (seq, lo, hi, seq.prefill_tokens[lo:hi],
+                 seq.slot_cache[lo:hi], seq.slot_cache[:hi]))
+        # the decoding sequences as this step found them (one whose
+        # prompt ends in this step's prefill pass decodes from the next
+        # on), but for those whose every token is dispatched
+        decode_args = []
+        for seq in self._active:
+            if seq.state != _DECODE \
+                    or len(seq.generated) + seq.ahead >= seq.max_new:
+                continue
+            # the newest token: on the device while unread (`last` is
+            # then not looked at), else the host's
+            last = (seq.generated[-1] if seq.generated
+                    else seq.prefill_tokens[-1])
+            for kind, st in seq.windows.items():
+                self._windows[kind].advance(st, seq.pos, seq.pos + 1)
+            # snapshot the block table under the lock: a concurrent CoW
+            # split may rewrite entries after we release it
+            decode_args.append(
+                (seq, last, seq.feed if seq.ahead else -1,
+                 seq.slot_cache[seq.pos], list(seq.block_table),
+                 seq.pos + 1))
+        return prefill_args, decode_args
 
     def _dispatch_prefill(self, step: int, prefill_args) -> int:
         """Chunked prefill, batched across lanes: up to prefill_lanes
@@ -1801,10 +1994,12 @@ class LLMEngine:
             ctx_mask[lane, :hi] = True
             q_pos[lane, :hi - lo] = self._arange[lo:hi]
             last_idx[lane] = hi - lo - 1
-        windows = self._window_arrays(
-            [(lane, seq.windows, lo, hi) for lane, (seq, lo, hi, *_r)
-             in enumerate(prefill_args)], lanes, c, width) \
-            if self._windows else None
+        windows = None
+        if self._windows:
+            with self._clock.host["window_arrays"]:
+                windows = self._window_arrays(
+                    [(lane, seq.windows, lo, hi) for lane, (seq, lo, hi, *_r)
+                     in enumerate(prefill_args)], lanes, c, width)
         phase("prefill_dispatch", width=width, lanes=lanes)
         out, top2 = self._forward(
             tokens, slot_arr, ctx, ctx_pos, ctx_mask, q_pos, last_idx,
@@ -1881,15 +2076,18 @@ class LLMEngine:
             block_tables[lane, :used] = table[:used]
             context_lens[lane] = n
             q_pos[lane, 0] = n - 1
-        windows = self._window_arrays(
-            [(lane, seq.windows, n - 1, n) for lane, (seq, *_a, n)
-             in enumerate(decode_args)], b, 1, width, decode=True) \
-            if self._windows else None
-        self._count_paged_grid(block_tables, context_lens)
-        for arrays in (windows or {}).values():
-            self._count_paged_grid(
-                arrays["block_tables"],
-                arrays["context_lens"] - arrays["starts"])
+        windows = None
+        if self._windows:
+            with self._clock.host["window_arrays"]:
+                windows = self._window_arrays(
+                    [(lane, seq.windows, n - 1, n) for lane, (seq, *_a, n)
+                     in enumerate(decode_args)], b, 1, width, decode=True)
+        with self._clock.host["grid_count"]:
+            self._count_paged_grid(block_tables, context_lens)
+            for arrays in (windows or {}).values():
+                self._count_paged_grid(
+                    arrays["block_tables"],
+                    arrays["context_lens"] - arrays["starts"])
         phase("decode_dispatch")
         out, top2 = self._forward(
             tokens, slot_arr, None, None, None, q_pos, last_idx,
@@ -1936,15 +2134,24 @@ class LLMEngine:
         token was in flight gets none.  True when a pass was read."""
         np = self._np
         phase = self._clock.phase
-        read = False
-        while self._flight and (before is None
-                                or self._flight[0].step < before):
-            rec = self._flight.popleft()
+        flight, read = self._flight, False
+
+        def due() -> bool:
+            return bool(flight) and (before is None
+                                     or flight[0].step < before)
+
+        while due():
+            rec = flight.popleft()
             read = True
             phase(rec.kind + "_sync", of=rec.step)
             host = np.asarray(rec.out)  # device sync: the pass is done
             top2 = rec.top2 and tuple(np.asarray(x) for x in rec.top2)
             phase(rec.kind + "_emit")
+            if flight and not due():
+                # the last read of this step, a newer pass on the device:
+                # what the host does from here to its next dispatch has
+                # that pass's time to fit in
+                self._clock.turnaround(of=flight[-1].step)
             lanes = host.shape[0] - len(self._model_counters)
             toks = self._split_counters(host, lanes, rec.kind)
             with self._lock:
@@ -1983,9 +2190,11 @@ class LLMEngine:
         try:
             while not self._stopped.is_set():
                 if not self.step():
-                    with self._clock.span("llm.park"), self._cond:
+                    self._clock.phase("park")
+                    with self._cond:
                         if not self._queued and not self._active:
                             self._cond.wait(0.05)
+                    self._clock.phase("between")
             self.drain()  # stopped with a step in flight: its tokens
             return {"steps": self._steps}
         except BaseException as e:
@@ -2069,19 +2278,20 @@ class LLMEngine:
         m = self.metrics()
         if m is None:
             return
-        m["pages"].set(self.num_pages - 1 - len(self._free_pages),
-                       tags={"state": "used"})
-        m["pages"].set(len(self._free_pages), tags={"state": "free"})
-        m["pages"].set(self._shared_page_count(), tags={"state": "shared"})
-        m["batch"].set(batch)
-        m["queue"].set(len(self._queued))
-        m["tps"].set(step_tokens)
+        with self._clock.host["gauges"]:
+            m["pages"].set(self.num_pages - 1 - len(self._free_pages),
+                           tags={"state": "used"})
+            m["pages"].set(len(self._free_pages), tags={"state": "free"})
+            m["pages"].set(self._shared_page_count(),
+                           tags={"state": "shared"})
+            m["batch"].set(batch)
+            m["queue"].set(len(self._queued))
+            m["tps"].set(step_tokens)
 
     def stats(self) -> Dict[str, Any]:
         """Counters and gauges of this engine.  Every `*_total`,
         `*_secs` and `*_steps` key is cumulative and never falls: a
         reader takes the change between two calls."""
-        from ray_tpu.models import cache as kv_cache
         from ray_tpu.ops import compile_counts
 
         with self._lock:
@@ -2094,8 +2304,7 @@ class LLMEngine:
                     "prefill_steps": self._prefill_steps,
                     "prefill_secs": self._clock.pass_secs("prefill")
                     - self._warm_secs["prefill"],
-                    "step_secs": self._clock.step_secs,
-                    "phase_secs": dict(self._clock.phase_secs),
+                    **self._clock.stats(),
                     **self._totals,
                     **{name: dict(by_pass) for name, by_pass
                        in self._model_counters.items()},
@@ -2107,7 +2316,6 @@ class LLMEngine:
                     "active": len(self._active),
                     "cancelled": self._cancelled_total,
                     "deadline_expired": self._deadline_expired_total,
-                    "live_seqs": len(self._by_rid),
                     "free_pages": len(self._free_pages),
                     "used_pages": self.num_pages - 1 - len(self._free_pages),
                     "kv_pages_in_use": {
@@ -2122,10 +2330,6 @@ class LLMEngine:
                     "prefix_hits": self._prefix_hits,
                     "prefix_tokens_shared": self._prefix_tokens_shared,
                     "cow_splits": self._cow_splits,
-                    "pages_allocated_total": self._pages_alloc_total,
-                    # of one page of the full kind, over its layers
-                    "kv_page_bytes": kv_cache.pool_bytes(
-                        self._pools, self._kinds, "full") // self.num_pages,
                     "kv_pages_shipped_out": self._kv_pages_shipped_out,
                     "kv_pages_shipped_in": self._kv_pages_shipped_in,
                     **({"latent_pool_bytes": sum(

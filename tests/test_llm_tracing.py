@@ -20,7 +20,9 @@ import pytest
 from ray_tpu import ops
 from ray_tpu._private import tracing
 from ray_tpu.models.llama import LlamaConfig
-from ray_tpu.serve.llm import _PHASES, LLMEngine, _LLMCallable
+from ray_tpu.serve import llm
+from ray_tpu.serve.llm import (_CPU_GROUP, _HOST_PHASES, _HOST_SPANS, _PHASES,
+                               LLMEngine, _LLMCallable, _StepClock)
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))   # benchmarks/, for trace_reduce
@@ -155,6 +157,339 @@ def test_request_and_work_counters_equal_what_was_sent():
     d = _delta(eng.stats(), before)
     assert d["submitted_total"] == n + 1 and d["finished_total"] == n
     assert d["cancelled"] == 1
+
+
+def _clock_identities(d):
+    """What holds between the clock's readings in any change `d` of
+    `stats()`, to rounding: every instant is in one phase, the host's
+    phases are the nine less the two waits plus `between`, a starved or
+    off-CPU second is a second of the phase (of the stretch between two
+    readings of the CPU clock) it is booked under."""
+    p = d["phase_secs"]
+    assert d["loop_secs"] == pytest.approx(
+        sum(p.values()) + d["between_secs"] + d["park_secs"], rel=1e-9)
+    assert d["loop_secs"] == pytest.approx(
+        d["step_secs"] + d["between_secs"] + d["park_secs"], rel=1e-9)
+    host = {**{k: p[k] for k in _HOST_PHASES if k in p},
+            "between": d["between_secs"]}
+    assert set(host) == set(_HOST_PHASES) == set(d["starved_secs"])
+    stretch = dict.fromkeys(d["off_cpu_secs"], 0.0)
+    for k, secs in host.items():
+        stretch[_CPU_GROUP[k]] += secs
+    assert set(stretch) == {"front", "prefill_emit", "decode_emit",
+                            "between"}
+    # (as far as the CPU clock was read: not the open step's last
+    # stretch where another thread asks mid-step)
+    assert d["host_wall_secs"] == pytest.approx(sum(host.values()))
+    assert sum(d["starved_secs"].values()) \
+        == pytest.approx(d["starved_secs_total"])
+    assert 0.0 <= d["starved_secs_total"] <= d["loop_secs"]
+    assert sum(d["off_cpu_secs"].values()) \
+        == pytest.approx(d["host_off_cpu_secs"])
+    assert d["host_cpu_secs"] + d["host_off_cpu_secs"] \
+        == pytest.approx(d["host_wall_secs"])
+    assert 0.0 <= d["host_cpu_secs"] <= d["host_wall_secs"]
+    for k, secs in host.items():
+        assert -1e-12 <= d["starved_secs"][k] <= secs + 1e-12, k
+    for k, secs in stretch.items():
+        assert -1e-12 <= d["off_cpu_secs"][k] <= secs + 1e-12, k
+    assert set(d["host_secs"]) == set(_HOST_SPANS)
+
+
+def test_no_instant_of_the_stepping_thread_is_unnamed_stepped_inline():
+    t0 = time.perf_counter()
+    eng = _engine()
+    before = eng.stats()
+    eng.generate_batch([_request(i) for i in range(5)])
+    eng.generate_batch([_request(i) for i in range(2)])
+    d = _delta(eng.stats(), before)
+    wall = time.perf_counter() - t0
+    _clock_identities(d)
+    # nobody parks an engine stepped inline; the clock runs from the
+    # engine's first step (its warm-up's) on, the callers' own code and
+    # whatever lies between two calls `between`
+    assert d["park_secs"] == 0.0 and d["between_secs"] > 0.0
+    assert d["step_secs"] < d["loop_secs"] <= wall
+    # the suspects inside the phases, by name: a part of its phase
+    assert 0.0 < d["host_secs"]["plan"] <= d["phase_secs"]["admit"]
+    assert 0.0 < d["host_secs"]["grid_count"] \
+        <= d["phase_secs"]["decode_build"]
+    assert d["host_secs"]["gauges"] > 0.0
+    assert d["host_secs"]["window_arrays"] == 0.0   # one kind of layer
+
+
+def test_no_instant_is_unnamed_under_a_pinned_loop():
+    eng = _engine()
+    before = eng.stats()
+    loop = threading.Thread(target=eng.run_loop, daemon=True)
+    loop.start()
+    try:
+        for _burst in range(2):
+            seqs = [eng.submit(_request(i, max_new=6)) for i in range(3)]
+            deadline = time.monotonic() + 120
+            while any(not s.done for s in seqs):
+                assert time.monotonic() < deadline
+                time.sleep(0.005)
+            time.sleep(0.06)   # longer than one park
+    finally:
+        eng.stop()
+        loop.join(30)
+    assert not loop.is_alive()
+    d = _delta(eng.stats(), before)
+    _clock_identities(d)
+    assert d["park_secs"] > 0.05 and d["between_secs"] > 0.0
+    # parked, the engine has nothing in flight: idle, not starved
+    assert d["starved_secs_total"] <= d["loop_secs"] - d["park_secs"]
+
+
+class _Ticks:
+    """`time` for the clock alone: every reading of the wall clock is
+    one second after the last; the thread's CPU clock stands where the
+    test puts it, and its readings are counted."""
+
+    def __init__(self):
+        self.now = self.cpu = 0.0
+        self.cpu_reads = 0
+
+    def perf_counter(self):
+        self.now += 1.0
+        return self.now
+
+    def thread_time(self):
+        self.cpu_reads += 1
+        return self.cpu
+
+
+class _Pass:
+    """A stand-in for a pass in flight: `ready` is what `is_ready()`
+    answers, and every question is recorded."""
+
+    def __init__(self, step, ready=False):
+        self.step, self.out, self.ready, self.asked = step, self, ready, 0
+
+    def is_ready(self):
+        self.asked += 1
+        return self.ready
+
+
+def test_the_starvation_clock_counts_from_the_boundary_that_saw_it(
+        monkeypatch):
+    ticks = _Ticks()
+    monkeypatch.setattr(llm, "time", ticks)
+    flight = []
+    clock = _StepClock(flight)
+
+    def starved():
+        got = {k: v for k, v in clock.starved_secs.items() if v}
+        assert sum(got.values()) == clock.stats()["starved_secs_total"]
+        return got, clock.starved_steps
+
+    # an empty flight: nothing is asked, nothing counted
+    clock.begin(0)
+    clock.phase("decode_build")
+    clock.phase("decode_dispatch")
+    flight.append(_Pass(0))
+    clock.end()                        # asked: a pass is in flight
+    assert starved() == ({}, 0) and flight[-1].asked == 1
+    # step 1: the pass in flight is done at the step's THIRD boundary
+    # (the one that opens the dispatch): the dispatch's second, nothing
+    # before
+    clock.begin(1)                     # boundary 1: not ready
+    clock.phase("decode_build")        # boundary 2: not ready
+    flight[-1].ready = True
+    clock.phase("decode_dispatch")     # boundary 3: ready
+    assert flight[-1].asked == 4
+    flight.append(_Pass(1))
+    clock.phase("decode_sync", of=0)   # the dispatch ends: counted
+    assert starved() == ({"decode_dispatch": 1.0}, 1)
+    # a boundary that opens a sync asks nothing (the wait tells)
+    assert flight[-1].asked == 0
+    flight.pop(0)
+    flight[-1].ready = True            # done while the host waited
+    clock.phase("decode_emit")         # seen here: emit, between, ...
+    clock.end()
+    clock.begin(2)
+    clock.phase("prefill_build")
+    clock.phase("prefill_dispatch")
+    asked = flight[-1].asked
+    assert starved() == ({"decode_dispatch": 1.0}, 1)   # not yet landed
+    flight.append(_Pass(2))
+    # ... up to and including the next dispatch, and no further: the
+    # prefill pass is the newest now, and it is running
+    clock.phase("decode_build")
+    clock.phase("decode_dispatch")
+    assert flight[-2].asked == asked   # seen dry: not asked again
+    flight.append(_Pass(2))
+    assert starved() == ({"decode_dispatch": 1.0, "decode_emit": 1.0,
+                          "between": 1.0, "admit": 1.0,
+                          "prefill_build": 1.0, "prefill_dispatch": 1.0}, 2)
+    # nothing inside a sync: the newest pass is done before the waits
+    # for the older ones, whose seconds are the device's, not the host's
+    flight.pop(0)
+    flight[-1].ready = True
+    clock.phase("prefill_sync", of=1)
+    flight.pop(0)
+    clock.phase("prefill_emit")        # seen here
+    clock.phase("decode_sync", of=2)   # a wait: not counted
+    clock.phase("decode_emit")
+    clock.end()
+    clock.begin(3)
+    clock.phase("decode_build")
+    clock.phase("decode_dispatch")
+    flight.append(_Pass(3))
+    clock.end()
+    got, steps = starved()
+    assert steps == 3 and "decode_sync" not in clock.starved_secs
+    assert got == {"decode_dispatch": 2.0, "decode_emit": 2.0,
+                   "between": 2.0, "admit": 2.0, "prefill_build": 1.0,
+                   "prefill_dispatch": 1.0, "prefill_emit": 1.0,
+                   "decode_build": 1.0}
+    # seen dry, but no dispatch follows (nothing left to do: the step
+    # reads what is in flight and the engine idles): not starved
+    for rec in flight:
+        rec.ready = True
+    clock.begin(4)                     # seen here
+    clock.phase("decode_sync", of=2)
+    del flight[:]
+    clock.phase("decode_emit")
+    clock.end()
+    clock.phase("park")
+    clock.phase("between")
+    clock.begin(5)                     # a request came: nothing in flight
+    clock.phase("decode_build")
+    clock.phase("decode_dispatch")
+    clock.end()
+    assert starved() == (got, 3)
+    # one reading of the wall clock a boundary, each a second here: the
+    # phases and the time outside them add up to the loop's seconds
+    st = clock.stats()
+    assert st["loop_secs"] == sum(clock.secs.values()) == ticks.now - 1.0
+    assert st["park_secs"] == 1.0 and st["between_secs"] == 6.0
+    assert st["starved_secs_total"] == 12.0 <= st["loop_secs"]
+    # a CPU clock that never moved: every host second was off the CPU
+    assert st["host_cpu_secs"] == 0.0
+    assert st["host_off_cpu_secs"] == st["host_wall_secs"] \
+        == st["loop_secs"] - 1.0 - sum(
+            v for k, v in clock.secs.items() if k.endswith("_sync"))
+
+
+def test_work_or_waiting_is_read_at_a_steps_ends_and_an_emits(monkeypatch):
+    """The thread's CPU clock costs a system call, so the clock reads it
+    at `begin`, at `end` and where an emit begins and ends: four
+    stretches (`_CPU_GROUP`), each host second of which was on the CPU
+    or off it."""
+    ticks = _Ticks()
+    monkeypatch.setattr(llm, "time", ticks)
+    clock = _StepClock([])
+
+    def burn(secs):   # the thread runs on the CPU for `secs`
+        ticks.cpu += secs
+
+    clock.begin(0)                      # admit
+    reads = ticks.cpu_reads
+    burn(0.75)
+    clock.phase("prefill_build")
+    clock.phase("prefill_dispatch")
+    burn(0.5)
+    clock.phase("decode_build")
+    clock.phase("decode_dispatch")
+    burn(0.25)
+    clock.phase("prefill_sync", of=0)   # the wait burns nothing
+    clock.phase("prefill_emit")         # read: `front` so far 1.5 of 5
+    burn(1.0)
+    clock.phase("decode_sync", of=0)    # read: the emit ran throughout
+    clock.phase("decode_emit")          # read: nothing more of `front`
+    burn(0.25)
+    clock.end()                         # read
+    burn(3.5)                           # a coarse tick: more than the
+    clock.phase("park")                 # two seconds `between` lasted
+    clock.phase("between")
+    clock.begin(1)                      # read
+    assert ticks.cpu_reads - reads == 5
+    clock.phase("decode_build")
+    clock.phase("decode_dispatch")
+    clock.phase("decode_sync", of=1)
+    clock.phase("decode_emit")          # read
+    clock.end()                         # read: 3 a step without a prefill
+    assert ticks.cpu_reads - reads == 7
+    st = clock.stats()
+    assert st["off_cpu_secs"] == {"front": 8.0 - 1.5, "prefill_emit": 0.0,
+                                  "decode_emit": 2.0 - 0.25, "between": 0.0}
+    assert st["host_wall_secs"] == 8.0 + 1.0 + 2.0 + 2.0
+    assert st["host_off_cpu_secs"] == 6.5 + 1.75
+    assert st["host_cpu_secs"] == st["host_wall_secs"] - 8.25
+    # what the tick read beyond `between`'s two seconds is owed to its
+    # next stretch: on the CPU for 1.5 of the next one
+    clock.phase("park")
+    clock.phase("between")
+    clock.begin(2)
+    clock.end()
+    assert clock.stats()["off_cpu_secs"]["between"] == 0.5
+    # another thread steps (an engine driven inline): its CPU clock is
+    # its own, and what it read before is not `between`'s
+    ticks.cpu = 1e6
+    monkeypatch.setattr(llm.threading, "get_ident", lambda: -1)
+    clock.begin(3)
+    clock.end()
+    assert clock.stats()["off_cpu_secs"]["between"] == 0.5 + 1.0
+
+
+def test_one_turnaround_a_dispatching_step_behind_a_read_back():
+    """`llm.turnaround` opens behind the last read-back of a step that
+    leaves a pass on the device (`of`: the step that dispatched it, the
+    one open) and ends inside the next step, at its first `_forward`."""
+    eng = _engine()
+    spans, real = [], eng._clock.span
+
+    class Spy:
+        def __init__(self, name, **args):
+            self.row, self.inner = [name, args, None], real(name, **args)
+
+        def __enter__(self):
+            self.row[2] = step[0], "open"
+            spans.append(tuple(self.row))
+            return self.inner.__enter__()
+
+        def __exit__(self, *exc):
+            spans.append((self.row[0], self.row[1], (step[0], "close")))
+            return self.inner.__exit__(*exc)
+
+    step = [None]
+    eng._clock.span = Spy
+    real_begin = eng._clock.begin
+
+    def begin(n):
+        step[0] = n
+        return real_begin(n)
+
+    eng._clock.begin = begin
+    before = eng.stats()
+    _drain(eng, [eng.submit(_request(i, max_new=6)) for i in range(3)])
+    eng.drain()
+    d = _delta(eng.stats(), before)
+    opened = [(args["of"], at) for name, args, (at, what) in spans
+              if name == "llm.turnaround" and what == "open"]
+    closed = [(args["of"], at) for name, args, (at, what) in spans
+              if name == "llm.turnaround" and what == "close"]
+    dispatching = sorted({at for name, _a, (at, what) in spans
+                          if name.endswith(".dispatch") and what == "open"})
+    # the step in flight is the one that is open when its predecessor
+    # has been read; the turnaround ends one step later
+    assert opened and all(of == at for of, at in opened)
+    assert all(at == of + 1 for of, at in closed)
+    # a dispatching step ends one where the step before it dispatched
+    # and read (a burst's first step reads nothing, so its second ends
+    # none); a burst's last read-back opens one that no dispatch ends:
+    # it is dropped, not counted
+    reading = {at for name, _a, (at, what) in spans
+               if name.endswith(".sync") and what == "open"}
+    assert len(closed) == len(opened)
+    ended = [of + 1 for of, _at in opened if of + 1 in dispatching]
+    assert ended == [n for n in dispatching
+                     if n - 1 in dispatching and n - 1 in reading]
+    assert d["turnarounds_total"] == len(ended) > 3
+    assert len(opened) > len(ended)
+    assert 0.0 < d["turnaround_secs"] < d["loop_secs"]
 
 
 def _spans_of(fn):
@@ -298,6 +633,9 @@ def test_a_profile_holds_the_step_its_phases_and_the_park(tmp_path):
             # park (50 ms), so that one span covers the device's gap
             time.sleep(0.03)
             serve(3)
+            # a lull of several parks: no one of them covers the gap
+            time.sleep(0.17)
+            serve(2)
         finally:
             replica.profile_stop()
     finally:
@@ -306,13 +644,32 @@ def test_a_profile_holds_the_step_its_phases_and_the_park(tmp_path):
     assert not loop.is_alive()
     rows = trace_reduce.load(trace_reduce.find_xplane(str(tmp_path)))
     names = {name for _s, _e, name in rows["host"]}
-    assert {"llm.step", "llm.park"} | set(_PHASES.values()) <= names
+    assert {"llm.step", "llm.park", "llm.idle", "llm.between",
+            "llm.turnaround", "llm.plan", "llm.gauges", "llm.grid_count"} \
+        | set(_PHASES.values()) <= names
     # a phase lies inside a step: one clock, one thread
     steps = sorted((s, e) for s, e, n in rows["host"] if n == "llm.step")
     for s, e, n in rows["host"]:
         if n in _PHASES.values():
             assert any(s0 - 1e-6 <= s and e <= e0 + 1e-6
                        for s0, e0 in steps), n
+    # the time outside a step lies outside every step, the host's named
+    # work inside one
+    for s, e, n in rows["host"]:
+        if n in ("llm.between", "llm.park"):
+            assert all(e <= s0 + 1e-6 or e0 <= s + 1e-6
+                       for s0, e0 in steps), n
+        elif n in _HOST_SPANS.values():
+            assert any(s0 - 1e-6 <= s and e <= e0 + 1e-6
+                       for s0, e0 in steps), n
+    # a turnaround is NOT nested in a step: it covers the end of the
+    # step that read back and the start of the next, and the profiler
+    # keeps its interval as it was
+    turns = [(s, e) for s, e, n in rows["host"] if n == "llm.turnaround"]
+    across = [(s, e) for s, e in turns
+              if any(s < e0 < e for _s0, e0 in steps)
+              and any(s < s0 < e for s0, _e0 in steps)]
+    assert len(across) >= len(turns) - 2 > 0, (len(across), len(turns))
     reduced = trace_reduce.reduce_events(**rows, unattributed=unattributed)
     gaps = dict(reduced["idle_gaps"])
     # the step in flight when the profile stops has no `llm.step` span in
@@ -320,6 +677,15 @@ def test_a_profile_holds_the_step_its_phases_and_the_park(tmp_path):
     # worth at most, once in some 25 runs
     assert gaps.get(unattributed, 0.0) <= max(e - s for s, e in steps), gaps
     assert gaps["llm.park"] >= 0.02, gaps
+    # the lull is cut into parks of 50 ms and the steps between them
+    # that found nothing; `llm.idle` holds them all, and the gap is its
+    parks = sorted((s, e) for s, e, n in rows["host"] if n == "llm.park")
+    idles = [(s, e) for s, e, n in rows["host"] if n == "llm.idle"]
+    lull = max(idles, key=lambda iv: iv[1] - iv[0])
+    inside = [(s, e) for s, e in parks if lull[0] <= s and e <= lull[1]]
+    assert len(inside) >= 3 and lull[1] - lull[0] >= 0.15
+    assert any(lull[0] < s0 and e0 < lull[1] for s0, e0 in steps)
+    assert gaps["llm.idle"] >= 0.1, gaps
 
 
 def test_flax_names_the_model_parts_and_pallas_the_kernels():

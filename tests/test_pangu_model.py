@@ -65,8 +65,7 @@ def test_model_type_picks_the_family_and_the_row_is_one_latent_vector():
                                 jnp.bfloat16)
     assert list(pools) == ["latent"]
     assert [p.shape for p in pools["latent"]] == [(64, 640)] * 2
-    assert kv_cache.pool_bytes(pools, ["full"] * 2, "full") == \
-        2 * 64 * 640 * 2
+    assert sum(p.nbytes for p in pools["latent"]) == 2 * 64 * 640 * 2
     # a key-and-value layer's are what they were
     kv = kv_cache.LayerCache("full", 0, 2, 16)
     assert kv.rows() == {"k": (2, 16), "v": (2, 16)} and kv.latent == 0
