@@ -15,7 +15,13 @@ A model FAMILY is a module of this package that defines
                            forward reads it in (no master weights), and
                            the engine holds its tree in those dtypes
 
-and whose config states its cache by layer (models/cache.py).  The plain
+and whose config states its cache by layer (models/cache.py): rows a
+position in pages (`full`, `window`), or — kind `state` — ONE
+fixed-size row a sequence, a recurrent layer's carry, for which the
+engine keeps a slot a sequence and hands a pass `groups["state"]` (the
+lanes' slots, valid lengths, and whether the chunk starts its
+sequence).  A row's part states its dtype where it is not the model's
+(a state's float32 carry in a bfloat16 model).  The plain
 reference of a family lives with the benchmark (`benchmarks/reference*`)
 and reads nothing but the parameter tree.
 
@@ -53,7 +59,8 @@ __all__ = ["LlamaConfig", "LlamaModel", "llama_param_rules", "resolve",
 
 # `model_type` -> the module of this package that implements it
 FAMILIES = {"llama": "llama", "mistral": "llama", "laguna": "laguna",
-            "mellum": "laguna", "pangu_ultra_moe": "pangu"}
+            "mellum": "laguna", "pangu_ultra_moe": "pangu",
+            "granitemoehybrid": "granite"}
 # keys that a `model_type`'s published config class defaults, so that a
 # dictionary of that type may leave them out (a family's `from_dict`
 # takes an absent key for an absent mechanism).  `benchmarks/kinds/

@@ -19,22 +19,36 @@ dimension 2 must be aligned to tiling (128), but is 576" is what a
 page's copy out of a 576-wide pool gets), so the pool's shape states
 what the memory holds and a page of it is one copy.
 
+A third kind, `state` (`StateCache`), keeps no row a position at all:
+the layer carries a recurrent state, ONE row a SEQUENCE whatever its
+length, so the kind's pool has a slot a sequence (slot 0 the garbage
+slot, as everywhere) and the engine hands a pass one slot a lane, not
+one a token.  Its row has two parts, `"conv"` (the last inputs of the
+layer's causal convolution) and `"ssm"` (the state matrix of every
+head).  A row's part states its DTYPE where that is not the model's
+(`dtypes()`): the `ssm` part is float32 in a bfloat16 model, because the
+pool IS the carry of the recurrence — read, multiplied and written back
+every token — and a carry rounded to 8 bits every step forgets what a
+float32 one keeps.
+
 The pools themselves are paging-agnostic flat slot arrays, by the name
-of the row's part (`"k"`, `"v"`; `"latent"`) a list over the layers:
-`[slots of the layer's kind, *the part's shape]`, None at a layer whose
-row has no such part.  Slot 0 of every pool is the garbage slot that
-padding writes to.  Everything below goes by what a layer holds.
+of the row's part (`"k"`, `"v"`; `"latent"`; `"conv"`, `"ssm"`) a list
+over the layers: `[slots of the layer's kind, *the part's shape]`, None
+at a layer whose row has no such part.  Slot 0 of every pool is the
+garbage slot that padding writes to.  Everything below goes by what a
+layer holds.
 """
 
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, NamedTuple, Sequence, Tuple
 
 import jax.numpy as jnp
 
 
 class LayerCache(NamedTuple):
-    kind: str        # "full" | "window"
+    kind: str        # "full" | "window" (a "state" layer: StateCache)
     window: int      # positions a window layer sees (0 for full)
     kv_heads: int
     head_dim: int
@@ -47,11 +61,40 @@ class LayerCache(NamedTuple):
         part = (self.kv_heads, self.head_dim)
         return {"k": part, "v": part}
 
+    def dtypes(self) -> Dict[str, Any]:
+        """The parts that are not in the model's dtype: none."""
+        return {}
+
+
+class StateCache(NamedTuple):
+    """A layer that keeps one fixed-size state a sequence (`kind` is
+    always "state"): `conv` = (the convolution's taps - 1, its
+    channels), `ssm` = (heads, head_dim, state size)."""
+    kind: str
+    window: int      # 0: the kind has no positions
+    conv: Tuple[int, int]
+    ssm: Tuple[int, int, int]
+
+    def rows(self) -> Dict[str, Tuple[int, ...]]:
+        return {"conv": tuple(self.conv), "ssm": tuple(self.ssm)}
+
+    def dtypes(self) -> Dict[str, Any]:
+        """The recurrence's carry is float32 whatever the model's."""
+        return {"ssm": jnp.float32}
+
 
 def latent_row_width(latent: int, lanes: int = 128) -> int:
     """What a latent pool's row is allocated at: the row's `latent`
     numbers, then zeros up to the next multiple of the chip's lanes."""
     return -(-latent // lanes) * lanes
+
+
+def state_row_bytes(spec: Sequence[Any], dtype: Any) -> int:
+    """The bytes ONE sequence's state takes over all the state layers."""
+    return sum(math.prod(shape) * jnp.dtype(
+        layer.dtypes().get(name, dtype)).itemsize
+        for layer in spec if layer.kind == "state"
+        for name, shape in layer.rows().items())
 
 
 def kinds_of(spec: Sequence[LayerCache]) -> Dict[str, int]:
@@ -68,9 +111,10 @@ def kinds_of(spec: Sequence[LayerCache]) -> Dict[str, int]:
 def make_pools(spec: Sequence[LayerCache], slots: Dict[str, int],
                dtype: Any) -> Dict[str, Any]:
     """Zeroed pools: layer i holds `slots[spec[i].kind]` rows of each
-    part of its row."""
+    part of its row, in the part's own dtype where it states one."""
     rows = [layer.rows() for layer in spec]
-    return {name: [jnp.zeros((slots[layer.kind], *r[name]), dtype)
+    return {name: [jnp.zeros((slots[layer.kind], *r[name]),
+                             layer.dtypes().get(name, dtype))
                    if name in r else None
                    for layer, r in zip(spec, rows)]
             for name in dict.fromkeys(n for r in rows for n in r)}

@@ -169,8 +169,18 @@ def make_mesh_attention(mesh) -> Callable:
     return attention
 
 
+def _scaled(logits: jax.Array, d: int, scale: Optional[float]):
+    """The scores by the softmax scale: 1 / sqrt(d) where a model states
+    none (the division every program before `scale` was compiled with),
+    else the model's own factor."""
+    if scale is None:
+        return logits / jnp.sqrt(d).astype(jnp.float32)
+    return logits * jnp.float32(scale)
+
+
 def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                    causal: bool = True, window: int = 0) -> jax.Array:
+                    causal: bool = True, window: int = 0,
+                    scale: Optional[float] = None) -> jax.Array:
     """The dense softmax-attention math itself, [S, S] scores and all:
     what :func:`default_attention` runs below the flash threshold, and
     what the flash kernels are tested against."""
@@ -179,7 +189,7 @@ def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     group = h // hkv
     q = q.reshape(b, s, hkv, group, d)
     logits = jnp.einsum("bshgd,bthd->bhgst", q, k).astype(jnp.float32)
-    logits = logits / jnp.sqrt(d).astype(jnp.float32)
+    logits = _scaled(logits, d, scale)
     if causal:
         mask = jnp.tril(jnp.ones((s, s), dtype=bool))
         if window:
@@ -193,7 +203,8 @@ def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 def cached_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                      ctx: jax.Array, ctx_pos: jax.Array,
                      ctx_mask: jax.Array, q_pos: jax.Array,
-                     window: Optional[int] = None) -> jax.Array:
+                     window: Optional[int] = None,
+                     scale: Optional[float] = None) -> jax.Array:
     """Attention over a slot-pool KV cache.
 
     q: [B,S,H,D] (post-rope); pool_k/pool_v: [T,Hkv,D] flat slot pools
@@ -203,7 +214,8 @@ def cached_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     validity; q_pos: [B,S] query positions.  Causality = position mask,
     so one kernel serves chunked prefill (S>1) and decode (S=1).  With
     ``window``, a query sees the last ``window`` positions up to its
-    own only."""
+    own only.  ``scale``: the factor on the scores where it is not
+    1 / sqrt(D)."""
     b, s, h, d = q.shape
     hkv = pool_k.shape[1]
     group = h // hkv
@@ -211,7 +223,7 @@ def cached_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     cv = pool_v[ctx.reshape(-1)].reshape(b, ctx.shape[1], hkv, d)
     q5 = q.reshape(b, s, hkv, group, d)
     logits = jnp.einsum("bshgd,blhd->bhgsl", q5, ck).astype(jnp.float32)
-    logits = logits / jnp.sqrt(d).astype(jnp.float32)
+    logits = _scaled(logits, d, scale)
     mask = (ctx_pos[:, None, :] <= q_pos[:, :, None]) \
         & ctx_mask[:, None, :]                      # [B,S,L]
     if window is not None:

@@ -183,7 +183,8 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                     *, page_size: int,
                     interpret: Optional[bool] = None,
                     window: Optional[int] = None,
-                    starts: Optional[jax.Array] = None) -> jax.Array:
+                    starts: Optional[jax.Array] = None,
+                    scale: Optional[float] = None) -> jax.Array:
     """Single-token decode attention over paged KV pools.
 
     q: [B, 1, H, D] post-rope queries (the current token's k/v must
@@ -201,12 +202,18 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     With ``window``, ``block_tables[b]`` lists the pages from position
     ``starts[b]`` (a multiple of the page size) on, and only the last
     ``window`` positions before ``context_lens[b]`` attend.
+
+    ``scale`` is the factor on the scores; None is 1 / sqrt(D).  The
+    chip's compiler copies whole 128-lane tiles only, so a model whose
+    heads are 64 wide stores two of them side by side in one row and
+    asks with queries that are zero on the other head's half
+    (models/granite.py): D is then 128 here and the scale the model's.
     """
     from ray_tpu.ops import interpret_default
 
     return _paged_call(q, pool_k, pool_v, block_tables, context_lens,
                        starts, page_size=page_size, window=window,
-                       interpret=interpret_default(interpret))
+                       scale=scale, interpret=interpret_default(interpret))
 
 
 # A jit of its own: a model's layers call with the same shapes, and so
@@ -214,9 +221,11 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
 # tenth of a second each, 48 times in the warm-up of a 12-layer engine's
 # four table widths: PERF.md, PR 33).
 @functools.partial(jax.jit,
-                   static_argnames=("page_size", "window", "interpret"))
+                   static_argnames=("page_size", "window", "scale",
+                                    "interpret"))
 def _paged_call(q, pool_k, pool_v, block_tables, context_lens, starts, *,
-                page_size: int, window: Optional[int], interpret: bool):
+                page_size: int, window: Optional[int],
+                scale: Optional[float] = None, interpret: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -227,7 +236,8 @@ def _paged_call(q, pool_k, pool_v, block_tables, context_lens, starts, *,
     num_pages = num_slots // page_size
     g = h // hkv
     w = block_tables.shape[1]
-    scale = 1.0 / (d ** 0.5)
+    if scale is None:
+        scale = 1.0 / (d ** 0.5)
 
     qr = q.reshape(b, hkv, g, d)                     # GQA head grouping
     kp = pool_k.reshape(num_pages, page_size, hkv, d)

@@ -76,6 +76,23 @@ a second block table a sequence.  Prefix sharing is REFUSED for a model
 with window layers (a shared prefix would need the window layers' pages
 before its end, which the sequence that wrote them has given back);
 ``stats()["prefix_sharing"]`` says so.
+
+A ``state`` layer (a recurrent mixer) keeps no row a position: its cache
+is ONE fixed-size row a sequence.  The engine keeps a slot a sequence
+for the kind (``_Seq.state_slot``: taken at admission, given back when
+the sequence ends; slot 0 is the garbage slot, and ``max_batch`` more
+are enough, a sequence holding a lane-place of ``_active`` from the one
+to the other) and hands every pass ``groups["state"]``: each lane's
+slot, the valid tokens it has in the pass, and ``fresh`` where the
+lane's chunk starts its sequence — such a chunk reads no state, so what
+a slot's last owner left in it is never seen.  That also makes the
+run-ahead's wasted lane-step harmless: it updates a slot its sequence
+has given back, and whoever holds the slot next starts with a fresh
+chunk, dispatched later and so run later.  The slot follows the
+sequence, not the lane: lanes are dealt anew every step.  Page shipping
+carries the state row with the pages.  Prefix sharing is REFUSED for
+such a model too: the pages of a prefix are worth nothing without the
+state at its end, which nobody kept.
 """
 
 from __future__ import annotations
@@ -554,7 +571,8 @@ class _Seq:
                  "cancelled", "slot_cache", "cond", "deadline", "kv_import",
                  "prefill_export", "export_payload", "trace_ctx",
                  "prefix_tokens", "submit_step", "admit_step",
-                 "first_token_step", "windows", "ahead", "feed")
+                 "first_token_step", "windows", "ahead", "feed",
+                 "state_slot")
 
     def __init__(self, request_id: str, prompt: List[int], max_new: int,
                  eos: Optional[int], preknown: Optional[List[int]] = None):
@@ -575,6 +593,8 @@ class _Seq:
         self.block_table: List[int] = []   # the "full" group's pages
         # cache kind -> _SeqWindow, for each window kind of the model
         self.windows: Dict[str, "_SeqWindow"] = {}
+        # the sequence's slot in the state kind's pools (0: none)
+        self.state_slot = 0
         # tokens whose KV a DISPATCHED pass has written or will write:
         # the device runs passes in the order they were dispatched, so
         # to every later pass they are in the cache
@@ -787,11 +807,18 @@ class LLMEngine:
         self._kinds = [layer.kind for layer in spec]
         # layers whose row is one latent vector (models/cache.py): what
         # their passes read is counted apart, `latent_*` in stats()
-        self._latent_layers = sum(bool(layer.latent) for layer in spec)
+        self._latent_layers = sum("latent" in layer.rows() for layer in spec)
         windows = kv_cache.kinds_of(spec)
         if windows.pop("full", None) is None:
             raise ValueError("a model with no full-attention layer: the "
                              "engine's page budget is the full kind's")
+        # layers that keep one state a sequence: a slot a sequence, no
+        # pages (a kind that is neither `full` nor `state` is a window)
+        windows.pop("state", None)
+        self._state_layers = self._kinds.count("state")
+        self._free_state: List[int] = list(range(self.max_batch, 0, -1)) \
+            if self._state_layers else []
+        self._state_row_bytes = kv_cache.state_row_bytes(spec, cfg.dtype)
         self._windows = {
             kind: _WindowPages(np, kind, window, self.page_size,
                                self.prefill_chunk, self.max_batch,
@@ -823,6 +850,7 @@ class LLMEngine:
         t1 = time.perf_counter()
         self._pools = kv_cache.make_pools(
             spec, {"full": self.num_pages * self.page_size,
+                   "state": 1 + self.max_batch,
                    **{kind: g.num_pages * self.page_size
                       for kind, g in self._windows.items()}}, cfg.dtype)
         self.startup_secs = {"params": 0.0,
@@ -871,6 +899,11 @@ class LLMEngine:
             self._sharing_refused = (
                 "the model has window layers, whose pages before a "
                 "prefix's end are given back")
+        elif self.prefix_sharing and self._state_layers:
+            self.prefix_sharing = False
+            self._sharing_refused = (
+                "the model has state layers, and no state is kept at a "
+                "prefix's end")
         self._page_refs = [0] * self.num_pages
         self._prefix_index: Dict[bytes, int] = {}
         self._children: Dict[bytes, set] = {}
@@ -951,6 +984,13 @@ class LLMEngine:
             self._totals.update(latent_decode_rows_total=0,
                                 latent_prefill_rows_total=0,
                                 latent_decode_calls_total=0)
+        if self._state_layers:
+            # state rows the decode passes' kernel calls update (a live
+            # lane, a state layer) and the prefill passes' chunks (a
+            # lane with tokens, a state layer), and those kernel calls
+            self._totals.update(state_decode_rows_total=0,
+                                state_prefill_rows_total=0,
+                                state_decode_calls_total=0)
         self._prefill_widths = self._prefill_ctx_buckets()
         self._prefill_passes_by_width = dict.fromkeys(
             self._prefill_widths, 0)
@@ -1153,12 +1193,14 @@ class LLMEngine:
 
     def _forward(self, tokens, slot_arr, ctx, ctx_pos, ctx_mask, q_pos,
                  last_idx, block_tables=None, context_lens=None,
-                 windows=None, feed=None):
+                 windows=None, feed=None, state=None):
         """Dispatch one jitted forward with this engine's static sampling
         knobs; the per-call rng split only happens on the sampling path,
         so greedy engines run the exact pre-sampling program.  The
         positional arrays are the full kind's; `windows` has the other
-        kinds' (`_window_arrays`); `feed` is a decode pass's
+        kinds' (`_window_arrays`); `state` the state kind's
+        (`_state_arrays`: given by every pass of a model with state
+        layers, by no other); `feed` is a decode pass's
         (`_jit_forward`).  The pools become the pass's; returns what
         stays on the device until it is read back: (the tokens, with the
         model's counter vector behind them if it counts; under
@@ -1176,7 +1218,8 @@ class LLMEngine:
             full.update(ctx=ctx, ctx_pos=ctx_pos, ctx_mask=ctx_mask)
         tok, self._pools, *top2 = self._step_fn(
             self._model, self._params, self._pools, tokens, q_pos,
-            last_idx, {"full": full, **(windows or {})},
+            last_idx, {"full": full, **(windows or {}),
+                       **({"state": state} if state is not None else {})},
             temperature=self.temperature, top_k=self.top_k, rng=rng,
             top2=self.logit_trace, feed=feed)
         self._clock.dispatched()
@@ -1230,6 +1273,22 @@ class LLMEngine:
             out[kind] = {"slots": slots, "ctx": ctx, "ctx_pos": ctx_pos,
                          "ctx_mask": ctx_mask}
         return out
+
+    def _state_arrays(self, rows, lanes: int):
+        """The state kind's arrays of one pass of `lanes` lanes, or None
+        for a model without state layers.  `rows` = [(lane, the
+        sequence's slot, its valid tokens in the pass, whether they
+        start the sequence)]; a lane without a row is garbage: slot 0,
+        no token."""
+        if not self._state_layers:
+            return None
+        np = self._np
+        slots = np.zeros((lanes,), np.int32)
+        lens = np.zeros((lanes,), np.int32)
+        fresh = np.zeros((lanes,), bool)
+        for lane, slot, n, first in rows:
+            slots[lane], lens[lane], fresh[lane] = slot, n, first
+        return {"slots": slots, "lens": lens, "fresh": fresh}
 
     def _trace_top2(self, seq: _Seq, lane: int, top2) -> None:
         """Lock held, just before `_emit_token`: the two largest logits
@@ -1309,7 +1368,8 @@ class LLMEngine:
             self._forward(
                 zeros, zeros, ctx, ctx, np.zeros((lanes, width), bool),
                 zeros, np.zeros((self.prefill_lanes,), np.int32),
-                windows=self._window_arrays([], lanes, c, width))
+                windows=self._window_arrays([], lanes, c, width),
+                state=self._state_arrays([], lanes))
 
     def _warm_paged_buckets(self) -> None:
         """Compile every paged block-table width bucket up front, at
@@ -1338,6 +1398,7 @@ class LLMEngine:
                  "context_lens": np.zeros((b,), np.int32),
                  "windows": self._window_arrays([], b, 1, width,
                                                 decode=True),
+                 "state": self._state_arrays([], b),
                  "feed": (np.full((b,), -1, np.int32), self._no_feed)})
 
     def _lower_decode(self, width: int):
@@ -1354,7 +1415,9 @@ class LLMEngine:
             {"full": {"slots": slots,
                       "block_tables": kwargs["block_tables"],
                       "context_lens": kwargs["context_lens"]},
-             **kwargs["windows"]}, kwargs["feed"])
+             **kwargs["windows"],
+             **({"state": kwargs["state"]} if kwargs["state"] is not None
+                else {})}, kwargs["feed"])
 
     def device_report(self) -> Dict[str, Any]:
         """`ops.device_report()` plus what this engine put on the device
@@ -1392,6 +1455,7 @@ class LLMEngine:
                    page_size=self.page_size,
                    param_bytes=nbytes(self._params),
                    kv_pool_bytes=nbytes(self._pools),
+                   state_pool_bytes=self._state_pool_bytes(),
                    # executables behind the jitted stepper, all engines
                    # of this process: constant once warm-up is done
                    compiled_steps=sum(fn._cache_size()
@@ -1403,6 +1467,11 @@ class LLMEngine:
         text = self._lower_decode(self._paged_width_buckets()[0]).as_text()
         rep["decode_has_tpu_custom_call"] = "tpu_custom_call" in text
         return rep
+
+    def _state_pool_bytes(self) -> int:
+        """What the state kind's pools take: a row a slot, the garbage
+        slot among them (part of `kv_pool_bytes`)."""
+        return (1 + self.max_batch) * self._state_row_bytes
 
     def _alloc_pages(self, n: int) -> List[int]:
         pages = self._free_pages[:n]
@@ -1453,6 +1522,12 @@ class LLMEngine:
         seq.block_table = []
         for kind, st in seq.windows.items():
             self._windows[kind].release(st)
+        if seq.state_slot:
+            # passes in flight may still update the slot (the run-ahead's
+            # lane-step of a sequence that has ended): they run before
+            # any pass of its next owner, whose first chunk is `fresh`
+            self._free_state.append(seq.state_slot)
+            seq.state_slot = 0
         seq.kv_import = None
         if seq in self._active:
             self._active.remove(seq)
@@ -1645,6 +1720,9 @@ class LLMEngine:
                                         len(bt))).astype(np.int32)
             seq.windows = {kind: g.new_seq(pages)
                            for kind, g in self._windows.items()}
+            if self._state_layers:
+                # never empty: a slot a place in `_active`
+                seq.state_slot = self._free_state.pop()
             shared_tok = len(shared) * self.page_size
             if cow is not None:
                 src_page, n_tok = cow
@@ -1720,6 +1798,9 @@ class LLMEngine:
                 group.advance(seq.windows[kind], n, n)
             start = max(0, n - group.window + 1)
             slots[kind] = seq.windows[kind].slots[start:n]
+        if self._state_layers:
+            # the one row that stands for all `n` tokens
+            slots["state"] = [seq.state_slot]
         return slots
 
     def _attach_imports_locked(self) -> bool:
@@ -2000,10 +2081,14 @@ class LLMEngine:
                 windows = self._window_arrays(
                     [(lane, seq.windows, lo, hi) for lane, (seq, lo, hi, *_r)
                      in enumerate(prefill_args)], lanes, c, width)
+        state = self._state_arrays(
+            [(lane, seq.state_slot, hi - lo, lo == 0)
+             for lane, (seq, lo, hi, *_r) in enumerate(prefill_args)],
+            lanes)
         phase("prefill_dispatch", width=width, lanes=lanes)
         out, top2 = self._forward(
             tokens, slot_arr, ctx, ctx_pos, ctx_mask, q_pos, last_idx,
-            windows=windows)
+            windows=windows, state=state)
         self._feed[1] = out
         self._prefill_steps += 1
         chunk_tokens = sum(hi - lo for _s, lo, hi, *_r in prefill_args)
@@ -2016,6 +2101,9 @@ class LLMEngine:
         if self._latent_layers:
             self._totals["latent_prefill_rows_total"] += \
                 self._latent_layers * sum(ctx_rows)
+        if self._state_layers:
+            self._totals["state_prefill_rows_total"] += \
+                self._state_layers * len(prefill_args)
         self._prefill_passes_by_width[width] += 1
         owed = []
         with self._lock:
@@ -2088,11 +2176,14 @@ class LLMEngine:
                 self._count_paged_grid(
                     arrays["block_tables"],
                     arrays["context_lens"] - arrays["starts"])
+        state = self._state_arrays(
+            [(lane, seq.state_slot, 1, False)
+             for lane, (seq, *_a) in enumerate(decode_args)], b)
         phase("decode_dispatch")
         out, top2 = self._forward(
             tokens, slot_arr, None, None, None, q_pos, last_idx,
             block_tables=block_tables, context_lens=context_lens,
-            windows=windows, feed=(src, tuple(feed)))
+            windows=windows, feed=(src, tuple(feed)), state=state)
         self._feed[0] = out
         self._decode_steps += 1
         self._totals["decode_lane_steps_total"] += len(decode_args)
@@ -2100,6 +2191,10 @@ class LLMEngine:
             self._totals["latent_decode_rows_total"] += \
                 self._latent_layers * int(context_lens.sum())
             self._totals["latent_decode_calls_total"] += self._latent_layers
+        if self._state_layers:
+            self._totals["state_decode_rows_total"] += \
+                self._state_layers * len(decode_args)
+            self._totals["state_decode_calls_total"] += self._state_layers
         owed = []
         with self._lock:
             for lane, (seq, *_rest) in enumerate(decode_args):
@@ -2338,6 +2433,10 @@ class LLMEngine:
                         "latent_pages_in_use":
                             self.num_pages - 1 - len(self._free_pages)}
                        if self._latent_layers else {}),
+                    **({"state_slots_in_use":
+                            self.max_batch - len(self._free_state),
+                        "state_pool_bytes": self._state_pool_bytes()}
+                       if self._state_layers else {}),
                     "loop_running": self._loop_running,
                     "last_batch": self._last_batch}
 

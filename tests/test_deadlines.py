@@ -22,6 +22,10 @@ from ray_tpu._private import deadlines
 def cluster():
     ray_tpu.init(num_cpus=2, object_store_memory=64 * 1024 * 1024)
     try:
+        # both workers up before any test's clock starts: under the whole
+        # run's load a worker's start alone can outlast a 0.5 s budget,
+        # and the task then expires queued, not running
+        ray_tpu.get([_sleep.remote(0.2) for _ in range(2)], timeout=60)
         yield ray_tpu
     finally:
         ray_tpu.shutdown()

@@ -249,6 +249,59 @@ def test_latent_decode_compiles_at_the_cells_shapes(one_chip, width):
     assert "paged_attention_decode" not in text
 
 
+# benchmarks/configs/granite-4.0-h-micro-serve.json: 64 lanes; a state
+# pool of 65 slots of 64 heads x 64 x 128 float32 a state layer, updated
+# in place by slot; 32 query heads of 64 over 8 KV heads, which the cache
+# keeps in 4 pairs of 128 lanes (a 64-wide page copy is refused: "Slice
+# shape along dimension 3 must be aligned to tiling (128), but is 64"),
+# at the model's own scale of 1/64, every table width to 4096 tokens
+
+
+def test_state_update_compiles_at_the_cells_shapes(one_chip):
+    from ray_tpu.ops import ssm
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lanes, heads, p, n = 64, 64, 64, 128
+    compiled = jax.jit(
+        lambda pool, slots, x, dt, a, b, c, d: ssm.ssm_state_update(
+            pool, slots, x, dt, a, b, c, d, interpret=False),
+        donate_argnums=(0,)
+    ).lower(spec((1 + lanes, heads, p, n), jnp.float32),
+            spec((lanes,), jnp.int32), spec((lanes, heads, p), jnp.bfloat16),
+            spec((lanes, heads), jnp.float32), spec((heads,), jnp.float32),
+            spec((lanes, n), jnp.bfloat16), spec((lanes, n), jnp.bfloat16),
+            spec((heads,), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ssm_state_update" in text
+    # in place: the pool that comes out IS the one that went in, and the
+    # program holds no second copy of its 136 MB
+    memory = compiled.memory_analysis()
+    pool_bytes = (1 + lanes) * heads * p * n * 4
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < pool_bytes // 8
+
+
+@pytest.mark.parametrize("width", [4, 16, 64, 256])
+def test_paged_decode_compiles_at_granite_paired_shapes(one_chip, width):
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lanes, heads, pairs, row = 64, 32, 4, 128
+    slots = (1 + lanes * (4096 // PAGE)) * PAGE
+    compiled = jax.jit(
+        lambda q, k, v, bt, cl: paged_attention(
+            q, k, v, bt, cl, page_size=PAGE, scale=1 / 64, interpret=False)
+    ).lower(spec((lanes, 1, heads, row), jnp.bfloat16),
+            spec((slots, pairs, row), jnp.bfloat16),
+            spec((slots, pairs, row), jnp.bfloat16),
+            spec((lanes, width), jnp.int32),
+            spec((lanes,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_attention_decode" in text
+
+
 # ------------------------------------------ the narrow prefill pass's program
 # serve/llm.py, `_narrow_prefill_shape`: PREFILL_NARROW_LANES lanes of one
 # chunk over the SECOND prefill width, 1024 columns in all three serving
