@@ -262,7 +262,8 @@ class LatentAttention(nn.Module):
         else:
             out = la.latent_chunk_attention(
                 q_row, pool, cache["ctx"], cache["ctx_pos"],
-                cache["ctx_mask"], positions, value_width=r, scale=scale)
+                cache["ctx_mask"], positions, page_size=self.page_size,
+                value_width=r, scale=scale)
         with jax.named_scope("latent_unabsorb"):
             out = jnp.einsum("bshr,rhv->bshv", out, wkv_b[..., dn:])
         return wo(out), pool

@@ -16,27 +16,53 @@ key head — 2 H (W + value_width) operations for W numbers read, at the
 chip's ridge for 128 heads, where grouped-query attention (a few query
 heads a key head) is bound by memory alone.
 
-Two passes:
+Two kernels over the lane's pages.  Both leave the pool `[T, W]` in HBM
+and walk a lane's block table a block of pages at a time: a block's
+pages are copied into one half of a double buffer while the other half
+is computed on (`_page_copies`; a page past the lane's last is its last
+again, masked by position), a block past the lane's last page is not
+computed, an empty lane reads nothing and writes zeros.  Both fetch a
+page ONCE as key and as value (the value is a slice of the key's
+buffer), leave operands in the pool's dtype and keep scores, running
+maximum, denominator and accumulator in float32 in VMEM — at 128 heads
+the products are as much of a call as the copies, and a float32 product
+takes the bfloat16 unit several passes.  Each is a `jax.jit` of its
+own: traced and lowered once a program, not once a layer.
 
-`latent_paged_attention`  one query a lane (decode) over the lane's
-    pages, a Pallas kernel named `latent_attention_decode`.  It shares
-    the paged decode kernel's walk (ops/paged_attention.py: the grid of
-    lanes x blocks of `pages_per_step` pages, the pool left in HBM, a
-    block's pages copied into one half of a double buffer while the
-    other is computed on, the table clamp) and differs where the row
-    form does: ONE pool and one copy a page, the value a slice of the
-    key's buffer, and operands left in the pool's dtype with float32
-    accumulation — at 128 heads a lane the products are as much of the
-    call as the copies, and a float32 product takes the bfloat16 unit
-    several passes.
+`latent_paged_attention`  one query a lane (decode), the kernel
+    `latent_attention_decode`.  Its grid is the paged decode kernel's
+    (ops/paged_attention.py): lanes x blocks of `pages_per_step` pages,
+    all 128 heads of the lane's one query in a step.
 
-`latent_chunk_attention`  a chunk of queries a lane (chunked prefill)
-    over a gathered context, in blocks of the context with a running
-    maximum and denominator: the `[lanes, heads, chunk, context]` score
-    array of `llama.cached_attention` (2.1 GB at 8 lanes x 128 heads x
-    64 x 8192 in float32) is never held.  Lane by lane, each over the
-    blocks its OWN context fills: a pass costs what its lanes read, not
-    what its width bucket — or its longest lane — could hold.
+`latent_chunk_attention`  a chunk of queries a lane (chunked prefill),
+    the kernel `latent_attention_prefill`.  It differs where a chunk
+    does:
+    - QUERY TILES.  A lane's 64 queries x 128 heads are 8,192 query
+      rows of 640 numbers, 10.5 MB: not one VMEM block.  The grid is
+      lanes x tiles of whole heads (`_prefill_tiles`: 32 heads x 64
+      queries = 2,048 rows), head-major so that a tile's rows share one
+      `[queries, keys]` mask.  A lane's rows are read again once a tile
+      — 1,280 B a row against 4.7 MFLOP of products a row and tile: the
+      kernel is the MXU's.
+    - THE WALK IS A LOOP INSIDE A GRID STEP, over the lane's own blocks
+      of 512 rows: a grid axis over the table's width would pay a grid
+      step (0.2 us, measured) for every block a lane does not have,
+      1,024 of them at the widest table.  The copies of a block's pages
+      are issued unrolled, so that their scalar work packs beside the
+      products.
+    - THE CAUSAL EDGE runs inside the chunk: a query sees the positions
+      up to its own (`q_pos`) below the lane's length; a padded query
+      (`q_pos` 0) sees position 0 and stays finite.
+    - THE TABLE IS READ FROM `ctx`.  The engine's prefill pass hands a
+      layer the slot of every context position 0..n-1 in order
+      (`serve/llm.py`, `_dispatch_prefill`: `seq.slot_cache[:hi]`, zeros
+      behind).  A page holds `page_size` consecutive positions, so every
+      `page_size`-th column names a page — the sequence's block table as
+      far as its context reaches, shared prefix pages included — and the
+      mask's count is the length: two integer operations, no gather of
+      rows, no score array `[lanes, heads, chunk, context]` in HBM (2.1
+      GB at 8 x 128 x 64 x 8192 in float32), and a pass costs what its
+      lanes hold, not what its width bucket could.
 """
 
 from __future__ import annotations
@@ -50,8 +76,56 @@ import jax.numpy as jnp
 from ray_tpu.ops.paged_attention import pages_per_step
 
 _NEG_INF = -1e30
-# context rows a block of `latent_chunk_attention` covers
-CHUNK_CTX_BLOCK = 512
+
+
+def _page_copies(bt_ref, pool_hbm, buf, sem, lane, used, pages: int,
+                 unroll: bool = False):
+    """(fetch, wait) over blocks of `pages` pages of `lane`'s table, of
+    which it uses `used`: `fetch(block, half)` starts the copies of a
+    block's pages into buffer half `half` (a page past the lane's last
+    is its last again), `wait(half)` waits for them.  Unrolled, the
+    copies' scalar work is straight-line code that the compiler packs
+    beside the vector work around it."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    def fetch(block, half):
+        def page(j, carry):
+            at = bt_ref[lane, jnp.minimum(block * pages + j, used - 1)]
+            pltpu.make_async_copy(pool_hbm.at[at], buf.at[half, j],
+                                  sem.at[half]).start()
+            return carry
+        jax.lax.fori_loop(0, pages, page, 0, unroll=unroll)
+
+    def wait(half):
+        def page(j, carry):
+            pltpu.make_async_copy(pool_hbm.at[0], buf.at[half, j],
+                                  sem.at[half]).wait()
+            return carry
+        jax.lax.fori_loop(0, pages, page, 0, unroll=unroll)
+
+    return fetch, wait
+
+
+def _softmax_block(s, masked, rows, acc_ref, m_ref, l_ref,
+                   value_width: int):
+    """One block of the running softmax: scores `s` [R, keys] (float32)
+    of R query rows against the block's `rows` [keys, W], `masked(x,
+    fill)` putting `fill` where a row does not see a key; the running
+    maximum and denominator [R, 128] and the accumulator [R,
+    value_width] move on.  The value is the row's head: the same fetched
+    bytes."""
+    s = masked(s, _NEG_INF)
+    m_prev = m_ref[:, :1]                                # [R, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = masked(jnp.exp(s - m_new), 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_ref[:, :1] = l_ref[:, :1] * corr + jnp.sum(p, axis=-1,
+                                                 keepdims=True)
+    m_ref[:, :1] = m_new
+    acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
+        p.astype(rows.dtype), rows[:, :value_width],
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)              # [R, value_width]
 
 
 def _decode_kernel(bt_ref, cl_ref, q_ref, pool_hbm, o_ref, buf, sem,
@@ -62,7 +136,6 @@ def _decode_kernel(bt_ref, cl_ref, q_ref, pool_hbm, o_ref, buf, sem,
     semaphores [2] (a buffer half each); float32 scratch: acc [H,
     value_width], running max and denominator [H, 128]."""
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     b = pl.program_id(0)
     pi = pl.program_id(1)
@@ -72,23 +145,7 @@ def _decode_kernel(bt_ref, cl_ref, q_ref, pool_hbm, o_ref, buf, sem,
     used = jnp.minimum((ctx + page_size - 1) // page_size, bt_ref.shape[1])
     blocks = (used + pages - 1) // pages
     keys = pages * page_size
-
-    def fetch(block, half):
-        """Start the copies of `block`'s pages into buffer `half`; a
-        page past the lane's last is its last again."""
-        def page(j, carry):
-            at = bt_ref[b, jnp.minimum(block * pages + j, used - 1)]
-            pltpu.make_async_copy(pool_hbm.at[at], buf.at[half, j],
-                                  sem.at[half]).start()
-            return carry
-        jax.lax.fori_loop(0, pages, page, 0)
-
-    def wait(half):
-        def page(j, carry):
-            pltpu.make_async_copy(pool_hbm.at[0], buf.at[half, j],
-                                  sem.at[half]).wait()
-            return carry
-        jax.lax.fori_loop(0, pages, page, 0)
+    fetch, wait = _page_copies(bt_ref, pool_hbm, buf, sem, b, used, pages)
 
     @pl.when(pi == 0)
     def _init():
@@ -118,19 +175,8 @@ def _decode_kernel(bt_ref, cl_ref, q_ref, pool_hbm, o_ref, buf, sem,
         # garbage, and the block's pages past it are that page again
         pos = pi * keys + jax.lax.broadcasted_iota(jnp.int32, (1, keys), 1)
         valid = pos < ctx
-        s = jnp.where(valid, s, _NEG_INF)
-        m_prev = m_ref[:, :1]                            # [H, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[:, :1] = l_ref[:, :1] * corr + jnp.sum(p, axis=-1,
-                                                     keepdims=True)
-        m_ref[:, :1] = m_new
-        # the value is the row's head: the same fetched bytes
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            p.astype(rows.dtype), rows[:, :value_width],
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [H, value_width]
+        _softmax_block(s, lambda x, fill: jnp.where(valid, x, fill), rows,
+                       acc_ref, m_ref, l_ref, value_width)
 
     @pl.when(pi == n_p - 1)
     def _finalize():
@@ -211,55 +257,179 @@ def _decode_call(q, pool, block_tables, context_lens, *, page_size: int,
     return out.reshape(b, 1, h, value_width)
 
 
+# query rows (heads x the chunk's queries) a tile of the prefill kernel
+# holds, and context rows a block of its walk covers.  Measured on the
+# v5e at the cell's shapes (PERF.md, PR 41): 2048 x 512 beats 1024 x 512
+# by 4 % and 512-row tiles lose 14 %; blocks of 256 or 1024 rows lose 8
+# to 19 %; query sub-tiles inside a block lose too (the block's two
+# products run at the MXU's rate as they stand).  At 2048 x 512 the
+# float32 scores and the accumulator are 4 MB each, and a block's
+# products (2.4 GFLOP at a row of 640) hide its 0.66 MB of page copies
+_PREFILL_QUERY_ROWS = 2048
+_PREFILL_BLOCK_ROWS = 512
+# a tile's blocks, scratch and temporaries are some 30 MB at the cell's
+# shapes, over the compiler's default allowance
+_PREFILL_VMEM_BYTES = 48 << 20
+
+
+def _prefill_kernel(bt_ref, cl_ref, q_ref, qpos_ref, pool_hbm, o_ref, buf,
+                    sem, acc_ref, m_ref, l_ref, *, page_size: int,
+                    pages: int, scale: float, value_width: int):
+    """q [1, Ht, S, W]: a tile of Ht heads' queries of one lane's chunk;
+    qpos [1, S, 1]; the pool in HBM; o [1, Ht, S, value_width]; `buf`
+    and `sem` as the decode kernel's; float32 scratch by query row (head
+    by head, Ht x S of them): acc [rows, value_width], running max and
+    denominator [rows, 128].  A grid step is one tile; it walks the
+    lane's blocks itself."""
+    from jax.experimental import pallas as pl
+
+    b = pl.program_id(0)
+    ctx = cl_ref[b]
+    used = jnp.minimum((ctx + page_size - 1) // page_size, bt_ref.shape[1])
+    blocks = (used + pages - 1) // pages
+    keys = pages * page_size
+    _one, heads, chunk, width = q_ref.shape
+    fetch, wait = _page_copies(bt_ref, pool_hbm, buf, sem, b, used, pages,
+                               unroll=True)
+    m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    @pl.when(blocks > 0)
+    def _first():
+        fetch(0, 0)
+
+    def block(ci, carry):
+        half = ci % 2
+
+        @pl.when(ci + 1 < blocks)
+        def _next():
+            fetch(ci + 1, 1 - half)
+
+        wait(half)
+        q = q_ref[0].reshape(heads * chunk, width)       # [rows, W]
+        rows = buf[half].reshape(keys, width)            # [keys, W]
+        s = jax.lax.dot_general(
+            q, rows, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [rows, keys]
+        # the causal edge runs inside the chunk: a query sees positions
+        # up to its own, and none at or past the lane's length (the last
+        # page's rows there hold garbage); one mask for every head
+        pos = ci * keys + jax.lax.broadcasted_iota(jnp.int32, (1, keys), 1)
+        seen = (pos <= qpos_ref[0]) & (pos < ctx)        # [S, keys]
+
+        def by_head(x, fill):
+            x = x.reshape(heads, chunk, keys)
+            return jnp.where(seen[None], x, fill).reshape(heads * chunk,
+                                                          keys)
+        _softmax_block(s, by_head, rows, acc_ref, m_ref, l_ref, value_width)
+        return carry
+
+    # a lane's own blocks and no more: an empty lane runs none
+    jax.lax.fori_loop(0, blocks, block, 0)
+    inv = 1.0 / jnp.maximum(l_ref[:, :1], 1e-20)
+    o_ref[0] = (acc_ref[:] * inv).astype(o_ref.dtype).reshape(
+        heads, chunk, value_width)
+
+
 def latent_chunk_attention(q: jax.Array, pool: jax.Array, ctx: jax.Array,
                            ctx_pos: jax.Array, ctx_mask: jax.Array,
-                           q_pos: jax.Array, *, value_width: int,
-                           scale: float) -> jax.Array:
-    """A chunk of queries a lane over a gathered latent context.
+                           q_pos: jax.Array, *, page_size: int,
+                           value_width: int, scale: float,
+                           interpret: Optional[bool] = None) -> jax.Array:
+    """A chunk of queries a lane (chunked prefill) over the lane's
+    latent pages.
 
     q: [B, S, H, W] absorbed queries; pool: [T, W] (this call's rows
-    already written); ctx: [B, L] slot of each context entry, ctx_pos:
-    [B, L] its position, ctx_mask: [B, L] its validity; q_pos: [B, S].
-    A query sees the valid entries at positions up to its own.  Returns
-    [B, S, H, value_width] in q's dtype."""
-    _b, s, h, _w = q.shape
-    length = ctx.shape[1]
-    blk = min(CHUNK_CTX_BLOCK, length)
-    assert length % blk == 0, (length, blk)
+    already written); q_pos: [B, S].  The context is what the engine's
+    prefill pass hands a full layer: ctx [B, L] the slot of context
+    position 0, 1, ... in order (`ctx_pos` says so and is not read),
+    ctx_mask [B, L] true on the lane's first n columns.  A page holds
+    `page_size` consecutive positions, so every `page_size`-th column
+    names a page: the lane's block table, read here with no gather of
+    rows.  A query sees the positions up to its own below n.  Returns
+    [B, S, H, value_width] in q's dtype; a lane of n = 0 zeros."""
+    from ray_tpu.ops import interpret_default
 
-    def lane(args):
-        """One lane's chunk [S, H, W] over its own context: the blocks
-        that hold a valid entry of THIS lane, so a short context beside
-        a long one in the pass costs its own length (and an empty lane
-        nothing)."""
-        q, ctx, ctx_pos, ctx_mask, q_pos = args
-        last = jnp.max(jnp.where(ctx_mask, jnp.arange(length) + 1, 0))
+    del ctx_pos
+    table = ctx[:, ::page_size] // page_size
+    heads, pages = _prefill_tiles(q.shape[2], q.shape[1], table.shape[1],
+                                  page_size)
+    return _prefill_call(q, pool, table, ctx_mask.sum(-1), q_pos,
+                         page_size=page_size, value_width=value_width,
+                         scale=float(scale), tile_heads=heads,
+                         block_pages=pages,
+                         interpret=interpret_default(interpret))
 
-        def take(a, i):
-            return jax.lax.dynamic_slice_in_dim(a, i * blk, blk)
 
-        def block(i, carry):
-            m, l, acc = carry            # [S, H, 1], [S, H, 1], [S, H, V]
-            rows = pool[take(ctx, i)]                        # [blk, W]
-            sc = jnp.einsum("shw,kw->shk", q, rows,
-                            preferred_element_type=jnp.float32) * scale
-            seen = ((take(ctx_pos, i)[None, :] <= q_pos[:, None])
-                    & take(ctx_mask, i)[None, :])[:, None, :]
-            sc = jnp.where(seen, sc, _NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
-            p = jnp.where(seen, jnp.exp(sc - m_new), 0.0)
-            corr = jnp.exp(m - m_new)
-            l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-            acc = acc * corr + jnp.einsum(
-                "shk,kv->shv", p.astype(rows.dtype),
-                rows[:, :value_width], preferred_element_type=jnp.float32)
-            return m_new, l, acc
+def _prefill_tiles(heads: int, chunk: int, table_width: int,
+                   page_size: int):
+    """(heads a query tile, pages a context block) of the prefill
+    kernel, from the call's static shapes: the most heads that divide
+    `heads` and keep a tile within _PREFILL_QUERY_ROWS query rows, and
+    _PREFILL_BLOCK_ROWS context rows or the whole table if narrower."""
+    tile = max(1, min(heads, _PREFILL_QUERY_ROWS // chunk))
+    while heads % tile:
+        tile -= 1
+    return tile, min(table_width, max(1, _PREFILL_BLOCK_ROWS // page_size))
 
-        m0 = jnp.full((s, h, 1), _NEG_INF, jnp.float32)
-        l0 = jnp.zeros((s, h, 1), jnp.float32)
-        acc0 = jnp.zeros((s, h, value_width), jnp.float32)
-        _m, l, acc = jax.lax.fori_loop(0, (last + blk - 1) // blk, block,
-                                       (m0, l0, acc0))
-        return (acc / jnp.maximum(l, 1e-20)).astype(q.dtype)
 
-    return jax.lax.map(lane, (q, ctx, ctx_pos, ctx_mask, q_pos))
+# a jit of its own, as `_decode_call`
+@functools.partial(jax.jit, static_argnames=(
+    "page_size", "value_width", "scale", "tile_heads", "block_pages",
+    "interpret"))
+def _prefill_call(q, pool, block_tables, context_lens, q_pos, *,
+                  page_size: int, value_width: int, scale: float,
+                  tile_heads: int, block_pages: int, interpret: bool):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s, h, w = q.shape
+    num_slots = pool.shape[0]
+    assert pool.shape == (num_slots, w) and num_slots % page_size == 0
+    assert h % tile_heads == 0, (h, tile_heads)
+    width = block_tables.shape[1]
+    paged = pool.reshape(num_slots // page_size, page_size, w)
+    bt = block_tables.astype(jnp.int32)
+    cl = context_lens.astype(jnp.int32)
+    if interpret:
+        # as `_decode_call`: the table's pages only
+        paged = paged[bt.reshape(-1)]
+        bt = jnp.arange(b * width, dtype=jnp.int32).reshape(b, width)
+
+    def _tile(bi, hi, *_scalars):
+        return (bi, hi, 0, 0)
+
+    def _lane(bi, hi, *_scalars):
+        return (bi, 0, 0)
+
+    kernel = functools.partial(_prefill_kernel, page_size=page_size,
+                               pages=block_pages, scale=scale,
+                               value_width=value_width)
+    rows = tile_heads * s
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, h // tile_heads),
+        in_specs=[pl.BlockSpec((1, tile_heads, s, w), _tile),
+                  pl.BlockSpec((1, s, 1), _lane),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, tile_heads, s, value_width), _tile),
+        scratch_shapes=[
+            pltpu.VMEM((2, block_pages, page_size, w), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((rows, value_width), jnp.float32),   # acc
+            pltpu.VMEM((rows, 128), jnp.float32),           # running max
+            pltpu.VMEM((rows, 128), jnp.float32),           # running denom
+        ])
+    # head-major, so that a tile's query rows share one [S, keys] mask;
+    # the transposes fold into the products on either side
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((b, h, s, value_width), q.dtype),
+        grid_spec=grid_spec, interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_PREFILL_VMEM_BYTES),
+        name="latent_attention_prefill",
+    )(bt, cl, q.transpose(0, 2, 1, 3),
+      q_pos.astype(jnp.int32)[..., None], paged)
+    return out.transpose(0, 2, 1, 3)

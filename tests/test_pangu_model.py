@@ -230,10 +230,66 @@ def test_latent_pools_ship_copy_and_share(params):
     assert decoder.stats()["prefill_steps"] == 0
 
 
+def test_the_prefill_pass_carries_each_lanes_block_table():
+    """What `latent_chunk_attention` reads its table from: every
+    `page_size`-th column of the pass's `ctx`, by whole pages, is the
+    sequence's `block_table` as far as its context reaches and its
+    length is the mask's count — for every pass an engine builds, the
+    passes of a prompt that shares a live prefix's pages (and holds a
+    copy-on-write page of its own behind them) among them."""
+    eng = _engine()
+    passes, tables, calls = [], {}, []
+    dispatch, forward = eng._dispatch_prefill, eng._forward
+
+    def spy_dispatch(step, prefill_args):
+        lanes = [(list(seq.block_table), hi)
+                 for seq, _lo, hi, *_rest in prefill_args]
+        tables.update((seq, list(seq.block_table))
+                      for seq, *_rest in prefill_args)
+        out = dispatch(step, prefill_args)
+        # the pass's own call is the last (warm-up's come before it)
+        passes.append((lanes, *calls[-1]))
+        return out
+
+    def spy_forward(tokens, slot_arr, ctx, ctx_pos, ctx_mask, *rest, **kw):
+        if ctx is not None:
+            calls.append((np.asarray(ctx), np.asarray(ctx_mask)))
+        return forward(tokens, slot_arr, ctx, ctx_pos, ctx_mask, *rest,
+                       **kw)
+
+    eng._dispatch_prefill, eng._forward = spy_dispatch, spy_forward
+    prompt = [int(t) for t in TOKENS[:96]]
+    first = eng.submit({"tokens": prompt, "max_new_tokens": 4})
+    for _ in range(4):
+        eng.step()
+    second = eng.submit({"tokens": prompt, "max_new_tokens": 4})
+    third = eng.submit({"tokens": [5] + prompt[:70], "max_new_tokens": 4})
+    _drain(eng)
+    assert eng.stats()["prefix_hits"] == 1 and eng.stats()["cow_splits"] == 1
+    # five whole pages shared, the sixth a copy of 15 of its rows
+    assert tables[first][:5] == tables[second][:5]
+    assert tables[first][5] != tables[second][5]
+    assert len(passes) >= 4 and third.generated
+    for lanes, ctx, mask in passes:
+        table = ctx[:, ::PAGE] // PAGE
+        np.testing.assert_array_equal(mask.sum(-1)[:len(lanes)],
+                                      [hi for _bt, hi in lanes])
+        assert not mask[len(lanes):].any()
+        for lane, (block_table, hi) in enumerate(lanes):
+            used = -(-hi // PAGE)
+            assert list(table[lane, :used]) == block_table[:used]
+            # positions in order: a column's slot is its page's row
+            np.testing.assert_array_equal(
+                ctx[lane, :hi], np.repeat(table[lane, :used] * PAGE,
+                                          PAGE)[:hi] + np.arange(hi) % PAGE)
+            # behind the length: the garbage page, a valid address
+            assert not ctx[lane, hi:].any()
+
+
 class _NarrowKit:
     """This family's kit for `narrow_prefill_cases`: chunk 16 under a
     context of 384 gives the prefill pass the widths 64, 256 and 384;
-    the lane-by-lane latent attention follows the pass's lanes."""
+    the prefill kernel's grid follows the pass's lanes."""
 
     @staticmethod
     def make(max_len=384, **kw):

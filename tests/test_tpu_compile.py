@@ -249,6 +249,47 @@ def test_latent_decode_compiles_at_the_cells_shapes(one_chip, width):
     assert "paged_attention_decode" not in text
 
 
+# the same cell's prefill pass: 8 lanes (2 in the narrow pass) of 64
+# queries x 128 heads over a context of every width bucket, the table
+# read from the pass's `ctx`; a tile of the 8,192 query rows and a block
+# of pages at a time, the scores and running sums in VMEM
+
+
+@pytest.mark.parametrize("width", [256, 1024, 4096, 8192])
+@pytest.mark.parametrize("lanes", [8, 2], ids=["wide", "narrow"])
+def test_latent_prefill_compiles_at_the_cells_shapes(one_chip, lanes, width):
+    from ray_tpu.models.cache import latent_row_width
+    from ray_tpu.ops import latent_attention as la
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    chunk, heads, row = 64, 128, latent_row_width(512 + 64)
+    slots = (1 + 32 * (8192 // PAGE)) * PAGE
+    compiled = jax.jit(
+        lambda q, pool, ctx, ctx_pos, ctx_mask, q_pos:
+        la.latent_chunk_attention(
+            q, pool, ctx, ctx_pos, ctx_mask, q_pos, page_size=PAGE,
+            value_width=512, scale=192 ** -0.5, interpret=False)
+    ).lower(spec((lanes, chunk, heads, row), jnp.bfloat16),
+            spec((slots, row), jnp.bfloat16),
+            spec((lanes, width), jnp.int32),
+            spec((lanes, width), jnp.int32),
+            spec((lanes, width), jnp.bool_),
+            spec((lanes, chunk), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "latent_attention_prefill" in text
+    # no reader's pattern takes it for the decode kernel
+    assert "latent_attention_decode" not in text
+    assert "paged_attention_decode" not in text
+    # the chip's compiler held the kernel's blocks, scratch and
+    # temporaries to the VMEM the call asks for (it refuses what does
+    # not fit), and the call asks for less than the chip has
+    assert ('"scoped_memory_configs":[{"memory_space":"1","offset":"0",'
+            f'"size":"{la._PREFILL_VMEM_BYTES}"}}]') in text
+    assert la._PREFILL_VMEM_BYTES < 128 << 20
+
+
 # benchmarks/configs/granite-4.0-h-micro-serve.json: 64 lanes; a state
 # pool of 65 slots of 64 heads x 64 x 128 float32 a state layer, updated
 # in place by slot; 32 query heads of 64 over 8 KV heads, which the cache
