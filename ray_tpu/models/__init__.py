@@ -60,7 +60,7 @@ __all__ = ["LlamaConfig", "LlamaModel", "llama_param_rules", "resolve",
 # `model_type` -> the module of this package that implements it
 FAMILIES = {"llama": "llama", "mistral": "llama", "laguna": "laguna",
             "mellum": "laguna", "pangu_ultra_moe": "pangu",
-            "granitemoehybrid": "granite"}
+            "glm_moe_dsa": "pangu", "granitemoehybrid": "granite"}
 # keys that a `model_type`'s published config class defaults, so that a
 # dictionary of that type may leave them out (a family's `from_dict`
 # takes an absent key for an absent mechanism).  `benchmarks/kinds/

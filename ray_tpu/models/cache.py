@@ -17,7 +17,12 @@ the chip's 128 lanes, zeros behind the row: the chip stores a `[slots,
 and its kernel compiler copies whole tiles only ("Slice shape along
 dimension 2 must be aligned to tiling (128), but is 576" is what a
 page's copy out of a 576-wide pool gets), so the pool's shape states
-what the memory holds and a page of it is one copy.
+what the memory holds and a page of it is one copy.  A latent layer that
+SELECTS the rows its attention reads (learned sparse attention:
+models/pangu.py, `IndexedLatentCache`) keeps a SECOND PART a token, the
+key its indexer scores, `index` numbers in a pool of its own, `"index"`,
+beside `"latent"`: two parts of one row at the same slot, so whatever
+moves a page (the copy-on-write split, page shipping) moves both.
 
 A third kind, `state` (`StateCache`), keeps no row a position at all:
 the layer carries a recurrent state, ONE row a SEQUENCE whatever its
@@ -32,7 +37,8 @@ every token — and a carry rounded to 8 bits every step forgets what a
 float32 one keeps.
 
 The pools themselves are paging-agnostic flat slot arrays, by the name
-of the row's part (`"k"`, `"v"`; `"latent"`; `"conv"`, `"ssm"`) a list
+of the row's part (`"k"`, `"v"`; `"latent"`, `"index"`; `"conv"`,
+`"ssm"`) a list
 over the layers: `[slots of the layer's kind, *the part's shape]`, None
 at a layer whose row has no such part.  Slot 0 of every pool is the
 garbage slot that padding writes to.  Everything below goes by what a
@@ -63,6 +69,24 @@ class LayerCache(NamedTuple):
 
     def dtypes(self) -> Dict[str, Any]:
         """The parts that are not in the model's dtype: none."""
+        return {}
+
+
+class IndexedLatentCache(NamedTuple):
+    """A latent layer whose attention reads only the rows an indexer
+    selects: the row is the latent vector (`latent` numbers, stored as
+    `LayerCache`'s) and, beside it, the indexer's key of `index`
+    numbers.  Paged as `kind` says, like a `LayerCache`."""
+    kind: str
+    window: int
+    latent: int
+    index: int
+
+    def rows(self) -> Dict[str, Tuple[int, ...]]:
+        return {"latent": (latent_row_width(self.latent),),
+                "index": (self.index,)}
+
+    def dtypes(self) -> Dict[str, Any]:
         return {}
 
 

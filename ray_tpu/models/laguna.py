@@ -369,9 +369,13 @@ class GatedAttention(nn.Module):
 class ExpertLayer(nn.Module):
     """This share's part of the routed sum, plus the shared expert where
     the config has one.  `scores` is the family's score function
-    (another family's expert layer is this one with its own)."""
+    (another family's expert layer is this one with its own); with
+    `selection_bias` the router has a bias an expert, `moe_router_bias`,
+    that moves who is chosen and not what a chosen expert weighs
+    (`ops/moe.py`, `route`)."""
     cfg: LagunaConfig
     scores: Any = router_scores
+    selection_bias: bool = False
 
     @nn.compact
     def __call__(self, x, valid):
@@ -391,10 +395,14 @@ class ExpertLayer(nn.Module):
         w1 = self.param("moe_experts_w1", *experts((e, d, f), d))
         w3 = self.param("moe_experts_w3", *experts((e, d, f), d))
         w2 = self.param("moe_experts_w2", *experts((e, f, d), f))
+        # drawn small and not zero: zeros would hide a dropped bias
+        bias = self.param("moe_router_bias", nn.initializers.normal(0.05),
+                          (cfg.num_experts,), jnp.float32) \
+            if self.selection_bias else None
         routed, counters = moe.moe_layer(
             flat, w_router, w1, w3, w2, top_k=cfg.num_experts_per_tok,
             held=(lo, hi), valid=valid.reshape(b * s),
-            normalize=cfg.norm_topk_prob, scores=self.scores)
+            normalize=cfg.norm_topk_prob, scores=self.scores, bias=bias)
         routed = routed.reshape(b, s, d)
         if cfg.shared_expert_intermediate_size:
             shared = SwiGLU(cfg, cfg.shared_expert_intermediate_size,

@@ -63,6 +63,17 @@ own: traced and lowered once a program, not once a layer.
       rows, no score array `[lanes, heads, chunk, context]` in HBM (2.1
       GB at 8 x 128 x 64 x 8192 in float32), and a pass costs what its
       lanes hold, not what its width bucket could.
+    - A SELECTION (`select=`: learned sparse attention,
+      ops/sparse_index.py).  Given every query's index scores of the
+      lane's positions and its (threshold, tie), a block's unselected
+      columns are masked like the causal edge: the same pages, the same
+      grid, a block of scores `[queries, keys]` copied beside a block's
+      pages.  The plain first form: the products of the masked columns
+      are still made.
+
+`latent_paged_attention` over SELECTED rows is itself: the caller
+gathers a lane's rows (`sparse_index.gather_rows`) into a pool of their
+own, a lane after a lane, and hands an identity table.
 """
 
 from __future__ import annotations
@@ -272,25 +283,48 @@ _PREFILL_BLOCK_ROWS = 512
 _PREFILL_VMEM_BYTES = 48 << 20
 
 
-def _prefill_kernel(bt_ref, cl_ref, q_ref, qpos_ref, pool_hbm, o_ref, buf,
-                    sem, acc_ref, m_ref, l_ref, *, page_size: int,
-                    pages: int, scale: float, value_width: int):
+def _prefill_kernel(bt_ref, cl_ref, q_ref, qpos_ref, *refs, page_size: int,
+                    pages: int, scale: float, value_width: int,
+                    select: bool = False):
     """q [1, Ht, S, W]: a tile of Ht heads' queries of one lane's chunk;
     qpos [1, S, 1]; the pool in HBM; o [1, Ht, S, value_width]; `buf`
     and `sem` as the decode kernel's; float32 scratch by query row (head
     by head, Ht x S of them): acc [rows, value_width], running max and
     denominator [rows, 128].  A grid step is one tile; it walks the
-    lane's blocks itself."""
+    lane's blocks itself.  With `select`, before the pool: thr [1, S, 1]
+    float32, tie [1, S, 1] and the index scores in HBM [B, blocks, S,
+    keys]; before acc: their double buffer [2, S, keys] and its
+    semaphores."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
+    if select:
+        (thr_ref, tie_ref, scores_hbm, pool_hbm, o_ref, buf, sem, sbuf,
+         ssem, acc_ref, m_ref, l_ref) = refs
+    else:
+        pool_hbm, o_ref, buf, sem, acc_ref, m_ref, l_ref = refs
     b = pl.program_id(0)
     ctx = cl_ref[b]
     used = jnp.minimum((ctx + page_size - 1) // page_size, bt_ref.shape[1])
     blocks = (used + pages - 1) // pages
     keys = pages * page_size
     _one, heads, chunk, width = q_ref.shape
-    fetch, wait = _page_copies(bt_ref, pool_hbm, buf, sem, b, used, pages,
-                               unroll=True)
+    fetch_pages, wait_pages = _page_copies(bt_ref, pool_hbm, buf, sem, b,
+                                           used, pages, unroll=True)
+    if select:
+        def scores_copy(block, half):
+            return pltpu.make_async_copy(scores_hbm.at[b, block],
+                                         sbuf.at[half], ssem.at[half])
+
+        def fetch(block, half):
+            fetch_pages(block, half)
+            scores_copy(block, half).start()
+
+        def wait(half):
+            wait_pages(half)
+            scores_copy(0, half).wait()
+    else:
+        fetch, wait = fetch_pages, wait_pages
     m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
     l_ref[:] = jnp.zeros_like(l_ref)
     acc_ref[:] = jnp.zeros_like(acc_ref)
@@ -317,6 +351,11 @@ def _prefill_kernel(bt_ref, cl_ref, q_ref, qpos_ref, pool_hbm, o_ref, buf,
         # page's rows there hold garbage); one mask for every head
         pos = ci * keys + jax.lax.broadcasted_iota(jnp.int32, (1, keys), 1)
         seen = (pos <= qpos_ref[0]) & (pos < ctx)        # [S, keys]
+        if select:
+            # ... and of those only the rows its indexer selected
+            index = sbuf[half]                           # [S, keys]
+            seen &= (index > thr_ref[0]) | (
+                (index == thr_ref[0]) & (pos <= tie_ref[0]))
 
         def by_head(x, fill):
             x = x.reshape(heads, chunk, keys)
@@ -336,7 +375,8 @@ def latent_chunk_attention(q: jax.Array, pool: jax.Array, ctx: jax.Array,
                            ctx_pos: jax.Array, ctx_mask: jax.Array,
                            q_pos: jax.Array, *, page_size: int,
                            value_width: int, scale: float,
-                           interpret: Optional[bool] = None) -> jax.Array:
+                           interpret: Optional[bool] = None,
+                           select=None) -> jax.Array:
     """A chunk of queries a lane (chunked prefill) over the lane's
     latent pages.
 
@@ -347,15 +387,18 @@ def latent_chunk_attention(q: jax.Array, pool: jax.Array, ctx: jax.Array,
     ctx_mask [B, L] true on the lane's first n columns.  A page holds
     `page_size` consecutive positions, so every `page_size`-th column
     names a page: the lane's block table, read here with no gather of
-    rows.  A query sees the positions up to its own below n.  Returns
-    [B, S, H, value_width] in q's dtype; a lane of n = 0 zeros."""
+    rows.  A query sees the positions up to its own below n — with
+    `select` = (index scores [B, S, L] float32, threshold [B, S], tie
+    [B, S]: `sparse_index.select_threshold`'s) only those of them its
+    indexer selected.  Returns [B, S, H, value_width] in q's dtype; a
+    lane of n = 0 zeros."""
     from ray_tpu.ops import interpret_default
 
     del ctx_pos
     table = ctx[:, ::page_size] // page_size
     heads, pages = _prefill_tiles(q.shape[2], q.shape[1], table.shape[1],
                                   page_size)
-    return _prefill_call(q, pool, table, ctx_mask.sum(-1), q_pos,
+    return _prefill_call(q, pool, table, ctx_mask.sum(-1), q_pos, select,
                          page_size=page_size, value_width=value_width,
                          scale=float(scale), tile_heads=heads,
                          block_pages=pages,
@@ -378,8 +421,8 @@ def _prefill_tiles(heads: int, chunk: int, table_width: int,
 @functools.partial(jax.jit, static_argnames=(
     "page_size", "value_width", "scale", "tile_heads", "block_pages",
     "interpret"))
-def _prefill_call(q, pool, block_tables, context_lens, q_pos, *,
-                  page_size: int, value_width: int, scale: float,
+def _prefill_call(q, pool, block_tables, context_lens, q_pos, select=None,
+                  *, page_size: int, value_width: int, scale: float,
                   tile_heads: int, block_pages: int, interpret: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -403,20 +446,38 @@ def _prefill_call(q, pool, block_tables, context_lens, q_pos, *,
     def _lane(bi, hi, *_scalars):
         return (bi, 0, 0)
 
+    rows = tile_heads * s
     kernel = functools.partial(_prefill_kernel, page_size=page_size,
                                pages=block_pages, scale=scale,
-                               value_width=value_width)
-    rows = tile_heads * s
+                               value_width=value_width,
+                               select=select is not None)
+    chosen, chosen_specs, chosen_scratch = (), [], []
+    if select is not None:
+        # the scores a block of the walk at a time, [B, blocks, S, keys]
+        scores, thr, tie = select
+        keys = block_pages * page_size
+        blocks = -(-width // block_pages)
+        scores = jnp.pad(scores.astype(jnp.float32), (
+            (0, 0), (0, 0), (0, blocks * keys - scores.shape[-1])),
+            constant_values=-jnp.inf)
+        chosen = (thr.astype(jnp.float32)[..., None],
+                  tie.astype(jnp.int32)[..., None],
+                  scores.reshape(b, s, blocks, keys).transpose(0, 2, 1, 3))
+        chosen_specs = [pl.BlockSpec((1, s, 1), _lane),
+                        pl.BlockSpec((1, s, 1), _lane),
+                        pl.BlockSpec(memory_space=pl.ANY)]
+        chosen_scratch = [pltpu.VMEM((2, s, keys), jnp.float32),
+                          pltpu.SemaphoreType.DMA((2,))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, h // tile_heads),
         in_specs=[pl.BlockSpec((1, tile_heads, s, w), _tile),
-                  pl.BlockSpec((1, s, 1), _lane),
+                  pl.BlockSpec((1, s, 1), _lane), *chosen_specs,
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, tile_heads, s, value_width), _tile),
         scratch_shapes=[
             pltpu.VMEM((2, block_pages, page_size, w), pool.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)), *chosen_scratch,
             pltpu.VMEM((rows, value_width), jnp.float32),   # acc
             pltpu.VMEM((rows, 128), jnp.float32),           # running max
             pltpu.VMEM((rows, 128), jnp.float32),           # running denom
@@ -431,5 +492,5 @@ def _prefill_call(q, pool, block_tables, context_lens, q_pos, *,
             vmem_limit_bytes=_PREFILL_VMEM_BYTES),
         name="latent_attention_prefill",
     )(bt, cl, q.transpose(0, 2, 1, 3),
-      q_pos.astype(jnp.int32)[..., None], paged)
+      q_pos.astype(jnp.int32)[..., None], *chosen, paged)
     return out.transpose(0, 2, 1, 3)

@@ -91,18 +91,25 @@ def softmax_scores(logits: jax.Array) -> jax.Array:
 
 def route(x: jax.Array, w_router: jax.Array, top_k: int,
           normalize: bool = True,
-          scores: Callable[[jax.Array], jax.Array] = softmax_scores
+          scores: Callable[[jax.Array], jax.Array] = softmax_scores,
+          bias: Optional[jax.Array] = None
           ) -> Tuple[jax.Array, jax.Array]:
     """x [T, D], w_router [D, E] -> (expert ids [T, k] int32, weights
     [T, k] float32).  Logits and `scores` (softmax over all experts
     unless the model says otherwise) in float32 whatever the stored
-    dtype: a logit moved by a bfloat16 rounding changes who is chosen."""
+    dtype: a logit moved by a bfloat16 rounding changes who is chosen.
+    With a selection `bias` [E] the experts are CHOSEN by score + bias
+    and weighted by the score alone."""
     with jax.named_scope("moe_router"):
         logits = jnp.dot(x.astype(jnp.float32),
                          w_router.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
         probs = scores(logits)
-        weights, ids = jax.lax.top_k(probs, top_k)
+        if bias is None:
+            weights, ids = jax.lax.top_k(probs, top_k)
+        else:
+            _, ids = jax.lax.top_k(probs + bias.astype(jnp.float32), top_k)
+            weights = jnp.take_along_axis(probs, ids, axis=-1)
         if normalize:
             weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
         return ids.astype(jnp.int32), weights
@@ -502,7 +509,8 @@ def moe_layer(x: jax.Array, w_router: jax.Array, w1: jax.Array,
               held: Tuple[int, int], valid: Optional[jax.Array] = None,
               normalize: bool = True,
               scores: Callable[[jax.Array], jax.Array] = softmax_scores,
-              interpret: Optional[bool] = None
+              interpret: Optional[bool] = None,
+              bias: Optional[jax.Array] = None
               ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """The routed part of an expert layer for the experts held here.
 
@@ -510,14 +518,15 @@ def moe_layer(x: jax.Array, w_router: jax.Array, w1: jax.Array,
     [E_held, F, D] (the held experts' matrices only).  Returns (y [T, D]
     float32 — the sum over this share's chosen experts of routing weight
     x SwiGLU_e(x), unscaled; the caller applies the model's factor and
-    adds what every share computes alike — and the counters of
+    adds what every share computes alike; `bias` is `route`'s — and the
+    counters of
     `TRAIN_COUNTERS` as int32 scalars).  The chosen experts `ids` [T, k]
     ride along under "ids" for a caller that compares routings."""
     t = x.shape[0]
     num_experts = w_router.shape[-1]
     if valid is None:
         valid = jnp.ones((t,), bool)
-    ids, weights = route(x, w_router, top_k, normalize, scores)
+    ids, weights = route(x, w_router, top_k, normalize, scores, bias)
     tm = row_tile(t, top_k, num_experts)
     d = dispatch(ids, valid, held, tm)
     y = _routed(x, weights, w1, w3, w2, d, tm, interpret)

@@ -1456,6 +1456,9 @@ class LLMEngine:
                    param_bytes=nbytes(self._params),
                    kv_pool_bytes=nbytes(self._pools),
                    state_pool_bytes=self._state_pool_bytes(),
+                   **{f"{part}_pool_bytes": self._part_bytes(part)
+                      for part in ("latent", "index")
+                      if part in self._pools},
                    # executables behind the jitted stepper, all engines
                    # of this process: constant once warm-up is done
                    compiled_steps=sum(fn._cache_size()
@@ -1467,6 +1470,11 @@ class LLMEngine:
         text = self._lower_decode(self._paged_width_buckets()[0]).as_text()
         rep["decode_has_tpu_custom_call"] = "tpu_custom_call" in text
         return rep
+
+    def _part_bytes(self, part: str) -> int:
+        """What the pools of one part of a row take, all layers."""
+        return sum(int(p.nbytes) for p in self._pools.get(part, ())
+                   if p is not None)
 
     def _state_pool_bytes(self) -> int:
         """What the state kind's pools take: a row a slot, the garbage
@@ -2427,12 +2435,12 @@ class LLMEngine:
                     "cow_splits": self._cow_splits,
                     "kv_pages_shipped_out": self._kv_pages_shipped_out,
                     "kv_pages_shipped_in": self._kv_pages_shipped_in,
-                    **({"latent_pool_bytes": sum(
-                            int(p.nbytes) for p in self._pools["latent"]
-                            if p is not None),
+                    **({"latent_pool_bytes": self._part_bytes("latent"),
                         "latent_pages_in_use":
                             self.num_pages - 1 - len(self._free_pages)}
                        if self._latent_layers else {}),
+                    **({"index_pool_bytes": self._part_bytes("index")}
+                       if "index" in self._pools else {}),
                     **({"state_slots_in_use":
                             self.max_batch - len(self._free_state),
                         "state_pool_bytes": self._state_pool_bytes()}

@@ -1,0 +1,178 @@
+"""ops/sparse_index.py and the selection inside the latent kernels: the
+indexer's scores, the exact selection of the `top_k` largest (a tie to
+the lower position), and the two sparse forms of the attention — the
+chunk kernel that masks what was not selected, and gather-then-decode —
+against plain attention over the same set of rows."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import latent_attention as la
+from ray_tpu.ops import sparse_index as si
+
+PAGE = 16
+
+
+def _top_k_mask(scores, k):
+    """The oracle: `lax.top_k` puts equal values lower index first."""
+    at = np.asarray(jax.lax.top_k(jnp.asarray(scores), k)[1])
+    mask = np.zeros(scores.shape, bool)
+    np.put_along_axis(mask, at, True, axis=-1)
+    return mask
+
+
+@pytest.mark.parametrize("k", [1, 7, 32, 100])
+def test_threshold_selection_is_top_k_with_ties_to_the_lower_position(k):
+    rng = np.random.RandomState(k)
+    scores = rng.randn(6, 100).astype(np.float32)
+    # a constructed tie that straddles the cut: row 0's k-th largest
+    # value five times over, at scattered positions
+    order = np.argsort(-scores[0])
+    scores[0, rng.choice(100, 5, replace=False)] = scores[0, order[k - 1]]
+    # many equal values (a ReLU's zeros)
+    scores[1, ::3] = 0.0
+    # a row that sees fewer than k positions, and one that sees none
+    scores[2, 5:] = -np.inf
+    scores[3, :] = -np.inf
+    # every value equal
+    scores[4, :] = 1.5
+    # -0.0 is +0.0 to a comparison (a sort would put it lower; the score
+    # kernel hands out one zero, so `top_k` never meets the other)
+    scores[5, 2::4] = 0.0
+    both = scores.copy()
+    both[5, 2::8] = -0.0
+    np.testing.assert_array_equal(
+        np.asarray(si.selected(jnp.asarray(scores),
+                               *si.select_threshold(jnp.asarray(both), k))),
+        np.asarray(si.selected(jnp.asarray(scores),
+                               *si.select_threshold(jnp.asarray(scores),
+                                                    k))))
+    thr, tie = si.select_threshold(jnp.asarray(scores), k)
+    got = np.asarray(si.selected(jnp.asarray(scores), thr, tie))
+    seen = scores > -np.inf
+    want = _top_k_mask(scores, k)
+    np.testing.assert_array_equal(got & seen, want & seen)
+    assert (got & seen).sum(-1).tolist() == [
+        min(k, int(n)) for n in seen.sum(-1)]
+    # a row that sees no more than k rows takes all of them
+    assert (got & seen)[2].sum() == min(k, 5)
+
+
+def test_threshold_of_a_batch_of_queries_and_a_width_below_k():
+    rng = np.random.RandomState(0)
+    scores = jnp.asarray(rng.randn(2, 3, 64), jnp.float32)
+    got = si.selected(scores, *si.select_threshold(scores, 16))
+    np.testing.assert_array_equal(np.asarray(got),
+                                  _top_k_mask(np.asarray(scores), 16))
+    # k past the width: everything
+    assert bool(si.selected(scores, *si.select_threshold(scores, 500)).all())
+    rows = np.asarray(si.select_rows(scores[0], 16))
+    np.testing.assert_array_equal(
+        rows, np.asarray(jax.lax.top_k(scores[0], 16)[1]))
+
+
+def _pool_case(rng, lanes, lens, pages, width, dtype=jnp.float32):
+    """A pool of `width`-wide rows, each lane's pages scattered over it,
+    and the rows of each lane in order."""
+    total = 1 + lanes * pages
+    pool = rng.randn(total * PAGE, width).astype(np.float32)
+    perm = 1 + rng.permutation(lanes * pages).reshape(lanes, pages)
+    table = perm.astype(np.int32)
+    slots = (table[:, :, None] * PAGE + np.arange(PAGE)).reshape(lanes, -1)
+    return jnp.asarray(pool, dtype), table, slots
+
+
+@pytest.mark.parametrize("chunk", [1, 8])
+def test_index_scores_kernel_is_the_plain_sum(chunk):
+    rng = np.random.RandomState(1)
+    lanes, heads, dim, pages = 3, 4, 16, 6
+    lens = np.asarray([70, 0, 96])
+    pool, table, slots = _pool_case(rng, lanes, lens, pages, dim)
+    q = jnp.asarray(rng.randn(lanes, chunk, heads, dim), jnp.float32)
+    w = jnp.asarray(rng.randn(lanes, chunk, heads), jnp.float32)
+    q_pos = np.maximum(lens[:, None] - chunk + np.arange(chunk), 0)
+    got = np.asarray(si.index_scores(
+        q, w, pool, jnp.asarray(table), jnp.asarray(lens),
+        jnp.asarray(q_pos), page_size=PAGE))
+    keys = np.asarray(pool)[slots]                       # [B, L, d]
+    dots = np.einsum("bsjd,bld->bsjl", np.asarray(q), keys)
+    want = (np.maximum(dots, 0) * np.asarray(w)[..., None]).sum(2)
+    pos = np.arange(pages * PAGE)
+    seen = (pos[None, None] <= q_pos[..., None]) \
+        & (pos[None, None] < lens[:, None, None])
+    assert got.shape == (lanes, chunk, pages * PAGE)
+    assert np.all(got[~seen] == -np.inf)
+    np.testing.assert_allclose(got[seen], want[seen], rtol=1e-5, atol=1e-5)
+
+
+def _plain(q, rows, mask, value_width, scale):
+    """softmax over the masked rows of q [S, H, W] . rows [L, W]."""
+    s = np.einsum("shw,lw->shl", q, rows) * scale
+    s = np.where(mask[:, None, :], s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    p = p / p.sum(-1, keepdims=True)
+    return np.einsum("shl,lv->shv", p, rows[:, :value_width])
+
+
+def test_masked_chunk_kernel_is_plain_attention_over_the_selected_rows():
+    rng = np.random.RandomState(2)
+    lanes, chunk, heads, width, vw, pages, k = 2, 8, 4, 128, 32, 40, 48
+    lens = np.asarray([600, 130])
+    pool, table, slots = _pool_case(rng, lanes, lens, pages, width)
+    q = rng.randn(lanes, chunk, heads, width).astype(np.float32) * 0.3
+    q_pos = lens[:, None] - chunk + np.arange(chunk)
+    marks = rng.randn(lanes, chunk, pages * PAGE).astype(np.float32)
+    marks[0, :, 7] = marks[0, :, 300] = marks[0, :, 301] = 0.25   # ties
+    pos = np.arange(pages * PAGE)
+    seen = (pos[None, None] <= q_pos[..., None]) \
+        & (pos[None, None] < lens[:, None, None])
+    marks = np.where(seen, marks, -np.inf)
+    thr, tie = si.select_threshold(jnp.asarray(marks), k)
+    ctx = np.where(pos[None] < lens[:, None], slots, 0)
+    got = np.asarray(la.latent_chunk_attention(
+        jnp.asarray(q), pool, jnp.asarray(ctx), None,
+        jnp.asarray(pos[None] < lens[:, None]), jnp.asarray(q_pos),
+        page_size=PAGE, value_width=vw, scale=0.1,
+        select=(jnp.asarray(marks), thr, tie)))
+    chosen = _top_k_mask(marks, k) & seen
+    assert chosen.sum(-1).tolist() == [[k] * chunk] * lanes
+    for b in range(lanes):
+        want = _plain(q[b], np.asarray(pool)[slots[b]], chosen[b], vw, 0.1)
+        np.testing.assert_allclose(got[b], want, rtol=2e-4, atol=2e-5)
+    # without a selection the same call reads every visible row
+    dense = np.asarray(la.latent_chunk_attention(
+        jnp.asarray(q), pool, jnp.asarray(ctx), None,
+        jnp.asarray(pos[None] < lens[:, None]), jnp.asarray(q_pos),
+        page_size=PAGE, value_width=vw, scale=0.1))
+    want = _plain(q[0], np.asarray(pool)[slots[0]], seen[0], vw, 0.1)
+    np.testing.assert_allclose(dense[0], want, rtol=2e-4, atol=2e-5)
+    assert np.abs(dense - got).max() > 1e-3
+
+
+def test_gather_then_decode_is_plain_attention_over_the_selected_rows():
+    rng = np.random.RandomState(3)
+    lanes, heads, width, vw, pages, k = 3, 4, 128, 32, 12, 32
+    lens = np.asarray([150, 20, 0])
+    pool, table, slots = _pool_case(rng, lanes, lens, pages, width)
+    q = rng.randn(lanes, 1, heads, width).astype(np.float32) * 0.3
+    pos = np.arange(pages * PAGE)
+    marks = np.where(pos[None] < lens[:, None],
+                     rng.randn(lanes, pages * PAGE), -np.inf
+                     ).astype(np.float32)
+    at = si.select_rows(jnp.asarray(marks), k)
+    rows = si.gather_rows(pool, jnp.asarray(table), at, page_size=PAGE)
+    assert rows.shape == (lanes * k, width)
+    got = np.asarray(la.latent_paged_attention(
+        jnp.asarray(q), rows,
+        jnp.arange(lanes * k // PAGE, dtype=jnp.int32).reshape(lanes, -1),
+        jnp.minimum(jnp.asarray(lens), k), page_size=PAGE, value_width=vw,
+        scale=0.1))
+    chosen = _top_k_mask(marks, k) & (marks > -np.inf)
+    assert chosen.sum(-1).tolist() == [k, 20, 0]
+    for b in range(2):
+        want = _plain(q[b], np.asarray(pool)[slots[b]], chosen[b][None],
+                      vw, 0.1)
+        np.testing.assert_allclose(got[b], want, rtol=2e-4, atol=2e-5)
+    assert not got[2].any()          # an empty lane: zeros

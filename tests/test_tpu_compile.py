@@ -290,6 +290,116 @@ def test_latent_prefill_compiles_at_the_cells_shapes(one_chip, lanes, width):
     assert la._PREFILL_VMEM_BYTES < 128 << 20
 
 
+# ------------------------------------- the sparse-attention cell's kernels
+# benchmarks/configs/glm-5-serve.json: 64 heads over a latent row of 512 +
+# 64 (a pool 640 wide) and an index key of 128 scored by 32 index heads,
+# 2,048 rows selected of contexts to 32,768; 16 decode lanes, 8 prefill
+# lanes of 64 queries
+
+
+def _glm_pools(lanes=16, positions=32768):
+    from ray_tpu.models.cache import latent_row_width
+
+    slots = (1 + lanes * (positions // PAGE)) * PAGE
+    return slots, latent_row_width(512 + 64)
+
+
+@pytest.mark.parametrize("lanes,chunk,pages", [
+    (8, 64, 256), (8, 64, 1024), (8, 64, 2048), (16, 1, 256), (16, 1, 2048)],
+    ids=["prefill-4096", "prefill-16384", "prefill-32768", "decode-256",
+         "decode-2048"])
+def test_index_scores_compile_at_the_cells_shapes(one_chip, lanes, chunk,
+                                                  pages):
+    from ray_tpu.ops import sparse_index as si
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    slots, _row = _glm_pools()
+    compiled = jax.jit(
+        lambda q, w, pool, table, lens, q_pos: si.index_scores(
+            q, w, pool, table, lens, q_pos, page_size=PAGE, interpret=False)
+    ).lower(spec((lanes, chunk, 32, 128), jnp.bfloat16),
+            spec((lanes, chunk, 32), jnp.float32),
+            spec((slots, 128), jnp.bfloat16),
+            spec((lanes, pages), jnp.int32), spec((lanes,), jnp.int32),
+            spec((lanes, chunk), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "sparse_index_scores" in text
+    # float32 scores of every position of the table's width
+    assert f"f32[{lanes},{chunk},{pages * PAGE}]" in text
+
+
+@pytest.mark.parametrize("width", [4096, 16384, 32768])
+def test_selecting_prefill_kernel_compiles_at_the_cells_shapes(one_chip,
+                                                               width):
+    """The chunk kernel with a selection: the scores a block at a time
+    beside the pages, thresholds and ties by query, at 64 heads."""
+    from ray_tpu.ops import latent_attention as la
+    from ray_tpu.ops import sparse_index as si
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lanes, chunk, heads = 8, 64, 64
+    slots, row = _glm_pools()
+
+    def call(q, pool, ctx, ctx_mask, q_pos, marks):
+        return la.latent_chunk_attention(
+            q, pool, ctx, None, ctx_mask, q_pos, page_size=PAGE,
+            value_width=512, scale=256 ** -0.5, interpret=False,
+            select=(marks, *si.select_threshold(marks, 2048)))
+
+    compiled = jax.jit(call).lower(
+        spec((lanes, chunk, heads, row), jnp.bfloat16),
+        spec((slots, row), jnp.bfloat16), spec((lanes, width), jnp.int32),
+        spec((lanes, width), jnp.bool_), spec((lanes, chunk), jnp.int32),
+        spec((lanes, chunk, width), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "latent_attention_prefill" in text
+    assert "latent_attention_decode" not in text
+    # held to the VMEM the call asks for, less what the scores' buffer
+    # and its semaphores take of it
+    import re
+
+    sizes = [int(n) for n in re.findall(
+        r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"0",'
+        r'"size":"(\d+)"', text)]
+    assert sizes and 0 < max(sizes) <= la._PREFILL_VMEM_BYTES
+
+
+def test_decode_over_gathered_rows_compiles_at_the_cells_shapes(one_chip):
+    """16 lanes' 2,048 selected rows, gathered a lane after a lane, under
+    an identity table of 128 pages a lane: the decode kernel at 64
+    heads."""
+    from ray_tpu.ops import latent_attention as la
+    from ray_tpu.ops import sparse_index as si
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lanes, heads, k = 16, 64, 2048
+    slots, row = _glm_pools()
+
+    def call(q, pool, table, lens, marks):
+        at = si.select_rows(marks, k)
+        rows = si.gather_rows(pool, table, at, page_size=PAGE)
+        return la.latent_paged_attention(
+            q, rows, jnp.arange(lanes * k // PAGE, dtype=jnp.int32
+                                ).reshape(lanes, k // PAGE),
+            jnp.minimum(lens, k), page_size=PAGE, value_width=512,
+            scale=256 ** -0.5, interpret=False)
+
+    compiled = jax.jit(call).lower(
+        spec((lanes, 1, heads, row), jnp.bfloat16),
+        spec((slots, row), jnp.bfloat16), spec((lanes, 2048), jnp.int32),
+        spec((lanes,), jnp.int32), spec((lanes, 32768), jnp.float32)
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "latent_attention_decode" in text
+    assert f"bf16[{lanes * k},{row}]" in text      # the gathered pool
+
+
 # benchmarks/configs/granite-4.0-h-micro-serve.json: 64 lanes; a state
 # pool of 65 slots of 64 heads x 64 x 128 float32 a state layer, updated
 # in place by slot; 32 query heads of 64 over 8 KV heads, which the cache
@@ -358,7 +468,7 @@ def _serving_config(name):
     import json
     import os
 
-    from benchmarks.kinds import serve_laguna, serve_pangu
+    from benchmarks.kinds import serve_glm, serve_laguna, serve_pangu
     from benchmarks.model_math import llama_kwargs
 
     path = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
@@ -366,14 +476,15 @@ def _serving_config(name):
     with open(path) as f:
         cfg = json.load(f)
     model_kwargs = {"laguna": serve_laguna.model_kwargs,
-                    "pangu_ultra_moe": serve_pangu.model_kwargs}.get(
+                    "pangu_ultra_moe": serve_pangu.model_kwargs,
+                    "glm_moe_dsa": serve_glm.model_kwargs}.get(
                         cfg["model_type"], llama_kwargs)
     return model_kwargs(cfg), cfg["deployment"]["engine"]["max_batch"]
 
 
 @pytest.mark.parametrize("name", [
     "mistral-7b-v0.3-serve", "laguna-s-2.1-serve",
-    "openpangu-ultra-moe-718b-serve"])
+    "openpangu-ultra-moe-718b-serve", "glm-5-serve"])
 def test_narrow_prefill_program_compiles_at_the_cells_shapes(
         one_chip, monkeypatch, name):
     import numpy as np
@@ -428,5 +539,6 @@ def test_narrow_prefill_program_compiles_at_the_cells_shapes(
     mem = lowered.compile().memory_analysis()
     # weights and pools as the cell holds them, and beside them what 2 x
     # 64 slots need: 38 to 56 MiB where the 8-lane pass plans 108 to 267
+    # (the sparse configuration's 1024 columns take the dense path)
     assert 0 < mem.temp_size_in_bytes < 2 ** 27
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
