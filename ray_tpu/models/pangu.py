@@ -265,10 +265,13 @@ def _pairs(x: jax.Array, dim: int, interleaved: bool) -> jax.Array:
 # what the sparse setting counts on the device, a layer a pass (query x
 # visible-key pairs its indexer scored; rows its queries could see and
 # rows their attention read; rows its decode lanes gathered; queries
-# that saw no more than `index_topk` rows)
+# that saw no more than `index_topk` rows; pages of index keys its
+# indexer's kernel copied: those that hold a position a lane's queries
+# see, of the lanes x table width a gather of the table would fetch)
 SPARSE_COUNTERS = ("sparse_index_pairs_total", "sparse_rows_visible_total",
                    "sparse_rows_selected_total", "sparse_decode_rows_total",
-                   "sparse_dense_queries_total")
+                   "sparse_dense_queries_total",
+                   "sparse_index_pages_read_total")
 
 
 # ----------------------------------------------------------------- modules
@@ -425,7 +428,7 @@ class LatentAttention(nn.Module):
         # the tokens' index keys go in before their queries score them
         index = cache["index"].at[cache["slots"].reshape(-1)].set(
             ik.reshape(b * s, -1))
-        scored = gathered = 0
+        scored = gathered = pages = 0
         tables, lens = cache.get("block_tables"), cache.get("context_lens")
         if tables is not None:
             visible = lens[:, None]
@@ -442,6 +445,7 @@ class LatentAttention(nn.Module):
                     pool = si.gather_rows(pool, tables, at, page_size=ps)
                 tables = jnp.arange(b * top_k // ps, dtype=jnp.int32
                                     ).reshape(b, top_k // ps)
+                pages = jnp.sum(si.pages_read(lens, positions, ps))
                 lens = jnp.minimum(lens, top_k)
                 scored, gathered = jnp.sum(visible), read
             out = la.latent_paged_attention(q_row, pool, tables, lens,
@@ -463,6 +467,7 @@ class LatentAttention(nn.Module):
                     select = (marks, *si.select_threshold(marks, top_k))
                     picked = si.selected(*select) & (marks > -jnp.inf)
                 scored = jnp.sum(visible)
+                pages = jnp.sum(si.pages_read(lens, positions, ps))
                 read = jnp.sum(jnp.where(real[..., None], picked, False),
                                dtype=jnp.int32)
             out = la.latent_chunk_attention(
@@ -471,7 +476,7 @@ class LatentAttention(nn.Module):
         return out, index, jnp.stack([
             jnp.asarray(v, jnp.int32) for v in
             (scored, jnp.sum(visible), read, gathered,
-             jnp.sum((visible > 0) & (visible <= top_k)))])
+             jnp.sum((visible > 0) & (visible <= top_k)), pages)])
 
 
 class PanguBlock(nn.Module):

@@ -14,9 +14,12 @@ them while it sees no more than that); a tie goes to the lower position.
     (a chunk's, or a decode lane's one) against the lane's pages of the
     index pool, a block of keys a grid step; what a query does not see
     (past its own position, past the lane's length) reads -inf.  The
-    lane's index rows are gathered by PAGE first (`[pages, page_size x
-    d]` rows of 4 KB): the plain form; the kernel could walk the table
-    itself as the attention kernels do.
+    pool stays where it lies, `[T, d]` in HBM: the kernel walks the
+    lane's block table itself as the attention kernels do
+    (`latent_attention._page_copies`: a block's pages copied into one
+    half of a double buffer while the other half is scored), and a
+    block past the last position any of the lane's queries sees copies
+    nothing.
 `select_threshold`  a chunk's selection as a THRESHOLD a query, exact
     and without a sort: the `top_k`-th largest score, by bisection over
     the order-preserving integer image of float32 (32 counts), and the
@@ -40,40 +43,83 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-# keys a grid step of the score kernel covers: at 32 heads x 64 queries
-# the float32 products of a block are 4 MB
+# keys a grid step of the score kernel covers, by what the call scores a
+# lane: at 32 heads x 64 queries the float32 products of 512 keys are 4
+# MB; one query a lane has products of 32 rows, and its step is as wide
+# as its page copies pay for (256 of them, 21 ns a page unrolled; on the
+# v5e at the cell's shapes 4096 beats 2048 by 2-11 % and 1024 by 10-19 %
+# below 30k rows a lane: PERF.md, PR 43)
 _SCORE_BLOCK_KEYS = 512
+_SCORE_BLOCK_KEYS_ONE_QUERY = 4096
+# what the kernel may take of VMEM: the compiler's own allowance
+_SCORE_VMEM_BYTES = 16 << 20
 
 
-def _scores_kernel(n_ref, q_ref, w_ref, qpos_ref, k_ref, o_ref, *,
-                   heads: int, chunk: int, keys: int):
+def _score_block_pages(chunk: int, table_width: int, page_size: int) -> int:
+    """Pages a grid step covers: the most that divide the table and keep
+    the step within its block of keys."""
+    keys = _SCORE_BLOCK_KEYS_ONE_QUERY if chunk == 1 else _SCORE_BLOCK_KEYS
+    pages = max(1, min(table_width, keys // page_size))
+    while table_width % pages:
+        pages -= 1
+    return pages
+
+
+def _scores_kernel(bt_ref, n_ref, q_ref, w_ref, qpos_ref, pool_hbm, o_ref,
+                   buf, sem, *, heads: int, chunk: int, page_size: int,
+                   pages: int):
     """q [1, J x S, d] head-major; w [1, J x S, 1] float32; qpos [1, S,
-    1]; k [1, keys, d]: a block of the lane's index rows; o [1, S, keys]
-    float32.  `n_ref` [B]: the lane's length."""
+    1]; the pool `[num_pages, page_size, d]` in HBM; o [1, S, keys]
+    float32: a block of `pages` pages of the lane's positions; `buf` [2,
+    pages, page_size, d] and its DMA semaphores [2] (a buffer half
+    each).  `bt_ref` [B, P]: the lanes' pages; `n_ref` [B]: how far the
+    lane's queries see (no further than its length)."""
     from jax.experimental import pallas as pl
 
-    b = pl.program_id(0)
-    start = pl.program_id(1) * keys
-    n = n_ref[b]
+    from ray_tpu.ops.latent_attention import _page_copies
 
-    @pl.when(start < n)
+    b = pl.program_id(0)
+    ki = pl.program_id(1)
+    n = n_ref[b]
+    keys = pages * page_size
+    # never past the table, whatever the lengths say
+    used = jnp.minimum((n + page_size - 1) // page_size, bt_ref.shape[1])
+    blocks = (used + pages - 1) // pages
+    # (unrolled: a copy's scalar work in a loop is twice the time)
+    fetch, wait = _page_copies(bt_ref, pool_hbm, buf, sem, b, used, pages,
+                               unroll=True)
+
+    @pl.when(ki < blocks)
     def _live():
+        half = ki % 2
+
+        @pl.when(ki == 0)
+        def _first():
+            fetch(0, 0)
+
+        @pl.when(ki + 1 < blocks)
+        def _next():
+            fetch(ki + 1, 1 - half)
+
+        wait(half)
+        rows = buf[half].reshape(keys, buf.shape[-1])    # [keys, d]
         s = jax.lax.dot_general(
-            q_ref[0], k_ref[0], dimension_numbers=(((1,), (1,)), ((), ())),
+            q_ref[0], rows, dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)          # [J x S, keys]
         s = jnp.maximum(s, 0.0) * w_ref[0]
         if chunk == 1:
             total = jnp.sum(s, axis=0, keepdims=True)    # [1, keys]
         else:
             total = jnp.sum(s.reshape(heads, chunk, keys), axis=0)
-        pos = start + jax.lax.broadcasted_iota(jnp.int32, (1, keys), 1)
+        pos = ki * keys + jax.lax.broadcasted_iota(jnp.int32, (1, keys), 1)
+        # (the block's pages past the lane's last are that page again)
         seen = (pos <= qpos_ref[0]) & (pos < n)          # [S, keys]
         # (a sum of -0.0's is -0.0, which a sort puts under +0.0 and a
         # comparison does not: one zero leaves here)
         total = jnp.where(total == 0.0, 0.0, total)
         o_ref[0] = jnp.where(seen, total, -jnp.inf)
 
-    @pl.when(start >= n)
+    @pl.when(ki >= blocks)
     def _dead():
         o_ref[0] = jnp.full(o_ref.shape[1:], -jnp.inf, o_ref.dtype)
 
@@ -95,6 +141,20 @@ def index_scores(q: jax.Array, w: jax.Array, pool: jax.Array,
                         interpret=interpret_default(interpret))
 
 
+def _seen(lens: jax.Array, q_pos: jax.Array) -> jax.Array:
+    """[B] int32: how far a lane's queries see — the positions below its
+    length up to the last query's own."""
+    return jnp.minimum(lens.astype(jnp.int32),
+                       jnp.max(q_pos.astype(jnp.int32), axis=-1) + 1)
+
+
+def pages_read(lens: jax.Array, q_pos: jax.Array, page_size: int
+               ) -> jax.Array:
+    """[B] int32: the pages of a lane that `index_scores` copies — those
+    that hold a position one of the lane's queries sees."""
+    return (_seen(lens, q_pos) + page_size - 1) // page_size
+
+
 @functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
 def _scores_call(q, w, pool, table, lens, q_pos, *, page_size: int,
                  interpret: bool):
@@ -102,39 +162,42 @@ def _scores_call(q, w, pool, table, lens, q_pos, *, page_size: int,
     from jax.experimental.pallas import tpu as pltpu
 
     b, s, j, d = q.shape
-    pages = table.shape[1]
-    width = pages * page_size
-    keys = min(_SCORE_BLOCK_KEYS, width)
-    assert width % keys == 0, (width, keys)
-    rows = pool.reshape(pool.shape[0] // page_size, page_size * d)[
-        table.astype(jnp.int32)].reshape(b, width, d)
+    width = table.shape[1]
+    pages = _score_block_pages(s, width, page_size)
+    keys = pages * page_size
 
     def _lane(bi, ki, *_scalars):
         return (bi, 0, 0)
-
-    def _block(bi, ki, *_scalars):
-        return (bi, ki, 0)
 
     def _out(bi, ki, *_scalars):
         return (bi, 0, ki)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, width // keys),
+        num_scalar_prefetch=2,
+        grid=(b, width // pages),
         in_specs=[pl.BlockSpec((1, j * s, d), _lane),
                   pl.BlockSpec((1, j * s, 1), _lane),
                   pl.BlockSpec((1, s, 1), _lane),
-                  pl.BlockSpec((1, keys, d), _block)],
-        out_specs=pl.BlockSpec((1, s, keys), _out))
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, s, keys), _out),
+        scratch_shapes=[pltpu.VMEM((2, pages, page_size, d), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,))])
     return pl.pallas_call(
-        functools.partial(_scores_kernel, heads=j, chunk=s, keys=keys),
-        out_shape=jax.ShapeDtypeStruct((b, s, width), jnp.float32),
+        functools.partial(_scores_kernel, heads=j, chunk=s,
+                          page_size=page_size, pages=pages),
+        out_shape=jax.ShapeDtypeStruct((b, s, width * page_size),
+                                       jnp.float32),
         grid_spec=grid_spec, interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_SCORE_VMEM_BYTES),
         name="sparse_index_scores",
-    )(lens.astype(jnp.int32),
+    # (the mask `pos < lens` under `pos <= q_pos` is `pos < _seen`)
+    )(table.astype(jnp.int32), _seen(lens, q_pos),
       q.transpose(0, 2, 1, 3).reshape(b, j * s, d),
       w.astype(jnp.float32).transpose(0, 2, 1).reshape(b, j * s, 1),
-      q_pos.astype(jnp.int32)[..., None], rows)
+      q_pos.astype(jnp.int32)[..., None],
+      # a split of the major dimension: a page is a tile, nothing moves
+      pool.reshape(pool.shape[0] // page_size, page_size, d))
 
 
 def plain_scores(q: jax.Array, w: jax.Array, k: jax.Array) -> jax.Array:

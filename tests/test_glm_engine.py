@@ -66,7 +66,7 @@ def test_widths_on_both_sides_of_index_topk(alone):
     # decode: a table of 4 pages is dense, 16 and 32 gather
     assert alone._prefill_widths == [64, 256, 512]
     assert alone._paged_width_buckets() == [4, 16, 32]
-    assert alone._model.counters[-5:] == SPARSE_COUNTERS
+    assert alone._model.counters[-6:] == SPARSE_COUNTERS
 
 
 def test_both_parts_of_a_page_are_shared_split_and_shipped(alone):

@@ -152,9 +152,12 @@ def test_chunked_prefill_then_decode_is_the_references_every_position(
     layers = CFG.num_hidden_layers
     visible = sum(range(1, n_prefill + 1))
     read = sum(min(t, 64) for t in range(1, n_prefill + 1))
+    # (the index pages copied: those up to a scored chunk's last row)
     assert counted.tolist() == [
         layers * sum(range(65, n_prefill + 1)), layers * visible,
-        layers * read, 0, layers * 64]
+        layers * read, 0, layers * 64,
+        layers * sum(-(-min(lo + chunk, n_prefill) // PAGE)
+                     for lo in range(64, n_prefill, chunk))]
     table = np.zeros((1, 16), np.int32)
     table[0, :15] = np.arange(1, 16)
     for n in range(n_prefill, len(TOKENS)):
@@ -165,7 +168,8 @@ def test_chunked_prefill_then_decode_is_the_references_every_position(
             TOKENS[None, n:n + 1])
         got.append(np.asarray(logits[0]))
         assert np.asarray(vec[-len(SPARSE_COUNTERS):]).tolist() == [
-            layers * (n + 1), layers * (n + 1), layers * 64, layers * 64, 0]
+            layers * (n + 1), layers * (n + 1), layers * 64, layers * 64, 0,
+            layers * -(-(n + 1) // PAGE)]
     np.testing.assert_allclose(np.concatenate(got), want[0], atol=3e-4)
     assert sorted(pools) == ["index", "latent"]
 

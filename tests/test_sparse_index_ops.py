@@ -107,6 +107,120 @@ def test_index_scores_kernel_is_the_plain_sum(chunk):
     np.testing.assert_allclose(got[seen], want[seen], rtol=1e-5, atol=1e-5)
 
 
+def _gathered_scores(q, w, pool, table, lens, q_pos):
+    """The parent's form (PR 42): a lane's index rows gathered by page
+    into a copy, `[pages, page_size x d]` rows, and the same sum."""
+    b, s, j, d = q.shape
+    rows = pool.reshape(-1, PAGE * d)[table].reshape(b, -1, d)
+    dots = jnp.einsum("bsjd,bld->bjsl", q, rows,
+                      preferred_element_type=jnp.float32)
+    total = jnp.sum(jnp.maximum(dots, 0.0)
+                    * w.transpose(0, 2, 1)[..., None], axis=1)
+    total = jnp.where(total == 0.0, 0.0, total)
+    pos = jnp.arange(rows.shape[1])
+    seen = (pos <= q_pos[..., None]) & (pos < lens[:, None, None])
+    return jnp.where(seen, total, -jnp.inf)
+
+
+def _small_integers(rng, *shape):
+    """Whole numbers of -3..3: their products and sums are exact in
+    float32 in whatever order a backend takes them, so two forms that
+    read the same rows agree to the bit."""
+    return rng.randint(-3, 4, shape).astype(np.float32)
+
+
+TABLES = ["falling", "shuffled", "shared_page", "empty_lane", "mid_block",
+          "dead_zero", "dead_garbage", "q_behind"]
+
+
+def _table_case(case, chunk, rng):
+    """Three lanes over a table two blocks of the kernel's walk wide:
+    (pool, table, lens, q_pos) with the pages of `case`.  By default a
+    lane's pages are shuffled, its dead entries 0, its queries the last
+    `chunk` positions of its length; lane 0 ends mid-page in the second
+    block, lane 1 four pages and three rows past its chunk, lane 2 a row
+    short of the first block's end."""
+    lanes, dim = 3, 32
+    pages = 2 * si._score_block_pages(chunk, 1 << 20, PAGE)
+    block = pages * PAGE // 2
+    short = chunk + 4 * PAGE + 3
+    lens = np.asarray([block + 5 * PAGE + 7, short, block - 1])
+    if case == "empty_lane":
+        lens[1] = 0
+    if case in ("dead_zero", "dead_garbage"):
+        # a table twice as wide as its live part
+        lens = np.asarray([block - 9, short, block // 2 + 3])
+    poison = 1 + lanes * pages + np.arange(8)
+    pool = _small_integers(
+        rng, (1 + lanes * pages + len(poison)) * PAGE, dim)
+    used = -(-lens // PAGE)
+    table = np.zeros((lanes, pages), np.int32)
+    for b in range(lanes):
+        own = 1 + b * pages + np.arange(used[b])
+        table[b, :used[b]] = own[::-1] if case == "falling" \
+            else rng.permutation(own)
+    if case == "shared_page":
+        # a shared prefix: lane 1's first two pages are lane 0's
+        table[1, :2] = table[0, :2]
+    if case == "dead_garbage":
+        # in range and never read: those pages hold NaN
+        pool[(poison[:, None] * PAGE + np.arange(PAGE)).reshape(-1)] = np.nan
+        for b in range(lanes):
+            table[b, used[b]:] = rng.choice(poison, pages - used[b])
+    behind = 40 if case == "q_behind" else 0
+    q_pos = np.maximum(
+        lens[:, None] - behind - chunk + np.arange(chunk), 0)
+    return (jnp.asarray(pool), jnp.asarray(table), jnp.asarray(lens),
+            jnp.asarray(q_pos, jnp.int32))
+
+
+@pytest.mark.parametrize("case", TABLES)
+@pytest.mark.parametrize("chunk", [1, 64])
+def test_in_place_kernel_is_the_gathered_form_bit_for_bit(chunk, case):
+    """The kernel that walks the table against the parent's gathered
+    copy (bit for bit: a key's score does not depend on the block that
+    held it) and against `plain_scores` over the lane's rows in order."""
+    rng = np.random.RandomState(TABLES.index(case))
+    heads = 4
+    pool, table, lens, q_pos = _table_case(case, chunk, rng)
+    lanes, width = table.shape[0], table.shape[1] * PAGE
+    q = jnp.asarray(_small_integers(rng, lanes, chunk, heads, pool.shape[1]))
+    w = jnp.asarray(_small_integers(rng, lanes, chunk, heads) / 8)
+    got = np.asarray(si.index_scores(q, w, pool, table, lens, q_pos,
+                                     page_size=PAGE))
+    assert got.shape == (lanes, chunk, width) and not np.isnan(got).any()
+    np.testing.assert_array_equal(
+        got, np.asarray(_gathered_scores(q, w, pool, table, lens, q_pos)))
+    pos = np.arange(width)
+    seen = (pos <= np.asarray(q_pos)[..., None]) \
+        & (pos < np.asarray(lens)[:, None, None])
+    assert np.all(got[~seen] == -np.inf) and np.all(got[seen] > -np.inf)
+    # the plain form: a lane's rows in order as one sequence, the
+    # chunk's queries at their own positions of it
+    reach = int(np.asarray(lens).max())
+    slots = (np.asarray(table)[:, :, None] * PAGE
+             + np.arange(PAGE)).reshape(lanes, -1)[:, :reach]
+    for b in range(lanes):
+        if not int(lens[b]):
+            continue
+        at = np.asarray(q_pos)[b]
+        full_q = jnp.zeros((1, reach, heads, pool.shape[1]), jnp.float32
+                           ).at[0, at].set(q[b])
+        full_w = jnp.zeros((1, reach, heads), jnp.float32).at[0, at].set(w[b])
+        want = np.asarray(si.plain_scores(
+            full_q, full_w, jnp.nan_to_num(pool)[slots[b]][None]))[0, at]
+        mine = seen[b][:, :reach].nonzero()
+        np.testing.assert_array_equal(got[b][mine], want[mine])
+
+
+def test_pages_read_are_those_a_lanes_queries_see():
+    lens = jnp.asarray([0, 1, 16, 17, 100, 100])
+    q_pos = jnp.asarray([[0, 0], [0, 0], [14, 15], [15, 16], [98, 99],
+                         [30, 31]])
+    assert np.asarray(si.pages_read(lens, q_pos, PAGE)).tolist() \
+        == [0, 1, 1, 2, 7, 2]
+
+
 def _plain(q, rows, mask, value_width, scale):
     """softmax over the masked rows of q [S, H, W] . rows [L, W]."""
     s = np.einsum("shw,lw->shl", q, rows) * scale

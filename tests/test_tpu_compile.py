@@ -304,30 +304,75 @@ def _glm_pools(lanes=16, positions=32768):
     return slots, latent_row_width(512 + 64)
 
 
-@pytest.mark.parametrize("lanes,chunk,pages", [
+_INDEX_SHAPES = pytest.mark.parametrize("lanes,chunk,pages", [
     (8, 64, 256), (8, 64, 1024), (8, 64, 2048), (16, 1, 256), (16, 1, 2048)],
     ids=["prefill-4096", "prefill-16384", "prefill-32768", "decode-256",
          "decode-2048"])
-def test_index_scores_compile_at_the_cells_shapes(one_chip, lanes, chunk,
-                                                  pages):
+
+
+@pytest.fixture(scope="module")
+def index_scores_text(one_chip):
+    """The compiled text of `index_scores` at one of the cell's shapes,
+    compiled once for the tests that read it."""
+    import functools
+
     from ray_tpu.ops import sparse_index as si
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    slots, _row = _glm_pools()
-    compiled = jax.jit(
-        lambda q, w, pool, table, lens, q_pos: si.index_scores(
-            q, w, pool, table, lens, q_pos, page_size=PAGE, interpret=False)
-    ).lower(spec((lanes, chunk, 32, 128), jnp.bfloat16),
-            spec((lanes, chunk, 32), jnp.float32),
-            spec((slots, 128), jnp.bfloat16),
-            spec((lanes, pages), jnp.int32), spec((lanes,), jnp.int32),
-            spec((lanes, chunk), jnp.int32)).compile()
-    text = compiled.as_text()
+    @functools.cache
+    def text(lanes, chunk, pages):
+        slots, _row = _glm_pools()
+        return jax.jit(
+            lambda q, w, pool, table, lens, q_pos: si.index_scores(
+                q, w, pool, table, lens, q_pos, page_size=PAGE,
+                interpret=False)
+        ).lower(spec((lanes, chunk, 32, 128), jnp.bfloat16),
+                spec((lanes, chunk, 32), jnp.float32),
+                spec((slots, 128), jnp.bfloat16),
+                spec((lanes, pages), jnp.int32), spec((lanes,), jnp.int32),
+                spec((lanes, chunk), jnp.int32)).compile().as_text()
+
+    return text
+
+
+def _scoped_vmem(text):
+    """The scoped VMEM, in bytes, of every kernel of a compiled text."""
+    import re
+
+    return [int(n) for n in re.findall(
+        r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"0",'
+        r'"size":"(\d+)"', text)]
+
+
+@_INDEX_SHAPES
+def test_index_scores_compile_at_the_cells_shapes(index_scores_text, lanes,
+                                                  chunk, pages):
+    text = index_scores_text(lanes, chunk, pages)
     assert "tpu_custom_call" in text and "sparse_index_scores" in text
     # float32 scores of every position of the table's width
     assert f"f32[{lanes},{chunk},{pages * PAGE}]" in text
+
+
+@_INDEX_SHAPES
+def test_index_scores_read_the_pool_where_it_lies(index_scores_text, lanes,
+                                                  chunk, pages):
+    """The kernel walks the table itself: the program holds no copy of
+    the pool relaid a page a row, none of the table's pages gathered,
+    and the kernel's double buffer of pages fits the VMEM the module
+    states."""
+    from ray_tpu.ops import sparse_index as si
+
+    text = index_scores_text(lanes, chunk, pages)
+    slots, _row = _glm_pools()
+    assert f"bf16[{slots // PAGE},{PAGE},128]" in text   # a page a tile
+    for copy in (f"bf16[{slots // PAGE},{PAGE * 128}]",
+                 f"bf16[{lanes * pages},",
+                 f"bf16[{lanes},{pages * PAGE},128]"):
+        assert copy not in text, copy
+    sizes = _scoped_vmem(text)
+    assert sizes and 0 < max(sizes) <= si._SCORE_VMEM_BYTES
 
 
 @pytest.mark.parametrize("width", [4096, 16384, 32768])
@@ -360,11 +405,7 @@ def test_selecting_prefill_kernel_compiles_at_the_cells_shapes(one_chip,
     assert "latent_attention_decode" not in text
     # held to the VMEM the call asks for, less what the scores' buffer
     # and its semaphores take of it
-    import re
-
-    sizes = [int(n) for n in re.findall(
-        r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"0",'
-        r'"size":"(\d+)"', text)]
+    sizes = _scoped_vmem(text)
     assert sizes and 0 < max(sizes) <= la._PREFILL_VMEM_BYTES
 
 
