@@ -11,19 +11,29 @@ is simply not there: no code stands in for the exchange).
                probabilities divided by their sum           (moe_router)
     dispatch   the (token, expert) assignments whose expert is held
                here, grouped by expert; each group padded to whole row
-               tiles so that a tile belongs to one expert  (moe_dispatch)
+               tiles so that a tile belongs to one expert  (moe_dispatch).
+               The ROWS are moved by a Pallas call, `moe_dispatch_rows`:
+               a grid step a row tile copies its tokens' rows out of x
+               by index, HBM to VMEM, and writes the tile
     experts    SwiGLU of every row tile under its expert's matrices, a
                Pallas grouped matmul that visits the ACTIVE tiles only:
                an expert no token chose is never read      (moe_experts)
     combine    each token's rows times their routing weights, summed
-                                                           (moe_combine)
+               (moe_combine) — a Pallas call, `moe_combine_rows`, that
+               walks the row tiles and adds each row into its token's
+               float32 sum, which stays in VMEM meanwhile
 
 No token is dropped and there is no capacity factor: the row buffer is
 as long as the worst case (every assignment held here, every expert's
 group ending in a nearly empty tile), and the grid's unused tiles are
-predicated off and fetch nothing.  So the layer's weight traffic follows
-the routed tokens — a decode step of 16 lanes reads the experts those
-lanes chose, not every expert held.
+predicated off and fetch nothing — in the row kernels as in the grouped
+matmuls: an INACTIVE tile is neither read nor written by the dispatch,
+the combine or their transposes, and of the T x k assignments only
+those held here move a row (a chip that holds 16 of 64 experts fills a
+quarter of its buffer).  So the layer's weight traffic follows the
+routed tokens — a decode step of 16 lanes reads the experts those
+lanes chose, not every expert held — and its row traffic the rows that
+exist.
 
 `valid` masks tokens that are padding (an empty decode lane, the tail of
 a prefill chunk): they are routed nowhere, touch no expert and count in
@@ -35,11 +45,13 @@ tensors, so a training step runs the same `moe_layer` as the engine.
 top-k's chosen probabilities and the softmax).  Dispatch, experts and
 combine are one `jax.custom_vjp` (`_routed`) whose backward is written
 by hand: a row belongs to one assignment, so the transpose of the
-combine's gather is itself a gather (each row takes its token's dy times
-its routing weight) and that of the dispatch's gather a sum of each
-token's k rows — no scatter-add of wide rows.  Between them two Pallas
-calls, named `moe_experts_bwd_dx` and `moe_experts_bwd_dw`, visit the
-active tiles as the forward does: the first recomputes gate and up and
+combine is the dispatch's walk over dy (`moe_combine_rows_bwd`: in one
+visit of an active tile each row takes its token's dy times its routing
+weight, and its dot with the row's output is the weight's gradient) and
+that of the dispatch the combine's over dx's rows with weights of one
+(`moe_combine_rows` again).  Between them two Pallas calls, named
+`moe_experts_bwd_dx` and `moe_experts_bwd_dw`, visit the active tiles
+as the forward does: the first recomputes gate and up and
 gives dh, dgate, dup and dx a row tile; the second accumulates dW1,
 dW3 and dW2 over each expert's row tiles in a block that stays in VMEM
 while the expert lasts.  An expert no token chose is not visited and
@@ -58,8 +70,9 @@ import numpy as np
 
 from ray_tpu.ops import interpret_default
 
-__all__ = ["route", "dispatch", "grouped_swiglu", "combine", "moe_layer",
-           "row_tile", "COUNTERS", "TRAIN_COUNTERS"]
+__all__ = ["route", "dispatch", "dispatch_rows", "grouped_swiglu", "combine",
+           "combine_rows", "combine_rows_bwd", "moe_layer", "row_tile",
+           "COUNTERS", "TRAIN_COUNTERS"]
 
 # what `moe_layer` counts for the engine, in the order of its counter
 # vector; a training step also reads the last two of TRAIN_COUNTERS,
@@ -70,7 +83,8 @@ TRAIN_COUNTERS = COUNTERS + ("row_tiles_active", "row_tiles")
 
 class Dispatch(NamedTuple):
     """Where every held assignment's row lies, and whose every tile is."""
-    row_token: jax.Array     # [R] token of each row (n_tokens = padding)
+    row_assign: jax.Array    # [R] assignment t * k + j of each row (T * k
+                             # = padding); its token is row_assign // k
     dest: jax.Array          # [T, k] row of each assignment (R = not held)
     tile_expert: jax.Array   # [R / tm] local expert of each row tile
     active_tiles: jax.Array  # [] tiles that hold a row
@@ -144,13 +158,13 @@ def dispatch(ids: jax.Array, valid: jax.Array, held: Tuple[int, int],
             starts[safe] + jnp.arange(a, dtype=jnp.int32) - first[safe],
             rows)
         dest = jnp.zeros((a,), jnp.int32).at[order].set(dest_sorted)
-        row_token = jnp.full((rows,), t, jnp.int32).at[dest].set(
-            jnp.arange(a, dtype=jnp.int32) // k, mode="drop")
+        row_assign = jnp.full((rows,), a, jnp.int32).at[dest].set(
+            jnp.arange(a, dtype=jnp.int32), mode="drop")
         tile_start = jnp.arange(n_tiles, dtype=jnp.int32) * tm
         tile_expert = jnp.minimum(
             jnp.searchsorted(ends, tile_start, side="right"),
             e - 1).astype(jnp.int32)
-        return Dispatch(row_token, dest.reshape(t, k), tile_expert,
+        return Dispatch(row_assign, dest.reshape(t, k), tile_expert,
                         (ends[-1] // tm).astype(jnp.int32), counts)
 
 
@@ -428,7 +442,8 @@ def combine(y_rows: jax.Array, dest: jax.Array, weights: jax.Array
             ) -> jax.Array:
     """y_rows [R, D]; dest, weights [T, k] -> [T, D] float32: each
     token's held rows times their routing weights, summed.  An
-    assignment that is not held (dest = R) adds nothing."""
+    assignment that is not held (dest = R) adds nothing.  The plain
+    form: a gather of all T x k assignments, held or not."""
     with jax.named_scope("moe_combine"):
         rows = y_rows.shape[0]
         here = dest < rows
@@ -438,11 +453,299 @@ def combine(y_rows: jax.Array, dest: jax.Array, weights: jax.Array
         return jnp.sum(jnp.where(here[..., None], picked, 0.0) * w, axis=1)
 
 
-def _gather_rows(x: jax.Array, row_token: jax.Array) -> jax.Array:
-    """x [T, D] -> [R, D]: each row's token; a padding row (token T)
-    is zeros."""
-    xpad = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])
-    return xpad[row_token]
+def _lanes(d: int) -> int:
+    """Columns of a row's slab: a vector register's 128 lanes, or the
+    whole of a row narrower than that (a toy model's)."""
+    return 128 if d % 128 == 0 else d
+
+
+def _row_slabs(x: jax.Array) -> jax.Array:
+    """x [T, D] -> [T, D / 128, 128] float32: a token's row as a slab of
+    whole tiles, which a copy can address by the token (the chip's
+    compiler refuses a one-row slice of a `[T, D]` array, whose rows lie
+    eight to a tile: "Slice shape along dimension 0 must be aligned to
+    tiling (8), but is 1").  One pass over the T tokens, not the rows."""
+    t, d = x.shape
+    return x.astype(jnp.float32).reshape(t, d // _lanes(d), _lanes(d))
+
+
+def _row_operands(row_assign, weights, active_tiles, n_assign, top_k, tm):
+    """The row kernels' scalar operands, made here so that a kernel's
+    scalar work a row is a load (the copies are issued by the scalar
+    core: a `min` and a shift a row cost a sixth more at Mellum's
+    shapes — 662 against 568 us a call; my chip runs, PR 44): each
+    row's token and, where there are weights, its assignment — a
+    padding row's the last, masked in the kernel — the routing weights
+    by assignment, the rows of each tile that hold a token (the FIRST
+    ones: a group starts on a tile and its padding ends it) and the
+    active tiles."""
+    held = jnp.sum((row_assign < n_assign).reshape(-1, tm), axis=1,
+                   dtype=jnp.int32)
+    assign = jnp.minimum(row_assign, n_assign - 1)
+    token = assign // top_k
+    if weights is None:
+        assign, w = assign[:1], jnp.zeros((1,), jnp.float32)
+    else:
+        w = weights.reshape(-1).astype(jnp.float32)
+    return (token, assign, w, held,
+            jnp.reshape(active_tiles, (1,)).astype(jnp.int32))
+
+
+def _row_map(i, rt, ra, w, nv, na):
+    return (_tile(i, na), 0)
+
+
+# Rows of a tile a row kernel handles as straight-line code
+_ROW_GROUP = 8
+
+
+def _for_rows(tm: int, unroll: bool, body) -> None:
+    """body(r) for the `tm` rows of a tile.  On the chip: a loop over
+    groups of `_ROW_GROUP` rows, a group straight-line code — a copy
+    issued from a loop of single rows costs twice the time (PR 43), and
+    the whole tile unrolled, 128 rows, is dear to TRACE: the Mellum
+    cell's warm `setup_s` went 48 -> 95 s for 1 % more tokens a second
+    than groups of 8 give (52,900-53,330 against 52,440-52,470; 567
+    against 603 us a dispatch call alone; my chip runs, PR 44).  In the
+    interpreter a loop of single rows (unrolled, the tests of every
+    family that runs the layer took half as long again)."""
+    group = min(tm, _ROW_GROUP) if unroll else 1
+
+    def rows(q, carry):
+        for j in range(group):
+            body(q * group + j)
+        return carry
+    jax.lax.fori_loop(0, tm // group, rows, 0)
+
+
+def _gather_kernel(rt_ref, ra_ref, w_ref, nv_ref, na_ref, src_hbm, *refs,
+                   tm: int, backward: bool, unroll: bool):
+    """Grid step i: the slabs of row tile i's tokens copied from
+    `src_hbm` [T, chunks, lanes] into a buffer half — the NEXT active
+    tile's started before this one's are waited for — and written out
+    as rows.  Forward: out [tm, D] = the rows, a padding row zeros.
+    Backward (src = dy): y [tm, D] in; out = dy rows x their routing
+    weights, dot [tm, 1] = sum(dy row x y row) in float32.
+
+    Every row of an active tile copies, a padding row the last token's
+    slab, masked after: a branch a row to spare a group's last few
+    copies cost a third more at Mellum's shapes (765 against 568 us a
+    call; my chip runs, PR 44)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if backward:
+        y_ref, out_ref, dot_ref, buf, sem, wcol = refs
+    else:
+        out_ref, buf, sem = refs
+    i = pl.program_id(0)
+    na = na_ref[0]
+    chunks, lanes = buf.shape[2:]
+
+    def start(tile, half):
+        _for_rows(tm, unroll, lambda r: pltpu.make_async_copy(
+            src_hbm.at[rt_ref[tile * tm + r]], buf.at[half, r],
+            sem.at[half]).start())
+
+    @pl.when(i < na)
+    def _():
+        half = i % 2
+
+        @pl.when(i == 0)
+        def _first():
+            start(0, 0)
+
+        @pl.when(i + 1 < na)
+        def _next():
+            start(i + 1, 1 - half)
+
+        def landed(r):
+            pltpu.make_async_copy(src_hbm.at[0], buf.at[half, r],
+                                  sem.at[half]).wait()
+            if backward:   # the row's routing weight, on all its lanes
+                wcol[pl.ds(r, 1), :] = jnp.full(
+                    (1, lanes), w_ref[ra_ref[i * tm + r]])
+        _for_rows(tm, unroll, landed)
+        held = jax.lax.broadcasted_iota(jnp.int32, (tm, lanes), 0) < nv_ref[i]
+        dot = jnp.zeros((tm, lanes), jnp.float32)
+        for c in range(chunks):
+            cols = slice(c * lanes, (c + 1) * lanes)
+            # a slab's c-th sublane of every row: one strided load
+            piece = jnp.where(held, buf[half, :, c, :], 0.0)
+            if backward:
+                dot += piece * y_ref[:, cols]
+                piece = piece * wcol[...]
+            out_ref[:, cols] = piece.astype(out_ref.dtype)
+        if backward:
+            dot_ref[...] = jnp.sum(dot, axis=-1, keepdims=True)
+
+
+# The row kernels' calls are `jax.jit`s of their own: a model's program
+# then traces each ONCE for all its layers, their recomputation under
+# remat and the backward, not a time a call (PR 33 found the same of the
+# paged kernel: cheap on the device, dear to trace)
+@functools.partial(jax.jit, static_argnames=("top_k", "tm", "dtype",
+                                             "interpret"))
+def _gather_call(src, row_assign, active_tiles, y_rows=None, weights=None, *,
+                 top_k, tm, dtype, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    backward = y_rows is not None
+    t, d = src.shape
+    rows = row_assign.shape[0]
+    chunks, lanes = d // _lanes(d), _lanes(d)
+    wide = pl.BlockSpec((tm, d), _row_map)
+    thin = pl.BlockSpec((tm, 1), _row_map)
+    out = jax.ShapeDtypeStruct((rows, d), dtype)
+    return pl.pallas_call(
+        functools.partial(_gather_kernel, tm=tm, backward=backward,
+                          unroll=not interpret),
+        out_shape=([out, jax.ShapeDtypeStruct((rows, 1), jnp.float32)]
+                   if backward else out),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(rows // tm,),
+            in_specs=([pl.BlockSpec(memory_space=pl.ANY)]
+                      + ([wide] if backward else [])),
+            out_specs=[wide, thin] if backward else wide,
+            scratch_shapes=(
+                [pltpu.VMEM((2, tm, chunks, lanes), jnp.float32),
+                 pltpu.SemaphoreType.DMA((2,))]
+                + ([pltpu.VMEM((tm, lanes), jnp.float32)] if backward
+                   else []))),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="moe_combine_rows_bwd" if backward else "moe_dispatch_rows",
+    )(*_row_operands(row_assign, weights, active_tiles, t * top_k, top_k,
+                     tm), _row_slabs(src), *((y_rows,) if backward else ()))
+
+
+def dispatch_rows(x: jax.Array, row_assign: jax.Array,
+                  active_tiles: jax.Array, *, top_k: int, tm: int,
+                  interpret: Optional[bool] = None) -> jax.Array:
+    """x [T, D], row_assign [R] -> xs [R, D]: each row's token, a padding
+    row of an active tile zeros (the expert kernels multiply it).  A
+    Pallas call, `moe_dispatch_rows`, that visits the ACTIVE tiles: an
+    inactive tile is neither read nor written."""
+    return _gather_call(x, row_assign, active_tiles, top_k=top_k, tm=tm,
+                        dtype=x.dtype, interpret=interpret_default(interpret))
+
+
+def combine_rows_bwd(dy: jax.Array, y_rows: jax.Array, weights: jax.Array,
+                     row_assign: jax.Array, active_tiles: jax.Array, *,
+                     tm: int, dtype, interpret: Optional[bool] = None):
+    """The combine's transpose in one visit of every active tile
+    (`moe_combine_rows_bwd`): dy [T, D] float32 (already rounded to
+    `dtype`, the products'), y_rows [R, D] float32, weights [T, k] ->
+    (dy_rows [R, D] = each row's token's dy x its routing weight, in
+    `dtype`; row_dot [R] float32 = sum over D of that dy x y_rows, the
+    weight's gradient)."""
+    dy_rows, row_dot = _gather_call(
+        dy, row_assign, active_tiles, y_rows, weights,
+        top_k=weights.shape[1], tm=tm, dtype=jnp.dtype(dtype),
+        interpret=interpret_default(interpret))
+    return dy_rows, row_dot[:, 0]
+
+
+def _combine_kernel(rt_ref, ra_ref, w_ref, nv_ref, na_ref, rows_ref, out_ref,
+                    stage, *, tm: int, weighted: bool, unroll: bool):
+    """Grid step i: row tile i, [tm, D], added row by row — times the
+    row's routing weight if `weighted` — into its tokens' sums: out
+    [T, D] float32, which stays in VMEM from the first step to the
+    last.  A padding row adds zeros to the last token (no branch a row:
+    see `_gather_kernel`)."""
+    from jax.experimental import pallas as pl
+
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _zero():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(i < na_ref[0])
+    def _():
+        held = jax.lax.broadcasted_iota(jnp.int32, stage.shape, 0) < nv_ref[i]
+        stage[...] = jnp.where(held, rows_ref[...].astype(jnp.float32), 0.0)
+
+        def add(r):
+            term = stage[pl.ds(r, 1), :]
+            if weighted:
+                term = w_ref[ra_ref[i * tm + r]] * term
+            out_ref[pl.ds(rt_ref[i * tm + r], 1), :] += term
+        _for_rows(tm, unroll, add)
+
+
+# VMEM the combine's [T, D] float32 sums may take (the chip has 128 MiB;
+# Mellum's 8192 x 2304 are 72 of them); over it `_combined` takes the
+# plain form
+_COMBINE_SUM_BYTES = 80 * 1024 * 1024
+
+
+@functools.partial(jax.jit, static_argnames=("n_tokens", "top_k", "tm",
+                                             "interpret"))
+def combine_rows(rows: jax.Array, row_assign: jax.Array,
+                 active_tiles: jax.Array, n_tokens: int, *, top_k: int,
+                 tm: int, weights: Optional[jax.Array] = None,
+                 interpret: Optional[bool] = None) -> jax.Array:
+    """rows [R, D], row_assign [R], weights [T, k] or None (ones) ->
+    [T, D] float32: each token's held rows times their weights, summed
+    in float32 in the order of the ROWS (by expert, where `combine` sums
+    in the order of the token's k choices).  A Pallas call,
+    `moe_combine_rows`, over the ACTIVE tiles: a row belongs to one
+    assignment, so a walk over the rows that exist meets every held
+    assignment once and no other; rows of inactive tiles are not
+    read."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    interpret = interpret_default(interpret)
+    n_rows, d = rows.shape
+    if n_tokens * d * 4 > _COMBINE_SUM_BYTES:
+        raise ValueError(
+            f"combine_rows keeps [{n_tokens}, {d}] float32 sums in VMEM: "
+            f"over {_COMBINE_SUM_BYTES} bytes; split the tokens")
+    return pl.pallas_call(
+        functools.partial(_combine_kernel, tm=tm,
+                          weighted=weights is not None,
+                          unroll=not interpret),
+        out_shape=jax.ShapeDtypeStruct((n_tokens, d), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(n_rows // tm,),
+            in_specs=[pl.BlockSpec((tm, d), _row_map)],
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            scratch_shapes=[pltpu.VMEM((tm, d), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=100 * 1024 * 1024),
+        interpret=interpret,
+        name="moe_combine_rows",
+    )(*_row_operands(row_assign, weights, active_tiles, n_tokens * top_k,
+                     top_k, tm), rows)
+
+
+# Rows of the buffer a pick (R / (T x k)) from which the plain `combine`
+# is taken: many held experts and few tokens — Laguna's decode pass,
+# 2,368 rows for 320 picks, 90 active tiles of a row or two — make the
+# walk over the row tiles dearer than a gather of the picks (82.5
+# against 56.4 us a call at 7.4 rows a pick; at 3.0, GLM-5's decode
+# pass, 42.3 against 56.2, and every other pass the cells compile lies
+# below: PERF.md section 6, my chip runs, PR 44)
+_PLAIN_COMBINE_ROWS_A_PICK = 4
+
+
+def _combined(rows, d: Dispatch, weights, tm, interpret, *, ones=False):
+    """Each token's held rows of `rows` [R, D] times their routing
+    weights (`ones`: times one), summed: [T, D] float32."""
+    t, k = weights.shape
+    n_rows, width = rows.shape
+    if (n_rows >= _PLAIN_COMBINE_ROWS_A_PICK * t * k
+            or t * width * 4 > _COMBINE_SUM_BYTES):
+        return combine(rows, d.dest, jnp.ones_like(weights) if ones
+                       else weights)
+    return combine_rows(rows, d.row_assign, d.active_tiles, t, top_k=k,
+                        tm=tm, weights=None if ones else weights,
+                        interpret=interpret)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
@@ -457,12 +760,15 @@ def _routed(x, weights, w1, w3, w2, d: Dispatch, tm: int,
 
 def _routed_fwd(x, weights, w1, w3, w2, d, tm, interpret):
     w1c, w3c, w2c = (w.astype(x.dtype) for w in (w1, w3, w2))
+    top_k = weights.shape[1]
     with jax.named_scope("moe_dispatch"):
-        xs = _gather_rows(x, d.row_token)
+        xs = dispatch_rows(x, d.row_assign, d.active_tiles, top_k=top_k,
+                           tm=tm, interpret=interpret)
     with jax.named_scope("moe_experts"):
         y_rows = grouped_swiglu(xs, w1c, w3c, w2c, d.tile_expert,
                                 d.active_tiles, tm=tm, interpret=interpret)
-    y = combine(y_rows, d.dest, weights)
+    with jax.named_scope("moe_combine"):
+        y = _combined(y_rows, d, weights, tm, interpret)
     # residuals are arrays: the masters' dtypes ride as empty ones
     masters = tuple(jnp.zeros((0,), w.dtype) for w in (w1, w3, w2))
     return y, (xs, weights, w1c, w3c, w2c, d, y_rows, masters)
@@ -472,20 +778,17 @@ def _routed_bwd(tm, interpret, res, dy):
     xs, weights, w1c, w3c, w2c, d, y_rows, masters = res
     rows = xs.shape[0]
     with jax.named_scope("moe_combine"):
-        here = d.dest < rows
-        # a row belongs to one assignment: its weight, and its token's dy
-        row_w = jnp.zeros((rows + 1,), jnp.float32).at[d.dest].set(
-            jnp.where(here, weights, 0.0))[:rows]
-        # gathered in the dtype the products run in: half the bytes of
-        # the worst-case buffer where that is bfloat16
-        dy_tok = _gather_rows(dy.astype(xs.dtype), d.row_token).astype(
-            jnp.float32)
+        # dy in the dtype the products run in, as the rows it multiplies
+        info = jnp.finfo(xs.dtype)
+        dy = jax.lax.reduce_precision(dy.astype(jnp.float32), info.nexp,
+                                      info.nmant)
         # rows of inactive tiles were never written: only rows an
         # assignment points at are read back
-        row_dot = jnp.sum(dy_tok * y_rows, axis=-1)
+        dy_rows, row_dot = combine_rows_bwd(
+            dy, y_rows, weights, d.row_assign, d.active_tiles, tm=tm,
+            dtype=xs.dtype, interpret=interpret)
         d_weights = jnp.where(
-            here, row_dot[jnp.minimum(d.dest, rows - 1)], 0.0)
-        dy_rows = (dy_tok * row_w[:, None]).astype(xs.dtype)
+            d.dest < rows, row_dot[jnp.minimum(d.dest, rows - 1)], 0.0)
     with jax.named_scope("moe_experts"):
         dxs, dw1, dw3, dw2 = grouped_swiglu_bwd(
             xs, dy_rows, w1c, w3c, w2c, d.tile_expert, d.active_tiles,
@@ -495,7 +798,9 @@ def _routed_bwd(tm, interpret, res, dy):
             jnp.where(chosen, dw, 0.0).astype(like.dtype)
             for dw, like in zip((dw1, dw3, dw2), masters))
     with jax.named_scope("moe_dispatch"):
-        dx = combine(dxs, d.dest, jnp.ones_like(weights)).astype(xs.dtype)
+        # the dispatch's transpose: a token's k rows, summed
+        dx = _combined(dxs, d, weights, tm, interpret,
+                       ones=True).astype(xs.dtype)
     no_grad = jax.tree_util.tree_map(
         lambda a: np.zeros(a.shape, jax.dtypes.float0), d)
     return dx, d_weights.astype(weights.dtype), dw1, dw3, dw2, no_grad

@@ -111,6 +111,109 @@ def test_the_shares_add_up_to_the_uncut_reference_layer(shares):
     np.testing.assert_allclose(ours, uncut, rtol=1e-4, atol=1e-5)
 
 
+def _two_experts(tokens, k):
+    """ids [T, k]: every token on experts 3 and 5 (k = 2) or 3 (k = 1)."""
+    return jnp.tile(jnp.asarray([[3, 5][:k]], jnp.int32), (tokens, 1))
+
+
+def _drawn(tokens, k, seed):
+    """ids [T, k]: k distinct experts of E a token."""
+    scores = jax.random.uniform(jax.random.PRNGKey(seed), (tokens, E))
+    return jax.lax.top_k(scores, k)[1].astype(jnp.int32)
+
+
+# name: (tokens, k, held, row tile, rows' dtype, ids, valid tokens)
+_ROW_CASES = {
+    "a decode batch": (16, 2, (0, E), 16, jnp.bfloat16, None, 16),
+    "a prefill pass": (512, 2, (0, 4), 32, jnp.float32, None, 512),
+    "a training shape": (96, 2, (2, 6), 16, jnp.bfloat16, None, 96),
+    "valid masks a tail": (40, 2, (0, E), 16, jnp.float32, None, 29),
+    "no assignment held": (24, 2, (6, 8), 16, jnp.float32, _two_experts, 24),
+    "one expert holds every assignment, the buffer full":
+        (48, 1, (3, 4), 16, jnp.bfloat16, _two_experts, 48),
+}
+
+
+@pytest.mark.parametrize("case", list(_ROW_CASES), ids=lambda c: c.replace(
+    " ", "_").replace(",", ""))
+def test_row_kernels_are_the_plain_forms_they_replace(case):
+    """`moe_dispatch_rows`, `moe_combine_rows` and `moe_combine_rows_bwd`
+    against the gathers over the whole buffer they replaced, kept here
+    as the reference: `xpad[row_token]`, and `y_rows[dest]` with the
+    select and the weighted sum.  The rows of INACTIVE tiles are NaN on
+    the way in: nothing reads them.  Dispatch and the backward's rows
+    are exact; the sums are float32 in another ORDER (the kernel adds a
+    token's rows as they lie, by expert; the plain form over its k
+    choices), so they agree to float32's last places."""
+    tokens, k, held, tm, dtype, ids_of, n_valid = _ROW_CASES[case]
+    ids = ids_of(tokens, k) if ids_of else _drawn(tokens, k, seed=tokens)
+    valid = jnp.arange(tokens) < n_valid
+    d = moe.dispatch(ids, valid, held, tm)
+    rows = d.row_assign.shape[0]
+    row_token = d.row_assign // k              # `tokens` on a padding row
+    live = int(d.active_tiles) * tm
+    if case == "no assignment held":
+        assert live == 0
+    if case.startswith("one expert"):
+        assert int(jnp.sum(d.counts)) == tokens * k and live == tokens * k
+    ks = jax.random.split(jax.random.PRNGKey(7), 5)
+    x = jax.random.normal(ks[0], (tokens, D), jnp.float32).astype(dtype)
+    weights = jax.random.uniform(ks[1], (tokens, k), jnp.float32)
+    dead = (jnp.arange(rows) >= live)[:, None]
+    y_rows = jnp.where(dead, jnp.nan,
+                       jax.random.normal(ks[2], (rows, D), jnp.float32))
+    dxs = jnp.where(dead, jnp.nan, jax.random.normal(
+        ks[3], (rows, D), jnp.float32)).astype(dtype)
+    dy = jax.random.normal(ks[4], (tokens, D), jnp.float32)
+    dy = dy.astype(dtype).astype(jnp.float32)   # as _routed_bwd rounds it
+
+    def pad(a):
+        return jnp.concatenate([a, jnp.zeros((1, D), a.dtype)])
+
+    def plain_combine(rows_, w):
+        here = d.dest < rows
+        picked = rows_[jnp.minimum(d.dest, rows - 1)]
+        return jnp.sum(jnp.where(here[..., None], picked, 0.0)
+                       * jnp.where(here, w, 0.0)[..., None], axis=1)
+
+    # dispatch: the row is the row
+    xs = moe.dispatch_rows(x, d.row_assign, d.active_tiles, top_k=k, tm=tm,
+                           interpret=True)
+    assert xs.dtype == dtype and xs.shape == (rows, D)
+    np.testing.assert_array_equal(
+        np.asarray(xs[:live], np.float32),
+        np.asarray(pad(x)[row_token][:live], np.float32))
+    # combine: float32 sums, the poisoned rows never read
+    y = moe.combine_rows(y_rows, d.row_assign, d.active_tiles, tokens,
+                         top_k=k, tm=tm, weights=weights, interpret=True)
+    assert y.dtype == jnp.float32
+    np.testing.assert_allclose(y, plain_combine(y_rows, weights),
+                               rtol=2e-6, atol=2e-6)
+    # the dispatch's transpose: weights of one
+    dx = moe.combine_rows(dxs, d.row_assign, d.active_tiles, tokens,
+                          top_k=k, tm=tm, interpret=True)
+    np.testing.assert_allclose(
+        dx, plain_combine(dxs.astype(jnp.float32), jnp.ones_like(weights)),
+        rtol=2e-6, atol=2e-6)
+    # the combine's transpose: dy's rows, their weights, their dots
+    dy_rows, row_dot = moe.combine_rows_bwd(
+        dy, y_rows, weights, d.row_assign, d.active_tiles, tm=tm,
+        dtype=dtype, interpret=True)
+    dy_tok = pad(dy)[row_token][:live]
+    # a row belongs to one assignment: its weight (0 on a padding row)
+    row_w = jnp.zeros((rows + 1,), jnp.float32).at[d.dest].set(
+        jnp.where(d.dest < rows, weights, 0.0))[:rows]
+    assert dy_rows.dtype == dtype and row_dot.dtype == jnp.float32
+    np.testing.assert_array_equal(
+        np.asarray(dy_rows[:live], np.float32),
+        np.asarray((dy_tok * row_w[:live, None]).astype(dtype), np.float32))
+    np.testing.assert_allclose(
+        row_dot[:live], jnp.sum(dy_tok * y_rows[:live], axis=-1),
+        rtol=1e-5, atol=1e-5)
+    if n_valid < tokens:
+        assert float(jnp.abs(y[n_valid:]).max()) == 0.0
+
+
 def test_row_tile_follows_the_mean_group():
     assert moe.row_tile(32, 10, 256) == 16      # a decode batch
     assert moe.row_tile(512, 10, 256) == 32     # a prefill pass

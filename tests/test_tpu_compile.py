@@ -14,6 +14,8 @@ each and belong to a rehearsal script, not to tier-1; the one whole step
 here is the narrow prefill pass's (6 to 15 s a configuration).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -196,7 +198,11 @@ def test_expert_layer_gradient_compiles_at_mellum_shapes(one_chip):
     takes it: 8192 bfloat16 tokens of width 2304, 16 of 64 experts of
     width 896 held as float32 masters, top 8, row tiles of 128 — the
     forward's two calls and the backward's two, an expert's three float32
-    gradient blocks resident in VMEM while its tiles last."""
+    gradient blocks resident in VMEM while its tiles last; and around
+    them the row kernels (270 KB of assignments and 262 KB of routing
+    weights as scalar operands, the combine's 8192 x 2304 float32 sums
+    in VMEM), with no XLA gather over the 67,584 rows of the dropless
+    buffer or the 65,536 assignments left."""
     from ray_tpu.ops import moe
 
     def spec(shape, dtype):
@@ -216,6 +222,11 @@ def test_expert_layer_gradient_compiles_at_mellum_shapes(one_chip):
         spec((16, 896, 2304), jnp.float32)).compile()
     text = compiled.as_text()
     assert "moe_experts_bwd_dx" in text and "moe_experts_bwd_dw" in text
+    for name in ("moe_dispatch_rows", "moe_combine_rows",
+                 "moe_combine_rows_bwd"):
+        assert name in text, name
+    wide = re.findall(r"\[(?:67584|8192,8),2304\]\S* gather\(", text)
+    assert not wide, wide
 
 
 # ------------------------------------- the latent-attention cell's kernel
