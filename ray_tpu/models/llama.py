@@ -1,7 +1,7 @@
 """Llama-family decoder-only transformer, TPU-first.
 
 This is the flagship model for the framework's north-star path
-(BASELINE.json config #2: Llama-3-8B FSDP/GSPMD on a v5e pod slice).
+(Llama-3-8B under FSDP/GSPMD on a v5e pod slice; the cells: PERF.md).
 The reference has no model code of its own — Train wraps user torch
 models (reference: python/ray/train/torch/train_loop_utils.py) — so this
 is green-field, designed for the MXU and GSPMD from the start:

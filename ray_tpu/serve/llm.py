@@ -40,17 +40,6 @@ consumer vanished (SSE disconnect -> generator cancel) keep their pages
 only for ``detach_grace_s`` — the re-attach window for transparent
 resume — then are cancelled and recycled.
 
-Copy-on-write prefix sharing (``prefix_sharing``): page-aligned
-token-prefix blocks are hashed into a per-engine refcounted prefix
-index as prefill completes them; a new sequence whose prompt prefix
-matches attaches to the SAME physical pages (refcount + 1, recycled
-only at refcount 0) and prefills from the first unshared token.  A
-divergence MID-page copies the shared head of that page into a private
-page (copy-on-write) before the diverging tokens are written.  Shared
-pages are immutable by construction — a sequence only ever writes at
-positions >= its own ``pos``, and a page enters the index only once
-every sequence write past it has happened.
-
 Disaggregated prefill (``llm_deployment(prefill_replicas=N)``): a
 sibling replica pool runs ONLY chunked prefill (``prefill_request``),
 exports the finished KV pages via models.cache.gather_slots +
@@ -65,38 +54,21 @@ the deadline admission gate prices the two phases separately
 The model and its cache: ``model=`` resolves to a family of
 ``ray_tpu.models`` (a dictionary's ``model_type``; Llama's without one)
 and the family's config states its cache LAYER BY LAYER
-(models/cache.py).  The engine keeps one page group a kind that occurs:
-every model has ``full`` layers, which keep a page for every position —
-the pages, block table and prefix index described above are that
-group's.  A ``window`` layer attends over its last W positions only, so
-its group (`_WindowPages`) holds, for each sequence, the pages that
-cover the last W positions plus the chunk being written, and gives the
-rest back as the sequence moves on: a second pool, sized for that, and
-a second block table a sequence.  Prefix sharing is REFUSED for a model
-with window layers (a shared prefix would need the window layers' pages
-before its end, which the sequence that wrote them has given back);
-``stats()["prefix_sharing"]`` says so.
-
-A ``state`` layer (a recurrent mixer) keeps no row a position: its cache
-is ONE fixed-size row a sequence.  The engine keeps a slot a sequence
-for the kind (``_Seq.state_slot``: taken at admission, given back when
-the sequence ends; slot 0 is the garbage slot, and ``max_batch`` more
-are enough, a sequence holding a lane-place of ``_active`` from the one
-to the other) and hands every pass ``groups["state"]``: each lane's
-slot, the valid tokens it has in the pass, and ``fresh`` where the
-lane's chunk starts its sequence — such a chunk reads no state, so what
-a slot's last owner left in it is never seen.  That also makes the
-run-ahead's wasted lane-step harmless: it updates a slot its sequence
-has given back, and whoever holds the slot next starts with a fresh
-chunk, dispatched later and so run later.  The slot follows the
-sequence, not the lane: lanes are dealt anew every step.  Page shipping
-carries the state row with the pages.  Prefix sharing is REFUSED for
-such a model too: the pages of a prefix are worth nothing without the
-state at its end, which nobody kept.
+(models/cache.py).  The engine holds one CACHE GROUP a kind that occurs
+(``self._groups``, serve/cache_groups.py: full pages with the prefix
+index, window pages, state slots) and knows a kind by the groups' one
+interface alone: admit a sequence, advance it before a pass, the arrays
+of the pass (`_pass_groups`: the `groups` a model's forward takes), the
+row slots for page shipping, the counts.  The engine's own: the queue,
+lanes, clock, run-ahead, streaming, deadlines, sampling, pass shapes.
+Copy-on-write prefix sharing (``prefix_sharing``) is the full group's: a
+sequence whose page-aligned prompt prefix matches a live one's attaches
+to the SAME pages and prefills from the first unshared token.
 """
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from collections import deque
@@ -190,26 +162,13 @@ _HOST_PHASES = tuple(k for k in _CLOCKED if k not in _WAITS)
 _CPU_GROUP = {k: k if k in _EMITS else "between" if k in _OUTSIDE
               else "front" for k in _CLOCKED}
 # host work inside a phase, by name (`stats()["host_secs"]` -> the span):
-# the lane selection and the window pages' advance under `admit`'s lock,
-# the gauges (inside whichever phase is open), the window kinds' arrays
-# and the paged grid's count inside the builds
+# the lane selection and the groups' advance under `admit`'s lock, the
+# gauges (inside whichever phase is open), and in the builds the arrays of
+# a group that names the span and a decode pass's count: `_pass_groups`
 _HOST_SPANS = {"plan": "llm.plan", "gauges": "llm.gauges",
                "window_arrays": "llm.window_arrays",
                "grid_count": "llm.grid_count"}
-
-# prefix-index chain seed: block k's key hashes (parent key || block
-# tokens), so one digest equality implies the WHOLE prefix matches
-_PREFIX_SEED = b"rtpu-prefix-v1"
-
-
-def _chain_hash(parent: bytes, block) -> bytes:
-    import hashlib
-
-    h = hashlib.blake2b(parent, digest_size=16)
-    for t in block:
-        h.update(int(t).to_bytes(4, "little", signed=True))
-    return h.digest()
-
+_NO_SPAN = contextlib.nullcontext()
 
 def _pow4_widths(first: int, cap: int) -> List[int]:
     """`first`, 4 x `first`, ... up to (and capped at) `cap`: the widths
@@ -231,13 +190,11 @@ def _jit_forward(model, params, pools, tokens, q_pos, last_idx, groups,
     compiled executable (`pools`, the cache's slot arrays by the name of
     a row's part — models/cache.py — donated: in-place cache updates).
 
-    ``groups`` maps each cache kind of the model (models/cache.py) to
-    that kind's arrays: the write ``slots`` and the context in one of
-    two forms — a pytree-structure change, so each form is its own
-    trace: ``ctx``/``ctx_pos``/``ctx_mask`` gather arrays (chunked
-    prefill), or page-granular ``block_tables`` + ``context_lens`` (a
-    window kind also ``starts``), which take decode through the Pallas
-    paged-attention kernel.
+    ``groups`` maps each cache kind of the model to that kind's arrays
+    (serve/cache_groups.py): the write ``slots`` and the context in one
+    of two forms, each its own trace — gathered (chunked prefill), or
+    page-granular ``block_tables``, which take decode through the
+    Pallas paged-attention kernel.
 
     ``feed`` is a decode pass's: ``(src, outputs)``, the previous step's
     output arrays as the device holds them and, a lane, the place of its
@@ -565,23 +522,19 @@ class _Flight:
 
 class _Seq:
     __slots__ = ("request_id", "prompt", "prefill_tokens", "generated",
-                 "max_new", "eos", "block_table", "pos", "state", "done",
+                 "max_new", "eos", "cache", "pos", "state", "done",
                  "error", "attach_count", "detached_at", "done_at",
                  "submitted_at", "admitted_at", "first_token_at",
-                 "cancelled", "slot_cache", "cond", "deadline", "kv_import",
+                 "cancelled", "cond", "deadline", "kv_import",
                  "prefill_export", "export_payload", "trace_ctx",
                  "prefix_tokens", "submit_step", "admit_step",
-                 "first_token_step", "windows", "ahead", "feed",
-                 "state_slot")
+                 "first_token_step", "ahead", "feed")
 
     def __init__(self, request_id: str, prompt: List[int], max_new: int,
                  eos: Optional[int], preknown: Optional[List[int]] = None):
         self.request_id = request_id
-        # physical slot per position, vectorized at admission (the
-        # decode hot path slices this instead of re-deriving slots in
-        # Python per lane per step); cond is per-sequence so a token
-        # emit wakes THIS stream's consumer, not every parked thread
-        self.slot_cache = None
+        # cond is per-sequence so a token emit wakes THIS stream's
+        # consumer, not every parked thread
         self.cond: Optional[threading.Condition] = None
         self.prompt = list(prompt)
         self.generated: List[int] = list(preknown or [])
@@ -590,11 +543,8 @@ class _Seq:
         self.prefill_tokens = self.prompt + self.generated
         self.max_new = int(max_new)
         self.eos = eos
-        self.block_table: List[int] = []   # the "full" group's pages
-        # cache kind -> _SeqWindow, for each window kind of the model
-        self.windows: Dict[str, "_SeqWindow"] = {}
-        # the sequence's slot in the state kind's pools (0: none)
-        self.state_slot = 0
+        # cache kind -> what its group holds for the sequence, while live
+        self.cache: Dict[str, Any] = {}
         # tokens whose KV a DISPATCHED pass has written or will write:
         # the device runs passes in the order they were dispatched, so
         # to every later pass they are in the cache
@@ -638,92 +588,6 @@ class _Seq:
         return len(self.prompt) + self.max_new
 
 
-class _SeqWindow:
-    """One sequence's pages in one window group: `pages[p]` is the
-    physical page of logical page p (0 where it holds none: given back,
-    or not reached yet), `slots[i]` the slot of position i under the
-    same proviso; live are the logical pages [first, next)."""
-
-    __slots__ = ("pages", "slots", "first", "next")
-
-    def __init__(self, np, n_pages: int, page_size: int):
-        self.pages = np.zeros((n_pages,), np.int32)
-        self.slots = np.zeros((n_pages * page_size,), np.int32)
-        self.first = self.next = 0
-
-
-class _WindowPages:
-    """The page group of a `window` cache kind: layers that attend over
-    their last `window` positions.  A sequence holds the pages covering
-    the positions its next pass can read or write — from the oldest a
-    query of the pass still sees to the last it writes — and `advance`
-    gives the older ones back.  The pool holds `per_seq` pages for each
-    of `max_batch` sequences (`per_seq` = window + one prefill chunk,
-    in pages, + 2: a window and a chunk each start mid-page), so an
-    active sequence always finds its next page and admission never
-    waits on this group."""
-
-    def __init__(self, np, kind: str, window: int, page_size: int,
-                 chunk: int, max_batch: int, pages_per_seq: int):
-        self._np = np
-        self.kind, self.window, self.page_size = kind, window, page_size
-        self.per_seq = min(pages_per_seq,
-                           -(-(window + chunk) // page_size) + 2)
-        self.num_pages = 1 + max_batch * self.per_seq   # page 0: garbage
-        self.free: List[int] = list(range(1, self.num_pages))
-        self.allocated_total = 0
-        self.released_total = 0
-        # the widest context a pass reads here: a chunk's last query
-        # sees `window` positions back from itself, its first as many
-        # back from ITSELF, so window + chunk - 1; in whole pages
-        self.ctx_width = -(-(window + chunk) // page_size) * page_size
-        # pages a decode step's table lists: the window may start
-        # mid-page
-        self.table_width = -(-window // page_size) + 1
-
-    def new_seq(self, pages: int) -> _SeqWindow:
-        return _SeqWindow(self._np, pages, self.page_size)
-
-    def advance(self, st: _SeqWindow, lo: int, hi: int) -> None:
-        """Engine lock held.  The sequence's next pass has queries at
-        positions [lo, hi) and writes their rows: give back the pages
-        no query of it sees (all positions <= lo - window), take pages
-        up to the one `hi - 1` lies on."""
-        ps = self.page_size
-        dead = max(0, lo - self.window + 1) // ps
-        for p in range(st.first, min(dead, st.next)):
-            self.free.append(int(st.pages[p]))
-            st.pages[p] = 0
-            self.released_total += 1
-        st.first = max(st.first, dead)
-        st.next = max(st.next, st.first)
-        last = (hi - 1) // ps
-        while st.next <= last:
-            page = self.free.pop()
-            self.allocated_total += 1
-            st.pages[st.next] = page
-            st.slots[st.next * ps:(st.next + 1) * ps] = \
-                page * ps + self._np.arange(ps, dtype=self._np.int32)
-            st.next += 1
-
-    def release(self, st: _SeqWindow) -> None:
-        """Engine lock held: the sequence ended, all its pages back."""
-        for p in range(st.first, st.next):
-            self.free.append(int(st.pages[p]))
-        st.pages[:] = 0
-        st.first = st.next = 0
-
-    def table(self, st: _SeqWindow, n: int, width: int):
-        """(start position, the pages from there) a decode step with
-        `n` tokens of context lists, at most `width` of them."""
-        first = max(0, n - self.window) // self.page_size
-        last = (n - 1) // self.page_size
-        return first * self.page_size, st.pages[first:last + 1][:width]
-
-    def used(self) -> int:
-        return self.num_pages - 1 - len(self.free)
-
-
 class LLMEngine:
     """Continuous-batching decode engine over a paged KV cache.
 
@@ -733,13 +597,6 @@ class LLMEngine:
     engine lock.  (``warm_up`` and the tests instead drive
     ``generate_batch`` inline — an engine is stepped by its loop OR
     inline, never both.)
-
-    Paging: the cache is ``num_pages`` pages of ``page_size`` slots per
-    layer; page 0 is reserved as the garbage page for inactive batch
-    lanes and prefill padding.  A sequence's pages are allocated
-    UP FRONT for prompt + max_new at admission (no mid-decode OOM, at
-    the cost of reserving its worst case) and recycled the moment it
-    finishes, errors, or is cancelled.
     """
 
     def __init__(self, cfg=None, *, model: Any = "tiny",
@@ -763,6 +620,7 @@ class LLMEngine:
 
         from ray_tpu.models import cache as kv_cache, resolve
         from ray_tpu.ops import count_compile_cache_events, kernel_mode
+        from ray_tpu.serve import cache_groups
 
         self._np = np
         # where this engine runs, found once and reported by stats(): a
@@ -801,29 +659,14 @@ class LLMEngine:
         self.ctx_len = self.pages_per_seq * self.page_size
         self._model = self.family.build(cfg, self.page_size)
         # the cache, by what the model's specification says: the kind of
-        # each layer, and a page group for each window kind (the "full"
-        # group is this engine's own pages, block tables and index)
+        # each layer, and one group a kind that occurs
         spec = cfg.cache_spec()
         self._kinds = [layer.kind for layer in spec]
-        # layers whose row is one latent vector (models/cache.py): what
-        # their passes read is counted apart, `latent_*` in stats()
-        self._latent_layers = sum("latent" in layer.rows() for layer in spec)
-        windows = kv_cache.kinds_of(spec)
-        if windows.pop("full", None) is None:
-            raise ValueError("a model with no full-attention layer: the "
-                             "engine's page budget is the full kind's")
-        # layers that keep one state a sequence: a slot a sequence, no
-        # pages (a kind that is neither `full` nor `state` is a window)
-        windows.pop("state", None)
-        self._state_layers = self._kinds.count("state")
-        self._free_state: List[int] = list(range(self.max_batch, 0, -1)) \
-            if self._state_layers else []
-        self._state_row_bytes = kv_cache.state_row_bytes(spec, cfg.dtype)
-        self._windows = {
-            kind: _WindowPages(np, kind, window, self.page_size,
-                               self.prefill_chunk, self.max_batch,
-                               self.pages_per_seq)
-            for kind, window in windows.items()}
+        self._groups = cache_groups.build(
+            spec, cfg.dtype, page_size=self.page_size,
+            num_pages=self.num_pages, max_batch=self.max_batch,
+            chunk=self.prefill_chunk, pages_per_seq=self.pages_per_seq,
+            prefix_sharing=prefix_sharing)
         # seconds of this replica's start-up, by part: until the weights
         # the engine serves from were on the device, the call that
         # allocates the KV pools, and `warm_up`'s compiles.  The device
@@ -849,10 +692,8 @@ class LLMEngine:
         self._params = params
         t1 = time.perf_counter()
         self._pools = kv_cache.make_pools(
-            spec, {"full": self.num_pages * self.page_size,
-                   "state": 1 + self.max_batch,
-                   **{kind: g.num_pages * self.page_size
-                      for kind, g in self._windows.items()}}, cfg.dtype)
+            spec, {kind: g.slots for kind, g in self._groups.items()},
+            cfg.dtype)
         self.startup_secs = {"params": 0.0,
                              "pools": time.perf_counter() - t1, "warm": 0.0}
 
@@ -881,37 +722,6 @@ class LLMEngine:
 
         self._lock = threading.RLock()
         self._cond = threading.Condition(self._lock)
-        self._free_pages: List[int] = list(range(1, self.num_pages))
-        # ---- copy-on-write prefix sharing ----
-        # page_refs[p]: sequences whose block table includes page p —
-        # pages recycle to _free_pages only at refcount 0.  The prefix
-        # index maps a chain hash over page-aligned token blocks to ONE
-        # immutable page holding that block's KV; _children groups
-        # registered pages under their parent-chain hash so a mid-page
-        # divergence can find its copy-on-write source.
-        self.prefix_sharing = bool(prefix_sharing)
-        # a prefix is reusable only where the window layers still hold
-        # the positions before its end, and they have given them back:
-        # refused, not silently wrong (stats()["prefix_sharing"])
-        self._sharing_refused = ""
-        if self.prefix_sharing and self._windows:
-            self.prefix_sharing = False
-            self._sharing_refused = (
-                "the model has window layers, whose pages before a "
-                "prefix's end are given back")
-        elif self.prefix_sharing and self._state_layers:
-            self.prefix_sharing = False
-            self._sharing_refused = (
-                "the model has state layers, and no state is kept at a "
-                "prefix's end")
-        self._page_refs = [0] * self.num_pages
-        self._prefix_index: Dict[bytes, int] = {}
-        self._children: Dict[bytes, set] = {}
-        self._page_tokens: Dict[int, tuple] = {}
-        self._page_keys: Dict[int, tuple] = {}
-        self._prefix_hits = 0
-        self._prefix_tokens_shared = 0
-        self._cow_splits = 0
         self._kv_pages_shipped_out = 0
         self._kv_pages_shipped_in = 0
         self._queued: deque = deque()
@@ -924,7 +734,6 @@ class LLMEngine:
         self._cancelled_total = 0
         self._last_batch = 0
         self._metrics = None
-        self._warm = False
         self._paged_warm = False
         self._prefill_warm = False
         # pass accumulators (a reader takes mean step cost as a delta
@@ -960,9 +769,6 @@ class LLMEngine:
         # and lane-steps computed for a sequence that had ended by the
         # time they were read (its `eos`, a cancel or its deadline came
         # while they were in flight; over decode_lane_steps_total).
-        # The paged kernel's grid: the steps the decode passes' calls
-        # had (lanes x blocks of `pages_per_step` pages of the table, a
-        # kind of layer each pass) and those that held a page.
         self._totals = {"prefill_tokens_total": 0,
                         "prefill_slots_total": 0,
                         "prefill_narrow_passes_total": 0,
@@ -971,26 +777,10 @@ class LLMEngine:
                         "decode_lane_steps_total": 0,
                         "decode_lane_steps_wasted_total": 0,
                         "runahead_decode_steps_total": 0,
-                        "paged_grid_steps_total": 0,
-                        "paged_grid_steps_live_total": 0,
                         "submitted_total": 0, "admitted_total": 0,
                         "first_tokens_total": 0, "finished_total": 0,
                         "queue_wait_secs_total": 0.0,
                         "prefill_wait_secs_total": 0.0}
-        if self._latent_layers:
-            # latent rows the decode passes' kernel calls and the
-            # prefill passes' blocks read (a lane's context, a latent
-            # layer), and those kernel calls (a layer a decode pass)
-            self._totals.update(latent_decode_rows_total=0,
-                                latent_prefill_rows_total=0,
-                                latent_decode_calls_total=0)
-        if self._state_layers:
-            # state rows the decode passes' kernel calls update (a live
-            # lane, a state layer) and the prefill passes' chunks (a
-            # lane with tokens, a state layer), and those kernel calls
-            self._totals.update(state_decode_rows_total=0,
-                                state_prefill_rows_total=0,
-                                state_decode_calls_total=0)
         self._prefill_widths = self._prefill_ctx_buckets()
         self._prefill_passes_by_width = dict.fromkeys(
             self._prefill_widths, 0)
@@ -1100,11 +890,12 @@ class LLMEngine:
                 raise ValueError(
                     f"prompt+max_new_tokens = {len(prompt) + max_new} "
                     f"exceeds max_seq_len {self.cfg.max_seq_len}")
-            pages_needed = -(-(len(prompt) + max_new) // self.page_size)
-            if pages_needed > self.num_pages - 1:
+            total = len(prompt) + max_new
+            room = min(g.max_tokens for g in self._groups.values())
+            if total > room:
                 raise LLMOverloadedError(
-                    f"request needs {pages_needed} KV pages; replica "
-                    f"has {self.num_pages - 1}")
+                    f"request needs {-(-total // self.page_size)} KV pages; "
+                    f"replica has {room // self.page_size}")
             if len(self._queued) >= self.max_queue:
                 raise LLMOverloadedError(
                     f"admission queue full ({self.max_queue})")
@@ -1191,37 +982,24 @@ class LLMEngine:
 
     # ------------------------------------------------------------- stepping
 
-    def _forward(self, tokens, slot_arr, ctx, ctx_pos, ctx_mask, q_pos,
-                 last_idx, block_tables=None, context_lens=None,
-                 windows=None, feed=None, state=None):
+    def _forward(self, tokens, q_pos, last_idx, groups, feed=None):
         """Dispatch one jitted forward with this engine's static sampling
         knobs; the per-call rng split only happens on the sampling path,
-        so greedy engines run the exact pre-sampling program.  The
-        positional arrays are the full kind's; `windows` has the other
-        kinds' (`_window_arrays`); `state` the state kind's
-        (`_state_arrays`: given by every pass of a model with state
-        layers, by no other); `feed` is a decode pass's
-        (`_jit_forward`).  The pools become the pass's; returns what
-        stays on the device until it is read back: (the tokens, with the
-        model's counter vector behind them if it counts; under
-        `logit_trace` the two largest logits and their ids, else None)."""
+        so greedy engines run the exact pre-sampling program.  `groups`
+        is `_pass_groups`'s; `feed` is a decode pass's (`_jit_forward`).
+        The pools become the pass's; returns what stays on the device
+        until it is read back: (the tokens, with the model's counter
+        vector behind them if it counts; under `logit_trace` the two
+        largest logits and their ids, else None)."""
         rng = None
         if self._sample_rng is not None:
             import jax
 
             self._sample_rng, rng = jax.random.split(self._sample_rng)
-        full = {"slots": slot_arr}
-        if block_tables is not None:
-            full.update(block_tables=block_tables,
-                        context_lens=context_lens)
-        else:
-            full.update(ctx=ctx, ctx_pos=ctx_pos, ctx_mask=ctx_mask)
         tok, self._pools, *top2 = self._step_fn(
             self._model, self._params, self._pools, tokens, q_pos,
-            last_idx, {"full": full, **(windows or {}),
-                       **({"state": state} if state is not None else {})},
-            temperature=self.temperature, top_k=self.top_k, rng=rng,
-            top2=self.logit_trace, feed=feed)
+            last_idx, groups, temperature=self.temperature,
+            top_k=self.top_k, rng=rng, top2=self.logit_trace, feed=feed)
         self._clock.dispatched()
         return tok, (top2[0] if top2 else None)
 
@@ -1232,63 +1010,65 @@ class LLMEngine:
             self._model_counters[name][phase] += int(value)
         return next_tok[:lanes]
 
-    def _window_arrays(self, rows, lanes: int, cols: int, width: int,
-                       decode: bool = False):
-        """The window kinds' arrays of one pass of `lanes` x `cols`
-        queries, in the form of its kind of pass.  `rows` = [(lane, the
-        sequence's windows, lo, hi)]: the lane's queries are at [lo, hi);
-        a lane without a row is garbage (slot 0, nothing to see).  A
-        prefill pass gathers: the context is the last `min(width,
-        group.ctx_width)` positions before `hi`.  A decode pass (hi = lo
-        + 1) takes block tables: the window's pages, `min(width,
-        group.table_width)` of them, from position `starts` on."""
-        np = self._np
+    def _pass_groups(self, rows, lanes: int, cols: int, width: int,
+                     decode: bool = False) -> Dict[str, Any]:
+        """The `groups` of one pass of `lanes` x `cols` queries: every
+        cache group's arrays, gathered at context `width` for a prefill
+        pass, block tables `width` pages wide for a decode pass.  `rows`
+        = [(lane, the sequence's `cache`, lo, hi)], the lane's queries at
+        [lo, hi); a lane without a row is garbage, so `rows=[]` is a
+        warm-up's pass: the same tree structure, and nothing counted
+        (a real pass adds what it reads to the groups' totals)."""
         out = {}
-        for kind, group in self._windows.items():
-            slots = np.zeros((lanes, cols), np.int32)
-            if decode:
-                w = min(width, group.table_width)
-                tables = np.zeros((lanes, w), np.int32)
-                starts = np.zeros((lanes,), np.int32)
-                lens = np.zeros((lanes,), np.int32)
-                for lane, wins, lo, hi in rows:
-                    slots[lane, 0] = wins[kind].slots[lo]
-                    starts[lane], pages = group.table(wins[kind], hi, w)
-                    tables[lane, :len(pages)] = pages
-                    lens[lane] = hi
-                out[kind] = {"slots": slots, "block_tables": tables,
-                             "context_lens": lens, "starts": starts}
-                continue
-            w = min(width, group.ctx_width)
-            ctx = np.zeros((lanes, w), np.int32)
-            ctx_pos = np.zeros((lanes, w), np.int32)
-            ctx_mask = np.zeros((lanes, w), bool)
-            for lane, wins, lo, hi in rows:
-                st = wins[kind]
-                slots[lane, :hi - lo] = st.slots[lo:hi]
-                start = max(0, hi - w)
-                ctx[lane, :hi - start] = st.slots[start:hi]
-                ctx_pos[lane, :hi - start] = self._arange[start:hi]
-                ctx_mask[lane, :hi - start] = True
-            out[kind] = {"slots": slots, "ctx": ctx, "ctx_pos": ctx_pos,
-                         "ctx_mask": ctx_mask}
+        for kind, group in self._groups.items():
+            with self._clock.host.get(group.host_span, _NO_SPAN):
+                out[kind] = group.decode_arrays(rows, lanes, width) \
+                    if decode else group.prefill_arrays(rows, lanes, cols,
+                                                        width)
+        if rows:
+            with self._clock.host["grid_count"] if decode else _NO_SPAN:
+                for kind, group in self._groups.items():
+                    group.count(rows, out[kind], decode)
         return out
 
-    def _state_arrays(self, rows, lanes: int):
-        """The state kind's arrays of one pass of `lanes` lanes, or None
-        for a model without state layers.  `rows` = [(lane, the
-        sequence's slot, its valid tokens in the pass, whether they
-        start the sequence)]; a lane without a row is garbage: slot 0,
-        no token."""
-        if not self._state_layers:
-            return None
+    def _prefill_inputs(self, prefill_args, lanes: int, width: int):
+        """`_forward`'s arguments of a prefill pass of `lanes` lanes at
+        context `width` over `prefill_args` (`_plan_locked`); the lanes
+        past them are garbage: [] is a warm-up's pass."""
         np = self._np
-        slots = np.zeros((lanes,), np.int32)
-        lens = np.zeros((lanes,), np.int32)
-        fresh = np.zeros((lanes,), bool)
-        for lane, slot, n, first in rows:
-            slots[lane], lens[lane], fresh[lane] = slot, n, first
-        return {"slots": slots, "lens": lens, "fresh": fresh}
+        tokens = np.zeros((lanes, self.prefill_chunk), np.int32)
+        q_pos = np.zeros((lanes, self.prefill_chunk), np.int32)
+        # a token an entry comes back (`_jit_forward`): the wide pass's
+        # count whatever the lanes, the shape the decode pass feeds on
+        last_idx = np.zeros((self.prefill_lanes,), np.int32)
+        rows = []
+        for lane, (seq, lo, hi, held) in enumerate(prefill_args):
+            tokens[lane, :hi - lo] = seq.prefill_tokens[lo:hi]
+            q_pos[lane, :hi - lo] = self._arange[lo:hi]
+            last_idx[lane] = hi - lo - 1
+            rows.append((lane, held, lo, hi))
+        return tokens, q_pos, last_idx, self._pass_groups(
+            rows, lanes, self.prefill_chunk, width)
+
+    def _decode_inputs(self, decode_args, width: int, feed):
+        """(`_forward`'s arguments, its `feed`) of a decode pass at
+        block-table `width` over `decode_args` (`_plan_locked`), its
+        input tokens taken from `feed` where the host has not read them;
+        the lanes past them are garbage: [] is a warm-up's pass."""
+        np = self._np
+        b = self.max_batch
+        tokens = np.zeros((b, 1), np.int32)
+        src = np.full((b,), -1, np.int32)
+        q_pos = np.zeros((b, 1), np.int32)
+        rows = []
+        for lane, (_seq, last, late, n, held) in enumerate(decode_args):
+            tokens[lane, 0] = last
+            src[lane] = late
+            q_pos[lane, 0] = n - 1
+            rows.append((lane, held, n - 1, n))
+        return (tokens, q_pos, np.zeros((b,), np.int32),
+                self._pass_groups(rows, b, 1, width, decode=True)), \
+            (src, tuple(feed))
 
     def _trace_top2(self, seq: _Seq, lane: int, top2) -> None:
         """Lock held, just before `_emit_token`: the two largest logits
@@ -1355,21 +1135,12 @@ class LLMEngine:
         0, every context column masked).  `but` is the (lanes, width)
         that pass is about to run itself, so an engine with one program
         runs nothing here."""
-        np = self._np
-        c = self.prefill_chunk
         shapes = [(self.prefill_lanes, w) for w in self._prefill_widths]
         if self._narrow_prefill:
             shapes.append(self._narrow_prefill)
         for lanes, width in shapes:
-            if (lanes, width) == but:
-                continue
-            zeros = np.zeros((lanes, c), np.int32)
-            ctx = np.zeros((lanes, width), np.int32)
-            self._forward(
-                zeros, zeros, ctx, ctx, np.zeros((lanes, width), bool),
-                zeros, np.zeros((self.prefill_lanes,), np.int32),
-                windows=self._window_arrays([], lanes, c, width),
-                state=self._state_arrays([], lanes))
+            if (lanes, width) != but:
+                self._forward(*self._prefill_inputs([], lanes, width))
 
     def _warm_paged_buckets(self) -> None:
         """Compile every paged block-table width bucket up front, at
@@ -1378,46 +1149,22 @@ class LLMEngine:
         DEADLINED in-flight request past deadline_force_cancel_grace_s
         gets the whole worker force-killed — so pay all compiles in one
         burst while nothing is at stake (the deployment warm-up request
-        lands here).  The dummy forwards run garbage lanes only (slot
-        0, context length 0); the jit cache is process-wide, so engines
-        sharing a config/geometry pay once."""
+        lands here).  Garbage lanes only; the jit cache is process-wide,
+        so engines sharing a config/geometry pay once."""
         for width in self._paged_width_buckets():
-            args, kwargs = self._garbage_decode_args(width)
-            self._forward(*args, **kwargs)
-
-    def _garbage_decode_args(self, width: int):
-        """`_forward` arguments for a decode step of garbage lanes only
-        (slot 0, context length 0, every token the host's) at
-        block-table `width`."""
-        np = self._np
-        b = self.max_batch
-        zeros1 = np.zeros((b, 1), np.int32)
-        return ((zeros1, zeros1, None, None, None, zeros1,
-                 np.zeros((b,), np.int32)),
-                {"block_tables": np.zeros((b, width), np.int32),
-                 "context_lens": np.zeros((b,), np.int32),
-                 "windows": self._window_arrays([], b, 1, width,
-                                                decode=True),
-                 "state": self._state_arrays([], b),
-                 "feed": (np.full((b,), -1, np.int32), self._no_feed)})
+            inputs, feed = self._decode_inputs([], width, self._no_feed)
+            self._forward(*inputs, feed=feed)
 
     def _lower_decode(self, width: int):
         """The decode step at block-table `width`, lowered and not run:
         for its text (`device_report`) or the compiler's analysis."""
-        import jax
-
-        (tokens, slots, _c, _p, _m, q_pos, last_idx), kwargs = \
-            self._garbage_decode_args(width)
+        (tokens, q_pos, last_idx, groups), feed = self._decode_inputs(
+            [], width, self._no_feed)
         return _jitted_forward(self.temperature, self.top_k,
                                self.logit_trace).lower(
             self._model, self._params, self._pools, tokens, q_pos, last_idx,
-            jax.numpy.zeros((2,), dtype="uint32"),  # rng, unused
-            {"full": {"slots": slots,
-                      "block_tables": kwargs["block_tables"],
-                      "context_lens": kwargs["context_lens"]},
-             **kwargs["windows"],
-             **({"state": kwargs["state"]} if kwargs["state"] is not None
-                else {})}, kwargs["feed"])
+            self._np.zeros((2,), "uint32"),  # rng, unused
+            groups, feed)
 
     def device_report(self) -> Dict[str, Any]:
         """`ops.device_report()` plus what this engine put on the device
@@ -1431,6 +1178,7 @@ class LLMEngine:
         import jax
 
         from ray_tpu.ops import device_report
+        from ray_tpu.serve import cache_groups
 
         def nbytes(tree) -> int:
             return sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(tree))
@@ -1455,10 +1203,7 @@ class LLMEngine:
                    page_size=self.page_size,
                    param_bytes=nbytes(self._params),
                    kv_pool_bytes=nbytes(self._pools),
-                   state_pool_bytes=self._state_pool_bytes(),
-                   **{f"{part}_pool_bytes": self._part_bytes(part)
-                      for part in ("latent", "index")
-                      if part in self._pools},
+                   **cache_groups.pool_bytes(self._groups, self._pools),
                    # executables behind the jitted stepper, all engines
                    # of this process: constant once warm-up is done
                    compiled_steps=sum(fn._cache_size()
@@ -1471,50 +1216,9 @@ class LLMEngine:
         rep["decode_has_tpu_custom_call"] = "tpu_custom_call" in text
         return rep
 
-    def _part_bytes(self, part: str) -> int:
-        """What the pools of one part of a row take, all layers."""
-        return sum(int(p.nbytes) for p in self._pools.get(part, ())
-                   if p is not None)
-
-    def _state_pool_bytes(self) -> int:
-        """What the state kind's pools take: a row a slot, the garbage
-        slot among them (part of `kv_pool_bytes`)."""
-        return (1 + self.max_batch) * self._state_row_bytes
-
-    def _alloc_pages(self, n: int) -> List[int]:
-        pages = self._free_pages[:n]
-        del self._free_pages[:n]
-        for p in pages:
-            self._page_refs[p] = 1
-        return pages
-
-    def _release_pages(self, pages: List[int]) -> None:
-        """Lock held.  Drop one reference per page; pages reaching
-        refcount 0 return to the free list and leave the prefix index
-        (a later lookup must never attach to a recycled page)."""
-        freed = []
-        for p in pages:
-            self._page_refs[p] -= 1
-            if self._page_refs[p] <= 0:
-                self._page_refs[p] = 0
-                freed.append(p)
-                keys = self._page_keys.pop(p, None)
-                if keys is not None:
-                    parent, own = keys
-                    if self._prefix_index.get(own) == p:
-                        del self._prefix_index[own]
-                    kids = self._children.get(parent)
-                    if kids is not None:
-                        kids.discard(p)
-                        if not kids:
-                            del self._children[parent]
-                self._page_tokens.pop(p, None)
-        self._free_pages.extend(freed)
-
     def _finish_seq(self, seq: _Seq, cancelled: bool = False) -> None:
-        """Lock held.  Mark done and release page references
-        immediately — physical pages recycle only at refcount 0 (other
-        sequences may still be decoding against a shared prefix)."""
+        """Lock held.  Mark done and give back at once what the cache
+        groups hold for the sequence."""
         seq.done = True
         seq.cancelled = cancelled
         if cancelled:
@@ -1526,16 +1230,11 @@ class LLMEngine:
             self._record_request_spans(seq)
         if seq.cond is not None:
             seq.cond.notify_all()
-        self._release_pages(seq.block_table)
-        seq.block_table = []
-        for kind, st in seq.windows.items():
-            self._windows[kind].release(st)
-        if seq.state_slot:
-            # passes in flight may still update the slot (the run-ahead's
-            # lane-step of a sequence that has ended): they run before
-            # any pass of its next owner, whose first chunk is `fresh`
-            self._free_state.append(seq.state_slot)
-            seq.state_slot = 0
+        # a pass being built may hold the dictionary: a new one, so that
+        # a second call gives nothing back twice
+        held, seq.cache = seq.cache, {}
+        for kind, st in held.items():
+            self._groups[kind].release(st)
         seq.kv_import = None
         if seq in self._active:
             self._active.remove(seq)
@@ -1574,10 +1273,6 @@ class LLMEngine:
                 attributes=dict(attrs, first_step=first, last_step=last),
                 error="cancelled" if ended_here and seq.cancelled else "")
 
-    def _slot(self, seq: _Seq, pos: int) -> int:
-        return (seq.block_table[pos // self.page_size] * self.page_size
-                + pos % self.page_size)
-
     def _sweep(self, now: float) -> None:
         """Lock held: expire sequences past their deadline (pages
         recycle NOW; the consumer sees the typed error), cancel
@@ -1609,145 +1304,35 @@ class LLMEngine:
                     and now - seq.done_at > ttl:
                 del self._by_rid[rid]
 
-    def _match_prefix(self, seq: _Seq):
-        """Lock held.  Longest shared-prefix match for ``seq`` against
-        the refcounted index: returns (shared_pages, cow) where
-        ``shared_pages`` are live physical pages whose KV covers the
-        first ``len(shared_pages) * page_size`` prefill tokens
-        verbatim, and ``cow`` is an optional (source_page, n_tokens)
-        mid-page extension to copy-on-write into a private page.  At
-        least ONE token is always left for prefill — the final prompt
-        position's logits are what produce the first generated token."""
-        toks = seq.prefill_tokens
-        ps = self.page_size
-        limit = len(toks) - 1
-        shared: List[int] = []
-        if limit < 1 or not self._children:
-            return shared, None
-        h = _PREFIX_SEED
-        p = 0
-        while (p + 1) * ps <= limit:
-            block = tuple(toks[p * ps:(p + 1) * ps])
-            child = _chain_hash(h, block)
-            page = self._prefix_index.get(child)
-            # digest equality implies the whole prefix matches; the
-            # token compare turns a (cosmically unlikely) hash
-            # collision into a miss instead of a wrong-KV decode
-            if page is None or self._page_refs[page] <= 0 \
-                    or self._page_tokens.get(page) != block:
-                break
-            shared.append(page)
-            h = child
-            p += 1
-        # mid-page extension: a registered page under the same parent
-        # chain whose leading tokens match is a copy-on-write source —
-        # its shared head is copied into the diverging sequence's
-        # private page so prefill starts at the first unshared token
-        cow = None
-        rem = min(limit - p * ps, ps)
-        if rem > 0:
-            best, best_page = 0, None
-            want = toks[p * ps:p * ps + rem]
-            for cand in self._children.get(h, ()):
-                ct = self._page_tokens.get(cand)
-                if not ct or self._page_refs[cand] <= 0:
-                    continue
-                m = 0
-                for a, b in zip(ct, want):
-                    if a != b:
-                        break
-                    m += 1
-                if m > best:
-                    best, best_page = m, cand
-            if best > 0:
-                cow = (best_page, best)
-        return shared, cow
-
-    def _register_prefix_pages(self, seq: _Seq) -> None:
-        """Lock held.  Enter ``seq``'s fully-written prefill pages into
-        the prefix index.  A page is registered only once the sequence's
-        ``pos`` passed its end (all slots written, and no future write
-        can touch it — writes only happen at >= pos) and only within
-        the prefill region (decode-extended pages are private).
-        Idempotent: already-registered pages (including ones attached
-        FROM the index) are skipped."""
-        if not self.prefix_sharing:
-            return
-        ps = self.page_size
-        toks = seq.prefill_tokens
-        max_page = min(seq.pos, len(toks)) // ps
-        h = _PREFIX_SEED
-        for p in range(max_page):
-            block = tuple(toks[p * ps:(p + 1) * ps])
-            child = _chain_hash(h, block)
-            page = seq.block_table[p]
-            if page not in self._page_keys and self._page_refs[page] > 0:
-                # first registration wins; an identical-content page
-                # from another sequence stays unregistered (it will be
-                # recycled at its own refcount 0)
-                self._prefix_index.setdefault(child, page)
-                self._children.setdefault(h, set()).add(page)
-                self._page_tokens[page] = block
-                self._page_keys[page] = (h, child)
-            h = child
-
-    def _cow_copy(self, src_page: int, dst_page: int, n_tok: int) -> None:
-        """Lock held, loop-synchronized (only ever called from within a
-        step, never concurrent with a forward): copy the first
-        ``n_tok`` KV rows of ``src_page`` into ``dst_page``."""
-        from ray_tpu.models.cache import copy_slots
-
-        np = self._np
-        ps = self.page_size
-        src = np.arange(n_tok, dtype=np.int32) + src_page * ps
-        dst = np.arange(n_tok, dtype=np.int32) + dst_page * ps
-        self._pools = copy_slots(self._pools, self._kinds, "full", src,
-                                 dst)
-
     def _admit_locked(self) -> None:
         while self._queued and len(self._active) < self.max_batch:
             seq = self._queued[0]
-            pages = -(-seq.total_len // self.page_size)
-            shared: List[int] = []
-            cow = None
-            if self.prefix_sharing and seq.kv_import is None \
-                    and not seq.block_table:
-                shared, cow = self._match_prefix(seq)
-            if pages - len(shared) > len(self._free_pages):
-                break  # head-of-line waits for pages to recycle
+            # shipped rows are attached whole: no prefix to look for
+            tokens = seq.prefill_tokens if seq.kv_import is None else None
+            plans = {kind: group.fit(seq.total_len, tokens)
+                     for kind, group in self._groups.items()}
+            if None in plans.values():
+                break  # head-of-line waits for a group to have room
             self._queued.popleft()
-            for p in shared:
-                self._page_refs[p] += 1
-            seq.block_table = shared + self._alloc_pages(
-                pages - len(shared))
-            np = self._np
-            bt = np.asarray(seq.block_table, np.int64)
-            seq.slot_cache = (np.repeat(bt * self.page_size,
-                                        self.page_size)
-                              + np.tile(np.arange(self.page_size),
-                                        len(bt))).astype(np.int32)
-            seq.windows = {kind: g.new_seq(pages)
-                           for kind, g in self._windows.items()}
-            if self._state_layers:
-                # never empty: a slot a place in `_active`
-                seq.state_slot = self._free_state.pop()
-            shared_tok = len(shared) * self.page_size
-            if cow is not None:
-                src_page, n_tok = cow
-                self._cow_copy(src_page, seq.block_table[len(shared)],
-                               n_tok)
-                self._cow_splits += 1
-                shared_tok += n_tok
-            if shared_tok:
-                # prefill starts at the first unshared token: the
-                # attached pages already hold this prefix's KV
-                seq.pos = seq.prefix_tokens = shared_tok
-                self._prefix_hits += 1
-                self._prefix_tokens_shared += shared_tok
+            there, split = 0, False
+            for kind, group in self._groups.items():
+                seq.cache[kind], n, copy = group.admit(plans[kind])
+                there = max(there, n)
+                if copy is not None:
+                    # the shared head of a page into the private one
+                    # (inside a step: never concurrent with a forward)
+                    from ray_tpu.models.cache import copy_slots
+
+                    self._pools = copy_slots(self._pools, self._kinds,
+                                             kind, *copy)
+                    split = True
+            if there:
+                # prefill starts at the first token no group has yet
+                seq.pos = seq.prefix_tokens = there
                 m = self.metrics()
                 if m is not None:
                     m["prefix_hits"].inc(
-                        tags={"kind": "cow" if cow else "page"})
+                        tags={"kind": "cow" if split else "page"})
             seq.state = _PREFILL
             seq.admitted_at = time.monotonic()
             seq.admit_step = self._steps
@@ -1795,21 +1380,16 @@ class LLMEngine:
     # concurrent reader/writer).
 
     def _kv_row_slots(self, seq: _Seq, n: int, take: bool = False):
-        """Lock held.  The slots, by cache kind, of the rows a sequence
-        with `n` tokens in the cache ships or receives: every position
-        of the full kind, of a window kind the positions its next query
-        (at `n`) still sees.  `take`: the receiving side first takes the
-        window pages those rows go to."""
-        slots = {"full": seq.slot_cache[:n]}
-        for kind, group in self._windows.items():
-            if take:
-                group.advance(seq.windows[kind], n, n)
-            start = max(0, n - group.window + 1)
-            slots[kind] = seq.windows[kind].slots[start:n]
-        if self._state_layers:
-            # the one row that stands for all `n` tokens
-            slots["state"] = [seq.state_slot]
-        return slots
+        """Lock held.  By cache kind, the slots of the rows a sequence
+        with `n` tokens ships or (`take`: taken first) receives."""
+        return {kind: group.row_slots(seq.cache[kind], n, take)
+                for kind, group in self._groups.items()}
+
+    def _written(self, seq: _Seq) -> None:
+        """Lock held: every pass that writes the sequence's rows below
+        `seq.pos` is dispatched, so to any later pass they stand."""
+        for kind, group in self._groups.items():
+            group.written(seq.cache[kind], seq.prefill_tokens, seq.pos)
 
     def _attach_imports_locked(self) -> bool:
         """Scatter shipped KV rows for freshly-admitted sequences into
@@ -1833,10 +1413,9 @@ class LLMEngine:
             m = self.metrics()
             if m is not None:
                 m["shipped"].inc(n_pages, tags={"direction": "in"})
-            # imported pages carry a complete prompt prefix: register
-            # them so later same-prefix admissions share instead of
-            # re-importing or re-prefilling
-            self._register_prefix_pages(seq)
+            # imported pages carry a complete prompt prefix: later
+            # same-prefix admissions share instead of re-importing
+            self._written(seq)
             seq.state = _DECODE
             self._emit_token(seq, first_tok)
         return bool(imports)
@@ -2008,17 +1587,16 @@ class LLMEngine:
     def _plan_locked(self):
         """Lock held: what this step's passes will hold — a chunk of
         each prefilling sequence a lane can take, a position of each
-        decoding one — with their window pages advanced."""
+        decoding one — with every group advanced over it, and what they
+        hold for it now (a sequence that ends meanwhile gets a new
+        `cache`)."""
         prefill_args = []
         for seq in [s for s in self._active
                     if s.state == _PREFILL][:self.prefill_lanes]:
             lo = seq.pos
             hi = min(lo + self.prefill_chunk, len(seq.prefill_tokens))
-            for kind, st in seq.windows.items():
-                self._windows[kind].advance(st, lo, hi)
-            prefill_args.append(
-                (seq, lo, hi, seq.prefill_tokens[lo:hi],
-                 seq.slot_cache[lo:hi], seq.slot_cache[:hi]))
+            self._advance(seq, lo, hi)
+            prefill_args.append((seq, lo, hi, seq.cache))
         # the decoding sequences as this step found them (one whose
         # prompt ends in this step's prefill pass decodes from the next
         # on), but for those whose every token is dispatched
@@ -2031,15 +1609,15 @@ class LLMEngine:
             # then not looked at), else the host's
             last = (seq.generated[-1] if seq.generated
                     else seq.prefill_tokens[-1])
-            for kind, st in seq.windows.items():
-                self._windows[kind].advance(st, seq.pos, seq.pos + 1)
-            # snapshot the block table under the lock: a concurrent CoW
-            # split may rewrite entries after we release it
-            decode_args.append(
-                (seq, last, seq.feed if seq.ahead else -1,
-                 seq.slot_cache[seq.pos], list(seq.block_table),
-                 seq.pos + 1))
+            self._advance(seq, seq.pos, seq.pos + 1)
+            decode_args.append((seq, last, seq.feed if seq.ahead else -1,
+                                seq.pos + 1, seq.cache))
         return prefill_args, decode_args
+
+    def _advance(self, seq: _Seq, lo: int, hi: int) -> None:
+        """Lock held: the sequence's next pass has queries at [lo, hi)."""
+        for kind, group in self._groups.items():
+            group.advance(seq.cache[kind], lo, hi)
 
     def _dispatch_prefill(self, step: int, prefill_args) -> int:
         """Chunked prefill, batched across lanes: up to prefill_lanes
@@ -2053,7 +1631,6 @@ class LLMEngine:
         as the smallest _prefill_ctx_buckets() entry covering its
         longest lane — its cost tracks USED slots and context, at one
         program a shape.  Returns the prompt tokens the pass holds."""
-        np = self._np
         phase = self._clock.phase
         phase("prefill_build")
         ctx_rows = [hi for _s, _lo, hi, *_r in prefill_args]
@@ -2064,54 +1641,18 @@ class LLMEngine:
             t0 = time.perf_counter()
             self._warm_prefill_buckets(but=shape)
             self._warm_secs["prefill"] += time.perf_counter() - t0
-        c = self.prefill_chunk
-        tokens = np.zeros((lanes, c), np.int32)
-        slot_arr = np.zeros((lanes, c), np.int32)
-        ctx = np.zeros((lanes, width), np.int32)
-        ctx_pos = np.zeros((lanes, width), np.int32)
-        ctx_mask = np.zeros((lanes, width), bool)
-        q_pos = np.zeros((lanes, c), np.int32)
-        # a token an entry comes back (`_jit_forward`): the wide pass's
-        # count whatever the lanes, the shape the decode pass feeds on
-        last_idx = np.zeros((self.prefill_lanes,), np.int32)
-        for lane, (seq, lo, hi, toks, slots, ctx_slots) \
-                in enumerate(prefill_args):
-            tokens[lane, :hi - lo] = toks
-            slot_arr[lane, :hi - lo] = slots
-            ctx[lane, :hi] = ctx_slots
-            ctx_pos[lane, :hi] = self._arange[:hi]
-            ctx_mask[lane, :hi] = True
-            q_pos[lane, :hi - lo] = self._arange[lo:hi]
-            last_idx[lane] = hi - lo - 1
-        windows = None
-        if self._windows:
-            with self._clock.host["window_arrays"]:
-                windows = self._window_arrays(
-                    [(lane, seq.windows, lo, hi) for lane, (seq, lo, hi, *_r)
-                     in enumerate(prefill_args)], lanes, c, width)
-        state = self._state_arrays(
-            [(lane, seq.state_slot, hi - lo, lo == 0)
-             for lane, (seq, lo, hi, *_r) in enumerate(prefill_args)],
-            lanes)
+        inputs = self._prefill_inputs(prefill_args, lanes, width)
         phase("prefill_dispatch", width=width, lanes=lanes)
-        out, top2 = self._forward(
-            tokens, slot_arr, ctx, ctx_pos, ctx_mask, q_pos, last_idx,
-            windows=windows, state=state)
+        out, top2 = self._forward(*inputs)
         self._feed[1] = out
         self._prefill_steps += 1
         chunk_tokens = sum(hi - lo for _s, lo, hi, *_r in prefill_args)
         self._totals["prefill_tokens_total"] += chunk_tokens
-        self._totals["prefill_slots_total"] += lanes * c
+        self._totals["prefill_slots_total"] += lanes * self.prefill_chunk
         self._totals["prefill_narrow_passes_total"] += \
             lanes < self.prefill_lanes
         self._totals["prefill_ctx_rows_total"] += sum(ctx_rows)
         self._totals["prefill_ctx_cols_total"] += lanes * width
-        if self._latent_layers:
-            self._totals["latent_prefill_rows_total"] += \
-                self._latent_layers * sum(ctx_rows)
-        if self._state_layers:
-            self._totals["state_prefill_rows_total"] += \
-                self._state_layers * len(prefill_args)
         self._prefill_passes_by_width[width] += 1
         owed = []
         with self._lock:
@@ -2120,10 +1661,9 @@ class LLMEngine:
                     continue  # cancelled mid-build: pages already back
                 seq.pos = hi
                 # the pages this chunk completes are immutable from this
-                # pass on, and the pass is dispatched: enter them into
-                # the prefix index, so later admissions with the same
-                # prompt prefix share them (their passes run after it)
-                self._register_prefix_pages(seq)
+                # pass on, which is dispatched: later admissions with the
+                # same prompt prefix may share them (their passes run after)
+                self._written(seq)
                 if hi == len(seq.prefill_tokens):
                     seq.state = _SHIP if seq.prefill_export else _DECODE
                     seq.ahead += 1
@@ -2139,15 +1679,8 @@ class LLMEngine:
         """The token-level decode batch: one position of every decoding
         sequence, its input token taken from `feed` (the last step's
         outputs, on the device) where the host has not read it yet."""
-        np = self._np
         phase = self._clock.phase
         phase("decode_build")
-        b = self.max_batch
-        tokens = np.zeros((b, 1), np.int32)
-        src = np.full((b,), -1, np.int32)
-        slot_arr = np.zeros((b, 1), np.int32)
-        q_pos = np.zeros((b, 1), np.int32)
-        last_idx = np.zeros((b,), np.int32)
         if not self._paged_warm:
             self._paged_warm = True
             t0 = time.perf_counter()
@@ -2158,51 +1691,16 @@ class LLMEngine:
         # covering the max used pages across lanes: decode cost tracks
         # USED context, and the jit retrace per bucket is
         # O(log pages_per_seq) traces total.
-        max_used = max(-(-n // self.page_size) for *_a, n in decode_args)
+        max_used = max(-(-n // self.page_size)
+                       for _s, _t, _l, n, _h in decode_args)
         width = next(w for w in self._paged_width_buckets()
                      if w >= max_used)
-        block_tables = np.zeros((b, width), np.int32)
-        context_lens = np.zeros((b,), np.int32)
-        for lane, (seq, last, late, slot, table, n) \
-                in enumerate(decode_args):
-            tokens[lane, 0] = last
-            src[lane] = late
-            slot_arr[lane, 0] = slot
-            used = -(-n // self.page_size)
-            block_tables[lane, :used] = table[:used]
-            context_lens[lane] = n
-            q_pos[lane, 0] = n - 1
-        windows = None
-        if self._windows:
-            with self._clock.host["window_arrays"]:
-                windows = self._window_arrays(
-                    [(lane, seq.windows, n - 1, n) for lane, (seq, *_a, n)
-                     in enumerate(decode_args)], b, 1, width, decode=True)
-        with self._clock.host["grid_count"]:
-            self._count_paged_grid(block_tables, context_lens)
-            for arrays in (windows or {}).values():
-                self._count_paged_grid(
-                    arrays["block_tables"],
-                    arrays["context_lens"] - arrays["starts"])
-        state = self._state_arrays(
-            [(lane, seq.state_slot, 1, False)
-             for lane, (seq, *_a) in enumerate(decode_args)], b)
+        inputs, feed = self._decode_inputs(decode_args, width, feed)
         phase("decode_dispatch")
-        out, top2 = self._forward(
-            tokens, slot_arr, None, None, None, q_pos, last_idx,
-            block_tables=block_tables, context_lens=context_lens,
-            windows=windows, feed=(src, tuple(feed)), state=state)
+        out, top2 = self._forward(*inputs, feed=feed)
         self._feed[0] = out
         self._decode_steps += 1
         self._totals["decode_lane_steps_total"] += len(decode_args)
-        if self._latent_layers:
-            self._totals["latent_decode_rows_total"] += \
-                self._latent_layers * int(context_lens.sum())
-            self._totals["latent_decode_calls_total"] += self._latent_layers
-        if self._state_layers:
-            self._totals["state_decode_rows_total"] += \
-                self._state_layers * len(decode_args)
-            self._totals["state_decode_calls_total"] += self._state_layers
         owed = []
         with self._lock:
             for lane, (seq, *_rest) in enumerate(decode_args):
@@ -2216,18 +1714,6 @@ class LLMEngine:
         m = self.metrics()
         if m is not None:
             m["tokens"].inc(len(decode_args), tags={"phase": "decode"})
-
-    def _count_paged_grid(self, tables, tokens) -> None:
-        """One kind of layer's paged-kernel call of a decode pass:
-        `tables` [lanes, width] as the kernel gets it, `tokens` [lanes]
-        the positions each lane's table covers (0: not held)."""
-        from ray_tpu.ops.paged_attention import pages_per_step
-
-        lanes, width = tables.shape
-        pages = pages_per_step(width, self.page_size)
-        self._totals["paged_grid_steps_total"] += lanes * -(-width // pages)
-        self._totals["paged_grid_steps_live_total"] += \
-            int((-(-tokens // (pages * self.page_size))).sum())
 
     def _read_back(self, before: Optional[int] = None) -> bool:
         """Read the passes in flight that steps before `before`
@@ -2372,21 +1858,15 @@ class LLMEngine:
                 return None
         return self._metrics
 
-    def _shared_page_count(self) -> int:
-        """Lock held: pages referenced by more than one sequence."""
-        return sum(1 for r in self._page_refs if r > 1)
-
     def _set_gauges(self, batch: int = 0, step_tokens: int = 0) -> None:
         """Publish the step's own counts (an idle step's are zero)."""
         m = self.metrics()
         if m is None:
             return
         with self._clock.host["gauges"]:
-            m["pages"].set(self.num_pages - 1 - len(self._free_pages),
-                           tags={"state": "used"})
-            m["pages"].set(len(self._free_pages), tags={"state": "free"})
-            m["pages"].set(self._shared_page_count(),
-                           tags={"state": "shared"})
+            for group in self._groups.values():
+                for state, pages in group.gauges().items():
+                    m["pages"].set(pages, tags={"state": state})
             m["batch"].set(batch)
             m["queue"].set(len(self._queued))
             m["tps"].set(step_tokens)
@@ -2396,6 +1876,7 @@ class LLMEngine:
         `*_secs` and `*_steps` key is cumulative and never falls: a
         reader takes the change between two calls."""
         from ray_tpu.ops import compile_counts
+        from ray_tpu.serve import cache_groups
 
         with self._lock:
             return {"steps": self._steps,
@@ -2419,32 +1900,9 @@ class LLMEngine:
                     "active": len(self._active),
                     "cancelled": self._cancelled_total,
                     "deadline_expired": self._deadline_expired_total,
-                    "free_pages": len(self._free_pages),
-                    "used_pages": self.num_pages - 1 - len(self._free_pages),
-                    "kv_pages_in_use": {
-                        "full": self.num_pages - 1 - len(self._free_pages),
-                        **{kind: g.used()
-                           for kind, g in self._windows.items()}},
-                    "kv_window_pages_released_total": sum(
-                        g.released_total for g in self._windows.values()),
-                    "shared_pages": self._shared_page_count(),
-                    "prefix_sharing": self.prefix_sharing,
-                    "prefix_sharing_refused": self._sharing_refused,
-                    "prefix_hits": self._prefix_hits,
-                    "prefix_tokens_shared": self._prefix_tokens_shared,
-                    "cow_splits": self._cow_splits,
+                    **cache_groups.stats(self._groups, self._pools),
                     "kv_pages_shipped_out": self._kv_pages_shipped_out,
                     "kv_pages_shipped_in": self._kv_pages_shipped_in,
-                    **({"latent_pool_bytes": self._part_bytes("latent"),
-                        "latent_pages_in_use":
-                            self.num_pages - 1 - len(self._free_pages)}
-                       if self._latent_layers else {}),
-                    **({"index_pool_bytes": self._part_bytes("index")}
-                       if "index" in self._pools else {}),
-                    **({"state_slots_in_use":
-                            self.max_batch - len(self._free_state),
-                        "state_pool_bytes": self._state_pool_bytes()}
-                       if self._state_layers else {}),
                     "loop_running": self._loop_running,
                     "last_batch": self._last_batch}
 

@@ -61,11 +61,11 @@ def _spy_shapes(eng):
     context width or table width)."""
     shapes, forward = [], eng._forward
 
-    def spy(tokens, slot_arr, ctx, *rest, **kw):
-        width = ctx.shape[1] if ctx is not None \
-            else kw["block_tables"].shape[1]
+    def spy(tokens, q_pos, last_idx, groups, **kw):
+        full = groups["full"]
+        width = full["ctx" if "ctx" in full else "block_tables"].shape[1]
         shapes.append((*tokens.shape, width))
-        return forward(tokens, slot_arr, ctx, *rest, **kw)
+        return forward(tokens, q_pos, last_idx, groups, **kw)
 
     eng._forward = spy
     return shapes

@@ -1,0 +1,140 @@
+"""The engine and its cache groups (serve/cache_groups.py), over the five
+tiny configurations: what `stats()` and `device_report()` are keyed by —
+literals taken from the commit before the groups left `LLMEngine` (PR 44),
+which every reader of the benchmark goes by — and that a warm-up's
+garbage pass and a real pass are told of the cache by `groups` of one
+tree structure, so a shape compiles once."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models.granite import GraniteConfig
+from ray_tpu.models.laguna import LagunaConfig
+from ray_tpu.models.pangu import PanguConfig
+from ray_tpu.serve.llm import LLMEngine
+
+STATS = frozenset("""
+    active admitted_total between_secs cancelled compile_secs_total
+    compiles_total cow_splits deadline_expired decode_lane_steps_total
+    decode_lane_steps_wasted_total decode_secs decode_steps finished_total
+    first_tokens_total free_pages host_cpu_secs host_off_cpu_secs
+    host_secs host_wall_secs kernel_mode kv_pages_in_use
+    kv_pages_shipped_in kv_pages_shipped_out
+    kv_window_pages_released_total last_batch loop_running loop_secs
+    off_cpu_secs paged_grid_steps_live_total paged_grid_steps_total
+    park_secs phase_secs platform prefill_ctx_cols_total
+    prefill_ctx_rows_total prefill_narrow_passes_total
+    prefill_passes_by_width prefill_secs prefill_slots_total prefill_steps
+    prefill_tokens_total prefill_wait_secs_total prefix_hits
+    prefix_sharing prefix_sharing_refused prefix_tokens_shared
+    queue_wait_secs_total queued runahead_decode_steps_total shared_pages
+    startup_secs starved_secs starved_secs_total starved_steps_total
+    step_secs steps submitted_total turnaround_secs turnarounds_total
+    used_pages
+""".split())
+REPORT = frozenset("""
+    attention_impl bytes_in_use chips_per_process_bounds compile_cache_dir
+    compile_cache_hits compile_cache_misses compile_secs_total
+    compiled_steps compiles_total decode_has_tpu_custom_call device_count
+    device_ids device_kind dtype kernel_mode kv_pool_bytes model page_size
+    param_bytes peak_bytes_in_use pid platform recent_compiles
+    state_pool_bytes visible_chips
+""".split())
+MOE = {f"moe_{name}_total" for name in (
+    "assignments", "expert_calls", "expert_slots", "layer_passes",
+    "max_load")}
+LATENT = {"latent_decode_calls_total", "latent_decode_rows_total",
+          "latent_prefill_rows_total", "latent_pages_in_use",
+          "latent_pool_bytes"}
+SPARSE = {f"sparse_{name}_total" for name in (
+    "decode_rows", "dense_queries", "index_pages_read", "index_pairs",
+    "rows_selected", "rows_visible")}
+STATE = {"state_decode_calls_total", "state_decode_rows_total",
+         "state_prefill_rows_total", "state_slots_in_use",
+         "state_pool_bytes"}
+
+
+def _llama():
+    return dict(model="tiny", seed=1, page_size=8)
+
+
+def _laguna():
+    return dict(cfg=dataclasses.replace(
+        LagunaConfig.tiny(), dtype=jnp.float32,
+        max_position_embeddings=128), seed=3, page_size=8)
+
+
+def _pangu():
+    return dict(cfg=dataclasses.replace(
+        PanguConfig.tiny(), dtype=jnp.float32, max_position_embeddings=128),
+        seed=5, page_size=16)
+
+
+def _pangu_sparse():
+    from test_glm_model import CFG, PAGE
+
+    return dict(cfg=dataclasses.replace(CFG, max_position_embeddings=128),
+                seed=5, page_size=PAGE, prefill_lanes=2)
+
+
+def _granite():
+    return dict(cfg=dataclasses.replace(
+        GraniteConfig.tiny(), dtype=jnp.float32, param_dtype=jnp.float32,
+        max_position_embeddings=128), seed=5, page_size=16)
+
+
+# the configuration -> (its cache kinds, what `stats()` has beyond STATS,
+# what `device_report()` has beyond REPORT)
+CASES = {
+    _llama: (["full"], set(), set()),
+    _laguna: (["full", "window"], MOE, set()),
+    _pangu: (["full"], MOE | LATENT, {"latent_pool_bytes"}),
+    _pangu_sparse: (["full"], MOE | LATENT | SPARSE | {"index_pool_bytes"},
+                    {"latent_pool_bytes", "index_pool_bytes"}),
+    _granite: (["full", "state"], STATE, set()),
+}
+
+
+def _shapes(tree):
+    return (jax.tree_util.tree_structure(tree),
+            [(np.shape(leaf), np.asarray(leaf).dtype)
+             for leaf in jax.tree_util.tree_leaves(tree)])
+
+
+@pytest.mark.parametrize("make", list(CASES), ids=lambda f: f.__name__[1:])
+def test_the_keys_are_the_parents_and_a_shape_compiles_once(make):
+    kinds, more_stats, more_report = CASES[make]
+    eng = LLMEngine(**make(), max_batch=4, prefill_chunk=16)
+    assert list(eng._groups) == kinds
+    eng.warm_up()
+    compiled = eng.device_report()["compiled_steps"]
+    passes, forward = [], eng._forward
+
+    def spy(tokens, q_pos, last_idx, groups, **kw):
+        passes.append((tokens.shape, groups))
+        return forward(tokens, q_pos, last_idx, groups, **kw)
+
+    eng._forward = spy
+    rs = np.random.RandomState(7)
+    outs = eng.generate_batch(
+        [{"tokens": [int(t) for t in rs.randint(1, 200, n)],
+          "max_new_tokens": 3} for n in (40, 5, 21)])
+    assert [len(o) for o in outs] == [3, 3, 3]
+    assert {cols for (_l, cols), _g in passes} == {16, 1}
+    for (lanes, cols), groups in passes:
+        assert list(groups) == kinds
+        full, decode = groups["full"], cols == 1
+        width = full["block_tables" if decode else "ctx"].shape[1]
+        # what the warm-up ran at this shape: no row, every lane garbage
+        garbage = eng._pass_groups([], lanes, cols, width, decode=decode)
+        assert _shapes(garbage) == _shapes(groups)
+        assert not any(np.asarray(leaf).any()
+                       for leaf in jax.tree_util.tree_leaves(garbage))
+    report = eng.device_report()
+    assert report["compiled_steps"] == compiled
+    assert set(eng.stats()) == STATS | more_stats
+    assert set(report) == REPORT | more_report

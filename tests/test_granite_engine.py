@@ -1,4 +1,4 @@
-"""The engine's state slots (serve/llm.py) on the hybrid state-space
+"""The engine's state slots (serve/cache_groups.py) on the hybrid state-space
 family: sequences changing lanes, slots re-used behind a step in flight,
 pages and state shipped, the narrow and the wide prefill pass — against
 the plain reference (seeded random weights, small size, float32, CPU)."""
@@ -79,7 +79,7 @@ def test_engine_logits_under_churn_are_the_references():
         and st["prefill_steps"] > st["prefill_narrow_passes_total"]
     assert st["runahead_decode_steps_total"] > 0
     assert st["state_slots_in_use"] == 0 and st["used_pages"] == 0
-    assert sorted(eng._free_state) == [1, 2, 3, 4]
+    assert sorted(eng._groups["state"].free) == [1, 2, 3, 4]
     # the counters are the hand counts: a state layer a lane with tokens
     assert st["state_decode_rows_total"] == 3 * st["decode_lane_steps_total"]
     assert st["state_decode_calls_total"] == 3 * st["decode_steps"]
@@ -115,7 +115,7 @@ def test_a_slot_retaken_behind_an_eos_under_runahead_starts_fresh():
     assert out == free[:k + 1]
     assert eng.stats()["decode_lane_steps_wasted_total"] \
         - before["decode_lane_steps_wasted_total"] == 1
-    assert eng._free_state == [1]
+    assert eng._groups["state"].free == [1]
     other = _prompt(90, salt=7)
     got = eng.generate_batch([{"tokens": other, "max_new_tokens": 12}])[0]
     want = _engine(max_batch=1).generate_batch(
@@ -128,15 +128,16 @@ def test_a_stale_slot_would_show():
     """The same, with `fresh` never set: the second owner reads what the
     first left and its logits move — the check above is not blind."""
     eng = _engine(max_batch=1, logit_trace=True)
-    arrays = eng._state_arrays
+    group = eng._groups["state"]
+    arrays = group.prefill_arrays
 
-    def stale(rows, lanes):
-        return arrays([(lane, slot, n, False) for lane, slot, n, _f in rows],
-                      lanes)
+    def stale(rows, lanes, *shape):
+        return {**arrays(rows, lanes, *shape),
+                "fresh": np.zeros((lanes,), bool)}
 
     first, other = _prompt(70), _prompt(90, salt=7)
     eng.generate_batch([{"tokens": first, "max_new_tokens": 4}])
-    eng._state_arrays = stale
+    group.prefill_arrays = stale
     eng.generate_batch([{"tokens": other, "max_new_tokens": 4,
                          "request_id": "stale"}])
     lg = np.asarray(ref.logits(eng._params, other, SIZES))[-1]

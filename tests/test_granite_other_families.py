@@ -61,5 +61,5 @@ def test_a_family_without_state_layers_is_what_it_was(model):
     eng.generate_batch([{"tokens": [1, 2, 3], "max_new_tokens": 3}])
     assert groups and not any("state" in g for g in groups)
     assert not any(k.startswith("state_") for k in eng.stats())
-    assert not eng._free_state
+    assert "state" not in eng._groups
     assert eng.device_report()["state_pool_bytes"] == 0

@@ -103,13 +103,13 @@ def test_window_arrays_take_the_form_of_their_pass():
     positions, a decode pass lists its pages from `starts` on; a lane
     without a row is garbage in both."""
     eng = _engine()
-    group = eng._windows["window"]
+    group = eng._groups["window"]
     seq = eng.submit({"tokens": _prompt(100), "max_new_tokens": 8})
     while len(seq.generated) < 3:
         eng.step()
-    n, st = seq.pos, seq.windows["window"]
-    row = [(1, seq.windows, n - 1, n)]
-    dec = eng._window_arrays(row, 4, 1, 16, decode=True)["window"]
+    n, st = seq.pos, seq.cache["window"]
+    row = [(1, seq.cache, n - 1, n)]
+    dec = eng._pass_groups(row, 4, 1, 16, decode=True)["window"]
     assert set(dec) == {"slots", "block_tables", "context_lens", "starts"}
     assert dec["block_tables"].shape == (4, group.table_width)
     start = int(dec["starts"][1])
@@ -119,7 +119,7 @@ def test_window_arrays_take_the_form_of_their_pass():
         == st.pages[start // PAGE:start // PAGE + live].tolist()
     assert dec["context_lens"].tolist() == [0, n, 0, 0]
     assert dec["slots"][:, 0].tolist() == [0, st.slots[n - 1], 0, 0]
-    pre = eng._window_arrays(row, 4, 1, 256)["window"]
+    pre = eng._pass_groups(row, 4, 1, 256)["window"]
     assert set(pre) == {"slots", "ctx", "ctx_pos", "ctx_mask"}
     assert pre["ctx"].shape == (4, group.ctx_width)
     assert pre["ctx_mask"].sum(axis=1).tolist() == [0, group.ctx_width, 0, 0]
@@ -135,9 +135,9 @@ def test_window_pages_come_back_while_a_sequence_runs():
     moved past is given back as it goes, and its end leaves both groups
     as it found them."""
     eng = _engine()
-    group = eng._windows["window"]
+    group, full = eng._groups["window"], eng._groups["full"]
     assert group.per_seq == 8 and group.num_pages == 1 + 4 * 8
-    free_full, free_win = len(eng._free_pages), len(group.free)
+    free_full, free_win = len(full.free), len(group.free)
     seq = eng.submit({"tokens": _prompt(300), "max_new_tokens": 20})
     held = []
     while not seq.done:
@@ -151,7 +151,7 @@ def test_window_pages_come_back_while_a_sequence_runs():
     st = eng.stats()
     assert st["kv_window_pages_released_total"] >= 40 - group.per_seq
     assert st["kv_pages_in_use"] == {"full": 0, "window": 0}
-    assert len(eng._free_pages) == free_full
+    assert len(full.free) == free_full
     assert sorted(group.free) == list(range(1, group.num_pages))
     assert len(group.free) == free_win
     # cancelled mid-flight: the same
@@ -244,7 +244,7 @@ def test_window_pages_released_and_reused_under_runahead():
     while any(s.state != "decode" for s in seqs):
         eng.step()
     before = eng.stats()
-    group = eng._windows["window"]
+    group = eng._groups["window"]
     allocated = group.allocated_total
     _drain(eng)
     st = eng.stats()
@@ -297,8 +297,8 @@ def test_a_llama_engine_is_the_parents():
     rep = eng.device_report()
     assert (rep["param_bytes"], rep["kv_pool_bytes"]) == (214272, 133120)
     assert rep["model"]["family"] == "llama" and rep["model"]["share"] is None
-    assert eng._windows == {} and eng.prefix_sharing
-    assert eng._garbage_decode_args(4)[1]["windows"] == {}
+    assert list(eng._groups) == ["full"] and eng.stats()["prefix_sharing"]
+    assert list(eng._pass_groups([], 4, 1, 4, decode=True)) == ["full"]
     cost = eng._lower_decode(4).compile().cost_analysis()
     assert (cost["flops"], cost["bytes accessed"],
             cost["transcendentals"]) == (1281390.0, 3161810.0, 1572.0)

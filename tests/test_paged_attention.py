@@ -66,7 +66,7 @@ def _dense_reference(q, pool_k, pool_v, bt, ctx_lens, page_size,
 
 def _window_tables(bt, ctx_lens, page_size, window):
     """What the engine's window group hands a decode pass
-    (`_WindowPages.table`): each lane's pages from the one the window's
+    (`cache_groups.WindowPages`): each lane's pages from the one the window's
     first position lies on, and the position that page starts at."""
     first = np.maximum(0, ctx_lens - window) // page_size
     tables = np.zeros((len(bt), -(-window // page_size) + 1), np.int32)

@@ -233,7 +233,7 @@ def test_latent_pools_ship_copy_and_share(params):
 def test_the_prefill_pass_carries_each_lanes_block_table():
     """What `latent_chunk_attention` reads its table from: every
     `page_size`-th column of the pass's `ctx`, by whole pages, is the
-    sequence's `block_table` as far as its context reaches and its
+    sequence's pages (`seq.cache["full"].pages`) as far as its context reaches and its
     length is the mask's count — for every pass an engine builds, the
     passes of a prompt that shares a live prefix's pages (and holds a
     copy-on-write page of its own behind them) among them."""
@@ -242,20 +242,21 @@ def test_the_prefill_pass_carries_each_lanes_block_table():
     dispatch, forward = eng._dispatch_prefill, eng._forward
 
     def spy_dispatch(step, prefill_args):
-        lanes = [(list(seq.block_table), hi)
+        lanes = [(seq.cache["full"].pages.tolist(), hi)
                  for seq, _lo, hi, *_rest in prefill_args]
-        tables.update((seq, list(seq.block_table))
+        tables.update((seq, seq.cache["full"].pages.tolist())
                       for seq, *_rest in prefill_args)
         out = dispatch(step, prefill_args)
         # the pass's own call is the last (warm-up's come before it)
         passes.append((lanes, *calls[-1]))
         return out
 
-    def spy_forward(tokens, slot_arr, ctx, ctx_pos, ctx_mask, *rest, **kw):
-        if ctx is not None:
-            calls.append((np.asarray(ctx), np.asarray(ctx_mask)))
-        return forward(tokens, slot_arr, ctx, ctx_pos, ctx_mask, *rest,
-                       **kw)
+    def spy_forward(tokens, q_pos, last_idx, groups, **kw):
+        full = groups["full"]
+        if "ctx" in full:
+            calls.append((np.asarray(full["ctx"]),
+                          np.asarray(full["ctx_mask"])))
+        return forward(tokens, q_pos, last_idx, groups, **kw)
 
     eng._dispatch_prefill, eng._forward = spy_dispatch, spy_forward
     prompt = [int(t) for t in TOKENS[:96]]

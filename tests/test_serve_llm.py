@@ -168,7 +168,8 @@ def test_engine_defaults():
     pages_per_seq = MODEL["max_seq_len"] // 16
     assert (eng.page_size, eng.max_batch, eng.prefill_chunk,
             eng.prefill_lanes, eng.stream_flush_tokens, eng.max_queue,
-            eng.detach_grace_s, eng.prefix_sharing, eng.temperature,
+            eng.detach_grace_s, eng.stats()["prefix_sharing"],
+            eng.temperature,
             eng.top_k) == (16, 32, 64, 8, 4, 256, 2.0, True, 0.0, 0)
     # llm_kv_pages 0: sized for max_batch sequences at max_seq_len
     assert eng.num_pages == 1 + 32 * pages_per_seq
@@ -283,10 +284,10 @@ def test_every_prefill_width_is_compiled_before_the_second_pass(warmed_by):
     eng = _wide_engine()
     shapes, forward = [], eng._forward
 
-    def spy(tokens, slot_arr, ctx, *rest, **kw):
-        if ctx is not None:
-            shapes.append((tokens.shape[0], ctx.shape[1]))
-        return forward(tokens, slot_arr, ctx, *rest, **kw)
+    def spy(tokens, q_pos, last_idx, groups, **kw):
+        if "ctx" in groups["full"]:
+            shapes.append((tokens.shape[0], groups["full"]["ctx"].shape[1]))
+        return forward(tokens, q_pos, last_idx, groups, **kw)
 
     eng._forward = spy
     lanes = eng.prefill_lanes
@@ -727,7 +728,7 @@ def test_prefix_sharing_decode_identity():
                      "request_id": "p1"})
     for _ in range(4):
         eng.step()  # s1 past prefill: its pages are registered
-    assert len(eng._prefix_index) == 3
+    assert len(eng._groups["full"].index) == 3
     # identical prompt: 2 full shared pages + a CoW extension of 7
     # tokens (one token always left to prefill for first-token logits)
     s2 = eng.submit({"tokens": base, "max_new_tokens": 6,
@@ -784,14 +785,14 @@ def test_shared_pages_recycle_only_at_refcount_zero():
         s2 = eng.submit(req2)
         eng.step()
         assert eng.stats()["prefix_hits"] == 1
-        shared = [p for p in s2.block_table
-                  if eng._page_refs[p] > 1]
+        refs = eng._groups["full"].refs
+        shared = [p for p in s2.cache["full"].pages if refs[p] > 1]
         assert shared, "second sequence landed on no shared pages"
         assert eng.stats()["shared_pages"] == len(shared)
         kill(eng, s1)  # first holder dies mid-decode
         assert s1.done and s1.cancelled
         for p in shared:
-            assert eng._page_refs[p] == 1, \
+            assert refs[p] == 1, \
                 "shared page recycled while the survivor holds it"
         _drain(eng)
         assert s2.done and not s2.cancelled

@@ -566,7 +566,7 @@ def test_narrow_prefill_program_compiles_at_the_cells_shapes(
         model.init, jax.random.PRNGKey(0), np.zeros((1, 8), np.int32)
     )["params"])
     # the engine's pools: every position of a full kind, of a window kind
-    # the window and a chunk (`_WindowPages`); a window kind gathers that
+    # the window and a chunk (`cache_groups.WindowPages`); a window kind gathers that
     # much context, in whole pages, where the pass's width is wider
     kinds = kv_cache.kinds_of(cfg.cache_spec())
     span = {kind: -(-(window + chunk) // PAGE) if window

@@ -96,7 +96,7 @@ def test_jax_trainer_data_parallel(cluster):
 
     def mnist_style_loop(config):
         """DataParallel MLP on synthetic data over all local devices
-        (BASELINE.json config #1 shape). Defined inside the test so
+        (the smallest training shape). Defined inside the test so
         cloudpickle serializes it by value."""
         import jax
         import optax
