@@ -833,6 +833,26 @@ def llm_prefix_metrics() -> Tuple[Counter, Counter]:
     return _llm_prefix_metrics
 
 
+_llm_block_metrics: Optional[Counter] = None
+
+
+def llm_block_metrics() -> Counter:
+    """Process-singleton counter of a block-diffusion model's decode
+    passes (serve/llm.py, `_read_blocks`):
+    ``ray_tpu_llm_block_lane_passes_total`` — a lane's passes over its
+    open block, labeled kind=denoise|commit by what the pass found (a
+    mask left, or none: the block's last overwrite).  Their ratio to the
+    decode tokens of ``ray_tpu_llm_tokens_total`` is what a token costs
+    in passes."""
+    global _llm_block_metrics
+    if _llm_block_metrics is None:
+        _llm_block_metrics = Counter(
+            "ray_tpu_llm_block_lane_passes_total",
+            "block passes of a block-diffusion model's lanes "
+            "(kind=denoise|commit)")
+    return _llm_block_metrics
+
+
 async def start_metrics_http_server(registry: MetricsRegistry,
                                     host: str = "127.0.0.1",
                                     port: int = 0,

@@ -60,13 +60,17 @@ __all__ = ["LlamaConfig", "LlamaModel", "llama_param_rules", "resolve",
 # `model_type` -> the module of this package that implements it
 FAMILIES = {"llama": "llama", "mistral": "llama", "laguna": "laguna",
             "mellum": "laguna", "pangu_ultra_moe": "pangu",
-            "glm_moe_dsa": "pangu", "granitemoehybrid": "granite"}
+            "glm_moe_dsa": "pangu", "granitemoehybrid": "granite",
+            "sdar_moe": "laguna"}
 # keys that a `model_type`'s published config class defaults, so that a
 # dictionary of that type may leave them out (a family's `from_dict`
 # takes an absent key for an absent mechanism).  `benchmarks/kinds/
 # serve_laguna.py` hands the engine its keys without `gating`; once it
 # lists the key this entry can go.
-CLASS_DEFAULTS = {"laguna": {"gating": "per-head"}}
+# `sdar_moe`: the published class derives from the Qwen3-MoE code, which
+# norms q and k whatever the config says (it has no key for it).
+CLASS_DEFAULTS = {"laguna": {"gating": "per-head"},
+                  "sdar_moe": {"qk_norm": True}}
 
 
 def resolve(model: Any) -> Tuple[Any, Any]:

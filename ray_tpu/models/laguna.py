@@ -1,8 +1,8 @@
 """The expert-layer decoder family: attention whose kind (full or
 sliding window) and head count change by layer, rotary tables by layer
-kind, and MLPs that are dense or routed experts by layer.  Two published
-models are settings of it, told apart by the keys their `config.json`
-has and never by name:
+kind, and MLPs that are dense or routed experts by layer.  Three
+published models are settings of it, told apart by the keys their
+`config.json` has and never by name:
 
     `model_type: laguna`  a head-wise attention gate (`gating`; `gated`
         here), a dense leading layer, a shared expert beside the routed ones
@@ -12,6 +12,14 @@ has and never by name:
     `model_type: mellum`  none of those keys: no gate, no
         shared expert, every layer sparse, one head count
         (`num_attention_heads`) — trained through train/gspmd.py
+    `model_type: sdar_moe`  none of those keys either, and no
+        `layer_types` (every layer full attention), `rope_theta` where
+        the others have `rope_parameters`; a weighted RMSNorm on each
+        head's q and k (`qk_norm`: the published class's code, which
+        the config has no key for — `models.CLASS_DEFAULTS`); and a
+        `generation` group: the model GENERATES BY DIFFUSION OVER
+        BLOCKS of `block_length` positions (below) — served through
+        LLMEngine, a decode pass carrying a whole block a lane
 
 A mechanism whose key is absent is not there (`LagunaConfig.from_dict`).
 Written from the published keys: `layer_types`, `mlp_layer_types`,
@@ -32,7 +40,24 @@ Written from the published keys: `layer_types`, `mlp_layer_types`,
 What the config does not say is ONE function each, so that a reader with
 the model's own code corrects it in one place (the configuration file
 lists them under `assumed`): `gate_activation`, `router_scores`,
-`combine_shared` and `qk_normalize`.
+`combine_shared` and `qk_normalize`; the block setting's are
+`block_candidates` (the mask id is never a candidate), `block_confidence`
+and `block_transfer` (the sampler's `low_confidence_dynamic` rule), and
+the logits at position t predict the token AT t (no shift: the engine
+samples a block's positions from their own logits).
+
+Generation by diffusion over blocks (`block_length` B > 0): attention is
+BLOCK-causal — position t sees s iff s // B <= t // B, its whole block
+and every earlier one — in the whole-sequence route, in a prefill chunk
+(`cached_attention(block=B)`; a chunk ends on a block's end) and in a
+block pass, where a lane's B queries see the committed rows and the
+block's own B rows, which the pass writes first
+(`paged_attention_block`).  The engine's sampler (`block_sample`, run on
+the device behind the model) takes the block's tokens, masked where they
+equal `mask_token_id`, and the logits: z = argmax over the candidates,
+c = softmax(logits)[z] in float32; among the masked positions those
+with c > `confidence_threshold` are unmasked, and where they are fewer
+than the pass's scheduled count the `need` of largest c instead.
 
 This chip may hold a share of a layer: `experts_held` = (lo, hi) of the
 router's `num_experts` (ops/moe.py computes that share's part of the
@@ -105,6 +130,13 @@ class LagunaConfig:
     rope_parameters: Tuple = ()        # `_frozen` of the published group
     experts_held: Tuple[int, int] = (0, 256)
     gated: bool = True                 # the head-wise attention gate
+    qk_norm: bool = False              # RMSNorm, weighted, on q and k heads
+    # generation by diffusion over blocks (the `generation` group): 0 is
+    # token by token
+    block_length: int = 0
+    denoising_steps: int = 0           # T <= B passes a block at most
+    confidence_threshold: float = 0.0
+    mask_token_id: int = 0
     dtype: Any = jnp.bfloat16          # activations and the KV cache
     param_dtype: Any = jnp.bfloat16    # the stored matrices
 
@@ -117,7 +149,10 @@ class LagunaConfig:
         `moe_routed_scaling_factor`, factor 1; no
         `num_attention_heads_per_layer`, `num_attention_heads` on every
         layer; no `mlp_layer_types`, every layer sparse; no
-        `experts_held`, all of `num_experts`.  (The constructor's own
+        `experts_held`, all of `num_experts`; no `layer_types`, every
+        layer full attention; no `rope_parameters`, the default rotary of
+        the whole head at `rope_theta`; no `generation`, token by token.
+        A key whose value is null is absent.  (The constructor's own
         defaults are the gated model's; `models.resolve` gives a
         dictionary the keys its `model_type`'s config class defaults.)
         Keys that say nothing of the shape (`model_type`, ...) are read
@@ -131,11 +166,22 @@ class LagunaConfig:
             "mlp_layer_types": ("sparse",) * layers,
             "experts_held": (0, int(model.get("num_experts",
                                               cls.num_experts)))}
+        model = {k: v for k, v in model.items() if v is not None}
         if "num_attention_heads" in model:
             absent["num_attention_heads_per_layer"] = (
                 int(model["num_attention_heads"]),) * layers
+        if "layer_types" not in model:
+            absent["layer_types"] = (FULL,) * layers
+        if "rope_parameters" not in model and "rope_theta" in model:
+            absent["rope_parameters"] = _frozen({FULL: {
+                "rope_type": "default", "rope_theta": model["rope_theta"]}})
         given = {k: _frozen(v) for k, v in model.items() if k in names}
-        return cls(**{**absent, **given})
+        # the `generation` group's numbers are fields here; a key that is
+        # not (the sampler's rule by name: `block_transfer` is the one
+        # this program has) is read by nobody
+        generation = {k: v for k, v in model.get("generation", {}).items()
+                      if k in names}
+        return cls(**{**absent, **given, **generation})
 
     @classmethod
     def tiny(cls) -> "LagunaConfig":
@@ -183,6 +229,21 @@ class LagunaConfig:
                 "vocab_rows": self.vocab_size}
 
     @classmethod
+    def tiny_blocks(cls) -> "LagunaConfig":
+        """Test size of the block-diffusion setting: 3 full layers, q/k
+        norms, every one of 8 experts held, blocks of 4; float32, so
+        that a test's comparison is of the mechanism."""
+        return cls.from_dict(dict(
+            vocab_size=256, hidden_size=64, num_hidden_layers=3,
+            num_attention_heads=8, num_key_value_heads=2, head_dim=16,
+            max_position_embeddings=256, num_experts=8,
+            num_experts_per_tok=2, moe_intermediate_size=32,
+            rope_theta=1000000, qk_norm=True, sliding_window=None,
+            generation={"block_length": 4, "denoising_steps": 4,
+                        "confidence_threshold": 0.9, "mask_token_id": 255},
+            dtype=jnp.float32, param_dtype=jnp.float32))
+
+    @classmethod
     def tiny_ungated(cls) -> "LagunaConfig":
         """Test size of the other setting: two periods of sliding x3 +
         full, no gate, no shared expert, one head count, 4 of 8 experts
@@ -225,9 +286,69 @@ def combine_shared(shared: jax.Array, routed: jax.Array,
     return shared + factor * routed
 
 
-def qk_normalize(q: jax.Array, k: jax.Array):
-    """assumed (4): no normalisation of q or k."""
-    return q, k
+def qk_normalize(q: jax.Array, k: jax.Array, norm_q=None, norm_k=None):
+    """assumed (4): no normalisation of q or k — but in the setting whose
+    published class has one (`qk_norm`): a weighted RMSNorm over each
+    head's numbers, one weight vector a layer for q and one for k,
+    BEFORE the rotation."""
+    if norm_q is None:
+        return q, k
+    with jax.named_scope("qk_norm"):
+        return norm_q(q), norm_k(k)
+
+
+def block_candidates(logits: jax.Array, mask_id: int) -> jax.Array:
+    """assumed (5): the mask id is never a candidate — a position
+    unmasked to the mask's own id would read as masked for ever."""
+    return jnp.where(jnp.arange(logits.shape[-1]) == mask_id, -jnp.inf,
+                     logits.astype(jnp.float32))
+
+
+def block_confidence(logits: jax.Array):
+    """assumed (6): (z, c) = the argmax of the candidates and its softmax
+    probability, in float32."""
+    top = jnp.max(logits, axis=-1)
+    c = 1.0 / jnp.sum(jnp.exp(logits - top[..., None]), axis=-1)
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32), c
+
+
+def block_transfer(masked: jax.Array, c: jax.Array, need: jax.Array,
+                   threshold: float):
+    """assumed (7): the sampler's `low_confidence_dynamic` rule over a
+    block [..., B] -> (the positions unmasked, those among them that the
+    threshold alone allowed): among the masked positions those with c >
+    threshold, and where they are fewer than `need` [...] the `need` of
+    largest c instead, a tie to the lower position."""
+    over = masked & (c > threshold)
+    conf = jnp.where(masked, c, -jnp.inf)
+    at = jnp.arange(c.shape[-1])
+    ahead = (conf[..., None, :] > conf[..., :, None]) | (
+        (conf[..., None, :] == conf[..., :, None])
+        & (at[None, :] < at[:, None]))
+    forced = masked & (jnp.sum(ahead, axis=-1) < need[..., None])
+    enough = jnp.sum(over, axis=-1, keepdims=True) >= need[..., None]
+    transfer = jnp.where(enough, over, forced)
+    return transfer, transfer & over
+
+
+def block_sample(cfg: "LagunaConfig", tokens: jax.Array, logits: jax.Array,
+                 need: jax.Array):
+    """One pass of the block sampler, on the device behind the model:
+    `tokens` [L, B] the blocks as the pass read them, `logits` [L, B, V]
+    what it made of them, `need` [L] the schedule's count for the pass.
+    -> (the blocks' next state [L, B], then a lane: positions masked at
+    entry, unmasked by the pass, unmasked by the threshold alone).  A
+    block with no mask left comes back as it is: its pass was the
+    commit, which wrote its rows for good."""
+    with jax.named_scope("diffusion_sample"):
+        z, c = block_confidence(block_candidates(logits, cfg.mask_token_id))
+    with jax.named_scope("diffusion_transfer"):
+        masked = tokens == cfg.mask_token_id
+        transfer, over = block_transfer(masked, c, need,
+                                        cfg.confidence_threshold)
+        counts = (jnp.sum(m, axis=-1).astype(jnp.int32)
+                  for m in (masked, transfer, over))
+        return (jnp.where(transfer, z, tokens).astype(jnp.int32), *counts)
 
 
 # ------------------------------------------------------------------ rotary
@@ -326,7 +447,10 @@ class GatedAttention(nn.Module):
         q = dense((n_heads, cfg.head_dim), "wq")(x)
         k = dense((cfg.num_key_value_heads, cfg.head_dim), "wk")(x)
         v = dense((cfg.num_key_value_heads, cfg.head_dim), "wv")(x)
-        q, k = qk_normalize(q, k)
+        norms = (RMSNorm(cfg.rms_norm_eps, name="q_norm"),
+                 RMSNorm(cfg.rms_norm_eps, name="k_norm")) \
+            if cfg.qk_norm else ()
+        q, k = qk_normalize(q, k, *norms)
         rot = rope_tables(cfg.rope(kind), cfg.head_dim)
         q = _rotary(q, positions, *rot)
         k = _rotary(k, positions, *rot)
@@ -339,8 +463,9 @@ class GatedAttention(nn.Module):
             dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="wo")
         pools = None
         if cache is None:
+            mask = {"block": cfg.block_length} if cfg.block_length else {}
             out = (self.kernel or default_attention)(
-                q, k, v, causal=True, window=window)
+                q, k, v, causal=True, window=window, **mask)
         else:
             b, s = k.shape[0], k.shape[1]
             flat = cache["slots"].reshape(-1)
@@ -358,7 +483,8 @@ class GatedAttention(nn.Module):
             else:
                 out = cached_attention(
                     q, pool_k, pool_v, cache["ctx"], cache["ctx_pos"],
-                    cache["ctx_mask"], positions, window=window or None)
+                    cache["ctx_mask"], positions, window=window or None,
+                    block=cfg.block_length)
         if cfg.gated:
             with jax.named_scope("attn_gate"):
                 out = (out.astype(jnp.float32) * gate[..., None]).astype(

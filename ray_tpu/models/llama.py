@@ -131,15 +131,21 @@ FLASH_PREFILL_MIN_SEQ = 512
 
 
 def default_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                      causal: bool = True, window: int = 0) -> jax.Array:
+                      causal: bool = True, window: int = 0,
+                      block: int = 0) -> jax.Array:
     """The one attention route of a whole sequence, for every family,
     serving prefill and training alike: XLA fuses the dense math well on
     its own; the Pallas flash kernels (ray_tpu/ops/flash_attention.py,
     forward and backward) replace it for long sequences
     (>= FLASH_PREFILL_MIN_SEQ, multiple of 128).  `window` > 0: a query
     sees the last `window` positions up to its own (causal only).
+    `block` > 0: the mask is BLOCK-causal, a query sees every position
+    of its own block of `block` and whole earlier blocks (dense math
+    only: the flash kernels' mask is the causal one).
     q: [B,S,H,D], k/v: [B,S,Hkv,D]."""
     s, t = q.shape[1], k.shape[1]
+    if block:
+        return dense_attention(q, k, v, causal, window, block=block)
     if (causal and s == t and s >= FLASH_PREFILL_MIN_SEQ
             and s % 128 == 0):
         from ray_tpu.ops.flash_attention import flash_attention
@@ -180,7 +186,8 @@ def _scaled(logits: jax.Array, d: int, scale: Optional[float]):
 
 def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, window: int = 0,
-                    scale: Optional[float] = None) -> jax.Array:
+                    scale: Optional[float] = None,
+                    block: int = 0) -> jax.Array:
     """The dense softmax-attention math itself, [S, S] scores and all:
     what :func:`default_attention` runs below the flash threshold, and
     what the flash kernels are tested against."""
@@ -192,6 +199,9 @@ def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     logits = _scaled(logits, d, scale)
     if causal:
         mask = jnp.tril(jnp.ones((s, s), dtype=bool))
+        if block:
+            at = jnp.arange(s)
+            mask = at[None, :] < (at[:, None] // block + 1) * block
         if window:
             mask = mask & ~jnp.tril(jnp.ones((s, s), dtype=bool), -window)
         logits = jnp.where(mask[None, None, None], logits, -1e30)
@@ -204,7 +214,8 @@ def cached_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                      ctx: jax.Array, ctx_pos: jax.Array,
                      ctx_mask: jax.Array, q_pos: jax.Array,
                      window: Optional[int] = None,
-                     scale: Optional[float] = None) -> jax.Array:
+                     scale: Optional[float] = None,
+                     block: int = 0) -> jax.Array:
     """Attention over a slot-pool KV cache.
 
     q: [B,S,H,D] (post-rope); pool_k/pool_v: [T,Hkv,D] flat slot pools
@@ -215,7 +226,9 @@ def cached_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     so one kernel serves chunked prefill (S>1) and decode (S=1).  With
     ``window``, a query sees the last ``window`` positions up to its
     own only.  ``scale``: the factor on the scores where it is not
-    1 / sqrt(D)."""
+    1 / sqrt(D).  ``block`` > 0: causality by BLOCKS of that many
+    positions, a query sees its whole block (whose rows this call's
+    chunk wrote: a chunk ends on a block's end) and every earlier one."""
     b, s, h, d = q.shape
     hkv = pool_k.shape[1]
     group = h // hkv
@@ -224,7 +237,8 @@ def cached_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     q5 = q.reshape(b, s, hkv, group, d)
     logits = jnp.einsum("bshgd,blhd->bhgsl", q5, ck).astype(jnp.float32)
     logits = _scaled(logits, d, scale)
-    mask = (ctx_pos[:, None, :] <= q_pos[:, :, None]) \
+    seen = q_pos if not block else (q_pos // block + 1) * block - 1
+    mask = (ctx_pos[:, None, :] <= seen[:, :, None]) \
         & ctx_mask[:, None, :]                      # [B,S,L]
     if window is not None:
         mask = mask & (ctx_pos[:, None, :] > q_pos[:, :, None] - window)

@@ -47,6 +47,14 @@ the table's first page begins at, and a key at position p counts when
 `context_lens[b] - window <= p < context_lens[b]`.  The kernel is then
 named `paged_attention_decode_window`, so a trace tells the two kinds of
 layer apart.
+
+A BLOCK of S > 1 queries a lane (`q` [B, S, H, D]: a model that
+generates by diffusion over blocks, models/laguna.py) is the same walk
+with S x G query rows a KV head where a decode step has G: every query
+of the block sees every row below `context_lens[b]`, the block's own
+rows among them (the caller wrote them first), so there is no mask
+among the last S positions and nothing else changes.  The kernel is
+then named `paged_attention_block`.
 """
 
 from __future__ import annotations
@@ -185,9 +193,10 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                     window: Optional[int] = None,
                     starts: Optional[jax.Array] = None,
                     scale: Optional[float] = None) -> jax.Array:
-    """Single-token decode attention over paged KV pools.
+    """Decode attention over paged KV pools: one query a lane, or a
+    block of S that all see the same rows (the module's text).
 
-    q: [B, 1, H, D] post-rope queries (the current token's k/v must
+    q: [B, 1, H, D] (or [B, S, H, D]) post-rope queries (the current token's k/v must
     already be written into the pools); pool_k/pool_v: [T, Hkv, D];
     block_tables: [B, W] physical page of each logical page; and
     context_lens: [B] live tokens per lane (position < context_lens[b]
@@ -230,7 +239,7 @@ def _paged_call(q, pool_k, pool_v, block_tables, context_lens, starts, *,
     from jax.experimental.pallas import tpu as pltpu
 
     b, s, h, d = q.shape
-    assert s == 1, f"paged_attention is decode-only (S=1), got S={s}"
+    assert s == 1 or window is None, "a block of queries has no window"
     num_slots, hkv, _ = pool_k.shape
     assert num_slots % page_size == 0, "pool not page-aligned"
     num_pages = num_slots // page_size
@@ -239,7 +248,14 @@ def _paged_call(q, pool_k, pool_v, block_tables, context_lens, starts, *,
     if scale is None:
         scale = 1.0 / (d ** 0.5)
 
-    qr = q.reshape(b, hkv, g, d)                     # GQA head grouping
+    if s == 1:
+        qr = q.reshape(b, hkv, g, d)                 # GQA head grouping
+    else:
+        # a block's queries are more rows of their KV head: [B, Hkv,
+        # S x G, D]
+        qr = q.reshape(b, s, hkv, g, d).transpose(0, 2, 1, 3, 4).reshape(
+            b, hkv, s * g, d)
+        g = s * g
     kp = pool_k.reshape(num_pages, page_size, hkv, d)
     vp = pool_v.reshape(num_pages, page_size, hkv, d)
     bt = block_tables.astype(jnp.int32)
@@ -292,7 +308,11 @@ def _paged_call(q, pool_k, pool_v, block_tables, context_lens, starts, *,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
-        name="paged_attention_decode" if window is None
+        name="paged_attention_block" if s > 1
+        else "paged_attention_decode" if window is None
         else "paged_attention_decode_window",
     )(*scalars, qr, kp, vp)
-    return out.reshape(b, 1, h, d)
+    if s == 1:
+        return out.reshape(b, 1, h, d)
+    return out.reshape(b, hkv, s, h // hkv, d).transpose(
+        0, 2, 1, 3, 4).reshape(b, s, h, d)

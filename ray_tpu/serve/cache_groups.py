@@ -13,11 +13,13 @@ for the arrays and the count, which are the stepping thread's:
                       `tokens`: the prompt, where a prefix may be matched
   admit(plan)         take it -> (held, tokens already there, row copy)
   advance(held, lo, hi)      before a pass with queries at [lo, hi)
-  prefill_arrays(rows, lanes, cols, width), decode_arrays(rows, lanes, width)
-                      the kind's entry of a pass's `groups`, gathered or
+  prefill_arrays(rows, lanes, cols, width), decode_arrays(rows, lanes,
+  width, cols)        the kind's entry of a pass's `groups`, gathered or
                       as block tables; `rows` = [(lane, {kind: held}, lo,
                       hi)], a lane without a row is garbage (slot 0,
-                      nothing to see), so `rows=[]` is a warm-up
+                      nothing to see), so `rows=[]` is a warm-up.  A
+                      decode pass has `cols` = 1 query a lane, or a
+                      model's whole block of them (serve/llm.py)
   count(rows, arrays, decode)    what that pass reads, into `totals`
   written(held, tokens, upto)    rows below `upto` will not change
   row_slots(held, n, take)   the slots of the rows a sequence with `n`
@@ -122,18 +124,19 @@ class _Pages(_Group):
         return {"slots": slots, "ctx": ctx, "ctx_pos": ctx_pos,
                 "ctx_mask": ctx_mask}
 
-    def decode_arrays(self, rows, lanes: int, width: int):
-        """Block tables (hi = lo + 1): the pages a lane's query sees, at
+    def decode_arrays(self, rows, lanes: int, width: int, cols: int = 1):
+        """Block tables: the pages a lane's queries at [lo, hi) see (one
+        query, or a block of `cols` that all see the rows below `hi`), at
         most `w` of them, from position `starts` on where the kind has a
         window."""
         w, ps = min(width, self.table_width), self.page_size
-        slots = np.zeros((lanes, 1), np.int32)
+        slots = np.zeros((lanes, cols), np.int32)
         tables = np.zeros((lanes, w), np.int32)
         starts = np.zeros((lanes,), np.int32)
         lens = np.zeros((lanes,), np.int32)
         for lane, held, lo, hi in rows:
             st = held[self.kind]
-            slots[lane, 0] = st.slots[lo]
+            slots[lane, :hi - lo] = st.slots[lo:hi]
             first = max(0, hi - self.window) // ps if self.window else 0
             pages = st.pages[first:(hi - 1) // ps + 1][:w]
             tables[lane, :len(pages)] = pages
@@ -169,11 +172,21 @@ class FullPages(_Pages):
     page under the same parent chain (`_children`) into a private one —
     the row copy `admit` returns.  Shared pages are immutable: a
     sequence writes at positions >= its own `pos` only.  A latent row is
-    a ROW FORM of this kind, not a kind (counted apart, `latent_*`)."""
+    a ROW FORM of this kind, not a kind (counted apart, `latent_*`).
+
+    Where the model generates in BLOCKS of `block` positions (a page is
+    whole blocks), a row depends on its whole block's tokens and is
+    rewritten until the block commits: the engine calls `written` with
+    the end of the last COMMITTED block, and a mid-page copy takes whole
+    blocks only."""
 
     def __init__(self, spec, page_size: int, num_pages: int, ctx_len: int,
-                 prefix_sharing: bool, refused: str):
+                 prefix_sharing: bool, refused: str, block: int = 1):
         super().__init__("full", page_size, num_pages, ctx_len)
+        if page_size % block:
+            raise ValueError(f"pages of {page_size} positions do not hold "
+                             f"whole blocks of {block}")
+        self.block = block
         # refused, not silently wrong, where another group cannot share
         self.prefix_sharing = bool(prefix_sharing) and not refused
         self.sharing_refused = refused if prefix_sharing else ""
@@ -290,6 +303,7 @@ class FullPages(_Pages):
                     if a != b:
                         break
                     m += 1
+                m -= m % self.block   # whole blocks: the class's text
                 if m > best:
                     best, best_page = m, cand
             if best > 0:
@@ -496,11 +510,13 @@ class StateSlots(_Group):
 
 
 def build(spec, dtype, *, page_size: int, num_pages: int, max_batch: int,
-          chunk: int, pages_per_seq: int, prefix_sharing: bool
-          ) -> Dict[str, _Group]:
+          chunk: int, pages_per_seq: int, prefix_sharing: bool,
+          block: int = 1) -> Dict[str, _Group]:
     """The groups of a model whose config states the cache `spec`, by
     kind: `full` (`num_pages` pages, the engine's page budget), then a
-    window group for each window kind, then the state kind's slots."""
+    window group for each window kind, then the state kind's slots.
+    `block`: the positions a decode pass of the model writes together
+    (`FullPages`)."""
     kinds = kv_cache.kinds_of(spec)
     if kinds.pop("full", None) is None:
         raise ValueError("a model with no full-attention layer: the "
@@ -516,7 +532,7 @@ def build(spec, dtype, *, page_size: int, num_pages: int, max_batch: int,
     refused = next((g.no_sharing for g in groups.values()), "")
     return {"full": FullPages(spec, page_size, num_pages,
                               pages_per_seq * page_size, prefix_sharing,
-                              refused), **groups}
+                              refused, block), **groups}
 
 
 def stats(groups, pools) -> Dict[str, Any]:

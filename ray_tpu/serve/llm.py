@@ -64,6 +64,25 @@ lanes, clock, run-ahead, streaming, deadlines, sampling, pass shapes.
 Copy-on-write prefix sharing (``prefix_sharing``) is the full group's: a
 sequence whose page-aligned prompt prefix matches a live one's attaches
 to the SAME pages and prefills from the first unshared token.
+
+A model that GENERATES BY DIFFUSION OVER BLOCKS (its config's
+``block_length`` B > 0, models/laguna.py) is served by the same loop with
+a decode pass that carries a whole block a lane (`_Block`): the prompt's
+whole leading blocks are prefilled (their rows are final: a row depends
+on its own block and earlier ones), the prompt's tail opens the first
+block, and a BLOCK PASS runs the model on a lane's B positions over the
+committed rows and the block's own, which it writes into the sequence's
+page before its queries read them and OVERWRITES at every pass.  The
+family's sampler, on the device behind the model, unmasks positions; a
+pass that finds no mask left changes nothing and is the COMMIT, the last
+overwrite, after which the next block opens.  A request may carry
+``denoising_steps`` (1 ... B).  A stream's item is a block: it is
+delivered when no mask is left in it (the prompt's tail not among its
+tokens, the last block cut at ``max_new_tokens``), so ``ttft`` is the
+first block and the time between tokens is a block's passes over its
+tokens.  The run-ahead stays (`_Block`): where a pass's block stands is
+the host's to know a pass late, what is in it the device's.  Pages are
+published (prefix index, shipping) up to the last committed block only.
 """
 
 from __future__ import annotations
@@ -182,7 +201,8 @@ def _pow4_widths(first: int, cap: int) -> List[int]:
 
 
 def _jit_forward(model, params, pools, tokens, q_pos, last_idx, groups,
-                 temperature=0.0, top_k=0, rng=None, top2=False, feed=None):
+                 temperature=0.0, top_k=0, rng=None, top2=False, feed=None,
+                 head=True):
     """One forward over the paged cache -> (next tokens at ``last_idx``,
     updated pools).  Jitted ONCE per (model, shapes, sampling knobs) —
     the flax module AND the sampling knobs are hashable static
@@ -215,8 +235,9 @@ def _jit_forward(model, params, pools, tokens, q_pos, last_idx, groups,
     jax.random.categorical.  Each distinct (temperature, top_k) pair is
     its own executable; lanes within one engine always share the knobs
     (per-lane temperatures would force them to be traced values).
-    ``top2`` is a third one, off in serving: see `_jitted_forward`."""
-    fn = _jitted_forward(temperature, top_k, top2)
+    ``top2`` is a third one, off in serving, and ``head`` a fourth: see
+    `_jitted_forward`."""
+    fn = _jitted_forward(temperature, top_k, top2, head)
     if rng is None:
         import jax.numpy as jnp
 
@@ -225,17 +246,20 @@ def _jit_forward(model, params, pools, tokens, q_pos, last_idx, groups,
               feed)
 
 
-def _jitted_forward(temperature=0.0, top_k=0, top2=False):
+def _jitted_forward(temperature=0.0, top_k=0, top2=False, head=True):
     """The process-wide jitted stepper for one set of static knobs.
     A model that counts on the device (`model.counters`) gets its
     counter vector appended to the tokens, one array and one transfer.
     With ``top2`` it returns a third value: each lane's two largest
     logits and their ids ([B, 2] float32, [B, 2] int32) — what a
     comparison of two engines needs to tell a rounding flip from a
-    wrong read (`LLMEngine(logit_trace=True)`)."""
+    wrong read (`LLMEngine(logit_trace=True)`).  Without ``head`` the
+    pass returns zeros for tokens and its logits are computed by nobody:
+    the prefill pass of a block model, whose first tokens a block pass
+    makes."""
     import jax
 
-    key = (float(temperature), int(top_k), bool(top2))
+    key = (float(temperature), int(top_k), bool(top2), bool(head))
     fn = _forward_cache.get(key)
     if fn is None:
         import jax.numpy as jnp
@@ -255,6 +279,12 @@ def _jitted_forward(temperature=0.0, top_k=0, top2=False):
             # a pass of fewer lanes than `last_idx` has entries (the
             # narrow prefill pass) looks at the first of them
             lanes = tokens.shape[0]
+            if not head:
+                tok = jnp.zeros((last_idx.shape[0],), jnp.int32)
+                if counted:
+                    tok = jnp.concatenate(
+                        [tok, counted[0].astype(jnp.int32)])
+                return tok, pools
             picked = jnp.take_along_axis(
                 logits, last_idx[:lanes, None, None], axis=1)[:, 0]
             if temperature <= 0.0:
@@ -287,6 +317,40 @@ def _jitted_forward(temperature=0.0, top_k=0, top2=False):
 
         fn = _forward_cache[key] = jax.jit(
             _fwd, static_argnums=0, donate_argnums=(2,))
+    return fn
+
+
+def _jitted_block_forward():
+    """The process-wide jitted BLOCK pass: the model on every lane's
+    block, then the family's sampler (`sample`, static like the model:
+    `block_sample(cfg, tokens, logits, need)`) on the device.  ``feed``
+    = (src, the last block pass's output): a lane whose `src` >= 0 takes
+    its block's tokens from there, as the device left them.  Returns one
+    int32 array — the blocks' next state [lanes x B], then a lane the
+    positions masked at entry, unmasked by the pass, unmasked by the
+    threshold alone, then the model's counter vector — and the pools."""
+    import jax
+
+    fn = _forward_cache.get("block")
+    if fn is None:
+        import jax.numpy as jnp
+
+        def _fwd(model, sample, params, pools, tokens, q_pos, need, groups,
+                 feed):
+            src, last = feed
+            lanes, b = tokens.shape
+            late = last[:lanes * b].reshape(lanes, b)[jnp.maximum(src, 0)]
+            tokens = jnp.where(src[:, None] >= 0, late, tokens)
+            cache = {**pools, "q_pos": q_pos, "groups": groups}
+            logits, pools, *counted = model.apply(
+                {"params": params}, tokens, cache)
+            state, *counts = sample(model.cfg, tokens, logits, need)
+            return jnp.concatenate(
+                [state.reshape(-1), *counts,
+                 *(c.astype(jnp.int32) for c in counted)]), pools
+
+        fn = _forward_cache["block"] = jax.jit(
+            _fwd, static_argnums=(0, 1), donate_argnums=(3,))
     return fn
 
 
@@ -511,13 +575,45 @@ class _Flight:
     """A dispatched pass whose output the host has not read: the step
     that dispatched it, the output as the device holds it, and who is
     owed a token of it — `lanes` = [(lane, sequence)]: every lane of a
-    decode pass, of a prefill pass the lanes whose prompt ended in it."""
+    decode pass, of a prefill pass the lanes whose prompt ended in it.
+    A block pass's are (lane, sequence, the block's first position, the
+    block as the pass read it where the host knew: `_Block`)."""
 
     __slots__ = ("kind", "step", "out", "top2", "lanes")
 
     def __init__(self, kind: str, step: int, out, top2, lanes):
         self.kind, self.step, self.out = kind, step, out
         self.top2, self.lanes = top2, lanes
+
+
+class _Block:
+    """Where a sequence of a block model stands, as far as the host
+    knows — which is a pass late: a step dispatches pass n + 1 before it
+    reads pass n.
+
+    `p0`, `k`: the first position of the block the NEXT pass works on,
+    and that pass's number in its block (`sched[k]` positions it has to
+    unmask at least).  `cur`: the block's tokens as the next pass will
+    read them where `known`, else as the pass in flight read them.
+    What the host cannot know is what a denoising pass in flight leaves:
+    the next pass then takes the block from the device (`feed`) and is,
+    as the device finds it, one more denoising pass or the commit — at
+    the same positions either way.  What it can: a pass that READ a
+    block with no mask left is that block's commit, so the pass behind
+    it opens the next block, with the host's tokens.  `emit_p0`: the
+    first block not delivered yet.  `last`: the newest block state read
+    back.  `passes`: the record a request asked for (`record_passes`):
+    [first position, the block as read, as left] a pass read."""
+
+    __slots__ = ("p0", "k", "cur", "known", "sched", "emit_p0", "last",
+                 "passes")
+
+    def __init__(self, p0: int, cur: List[int], sched, record: bool):
+        self.p0 = self.emit_p0 = p0
+        self.k, self.cur, self.known = 0, cur, True
+        self.sched = sched
+        self.last = cur
+        self.passes: Optional[List[list]] = [] if record else None
 
 
 class _Seq:
@@ -528,7 +624,8 @@ class _Seq:
                  "cancelled", "cond", "deadline", "kv_import",
                  "prefill_export", "export_payload", "trace_ctx",
                  "prefix_tokens", "submit_step", "admit_step",
-                 "first_token_step", "ahead", "feed")
+                 "first_token_step", "ahead", "feed", "blk",
+                 "prefilled_at", "prefilled_step")
 
     def __init__(self, request_id: str, prompt: List[int], max_new: int,
                  eos: Optional[int], preknown: Optional[List[int]] = None):
@@ -555,6 +652,11 @@ class _Seq:
         # `ahead` > 0
         self.ahead = 0
         self.feed = -1
+        # a block model's: where its open block stands, and when its
+        # prompt's whole blocks were prefilled (`llm.first_block`)
+        self.blk: Optional[_Block] = None
+        self.prefilled_at: Optional[float] = None
+        self.prefilled_step = -1
         self.state = _QUEUED
         self.done = False
         self.error: Optional[BaseException] = None
@@ -643,6 +745,16 @@ class LLMEngine:
 
             cfg = dataclasses.replace(cfg, dtype=dtype)
         self.cfg = cfg
+        # a model that generates by diffusion over blocks: B positions a
+        # lane a decode pass (0: a token); the module's text
+        self._block = int(getattr(cfg, "block_length", 0) or 0)
+        if self._block and (float(temperature) > 0 or logit_trace
+                            or int(page_size) % self._block
+                            or int(prefill_chunk) % self._block):
+            raise ValueError(
+                f"a model of blocks of {self._block} is sampled greedily, "
+                f"records passes (`record_passes`) where another records "
+                f"logits, and needs pages and prefill chunks of whole blocks")
         self.page_size = int(page_size)
         self.max_batch = int(max_batch)
         self.prefill_chunk = int(prefill_chunk)
@@ -666,7 +778,7 @@ class LLMEngine:
             spec, cfg.dtype, page_size=self.page_size,
             num_pages=self.num_pages, max_batch=self.max_batch,
             chunk=self.prefill_chunk, pages_per_seq=self.pages_per_seq,
-            prefix_sharing=prefix_sharing)
+            prefix_sharing=prefix_sharing, block=self._block or 1)
         # seconds of this replica's start-up, by part: until the weights
         # the engine serves from were on the device, the call that
         # allocates the KV pools, and `warm_up`'s compiles.  The device
@@ -751,7 +863,11 @@ class LLMEngine:
         self._flight: deque = deque()
         self._clock = _StepClock(self._flight)
         counters = len(getattr(self._model, "counters", ()))
-        self._no_feed = (jnp.zeros((self.max_batch + counters,), jnp.int32),
+        # a lane's entries in a decode pass's output: its token, or its
+        # block's next state and three counts (`_jitted_block_forward`)
+        self._lane_out = self._block + 3 if self._block else 1
+        self._no_feed = (jnp.zeros((self.max_batch * self._lane_out
+                                    + counters,), jnp.int32),
                          jnp.zeros((self.prefill_lanes + counters,),
                                    jnp.int32))
         self._feed = list(self._no_feed)
@@ -781,6 +897,24 @@ class LLMEngine:
                         "first_tokens_total": 0, "finished_total": 0,
                         "queue_wait_secs_total": 0.0,
                         "prefill_wait_secs_total": 0.0}
+        # a block model's (the module's text), by what a lane's pass
+        # turned out to be when it was read — `denoise`: it found a mask;
+        # `commit`: none, the block's last overwrite — and a whole pass
+        # `denoise` where any lane of it was.  Read: blocks delivered (no
+        # mask left; all but a sequence's last are then committed),
+        # positions unmasked, those the threshold alone allowed, those
+        # computed past `max_new`.  Dispatched: rows the block kernel
+        # read (a lane's context, a layer) and open rows overwritten (a
+        # block's passes after its first, a layer).
+        self._block_totals: Dict[str, Any] = {} if not self._block else {
+            "block_passes_total": {"denoise": 0, "commit": 0},
+            "block_lane_passes_total": {"denoise": 0, "commit": 0},
+            "blocks_committed_total": 0,
+            "block_tokens_transferred_total": 0,
+            "block_tokens_over_threshold_total": 0,
+            "block_tokens_discarded_total": 0,
+            "block_rows_read_total": 0,
+            "block_open_rows_rewritten_total": 0}
         self._prefill_widths = self._prefill_ctx_buckets()
         self._prefill_passes_by_width = dict.fromkeys(
             self._prefill_widths, 0)
@@ -826,13 +960,26 @@ class LLMEngine:
         eos = int(eos) if eos is not None else None
         rid = str(request.get("request_id") or uuid.uuid4().hex[:16])
         prefill_only = request.get("_phase") == "prefill"
+        block = self._block
+        steps = int(request.get("denoising_steps")
+                    or getattr(self.cfg, "denoising_steps", 0) or block)
+        if block:
+            if not 1 <= steps <= block:
+                raise ValueError(f"denoising_steps must be 1 ... {block}")
+            if self.cfg.mask_token_id in prompt:
+                raise ValueError("the prompt holds the mask's id")
+            if prefill_only and len(prompt) < block:
+                raise ValueError("a prompt shorter than a block has no "
+                                 "rows to ship")
+        # the rows a prompt's prefill leaves: all of it, or its whole blocks
+        prefilled = len(prompt) - len(prompt) % (block or 1)
         if kv_pack is not None:
             meta = kv_pack[0]
             # the shipment must describe exactly this prompt: the rows
             # are attached positionally, so any mismatch would decode
             # against another request's KV
             if (list(meta.get("tokens") or []) != prompt
-                    or int(meta.get("n", -1)) != len(prompt)):
+                    or int(meta.get("n", -1)) != prefilled):
                 kv_pack = None
         # end-to-end deadline: the ambient context (stamped into the
         # replica task by the handle / the X-Request-Deadline-Ms
@@ -853,16 +1000,18 @@ class LLMEngine:
             # disaggregated phases price separately: a prefill-only
             # pass needs its chunks but no decode step, and a sequence
             # arriving WITH shipped KV needs one decode step but no
-            # prefill chunks.
+            # prefill chunks.  A block model's first item is its first
+            # block: as many passes as its schedule has.
             need = 0.0
             if self._step_ewma > 0.0:
-                chunks = -(-len(prompt) // self.prefill_chunk)
+                chunks = -(-prefilled // self.prefill_chunk)
+                first = steps if block else 1
                 if kv_pack is not None:
-                    need = self._step_ewma
+                    need = self._step_ewma * first
                 elif prefill_only:
                     need = self._step_ewma * chunks
                 else:
-                    need = self._step_ewma * (chunks + 1)
+                    need = self._step_ewma * (chunks + first)
             if rem <= need:
                 self._deadline_expired_total += 1
                 deadlines.count_exceeded("admission")
@@ -900,6 +1049,9 @@ class LLMEngine:
                 raise LLMOverloadedError(
                     f"admission queue full ({self.max_queue})")
             seq = _Seq(rid, prompt, max_new, eos)
+            if block:
+                seq.blk = self._open_first_block(
+                    seq, steps, bool(request.get("record_passes")))
             seq.trace_ctx = tracing.current_context()
             seq.submit_step = self._steps
             self._totals["submitted_total"] += 1
@@ -996,12 +1148,29 @@ class LLMEngine:
             import jax
 
             self._sample_rng, rng = jax.random.split(self._sample_rng)
+        if self._block:
+            # a block model's token-shaped pass is its prefill, whose
+            # logits nobody reads
+            tok, self._pools = self._step_fn(
+                self._model, self._params, self._pools, tokens, q_pos,
+                last_idx, groups, head=False)
+            self._clock.dispatched()
+            return tok, None
         tok, self._pools, *top2 = self._step_fn(
             self._model, self._params, self._pools, tokens, q_pos,
             last_idx, groups, temperature=self.temperature,
             top_k=self.top_k, rng=rng, top2=self.logit_trace, feed=feed)
         self._clock.dispatched()
         return tok, (top2[0] if top2 else None)
+
+    def _forward_block(self, tokens, q_pos, need, groups, feed):
+        """Dispatch one block pass (`_jitted_block_forward`); returns
+        its output as the device holds it."""
+        out, self._pools = _jitted_block_forward()(
+            self._model, self.family.block_sample, self._params,
+            self._pools, tokens, q_pos, need, groups, feed)
+        self._clock.dispatched()
+        return out
 
     def _split_counters(self, next_tok, lanes: int, phase: str):
         """The host copy of a pass's output: its `lanes` tokens, and the
@@ -1022,7 +1191,7 @@ class LLMEngine:
         out = {}
         for kind, group in self._groups.items():
             with self._clock.host.get(group.host_span, _NO_SPAN):
-                out[kind] = group.decode_arrays(rows, lanes, width) \
+                out[kind] = group.decode_arrays(rows, lanes, width, cols) \
                     if decode else group.prefill_arrays(rows, lanes, cols,
                                                         width)
         if rows:
@@ -1057,6 +1226,8 @@ class LLMEngine:
         the lanes past them are garbage: [] is a warm-up's pass."""
         np = self._np
         b = self.max_batch
+        if self._block:
+            return self._block_inputs(decode_args, width, feed)
         tokens = np.zeros((b, 1), np.int32)
         src = np.full((b,), -1, np.int32)
         q_pos = np.zeros((b, 1), np.int32)
@@ -1069,6 +1240,30 @@ class LLMEngine:
         return (tokens, q_pos, np.zeros((b,), np.int32),
                 self._pass_groups(rows, b, 1, width, decode=True)), \
             (src, tuple(feed))
+
+    def _block_inputs(self, decode_args, width: int, feed):
+        """`_decode_inputs` of a block pass: (`_forward_block`'s
+        arguments but `feed`, `feed`) over `decode_args` = [(sequence,
+        the block's tokens or None where the device has them, the lane
+        of the last pass's output they are in, the block's first
+        position, the schedule's count, ...)]."""
+        np = self._np
+        b, n = self.max_batch, self._block
+        tokens = np.zeros((b, n), np.int32)
+        src = np.full((b,), -1, np.int32)
+        q_pos = np.zeros((b, n), np.int32)
+        need = np.zeros((b,), np.int32)
+        rows = []
+        for lane, (_seq, cur, late, p0, count, *_r, held) in enumerate(
+                decode_args):
+            if cur is not None:
+                tokens[lane] = cur
+            src[lane], need[lane] = late, count
+            q_pos[lane] = self._arange[p0:p0 + n]
+            rows.append((lane, held, p0, p0 + n))
+        return (tokens, q_pos, need,
+                self._pass_groups(rows, b, n, width, decode=True)), \
+            (src, feed[0])
 
     def _trace_top2(self, seq: _Seq, lane: int, top2) -> None:
         """Lock held, just before `_emit_token`: the two largest logits
@@ -1151,15 +1346,20 @@ class LLMEngine:
         burst while nothing is at stake (the deployment warm-up request
         lands here).  Garbage lanes only; the jit cache is process-wide,
         so engines sharing a config/geometry pay once."""
+        forward = self._forward_block if self._block else self._forward
         for width in self._paged_width_buckets():
             inputs, feed = self._decode_inputs([], width, self._no_feed)
-            self._forward(*inputs, feed=feed)
+            forward(*inputs, feed=feed)
 
     def _lower_decode(self, width: int):
         """The decode step at block-table `width`, lowered and not run:
         for its text (`device_report`) or the compiler's analysis."""
-        (tokens, q_pos, last_idx, groups), feed = self._decode_inputs(
-            [], width, self._no_feed)
+        inputs, feed = self._decode_inputs([], width, self._no_feed)
+        if self._block:
+            return _jitted_block_forward().lower(
+                self._model, self.family.block_sample, self._params,
+                self._pools, *inputs, feed)
+        tokens, q_pos, last_idx, groups = inputs
         return _jitted_forward(self.temperature, self.top_k,
                                self.logit_trace).lower(
             self._model, self._params, self._pools, tokens, q_pos, last_idx,
@@ -1247,19 +1447,27 @@ class LLMEngine:
         """Lock held, the sequence just ended: its stages as spans under
         the caller's trace — `llm.queue` (submit to admission),
         `llm.prefill` (to the first token), `llm.decode` (to the end);
-        a stage it never reached has none, the one it ended in carries
-        the error.  Once a request, not once a token.  `first_step` and
+        a block model's `llm.prefill` ends with its prompt's whole
+        blocks and `llm.first_block` follows it (to the first block
+        delivered); a stage it never reached has none, the one it ended
+        in carries the error.  Once a request, not once a token.  `first_step` and
         `last_step` are `llm.step`'s `n` on the profiler's timeline."""
         wall = time.time() - time.monotonic()  # monotonic -> epoch
         attrs = {"request_id": seq.request_id,
                  "prompt_tokens": len(seq.prompt),
                  "prefix_tokens_shared": seq.prefix_tokens,
                  "tokens_generated": len(seq.generated)}
+        first = (("llm.prefill", seq.admitted_at, seq.first_token_at,
+                  seq.admit_step, seq.first_token_step),)
+        if seq.blk is not None:
+            first = (("llm.prefill", seq.admitted_at, seq.prefilled_at,
+                      seq.admit_step, seq.prefilled_step),
+                     ("llm.first_block", seq.prefilled_at,
+                      seq.first_token_at, seq.prefilled_step,
+                      seq.first_token_step))
         stages = (
             ("llm.queue", seq.submitted_at, seq.admitted_at,
-             seq.submit_step, seq.admit_step),
-            ("llm.prefill", seq.admitted_at, seq.first_token_at,
-             seq.admit_step, seq.first_token_step),
+             seq.submit_step, seq.admit_step), *first,
             ("llm.decode", seq.first_token_at, seq.done_at,
              seq.first_token_step, self._steps))
         for name, start, end, first, last in stages:
@@ -1340,6 +1548,37 @@ class LLMEngine:
             self._totals["queue_wait_secs_total"] += \
                 seq.admitted_at - seq.submitted_at
             self._active.append(seq)
+            if self._block and seq.kv_import is None \
+                    and seq.pos >= self._prefill_end(seq):
+                # shorter than a block, or every whole block shared
+                self._prefilled(seq)
+                if seq.prefill_export:
+                    self._export_seq_locked(seq, None)
+
+    def _prefill_end(self, seq: _Seq) -> int:
+        """The tokens a sequence's prefill passes hold: all it was given
+        — or their whole blocks, the rest opening the first block."""
+        n = len(seq.prefill_tokens)
+        return n - n % self._block if self._block else n
+
+    def _prefilled(self, seq: _Seq) -> None:
+        """Lock held: the last chunk of the prompt is dispatched (or
+        there was none to dispatch)."""
+        seq.state = _SHIP if seq.prefill_export else _DECODE
+        seq.prefilled_at = time.monotonic()
+        seq.prefilled_step = self._steps
+
+    def _open_first_block(self, seq: _Seq, steps: int,
+                          record: bool = False) -> _Block:
+        """The block the tokens a sequence was given end in: their tail
+        past the last whole block, then masks; with the schedule of
+        `steps` passes a block (B / T a pass, the remainder to the first
+        passes)."""
+        n, mask = self._block, self.cfg.mask_token_id
+        p0 = self._prefill_end(seq)
+        tail = seq.prefill_tokens[p0:]
+        sched = tuple(n // steps + (k < n % steps) for k in range(steps))
+        return _Block(p0, tail + [mask] * (n - len(tail)), sched, record)
 
     def _note_first_token(self, seq: _Seq) -> None:
         """Lock held: the sequence has a token; on its first, count it
@@ -1373,6 +1612,27 @@ class LLMEngine:
             # past it (n = 1, F+1, 2F+1, ...), not one window late
             seq.cond.notify_all()
 
+    def _emit_block(self, seq: _Seq, tokens: List[int]) -> None:
+        """Lock held: a block with no mask left, delivered — `tokens`,
+        what the sequence was not given of it — cut at the budget or
+        behind an `eos`; a block is an item of the stream."""
+        room = seq.max_new - len(seq.generated)
+        self._block_totals["block_tokens_discarded_total"] += \
+            max(len(tokens) - room, 0)
+        tokens = tokens[:room]
+        ended = seq.eos is not None and seq.eos in tokens
+        if ended:
+            tokens = tokens[:tokens.index(seq.eos) + 1]
+        seq.generated.extend(tokens)
+        self._note_first_token(seq)
+        m = self.metrics()
+        if m is not None:
+            m["tokens"].inc(len(tokens), tags={"phase": "decode"})
+        if ended or len(seq.generated) >= seq.max_new:
+            self._finish_seq(seq)
+        elif seq.cond is not None:
+            seq.cond.notify_all()
+
     # ------------------------------------------- disaggregated prefill
     # Export and import both touch the KV pools, so they only ever run
     # INSIDE a step, under the engine lock, never concurrent with a
@@ -1401,7 +1661,7 @@ class LLMEngine:
         for seq in imports:
             pack, seq.kv_import = seq.kv_import, None
             n = int(pack["meta"]["n"])
-            first_tok = int(pack["meta"]["first_token"])
+            first_tok = pack["meta"]["first_token"]
             from ray_tpu.models.cache import scatter_slots
 
             self._pools = scatter_slots(
@@ -1417,24 +1677,32 @@ class LLMEngine:
             # same-prefix admissions share instead of re-importing
             self._written(seq)
             seq.state = _DECODE
-            self._emit_token(seq, first_tok)
+            if self._block:
+                self._prefilled(seq)   # its first tokens: a block pass's
+            else:
+                self._emit_token(seq, first_tok)
         return bool(imports)
 
-    def _export_seq_locked(self, seq: _Seq, first_token: int) -> None:
+    def _export_seq_locked(self, seq: _Seq, first_token: Optional[int]
+                           ) -> None:
         """Prefill-only sequence finished its last chunk: gather its KV
         rows to host memory, stash them as the export payload, and
         finish the sequence (pages recycle NOW — the payload is a host
-        copy).  ``prefill_request`` wakes on the finish notify."""
+        copy).  ``prefill_request`` wakes on the finish notify.  A block
+        model's prefill makes no token (`first_token` None) and ships
+        the rows of the prompt's whole blocks."""
         from ray_tpu.models.cache import gather_slots
 
         self._note_first_token(seq)
-        seq.generated.append(int(first_token))
+        if first_token is not None:
+            first_token = int(first_token)
+            seq.generated.append(first_token)
         n = seq.pos
         n_pages = -(-n // self.page_size)
         seq.export_payload = {
             "meta": {"request_id": seq.request_id,
                      "tokens": list(seq.prompt),
-                     "first_token": int(first_token),
+                     "first_token": first_token,
                      "n": n, "pages": n_pages,
                      "page_size": self.page_size},
             "rows": gather_slots(self._pools, self._kinds,
@@ -1552,11 +1820,11 @@ class LLMEngine:
         if decode_args:
             self._dispatch_decode(step, decode_args, feed)
             self._totals["runahead_decode_steps_total"] += ahead
-            step_tokens += len(decode_args)
+            step_tokens += len(decode_args) * (self._block or 1)
         # the previous step's outputs; this step's too where a prompt
         # ended whose pages are to be shipped (`prefill_request` waits
         # for them, and the export reads the pools behind every pass)
-        exports = any(seq.prefill_export and hi == len(seq.prefill_tokens)
+        exports = any(seq.prefill_export and hi == self._prefill_end(seq)
                       for seq, _lo, hi, *_r in prefill_args)
         read = self._read_back(None if exports else step)
         if not (prefill_args or decode_args or read):
@@ -1594,9 +1862,11 @@ class LLMEngine:
         for seq in [s for s in self._active
                     if s.state == _PREFILL][:self.prefill_lanes]:
             lo = seq.pos
-            hi = min(lo + self.prefill_chunk, len(seq.prefill_tokens))
+            hi = min(lo + self.prefill_chunk, self._prefill_end(seq))
             self._advance(seq, lo, hi)
             prefill_args.append((seq, lo, hi, seq.cache))
+        if self._block:
+            return prefill_args, self._plan_blocks_locked()
         # the decoding sequences as this step found them (one whose
         # prompt ends in this step's prefill pass decodes from the next
         # on), but for those whose every token is dispatched
@@ -1613,6 +1883,40 @@ class LLMEngine:
             decode_args.append((seq, last, seq.feed if seq.ahead else -1,
                                 seq.pos + 1, seq.cache))
         return prefill_args, decode_args
+
+    def _plan_blocks_locked(self):
+        """`_plan_locked`'s decode part for a block model: the next pass
+        of every decoding sequence that has a block left — [(sequence,
+        the block's tokens where the host knows them, else None and the
+        lane of the last pass's output that holds them, the block's
+        first position, the positions the schedule has the pass unmask
+        at least, the pass's number in its block, whether it is the
+        commit where the host knows, the sequence's `cache`)]: `_Block`."""
+        decode_args = []
+        n, mask = self._block, self.cfg.mask_token_id
+        for seq in self._active:
+            blk = seq.blk
+            if seq.state != _DECODE \
+                    or blk.p0 >= len(seq.prompt) + seq.max_new:
+                continue   # or every block of it is dispatched
+            cur, late, commit = None, seq.feed, None
+            if blk.known:
+                cur, late, commit = blk.cur, -1, mask not in blk.cur
+            count = blk.sched[blk.k] if blk.k < len(blk.sched) else n
+            self._advance(seq, blk.p0, blk.p0 + n)
+            decode_args.append((seq, cur, late, blk.p0, count, blk.k,
+                                commit, seq.cache))
+        return decode_args
+
+    def _next_block(self, seq: _Seq) -> None:
+        """Lock held: the block's commit is dispatched, so its rows stand
+        and may be published; the next pass opens the block behind it."""
+        blk, n = seq.blk, self._block
+        blk.p0 += n
+        blk.k, blk.known = 0, True
+        blk.cur = [self.cfg.mask_token_id] * n
+        seq.pos = blk.p0
+        self._written(seq)
 
     def _advance(self, seq: _Seq, lo: int, hi: int) -> None:
         """Lock held: the sequence's next pass has queries at [lo, hi)."""
@@ -1664,8 +1968,12 @@ class LLMEngine:
                 # pass on, which is dispatched: later admissions with the
                 # same prompt prefix may share them (their passes run after)
                 self._written(seq)
-                if hi == len(seq.prefill_tokens):
-                    seq.state = _SHIP if seq.prefill_export else _DECODE
+                if hi < self._prefill_end(seq):
+                    continue
+                self._prefilled(seq)
+                if not self._block or seq.prefill_export:
+                    # a token is owed: the pass's at this lane, or (a
+                    # block model's) the read that ships the pages
                     seq.ahead += 1
                     seq.feed = self._no_feed[0].shape[0] + lane
                     owed.append((lane, seq))
@@ -1691,28 +1999,45 @@ class LLMEngine:
         # covering the max used pages across lanes: decode cost tracks
         # USED context, and the jit retrace per bucket is
         # O(log pages_per_seq) traces total.
-        max_used = max(-(-n // self.page_size)
-                       for _s, _t, _l, n, _h in decode_args)
+        block = self._block
+        max_used = max(-(-(arg[3] + block) // self.page_size)
+                       for arg in decode_args)
         width = next(w for w in self._paged_width_buckets()
                      if w >= max_used)
         inputs, feed = self._decode_inputs(decode_args, width, feed)
         phase("decode_dispatch")
-        out, top2 = self._forward(*inputs, feed=feed)
+        if block:
+            out, top2 = self._forward_block(*inputs, feed=feed), None
+        else:
+            out, top2 = self._forward(*inputs, feed=feed)
         self._feed[0] = out
         self._decode_steps += 1
         self._totals["decode_lane_steps_total"] += len(decode_args)
         owed = []
         with self._lock:
-            for lane, (seq, *_rest) in enumerate(decode_args):
+            for lane, (seq, *rest) in enumerate(decode_args):
                 if seq.done:
                     continue  # cancelled while we built
-                seq.pos += 1
                 seq.ahead += 1
                 seq.feed = lane
-                owed.append((lane, seq))
+                if not block:
+                    seq.pos += 1
+                    owed.append((lane, seq))
+                    continue
+                cur, _late, p0, _count, k, commit, _held = rest
+                owed.append((lane, seq, p0, cur))
+                layers = len(self._kinds)
+                self._block_totals["block_rows_read_total"] += \
+                    layers * (p0 + block)
+                self._block_totals["block_open_rows_rewritten_total"] += \
+                    layers * block * (k > 0)
+                if commit:
+                    self._next_block(seq)
+                else:
+                    seq.blk.k, seq.blk.known = k + 1, False
         self._flight.append(_Flight("decode", step, out, top2, owed))
         m = self.metrics()
-        if m is not None:
+        if m is not None and not block:   # a block's: when delivered
             m["tokens"].inc(len(decode_args), tags={"phase": "decode"})
 
     def _read_back(self, before: Optional[int] = None) -> bool:
@@ -1743,6 +2068,9 @@ class LLMEngine:
                 self._clock.turnaround(of=flight[-1].step)
             lanes = host.shape[0] - len(self._model_counters)
             toks = self._split_counters(host, lanes, rec.kind)
+            if self._block and rec.kind == "decode":
+                self._read_blocks(rec, toks)
+                continue
             with self._lock:
                 for lane, seq in rec.lanes:
                     seq.ahead -= 1
@@ -1752,11 +2080,67 @@ class LLMEngine:
                         self._totals["decode_lane_steps_wasted_total"] \
                             += rec.kind == "decode"
                     elif seq.prefill_export:
-                        self._export_seq_locked(seq, int(toks[lane]))
+                        self._export_seq_locked(
+                            seq, None if self._block else int(toks[lane]))
                     else:
                         self._trace_top2(seq, lane, top2)
                         self._emit_token(seq, int(toks[lane]))
         return read
+
+    def _read_blocks(self, rec: _Flight, out) -> None:
+        """`_read_back`'s lock section for a block pass: `out`, its host
+        copy without the model's counters (`_jitted_block_forward`).  A
+        lane's pass is counted by what it found; a block with no mask
+        left is delivered, once; and what the sequence's `_Block` says
+        of the pass to come is brought up to what this one left."""
+        n, mask, b = self._block, self.cfg.mask_token_id, self.max_batch
+        state = out[:b * n].reshape(b, n)
+        masked, moved, over = out[b * n:].reshape(3, b)
+        totals = self._block_totals
+        lanes = {"denoise": 0, "commit": 0}
+        with self._lock:
+            for lane, seq, p0, read_as in rec.lanes:
+                seq.ahead -= 1
+                lanes["denoise" if masked[lane] else "commit"] += 1
+                totals["block_tokens_transferred_total"] += int(moved[lane])
+                totals["block_tokens_over_threshold_total"] += \
+                    int(over[lane])
+                if seq.done:
+                    # it ended (its last block, an eos, a cancel, an
+                    # expiry) while this pass was in flight
+                    self._totals["decode_lane_steps_wasted_total"] += 1
+                    continue
+                blk, cur = seq.blk, state[lane].tolist()
+                if blk.passes is not None:
+                    blk.passes.append(
+                        [p0, list(blk.last if read_as is None else read_as),
+                         cur])
+                blk.last = cur
+                if p0 == blk.emit_p0 and mask not in cur:
+                    blk.emit_p0 += n
+                    totals["blocks_committed_total"] += 1
+                    self._emit_block(
+                        seq, cur[max(len(seq.prefill_tokens) - p0, 0):])
+                    if seq.done:
+                        continue
+                if p0 != blk.p0 or blk.known:
+                    continue   # an earlier block's pass, or its commit
+                # the pass behind this one, dispatched or to come, reads
+                # the block as this one left it
+                blk.cur = cur
+                if not seq.ahead:
+                    blk.known = True
+                elif mask not in cur:
+                    self._next_block(seq)   # the pass in flight commits
+            for kind, count in lanes.items():
+                totals["block_lane_passes_total"][kind] += count
+        m = self.metrics()
+        for kind, count in lanes.items():
+            if m is not None and count:
+                m["block_passes"].inc(count, tags={"kind": kind})
+        if rec.lanes:
+            totals["block_passes_total"][
+                "denoise" if lanes["denoise"] else "commit"] += 1
 
     def warm_up(self) -> None:
         """Compile every program traffic can reach, before any loop or
@@ -1764,7 +2148,9 @@ class LLMEngine:
         pass compiles every prefill context width and the narrow pass,
         its first decode step every decode width."""
         t0 = time.perf_counter()
-        self.generate_batch([{"tokens": [1], "max_new_tokens": 2}])
+        # a block model prefills whole blocks only: one, and a tail
+        self.generate_batch([{"tokens": [1] * (self._block + 1),
+                              "max_new_tokens": 2}])
         self.startup_secs["warm"] = time.perf_counter() - t0
         self._weights_waiter.join(60.0)  # a forward ran: they are there
 
@@ -1842,7 +2228,8 @@ class LLMEngine:
     def metrics(self):
         if self._metrics is None:
             try:
-                from ray_tpu._private.metrics import (llm_metrics,
+                from ray_tpu._private.metrics import (llm_block_metrics,
+                                                      llm_metrics,
                                                       llm_prefix_metrics)
 
                 (tokens, pages, batch, ttft, queue, tps,
@@ -1853,7 +2240,8 @@ class LLMEngine:
                                  "queue": queue, "tps": tps,
                                  "decode_step": decode_step,
                                  "prefix_hits": prefix_hits,
-                                 "shipped": shipped}
+                                 "shipped": shipped,
+                                 "block_passes": llm_block_metrics()}
             except Exception:
                 return None
         return self._metrics
@@ -1874,7 +2262,12 @@ class LLMEngine:
     def stats(self) -> Dict[str, Any]:
         """Counters and gauges of this engine.  Every `*_total`,
         `*_secs` and `*_steps` key is cumulative and never falls: a
-        reader takes the change between two calls."""
+        reader takes the change between two calls.  For a model that
+        generates in blocks, `decode_steps` and `decode_secs` count
+        BLOCK passes, `decode_lane_steps_total` and
+        `decode_lane_steps_wasted_total` a lane's passes of a whole block
+        each, and the `block_*` keys (the constructor has what each
+        counts) are there; for every other model they are not."""
         from ray_tpu.ops import compile_counts
         from ray_tpu.serve import cache_groups
 
@@ -1890,6 +2283,8 @@ class LLMEngine:
                     - self._warm_secs["prefill"],
                     **self._clock.stats(),
                     **self._totals,
+                    **{key: dict(v) if isinstance(v, dict) else v
+                       for key, v in self._block_totals.items()},
                     **{name: dict(by_pass) for name, by_pass
                        in self._model_counters.items()},
                     "prefill_passes_by_width":
@@ -1944,6 +2339,9 @@ class LLMEngine:
                 seq.cond = threading.Condition(self._lock)
                 if len(seq.generated) >= seq.max_new:
                     continue  # finished before the snapshot landed
+                if self._block:
+                    seq.blk = self._open_first_block(
+                        seq, self.cfg.denoising_steps or self._block)
                 seq.detached_at = now  # grace window for re-attach
                 self._totals["submitted_total"] += 1
                 self._by_rid[rid] = seq

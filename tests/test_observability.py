@@ -342,6 +342,7 @@ def test_metric_names_documented_in_readme(cluster):
                m.pipeline_metrics,
                m.llm_metrics,
                m.llm_prefix_metrics,
+               m.llm_block_metrics,
                m.autoscaler_metrics,
                m.serve_sheds_counter,
                m.deadline_metrics,

@@ -170,6 +170,54 @@ def test_paged_decode_compiles_at_laguna_shapes(one_chip, heads, window,
     assert "paged_attention_decode" in text
 
 
+# the SDAR cell (benchmarks/configs/sdar-30b-a3b-chat-serve.json): 48 lanes
+# of a block of 4 queries over 32 heads and 4 KV heads, so 32 query rows a
+# KV head, at every table width its engine asks for (contexts to 4096)
+@pytest.mark.parametrize("width", [4, 16, 64, 256])
+def test_block_kernel_compiles_at_the_sdar_cells_shapes(one_chip, width):
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lanes, block, hkv = 48, 4, 4
+    slots = (1 + lanes * 256) * PAGE
+    compiled = jax.jit(
+        lambda q, k, v, bt, cl: paged_attention(
+            q, k, v, bt, cl, page_size=PAGE, interpret=False)
+    ).lower(spec((lanes, block, 32, D), jnp.bfloat16),
+            spec((slots, hkv, D), jnp.bfloat16),
+            spec((slots, hkv, D), jnp.bfloat16),
+            spec((lanes, width), jnp.int32),
+            spec((lanes,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_attention_block" in text
+    assert "paged_attention_decode" not in text
+
+
+@pytest.mark.parametrize("tokens", [192, 512, 2048],
+                         ids=["blocks", "narrow", "wide"])
+def test_expert_layer_compiles_at_the_sdar_cells_shapes(one_chip, tokens):
+    """`ops.moe.moe_layer` as the cell runs it: 128 experts of width 768
+    all held, 8 a token, for a block pass (48 lanes x 4 positions: 12
+    rows an expert on average) and its prefill passes (2 x 256 and
+    8 x 256: the cell's `prefill_chunk`)."""
+    from ray_tpu.ops import moe
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda x, wr, w1, w3, w2, valid: moe.moe_layer(
+            x, wr, w1, w3, w2, top_k=8, held=(0, 128), valid=valid,
+            interpret=False)
+    ).lower(spec((tokens, 2048), jnp.bfloat16),
+            spec((2048, 128), jnp.bfloat16),
+            spec((128, 2048, 768), jnp.bfloat16),
+            spec((128, 2048, 768), jnp.bfloat16),
+            spec((128, 768, 2048), jnp.bfloat16),
+            spec((tokens,), jnp.bool_)).compile()
+    assert compiled.as_text().count("moe_experts") >= 2
+
+
 @pytest.mark.parametrize("tokens", [32, 512], ids=["decode", "prefill"])
 def test_expert_layer_compiles_at_laguna_shapes(one_chip, tokens):
     """`ops.moe.moe_layer` for a decode batch (row tiles of 16) and a
