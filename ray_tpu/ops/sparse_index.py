@@ -12,7 +12,8 @@ them while it sees no more than that); a tie goes to the lower position.
 
 `index_scores`   the kernel `sparse_index_scores`: every query of a pass
     (a chunk's, or a decode lane's one) against the lane's pages of the
-    index pool, a block of keys a grid step; what a query does not see
+    index pool, a block of keys a grid step (the fewer, the more query
+    rows the chunk has: `_score_block_pages`); what a query does not see
     (past its own position, past the lane's length) reads -inf.  The
     pool stays where it lies, `[T, d]` in HBM: the kernel walks the
     lane's block table itself as the attention kernels do
@@ -51,14 +52,21 @@ import jax.numpy as jnp
 # below 30k rows a lane: PERF.md, PR 43)
 _SCORE_BLOCK_KEYS = 512
 _SCORE_BLOCK_KEYS_ONE_QUERY = 4096
+# ... and the float32 products a step of a chunk may hold, those 4 MB:
+# a chunk of more query rows than 32 x 64 takes fewer keys a step (128 at
+# 32 heads x 256 queries, the deep prefill pass's), the product the same
+_SCORE_BLOCK_PRODUCTS = 32 * 64 * _SCORE_BLOCK_KEYS
 # what the kernel may take of VMEM: the compiler's own allowance
 _SCORE_VMEM_BYTES = 16 << 20
 
 
-def _score_block_pages(chunk: int, table_width: int, page_size: int) -> int:
+def _score_block_pages(chunk: int, table_width: int, page_size: int,
+                       heads: int) -> int:
     """Pages a grid step covers: the most that divide the table and keep
-    the step within its block of keys."""
-    keys = _SCORE_BLOCK_KEYS_ONE_QUERY if chunk == 1 else _SCORE_BLOCK_KEYS
+    the step within its block of keys, and its `heads` x `chunk` x keys
+    products within theirs (a page at the least)."""
+    keys = _SCORE_BLOCK_KEYS_ONE_QUERY if chunk == 1 else min(
+        _SCORE_BLOCK_KEYS, _SCORE_BLOCK_PRODUCTS // (heads * chunk))
     pages = max(1, min(table_width, keys // page_size))
     while table_width % pages:
         pages -= 1
@@ -163,7 +171,7 @@ def _scores_call(q, w, pool, table, lens, q_pos, *, page_size: int,
 
     b, s, j, d = q.shape
     width = table.shape[1]
-    pages = _score_block_pages(s, width, page_size)
+    pages = _score_block_pages(s, width, page_size, j)
     keys = pages * page_size
 
     def _lane(bi, ki, *_scalars):

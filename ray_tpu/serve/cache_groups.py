@@ -90,7 +90,12 @@ class _Pages(_Group):
     kind's are a window kind's whose window never closes), the grid."""
 
     window = 0   # 0: every position is seen
-    ctx_width = table_width = float("inf")   # no cap on a pass's width
+    table_width = float("inf")   # no cap on a decode pass's width
+
+    def ctx_width(self, cols: int):
+        """The widest context a prefill pass of `cols` queries a lane
+        reads of this kind: no cap on the pass's width."""
+        return float("inf")
 
     def __init__(self, kind: str, page_size: int, num_pages: int,
                  ctx_len: int):
@@ -109,7 +114,7 @@ class _Pages(_Group):
     def prefill_arrays(self, rows, lanes: int, cols: int, width: int):
         """Gathered: each lane's write slots, and as context the last
         `w` positions before its `hi` (all of them for the full kind)."""
-        w = min(width, self.ctx_width)
+        w = min(width, self.ctx_width(cols))
         slots = np.zeros((lanes, cols), np.int32)
         ctx = np.zeros((lanes, w), np.int32)
         ctx_pos = np.zeros((lanes, w), np.int32)
@@ -377,10 +382,11 @@ class WindowPages(_Pages):
     the positions its next pass can read or write — from the oldest a
     query of the pass still sees to the last it writes — and `advance`
     gives the older ones back.  The pool holds `per_seq` pages for each
-    of `max_batch` sequences (`per_seq` = window + one prefill chunk,
-    in pages, + 2: a window and a chunk each start mid-page), so an
-    active sequence always finds its next page and admission never
-    waits on this group."""
+    of `max_batch` sequences (`per_seq` = window + one prefill chunk —
+    `chunk`, the widest a pass of the engine may carry a lane — in
+    pages, + 2: a window and a chunk each start mid-page), so an active
+    sequence always finds its next page and admission never waits on
+    this group."""
 
     host_span = "window_arrays"
     no_sharing = ("the model has window layers, whose pages before a "
@@ -395,12 +401,13 @@ class WindowPages(_Pages):
                          pages_per_seq * page_size)
         self.allocated_total = 0
         self.released_total = 0
-        # the widest context a pass reads here: a chunk's last query sees
-        # `window` positions back, its first as many back from ITSELF, so
-        # window + chunk - 1; in whole pages
-        self.ctx_width = -(-(window + chunk) // page_size) * page_size
         # pages a decode step's table lists: the window may start mid-page
         self.table_width = -(-window // page_size) + 1
+
+    def ctx_width(self, cols: int) -> int:
+        """A chunk's last query sees `window` positions back, its first
+        as many back from ITSELF, so window + cols - 1; in whole pages."""
+        return -(-(self.window + cols) // self.page_size) * self.page_size
 
     def admit(self, total: int):
         pages = -(-total // self.page_size)

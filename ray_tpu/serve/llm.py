@@ -116,18 +116,24 @@ _forward_cache: Dict[int, Any] = {}
 # The defaults of `LLMEngine`'s arguments, the one place they are set.
 PAGE_SIZE = 16          # KV-cache tokens per page
 MAX_BATCH = 32          # decode lanes per engine step
-PREFILL_CHUNK = 64      # prompt tokens prefilled per step: bounds how long
-# one long prompt can stall in-flight decodes
+PREFILL_CHUNK = 64      # prompt tokens a lane of the WIDE prefill pass
+# takes a step
 PREFILL_LANES = 8       # sequences prefilling one chunk each per step
-# (batched prefill: admitting N streams costs N/lanes steps)
+# (batched prefill: admitting N streams costs N/lanes steps).  What bounds
+# how long the prompts can stall the in-flight decodes is a pass's SLOTS,
+# lanes x chunk = 512, which the pass computes whatever they hold — not the
+# chunk a lane: the DEEP pass (`_deep_prefill_shape`) lays the same slots
+# out as PREFILL_NARROW_LANES lanes of 256, the same matrix products over
+# the same rows, and a long prompt advances four times as far a step
 PREFILL_NARROW_LANES = 2  # lanes of the NARROW prefill pass, which a step
-# with at most that many prompts prefilling runs (`_prefill_shape`): not an
-# argument.  Measured on the v5e (PERF.md section 6, PR 35): below the knee
-# one prompt prefills at a time, and the 8-lane pass cost 16.2 ms for its
-# 512 slots whatever they held; at 2 x 64 slots the pass costs 7.1 ms, its
-# matrix products at the weights' own stream, so fewer lanes would buy
-# nothing, and two keep a second prompt that arrives while a first one
-# prefills in the narrow pass (the chat cells ran no other: 100 %, 99.9 %)
+# with at most that many prompts prefilling runs (`_prefill_shape`), and of
+# the deep one: not an argument.  Measured on the v5e (PERF.md section 6,
+# PR 35): below the knee one prompt prefills at a time, and the 8-lane pass
+# cost 16.2 ms for its 512 slots whatever they held; at 2 x 64 slots the
+# pass costs 7.1 ms, its matrix products at the weights' own stream, so
+# fewer lanes would buy nothing, and two keep a second prompt that arrives
+# while a first one prefills in the narrow pass (the chat cells ran no
+# other: 100 %, 99.9 %)
 STREAM_FLUSH_TOKENS = 4  # tokens coalesced per stream item after the
 # first (the first token flushes immediately for TTFT); each item costs a
 # stream push + a ref resolution + an SSE chunk, so this is the per-token
@@ -223,8 +229,8 @@ def _jit_forward(model, params, pools, tokens, q_pos, last_idx, groups,
     sequence's newest token is on the device only (`LLMEngine.step`).
 
     The pass returns a token an entry of ``last_idx``.  ``tokens`` may
-    have fewer lanes than that (the narrow prefill pass): the entries
-    past them read 0, and the output keeps the shape the decode
+    have fewer lanes than that (the narrow and the deep prefill pass): the
+    entries past them read 0, and the output keeps the shape the decode
     programs' ``feed`` was compiled for.
 
     Sampling is a pair of jit-STATIC knobs (ISSUE 13 satellite / PR-11
@@ -277,7 +283,7 @@ def _jitted_forward(temperature=0.0, top_k=0, top2=False, head=True):
             logits, pools, *counted = model.apply(
                 {"params": params}, tokens, cache)
             # a pass of fewer lanes than `last_idx` has entries (the
-            # narrow prefill pass) looks at the first of them
+            # narrow or the deep prefill pass) looks at the first of them
             lanes = tokens.shape[0]
             if not head:
                 tok = jnp.zeros((last_idx.shape[0],), jnp.int32)
@@ -769,6 +775,11 @@ class LLMEngine:
             num_pages = 1 + self.max_batch * self.pages_per_seq
         self.num_pages = max(int(num_pages), 2)
         self.ctx_len = self.pages_per_seq * self.page_size
+        # the prefill programs (`_prefill_shape`): the context widths, the
+        # narrow pass's (lanes, width) and the deep pass's (lanes, chunk)
+        self._prefill_widths = self._prefill_ctx_buckets()
+        self._narrow_prefill = self._narrow_prefill_shape()
+        self._deep_prefill = self._deep_prefill_shape()
         self._model = self.family.build(cfg, self.page_size)
         # the cache, by what the model's specification says: the kind of
         # each layer, and one group a kind that occurs
@@ -777,7 +788,7 @@ class LLMEngine:
         self._groups = cache_groups.build(
             spec, cfg.dtype, page_size=self.page_size,
             num_pages=self.num_pages, max_batch=self.max_batch,
-            chunk=self.prefill_chunk, pages_per_seq=self.pages_per_seq,
+            chunk=self._widest_chunk(), pages_per_seq=self.pages_per_seq,
             prefix_sharing=prefix_sharing, block=self._block or 1)
         # seconds of this replica's start-up, by part: until the weights
         # the engine serves from were on the device, the call that
@@ -873,10 +884,11 @@ class LLMEngine:
         self._feed = list(self._no_feed)
         # cumulative, as `stats()` gives them.  Work: prompt tokens
         # prefilled, the token slots (the pass's own lanes x chunk) the
-        # prefill passes had for them, the passes that were narrow (over
-        # prefill_steps), decode lanes stepped (over decode_steps: the
-        # mean batch).  Requests by stage (finished = ended and not
-        # cancelled), and the seconds they waited for the next one.
+        # prefill passes had for them, the passes that were narrow and
+        # those that were deep (over prefill_steps), decode lanes stepped
+        # (over decode_steps: the mean batch).  Requests by stage
+        # (finished = ended and not cancelled), and the seconds they
+        # waited for the next one.
         # Context: the rows the prefill passes' real lanes read, the
         # columns (the pass's lanes x width) the passes gathered for
         # them, and the passes by the width they took.  Run-ahead: decode
@@ -888,6 +900,7 @@ class LLMEngine:
         self._totals = {"prefill_tokens_total": 0,
                         "prefill_slots_total": 0,
                         "prefill_narrow_passes_total": 0,
+                        "prefill_deep_passes_total": 0,
                         "prefill_ctx_rows_total": 0,
                         "prefill_ctx_cols_total": 0,
                         "decode_lane_steps_total": 0,
@@ -915,10 +928,8 @@ class LLMEngine:
             "block_tokens_discarded_total": 0,
             "block_rows_read_total": 0,
             "block_open_rows_rewritten_total": 0}
-        self._prefill_widths = self._prefill_ctx_buckets()
         self._prefill_passes_by_width = dict.fromkeys(
             self._prefill_widths, 0)
-        self._narrow_prefill = self._narrow_prefill_shape()
         # what the model counts on the device (`model.counters`: names
         # of the vector it returns beside its logits), summed by pass
         self._model_counters = {
@@ -1004,7 +1015,10 @@ class LLMEngine:
             # block: as many passes as its schedule has.
             need = 0.0
             if self._step_ewma > 0.0:
-                chunks = -(-prefilled // self.prefill_chunk)
+                # (the fewest passes: deep chunks, then the tail's)
+                far = self._widest_chunk()
+                chunks = prefilled // far \
+                    + -(-(prefilled % far) // self.prefill_chunk)
                 first = steps if block else 1
                 if kv_pack is not None:
                     need = self._step_ewma * first
@@ -1200,13 +1214,15 @@ class LLMEngine:
                     group.count(rows, out[kind], decode)
         return out
 
-    def _prefill_inputs(self, prefill_args, lanes: int, width: int):
-        """`_forward`'s arguments of a prefill pass of `lanes` lanes at
-        context `width` over `prefill_args` (`_plan_locked`); the lanes
-        past them are garbage: [] is a warm-up's pass."""
+    def _prefill_inputs(self, prefill_args, lanes: int, chunk: int,
+                        width: int):
+        """`_forward`'s arguments of a prefill pass of `lanes` lanes x
+        `chunk` tokens at context `width` (a program of
+        `_prefill_programs`) over `prefill_args` (`_plan_locked`); the
+        lanes past them are garbage: [] is a warm-up's pass."""
         np = self._np
-        tokens = np.zeros((lanes, self.prefill_chunk), np.int32)
-        q_pos = np.zeros((lanes, self.prefill_chunk), np.int32)
+        tokens = np.zeros((lanes, chunk), np.int32)
+        q_pos = np.zeros((lanes, chunk), np.int32)
         # a token an entry comes back (`_jit_forward`): the wide pass's
         # count whatever the lanes, the shape the decode pass feeds on
         last_idx = np.zeros((self.prefill_lanes,), np.int32)
@@ -1217,7 +1233,7 @@ class LLMEngine:
             last_idx[lane] = hi - lo - 1
             rows.append((lane, held, lo, hi))
         return tokens, q_pos, last_idx, self._pass_groups(
-            rows, lanes, self.prefill_chunk, width)
+            rows, lanes, chunk, width)
 
     def _decode_inputs(self, decode_args, width: int, feed):
         """(`_forward`'s arguments, its `feed`) of a decode pass at
@@ -1310,32 +1326,94 @@ class LLMEngine:
             return None
         return PREFILL_NARROW_LANES, self._prefill_widths[1]
 
-    def _prefill_shape(self, prompts: int, longest: int):
-        """(lanes, width) of the pass that advances `prompts` sequences
-        one chunk each, the longest context among them `longest` rows:
-        the narrow program where both fit it, else `prefill_lanes` lanes
-        at the smallest width that covers `longest`.  From what the step
-        observes alone."""
-        narrow = self._narrow_prefill
-        if narrow and prompts <= narrow[0] and longest <= narrow[1]:
-            return narrow
-        return self.prefill_lanes, next(
-            w for w in self._prefill_widths if w >= longest)
+    def _deep_prefill_shape(self):
+        """(lanes, chunk) of the DEEP prefill pass, or None for an engine
+        that has none: the wide pass's slots, `prefill_lanes` x
+        `prefill_chunk`, laid out as PREFILL_NARROW_LANES lanes (whole
+        blocks a lane, where the model generates in blocks) — the same
+        matrix products over as many rows, so the same stall of the
+        decodes behind it, and a prompt advances `prefill_lanes` /
+        PREFILL_NARROW_LANES times as far a step.  An engine that has no
+        narrow program has no deep one, for the same reasons."""
+        if not self._narrow_prefill:
+            return None
+        chunk = self.prefill_lanes * self.prefill_chunk \
+            // PREFILL_NARROW_LANES
+        chunk -= chunk % (self._block or 1)
+        return (PREFILL_NARROW_LANES, chunk) \
+            if chunk > self.prefill_chunk else None
+
+    def _widest_chunk(self) -> int:
+        """The most tokens a lane of a prefill pass takes: the deep
+        pass's, where there is one."""
+        return (self._deep_prefill or (0, self.prefill_chunk))[1]
+
+    def _prefill_programs(self) -> List[tuple]:
+        """Every (lanes, chunk, width) a prefill pass can have, the
+        programs `_warm_prefill_buckets` compiles: the wide pass at each
+        context width — at the SECOND alone where there is a deep pass,
+        which takes every context beyond it, so that an engine compiles
+        as many programs as it did without one (a program is seconds of
+        every replica's start-up; three short prompts at once gather the
+        second width's columns, masked) — the narrow pass at its one
+        width, and the deep pass at the second width and every wider one
+        (a deep chunk is where the wide pass's first width ends:
+        `_prefill_ctx_buckets`), so that a long prompt is deep from its
+        first chunk."""
+        widths, chunk = self._prefill_widths, self.prefill_chunk
+        narrow, deep = self._narrow_prefill, self._deep_prefill
+        programs = [(self.prefill_lanes, chunk, w)
+                    for w in (widths[1:2] if deep else widths)]
+        if narrow:
+            programs.append((narrow[0], chunk, narrow[1]))
+        if deep:
+            programs += [(*deep, w) for w in widths[1:]]
+        return programs
+
+    def _prefill_shape(self, waiting):
+        """(lanes, chunk, width) of the pass for `waiting` = [(pos, end)],
+        the prompts in prefill in admission order (as many as the wide
+        pass has lanes), each with its rows [pos, end) still to prefill.
+        One of three, from what the step observes alone:
+
+        DEEP (`_deep_prefill_shape`), where one of the prompts that would
+        ride it — the first PREFILL_NARROW_LANES — has a whole deep chunk
+        left, so a deep lane would be full; or where any prompt's context
+        lies beyond the narrow program's width: the wide pass would pay
+        its slots at a wide context there, and the deep pass reads a
+        quarter of the lanes.  It advances its first prompts by up to a
+        deep chunk each; the others keep their lanes and pages and wait
+        their turn, first come first served.
+        NARROW, where at most PREFILL_NARROW_LANES prompts wait and fit
+        its width; else WIDE, `prefill_lanes` lanes at the smallest width
+        that covers the longest context (at the narrow width, never
+        another, where there is a deep pass).  The chunk is settled here,
+        BEFORE the plan advances anything by it."""
+        chunk, widths = self.prefill_chunk, self._prefill_widths
+        narrow, deep = self._narrow_prefill, self._deep_prefill
+        longest = max(min(pos + chunk, end) for pos, end in waiting)
+        if deep:
+            lanes, far = deep
+            if longest > narrow[1] or any(end - pos >= far
+                                          for pos, end in waiting[:lanes]):
+                reach = max(min(pos + far, end)
+                            for pos, end in waiting[:lanes])
+                return lanes, far, next(w for w in widths[1:] if w >= reach)
+        if narrow and len(waiting) <= narrow[0] and longest <= narrow[1]:
+            return narrow[0], chunk, narrow[1]
+        return self.prefill_lanes, chunk, next(
+            w for w in (widths[1:] if deep else widths) if w >= longest)
 
     def _warm_prefill_buckets(self, but) -> None:
-        """Compile every prefill program up front (each context width of
-        the wide pass, and the narrow pass), at the FIRST prefill pass,
-        for the reason `_warm_paged_buckets` gives for decode (the
-        deployment warm-up request lands here): garbage lanes only (slot
-        0, every context column masked).  `but` is the (lanes, width)
-        that pass is about to run itself, so an engine with one program
-        runs nothing here."""
-        shapes = [(self.prefill_lanes, w) for w in self._prefill_widths]
-        if self._narrow_prefill:
-            shapes.append(self._narrow_prefill)
-        for lanes, width in shapes:
-            if (lanes, width) != but:
-                self._forward(*self._prefill_inputs([], lanes, width))
+        """Compile every prefill program up front (`_prefill_programs`),
+        at the FIRST prefill pass, for the reason `_warm_paged_buckets`
+        gives for decode (the deployment warm-up request lands here):
+        garbage lanes only (slot 0, every context column masked).  `but`
+        is the (lanes, chunk, width) that pass is about to run itself, so
+        an engine with one program runs nothing here."""
+        for shape in self._prefill_programs():
+            if shape != but:
+                self._forward(*self._prefill_inputs([], *shape))
 
     def _warm_paged_buckets(self) -> None:
         """Compile every paged block-table width bucket up front, at
@@ -1809,14 +1887,14 @@ class LLMEngine:
             self._admit_locked()
             imported = self._attach_imports_locked()
             with self._clock.host["plan"]:
-                prefill_args, decode_args = self._plan_locked()
+                shape, prefill_args, decode_args = self._plan_locked()
         step = self._steps
         # an unread step before this one: its tokens are on the device
         ahead = bool(self._flight)
         feed, self._feed = self._feed, list(self._no_feed)
         step_tokens = 0
         if prefill_args:
-            step_tokens += self._dispatch_prefill(step, prefill_args)
+            step_tokens += self._dispatch_prefill(step, prefill_args, shape)
         if decode_args:
             self._dispatch_decode(step, decode_args, feed)
             self._totals["runahead_decode_steps_total"] += ahead
@@ -1853,20 +1931,24 @@ class LLMEngine:
         return True
 
     def _plan_locked(self):
-        """Lock held: what this step's passes will hold — a chunk of
-        each prefilling sequence a lane can take, a position of each
-        decoding one — with every group advanced over it, and what they
-        hold for it now (a sequence that ends meanwhile gets a new
+        """Lock held: what this step's passes will hold — the prefill
+        pass's shape (`_prefill_shape`; None without one) and a chunk of
+        it for each prefilling sequence it has a lane for, a position of
+        each decoding one — with every group advanced over it, and what
+        they hold for it now (a sequence that ends meanwhile gets a new
         `cache`)."""
-        prefill_args = []
-        for seq in [s for s in self._active
-                    if s.state == _PREFILL][:self.prefill_lanes]:
-            lo = seq.pos
-            hi = min(lo + self.prefill_chunk, self._prefill_end(seq))
-            self._advance(seq, lo, hi)
-            prefill_args.append((seq, lo, hi, seq.cache))
+        waiting = [s for s in self._active
+                   if s.state == _PREFILL][:self.prefill_lanes]
+        shape, prefill_args = None, []
+        if waiting:
+            rows = [(s.pos, self._prefill_end(s)) for s in waiting]
+            lanes, chunk, _width = shape = self._prefill_shape(rows)
+            for seq, (lo, end) in zip(waiting[:lanes], rows):
+                hi = min(lo + chunk, end)
+                self._advance(seq, lo, hi)
+                prefill_args.append((seq, lo, hi, seq.cache))
         if self._block:
-            return prefill_args, self._plan_blocks_locked()
+            return shape, prefill_args, self._plan_blocks_locked()
         # the decoding sequences as this step found them (one whose
         # prompt ends in this step's prefill pass decodes from the next
         # on), but for those whose every token is dispatched
@@ -1882,7 +1964,7 @@ class LLMEngine:
             self._advance(seq, seq.pos, seq.pos + 1)
             decode_args.append((seq, last, seq.feed if seq.ahead else -1,
                                 seq.pos + 1, seq.cache))
-        return prefill_args, decode_args
+        return shape, prefill_args, decode_args
 
     def _plan_blocks_locked(self):
         """`_plan_locked`'s decode part for a block model: the next pass
@@ -1923,38 +2005,37 @@ class LLMEngine:
         for kind, group in self._groups.items():
             group.advance(seq.cache[kind], lo, hi)
 
-    def _dispatch_prefill(self, step: int, prefill_args) -> int:
-        """Chunked prefill, batched across lanes: up to prefill_lanes
-        sequences advance one chunk each in ONE pass of fixed shape
-        (lanes x chunk, empty lanes are garbage), in the steps where a
-        prompt waits — a burst of N admissions costs N/lanes passes,
-        while a LONG prompt still shares the loop with in-flight decodes
-        instead of monopolizing it.  The pass is as narrow as what waits
-        (`_prefill_shape`): the narrow program's few lanes where few
-        prompts prefill, else prefill_lanes lanes over a context as wide
-        as the smallest _prefill_ctx_buckets() entry covering its
-        longest lane — its cost tracks USED slots and context, at one
-        program a shape.  Returns the prompt tokens the pass holds."""
+    def _dispatch_prefill(self, step: int, prefill_args, shape) -> int:
+        """Chunked prefill, batched across lanes: the sequences of
+        `prefill_args` advance one chunk each in ONE pass of the fixed
+        `shape` (lanes, chunk, width) the plan settled (`_prefill_shape`;
+        empty lanes are garbage), in the steps where a prompt waits — a
+        burst of N short admissions costs N/lanes passes, while a LONG
+        prompt still shares the loop with in-flight decodes instead of
+        monopolizing it: a pass has at most the wide pass's slots.  Its
+        cost tracks USED slots and context, at one program a shape.
+        Returns the prompt tokens the pass holds."""
         phase = self._clock.phase
         phase("prefill_build")
         ctx_rows = [hi for _s, _lo, hi, *_r in prefill_args]
-        lanes, width = shape = self._prefill_shape(
-            len(prefill_args), max(ctx_rows))
+        lanes, chunk, width = shape
         if not self._prefill_warm:
             self._prefill_warm = True
             t0 = time.perf_counter()
             self._warm_prefill_buckets(but=shape)
             self._warm_secs["prefill"] += time.perf_counter() - t0
-        inputs = self._prefill_inputs(prefill_args, lanes, width)
-        phase("prefill_dispatch", width=width, lanes=lanes)
+        inputs = self._prefill_inputs(prefill_args, *shape)
+        phase("prefill_dispatch", width=width, lanes=lanes, chunk=chunk)
         out, top2 = self._forward(*inputs)
         self._feed[1] = out
         self._prefill_steps += 1
         chunk_tokens = sum(hi - lo for _s, lo, hi, *_r in prefill_args)
         self._totals["prefill_tokens_total"] += chunk_tokens
-        self._totals["prefill_slots_total"] += lanes * self.prefill_chunk
+        self._totals["prefill_slots_total"] += lanes * chunk
         self._totals["prefill_narrow_passes_total"] += \
-            lanes < self.prefill_lanes
+            lanes < self.prefill_lanes and chunk == self.prefill_chunk
+        self._totals["prefill_deep_passes_total"] += \
+            chunk != self.prefill_chunk
         self._totals["prefill_ctx_rows_total"] += sum(ctx_rows)
         self._totals["prefill_ctx_cols_total"] += lanes * width
         self._prefill_passes_by_width[width] += 1
@@ -2145,8 +2226,8 @@ class LLMEngine:
     def warm_up(self) -> None:
         """Compile every program traffic can reach, before any loop or
         traffic, by running one tiny request inline: its first prefill
-        pass compiles every prefill context width and the narrow pass,
-        its first decode step every decode width."""
+        pass compiles every prefill program (`_prefill_programs`: wide,
+        narrow and deep), its first decode step every decode width."""
         t0 = time.perf_counter()
         # a block model prefills whole blocks only: one, and a tail
         self.generate_batch([{"tokens": [1] * (self._block + 1),
@@ -2365,8 +2446,8 @@ class _LLMCallable:
     def __init__(self, warm: bool = True, **engine_kwargs):
         self._engine = LLMEngine(**engine_kwargs)
         if warm:
-            # compile every jitted shape (each prefill context width,
-            # the narrow prefill pass, each decode width) HERE, inside
+            # compile every jitted shape (each prefill program: wide,
+            # narrow and deep; each decode width) HERE, inside
             # the replica constructor: the deploy health gate
             # (serve_replica_health_timeout_s) covers it, so the first
             # real request never pays ~seconds of XLA compile while

@@ -27,7 +27,8 @@ STATS = frozenset("""
     kv_window_pages_released_total last_batch loop_running loop_secs
     off_cpu_secs paged_grid_steps_live_total paged_grid_steps_total
     park_secs phase_secs platform prefill_ctx_cols_total
-    prefill_ctx_rows_total prefill_narrow_passes_total
+    prefill_ctx_rows_total prefill_deep_passes_total
+    prefill_narrow_passes_total
     prefill_passes_by_width prefill_secs prefill_slots_total prefill_steps
     prefill_tokens_total prefill_wait_secs_total prefix_hits
     prefix_sharing prefix_sharing_refused prefix_tokens_shared
@@ -124,7 +125,11 @@ def test_the_keys_are_the_parents_and_a_shape_compiles_once(make):
         [{"tokens": [int(t) for t in rs.randint(1, 200, n)],
           "max_new_tokens": 3} for n in (40, 5, 21)])
     assert [len(o) for o in outs] == [3, 3, 3]
-    assert {cols for (_l, cols), _g in passes} == {16, 1}
+    # (the prompt of 40 has a deep chunk to go where there is a deep pass)
+    deep = {eng._deep_prefill[1]} if eng._deep_prefill else set()
+    assert deep == (set() if make is _pangu_sparse else {32})
+    assert {cols for (_l, cols), _g in passes} == {16, 1} | deep
+    assert eng.stats()["prefill_deep_passes_total"] == len(deep)
     for (lanes, cols), groups in passes:
         assert list(groups) == kinds
         full, decode = groups["full"], cols == 1
@@ -138,3 +143,41 @@ def test_the_keys_are_the_parents_and_a_shape_compiles_once(make):
     assert report["compiled_steps"] == compiled
     assert set(eng.stats()) == STATS | more_stats
     assert set(report) == REPORT | more_report
+
+
+@pytest.mark.parametrize("chunk", [16, 32, 64, 256])
+def test_window_pages_are_sized_by_the_widest_chunk(chunk):
+    """`WindowPages` is built for the widest chunk a pass of the engine
+    may carry (the deep pass's): every one of `max_batch` sequences
+    advancing by whole chunks at once finds its pages, holds no more
+    than `per_seq`, and a pass's gathered context is as wide as ITS OWN
+    chunk asks — the narrow pass's columns are not the deep one's."""
+    from ray_tpu.serve.cache_groups import WindowPages
+
+    window, page, lanes, total = 32, 8, 4, 1024
+    group = WindowPages("window", window, page, chunk, lanes, total // page)
+    assert group.per_seq == (window + chunk) // page + 2
+    assert group.num_pages == 1 + lanes * group.per_seq
+    for cols in (1, 16, chunk):
+        assert group.ctx_width(cols) == -(-(window + cols) // page) * page
+    held = [group.admit(total)[0] for _ in range(lanes)]
+    rows = []
+    for lo in range(3, total - chunk, chunk):   # chunks that start mid-page
+        for lane, st in enumerate(held):
+            group.advance(st, lo, lo + chunk)
+            assert st.next - st.first <= group.per_seq
+            rows.append((lane, {"window": st}, lo, lo + chunk))
+        arrays = group.prefill_arrays(rows[-lanes:], lanes, chunk, 4096)
+        width = group.ctx_width(chunk)
+        assert arrays["ctx"].shape == (lanes, width)
+        assert arrays["slots"].shape == (lanes, chunk)
+        seen = min(lo + chunk, width)
+        assert arrays["ctx_mask"].sum(axis=1).tolist() == [seen] * lanes
+        # every row a query of the chunk sees is on a page the lane holds
+        assert (arrays["ctx"][arrays["ctx_mask"]][-(window + chunk - 1):]
+                >= page).all()
+        assert group.used() <= lanes * group.per_seq
+    for st in held:
+        group.release(st)
+    assert group.used() == 0
+    assert sorted(group.free) == list(range(1, group.num_pages))

@@ -119,3 +119,46 @@ def test_both_parts_of_a_page_are_shared_split_and_shipped(alone):
         p + 3.0 for p in dirty._pools["index"]]}
     assert dirty.generate_batch(
         [{"tokens": prompt, "max_new_tokens": NEW}])[0] == want
+
+
+@pytest.mark.parametrize("sharing", [False, True],
+                         ids=["unshared", "shared"])
+def test_deep_passes_answer_as_chunks_of_16_do(alone, sharing):
+    """Four prefill lanes give the family's sparse setting a narrow and a
+    DEEP pass (2 x 32: index scores of 32 queries a lane, a threshold a
+    query, the chunk kernel's mask) at 256 and 512 columns: a prompt of
+    230 rows is deep from its first chunk, two that arrive behind it —
+    on its own pages where prefixes are shared — wait their turn, and
+    every answer is that of the engine held to two lanes of 16 (which is
+    the reference's: the test above), with nothing compiled after the
+    warm-up."""
+    eng = _engine(params=alone._params, prefill_lanes=4,
+                  prefix_sharing=sharing)
+    assert eng._deep_prefill == (2, 32) and alone._deep_prefill is None
+    assert eng._prefill_programs() == [
+        (4, 16, 256), (2, 16, 256), (2, 32, 256), (2, 32, 512)]
+    eng.warm_up()
+    before = eng.stats()
+    prompts = [[int(t) for t in TOKENS[:n]] for n in (230, 150, 40)]
+    seqs = [eng.submit({"tokens": prompts[0], "max_new_tokens": NEW})]
+    for _ in range(3):
+        eng.step()
+    assert seqs[0].pos == 96
+    seqs += [eng.submit({"tokens": p, "max_new_tokens": NEW})
+             for p in prompts[1:]]
+    _drain(eng)
+    st = eng.stats()
+    # (a copy-on-write split's row copies are eager operations, compiled
+    # at the first split of a process: not a pass's program)
+    assert sharing or st["compiles_total"] == before["compiles_total"]
+    assert st["prefix_hits"] == 2 * sharing
+    assert st["prefix_tokens_shared"] == (96 + 39) * sharing
+    # three alone, four beside the second prompt (or, where it started
+    # on the first one's pages, two beside it and two alone again)
+    assert st["prefill_deep_passes_total"] == 7
+    assert st["prefill_passes_by_width"][512] == 0
+    assert st["sparse_rows_selected_total"]["prefill"] > 0
+    want = alone.generate_batch([{"tokens": p, "max_new_tokens": NEW}
+                                 for p in prompts])
+    assert [list(s.generated) for s in seqs] == want
+    assert st["latent_pages_in_use"] == 0

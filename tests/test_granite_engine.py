@@ -59,8 +59,9 @@ def _assert_references(eng, prompts, outs, top2=None):
 
 def test_engine_logits_under_churn_are_the_references():
     """Eleven requests through four lanes and four state slots: the
-    wide and the narrow prefill pass, chunks of 64 through the state
-    pool, sequences that change lanes as others end, slots re-used by
+    wide, the narrow and the deep prefill pass (2 x 128), chunks of 64
+    and of 128 through the state pool, sequences that change lanes as
+    others end, slots re-used by
     later sequences with a step in flight — every generated token's two
     largest LOGITS (the engine's logit trace) are the reference's."""
     # a context of 512: two prefill widths, so a narrow program too
@@ -71,20 +72,31 @@ def test_engine_logits_under_churn_are_the_references():
     reqs = [{"tokens": _prompt(n, i), "max_new_tokens": m,
              "request_id": f"r{i}"}
             for i, (n, m) in enumerate(zip(lengths, news))]
+    assert eng._deep_prefill == (2, 128)
+    lane_passes, dispatch = [], eng._dispatch_prefill
+
+    def counting(step, prefill_args, shape):
+        lane_passes.append(len(prefill_args))
+        return dispatch(step, prefill_args, shape)
+
+    eng._dispatch_prefill = counting
     outs = eng.generate_batch(reqs)
     prompts = [r["tokens"] for r in reqs]
     _assert_references(eng, prompts, outs)
     st = eng.stats()
-    assert st["prefill_narrow_passes_total"] > 0 \
-        and st["prefill_steps"] > st["prefill_narrow_passes_total"]
+    narrow, deep = (st["prefill_narrow_passes_total"],
+                    st["prefill_deep_passes_total"])
+    assert narrow > 0 and deep > 0 and st["prefill_steps"] > narrow + deep
     assert st["runahead_decode_steps_total"] > 0
     assert st["state_slots_in_use"] == 0 and st["used_pages"] == 0
     assert sorted(eng._groups["state"].free) == [1, 2, 3, 4]
     # the counters are the hand counts: a state layer a lane with tokens
     assert st["state_decode_rows_total"] == 3 * st["decode_lane_steps_total"]
     assert st["state_decode_calls_total"] == 3 * st["decode_steps"]
+    # (a prompt's passes: chunks of 64, fewer where it rode deep ones)
     chunks = sum(-(-n // 64) for n in lengths)
-    assert st["state_prefill_rows_total"] == 3 * chunks
+    assert sum(-(-n // 128) for n in lengths) < sum(lane_passes) < chunks
+    assert st["state_prefill_rows_total"] == 3 * sum(lane_passes)
     assert st["state_pool_bytes"] == 5 * kv_cache.state_row_bytes(
         CFG.cache_spec(), CFG.dtype) == eng.device_report()[
         "state_pool_bytes"]

@@ -119,24 +119,32 @@ def test_window_arrays_take_the_form_of_their_pass():
         == st.pages[start // PAGE:start // PAGE + live].tolist()
     assert dec["context_lens"].tolist() == [0, n, 0, 0]
     assert dec["slots"][:, 0].tolist() == [0, st.slots[n - 1], 0, 0]
-    pre = eng._pass_groups(row, 4, 1, 256)["window"]
-    assert set(pre) == {"slots", "ctx", "ctx_pos", "ctx_mask"}
-    assert pre["ctx"].shape == (4, group.ctx_width)
-    assert pre["ctx_mask"].sum(axis=1).tolist() == [0, group.ctx_width, 0, 0]
-    assert pre["ctx_pos"][1, :group.ctx_width].tolist() \
-        == list(range(n - group.ctx_width, n))
+    # ... as wide as the pass's own chunk asks: the window and the chunk
+    for cols in (1, CHUNK, 2 * CHUNK):
+        width = group.ctx_width(cols)
+        assert width == -(-(WINDOW + cols) // PAGE) * PAGE
+        pre = eng._pass_groups([(1, seq.cache, n - cols, n)], 4, cols,
+                               256)["window"]
+        assert set(pre) == {"slots", "ctx", "ctx_pos", "ctx_mask"}
+        assert pre["slots"].shape == (4, cols)
+        assert pre["ctx"].shape == (4, width)
+        assert pre["ctx_mask"].sum(axis=1).tolist() == [0, width, 0, 0]
+        assert pre["ctx_pos"][1, :width].tolist() \
+            == list(range(n - width, n))
     assert eng.cancel(seq.request_id)
 
 
 def test_window_pages_come_back_while_a_sequence_runs():
     """A context of 40 pages (320 tokens) holds the window's pages, not
-    40: at most `per_seq` = (32 + 16) / 8 + 2 = 8 of the window kind,
+    40: at most `per_seq` = (32 + 32) / 8 + 2 = 10 of the window kind (the
+    window and the widest chunk a pass carries, the deep pass's 2 x 32),
     while the full kind holds all 40 from admission; what the sequence
     moved past is given back as it goes, and its end leaves both groups
     as it found them."""
     eng = _engine()
     group, full = eng._groups["window"], eng._groups["full"]
-    assert group.per_seq == 8 and group.num_pages == 1 + 4 * 8
+    assert eng._deep_prefill == (2, 2 * CHUNK)
+    assert group.per_seq == 10 and group.num_pages == 1 + 4 * 10
     free_full, free_win = len(full.free), len(group.free)
     seq = eng.submit({"tokens": _prompt(300), "max_new_tokens": 20})
     held = []

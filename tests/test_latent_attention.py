@@ -119,6 +119,13 @@ _CASES = {
     # a table of 7 pages under blocks of 2: the last block holds one
     "odd_table": ([(100, 108), (90, 97)],
                   [[1, 2, 3, 4, 5, 6, 7], [8, 9, 10, 11, 12, 13, 14]], 112),
+    # the deep pass: as many lanes of 4 x the chunk, a head a query tile
+    # (the tile's rows are the same); a full lane that crosses two
+    # blocks' edges beside a last chunk of 17 tokens, 15 padded queries
+    "deep_two_lanes": ([(40, 72), (3, 20)], [[1, 2, 3, 4, 5], [6, 7]],
+                       128, 32),
+    # ... and alone at lo = 0: every query's context is the chunk's own
+    "deep_first_chunk": ([(0, 32), (0, 0)], [[4, 9], [12]], 128, 32),
 }
 
 
@@ -133,12 +140,13 @@ def test_latent_prefill_kernel_against_plain_numpy(monkeypatch, dtype,
     stands in every page no lane uses and is never read."""
     monkeypatch.setattr(la, "_PREFILL_BLOCK_ROWS", 32)
     monkeypatch.setattr(la, "_PREFILL_QUERY_ROWS", 16)
-    lanes, tables, *cols = _CASES[case]
-    chunk, heads, width, value = 8, 4, 128, 32
-    assert la._prefill_tiles(heads, chunk, 128 // PAGE, PAGE) == (2, 2)
+    lanes, tables, cols, chunk = (*_CASES[case], 128, 8)[:4]
+    heads, width, value = 4, 128, 32
+    assert la._prefill_tiles(heads, chunk, 128 // PAGE, PAGE) \
+        == (max(1, 16 // chunk), 2)
     q, pool, ctx, pos, mask, q_pos = _engine_pass(
         np.random.RandomState(3), lanes, chunk, heads, width, tables,
-        pages=16, cols=cols[0] if cols else 128)
+        pages=16, cols=cols)
     out = la.latent_chunk_attention(
         jnp.asarray(q, dtype), jnp.asarray(pool, dtype), ctx, pos, mask,
         q_pos, page_size=PAGE, value_width=value, scale=0.1)

@@ -241,12 +241,12 @@ def test_the_prefill_pass_carries_each_lanes_block_table():
     passes, tables, calls = [], {}, []
     dispatch, forward = eng._dispatch_prefill, eng._forward
 
-    def spy_dispatch(step, prefill_args):
+    def spy_dispatch(step, prefill_args, shape):
         lanes = [(seq.cache["full"].pages.tolist(), hi)
                  for seq, _lo, hi, *_rest in prefill_args]
         tables.update((seq, seq.cache["full"].pages.tolist())
                       for seq, *_rest in prefill_args)
-        out = dispatch(step, prefill_args)
+        out = dispatch(step, prefill_args, shape)
         # the pass's own call is the last (warm-up's come before it)
         passes.append((lanes, *calls[-1]))
         return out

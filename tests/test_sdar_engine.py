@@ -124,3 +124,39 @@ def test_what_a_block_engine_refuses_and_says():
     text = eng._lower_decode(4).as_text(debug_info=True)
     for scope in ("diffusion_sample", "diffusion_transfer", "qk_norm"):
         assert scope in text, scope
+
+
+@pytest.mark.parametrize("sharing", [False, True],
+                         ids=["unshared", "shared"])
+def test_deep_prefill_passes_leave_the_blocks_as_chunks_of_16_do(sharing):
+    """Four prefill lanes give a block model a DEEP pass too (2 x 32, a
+    lane whole blocks): a prompt of 150 tokens prefills its 148 rows of
+    whole blocks in five passes where chunks of 16 take ten, two shorter
+    ones beside and behind it, and every token and every pass of every
+    block is the reference's loop — and the engine's held to two lanes
+    of 16.  Nothing compiles after the warm-up."""
+    eng = _engine(prefill_lanes=4, prefix_sharing=sharing)
+    assert eng._deep_prefill == (2, 32)
+    assert eng._prefill_programs() == [
+        (4, 16, 256), (2, 16, 256), (2, 32, 256)]
+    eng.warm_up()
+    before = eng.stats()
+    cases = [(150, 9), (70, 6), (21, 5)]
+    reqs = [{"tokens": _prompt(n), "max_new_tokens": new,
+             "request_id": f"d{i}", "record_passes": True}
+            for i, (n, new) in enumerate(cases)]
+    outs = eng.generate_batch(reqs)
+    st = eng.stats()
+    assert st["compiles_total"] == before["compiles_total"]
+    # the first two ride the deep pass until neither has 32 rows to go
+    assert st["prefill_deep_passes_total"] == 4
+    held = _engine(params=eng._params, prefix_sharing=sharing)
+    assert held._deep_prefill is None
+    assert held.generate_batch(
+        [dict(r, request_id="h" + r["request_id"]) for r in reqs]) == outs
+    for req, out, (n, new) in zip(reqs, outs, cases):
+        want = ref.generate(eng._params, req["tokens"], new, SIZES)
+        assert out == want["tokens"], f"prompt of {n}"
+        assert _passes(eng, req["request_id"]) == want["passes"]
+        assert _passes(held, "h" + req["request_id"]) == want["passes"]
+    assert st["kv_pages_in_use"] == {"full": 0}

@@ -103,8 +103,8 @@ def test_a_token_by_token_family_is_what_it_was(model):
     text = lowered.as_text()
     assert "paged_attention_block" not in text and "diffusion" not in text
     assert _cost(lowered.compile()) == decode
-    lanes, width = eng.prefill_lanes, eng._prefill_widths[0]
-    tokens, q_pos, last_idx, groups = eng._prefill_inputs([], lanes, width)
+    tokens, q_pos, last_idx, groups = eng._prefill_inputs(
+        [], eng.prefill_lanes, eng.prefill_chunk, eng._prefill_widths[0])
     assert _cost(llm._jitted_forward(0.0, 0, False).lower(
         eng._model, eng._params, eng._pools, tokens, q_pos, last_idx,
         np.zeros((2,), "uint32"), groups, None)) == prefill

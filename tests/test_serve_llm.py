@@ -8,6 +8,7 @@ cluster and warms its jit cache with a 1-token request before any
 timed assertion.
 """
 
+import itertools
 import json
 import socket
 import threading
@@ -189,12 +190,15 @@ def test_engine_defaults():
 # ------------------------------------------- prefill context-width buckets
 # One geometry for every test below, so the process-wide jit cache holds
 # its eight programs once: chunk 16 under a context of 1024 gives the
-# 4-lane prefill pass three widths (64, 256, 1024) and the 2-lane pass its
-# one (256), page 8 the decode pass four.
+# prefill pass three widths (64, 256, 1024) — the 4-lane pass and the
+# 2-lane pass of 16 run at the second (256), the deep pass (2 x 32) at the
+# last two — page 8 the decode pass four.
 
 WIDE_CHUNK, WIDE_CTX = 16, 1024
 WIDE_BUCKETS = [64, 256, 1024]
-NARROW = narrow_prefill_cases.NARROW   # 2 lanes x the second bucket
+NARROW = narrow_prefill_cases.NARROW   # 2 lanes x 16 x the second bucket
+DEEP = narrow_prefill_cases.DEEP       # 2 lanes x 32
+PROGRAMS = [(4, 16, 256), NARROW, (*DEEP, 256), (*DEEP, 1024)]
 
 
 def _wide_engine(**kw):
@@ -242,21 +246,21 @@ def _wide_logits(eng, toks):
 def test_prefill_width_buckets_keep_the_full_forwards_tokens(
         wide_eng, n_prompt, company):
     """A prompt ending one row under, on and over each bucket edge, and
-    one deep in the last bucket: the pass that holds its last chunk
-    takes the bucket that covers it (the narrow pass's one width where
-    that covers it: one or two prompts wait), also when a short prompt
-    shares that pass and reads the same wider context, and every token
-    is the no-cache forward's."""
+    one far in the last bucket: the pass that holds its last chunk —
+    deep, or narrow where less than a deep chunk is left within the
+    narrow width — takes the bucket that covers it, also when a short
+    prompt shares that pass and reads the same wider context, and every
+    token is the no-cache forward's."""
     eng = wide_eng
     assert eng._prefill_ctx_buckets() == WIDE_BUCKETS
-    assert eng._narrow_prefill == NARROW
+    assert eng._prefill_programs() == PROGRAMS
     salt = n_prompt + 2 * (company != "alone")
     long = eng.submit({"tokens": _wide_prompt(n_prompt, salt),
                        "max_new_tokens": 3})
     seqs = [long]
-    for _ in range(-(-n_prompt // WIDE_CHUNK) - 1):
+    while long.pos + eng._prefill_shape([(long.pos, n_prompt)])[1] < n_prompt:
         eng.step()
-    assert long.pos == (n_prompt - 1) // WIDE_CHUNK * WIDE_CHUNK
+    assert long.state == "prefill" and long.pos > n_prompt - DEEP[1] - 1
     if company == "with_a_short_prompt":
         seqs.append(eng.submit({"tokens": _wide_prompt(5, salt + 1),
                                 "max_new_tokens": 3}))
@@ -265,7 +269,7 @@ def test_prefill_width_buckets_keep_the_full_forwards_tokens(
     eng.drain()  # its tokens are read a step late
     assert all(s.generated for s in seqs)
     after = eng.stats()["prefill_passes_by_width"]
-    width = NARROW[1] if n_prompt <= NARROW[1] else WIDE_CTX
+    width = NARROW[2] if n_prompt <= NARROW[2] else WIDE_CTX
     assert {w: after[w] - before[w] for w in WIDE_BUCKETS} == {
         w: int(w == width) for w in WIDE_BUCKETS}
     _drain(eng)
@@ -279,57 +283,64 @@ def test_prefill_width_buckets_keep_the_full_forwards_tokens(
 def test_every_prefill_width_is_compiled_before_the_second_pass(warmed_by):
     """After `warm_up()`, and just as well after the first prefill pass
     and decode step of an engine nobody warmed, prompts that reach every
-    bucket, alone and together, compile nothing: no new executable
-    behind the stepper and no backend compile in the process."""
+    bucket and every shape, alone and together, compile nothing: no new
+    executable behind the stepper and no backend compile in the
+    process."""
     eng = _wide_engine()
     shapes, forward = [], eng._forward
 
     def spy(tokens, q_pos, last_idx, groups, **kw):
         if "ctx" in groups["full"]:
-            shapes.append((tokens.shape[0], groups["full"]["ctx"].shape[1]))
+            shapes.append((*tokens.shape, groups["full"]["ctx"].shape[1]))
         return forward(tokens, q_pos, last_idx, groups, **kw)
 
     eng._forward = spy
-    lanes = eng.prefill_lanes
-    wide = [(lanes, w) for w in WIDE_BUCKETS]
+    assert eng._prefill_programs() == PROGRAMS
     if warmed_by == "warm_up":
         eng.warm_up()   # its one-token prompt runs the narrow pass itself
-        assert shapes == wide + [NARROW]
+        assert shapes == [p for p in PROGRAMS if p != NARROW] + [NARROW]
     else:
-        for n in (70, 5, 9):
+        for n in (30, 5, 9):
             eng.submit({"tokens": _wide_prompt(n, n), "max_new_tokens": 2})
         eng.step()   # the first pass, wide: the other shapes, then its own
-        assert shapes == wide[1:] + [NARROW, wide[0]]
+        assert shapes == PROGRAMS[1:] + PROGRAMS[:1]
         _drain(eng)  # the first decode step warms decode's widths
-    assert sorted(set(shapes)) == sorted(wide + [NARROW])
+    assert sorted(set(shapes)) == sorted(PROGRAMS)
     assert eng.stats()["prefill_passes_by_width"][1024] == 0  # not counted
     steps = eng.device_report()["compiled_steps"]
     compiles = eng.stats()["compiles_total"]
-    assert steps >= len(WIDE_BUCKETS) + 1 + len(eng._paged_width_buckets())
-    for lengths in ([20], [63, 64, 65], [255, 5], [256, 257, 300],
-                    [1000, 40, 7], [300], [60, 61, 62]):
+    assert steps >= len(PROGRAMS) + len(eng._paged_width_buckets())
+    del shapes[:]
+    for lengths in ([20], [20, 25, 30], [63, 64, 65], [255, 5],
+                    [256, 257, 300], [1000, 40, 7], [300], [70, 9, 3, 12]):
         seqs = [eng.submit({"tokens": _wide_prompt(n, salt=n),
                             "max_new_tokens": 2}) for n in lengths]
         _drain(eng, rounds=400)
         assert all(s.done and len(s.generated) == 2 for s in seqs)
     st = eng.stats()
     by_width = st["prefill_passes_by_width"]
-    assert all(by_width[w] > 0 for w in WIDE_BUCKETS), by_width
-    assert 0 < st["prefill_narrow_passes_total"] < st["prefill_steps"]
+    # (no program at the first width where there is a deep pass)
+    assert by_width[64] == 0 and by_width[256] > 0 and by_width[1024] > 0
+    narrow, deep = (st["prefill_narrow_passes_total"],
+                    st["prefill_deep_passes_total"])
+    assert narrow > 0 and deep > 0 and narrow + deep < st["prefill_steps"]
+    assert set(shapes) == set(PROGRAMS)
     assert eng.device_report()["compiled_steps"] == steps
     assert eng.stats()["compiles_total"] == compiles
 
 
 def test_prefill_context_counters_say_what_was_gathered():
-    """`prefill_slots_total`, `prefill_ctx_cols_total` and
-    `prefill_narrow_passes_total` against a hand count: a pass adds its
-    OWN lanes x chunk and lanes x width, narrow or wide."""
+    """`prefill_slots_total`, `prefill_ctx_cols_total`,
+    `prefill_narrow_passes_total` and `prefill_deep_passes_total` against
+    a hand count: a pass adds its OWN lanes x chunk and lanes x width,
+    narrow, deep or wide."""
     eng = _wide_engine()
-    lanes, (n_lanes, n_width) = eng.prefill_lanes, NARROW
-    assert lanes == 4
+    lanes, (n_lanes, chunk, n_width) = eng.prefill_lanes, NARROW
+    assert lanes == 4 and chunk == WIDE_CHUNK
     st0 = eng.stats()
     assert st0["prefill_passes_by_width"] == dict.fromkeys(WIDE_BUCKETS, 0)
     assert st0["prefill_narrow_passes_total"] == 0
+    assert st0["prefill_deep_passes_total"] == 0
     # a 20-token prompt alone: two NARROW passes (16 + 4 tokens) reading
     # 16 and 20 rows, each gathering 2 lanes x the narrow pass's width
     eng.generate_batch([{"tokens": _wide_prompt(20), "max_new_tokens": 2}])
@@ -340,25 +351,75 @@ def test_prefill_context_counters_say_what_was_gathered():
     assert st["prefill_ctx_rows_total"] == 16 + 20
     assert st["prefill_ctx_cols_total"] == 2 * n_lanes * n_width
     assert st["prefill_passes_by_width"] == {64: 0, 256: 2, 1024: 0}
-    # three prompts at once: wide passes while all three wait (one: the
-    # short ones end in it), at the width the longest needs (64), then
-    # the long prompt alone in narrow ones: the rows of every live lane,
-    # the columns of the pass
+    # three prompts at once, the first with deep chunks to go: two DEEP
+    # passes of 2 x 32 — 32 rows of the long one beside the 9, then 32
+    # more beside the 5, which waited — and its last 6 rows in a narrow
+    # one: the rows of every live lane, the columns of the pass
     eng.generate_batch([{"tokens": _wide_prompt(70), "max_new_tokens": 2},
                         {"tokens": _wide_prompt(9, 1), "max_new_tokens": 2},
                         {"tokens": _wide_prompt(5, 2), "max_new_tokens": 2}])
     st = eng.stats()
-    assert st["prefill_steps"] == 2 + 5
-    assert st["prefill_narrow_passes_total"] == 2 + 4
-    assert sum(st["prefill_passes_by_width"].values()) == st["prefill_steps"]
-    assert st["prefill_passes_by_width"] == {64: 1, 256: 6, 1024: 0}
+    assert st["prefill_steps"] == 2 + 3
+    assert st["prefill_narrow_passes_total"] == 2 + 1
+    assert st["prefill_deep_passes_total"] == 2
+    assert st["prefill_passes_by_width"] == {64: 0, 256: 5, 1024: 0}
     assert st["prefill_tokens_total"] == 20 + 70 + 9 + 5
     assert st["prefill_slots_total"] == \
-        (6 * n_lanes + 1 * lanes) * WIDE_CHUNK
+        3 * n_lanes * WIDE_CHUNK + 2 * DEEP[0] * DEEP[1]
+    assert st["prefill_ctx_rows_total"] == 36 + (32 + 9) + (64 + 5) + 70
+    assert st["prefill_ctx_cols_total"] == 5 * n_lanes * n_width
+    # three with no deep chunk among them: a WIDE pass while all three
+    # wait (the short ones end in it), at the wide program's one width
+    # (256), then the last 4 rows of the first in a narrow one
+    eng.generate_batch([{"tokens": _wide_prompt(20, 3), "max_new_tokens": 2},
+                        {"tokens": _wide_prompt(9, 4), "max_new_tokens": 2},
+                        {"tokens": _wide_prompt(5, 5), "max_new_tokens": 2}])
+    st = eng.stats()
+    assert st["prefill_steps"] == 5 + 2
+    assert st["prefill_narrow_passes_total"] == 3 + 1
+    assert st["prefill_deep_passes_total"] == 2
+    assert sum(st["prefill_passes_by_width"].values()) == st["prefill_steps"]
+    assert st["prefill_passes_by_width"] == {64: 0, 256: 7, 1024: 0}
+    assert st["prefill_tokens_total"] == 20 + 70 + 9 + 5 + 20 + 9 + 5
+    assert st["prefill_slots_total"] == \
+        (4 * n_lanes + 1 * lanes) * WIDE_CHUNK + 2 * DEEP[0] * DEEP[1]
     assert st["prefill_ctx_rows_total"] == \
-        36 + 9 + 5 + 16 + 32 + 48 + 64 + 70
-    assert st["prefill_ctx_cols_total"] == 6 * n_lanes * n_width + lanes * 64
+        36 + 41 + 69 + 70 + (16 + 9 + 5) + 20
+    assert st["prefill_ctx_cols_total"] == (6 * n_lanes + lanes) * n_width
     assert st["prefill_ctx_rows_total"] <= st["prefill_ctx_cols_total"]
+
+
+def test_the_chat_canaries_never_meet_the_deep_program():
+    """The engine's default constants, and the rule alone (no pass run):
+    the deep pass is PREFILL_NARROW_LANES lanes of the wide pass's 512
+    slots; prompts of the benchmark's chat canaries' lengths, alone or
+    in any company of their own kind, at any point of their prefill,
+    pick the narrow or the wide program and never the deep one; a prompt
+    with a deep chunk to go, or a context past the narrow width, does."""
+    from ray_tpu.serve import llm
+
+    eng = LLMEngine(_cfg(max_seq_len=4096), max_batch=8, num_pages=600)
+    assert (eng.prefill_chunk, eng.prefill_lanes) == (
+        llm.PREFILL_CHUNK, llm.PREFILL_LANES) == (64, 8)
+    assert eng._deep_prefill == (llm.PREFILL_NARROW_LANES, 256) == (2, 256)
+    assert eng._prefill_widths == [256, 1024, 4096]
+    assert eng._prefill_programs() == [
+        (8, 64, 1024), (2, 64, 1024), (2, 256, 1024), (2, 256, 4096)]
+    canaries = (24, 150, 80, 200)
+    states = [(pos, n) for n in canaries for pos in range(0, n, 64)]
+    for k in (1, 2, 3):
+        for waiting in itertools.product(states, repeat=k):
+            lanes, chunk, width = eng._prefill_shape(list(waiting))
+            assert chunk == 64 and (lanes, width) == (
+                (2, 1024) if k <= 2 else (8, 1024))
+    assert eng._prefill_shape([(0, 255)]) == (2, 64, 1024)
+    assert eng._prefill_shape([(0, 256)]) == (2, 256, 1024)
+    assert eng._prefill_shape([(0, 24), (768, 1024)]) == (2, 256, 1024)
+    assert eng._prefill_shape([(1024, 1030)]) == (2, 256, 4096)
+    assert eng._prefill_shape([(0, 24), (0, 80), (1000, 1030)]) \
+        == (2, 256, 1024)
+    assert eng._prefill_shape([(0, 24), (0, 80), (0, 3000)]) \
+        == (8, 64, 1024)
 
 
 class _NarrowKit:

@@ -133,7 +133,7 @@ TABLES = ["falling", "shuffled", "shared_page", "empty_lane", "mid_block",
           "dead_zero", "dead_garbage", "q_behind"]
 
 
-def _table_case(case, chunk, rng):
+def _table_case(case, chunk, rng, heads=4):
     """Three lanes over a table two blocks of the kernel's walk wide:
     (pool, table, lens, q_pos) with the pages of `case`.  By default a
     lane's pages are shuffled, its dead entries 0, its queries the last
@@ -141,7 +141,10 @@ def _table_case(case, chunk, rng):
     block, lane 1 four pages and three rows past its chunk, lane 2 a row
     short of the first block's end."""
     lanes, dim = 3, 32
-    pages = 2 * si._score_block_pages(chunk, 1 << 20, PAGE)
+    step = si._score_block_pages(chunk, 1 << 20, PAGE, heads)
+    # (two blocks, or where a chunk is longer than a block — the deep
+    # pass's 256 queries over 128 keys a step — as many as hold it twice)
+    pages = 2 * step * max(1, -(-(chunk + 5 * PAGE) // (step * PAGE)))
     block = pages * PAGE // 2
     short = chunk + 4 * PAGE + 3
     lens = np.asarray([block + 5 * PAGE + 7, short, block - 1])
@@ -149,7 +152,7 @@ def _table_case(case, chunk, rng):
         lens[1] = 0
     if case in ("dead_zero", "dead_garbage"):
         # a table twice as wide as its live part
-        lens = np.asarray([block - 9, short, block // 2 + 3])
+        lens = np.asarray([block - 9, short, max(block // 2 + 3, chunk + 1)])
     poison = 1 + lanes * pages + np.arange(8)
     pool = _small_integers(
         rng, (1 + lanes * pages + len(poison)) * PAGE, dim)
@@ -175,14 +178,17 @@ def _table_case(case, chunk, rng):
 
 
 @pytest.mark.parametrize("case", TABLES)
-@pytest.mark.parametrize("chunk", [1, 64])
-def test_in_place_kernel_is_the_gathered_form_bit_for_bit(chunk, case):
+@pytest.mark.parametrize("chunk,heads", [(1, 4), (64, 4), (256, 32)],
+                         ids=["1", "64", "deep"])
+def test_in_place_kernel_is_the_gathered_form_bit_for_bit(chunk, heads,
+                                                          case):
     """The kernel that walks the table against the parent's gathered
     copy (bit for bit: a key's score does not depend on the block that
-    held it) and against `plain_scores` over the lane's rows in order."""
+    held it) and against `plain_scores` over the lane's rows in order —
+    a decode lane's one query, the wide pass's 64, and the deep pass's
+    256 under 32 heads, whose step is 128 keys and not 512."""
     rng = np.random.RandomState(TABLES.index(case))
-    heads = 4
-    pool, table, lens, q_pos = _table_case(case, chunk, rng)
+    pool, table, lens, q_pos = _table_case(case, chunk, rng, heads)
     lanes, width = table.shape[0], table.shape[1] * PAGE
     q = jnp.asarray(_small_integers(rng, lanes, chunk, heads, pool.shape[1]))
     w = jnp.asarray(_small_integers(rng, lanes, chunk, heads) / 8)
@@ -211,6 +217,70 @@ def test_in_place_kernel_is_the_gathered_form_bit_for_bit(chunk, case):
             full_q, full_w, jnp.nan_to_num(pool)[slots[b]][None]))[0, at]
         mine = seen[b][:, :reach].nonzero()
         np.testing.assert_array_equal(got[b][mine], want[mine])
+
+
+def test_two_lanes_of_256_score_as_eight_lanes_of_64_do():
+    """The deep prefill pass's call against the wide pass's on the SAME
+    rows: two sequences' 256 queries each as `[2, 256]` (128 keys a
+    step) and as `[8, 64]` (four lanes a sequence under one table, 512
+    keys a step) — every score equal to the last bit, random float32
+    operands: a key's score is one product over the key's width and one
+    sum over heads whatever block held the key and whatever lane the
+    query — and both the plain sum."""
+    rng = np.random.RandomState(47)
+    heads, dim, far, chunk = 32, 32, 256, 64
+    assert si._score_block_pages(far, 1 << 20, PAGE, heads) * PAGE == 128
+    assert si._score_block_pages(chunk, 1 << 20, PAGE, heads) * PAGE == 512
+    pages = 64                                    # 1,024 positions a lane
+    lens = np.asarray([300 + far, 13 + far])      # the chunks end there
+    pool, table, slots = _pool_case(rng, 2, lens, pages, dim)
+    q = jnp.asarray(rng.randn(2, far, heads, dim), jnp.float32)
+    w = jnp.asarray(rng.randn(2, far, heads), jnp.float32)
+    q_pos = lens[:, None] - far + np.arange(far)
+    deep = np.asarray(si.index_scores(
+        q, w, pool, jnp.asarray(table), jnp.asarray(lens),
+        jnp.asarray(q_pos), page_size=PAGE))
+    split = far // chunk
+    wide_pos = q_pos.reshape(2 * split, chunk)
+    wide = np.asarray(si.index_scores(
+        q.reshape(2 * split, chunk, heads, dim),
+        w.reshape(2 * split, chunk, heads), pool,
+        jnp.asarray(np.repeat(table, split, axis=0)),
+        jnp.asarray(wide_pos[:, -1] + 1), jnp.asarray(wide_pos),
+        page_size=PAGE))
+    assert deep.shape == (2, far, pages * PAGE)
+    np.testing.assert_array_equal(deep.reshape(wide.shape), wide)
+    keys = np.asarray(pool)[slots]
+    dots = np.einsum("bsjd,bld->bsjl", np.asarray(q), keys)
+    want = (np.maximum(dots, 0) * np.asarray(w)[..., None]).sum(2)
+    seen = np.arange(pages * PAGE) <= q_pos[..., None]
+    assert np.all(deep[~seen] == -np.inf)
+    np.testing.assert_allclose(deep[seen], want[seen], rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("heads", [4, 32, 64])
+def test_a_score_steps_products_stay_within_the_kernels_vmem(heads):
+    """`_score_block_pages` x heads x chunk float32 products for every
+    chunk a pass may carry, 1 to 1,024 queries a lane: within the VMEM
+    the call asks for, a whole number of pages that divides the table;
+    a decode lane's step and the wide pass's are what they were."""
+    for page in (8, 16, 64):
+        for chunk in [1, 2, 3, 16, 63, 64, 65, 128, 255, 256, 512, 1000,
+                      1024]:
+            for table in (1, 4, 48, 64, 2048):
+                pages = si._score_block_pages(chunk, table, page, heads)
+                assert 1 <= pages <= table and table % pages == 0
+                keys = pages * page
+                assert heads * chunk * keys * 4 <= si._SCORE_VMEM_BYTES
+                if chunk == 1:
+                    assert pages == min(
+                        table, si._SCORE_BLOCK_KEYS_ONE_QUERY // page) \
+                        or table % (si._SCORE_BLOCK_KEYS_ONE_QUERY // page)
+                elif heads * chunk <= 32 * 64 and table == 2048:
+                    assert keys == si._SCORE_BLOCK_KEYS
+    assert si._score_block_pages(256, 2048, 16, 32) * 16 == 128
+    assert si._score_block_pages(64, 2048, 16, 32) * 16 == 512
+    assert si._score_block_pages(1, 2048, 16, 32) * 16 == 4096
 
 
 def test_pages_read_are_those_a_lanes_queries_see():

@@ -315,15 +315,20 @@ def test_latent_decode_compiles_at_the_cells_shapes(one_chip, width):
 
 
 @pytest.mark.parametrize("width", [256, 1024, 4096, 8192])
-@pytest.mark.parametrize("lanes", [8, 2], ids=["wide", "narrow"])
-def test_latent_prefill_compiles_at_the_cells_shapes(one_chip, lanes, width):
+@pytest.mark.parametrize("lanes,chunk", [(8, 64), (2, 64), (2, 256)],
+                         ids=["wide", "narrow", "deep"])
+def test_latent_prefill_compiles_at_the_cells_shapes(one_chip, lanes, chunk,
+                                                     width):
     from ray_tpu.models.cache import latent_row_width
     from ray_tpu.ops import latent_attention as la
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    chunk, heads, row = 64, 128, latent_row_width(512 + 64)
+    # (the deep pass's tile is 8 heads x 256 queries, the same 2,048 rows)
+    assert la._prefill_tiles(128, chunk, width // PAGE, PAGE)[0] * chunk \
+        == la._PREFILL_QUERY_ROWS
+    heads, row = 128, latent_row_width(512 + 64)
     slots = (1 + 32 * (8192 // PAGE)) * PAGE
     compiled = jax.jit(
         lambda q, pool, ctx, ctx_pos, ctx_mask, q_pos:
@@ -364,9 +369,10 @@ def _glm_pools(lanes=16, positions=32768):
 
 
 _INDEX_SHAPES = pytest.mark.parametrize("lanes,chunk,pages", [
-    (8, 64, 256), (8, 64, 1024), (8, 64, 2048), (16, 1, 256), (16, 1, 2048)],
+    (8, 64, 256), (8, 64, 1024), (8, 64, 2048), (16, 1, 256), (16, 1, 2048),
+    (2, 256, 64), (2, 256, 2048)],
     ids=["prefill-4096", "prefill-16384", "prefill-32768", "decode-256",
-         "decode-2048"])
+         "decode-2048", "deep-1024", "deep-32768"])
 
 
 @pytest.fixture(scope="module")
@@ -434,18 +440,22 @@ def test_index_scores_read_the_pool_where_it_lies(index_scores_text, lanes,
     assert sizes and 0 < max(sizes) <= si._SCORE_VMEM_BYTES
 
 
-@pytest.mark.parametrize("width", [4096, 16384, 32768])
-def test_selecting_prefill_kernel_compiles_at_the_cells_shapes(one_chip,
-                                                               width):
+@pytest.mark.parametrize("lanes,chunk,width", [
+    (8, 64, 4096), (8, 64, 16384), (8, 64, 32768), (2, 256, 1024),
+    (2, 256, 32768)],
+    ids=["4096", "16384", "32768", "deep-1024", "deep-32768"])
+def test_selecting_prefill_kernel_compiles_at_the_cells_shapes(
+        one_chip, lanes, chunk, width):
     """The chunk kernel with a selection: the scores a block at a time
-    beside the pages, thresholds and ties by query, at 64 heads."""
+    beside the pages, thresholds and ties by query, at 64 heads — the
+    wide pass's lanes of 64 queries and the deep pass's two of 256."""
     from ray_tpu.ops import latent_attention as la
     from ray_tpu.ops import sparse_index as si
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    lanes, chunk, heads = 8, 64, 64
+    heads = 64
     slots, row = _glm_pools()
 
     def call(q, pool, ctx, ctx_mask, q_pos, marks):
@@ -553,15 +563,18 @@ def test_paged_decode_compiles_at_granite_paired_shapes(one_chip, width):
     assert "tpu_custom_call" in text and "paged_attention_decode" in text
 
 
-# ------------------------------------------ the narrow prefill pass's program
+# ------------------------------ the narrow and the deep prefill pass's program
 # serve/llm.py, `_narrow_prefill_shape`: PREFILL_NARROW_LANES lanes of one
 # chunk over the SECOND prefill width, 1024 columns in all three serving
-# configurations, returning a token for each of the wide pass's 8 lanes.
-# A whole step of the engine at the cell's depth and pools, by shapes
-# alone (no weight is made; 6 to 15 s each): the bytes the compiler
-# plans, nothing of results or times.
+# configurations, returning a token for each of the wide pass's 8 lanes;
+# `_deep_prefill_shape`: as many lanes of the wide pass's slots, 256 a
+# lane, here over the configuration's WIDEST context.  A whole step of
+# the engine at the cell's depth and pools, by shapes alone (no weight
+# is made; 6 to 15 s each): the bytes the compiler plans, nothing of
+# results or times.
 
 HBM_BYTES = int(15.75 * 2 ** 30)
+DEEP_TEMP_BYTES = 2 ** 31
 
 
 def _serving_config(name):
@@ -585,8 +598,9 @@ def _serving_config(name):
 @pytest.mark.parametrize("name", [
     "mistral-7b-v0.3-serve", "laguna-s-2.1-serve",
     "openpangu-ultra-moe-718b-serve", "glm-5-serve"])
+@pytest.mark.parametrize("deep", [False, True], ids=["narrow", "deep"])
 def test_narrow_prefill_program_compiles_at_the_cells_shapes(
-        one_chip, monkeypatch, name):
+        one_chip, monkeypatch, name, deep):
     import numpy as np
 
     import ray_tpu.ops
@@ -600,8 +614,11 @@ def test_narrow_prefill_program_compiles_at_the_cells_shapes(
     model_kwargs, max_batch = _serving_config(name)
     family, cfg = resolve(model_kwargs)
     lanes, chunk = PREFILL_NARROW_LANES, PREFILL_CHUNK
-    width = _pow4_widths(4 * chunk, cfg.max_seq_len)[1]
-    assert (lanes, width) == (2, 1024)
+    widths = _pow4_widths(4 * chunk, cfg.max_seq_len)
+    width, far = widths[1], PREFILL_LANES * chunk // lanes
+    assert (lanes, width, far) == (2, 1024, 256)
+    if deep:
+        chunk, width = far, widths[-1]
     model = family.build(cfg, PAGE)
 
     def spec(shape, dtype):
@@ -617,15 +634,17 @@ def test_narrow_prefill_program_compiles_at_the_cells_shapes(
     # the window and a chunk (`cache_groups.WindowPages`); a window kind gathers that
     # much context, in whole pages, where the pass's width is wider
     kinds = kv_cache.kinds_of(cfg.cache_spec())
-    span = {kind: -(-(window + chunk) // PAGE) if window
+    # (the pool's by the deep chunk, the pass's context by its own)
+    span = {kind: -(-(window + far) // PAGE) if window
             else cfg.max_seq_len // PAGE for kind, window in kinds.items()}
     pools = placed(jax.eval_shape(lambda: kv_cache.make_pools(
         cfg.cache_spec(),
         {kind: (1 + max_batch * (pages + 2 * bool(kinds[kind]))) * PAGE
          for kind, pages in span.items()}, cfg.dtype)))
     groups = {}
-    for kind, pages in span.items():
-        w = min(width, pages * PAGE)
+    for kind, window in kinds.items():
+        w = min(width, -(-(window + chunk) // PAGE) * PAGE) if window \
+            else width
         groups[kind] = {"slots": spec((lanes, chunk), jnp.int32),
                         "ctx": spec((lanes, w), jnp.int32),
                         "ctx_pos": spec((lanes, w), jnp.int32),
@@ -637,8 +656,13 @@ def test_narrow_prefill_program_compiles_at_the_cells_shapes(
     tok, _pools = lowered.out_info
     assert tok.shape == (PREFILL_LANES + len(getattr(model, "counters", ())),)
     mem = lowered.compile().memory_analysis()
+    print(name, "deep" if deep else "narrow", width,
+          mem.temp_size_in_bytes, mem.argument_size_in_bytes)
     # weights and pools as the cell holds them, and beside them what 2 x
     # 64 slots need: 38 to 56 MiB where the 8-lane pass plans 108 to 267
-    # (the sparse configuration's 1024 columns take the dense path)
-    assert 0 < mem.temp_size_in_bytes < 2 ** 27
+    # (the sparse configuration's 1024 columns take the dense path); the
+    # deep pass's 512 slots at the widest context plan what the wide
+    # pass's did there
+    assert 0 < mem.temp_size_in_bytes < (DEEP_TEMP_BYTES if deep
+                                         else 2 ** 27)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
