@@ -75,10 +75,11 @@ __all__ = ["route", "dispatch", "dispatch_rows", "grouped_swiglu", "combine",
            "COUNTERS", "TRAIN_COUNTERS"]
 
 # what `moe_layer` counts for the engine, in the order of its counter
-# vector; a training step also reads the last two of TRAIN_COUNTERS,
-# the row tiles its dropless buffer filled and had
-COUNTERS = ("assignments", "expert_calls", "max_load")
-TRAIN_COUNTERS = COUNTERS + ("row_tiles_active", "row_tiles")
+# vector — the last the row tiles that held a row: over `expert_calls`,
+# the tiles a touched expert filled — and a training step reads
+# TRAIN_COUNTERS, with the row tiles its dropless buffer had
+COUNTERS = ("assignments", "expert_calls", "max_load", "row_tiles_active")
+TRAIN_COUNTERS = COUNTERS + ("row_tiles",)
 
 
 class Dispatch(NamedTuple):
@@ -168,10 +169,11 @@ def dispatch(ids: jax.Array, valid: jax.Array, held: Tuple[int, int],
                         (ends[-1] // tm).astype(jnp.int32), counts)
 
 
-def _up_kernel(te_ref, na_ref, x_ref, w1_ref, w3_ref, h_ref):
+def _up_kernel(te_ref, na_ref, x_ref, w1_ref, w3_ref, h_ref, *,
+               tile_axis: int):
     from jax.experimental import pallas as pl
 
-    @pl.when(pl.program_id(0) < na_ref[0])
+    @pl.when(pl.program_id(tile_axis) < na_ref[0])
     def _():
         x = x_ref[...]
         gate = jnp.dot(x, w1_ref[0], preferred_element_type=jnp.float32)
@@ -179,30 +181,16 @@ def _up_kernel(te_ref, na_ref, x_ref, w1_ref, w3_ref, h_ref):
         h_ref[...] = (jax.nn.silu(gate) * up).astype(h_ref.dtype)
 
 
-def _down_kernel(te_ref, na_ref, h_ref, w2_ref, y_ref, *, slices: int):
-    """`slices` > 1: the grid's second dimension walks the hidden width,
-    and the row tile's output block, which stays where it is meanwhile,
-    takes the sum of the slices' products."""
+def _down_kernel(te_ref, na_ref, h_ref, w2_ref, y_ref, *, tile_axis: int):
+    """The product over the whole hidden width in one float32 `dot`,
+    whatever columns of the output the step's block of w2 holds."""
     from jax.experimental import pallas as pl
 
-    # read out here: the interpreter has no `program_id` inside a branch
-    first = pl.program_id(1) == 0 if slices > 1 else None
-
-    @pl.when(pl.program_id(0) < na_ref[0])
+    @pl.when(pl.program_id(tile_axis) < na_ref[0])
     def _():
-        y = jnp.dot(h_ref[...], w2_ref[0],
-                    preferred_element_type=jnp.float32)
-        if slices == 1:
-            y_ref[...] = y.astype(y_ref.dtype)
-            return
-
-        @pl.when(first)
-        def _set():
-            y_ref[...] = y.astype(y_ref.dtype)
-
-        @pl.when(jnp.logical_not(first))
-        def _add():
-            y_ref[...] += y.astype(y_ref.dtype)
+        y_ref[...] = jnp.dot(
+            h_ref[...], w2_ref[0],
+            preferred_element_type=jnp.float32).astype(y_ref.dtype)
 
 
 # VMEM the blocks of an expert's matrices may take in one grouped call,
@@ -210,17 +198,36 @@ def _down_kernel(te_ref, na_ref, h_ref, w2_ref, y_ref, *, slices: int):
 _EXPERT_BLOCK_BYTES = 48 * 1024 * 1024
 
 
+def _column_tile(depth: int, width: int, matrices: int, itemsize: int) -> int:
+    """Columns of `matrices` blocks `[depth, width]` a grid step holds:
+    all of them where the blocks, double buffered, fit
+    `_EXPERT_BLOCK_BYTES`, else `width` halved until they do, in
+    multiples of 128."""
+    tile = width
+    while (2 * matrices * depth * tile * itemsize > _EXPERT_BLOCK_BYTES
+           and tile % 256 == 0):
+        tile //= 2
+    return tile
+
+
 def hidden_tile(d: int, f: int, itemsize: int) -> int:
-    """Columns of an expert's hidden width a grid step of the forward
-    covers: all `f` where gate and up blocks of `[d, f]`, double
-    buffered, fit `_EXPERT_BLOCK_BYTES` (3072 x 1024 and every expert
-    the repo ran before the 7680 x 2048 ones: 25 MB), else `f` halved
-    until they do, in multiples of 128 (7680 x 2048: 512, four slices —
-    whole, the up call asked for 120 MB of VMEM under a limit of 96)."""
-    tf = f
-    while 2 * 2 * d * tf * itemsize > _EXPERT_BLOCK_BYTES and tf % 256 == 0:
-        tf //= 2
-    return tf
+    """Columns of an expert's hidden width a grid step of the gate/up
+    call covers: all `f` where the gate and up blocks of `[d, f]` fit
+    `_column_tile`'s budget (3072 x 1024 and every expert the repo ran
+    before the 7680 x 2048 ones: 25 MB), else a slice of it (7680 x
+    2048: 512, four slices — whole, the up call asked for 120 MB of
+    VMEM under a limit of 96; 6144 x 2048: 1024, two slices, which fill
+    the budget to the byte)."""
+    return _column_tile(d, f, 2, itemsize)
+
+
+def out_tile(d: int, f: int, itemsize: int) -> int:
+    """Columns of the OUTPUT a grid step of the down call covers, by the
+    same budget for its one block of `[f, d]`: all `d`, or a slice of it
+    (2048 x 7680: 3840, two slices; 2048 x 6144 fits whole).  The cut is
+    over w2's columns and not over the sum, so a step's product is the
+    unsliced form's one `dot` and no step adds to another's."""
+    return _column_tile(f, d, 1, itemsize)
 
 
 def _tile(i, na):
@@ -251,6 +258,49 @@ def _tile_plumbing(tile_expert: jax.Array, active_tiles: jax.Array):
     return te, na, row_map, w_map, params
 
 
+# What a step of a grouped forward call reads and writes, as
+# block(row tile, slice, tile_expert): a row tile at its full width, a
+# row tile's slice of the columns, the tile's expert's slice of columns
+def _rows_whole(tile, part, te):
+    return (tile, 0)
+
+
+def _rows_cut(tile, part, te):
+    return (tile, part)
+
+
+def _expert_cut(tile, part, te):
+    return (te[tile], 0, part)
+
+
+def _walk(n_tiles: int, slices: int):
+    """The grid of a grouped forward call whose weight blocks are cut
+    into `slices` of columns, and `at(block)`: the index map that puts a
+    grid step on `block(row tile, slice, tile_expert)`.
+
+    One slice: the grid is the row tiles alone, the program every expert
+    that fits one block has always had — consecutive tiles of one expert
+    stand on one weight block, which is fetched once.  More: the slices
+    are walked OUTSIDE the row tiles, `(slices, row tiles)`, so that the
+    same holds of every (expert, slice) block and an expert's bytes
+    cross HBM once a call however many tiles it fills (inside, a tile
+    ended on the last slice and the next tile of the SAME expert began
+    on the first: every tile re-read its expert whole).  Past the
+    active tiles a step keeps the last active tile's indices in its own
+    slice (no fetch, no write-back); with no active tile every step
+    stands on slice 0, and the call fetches the one block its first
+    step cannot avoid."""
+    if slices == 1:
+        def at(block):
+            return lambda i, te, na: block(_tile(i, na), 0, te)
+        return (n_tiles,), at
+
+    def at(block):
+        return lambda j, i, te, na: block(
+            _tile(i, na), jnp.where(na[0] > 0, j, 0), te)
+    return (slices, n_tiles), at
+
+
 def grouped_swiglu(xs: jax.Array, w1: jax.Array, w3: jax.Array,
                    w2: jax.Array, tile_expert: jax.Array,
                    active_tiles: jax.Array, *, tm: int,
@@ -264,72 +314,45 @@ def grouped_swiglu(xs: jax.Array, w1: jax.Array, w3: jax.Array,
     Two `pallas_call`s, both named `moe_experts`: gate and up with the
     activation, then down.  One grid step a row tile where an expert's
     whole matrices are one block, so that consecutive tiles of one
-    expert fetch it once; where they are too large for that
-    (`hidden_tile`) the grid is (row tiles, slices of the hidden width).
+    expert fetch it once.  Where they are too large for that the
+    matrices are cut by COLUMNS — gate and up over the hidden width
+    (`hidden_tile`), down over its output (`out_tile`) — and the grid is
+    (slices, row tiles): every (expert, slice) block is still fetched
+    once, and each step writes its own block of the result (`_walk`).
     An inactive tile keeps the last active tile's indices (no fetch)
     and its body is predicated off."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     interpret = interpret_default(interpret)
-    rows, d = xs.shape
-    f = w1.shape[-1]
-    n_tiles = rows // tm
-    tf = hidden_tile(d, f, w1.dtype.itemsize)
-    slices = f // tf
-    # one slice: the grid is the row tiles alone, the program every
-    # expert that fits one block has always had
-    grid = (n_tiles, slices) if slices > 1 else (n_tiles,)
+    rows = xs.shape[0]
     na = jnp.reshape(active_tiles, (1,)).astype(jnp.int32)
     te = tile_expert.astype(jnp.int32)
-    params = pltpu.CompilerParams(
-        dimension_semantics=("arbitrary",) * len(grid),
-        vmem_limit_bytes=96 * 1024 * 1024)
 
-    def part(i, j, na):
-        """The slice a step covers (`j`: the grid's second index, if it
-        has one): past the active tiles the last active tile's last."""
-        return jnp.where(i < na[0], j[0], slices - 1) if j else 0
+    def call(kernel, out_dtype, tile, x, *ws):
+        """x [R, depth] under each tile's expert's `[depth, width]`
+        matrices `ws`, `tile` columns a block -> [R, width]."""
+        _e, depth, width = ws[0].shape
+        grid, at = _walk(rows // tm, width // tile)
+        w_spec = pl.BlockSpec((1, depth, tile), at(_expert_cut))
+        return pl.pallas_call(
+            functools.partial(kernel, tile_axis=len(grid) - 1),
+            out_shape=jax.ShapeDtypeStruct((rows, width), out_dtype),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=grid,
+                in_specs=[pl.BlockSpec((tm, depth), at(_rows_whole))]
+                + [w_spec] * len(ws),
+                out_specs=pl.BlockSpec((tm, tile), at(_rows_cut))),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",) * len(grid),
+                vmem_limit_bytes=96 * 1024 * 1024),
+            interpret=interpret, name="moe_experts",
+        )(te, na, x, *ws)
 
-    def x_map(i, *rest):
-        *_j, _te, na = rest
-        return (_tile(i, na), 0)
-
-    def h_map(i, *rest):
-        *j, _te, na = rest
-        return (_tile(i, na), part(i, j, na))
-
-    def w_up_map(i, *rest):
-        *j, te, na = rest
-        return (te[_tile(i, na)], 0, part(i, j, na))
-
-    def w_down_map(i, *rest):
-        *j, te, na = rest
-        return (te[_tile(i, na)], part(i, j, na), 0)
-
-    h = pl.pallas_call(
-        _up_kernel,
-        out_shape=jax.ShapeDtypeStruct((rows, f), xs.dtype),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=grid,
-            in_specs=[pl.BlockSpec((tm, d), x_map),
-                      pl.BlockSpec((1, d, tf), w_up_map),
-                      pl.BlockSpec((1, d, tf), w_up_map)],
-            out_specs=pl.BlockSpec((tm, tf), h_map)),
-        compiler_params=params, interpret=interpret,
-        name="moe_experts",
-    )(te, na, xs, w1, w3)
-    return pl.pallas_call(
-        functools.partial(_down_kernel, slices=slices),
-        out_shape=jax.ShapeDtypeStruct((rows, d), jnp.float32),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=grid,
-            in_specs=[pl.BlockSpec((tm, tf), h_map),
-                      pl.BlockSpec((1, tf, d), w_down_map)],
-            out_specs=pl.BlockSpec((tm, d), x_map)),
-        compiler_params=params, interpret=interpret,
-        name="moe_experts",
-    )(te, na, h, w2)
+    d, f = w1.shape[1:]
+    itemsize = w1.dtype.itemsize
+    h = call(_up_kernel, xs.dtype, hidden_tile(d, f, itemsize), xs, w1, w3)
+    return call(_down_kernel, jnp.float32, out_tile(d, f, itemsize), h, w2)
 
 
 _NT = (((1,), (1,)), ((), ()))   # a b^T

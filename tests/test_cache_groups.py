@@ -47,7 +47,7 @@ REPORT = frozenset("""
 """.split())
 MOE = {f"moe_{name}_total" for name in (
     "assignments", "expert_calls", "expert_slots", "layer_passes",
-    "max_load")}
+    "max_load", "row_tiles_active")}
 LATENT = {"latent_decode_calls_total", "latent_decode_rows_total",
           "latent_prefill_rows_total", "latent_pages_in_use",
           "latent_pool_bytes"}

@@ -45,7 +45,7 @@ def test_a_family_without_an_indexer_is_what_it_was(model):
                              for n in names)
     counters = getattr(model, "counters", ())
     assert not any(c.startswith("sparse_") for c in counters)
-    assert len(counters) in (0, 5)
+    assert len(counters) in (0, 6)
 
 
 def test_the_latent_family_without_index_keys_takes_the_kernels_as_before():

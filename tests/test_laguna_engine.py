@@ -71,6 +71,8 @@ def test_prefill_in_chunks_then_decode_is_the_references_forward():
         assert 0 < calls[which] < slots[which]
         assert st["moe_assignments_total"][which] >= calls[which]
         assert slots[which] == 4 * st["moe_layer_passes_total"][which]
+        # a touched expert fills a row tile or more
+        assert st["moe_row_tiles_active_total"][which] >= calls[which]
     assert st["moe_layer_passes_total"]["decode"] == 4 * st["decode_steps"]
 
 

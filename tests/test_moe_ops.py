@@ -222,15 +222,22 @@ def test_row_tile_follows_the_mean_group():
 
 
 def test_an_expert_too_wide_for_one_block_is_walked_in_slices(monkeypatch):
-    """`hidden_tile`: an expert whose gate and up blocks do not fit the
-    budget is taken a slice of its hidden width a grid step, the down
-    product summed over the slices — the same layer (an expert nobody
-    chose and padding included), and the sizes the cells run keep one
-    slice or get the four the chip's VMEM allows."""
+    """`hidden_tile`, `out_tile`: an expert whose blocks do not fit the
+    budget is taken a slice of its columns a grid step — gate and up
+    over the hidden width, down over its output — the same layer (an
+    expert nobody chose and padding included), and the sizes the cells
+    run keep one slice or get the slices the chip's VMEM allows."""
     assert moe.hidden_tile(3072, 1024, 2) == 1024      # Laguna's expert
     assert moe.hidden_tile(2304, 896, 2) == 896
+    assert moe.hidden_tile(2048, 768, 2) == 768
     assert moe.hidden_tile(7680, 2048, 2) == 512
-    d, f = 32, 512
+    assert moe.hidden_tile(6144, 2048, 2) == 1024     # two slices
+    assert moe.out_tile(3072, 1024, 2) == 3072
+    assert moe.out_tile(2304, 896, 2) == 2304
+    assert moe.out_tile(2048, 768, 2) == 2048
+    assert moe.out_tile(7680, 2048, 2) == 3840
+    assert moe.out_tile(6144, 2048, 2) == 6144
+    d, f = 256, 512
     ks = jax.random.split(jax.random.PRNGKey(5), 5)
     wr = jax.random.normal(ks[0], (d, 4), jnp.float32) * d ** -0.5
     w1, w3 = (jax.random.normal(k, (4, d, f), jnp.float32) * d ** -0.5
@@ -242,6 +249,7 @@ def test_an_expert_too_wide_for_one_block_is_walked_in_slices(monkeypatch):
                              valid=valid)
     monkeypatch.setattr(moe, "_EXPERT_BLOCK_BYTES", 2 * 2 * d * 128 * 4)
     assert moe.hidden_tile(d, f, 4) == 128
+    assert moe.out_tile(d, f, 4) == 128
     jax.clear_caches()
     sliced, counters = moe.moe_layer(x, wr, w1, w3, w2, top_k=2,
                                      held=(0, 4), valid=valid)
@@ -250,6 +258,168 @@ def test_an_expert_too_wide_for_one_block_is_walked_in_slices(monkeypatch):
     np.testing.assert_allclose(
         sliced, _naive_wide(x, wr, w1, w3, w2, valid), rtol=1e-5, atol=1e-5)
     assert int(counters["assignments"]) == 2 * int(valid.sum())
+
+
+# Row tiles of each of four experts, the tiles the buffer has, and the
+# rows of the last active tile that hold a token (the others are the
+# zeros the dispatch writes)
+_TILE_LAYOUTS = {
+    "0_1_3_5_tiles": ((0, 1, 3, 5), 12, 8),
+    "last_tile_partial": ((2, 0, 0, 3), 7, 3),
+    "first_expert_only": ((4, 0, 0, 0), 6, 8),
+    "one_tile_each": ((1, 1, 1, 1), 8, 8),
+    "all_tiles_dead": ((0, 0, 0, 0), 5, 0),
+    "all_tiles_active": ((2, 1, 1, 3), 7, 8),
+    "one_row": ((0, 0, 1, 0), 5, 1),
+}
+# (d, f) and its (gate/up, down) slices under a budget of 1 MiB in
+# float32: the 7680 x 2048 expert cut
+# to test size (gate and up in four slices, down in two) and
+# the 6144 x 2048 one (two slices, down whole)
+_SLICED_SIZES = {"up4_down2": ((512, 512), (4, 2)),
+                 "up2_down1": ((256, 512), (2, 1))}
+_TEST_BUDGET = 1024 * 1024
+_TM = 8
+
+
+def _layout(name):
+    tiles, n_tiles, last_rows = _TILE_LAYOUTS[name]
+    tile_expert = [e for e, n in enumerate(tiles) for _ in range(n)]
+    active = len(tile_expert)
+    # past the groups the dispatch names the last expert
+    tile_expert += [len(tiles) - 1] * (n_tiles - active)
+    return np.asarray(tile_expert, np.int32), active, n_tiles, last_rows
+
+
+@pytest.mark.parametrize("size", list(_SLICED_SIZES))
+@pytest.mark.parametrize("layout", list(_TILE_LAYOUTS))
+def test_sliced_forward_is_the_plain_swiglu_of_every_active_row(
+        monkeypatch, layout, size):
+    """`grouped_swiglu` over hand-built tile layouts, its experts cut in
+    slices, against each row's expert's plain SwiGLU in float32."""
+    (d, f), slices = _SLICED_SIZES[size]
+    monkeypatch.setattr(moe, "_EXPERT_BLOCK_BYTES", _TEST_BUDGET)
+    assert (f // moe.hidden_tile(d, f, 4),
+            d // moe.out_tile(d, f, 4)) == slices
+    tile_expert, active, n_tiles, last_rows = _layout(layout)
+    rng = np.random.RandomState(len(layout))
+    w1, w3 = (rng.randn(4, d, f).astype(np.float32) * d ** -0.5
+              for _ in range(2))
+    w2 = rng.randn(4, f, d).astype(np.float32) * f ** -0.5
+    xs = rng.randn(n_tiles * _TM, d).astype(np.float32)
+    if active:
+        xs[(active - 1) * _TM + last_rows:active * _TM] = 0.0
+    got = np.asarray(moe.grouped_swiglu(
+        jnp.asarray(xs), jnp.asarray(w1), jnp.asarray(w3), jnp.asarray(w2),
+        jnp.asarray(tile_expert), jnp.int32(active), tm=_TM))
+    assert got.shape == (n_tiles * _TM, d) and got.dtype == np.float32
+    for t in range(active):
+        e, rows = tile_expert[t], xs[t * _TM:(t + 1) * _TM]
+        gate = rows @ w1[e]
+        plain = (gate / (1.0 + np.exp(-gate)) * (rows @ w3[e])) @ w2[e]
+        np.testing.assert_allclose(got[t * _TM:(t + 1) * _TM], plain,
+                                   rtol=2e-5, atol=2e-5)
+    if active and last_rows < _TM:
+        assert not got[(active - 1) * _TM + last_rows:active * _TM].any()
+
+
+def _blocks_in_grid_order(grid, index_map, tile_expert, active):
+    """The block every grid step stands on, the last grid index moving
+    fastest (the order a TPU walks a grid in)."""
+    te = jnp.asarray(tile_expert)
+    na = jnp.asarray([active], jnp.int32)
+    return [tuple(int(v) for v in index_map(*step, te, na))
+            for step in np.ndindex(*grid)]
+
+
+def _returned_to(blocks):
+    """The blocks a walk leaves and stands on again later: each costs a
+    second fetch (the pipeline skips a fetch only where a step's block
+    is the step before's)."""
+    runs = [b for n, b in enumerate(blocks) if n == 0 or blocks[n - 1] != b]
+    return sorted({b for b in runs if runs.count(b) > 1})
+
+
+@pytest.mark.parametrize("slices", [4, 2])
+@pytest.mark.parametrize("layout", list(_TILE_LAYOUTS))
+def test_no_weight_block_is_returned_to_after_it_is_left(layout, slices):
+    """The property the grid's order is for: every (expert, slice) block
+    of a sliced expert is one run of consecutive steps, so each touched
+    expert's bytes are fetched once a call however many tiles it fills;
+    every active tile meets every slice of its expert, and a block of
+    the result is stood on once."""
+    tile_expert, active, n_tiles, _rows = _layout(layout)
+    grid, at = moe._walk(n_tiles, slices)
+    assert grid == (slices, n_tiles)
+    weights = _blocks_in_grid_order(grid, at(moe._expert_cut), tile_expert,
+                                    active)
+    assert _returned_to(weights) == []
+    touched = {int(tile_expert[t]) for t in range(active)}
+    if active:
+        assert set(weights) == {(e, 0, j) for e in touched
+                                for j in range(slices)}
+    else:       # the one block the first step cannot avoid
+        assert set(weights) == {(int(tile_expert[0]), 0, 0)}
+    out = _blocks_in_grid_order(grid, at(moe._rows_cut), tile_expert, active)
+    assert _returned_to(out) == []
+    live = [out[j * n_tiles + t] for j in range(slices)
+            for t in range(active)]
+    assert live == [(t, j) for j in range(slices) for t in range(active)]
+    rows = _blocks_in_grid_order(grid, at(moe._rows_whole), tile_expert,
+                                 active)
+    assert rows == [(t, 0) for t, _j in out]
+
+
+def test_slices_inside_the_row_tiles_return_to_a_block():
+    """What the grid did before: slices walked INSIDE the row tiles come
+    back to an expert's first slice at every further tile of it — the
+    check above refuses that order."""
+    tile_expert, active, n_tiles, _rows = _layout("0_1_3_5_tiles")
+    slices = 4
+
+    def inside(i, j, te, na):
+        return moe._expert_cut(moe._tile(i, na), j, te)
+
+    blocks = _blocks_in_grid_order((n_tiles, slices), inside, tile_expert,
+                                   active)
+    assert _returned_to(blocks) == [(e, 0, j) for e in (2, 3)
+                                    for j in range(slices)]
+
+
+@pytest.mark.parametrize("layout", ["0_1_3_5_tiles", "all_tiles_dead"])
+def test_one_slice_keeps_the_grid_of_row_tiles_alone(layout):
+    tile_expert, active, n_tiles, _rows = _layout(layout)
+    grid, at = moe._walk(n_tiles, 1)
+    assert grid == (n_tiles,)
+    weights = _blocks_in_grid_order(grid, at(moe._expert_cut), tile_expert,
+                                    active)
+    last = max(active - 1, 0)
+    assert weights == [(int(tile_expert[min(t, last)]), 0, 0)
+                       for t in range(n_tiles)]
+    assert _returned_to(weights) == []
+
+
+def test_the_row_tiles_a_layer_filled_are_counted_for_the_engine():
+    """`row_tiles_active` on a hand-built routing: 40 tokens choose
+    expert 1 (three tiles of 16), the first 10 expert 2 beside it (one
+    tile) and the others expert 5, which the share (0, 4) does not hold.
+    The engine's vector ends in the count; a training step's keeps its
+    five names in their order."""
+    assert moe.COUNTERS == ("assignments", "expert_calls", "max_load",
+                            "row_tiles_active")
+    assert moe.TRAIN_COUNTERS == ("assignments", "expert_calls", "max_load",
+                                  "row_tiles_active", "row_tiles")
+    _wr, w1, w3, w2 = _weights()
+    wr = jnp.zeros((D, E)).at[0, 1].set(2.0).at[1, 2].set(1.0).at[2, 5].set(
+        1.0)
+    x = jnp.zeros((40, D)).at[:, 0].set(1.0).at[:10, 1].set(1.0).at[
+        10:, 2].set(1.0)
+    assert moe.row_tile(40, K, E) == 16
+    _y, counters = _layer(x, wr, w1, w3, w2, (0, 4))
+    assert int(counters["assignments"]) == 50
+    assert int(counters["expert_calls"]) == 2
+    assert int(counters["max_load"]) == 40
+    assert int(counters["row_tiles_active"]) == 3 + 1
 
 
 def _naive_wide(x, wr, w1, w3, w2, valid):

@@ -315,3 +315,61 @@ class _NarrowKit:
                          ids=lambda case: case.__name__)
 def test_narrow_prefill_pass(case):
     case(_NarrowKit)
+
+
+def _routers_zeroed(params):
+    """`params` with every router's matrix zero: all scores tie, so every
+    token chooses experts 0 and 1 (the top-k keeps the lowest of a tie),
+    both of the share (0, 4)."""
+    def zero(path, leaf):
+        return jnp.zeros_like(leaf) if "moe_router" in jax.tree_util.keystr(
+            path) else leaf
+    return jax.tree_util.tree_map_with_path(zero, params)
+
+
+def test_row_tiles_active_is_the_dispatchs_over_the_layers(params):
+    """`moe_row_tiles_active_total` on a hand-built routing: every token
+    on experts 0 and 1, so a pass of n tokens fills ceil(n / row tile)
+    tiles of each in both expert layers.  One pass through the model —
+    64 slots, 40 of them tokens, tiles of 16: 2 x 2 x 3 — and an engine's
+    `stats()` by phase: a decode pass of at most 4 lanes fills one tile
+    an expert, a prefill pass of four prompts four tiles an expert."""
+    from ray_tpu.ops import moe
+
+    fixed = _routers_zeroed(params)
+    model = build(CFG, PAGE)
+    names = model.counters
+    assert names[:4] == tuple(f"moe_{n}_total" for n in moe.COUNTERS)
+    assert names[3] == "moe_row_tiles_active_total" and len(names) == 6
+    pools = kv_cache.make_pools(CFG.cache_spec(), {"full": 17 * PAGE},
+                                CFG.dtype)
+    toks = np.zeros((1, 64), np.int32)
+    slots = np.zeros((1, 64), np.int32)
+    q_pos = np.zeros((1, 64), np.int32)
+    toks[0, :40], slots[0, :40] = TOKENS[:40], PAGE + np.arange(40)
+    q_pos[0, :40] = np.arange(40)
+    ctx = np.zeros((1, 64), np.int32)
+    ctx[0, :40] = PAGE + np.arange(40)
+    _logits, _pools, vec = model.apply({"params": fixed}, toks, _cache(
+        pools, slots, q_pos, ctx=ctx,
+        ctx_pos=np.arange(64, dtype=np.int32)[None],
+        ctx_mask=(np.arange(64) < 40)[None]))
+    assert moe.row_tile(64, 2, 8) == 16
+    assert dict(zip(names, np.asarray(vec).tolist())) == {
+        "moe_assignments_total": 2 * 2 * 40, "moe_expert_calls_total": 2 * 2,
+        "moe_max_load_total": 2 * 40, "moe_row_tiles_active_total": 2 * 2 * 3,
+        "moe_layer_passes_total": 2, "moe_expert_slots_total": 2 * 4}
+
+    eng = _engine(params=fixed)
+    eng.generate_batch([{"tokens": [int(t) for t in TOKENS[n:n + 60]],
+                         "max_new_tokens": 5} for n in range(4)])
+    st = eng.stats()
+    tiles, calls = st["moe_row_tiles_active_total"], \
+        st["moe_expert_calls_total"]
+    assert set(tiles) == {"decode", "prefill"}
+    assert tiles["decode"] == calls["decode"] == 2 * 2 * st["decode_steps"]
+    assert calls["prefill"] == 2 * 2 * st["prefill_steps"]
+    # the four prompts in one pass of 4 x 64 slots, tiles of 64: 240
+    # tokens an expert
+    assert st["prefill_steps"] == 1 and moe.row_tile(256, 2, 8) == 64
+    assert tiles["prefill"] == 2 * 2 * 4

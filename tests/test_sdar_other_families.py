@@ -64,18 +64,20 @@ def _granite():
 
 # model -> (decode pass at table width 4, prefill pass at its narrowest
 # context, parameters' leaves, pool bytes, parameter bytes) at commit
-# ec57b8b, page 16, 2 lanes
+# ec57b8b, page 16, 2 lanes; the four families with experts since
+# `row_tiles_active` rides their counter vector (one more int32 sum an
+# expert layer: 1 to 7 operations and 20 to 112 bytes a pass)
 PARENT = {
     _llama: ((833136.0, 2567250.0, 1178.0),
              (33225146.0, 21751354.0, 172704.0), 21, 69632, 214272),
-    _laguna: ((3109940.0, 8841484.0, 4356.0),
-              (75008000.0, 75460160.0, 545408.0), 69, 239616, 518144),
-    _mellum: ((4962360.0, 11891002.0, 8162.0),
-              (129570784.0, 151449696.0, 1165440.0), 83, 172032, 1528064),
-    _pangu: ((2988863.0, 9711478.0, 2354.0),
-             (94743312.0, 55280560.0, 234624.0), 53, 405504, 354496),
-    _glm: ((2954869.0, 9682160.0, 2060.0),
-           (103482016.0, 97393184.0, 240384.0), 64, 456192, 369536),
+    _laguna: ((3109943.0, 8841512.0, 4356.0),
+              (75008000.0, 75460272.0, 545408.0), 69, 239616, 518144),
+    _mellum: ((4962367.0, 11891046.0, 8162.0),
+              (129570784.0, 151449728.0, 1165440.0), 83, 172032, 1528064),
+    _pangu: ((2988864.0, 9711498.0, 2354.0),
+             (94743312.0, 55280612.0, 234624.0), 53, 405504, 354496),
+    _glm: ((2954870.0, 9682180.0, 2060.0),
+           (103482016.0, 97393240.0, 240384.0), 64, 456192, 369536),
     _granite: ((13674598.0, 32869884.0, 9004.0),
                (699572736.0, 182604736.0, 1087120.0), 46, 594624, 4562432),
 }

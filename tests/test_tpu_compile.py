@@ -241,6 +241,41 @@ def test_expert_layer_compiles_at_laguna_shapes(one_chip, tokens):
     assert compiled.as_text().count("moe_experts") >= 2
 
 
+@pytest.mark.parametrize("rows", [4352, 512], ids=["prefill", "decode"])
+@pytest.mark.parametrize("width,slices", [(7680, (4, 2)), (6144, (2, 1))],
+                         ids=["pangu", "glm5"])
+def test_sliced_expert_kernels_compile_at_the_latent_cells_shapes(
+        one_chip, width, slices, rows):
+    """`ops.moe.grouped_swiglu` where an expert's matrices do not fit one
+    block — 16 held experts of `width` x 2048, a prefill pass's 272 row
+    tiles of 16 and a decode pass's 32 — with the slices walked outside
+    the row tiles: both calls lower inside the 96 MiB their limit is."""
+    from ray_tpu.ops import moe
+
+    assert (2048 // moe.hidden_tile(width, 2048, 2),
+            width // moe.out_tile(width, 2048, 2)) == slices
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(
+        lambda xs, w1, w3, w2, te, na: moe.grouped_swiglu(
+            xs, w1, w3, w2, te, na, tm=16, interpret=False)
+    ).lower(spec((rows, width), jnp.bfloat16),
+            spec((16, width, 2048), jnp.bfloat16),
+            spec((16, width, 2048), jnp.bfloat16),
+            spec((16, 2048, width), jnp.bfloat16),
+            spec((rows // 16,), jnp.int32),
+            spec((), jnp.int32)).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2 and all("moe_experts" in c for c in calls)
+    assert f"bf16[{rows},2048]" in calls[0] and f"f32[{rows},{width}]" \
+        in calls[1]
+    # the kernel compiler refuses a call over its limit: both passed it
+    assert [_scoped_vmem(c) for c in calls] == [[96 * 1024 * 1024]] * 2
+
+
 def test_expert_layer_gradient_compiles_at_mellum_shapes(one_chip):
     """`jax.grad` through `ops.moe.moe_layer` as the Mellum cell's step
     takes it: 8192 bfloat16 tokens of width 2304, 16 of 64 experts of
