@@ -1,0 +1,428 @@
+"""Deployment kind "serve_olmo": `kinds/serve.py` for a model of the
+hybrid linear-attention family (`ray_tpu/models/olmo_hybrid.py`) — the
+same entry points
+
+    ray_tpu.init -> Deployment(OlmoReplica, llm=True).bind(engine args)
+    -> serve.run -> handle.stream from the open-loop client
+
+with the engine's `model=` made of the configuration's published keys
+(`model_type: olmo_hybrid` picks the family in
+`ray_tpu.models.resolve`), the plain reference of `reference_olmo.py`
+(the gated delta rule token by token, float32), and
+`kinds/serve_granite.py`'s comparison in its FORM: the engine's tokens,
+teacher-forced through the reference, each measured by how far under
+its own choice the reference puts it (bfloat16 spacings at the size of
+its largest logit), and `correct` decided by the SHARE of positions
+beyond two tolerances, not by the worst one (that file's text has why:
+a fault in the state or a slot moves MOST positions far, a rounding a
+few by a little).  Everything that is not the model's is imported from
+`kinds/serve.py`, `kinds/serve_laguna.py` and `kinds/serve_granite.py`;
+this file restates `run` and brings its own values.
+
+**What the canaries cover.**  The cell is for long prompts over a state
+that is carried chunk by chunk and pages that fill: a fault in the
+carry grows with every chunk.  So the canaries reach from less than one
+prefill chunk to the mix's longest request, 15,872 prompt tokens (248
+chunks of carried state) and 512 decoded ones through the state pool,
+1,072 judged positions in all, every prefill width and decode table
+width the engine has.  They are asked TOGETHER (several sequences a
+pass, lanes changing as the short ones end: what the reference judges)
+and then IN TURN, each alone on the idle engine: those tokens must come
+back the same after the window, to the last id, from whatever state
+slots and pages the window's traffic left behind.
+
+**Two limits on the logits**, each between two readings (PERF.md
+section 6, PR 50): the largest share the program gave over its seeds on
+the chip, and what the reference's other readings give against the
+reference proper (`reference_olmo.READINGS`).  `MAX_OFF_SHARE` of the
+positions may lie beyond `LOGIT_TOL_ULPS`; `MAX_FAR_SHARE` beyond
+`FAR_TOL_ULPS`.  A run is not `correct` if it passes either.
+
+**A third limit, on the carry itself.**  The configuration states a
+float32 state, and a carry rounded to bfloat16 a token moves a logit by
+less than the bfloat16 activations do: the `bfloat16_state` reading
+puts NO position beyond either tolerance, so no share of positions can
+refuse it.  `OlmoReplica.bench_carry` therefore serves the longest
+canary once more, alone, reads its slot's rows from the state pool
+behind its last token (15,872 + 511 tokens carried) and measures each
+linear layer's state against the reference's token-by-token state: the
+norm of the difference over the reference's norm, a head at a time.
+`MAX_CARRY_OFF` bounds the WORST HEAD OF THE FIRST LINEAR LAYER.  That
+layer's inputs are products of the embedding's rows, so its distance is
+its own arithmetic's — the bfloat16 k, v and gates, about 4e-3 of the
+state on every head alike, whatever the sequence's length — where a
+deeper layer's also carries every rounding of the layers before it (up
+to 5e-2 of a layer, 0.6 of one head, on a correct program); and a
+carry's rounding accumulates over a head's memory, so it shows first in
+the head that forgets slowest (1.2e-2 there, where the same reading's
+layers whole lie INSIDE the program's).  The limit lies between the
+program's largest reading over its seeds and that reading's smallest.
+
+A builder's run asks for other readings in the environment
+(`OLMO_READINGS=all`, or names between commas): each is printed as a
+`reference_reading` line — its picks judged against the reference
+proper as a program with that fault would be, and its carry.  A run of
+the cell sets nothing and computes none.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import random
+import shutil
+import time
+from typing import Any, Dict, List
+
+from ray_tpu import serve
+from ray_tpu.serve.api import Deployment
+
+from benchmarks.cluster import (bounded, check, wait_chips_free, wait_gone)
+from benchmarks.kinds.serve import (LOGIT_TOL_ULPS, NAME, ask_canaries,
+                                    call_all, latency_ms, merge_traces, ms,
+                                    one_window, sweep, wait_idle,
+                                    window_polls)
+from benchmarks.kinds.serve_granite import shares_beyond
+from benchmarks.kinds.serve_laguna import ask_in_turn, check_canaries
+from benchmarks.replica_olmo import OlmoReplica
+from benchmarks.stats import percentile
+
+# A tree without the model fails here, before any cluster starts.  (The
+# check is of the file: importing `ray_tpu.models.olmo_hybrid` would
+# import jax into this process, which must never hold the chip.)
+_MODEL = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))), "ray_tpu", "models", "olmo_hybrid.py")
+if not os.path.isfile(_MODEL):
+    raise ImportError(f"this tree has no {_MODEL}: the program cannot "
+                      f"run a model of the olmo_hybrid family")
+
+# The share of positions that may lie beyond LOGIT_TOL_ULPS: between the
+# program's largest over its 32 seeds (0.0019: two positions of 1,072;
+# no position of any seed past 6.8 spacings; 0.0093 even at 2 spacings)
+# and the smallest of the other readings' that it has to refuse (0.033,
+# a stale slot, the least of its four seeds; float8 0.487): PERF.md
+# section 6, PR 50.
+MAX_OFF_SHARE = 0.02
+# ... and the share that may lie beyond FAR_TOL_ULPS, where no rounding
+# of the program reached on any seed (its worst position: 6.8 spacings)
+# and a stale slot puts 0.0093 to 0.0215
+FAR_TOL_ULPS = 24.0
+MAX_FAR_SHARE = 0.003
+# ... and how far the worst head of the first linear layer may carry its
+# state from the reference's behind the longest canary: between the
+# program's largest over its seeds (0.0053) and the smallest of the
+# `bfloat16_state` reading's over three (0.0097, 0.0117, 0.0193), with
+# the more room above the program, whose fresh seeds read higher
+MAX_CARRY_OFF = 0.008
+READINGS = ("float8_e4m3fn", "beta_not_doubled", "qk_not_normalised",
+            "alpha_one", "bfloat16_state", "updating_pad", "stale_slot",
+            "conv_edge_dropped", "no_qk_norm")
+# (prompt tokens, tokens decoded): less than a chunk, one chunk, a few,
+# past the 256-, 1024- and 4096-column context buckets, and the mix's
+# longest request; decode tables of 4, 16, 64, 256 and 1024 pages;
+# answers from 16 tokens to 512 through the state pool.  1,072 positions
+CANARIES = ((24, 16), (64, 16), (150, 32), (330, 16), (900, 64),
+            (1100, 32), (3000, 256), (5000, 128), (15872, 512))
+# the configuration's keys the model is made of: every published key
+# (`OlmoHybridConfig.from_dict` reads what it knows and refuses, by
+# name, the parts of the family it does not write)
+MODEL_KEYS = (
+    "model_type", "vocab_size", "hidden_size", "intermediate_size",
+    "num_hidden_layers", "num_attention_heads", "num_key_value_heads",
+    "hidden_act", "max_position_embeddings", "attention_bias",
+    "rms_norm_eps", "tie_word_embeddings", "layer_types",
+    "linear_num_key_heads", "linear_num_value_heads",
+    "linear_key_head_dim", "linear_value_head_dim",
+    "linear_conv_kernel_dim", "linear_allow_neg_eigval", "rope_parameters")
+
+
+def judge(canaries, answers, refs) -> Dict[str, Any]:
+    """`check_canaries` under both limits (the module's text): its
+    counts at `LOGIT_TOL_ULPS`, `far_share` beyond `FAR_TOL_ULPS`, the
+    shares at other tolerances, and in `off` what either limit
+    refuses."""
+    held = check_canaries(canaries, answers, refs, tau=0.0,
+                          max_off_share=MAX_OFF_SHARE)
+    far = check_canaries(canaries, answers, refs, tau=0.0,
+                         tol_ulps=FAR_TOL_ULPS, max_off_share=MAX_FAR_SHARE)
+    held["far_share"] = far["off_share"]
+    if far["off_share"] > MAX_FAR_SHARE:
+        first = far["off"][0].partition("the first: ")[2]
+        held["off"].append(
+            f"{far['off_share']:.2%} of the judged positions (limit "
+            f"{MAX_FAR_SHARE:.2%}) lie more than {FAR_TOL_ULPS:g} bfloat16 "
+            f"spacings under the reference's choice, farther than a "
+            f"rounding goes; the first: {first}")
+    held["off_share_beyond"] = shares_beyond(canaries, answers, refs)
+    return held
+
+
+def judge_carry(carry: Dict[str, Any]) -> Dict[str, Any]:
+    """`OlmoReplica.bench_carry`'s distances under `MAX_CARRY_OFF`:
+    `carry_off`, the worst head's of the FIRST linear layer (the
+    module's text has why that one), the worst layer's whole and the
+    worst head's anywhere beside it, every layer's, and in `off` what
+    the limit refuses."""
+    first = max(carry["heads"][0])
+    off = [] if first <= MAX_CARRY_OFF else [
+        f"a head of the first linear layer carries a state {first:.2e} of "
+        f"its norm from the reference's behind the longest canary (limit "
+        f"{MAX_CARRY_OFF:.1e}): farther than the layer's rounded inputs "
+        f"put it"]
+    return {"carry_off": first, "carry_layer_off": max(carry["layers"]),
+            "carry_head_off": max(max(h) for h in carry["heads"]),
+            "carry_layers": carry["layers"],
+            "carry_heads_first": sorted(carry["heads"][0])[-5:],
+            "off": off}
+
+
+def asked_readings() -> List[str]:
+    """The readings a builder's run asks for in `OLMO_READINGS` ("all",
+    or names of `READINGS` between commas); none in a run of the cell."""
+    asked = os.environ.get("OLMO_READINGS", "")
+    names = list(READINGS) if asked == "all" else \
+        [name for name in asked.split(",") if name]
+    unknown = [name for name in names if name not in READINGS]
+    if unknown:
+        raise ValueError(f"OLMO_READINGS names {unknown}: not of {READINGS}")
+    return names
+
+
+def model_kwargs(cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """`LLMEngine(model=...)` for this configuration.  Refuses a file
+    whose `layer_types` does not have one entry a layer."""
+    if len(cfg["layer_types"]) != cfg["num_hidden_layers"]:
+        raise ValueError(f"layer_types has {len(cfg['layer_types'])} "
+                         f"entries for {cfg['num_hidden_layers']} layers")
+    return {k: cfg[k] for k in MODEL_KEYS}
+
+
+def canary_requests(seed: int, vocab: int, limit: int = 0
+                    ) -> List[Dict[str, Any]]:
+    """Seeded prompts of CANARIES' lengths with distinct first tokens
+    (see generators/open_loop.py); `limit` > 0 cuts each prompt and
+    answer to what a toy engine's context holds."""
+    rnd = random.Random(f"canary-{seed}")
+    sizes = [(min(n, limit // 2), min(m, limit // 8)) if limit else (n, m)
+             for n, m in CANARIES]
+    firsts = rnd.sample(range(1, vocab), len(sizes))
+    return [{"tokens": [first] + [rnd.randrange(1, vocab)
+                                  for _ in range(n - 1)],
+             "max_new_tokens": m}
+            for first, (n, m) in zip(firsts, sizes)]
+
+
+def run(ctx) -> Dict[str, Any]:
+    cfg, traffic = ctx.config, ctx.traffic
+    dep = cfg["deployment"]
+    n_rep = int(dep.get("replicas", 1))
+    check(n_rep == ctx.cell["chips"],
+          f"{n_rep} one-chip replica(s) in a cell of {ctx.cell['chips']} "
+          f"chip(s)")
+    model = model_kwargs(cfg)
+    vocab = int(cfg["vocab_size"])
+    engine_kwargs = dict(dep.get("engine", {}), model=model, seed=ctx.seed,
+                         sizes=cfg)
+    t_run = time.monotonic()
+    app = Deployment(
+        OlmoReplica, NAME, num_replicas=n_rep,
+        max_ongoing_requests=int(dep.get("max_ongoing_requests", 64)),
+        ray_actor_options={"resources": {"TPU": 1}}, llm=True,
+    ).bind(**engine_kwargs)
+    handle = bounded(f"serve.run: {n_rep} TPU:1 replica(s) to be scheduled, "
+                     f"build their engines and warm up", 1100, serve.run,
+                     app)
+    ready_s = time.monotonic() - t_run
+    replicas = list(handle._replicas)
+    check(len(replicas) == n_rep, f"{len(replicas)} replicas, not {n_rep}")
+    reports = call_all(replicas, "device_report")
+    rep0 = reports[0]
+    problems: List[str] = []
+
+    def expect(cond: bool, what: str) -> None:
+        if not cond:
+            problems.append(what)
+
+    if not ctx.rehearse:
+        for rep in reports:
+            check(rep["platform"] == "tpu",
+                  f"a replica's jax runs on {rep['platform']!r}, not a TPU")
+            check(rep["device_count"] == 1,
+                  f"a TPU:1 replica sees {rep['device_count']} devices")
+            expect(rep["kernel_mode"] == "compiled"
+                   and rep["decode_has_tpu_custom_call"],
+                   f"decode step without a compiled Pallas kernel "
+                   f"(kernels {rep['kernel_mode']!r}, tpu_custom_call "
+                   f"{rep['decode_has_tpu_custom_call']})")
+    check(len({rep["pid"] for rep in reports}) == n_rep,
+          "replicas share a process")
+    for rep in reports:
+        got = rep["model"]
+        check(got["family"] == "olmo_hybrid",
+              f"the engine runs {got['family']}")
+        check(got["share"] is None, f"the engine holds {got['share']}: "
+                                    f"this cell's model is whole")
+        kinds = ["state" if t == "linear_attention" else "full"
+                 for t in cfg["layer_types"]]
+        check([layer[0] for layer in got["cache_spec"]] == kinds,
+              f"the engine's cache is {got['cache_spec']}")
+    warm = call_all(replicas, "bench_state")
+    ctx.say("replicas", ready_s=ready_s, n=n_rep,
+            built_s=[s["built_s"] for s in warm],
+            # the engine's OWN peak, every pass shape warmed up and no
+            # reference beside it yet
+            engine_peak_bytes=[s["memory_peak_bytes"] for s in warm],
+            param_bytes=rep0["param_bytes"],
+            kv_pool_bytes=rep0["kv_pool_bytes"],
+            state_pool_bytes=rep0["state_pool_bytes"],
+            compiled_steps=[r["compiled_steps"] for r in reports],
+            cache_hits=[r["compile_cache_hits"] for r in reports],
+            cache_misses=[r["compile_cache_misses"] for r in reports],
+            cache_dir=rep0["compile_cache_dir"],
+            attention_impl=rep0["attention_impl"],
+            cache_kinds=[layer[0] for layer in rep0["model"]["cache_spec"]])
+
+    # ---- correctness sample, before: canaries on the idle engines, sent
+    # together and then in turn (the module's text); the judged tokens
+    # of those sent together against the plain reference on the engine's
+    # own weights, teacher-forced with the engine's answer
+    canaries = canary_requests(
+        ctx.seed, vocab,
+        limit=int(cfg["max_position_embeddings"]) if ctx.rehearse else 0)
+    together = ask_canaries(replicas, canaries)
+    wait_idle(replicas)
+    before = ask_in_turn(replicas, canaries)
+    expect(all(len(toks) == q["max_new_tokens"]
+               for row in (together[0], before[0])
+               for toks, q in zip(row, canaries)),
+           "a canary answered other than the tokens asked for")
+    for row in before[1:]:
+        expect(row == before[0], "replicas of one seed answer a canary "
+                                 "differently")
+    if not problems:
+        prompts = [q["tokens"] for q in canaries]
+        refs = call_all(replicas[:1], "bench_reference", prompts,
+                        together[0], seconds=1500)[0]
+        # nothing routes: the margin is 1 at every position and none is
+        # set aside
+        held = judge(canaries, together[0], refs)
+        held["moved_asked_alone"] = sum(
+            a != b for a, b in zip(together[0], before[0]))
+        problems.extend(held.pop("off")[:5])
+        ctx.say("reference", **held, tolerance_ulps=LOGIT_TOL_ULPS,
+                max_off_share=MAX_OFF_SHARE, far_tolerance_ulps=FAR_TOL_ULPS,
+                max_far_share=MAX_FAR_SHARE)
+        # the carry itself: the longest canary's states, read from its
+        # slot behind its last token, against the reference's
+        longest = canaries[-1]
+        carry = call_all(replicas[:1], "bench_carry", longest,
+                         seconds=1500)[0]
+        expect(carry.pop("tokens") == before[0][-1],
+               "the longest canary, asked alone again, answers other tokens")
+        said = judge_carry(carry)
+        problems.extend(said.pop("off"))
+        ctx.say("carry", **said, max_carry_off=MAX_CARRY_OFF)
+        for reading in asked_readings():
+            # the other readings (a builder's run): what the reference
+            # picks with that one thing changed, in the engine's
+            # contexts, judged against the reference proper as a program
+            # with that fault would be; and that reading's carry
+            picks = [r["top_id"] for r in call_all(
+                replicas[:1], "bench_reference", prompts, together[0],
+                reading=reading, seconds=1500)[0]]
+            proper = call_all(replicas[:1], "bench_reference", prompts,
+                              together[0], picks=picks, seconds=1500)[0]
+            said = judge(canaries, picks, proper)
+            carry = call_all(replicas[:1], "bench_carry", longest,
+                             answer=before[0][-1], reading=reading,
+                             seconds=1500)[0]
+            carry.pop("tokens")
+            ctx.say("reference_reading", reading=reading,
+                    **{**said, "off": said["off"][:2]},
+                    carry=judge_carry(carry))
+    compiles0 = [s["backend_compiles"]
+                 for s in call_all(replicas, "bench_state")]
+
+    generate = ctx.spec.generator(traffic["generator"])
+    outcome: Dict[str, Any] = {}
+    if ctx.sweep:
+        sweep(ctx, handle, replicas, generate, traffic, vocab)
+        outcome["sweep_only"] = True
+    else:
+        plan = generate(traffic, ctx.seed, ctx.seconds, vocab)
+        run, polls, s = one_window(ctx, handle, replicas, plan, traffic,
+                                   vocab, trace=ctx.trace)
+        check(not s["hung"], f"streams {s['hung'][:5]} never ended")
+        ctx.say("replica_stalls", since_warm_up=call_all(replicas,
+                                                         "bench_stalls"))
+        wait_idle(replicas)
+        traces: Dict[str, Any] = {}
+        if ctx.trace:
+            parts = call_all(replicas, "profile_reduce", seconds=300,
+                             unattributed="engine host, unattributed")
+            traces = merge_traces(parts)
+            if traces:
+                traces["span_stats"] = [p.get("span_stats") for p in parts]
+        outcome.update(
+            window_start_epoch=run["w0_epoch"],
+            attempted=s["attempted"], failed=s["failed"],
+            e2e={**latency_ms(s, qs=(75, 95)),
+                 "serve_tokens_per_s":
+                     s["tokens_in_window"] / s["window_s"]},
+            obs={"kind": "serve", "summary": s, "ready_s": ready_s,
+                 "polls": [window_polls(r, run["w0_epoch"], s["window_s"])
+                           for r in polls],
+                 "trace": traces, "model": cfg,
+                 "engine": {"param_bytes": rep0["param_bytes"],
+                            "dtype": rep0["dtype"],
+                            "page_size": rep0["page_size"]}})
+        ctx.say("client", attempted=s["attempted"], failed=s["failed"],
+                failed_rids=s["failed_rids"], finished=s["finished"],
+                open_at_end=s["open_at_end"],
+                late_p95_ms=ms(percentile(s["late_s"], 95)),
+                **latency_ms(s, qs=(50,)),
+                samples_ttft=len(s["ttft_s"]), samples_tpot=len(s["tpot_s"]),
+                offered_rps=len(plan["requests"])
+                / (plan["lead_in_s"] + plan["window_s"]))
+        with open(os.path.join(ctx.out_dir, "requests.json"), "w") as f:
+            json.dump({"w0": run["w0"], "w1": run["w1"],
+                       "records": [r.as_dict() for r in run["records"]],
+                       "polls": polls}, f)
+
+    # ---- correctness sample, after: the same canaries in turn, the same
+    # tokens; nothing compiled since warm-up; every page given back
+    after = ask_in_turn(replicas, canaries)
+    expect(after == before, "a canary's tokens changed over the window "
+                            "(a recycled or mis-shared page)")
+    wait_idle(replicas)
+    states = call_all(replicas, "bench_state")
+    reports1 = call_all(replicas, "device_report")
+    for r0, r1, c0, s1 in zip(reports, reports1, compiles0, states):
+        expect(r1["compiled_steps"] == r0["compiled_steps"]
+               and s1["backend_compiles"] == c0,
+               f"compiles after warm-up: compiled_steps "
+               f"{r0['compiled_steps']} -> {r1['compiled_steps']}, backend "
+               f"compiles {c0} -> {s1['backend_compiles']}")
+        expect(not any(s1["kv_pages_in_use"].values())
+               and not s1["state_slots_in_use"],
+               f"pages or state slots still held on an idle engine: "
+               f"{s1['kv_pages_in_use']}, "
+               f"{s1['state_slots_in_use']} slots")
+    pids = [r["pid"] for r in reports]
+    serve.delete(NAME)
+    wait_chips_free(n_rep, f"the replicas (pids {pids})")
+    check(wait_gone(pids),
+          f"a replica process of {pids} outlived its lease")
+    if not ctx.keep_trace:
+        for i in range(n_rep):
+            shutil.rmtree(os.path.join(ctx.out_dir, f"trace-r{i}"),
+                          ignore_errors=True)
+    if problems:
+        ctx.say("incorrect", problems=problems)
+    outcome.update(
+        correct=not problems,
+        device={"platform": rep0["platform"], "kind": rep0["device_kind"],
+                "count": sum(r["device_count"] for r in reports),
+                "memory_peak_bytes": max(s["memory_peak_bytes"]
+                                         for s in states)})
+    return outcome

@@ -17,7 +17,8 @@ A model FAMILY is a module of this package that defines
 
 and whose config states its cache by layer (models/cache.py): rows a
 position in pages (`full`, `window`), or — kind `state` — ONE
-fixed-size row a sequence, a recurrent layer's carry, for which the
+fixed-size row a sequence, a recurrent layer's carry (a state-space
+or a gated-delta-rule mixer's), for which the
 engine keeps a slot a sequence and hands a pass `groups["state"]` (the
 lanes' slots, valid lengths, and whether the chunk starts its
 sequence).  A row's part states its dtype where it is not the model's
@@ -61,7 +62,7 @@ __all__ = ["LlamaConfig", "LlamaModel", "llama_param_rules", "resolve",
 FAMILIES = {"llama": "llama", "mistral": "llama", "laguna": "laguna",
             "mellum": "laguna", "pangu_ultra_moe": "pangu",
             "glm_moe_dsa": "pangu", "granitemoehybrid": "granite",
-            "sdar_moe": "laguna"}
+            "sdar_moe": "laguna", "olmo_hybrid": "olmo_hybrid"}
 # keys that a `model_type`'s published config class defaults, so that a
 # dictionary of that type may leave them out (a family's `from_dict`
 # takes an absent key for an absent mechanism).  `benchmarks/kinds/

@@ -30,7 +30,8 @@ length, so the kind's pool has a slot a sequence (slot 0 the garbage
 slot, as everywhere) and the engine hands a pass one slot a lane, not
 one a token.  Its row has two parts, `"conv"` (the last inputs of the
 layer's causal convolution) and `"ssm"` (the state matrix of every
-head).  A row's part states its DTYPE where that is not the model's
+head: of a Mamba-2 layer or of a gated-delta-rule layer, the kind
+carries either as it stands).  A row's part states its DTYPE where that is not the model's
 (`dtypes()`): the `ssm` part is float32 in a bfloat16 model, because the
 pool IS the carry of the recurrence — read, multiplied and written back
 every token — and a carry rounded to 8 bits every step forgets what a
@@ -93,7 +94,16 @@ class IndexedLatentCache(NamedTuple):
 class StateCache(NamedTuple):
     """A layer that keeps one fixed-size state a sequence (`kind` is
     always "state"): `conv` = (the convolution's taps - 1, its
-    channels), `ssm` = (heads, head_dim, state size)."""
+    channels), `ssm` = the recurrence's carry as the pool STORES it.
+    For a Mamba-2 layer that is its shape, (heads, head_dim, state
+    size): 128 numbers wide, whole tiles of the chip's lanes.  For a
+    gated-delta-rule layer a head's carry is keys x values, (heads,
+    dk, dv) = (30, 96, 192) as published, which the chip would store
+    256 lanes wide; the layer states the layout it keeps instead,
+    (heads / 2, dk, 2 dv): the heads in pairs side by side, the same
+    numbers and no padding (ops/delta_rule.py, `pair_state`).  As with
+    a latent row, the shape states what the memory holds, so
+    `state_row_bytes` and `state_pool_bytes` are real bytes."""
     kind: str
     window: int      # 0: the kind has no positions
     conv: Tuple[int, int]

@@ -242,7 +242,7 @@ def _decode_call(q, pool, block_tables, context_lens, *, page_size: int,
     def _lane(bi, pi, *_scalars):
         return (bi, 0, 0)
 
-    pages = pages_per_step(width, page_size)
+    pages = pages_per_step(width, page_size, w)
     kernel = functools.partial(_decode_kernel, page_size=page_size,
                                pages=pages, scale=scale,
                                value_width=value_width)

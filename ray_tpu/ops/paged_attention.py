@@ -68,15 +68,29 @@ import jax.numpy as jnp
 _NEG_INF = -1e30
 
 
-def pages_per_step(width: int, page_size: int) -> int:
+STEP_VMEM_BYTES = 50 << 20   # what one grid step's pages may take of
+# the kernel's fast memory: half the 100.38 MB the chip's compiler allows
+VMEM_BYTES_A_NUMBER = 48     # ... at its own count of the kernel at 512
+# keys of 32 heads x 128 (100.42 MB asked for 2 Mi cached numbers of a
+# step's keys, as many values: the two double-buffered bfloat16 page
+# buffers are 8 B of it, the float32 copies and the products' staged
+# operands the rest)
+
+
+def pages_per_step(width: int, page_size: int, row: int) -> int:
     """K, the pages of a lane one grid step covers, from the table's
     static width: a quarter of the table, held between 128 and 512 keys,
     and never more than the table.  Measured on the v5e at every width
     the engine asks for (PERF.md, PR 33): fewer pages a step and a wide
     table is grid overhead again; more and a lane's first block, which
     nothing overlaps, and the copies past its last page cost more than
-    the steps saved."""
-    least, most = max(1, 128 // page_size), max(1, 512 // page_size)
+    the steps saved.  `row`: the numbers of one cache row (KV heads x
+    head width), which bound the keys by `STEP_VMEM_BYTES`: every row
+    of 2,048 numbers or fewer keeps 512, a row of 32 heads x 128 holds
+    256 (at 512 the chip's compiler refuses the kernel by 48 KB)."""
+    fit = STEP_VMEM_BYTES // (VMEM_BYTES_A_NUMBER * max(1, row))
+    least = max(1, 128 // page_size)
+    most = max(least, min(512, fit) // page_size)
     return min(width, max(least, min(most, width // 4)))
 
 
@@ -282,7 +296,7 @@ def _paged_call(q, pool_k, pool_v, block_tables, context_lens, starts, *,
     def _q_index(bi, pi, *_scalars):
         return (bi, 0, 0, 0)
 
-    pages = pages_per_step(w, page_size)
+    pages = pages_per_step(w, page_size, hkv * d)
     kernel = functools.partial(_paged_kernel, page_size=page_size,
                                pages=pages, scale=scale, window=window)
     page_buf = pltpu.VMEM((2, pages, page_size, hkv, d), pool_k.dtype)

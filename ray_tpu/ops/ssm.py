@@ -36,8 +36,11 @@ where none is), which the pipeline then neither fetches again nor
 writes, and the body does nothing.  Interpreted on the CPU as the other
 kernels are.
 
-`conv_chunk` is the layer's causal depthwise convolution over a pass,
-its last `taps - 1` VALID inputs kept as the `conv` part of the state.
+`conv_chunk` is a recurrent layer's causal depthwise convolution over a
+pass, its last `taps - 1` VALID inputs kept as the `conv` part of the
+state: this recurrence's x, B and C channels, and the q, k and v
+channels of a gated-delta-rule layer (ops/delta_rule.py), which has no
+bias and hands in zeros.
 """
 
 from __future__ import annotations
@@ -60,7 +63,8 @@ HEAD_BLOCK = 64   # heads of a lane one grid step of the decode kernel
 def conv_chunk(u: jax.Array, state: jax.Array, weight: jax.Array,
                bias: jax.Array, lens: jax.Array
                ) -> Tuple[jax.Array, jax.Array]:
-    """Causal depthwise convolution of a pass.  u: [L, S, C] this pass's
+    """Causal depthwise convolution of a pass, whatever recurrence
+    reads it (the module's text).  u: [L, S, C] this pass's
     inputs; state: [L, K-1, C] the lane's last K-1 inputs before them
     (zeros at a sequence's start); weight: [K, C], tap K-1 on the
     newest input; bias: [C]; lens: [L] valid tokens a lane.  Returns

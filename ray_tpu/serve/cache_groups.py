@@ -91,6 +91,8 @@ class _Pages(_Group):
 
     window = 0   # 0: every position is seen
     table_width = float("inf")   # no cap on a decode pass's width
+    row = 0      # the numbers of the widest cache row: the decode kernel's
+    # pages a step depend on them (`paged_attention.pages_per_step`)
 
     def ctx_width(self, cols: int):
         """The widest context a prefill pass of `cols` queries a lane
@@ -158,7 +160,7 @@ class _Pages(_Group):
 
         tokens = arrays["context_lens"] - arrays.get("starts", 0)
         lanes, width = arrays["block_tables"].shape
-        pages = pages_per_step(width, self.page_size)
+        pages = pages_per_step(width, self.page_size, self.row)
         self.totals["paged_grid_steps_total"] += lanes * -(-width // pages)
         self.totals["paged_grid_steps_live_total"] += \
             int((-(-tokens // (pages * self.page_size))).sum())
@@ -192,6 +194,9 @@ class FullPages(_Pages):
             raise ValueError(f"pages of {page_size} positions do not hold "
                              f"whole blocks of {block}")
         self.block = block
+        self.row = max((layer.kv_heads * layer.head_dim for layer in spec
+                        if layer.kind == "full"
+                        and "k" in layer.rows()), default=0)
         # refused, not silently wrong, where another group cannot share
         self.prefix_sharing = bool(prefix_sharing) and not refused
         self.sharing_refused = refused if prefix_sharing else ""

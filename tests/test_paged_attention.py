@@ -19,6 +19,8 @@ from ray_tpu.models import cache as kv_cache
 from ray_tpu.models.llama import LlamaConfig, cached_attention
 from ray_tpu.ops.paged_attention import paged_attention, pages_per_step
 
+ROW = 1024   # 8 KV heads x 128: a cache row that bounds no step's keys
+
 
 def _rand_paged_case(rng, batch, ctx_lens, n_heads, n_kv_heads, head_dim,
                      page_size, num_pages):
@@ -136,7 +138,7 @@ def test_kernel_matches_dense_reference(batch, ctx_lens, heads, kv_heads,
         tables[:, :bt.shape[1]] = bt
     if width or window:
         steps = -(-tables.shape[1]
-                  // pages_per_step(tables.shape[1], page_size))
+                  // pages_per_step(tables.shape[1], page_size, ROW))
         assert steps > 1 or tables.shape[1] <= 8
     out = paged_attention(q, pk, pv, jnp.asarray(tables), jnp.asarray(cl),
                           page_size=page_size, window=window,
@@ -161,7 +163,7 @@ def test_pages_per_step_at_the_engines_widths(width, pages):
     asked = set(_pow4_widths(4, 256)) | set(_pow4_widths(4, 512))
     asked |= {min(w, 512 // 16 + 1) for w in asked}
     assert width in asked and asked == {4, 16, 64, 256, 512, 33}
-    assert pages_per_step(width, 16) == pages
+    assert pages_per_step(width, 16, ROW) == pages
     assert -(-width // pages) <= 16      # a lane is at most 16 steps
     # 128 keys a step or the whole table, never more than 512 keys
     assert pages == width or 128 <= pages * 16 <= 512
@@ -170,9 +172,9 @@ def test_pages_per_step_at_the_engines_widths(width, pages):
 def test_pages_per_step_follows_the_page_size():
     """K is set in keys: a smaller page means more pages a step, and a
     table is never split below its width."""
-    assert [pages_per_step(64, p) for p in (4, 8, 16, 32, 128, 256)] \
+    assert [pages_per_step(64, p, ROW) for p in (4, 8, 16, 32, 128, 256)] \
         == [32, 16, 16, 16, 4, 2]
-    assert all(1 <= pages_per_step(w, p) <= w
+    assert all(1 <= pages_per_step(w, p, ROW) <= w
                for w in range(1, 70) for p in (1, 4, 16, 128, 1024))
 
 

@@ -455,7 +455,7 @@ def test_paged_grid_counters_are_the_hand_count():
     eng = _engine(cfg=_cfg(max_seq_len=512), page_size=16, num_pages=65,
                   max_batch=2, prefill_chunk=64)
     assert eng._paged_width_buckets() == [4, 16, 32]
-    assert [pages_per_step(w, 16) for w in (4, 16)] == [4, 8]
+    assert [pages_per_step(w, 16, 1024) for w in (4, 16)] == [4, 8]
     assert eng.stats()["paged_grid_steps_total"] == 0   # warm-up: none
     long = [1 + (5 * i) % 60 for i in range(130)]
     before = eng.stats()

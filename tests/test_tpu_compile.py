@@ -701,3 +701,102 @@ def test_narrow_prefill_program_compiles_at_the_cells_shapes(
     assert 0 < mem.temp_size_in_bytes < (DEEP_TEMP_BYTES if deep
                                          else 2 ** 27)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+# ------------------------------------------- the hybrid linear-attention cell
+# benchmarks/configs/olmo-hybrid-7b-serve.json: 32 lanes; a state pool of
+# 33 slots of 15 PAIRS of heads x 96 x 384 float32 a linear layer (30
+# heads of 96 x 192: a pool of that shape is stored 256 lanes wide),
+# updated in place by slot; prefill passes of 2 x 64, 8 x 64 and 2 x 256
+# tokens through the chunk kernel; 30 KV heads of 128 in a cache row of
+# 32 ("Slice shape along dimension 2 must be aligned to tiling (8), but
+# is 30" is what a page's copy out of a 30-row pool gets), one query row
+# a KV head, every table width to 16,384 tokens
+
+OLMO = dict(lanes=32, heads=30, dk=96, dv=192, kv_rows=32)
+
+
+def _spec(one_chip):
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+
+
+@pytest.mark.parametrize("lanes,tokens", [(2, 64), (8, 64), (2, 256)],
+                         ids=["narrow", "wide", "deep"])
+def test_delta_chunk_kernel_compiles_at_the_cells_shapes(one_chip, lanes,
+                                                         tokens):
+    from ray_tpu.ops import delta_rule
+
+    spec, f32 = _spec(one_chip), jnp.float32
+    h, dk, dv = OLMO["heads"], OLMO["dk"], OLMO["dv"]
+    compiled = jax.jit(
+        lambda q, k, v, g, b, s0: delta_rule.gated_delta_chunk(
+            q, k, v, g, b, s0, interpret=False)
+    ).lower(spec((lanes, tokens, h, dk), f32),
+            spec((lanes, tokens, h, dk), f32),
+            spec((lanes, tokens, h, dv), jnp.bfloat16),
+            spec((lanes, tokens, h), f32), spec((lanes, tokens, h), f32),
+            spec((lanes, h // 2, dk, 2 * dv), f32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "gated_delta_chunk" in text
+
+
+def test_delta_update_compiles_in_place_at_the_cells_shapes(one_chip):
+    from ray_tpu.ops import delta_rule
+
+    spec, f32 = _spec(one_chip), jnp.float32
+    lanes, h, dk, dv = (OLMO[k] for k in ("lanes", "heads", "dk", "dv"))
+    compiled = jax.jit(
+        lambda pool, slots, q, k, v, g, b: delta_rule.gated_delta_update(
+            pool, slots, q, k, v, g, b, interpret=False),
+        donate_argnums=(0,)
+    ).lower(spec((1 + lanes, h // 2, dk, 2 * dv), f32),
+            spec((lanes,), jnp.int32), spec((lanes, h, dk), f32),
+            spec((lanes, h, dk), f32), spec((lanes, h, dv), jnp.bfloat16),
+            spec((lanes, h), f32), spec((lanes, h), f32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "gated_delta_update" in text
+    # in place: the pool that comes out IS the one that went in, and the
+    # program holds no second copy of its 73 MB
+    memory = compiled.memory_analysis()
+    pool_bytes = (1 + lanes) * h * dk * dv * 4
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < pool_bytes // 8
+
+
+@pytest.mark.parametrize("width", [4, 16, 64, 256, 1024])
+def test_paged_decode_compiles_at_a_row_of_32_heads(one_chip, width):
+    """One query row a KV head (group 1), 32 rows of 128: a grid step
+    holds 256 keys of them where a row of 8 heads holds 512
+    (`pages_per_step`'s `row`), or the kernel's fast memory is refused
+    by 48 KB."""
+    from ray_tpu.ops.paged_attention import pages_per_step
+
+    spec = _spec(one_chip)
+    lanes, rows = OLMO["lanes"], OLMO["kv_rows"]
+    slots = 4097 * PAGE
+    compiled = jax.jit(
+        lambda q, k, v, bt, cl: paged_attention(
+            q, k, v, bt, cl, page_size=PAGE, interpret=False)
+    ).lower(spec((lanes, 1, rows, D), jnp.bfloat16),
+            spec((slots, rows, D), jnp.bfloat16),
+            spec((slots, rows, D), jnp.bfloat16),
+            spec((lanes, width), jnp.int32),
+            spec((lanes,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_attention_decode" in text
+    assert pages_per_step(width, PAGE, rows * D) == min(
+        width, max(8, min(16, width // 4)))
+
+
+@pytest.mark.parametrize("width", [4, 16, 64, 256, 512, 2048])
+@pytest.mark.parametrize("row", [64, 128, 512, 1024, 2048],
+                         ids=lambda r: f"row-{r}")
+def test_a_row_the_benchmark_had_keeps_its_pages_a_step(width, row):
+    """Mistral's and Laguna's 8 heads of 128, granite's 4 pairs, SDAR's
+    4 heads: no cache row of 2,048 numbers or fewer changes its grid, so
+    their decode programs are what they were."""
+    from ray_tpu.ops.paged_attention import pages_per_step
+
+    assert pages_per_step(width, PAGE, row) \
+        == min(width, max(8, min(32, width // 4)))
