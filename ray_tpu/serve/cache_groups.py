@@ -201,6 +201,10 @@ class FullPages(_Pages):
         self.prefix_sharing = bool(prefix_sharing) and not refused
         self.sharing_refused = refused if prefix_sharing else ""
         self.refs = [0] * num_pages
+        # pages with more than one reference, kept where `refs` is
+        # written (`admit`, `release`): a reference count going 1 -> 2
+        # adds one, 2 -> 1 takes one away
+        self.shared = 0
         self.max_tokens = (num_pages - 1) * page_size
         self.index: Dict[bytes, int] = {}
         self._children: Dict[bytes, set] = {}
@@ -231,6 +235,7 @@ class FullPages(_Pages):
         del self.free[:own]
         for p in table:
             self.refs[p] += 1   # a free page's is 0
+            self.shared += self.refs[p] == 2
         bt = np.asarray(table, np.int32)
         held = _SeqPages(bt, (bt[:, None] * ps + np.arange(
             ps, dtype=np.int32)).reshape(-1))
@@ -255,6 +260,7 @@ class FullPages(_Pages):
         freed = []
         for p in held.pages.tolist():
             self.refs[p] -= 1
+            self.shared -= self.refs[p] == 1
             if self.refs[p] <= 0:
                 self.refs[p] = 0
                 freed.append(p)
@@ -358,13 +364,9 @@ class FullPages(_Pages):
     def row_slots(self, held: _SeqPages, n: int, take: bool = False):
         return held.slots[:n]   # every position
 
-    def shared_pages(self) -> int:
-        """Pages referenced by more than one sequence."""
-        return sum(1 for r in self.refs if r > 1)
-
     def gauges(self) -> Dict[str, int]:
         return {"used": self.used(), "free": len(self.free),
-                "shared": self.shared_pages()}
+                "shared": self.shared}
 
     def stats(self, pools) -> Dict[str, Any]:
         return {**self.totals,
@@ -374,7 +376,7 @@ class FullPages(_Pages):
                    for part in ("latent", "index") if part in pools},
                 "free_pages": len(self.free), "used_pages": self.used(),
                 "kv_pages_in_use": {self.kind: self.used()},
-                "shared_pages": self.shared_pages(),
+                "shared_pages": self.shared,
                 "prefix_sharing": self.prefix_sharing,
                 "prefix_sharing_refused": self.sharing_refused,
                 **({"latent_pages_in_use": self.used()}

@@ -194,6 +194,9 @@ _HOST_SPANS = {"plan": "llm.plan", "gauges": "llm.gauges",
                "window_arrays": "llm.window_arrays",
                "grid_count": "llm.grid_count"}
 _NO_SPAN = contextlib.nullcontext()
+# the gauges as they were written last, (name, state) -> value: the
+# process's, as the gauges are (`_private/metrics.llm_metrics`)
+_gauged: Dict[tuple, int] = {}
 
 def _pow4_widths(first: int, cap: int) -> List[int]:
     """`first`, 4 x `first`, ... up to (and capped at) `cap`: the widths
@@ -409,14 +412,29 @@ class _StepClock:
       lasted; what the CPU clock read beyond that (its tick can be
       coarser than a stretch) is owed to the group's next stretches, so
       a reading is right over many steps, not step by step;
-    - what the device waited for: `flight` is the engine's passes
-      dispatched and unread, and the device runs them in order, so the
-      newest one done means the device has nothing.  From the first
-      boundary that sees it, the host phases the thread passes through,
-      up to and including the next dispatch, go to `starved_secs[phase]`
-      — a LOWER bound: the device ran dry somewhere inside the phase
-      before the boundary that saw it.  Nothing inside a `sync`; nothing
-      where no dispatch follows (an engine with nothing to do is idle);
+    - what the device waited for, between two bounds: `flight` is the
+      engine's passes dispatched and unread, and the device runs them in
+      order, so the newest one done means the device has nothing.  The
+      clock asks where a phase opens (not a `sync`: the wait tells) and
+      where a dispatch ENDS (`dispatched`, while the newest pass in
+      flight is still the one that ran beside the host's work).  From
+      the first boundary that sees it, the host phases the thread passes
+      through, up to and including the next dispatch, go to
+      `starved_secs[phase]` — the LOWER bound: the device ran dry
+      somewhere inside the ONE phase before the boundary that saw it (for
+      `dispatched`: inside the dispatch so far; behind a `sync`, whose
+      opening did not ask, the phase before it too, under the sync's
+      name), and those seconds go to `starved_before_secs[phase]` when
+      the dispatch lands, so lower + before is the UPPER bound and no
+      second is in both.  A dispatch onto a pass in flight is `chained`;
+      it is `dry` where that pass was done when the dispatch ended or the
+      device had been seen dry on the way, and `dry_in_dispatch` where
+      only the dispatch's end saw it (the lower bound has nothing of such
+      a step).
+      While a profile is taken the boundary that saw it leaves `llm.dry`
+      (`of`: the step whose pass had finished; `seen`: the phase).
+      Nothing where no dispatch follows, nothing onto an empty flight (an
+      engine with nothing to do is idle, not starved);
     - the turnaround: from the last read-back of a step (`turnaround`,
       at the boundary behind it) to the return of the next step's first
       `_forward` (`dispatched`), `llm.turnaround` with `of`, the step
@@ -438,7 +456,8 @@ class _StepClock:
         self._cpu_owed = dict.fromkeys(self.off_cpu_secs, 0.0)
         self._host_dt = 0.0   # the host phases' seconds since a CPU read
         self.starved_secs = dict.fromkeys(_HOST_PHASES, 0.0)
-        self.starved_steps = 0
+        self.starved_before_secs = dict.fromkeys(_CLOCKED, 0.0)
+        self.chained = self.chained_dry = self.dry_in_dispatch = 0
         self.host_secs = dict.fromkeys(_HOST_SPANS, 0.0)
         self.host = {key: _HostSpan(self, key) for key in _HOST_SPANS}
         self.turnaround_secs = 0.0
@@ -450,6 +469,14 @@ class _StepClock:
         self._decode = 0.0   # the open step's decode seconds
         self._dry = False    # the device was seen with nothing to run
         self._dry_since: list = []   # [(phase, seconds)] since then
+        # the seconds since the newest pass in flight was last found
+        # running (it may have finished anywhere in them, unseen: a
+        # `sync`'s opening does not ask); of the open phase, those
+        # `starved_before_secs` has; and what the boundary that saw the
+        # device dry found in doubt, (phase, seconds), until the next
+        # dispatch lands
+        self._doubt = self._booked = 0.0
+        self._dry_before = ("between", 0.0)
         self._step_span = self._phase_span = None
         self._turn_span = self._idle_span = None
 
@@ -463,14 +490,18 @@ class _StepClock:
             self.secs[key] += dt
             if key not in _WAITS:
                 self._host_dt += dt
-                if self._dry:
-                    self._dry_since.append((key, dt))
-                    if key in _DISPATCHES:   # the device has a pass again
-                        for k, secs in self._dry_since:
-                            self.starved_secs[k] += secs
-                        self.starved_steps += 1
-                        self._dry = False
-                        self._dry_since.clear()
+            if not self._dry:
+                self._doubt += dt - self._booked
+            elif key not in _WAITS:
+                self._dry_since.append((key, dt))
+                if key in _DISPATCHES:   # the device has a pass again
+                    for k, secs in self._dry_since:
+                        self.starved_secs[k] += secs
+                    k, secs = self._dry_before
+                    self.starved_before_secs[k] += secs
+                    self._dry = False
+                    self._dry_since.clear()
+            self._booked = 0.0
             if key.startswith("decode_"):
                 self._decode += dt
             self._phase_span.__exit__(None, None, None)
@@ -486,11 +517,19 @@ class _StepClock:
         return now
 
     def _open(self, key: str, args: Dict[str, Any]) -> None:
+        dry_of = None
         if not self._flight:
             self._dry = False    # nothing unread: idle, not starved
             self._dry_since.clear()
+            self._doubt = 0.0
         elif not self._dry and key not in _SYNCS:
-            self._dry = self._flight[-1].out.is_ready()
+            newest = self._flight[-1]
+            if newest.out.is_ready():
+                # it ran dry inside the phase this boundary closed (and,
+                # behind a `sync`, the one before it)
+                self._dry, dry_of = True, newest.step
+                self._dry_before = self._key, self._doubt
+            self._doubt = 0.0
         if self._turn_span is not None and key in _WAITS:
             # a wait before any dispatch: no pass came of this turnaround
             self._turn_span.__exit__(None, None, None)
@@ -505,7 +544,15 @@ class _StepClock:
         self._key = key
         self._phase_span = self.span(_CLOCKED[key], **args)
         self._phase_span.__enter__()
+        if dry_of is not None:
+            self._mark_dry(dry_of)
         self._note(key if key in _PHASES else None, self._n)
+
+    def _mark_dry(self, of: int) -> None:
+        """The boundary that first saw the device dry, on the profiler's
+        clock: inside the phase it opens, or the dispatch it ends."""
+        with self.span("llm.dry", of=of, seen=self._key):
+            pass
 
     def phase(self, key: str, **args) -> float:
         now = self._close(key in _EMITS)
@@ -542,13 +589,28 @@ class _StepClock:
         self._turn_span.__enter__()
 
     def dispatched(self) -> None:
-        """A `_forward` returned: the first one behind a turnaround's
-        beginning ends it."""
+        """A `_forward` returned, its pass not in `flight` yet: the first
+        one behind a turnaround's beginning ends it; and the newest pass
+        in flight is the one the device had while the host prepared this
+        one, so a dispatch that ends with it done ended dry."""
         if self._turn_span is not None:
             self.turnaround_secs += time.perf_counter() - self._t_turn
             self.turnarounds += 1
             self._turn_span.__exit__(None, None, None)
             self._turn_span = None
+        if not self._flight:
+            return   # onto an engine with nothing in flight: idle
+        self.chained += 1
+        if self._dry:
+            self.chained_dry += 1
+        elif self._flight[-1].out.is_ready():
+            # seen by nobody before: somewhere inside the dispatch so far
+            secs = time.perf_counter() - self._t0 - self._booked
+            self.starved_before_secs[self._key] += secs
+            self._booked += secs
+            self.chained_dry += 1
+            self.dry_in_dispatch += 1
+            self._mark_dry(self._flight[-1].step)
 
     def pass_secs(self, kind: str) -> float:
         """The seconds of one kind of pass: its four phases."""
@@ -569,7 +631,12 @@ class _StepClock:
                 "host_secs": dict(self.host_secs),
                 "starved_secs": dict(self.starved_secs),
                 "starved_secs_total": sum(self.starved_secs.values()),
-                "starved_steps_total": self.starved_steps,
+                "starved_before_secs": dict(self.starved_before_secs),
+                "starved_before_secs_total":
+                    sum(self.starved_before_secs.values()),
+                "chained_dispatches_total": self.chained,
+                "chained_dispatches_dry_total": self.chained_dry,
+                "dry_in_dispatch_total": self.dry_in_dispatch,
                 "turnaround_secs": self.turnaround_secs,
                 "turnarounds_total": self.turnarounds,
                 "host_wall_secs": cpu + off_cpu, "host_cpu_secs": cpu,
@@ -631,7 +698,7 @@ class _Seq:
                  "prefill_export", "export_payload", "trace_ctx",
                  "prefix_tokens", "submit_step", "admit_step",
                  "first_token_step", "ahead", "feed", "blk",
-                 "prefilled_at", "prefilled_step")
+                 "prefilled_at", "prefilled_step", "blocked_by")
 
     def __init__(self, request_id: str, prompt: List[int], max_new: int,
                  eos: Optional[int], preknown: Optional[List[int]] = None):
@@ -678,6 +745,9 @@ class _Seq:
         self.trace_ctx: Optional[tracing.SpanContext] = None
         self.prefix_tokens = 0
         self.submit_step = self.admit_step = self.first_token_step = -1
+        # the last reason admission refused it as the queue's head for
+        # (`_admit_locked`); empty if it never waited there
+        self.blocked_by = ""
         self.cancelled = False
         # absolute wall-clock deadline (epoch seconds; 0 = unbounded):
         # the sweep cancels expired in-flight sequences and recycles
@@ -930,6 +1000,9 @@ class LLMEngine:
             "block_open_rows_rewritten_total": 0}
         self._prefill_passes_by_width = dict.fromkeys(
             self._prefill_widths, 0)
+        # steps whose admission left the queue's head queued, by what
+        # refused it: no lane, or a cache group (by kind) with no room
+        self._admit_blocked = dict.fromkeys(("lanes", *self._groups), 0)
         # what the model counts on the device (`model.counters`: names
         # of the vector it returns beside its logits), summed by pass
         self._model_counters = {
@@ -1554,9 +1627,12 @@ class LLMEngine:
             ended_here = end is None or name == "llm.decode"
             if end is None:
                 end, last = seq.done_at, self._steps
+            more = {"blocked_by": seq.blocked_by} \
+                if name == "llm.queue" else {}
             tracing.record_span(
                 name, wall + start, wall + end, seq.trace_ctx,
-                attributes=dict(attrs, first_step=first, last_step=last),
+                attributes=dict(attrs, first_step=first, last_step=last,
+                                **more),
                 error="cancelled" if ended_here and seq.cancelled else "")
 
     def _sweep(self, now: float) -> None:
@@ -1591,6 +1667,11 @@ class LLMEngine:
                 del self._by_rid[rid]
 
     def _admit_locked(self) -> None:
+        """Lock held, once a step: admit from the queue's head while a
+        lane and every group have room.  Where the head stays queued the
+        step counts as blocked, by what refused it: `lanes`, or the kind
+        of the first group whose `fit` had no plan."""
+        blocked = "lanes"
         while self._queued and len(self._active) < self.max_batch:
             seq = self._queued[0]
             # shipped rows are attached whole: no prefix to look for
@@ -1598,7 +1679,9 @@ class LLMEngine:
             plans = {kind: group.fit(seq.total_len, tokens)
                      for kind, group in self._groups.items()}
             if None in plans.values():
-                break  # head-of-line waits for a group to have room
+                # head-of-line waits for a group to have room
+                blocked = next(k for k, plan in plans.items() if plan is None)
+                break
             self._queued.popleft()
             there, split = 0, False
             for kind, group in self._groups.items():
@@ -1632,6 +1715,11 @@ class LLMEngine:
                 self._prefilled(seq)
                 if seq.prefill_export:
                     self._export_seq_locked(seq, None)
+        if self._queued:
+            self._admit_blocked[blocked] += 1
+            self._queued[0].blocked_by = blocked
+            # the rest of `admit` under a span that says so
+            self._clock.phase("admit", blocked=blocked)
 
     def _prefill_end(self, seq: _Seq) -> int:
         """The tokens a sequence's prefill passes hold: all it was given
@@ -2328,17 +2416,24 @@ class LLMEngine:
         return self._metrics
 
     def _set_gauges(self, batch: int = 0, step_tokens: int = 0) -> None:
-        """Publish the step's own counts (an idle step's are zero)."""
+        """Publish the step's own counts (an idle step's are zero): a
+        gauge is written where its value differs from the last written,
+        so a scrape reads what it always read and a step whose counts
+        stand pays six comparisons."""
         m = self.metrics()
         if m is None:
             return
         with self._clock.host["gauges"]:
+            now = [("batch", None, batch), ("tps", None, step_tokens),
+                   ("queue", None, len(self._queued))]
             for group in self._groups.values():
-                for state, pages in group.gauges().items():
-                    m["pages"].set(pages, tags={"state": state})
-            m["batch"].set(batch)
-            m["queue"].set(len(self._queued))
-            m["tps"].set(step_tokens)
+                now += [("pages", state, pages)
+                        for state, pages in group.gauges().items()]
+            for name, state, value in now:
+                if _gauged.get((name, state)) != value:
+                    # a set takes the gauge's lock and builds its labels
+                    _gauged[name, state] = value
+                    m[name].set(value, tags=state and {"state": state})
 
     def stats(self) -> Dict[str, Any]:
         """Counters and gauges of this engine.  Every `*_total`,
@@ -2348,7 +2443,29 @@ class LLMEngine:
         BLOCK passes, `decode_lane_steps_total` and
         `decode_lane_steps_wasted_total` a lane's passes of a whole block
         each, and the `block_*` keys (the constructor has what each
-        counts) are there; for every other model they are not."""
+        counts) are there; for every other model they are not.
+
+        What the device waited for, in every run (`_StepClock`):
+        `starved_secs_total` over `loop_secs` is the LOWER bound of the
+        share of the stepping thread's time in which the device had
+        nothing to run until the next dispatch landed — the host phases
+        passed from the first clock boundary that found the newest pass
+        in flight done, up to and including the next dispatch — and
+        with `starved_before_secs_total` added the UPPER bound: the one
+        phase before each boundary that first saw the device dry, where
+        the dispatch's end saw it the dispatch so far; a trace's idle
+        share less its lulls lies between the two.
+        `chained_dispatches_dry_total` over `chained_dispatches_total`:
+        a dispatch is chained when a pass was in flight as it began, and
+        dry when that pass was done as the jitted call returned, or the
+        clock had seen the device with nothing to run on the way — in
+        how many steps the host, not the device, set the pace;
+        `dry_in_dispatch_total` of them were seen by the dispatch's end
+        alone.  `admit_blocked_steps_total` over `steps`: steps whose
+        admission left the head of the queue queued;
+        `admit_blocked_steps` says by what — `lanes` (every lane taken)
+        or the kind of the first cache group with no room (`full`:
+        pages; `window`; `state`: a slot)."""
         from ray_tpu.ops import compile_counts
         from ray_tpu.serve import cache_groups
 
@@ -2370,6 +2487,9 @@ class LLMEngine:
                        in self._model_counters.items()},
                     "prefill_passes_by_width":
                         dict(self._prefill_passes_by_width),
+                    "admit_blocked_steps": dict(self._admit_blocked),
+                    "admit_blocked_steps_total":
+                        sum(self._admit_blocked.values()),
                     **compile_counts(),
                     "startup_secs": dict(self.startup_secs),
                     "queued": len(self._queued),

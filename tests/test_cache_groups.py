@@ -18,9 +18,12 @@ from ray_tpu.models.pangu import PanguConfig
 from ray_tpu.serve.llm import LLMEngine
 
 STATS = frozenset("""
-    active admitted_total between_secs cancelled compile_secs_total
+    active admit_blocked_steps admit_blocked_steps_total admitted_total
+    between_secs cancelled chained_dispatches_dry_total
+    chained_dispatches_total compile_secs_total
     compiles_total cow_splits deadline_expired decode_lane_steps_total
-    decode_lane_steps_wasted_total decode_secs decode_steps finished_total
+    decode_lane_steps_wasted_total decode_secs decode_steps
+    dry_in_dispatch_total finished_total
     first_tokens_total free_pages host_cpu_secs host_off_cpu_secs
     host_secs host_wall_secs kernel_mode kv_pages_in_use
     kv_pages_shipped_in kv_pages_shipped_out
@@ -33,7 +36,8 @@ STATS = frozenset("""
     prefill_tokens_total prefill_wait_secs_total prefix_hits
     prefix_sharing prefix_sharing_refused prefix_tokens_shared
     queue_wait_secs_total queued runahead_decode_steps_total shared_pages
-    startup_secs starved_secs starved_secs_total starved_steps_total
+    startup_secs starved_before_secs starved_before_secs_total
+    starved_secs starved_secs_total
     step_secs steps submitted_total turnaround_secs turnarounds_total
     used_pages
 """.split())
@@ -181,3 +185,48 @@ def test_window_pages_are_sized_by_the_widest_chunk(chunk):
         group.release(st)
     assert group.used() == 0
     assert sorted(group.free) == list(range(1, group.num_pages))
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_the_shared_page_count_is_kept_where_a_reference_changes(seed):
+    """`FullPages.shared` (the `shared` gauge and `shared_pages` of
+    `stats()`) is kept on transitions — a page's references going 1 -> 2
+    adds one, 2 -> 1 takes one away — and equals a scan of the reference
+    counts after any run of admissions that share whole pages, split one
+    mid-page (copy-on-write) and release."""
+    from ray_tpu.models.llama import LlamaConfig
+    from ray_tpu.serve.cache_groups import FullPages
+
+    ps, total = 4, 48
+    cfg = LlamaConfig(vocab_size=64, dim=16, n_layers=1, n_heads=2,
+                      n_kv_heads=1, hidden_dim=32, max_seq_len=total)
+    full = FullPages(cfg.cache_spec(), ps, 1 + 6 * (total // ps), total,
+                     True, "")
+    rs = np.random.RandomState(seed)
+    stems = [[int(t) for t in rs.randint(1, 60, total)] for _ in range(3)]
+    live, most, shared_admissions, splits = [], 0, 0, 0
+    for _ in range(400):
+        scan = sum(r > 1 for r in full.refs)
+        assert full.shared == scan == full.gauges()["shared"] \
+            == full.stats({})["shared_pages"]
+        most = max(most, scan)
+        if live and (rs.rand() < 0.45 or len(live) == 6):
+            full.release(live.pop(rs.randint(len(live)))[0])
+            continue
+        # a stem's first `keep` tokens (whole pages or mid-page), then its own
+        keep = int(rs.randint(0, total - 8))
+        n = int(rs.randint(keep + 2, total - 4))
+        tokens = stems[rs.randint(3)][:keep] + [
+            int(t) for t in rs.randint(60, 64, n - keep)]
+        plan = full.fit(n + 4, tokens)
+        assert plan is not None
+        held, there, copy = full.admit(plan)
+        shared_admissions += there > 0
+        splits += copy is not None
+        full.written(held, tokens, n)   # its prompt's whole pages: shareable
+        live.append((held, tokens))
+    assert most > 5 and shared_admissions > 50 and splits > 10
+    for held, _tokens in live:
+        full.release(held)
+    assert full.shared == 0 == sum(full.refs)
+    assert sorted(full.free) == list(range(1, full.num_pages))

@@ -282,15 +282,18 @@ def test_the_starvation_clock_counts_from_the_boundary_that_saw_it(
     def starved():
         got = {k: v for k, v in clock.starved_secs.items() if v}
         assert sum(got.values()) == clock.stats()["starved_secs_total"]
-        return got, clock.starved_steps
+        assert clock.dry_in_dispatch == 0   # every one seen at a boundary
+        return got, clock.chained_dry
 
     # an empty flight: nothing is asked, nothing counted
     clock.begin(0)
     clock.phase("decode_build")
     clock.phase("decode_dispatch")
+    clock.dispatched()
     flight.append(_Pass(0))
     clock.end()                        # asked: a pass is in flight
     assert starved() == ({}, 0) and flight[-1].asked == 1
+    assert clock.chained == 0          # onto nothing: idle, not chained
     # step 1: the pass in flight is done at the step's THIRD boundary
     # (the one that opens the dispatch): the dispatch's second, nothing
     # before
@@ -298,6 +301,7 @@ def test_the_starvation_clock_counts_from_the_boundary_that_saw_it(
     clock.phase("decode_build")        # boundary 2: not ready
     flight[-1].ready = True
     clock.phase("decode_dispatch")     # boundary 3: ready
+    clock.dispatched()                 # seen dry already: not asked again
     assert flight[-1].asked == 4
     flight.append(_Pass(1))
     clock.phase("decode_sync", of=0)   # the dispatch ends: counted
@@ -311,13 +315,15 @@ def test_the_starvation_clock_counts_from_the_boundary_that_saw_it(
     clock.begin(2)
     clock.phase("prefill_build")
     clock.phase("prefill_dispatch")
+    clock.dispatched()
     asked = flight[-1].asked
-    assert starved() == ({"decode_dispatch": 1.0}, 1)   # not yet landed
+    assert starved() == ({"decode_dispatch": 1.0}, 2)   # not yet landed
     flight.append(_Pass(2))
     # ... up to and including the next dispatch, and no further: the
     # prefill pass is the newest now, and it is running
     clock.phase("decode_build")
     clock.phase("decode_dispatch")
+    clock.dispatched()                 # chained onto the running prefill
     assert flight[-2].asked == asked   # seen dry: not asked again
     flight.append(_Pass(2))
     assert starved() == ({"decode_dispatch": 1.0, "decode_emit": 1.0,
@@ -336,10 +342,12 @@ def test_the_starvation_clock_counts_from_the_boundary_that_saw_it(
     clock.begin(3)
     clock.phase("decode_build")
     clock.phase("decode_dispatch")
+    clock.dispatched()
     flight.append(_Pass(3))
     clock.end()
     got, steps = starved()
     assert steps == 3 and "decode_sync" not in clock.starved_secs
+    assert clock.chained == 4
     assert got == {"decode_dispatch": 2.0, "decode_emit": 2.0,
                    "between": 2.0, "admit": 2.0, "prefill_build": 1.0,
                    "prefill_dispatch": 1.0, "prefill_emit": 1.0,
@@ -358,8 +366,16 @@ def test_the_starvation_clock_counts_from_the_boundary_that_saw_it(
     clock.begin(5)                     # a request came: nothing in flight
     clock.phase("decode_build")
     clock.phase("decode_dispatch")
+    clock.dispatched()
     clock.end()
     assert starved() == (got, 3)
+    # the upper bound's share: the one phase before each boundary that
+    # saw it (behind a sync, whose opening asks nothing, the dispatch
+    # before it too), booked when the dispatch landed — and nothing of
+    # the stretch that ended with no dispatch (idle, not starved)
+    assert {k: v for k, v in clock.starved_before_secs.items() if v} == {
+        "decode_build": 1.0, "decode_sync": 1.0, "prefill_sync": 2.0}
+    assert (clock.chained, clock.chained_dry) == (4, 3)
     # one reading of the wall clock a boundary, each a second here: the
     # phases and the time outside them add up to the loop's seconds
     st = clock.stats()
@@ -371,6 +387,200 @@ def test_the_starvation_clock_counts_from_the_boundary_that_saw_it(
     assert st["host_off_cpu_secs"] == st["host_wall_secs"] \
         == st["loop_secs"] - 1.0 - sum(
             v for k, v in clock.secs.items() if k.endswith("_sync"))
+
+
+def test_a_dispatch_that_ends_dry_is_counted_and_bounds_the_idle_time(
+        monkeypatch):
+    """The device runs dry INSIDE a dispatch: no boundary sees it (the
+    pass that was in flight when the dispatch opened is done when it
+    ends, and the next boundary's newest pass is the new one), so the
+    lower bound has nothing; `dispatched` asks once more, before the new
+    pass joins the flight."""
+    ticks = _Ticks()
+    monkeypatch.setattr(llm, "time", ticks)
+    flight = []
+    clock = _StepClock(flight)
+    marks, real = [], clock.span
+
+    def spy(name, **args):
+        if name == "llm.dry":
+            marks.append((args, clock._key))
+        return real(name, **args)
+
+    clock.span = spy
+
+    def counts():
+        st = clock.stats()
+        assert st["starved_secs_total"] == sum(st["starved_secs"].values())
+        assert st["starved_before_secs_total"] \
+            == sum(st["starved_before_secs"].values())
+        return (st["chained_dispatches_total"],
+                st["chained_dispatches_dry_total"],
+                st["dry_in_dispatch_total"], st["starved_secs_total"],
+                {k: v for k, v in st["starved_before_secs"].items() if v})
+
+    # nothing in flight: the dispatch is neither chained nor dry
+    clock.begin(0)
+    clock.phase("decode_build")
+    clock.phase("decode_dispatch")
+    clock.dispatched()
+    flight.append(_Pass(0))
+    clock.end()
+    assert counts() == (0, 0, 0, 0.0, {})
+    # a pass in flight that outlasts the dispatch: chained, not dry
+    clock.begin(1)
+    clock.phase("decode_build")
+    clock.phase("decode_dispatch")
+    clock.dispatched()
+    flight.append(_Pass(1))
+    clock.phase("decode_sync", of=0)
+    flight.pop(0)
+    clock.phase("decode_emit")
+    clock.end()
+    assert counts() == (1, 0, 0, 0.0, {}) and not marks
+    # the pass in flight ends inside the dispatch: every boundary found
+    # it running, the dispatch's end finds it done
+    clock.begin(2)
+    clock.phase("decode_build")
+    clock.phase("decode_dispatch")
+    asked = flight[-1].asked
+    flight[-1].ready = True
+    clock.dispatched()                  # one more question, one clock read
+    assert flight[-1].asked == asked + 1
+    flight.append(_Pass(2))
+    clock.phase("decode_sync", of=1)
+    flight.pop(0)
+    clock.phase("decode_emit")
+    clock.end()
+    # the dispatch's second so far is the upper bound's, of its two; the
+    # lower bound is as it was
+    assert counts() == (2, 1, 1, 0.0, {"decode_dispatch": 1.0})
+    assert clock.secs["decode_dispatch"] == 1.0 + 1.0 + 2.0
+    assert marks == [({"of": 1, "seen": "decode_dispatch"},
+                      "decode_dispatch")]
+    # seen inside a prefill dispatch, and the prefill pass itself is done
+    # when the decode build opens: the rest of that dispatch is in doubt
+    # too, each second once
+    clock.begin(3)
+    clock.phase("prefill_build")
+    clock.phase("prefill_dispatch")
+    flight[-1].ready = True
+    clock.dispatched()
+    flight.append(_Pass(3, ready=True))
+    clock.phase("decode_build")         # sees the prefill pass done
+    clock.phase("decode_dispatch")
+    clock.dispatched()                  # dry on the way: counted, not asked
+    flight.append(_Pass(3))
+    clock.end()
+    chained, dry, inside, lower, before = counts()
+    assert (chained, dry, inside) == (4, 3, 2)
+    assert before == {"decode_dispatch": 1.0, "prefill_dispatch": 2.0}
+    assert clock.secs["prefill_dispatch"] == 2.0
+    assert lower == 2.0 == clock.starved_secs["decode_build"] \
+        + clock.starved_secs["decode_dispatch"]
+    assert [m[0] for m in marks[1:]] == [
+        {"of": 2, "seen": "prefill_dispatch"},
+        {"of": 3, "seen": "decode_build"}]
+
+
+class _Device:
+    """A device for the clock's bounds to be held against: it runs the
+    passes in order, each for a drawn number of clock reads, and knows
+    when it had nothing to run."""
+
+    def __init__(self, ticks, rs):
+        self.ticks, self.rs = ticks, rs
+        self.free_at = 0.0      # when the last pass enqueued ends
+        self.idle = 0.0         # seconds it waited for a dispatch, truly
+
+    def enqueue(self, chained: bool):
+        now = self.ticks.now
+        if chained and self.free_at < now:
+            self.idle += now - self.free_at
+        work = float(self.rs.choice([0.5, 1.5, 2.5, 4.5, 9.5]))
+        self.free_at = max(self.free_at, now) + work
+        return self.free_at
+
+
+class _TimedPass(_Pass):
+    """Done when the device's clock says so."""
+
+    def __init__(self, step, ticks, done_at):
+        super().__init__(step)
+        self.ticks, self.done_at = ticks, done_at
+
+    def is_ready(self):
+        return self.ticks.now >= self.done_at
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_the_two_bounds_hold_the_devices_wait_over_a_random_walk(
+        monkeypatch, seed):
+    """Steps of random shape (a prefill pass, a decode pass, both, none;
+    read-backs that lag by up to two steps; parks) over a device whose
+    passes take random times: what the device truly waited for a chained
+    dispatch lies between the clock's lower bound — less the tails of the
+    dispatches it booked whole, the device having its pass from
+    `dispatched` on — and its upper bound; no second is booked twice."""
+    import numpy as np
+
+    rs = np.random.RandomState(seed)
+    ticks = _Ticks()
+    monkeypatch.setattr(llm, "time", ticks)
+    flight = []
+    clock = _StepClock(flight)
+    device = _Device(ticks, rs)
+    tails = 0.0
+
+    def reads(n):   # the host works: so many readings of the clock long
+        for _ in range(n):
+            ticks.perf_counter()
+
+    def dispatch(kind, n):
+        nonlocal tails
+        clock.phase(f"{kind}_build")
+        clock.phase(f"{kind}_dispatch")
+        reads(rs.randint(0, 3))             # the jitted call
+        seen_before = clock._dry
+        clock.dispatched()
+        landed = ticks.now
+        rec = _TimedPass(n, ticks, device.enqueue(chained=bool(flight)))
+        rec.kind = kind
+        reads(rs.randint(0, 2))             # the host state it advances
+        flight.append(rec)
+        if seen_before:   # booked to the phase's end: the next reading
+            tails += ticks.now + 1.0 - landed
+
+    for n in range(300):
+        clock.begin(n)
+        for kind in ("prefill", "decode"):
+            if rs.rand() < 0.6:
+                dispatch(kind, n)
+        lag = rs.randint(0, 3)
+        while flight and flight[0].step <= n - lag:
+            rec = flight.pop(0)
+            clock.phase(rec.kind + "_sync", of=rec.step)
+            ticks.now = max(ticks.now, rec.done_at)   # the wait
+            clock.phase(rec.kind + "_emit")
+        clock.end()
+        if rs.rand() < 0.1 and not flight:
+            clock.phase("park")
+            clock.phase("between")
+        st = clock.stats()
+        lower = st["starved_secs_total"]
+        upper = lower + st["starved_before_secs_total"]
+        assert 0.0 <= lower <= upper <= st["loop_secs"]
+        assert st["dry_in_dispatch_total"] \
+            <= st["chained_dispatches_dry_total"] \
+            <= st["chained_dispatches_total"]
+        for k, secs in clock.secs.items():   # no second in both bounds
+            assert clock.starved_secs.get(k, 0.0) \
+                + clock.starved_before_secs[k] <= secs + 1e-9, k
+        if not clock._dry:   # (a dry stretch is booked when it ends)
+            assert lower - tails <= device.idle <= upper, \
+                (n, lower, tails, device.idle, upper)
+    assert clock.chained > 100 and clock.dry_in_dispatch > 5
+    assert lower > 0 and upper > lower
 
 
 def test_work_or_waiting_is_read_at_a_steps_ends_and_an_emits(monkeypatch):
@@ -572,6 +782,211 @@ def test_request_spans_ride_the_callers_trace():
     for rows in by_rid.values():
         assert rows[-1]["status"] == "cancelled"
         assert all("status" not in s for s in rows[:-1])
+
+
+def _blocked_lanes():
+    """Two lanes, three requests: the third waits for a lane."""
+    return _engine(), "lanes", 3
+
+
+def _blocked_full():
+    """Four lanes over 8 pages, requests of 3 pages: the third waits for
+    pages with two lanes empty (an engine whose `num_pages` is below
+    max_batch x pages_per_seq: tests/test_olmo_hybrid_admission.py)."""
+    return _engine(max_batch=4, num_pages=9), "full", 3
+
+
+def _blocked_state():
+    """`StateSlots` has a slot a lane and its `fit` never refuses (the
+    constructor takes no other number), so a state group that has no
+    room is stood in for: the second of two slots is 'taken'."""
+    import dataclasses
+
+    from ray_tpu.models.granite import GraniteConfig
+
+    eng = LLMEngine(dataclasses.replace(
+        GraniteConfig.tiny(), dtype=jnp.float32, param_dtype=jnp.float32,
+        max_position_embeddings=64), seed=5, page_size=8, max_batch=2,
+        prefill_chunk=CHUNK, prefill_lanes=LANES)
+    state = eng._groups["state"]
+    state.fit = lambda total, tokens: total if len(state.free) > 1 else None
+    return eng, "state", 2
+
+
+@pytest.mark.parametrize("make", [_blocked_lanes, _blocked_full,
+                                  _blocked_state],
+                         ids=lambda f: f.__name__[9:])
+def test_a_step_that_leaves_the_head_queued_says_what_refused_it(make):
+    eng, reason, n = make()
+    assert set(eng.stats()["admit_blocked_steps"]) \
+        == {"lanes", *eng._groups}
+    spans, real = [], eng._clock.span
+
+    def spy(name, **args):
+        if name == "llm.admit":
+            spans.append(args)
+        return real(name, **args)
+
+    eng._clock.span = spy
+    caller = tracing.start_span("caller", parent=None)
+    before = eng.stats()
+    seqs = []
+
+    def traced():
+        token = tracing.activate(caller.context())
+        try:
+            seqs.extend(eng.submit(_request(i, n_prompt=20, max_new=4))
+                        for i in range(n))
+        finally:
+            tracing.restore(token)
+        blocked = 0
+        while any(not s.done for s in seqs):
+            waiting = len(eng._queued)
+            eng.step()
+            # a step is blocked where requests are still queued behind
+            # its admission (the queue only shrinks there)
+            blocked += bool(eng._queued) and waiting > 0
+        return blocked
+
+    blocked = []
+    recorded = _spans_of(lambda: blocked.append(traced()))
+    d = _delta(eng.stats(), before)
+    assert d["admit_blocked_steps_total"] == blocked[0] > 0
+    assert d["admit_blocked_steps_total"] <= d["steps"]
+    assert d["admit_blocked_steps"] == {
+        k: blocked[0] if k == reason else 0
+        for k in d["admit_blocked_steps"]}
+    # the phase span says so: a blocked step's `admit` is cut in two at
+    # the refusal, and the second carries the reason
+    assert [a for a in spans if a] == [{"blocked": reason}] * blocked[0]
+    # the request's own span: what it waited for as the queue's head
+    queue = {s["attrs"]["request_id"]: s["attrs"]["blocked_by"]
+             for s in recorded if s["name"] == "llm.queue"}
+    assert [queue[s.request_id] for s in seqs] \
+        == [""] * (n - 1) + [reason]
+    assert all("blocked_by" not in s["attrs"] for s in recorded
+               if s["name"] != "llm.queue")
+
+
+def test_a_gauge_is_written_where_it_changed_and_reads_as_a_scan_would():
+    """`_set_gauges` runs every step on the thread the device waits for:
+    it sets a gauge only where the value differs from the last it wrote,
+    and the page gauges come from counts the group keeps, not a scan."""
+    eng = _engine(prefix_sharing=True, max_batch=4)
+    m = eng.metrics()
+    writes = []
+
+    class Spy:
+        def __init__(self, key):
+            self.key, self.gauge = key, m[key]
+
+        def set(self, value, tags=None):
+            writes.append((self.key, (tags or {}).get("state"), value))
+            self.gauge.set(value, tags=tags)
+
+    for key in ("pages", "batch", "queue", "tps"):
+        m[key] = Spy(key)
+    shared = [1 + j % 50 for j in range(24)]   # three pages in common
+    full = eng._groups["full"]
+    seqs, most, steps = [], 0, 0
+    while len(seqs) < 3 or any(not s.done for s in seqs):
+        if steps in (0, 4, 5):   # the first one's pages are written by 4
+            seqs.append(eng.submit({"tokens": shared + [60 + len(seqs)],
+                                    "max_new_tokens": 12}))
+        eng.step()
+        steps += 1
+        scan = sum(r > 1 for r in full.refs)
+        assert full.gauges() == {"used": full.used(),
+                                 "free": len(full.free), "shared": scan}
+        most = max(most, scan)
+    eng.step()   # an idle step publishes zeros
+    assert most >= 3 and steps > 6
+    last = {}
+    for key, state, value in writes:
+        assert last.get((key, state)) != value, "written twice"
+        last[key, state] = value
+    assert last.items() <= llm._gauged.items()
+    # far fewer writes than six a step, and the last of each the truth
+    assert len(writes) < 4 * steps
+    # (the queue's depth was 0 at every step's end, as the warm-up left
+    # it: never written again)
+    assert last == {("pages", "used"): 0, ("pages", "shared"): 0,
+                    ("pages", "free"): full.num_pages - 1,
+                    ("batch", None): 0, ("tps", None): 0}
+    assert m["queue"].gauge.render()[-1].endswith(" 0.0")
+    pages = dict(line.rsplit(" ", 1) for line in m["pages"].gauge.render()
+                 if not line.startswith("#"))
+    assert {k.split('state="')[1].split('"')[0]: float(v)
+            for k, v in pages.items()} == {
+        "used": 0.0, "free": float(full.num_pages - 1), "shared": 0.0}
+
+
+_polls = []   # one engine's two polls, for the six cases
+NEW_METRICS = {
+    "dispatch_dry_pct": (["chained_dispatches_dry_total"],
+                         ["chained_dispatches_total"]),
+    "device_starved_upper_pct": (["starved_secs_total",
+                                  "starved_before_secs_total"],
+                                 ["loop_secs"]),
+    "admit_blocked_pct": (["admit_blocked_steps_total"], ["steps"]),
+}
+
+
+@pytest.mark.parametrize("suffix", ["tail", "load"])
+@pytest.mark.parametrize("name", sorted(NEW_METRICS))
+def test_the_new_readings_reach_the_benchmark_as_data(name, suffix):
+    """Six data files for the reader that is there (`stats_ratio`), found
+    through BENCHMARK.json: a number from polls of an engine that has the
+    keys, nothing (and no error) from a program that lacks them."""
+    import json
+
+    from benchmarks.spec import Spec
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    metric = f"{name}.{suffix}"
+    entry = next(m for m in bench["per_layer"] if m["name"] == metric)
+    steady = [w["name"] for w in bench["workloads"]
+              if w["name"].startswith("serve-") and "steady" in w["name"]]
+    assert len(steady) == 7
+    assert entry["workloads"] == (steady if suffix == "tail"
+                                  else ["serve-chat-overload"])
+    assert entry["moves"] == {
+        ("tail", "dispatch_dry_pct"): "tpot_p95_ms",
+        ("tail", "device_starved_upper_pct"): "tpot_p95_ms",
+        ("tail", "admit_blocked_pct"): "ttft_p75_ms",
+    }.get((suffix, name), "serve_tokens_per_s")
+    assert (entry["unit"], entry["better"], entry["source"],
+            entry["layer"]) == ("%", "lower", "program_counter", "engine")
+    with open(os.path.join(root, "benchmarks", "layer_metrics",
+                           metric + ".json")) as f:
+        data = json.load(f)
+    num, den = NEW_METRICS[name]
+    assert data["reader"] == "stats_ratio"
+    assert data["params"] == {"num": num, "den": den, "scale": 100.0}
+    assert (data["unit"], data["layer"], data["moves"]) \
+        == (entry["unit"], entry["layer"], entry["moves"])
+    # two polls of a real engine, with work between them
+    if not _polls:
+        eng = _engine()
+        first = eng.stats()
+        _drain(eng, [eng.submit(_request(i, max_new=6)) for i in range(5)])
+        _polls.append([first, eng.stats()])
+    polls, first = _polls, _polls[0][0]
+    cell = entry["workloads"][0]
+    spec = Spec()   # of this one metric: the others' readers want a run
+    spec.benchmark = dict(spec.benchmark, per_layer=[entry])
+    got = spec.read_layer_metrics(cell, {"polls": polls})[metric]
+    d = _delta(polls[0][1], first)
+    want = 100.0 * sum(d[k] for k in num) / sum(d[k] for k in den)
+    assert got["value"] == pytest.approx(want) and 0.0 <= want <= 100.0
+    if name == "admit_blocked_pct":
+        assert want > 0.0   # five requests through two lanes
+    # the parent's program: no such key in its polls
+    old = [[{k: v for k, v in row.items() if k not in num}
+            for row in polls[0]]]
+    assert spec.read_layer_metrics(cell, {"polls": old}) == {}
 
 
 def test_compiles_are_counted_and_say_which_phase_and_step():
