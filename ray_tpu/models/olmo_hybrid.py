@@ -44,7 +44,11 @@ cache a prefill pass runs the chunk kernel from the lane's state
 (`gated_delta_chunk`: the pass's 64 or 256 tokens a lane in chunks of
 64), a decode pass the update kernel over the state pool in place
 (`gated_delta_update`); without one (`init`, tests) the module runs the
-chunk form's XLA twin over the whole sequence.
+chunk form's XLA twin over the whole sequence.  A prefill pass's full
+layers attend through the block-table kernel of `ops/paged_prefill.py`
+(`paged_attention_prefill`): a lane's chunk against the pages the lane
+holds, read through the pass's own `ctx`, scores and running sums in VMEM
+— work by the lane's rows, not by the width of the pass's bucket.
 
 **What the config does not say** is decided HERE and listed under
 `assumed` in the configuration file: the block's form and the norms'
@@ -76,8 +80,8 @@ import jax.numpy as jnp
 from ray_tpu.models.cache import LayerCache, StateCache
 from ray_tpu.models.granite import dt_bias_init
 from ray_tpu.models.llama import (RMSNorm, _drawn_in_float32, _kernel_init,
-                                  cached_attention, dense_attention)
-from ray_tpu.ops import delta_rule, ssm
+                                  dense_attention)
+from ray_tpu.ops import delta_rule, paged_prefill, ssm
 
 LINEAR, FULL = "linear_attention", "full_attention"
 CHUNK = delta_rule.CHUNK
@@ -252,41 +256,24 @@ class FullAttention(nn.Module):
         rows = cfg.kv_rows
         widen = lambda t: jnp.pad(  # noqa: E731
             t, ((0, 0), (0, 0), (0, rows - hkv), (0, 0)))
-        q, k, v = widen(q), widen(k), widen(v)
         flat = cache["slots"].reshape(-1)
-        pool_k = cache["k"].at[flat].set(k.reshape(b * s, rows, d))
-        pool_v = cache["v"].at[flat].set(v.reshape(b * s, rows, d))
+        pool_k = cache["k"].at[flat].set(widen(k).reshape(b * s, rows, d))
+        pool_v = cache["v"].at[flat].set(widen(v).reshape(b * s, rows, d))
         if cache.get("block_tables") is not None:
             from ray_tpu.ops.paged_attention import paged_attention
 
-            out = paged_attention(q, pool_k, pool_v, cache["block_tables"],
+            out = paged_attention(widen(q), pool_k, pool_v,
+                                  cache["block_tables"],
                                   cache["context_lens"],
-                                  page_size=self.page_size)
+                                  page_size=self.page_size)[:, :, :heads]
         else:
-            out = chunk_attention(q, pool_k, pool_v, cache, positions)
-        return wo(out[:, :, :heads]), pool_k, pool_v
-
-
-ATTENTION_SLOTS = 128   # queries of a prefill pass whose scores are alive
-# at once: at 30 KV heads of 128 a pass's gathered context is 7,680 B a
-# column a lane and its scores 120 B a column a query; the wide pass's 8
-# lanes over 16,384 columns planned 3.5 GB beside the pools, two lanes at
-# a time 1.2 GB
-
-
-def chunk_attention(q, pool_k, pool_v, cache, positions):
-    """`cached_attention` of a prefill pass, a few lanes at a time
-    (`ATTENTION_SLOTS`), one after another: the same numbers, a
-    fraction of the pass's scratch."""
-    lanes, s = q.shape[0], q.shape[1]
-    group = max(1, ATTENTION_SLOTS // s)
-    args = (q, cache["ctx"], cache["ctx_pos"], cache["ctx_mask"], positions)
-    if lanes <= group or lanes % group:
-        return cached_attention(q, pool_k, pool_v, *args[1:])
-    out = jax.lax.map(
-        lambda a: cached_attention(a[0], pool_k, pool_v, *a[1:]),
-        tuple(t.reshape(lanes // group, group, *t.shape[1:]) for t in args))
-    return out.reshape(lanes, *out.shape[2:])
+            # a prefill pass: the chunk over the pages its lane holds; the
+            # row's heads of zeros are copied with their page and never
+            # multiplied
+            out = paged_prefill.paged_prefill_attention(
+                q, pool_k, pool_v, cache["ctx"], cache["ctx_mask"],
+                positions, page_size=self.page_size, kv_heads=hkv)
+        return wo(out), pool_k, pool_v
 
 
 class GatedDeltaNet(nn.Module):

@@ -197,3 +197,51 @@ def test_pages_and_state_ship_from_a_prefill_engine():
     assert list(shipped.generated) == want and blocker.done
     assert decoder.stats()["prefill_steps"] == 1   # the blocker's
     assert decoder.stats()["state_slots_in_use"] == 0
+
+
+def test_a_prompt_in_chunks_through_the_prefill_kernel_is_the_dense_forward():
+    """A prompt served in chunks, with a second, longer sequence in the
+    same passes: every generated token and its two largest logits are
+    the ONE-PASS dense forward's (the module with no cache: plain causal
+    attention over the whole sequence, no page, no kernel) — and the
+    prefill pass's program attends through `paged_attention_prefill`,
+    with no decode kernel in it."""
+    import jax
+
+    from ray_tpu.models.olmo_hybrid import build
+
+    eng = _engine(logit_trace=True)
+    programs, lanes, step_fn = [], [], eng._step_fn
+
+    def traced(*args, **kw):
+        tokens = args[3]
+        if tokens.shape[1] > 1:
+            lanes.append(int((np.asarray(args[4]).max(-1) > 0).sum()))
+            if not programs:
+                programs.append(str(jax.make_jaxpr(
+                    lambda: step_fn(*args, **kw))()))
+        return step_fn(*args, **kw)
+
+    eng._step_fn = traced
+    prompts = [_prompt(150, salt=11), _prompt(333, salt=12)]
+    outs = eng.generate_batch([
+        {"tokens": p, "max_new_tokens": 5, "request_id": f"d{i}"}
+        for i, p in enumerate(prompts)])
+    assert "paged_attention_prefill" in programs[0]
+    assert "paged_attention_decode" not in programs[0]
+    # the two prompts rode the same passes until the shorter one ended,
+    # in chunks
+    live = [n for n in lanes if n]      # (a warm-up's pass carries none)
+    assert live[0] == 2 and live[-1] == 1 and len(live) < 333 // 64
+    dense = jax.jit(build(CFG, PAGE).apply)
+    trace = eng.device_report()["logit_trace"]
+    worst = 0.0
+    for i, (prompt, out) in enumerate(zip(prompts, outs)):
+        assert len(out) == 5
+        lg = np.asarray(dense({"params": eng._params},
+                              jnp.asarray([prompt + out[:-1]])))[0]
+        for j, l1, id1, l2, id2 in trace[f"d{i}"]:
+            row = lg[len(prompt) - 1 + j]
+            assert id1 == out[j] == int(row.argmax())
+            worst = max(worst, abs(row[id1] - l1), abs(row[id2] - l2))
+    assert worst < ATOL, worst

@@ -12,7 +12,7 @@ import pytest
 from benchmarks import reference_olmo as ref
 from ray_tpu.models import cache as kv_cache, resolve
 from ray_tpu.models.olmo_hybrid import (FULL, LINEAR, OlmoHybridConfig,
-                                        build, chunk_attention)
+                                        build)
 
 CFG = dataclasses.replace(OlmoHybridConfig.tiny(), dtype=jnp.float32,
                           param_dtype=jnp.float32)
@@ -142,28 +142,3 @@ def test_every_reading_of_the_reference_is_another_model(params, reading):
     # (keys at their own lengths with beta near 2 make the state's map
     # expansive: that reading's logits are not finite, which differs too)
     assert not np.abs(other - want).max() <= 100 * ATOL, reading
-
-
-def test_chunk_attention_in_turns_is_cached_attention():
-    """A prefill pass's lanes a few at a time: the same numbers."""
-    from ray_tpu.models.llama import cached_attention
-
-    rs = np.random.RandomState(3)
-    lanes, s, h, d, slots, width = 8, 64, 8, 16, 1024, 128
-    q = jnp.asarray(rs.randn(lanes, s, h, d), jnp.float32)
-    pool_k = jnp.asarray(rs.randn(slots, h, d), jnp.float32)
-    pool_v = jnp.asarray(rs.randn(slots, h, d), jnp.float32)
-    cache = {"ctx": jnp.asarray(rs.randint(1, slots, (lanes, width))),
-             "ctx_pos": jnp.broadcast_to(jnp.arange(width), (lanes, width)),
-             "ctx_mask": jnp.asarray(rs.rand(lanes, width) < 0.9)}
-    pos = jnp.broadcast_to(jnp.arange(width - s, width), (lanes, s))
-    want = cached_attention(q, pool_k, pool_v, cache["ctx"],
-                            cache["ctx_pos"], cache["ctx_mask"], pos)
-    got = chunk_attention(q, pool_k, pool_v, cache, pos)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
-                               atol=1e-6)
-    # two lanes of 64 are one turn: the call itself
-    got = chunk_attention(q[:2], pool_k, pool_v,
-                          {k: v[:2] for k, v in cache.items()}, pos[:2])
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want[:2]),
-                               rtol=0, atol=1e-6)

@@ -4,9 +4,14 @@ one are what they were (PR 50): no counter of the delta rule in their
 2,048 numbers — so the decode kernel's pages a grid step, the one thing
 `ops/paged_attention.py` and `serve/cache_groups.py` now derive from
 the row, are what they were at every table width (the kernels' compiles
-at the cells' widths: tests/test_tpu_compile.py)."""
+at the cells' widths: tests/test_tpu_compile.py).  Nor is a program of
+theirs moved by the block-table prefill kernel the hybrid family got in
+PR 54 (`ops/paged_prefill.py`): they never import it."""
 
 import dataclasses
+import os
+import subprocess
+import sys
 
 import pytest
 
@@ -50,6 +55,51 @@ def _pangu():
 
     return {"model_type": "pangu_ultra_moe",
             **_published(PanguConfig.tiny())}
+
+
+def _glm():
+    from ray_tpu.models.pangu import PanguConfig
+
+    return PanguConfig.tiny_sparse()
+
+
+_FRESH = """
+import sys
+sys.path[:0] = [{tests!r}, {root!r}]
+import test_olmo_other_families as here
+from ray_tpu.models import resolve
+from ray_tpu.serve.llm import LLMEngine
+
+family, cfg = resolve(getattr(here, {model!r})())
+eng = LLMEngine(model=cfg, seed=0, page_size=here.PAGE, max_batch=2)
+out = eng.generate_batch([{{"tokens": list(range(1, 41)),
+                           "max_new_tokens": 3}}])
+st = eng.stats()
+assert len(out[0]) >= 3 and st["prefill_steps"] and st["decode_steps"], st
+loaded = [m for m in ("ray_tpu.ops.paged_prefill",
+                      "ray_tpu.models.olmo_hybrid") if m in sys.modules]
+print("FAMILY", family.__name__, "LOADED", loaded)
+"""
+
+
+@pytest.mark.parametrize("model", ["_llama", "_laguna", "_sdar", "_granite",
+                                   "_pangu", "_glm"])
+def test_a_family_never_imports_the_hybrid_familys_prefill_kernel(model):
+    """In a fresh interpreter (another test of this worker may have
+    imported the hybrid family): resolving and building the family's
+    model and running its prefill and decode passes leaves
+    `ops/paged_prefill.py` and `models/olmo_hybrid.py` — the two files
+    PR 54 touched — out of `sys.modules`.  What a family never imports
+    cannot change its program."""
+    tests = os.path.dirname(os.path.abspath(__file__))
+    done = subprocess.run(
+        [sys.executable, "-c", _FRESH.format(
+            tests=tests, root=os.path.dirname(tests), model=model)],
+        capture_output=True, text=True, timeout=280,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert done.returncode == 0, done.stderr[-3000:]
+    said = [ln for ln in done.stdout.splitlines() if ln.startswith("FAMILY")]
+    assert said and said[-1].endswith("LOADED []"), said
 
 
 @pytest.mark.parametrize("model", [_llama, _laguna, _sdar, _granite, _pangu])

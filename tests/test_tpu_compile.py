@@ -789,6 +789,48 @@ def test_paged_decode_compiles_at_a_row_of_32_heads(one_chip, width):
         width, max(8, min(16, width // 4)))
 
 
+@pytest.mark.parametrize("lanes,chunk,width", [
+    (2, 64, 1024), (8, 64, 16384), (2, 256, 16384)],
+    ids=["narrow", "wide", "deep"])
+def test_paged_prefill_compiles_at_the_cells_shapes(one_chip, lanes, chunk,
+                                                    width):
+    """`paged_attention_prefill` (PR 54) at the cell's pass shapes — 2 x
+    64 at the narrow program's one bucket, 8 x 64 and 2 x 256 over the
+    widest table, 1,024 pages; 30 query heads over the row of 32 — holds
+    its page buffers, the head-major block, the scores and the running
+    sums in the VMEM it asks for; the program around it gathers no
+    context and loops over no lanes (`cached_attention` under `lax.map`
+    did both: a `while` whose body wrote bf16[16384,32,128] twice and
+    planned 1.2 GB)."""
+    from ray_tpu.ops import paged_prefill as pp
+
+    spec = _spec(one_chip)
+    heads, rows = OLMO["heads"], OLMO["kv_rows"]
+    slots = 4097 * PAGE
+    pool = spec((slots, rows, D), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, k, v, ctx, mask, q_pos: pp.paged_prefill_attention(
+            q, k, v, ctx, mask, q_pos, page_size=PAGE, kv_heads=heads,
+            interpret=False)
+    ).lower(spec((lanes, chunk, heads, D), jnp.bfloat16), pool, pool,
+            spec((lanes, width), jnp.int32), spec((lanes, width), jnp.bool_),
+            spec((lanes, chunk), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_attention_prefill" in text
+    assert "paged_attention_decode" not in text
+    assert not re.search(r"\bwhile\(", text)
+    assert f"[{width},{rows},{D}]" not in text
+    assert ('"scoped_memory_configs":[{"memory_space":"1","offset":"0",'
+            f'"size":"{pp._VMEM_BYTES}"}}]') in text
+    assert pp._VMEM_BYTES < 128 << 20
+    # beside the pools: the queries and the output head-major, nothing
+    # that grows with the table
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 27
+    # every head in one grid step, 512 keys a block at every bucket
+    assert pp._tile_heads(heads, chunk) == heads
+    assert pp._block_pages(pool, width // PAGE, PAGE) == 512 // PAGE
+
+
 @pytest.mark.parametrize("width", [4, 16, 64, 256, 512, 2048])
 @pytest.mark.parametrize("row", [64, 128, 512, 1024, 2048],
                          ids=lambda r: f"row-{r}")
