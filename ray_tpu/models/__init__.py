@@ -62,7 +62,8 @@ __all__ = ["LlamaConfig", "LlamaModel", "llama_param_rules", "resolve",
 FAMILIES = {"llama": "llama", "mistral": "llama", "laguna": "laguna",
             "mellum": "laguna", "pangu_ultra_moe": "pangu",
             "glm_moe_dsa": "pangu", "granitemoehybrid": "granite",
-            "sdar_moe": "laguna", "olmo_hybrid": "olmo_hybrid"}
+            "sdar_moe": "laguna", "olmo_hybrid": "olmo_hybrid",
+            "qwen3_next": "qwen3_next"}
 # keys that a `model_type`'s published config class defaults, so that a
 # dictionary of that type may leave them out (a family's `from_dict`
 # takes an absent key for an absent mechanism).  `benchmarks/kinds/
@@ -70,6 +71,8 @@ FAMILIES = {"llama": "llama", "mistral": "llama", "laguna": "laguna",
 # lists the key this entry can go.
 # `sdar_moe`: the published class derives from the Qwen3-MoE code, which
 # norms q and k whatever the config says (it has no key for it).
+# (`qwen3_next` needs no entry: every mechanism of its published class
+# has a key in its config or is written into models/qwen3_next.py.)
 CLASS_DEFAULTS = {"laguna": {"gating": "per-head"},
                   "sdar_moe": {"qk_norm": True}}
 

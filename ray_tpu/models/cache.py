@@ -24,6 +24,17 @@ key its indexer scores, `index` numbers in a pool of its own, `"index"`,
 beside `"latent"`: two parts of one row at the same slot, so whatever
 moves a page (the copy-on-write split, page shipping) moves both.
 
+A row of FEW WIDE heads (`FlatKVCache`: 2 KV heads of 256) keeps the key
+and the value as ONE VECTOR each, `[kv_heads x head_dim]`, the heads side
+by side: pools `[slots, 512]`.  The chip tiles an array's last two
+dimensions, bfloat16 in tiles of 16 x 128, so a `[slots, 2, 256]` pool is
+stored sixteen heads tall, 8 x the bytes its shape says (16,384 B a token
+where the row is 2,048); flat, a page of 16 positions is `[16, 512]`,
+whole tiles and one copy, and a head is a 256-lane slice of it.  The same
+numbers at the same slot, so the paging, the copy-on-write split and page
+shipping are `LayerCache`'s; the paged kernels take either form
+(`ops/paged_attention.py`, `ops/paged_prefill.py`).
+
 A third kind, `state` (`StateCache`), keeps no row a position at all:
 the layer carries a recurrent state, ONE row a SEQUENCE whatever its
 length, so the kind's pool has a slot a sequence (slot 0 the garbage
@@ -73,6 +84,24 @@ class LayerCache(NamedTuple):
         return {}
 
 
+class FlatKVCache(NamedTuple):
+    """A layer of few wide KV heads whose key and value are each stored
+    as one vector of `kv_heads * head_dim` numbers (the module's text):
+    the shape states what the memory holds, so `kv_pool_bytes` and the
+    gauges' page bytes are real bytes.  Paged as `kind` says."""
+    kind: str
+    window: int
+    kv_heads: int
+    head_dim: int
+
+    def rows(self) -> Dict[str, Tuple[int, ...]]:
+        part = (self.kv_heads * self.head_dim,)
+        return {"k": part, "v": part}
+
+    def dtypes(self) -> Dict[str, Any]:
+        return {}
+
+
 class IndexedLatentCache(NamedTuple):
     """A latent layer whose attention reads only the rows an indexer
     selects: the row is the latent vector (`latent` numbers, stored as
@@ -101,8 +130,9 @@ class StateCache(NamedTuple):
     dk, dv) = (30, 96, 192) as published, which the chip would store
     256 lanes wide; the layer states the layout it keeps instead,
     (heads / 2, dk, 2 dv): the heads in pairs side by side, the same
-    numbers and no padding (ops/delta_rule.py, `pair_state`).  As with
-    a latent row, the shape states what the memory holds, so
+    numbers and no padding (ops/delta_rule.py, `pair_state`); 32 value
+    heads of 128 x 128 under 16 key heads are kept the same way, (16,
+    128, 256).  As with a latent row, the shape states what the memory holds, so
     `state_row_bytes` and `state_pool_bytes` are real bytes."""
     kind: str
     window: int      # 0: the kind has no positions

@@ -498,10 +498,13 @@ class ExpertLayer(nn.Module):
     (another family's expert layer is this one with its own); with
     `selection_bias` the router has a bias an expert, `moe_router_bias`,
     that moves who is chosen and not what a chosen expert weighs
-    (`ops/moe.py`, `route`)."""
-    cfg: LagunaConfig
+    (`ops/moe.py`, `route`); with `shared_gate` the shared expert's
+    output is multiplied by sigmoid(x . w), one number a token
+    (`moe_shared_gate` [D, 1]: models/qwen3_next.py)."""
+    cfg: Any
     scores: Any = router_scores
     selection_bias: bool = False
+    shared_gate: bool = False
 
     @nn.compact
     def __call__(self, x, valid):
@@ -532,8 +535,15 @@ class ExpertLayer(nn.Module):
         routed = routed.reshape(b, s, d)
         if cfg.shared_expert_intermediate_size:
             shared = SwiGLU(cfg, cfg.shared_expert_intermediate_size,
-                            name="moe_shared")(x)
-            y = combine_shared(shared.astype(jnp.float32), routed,
+                            name="moe_shared")(x).astype(jnp.float32)
+            if self.shared_gate:
+                with jax.named_scope("moe_shared_gate"):
+                    w_gate = self.param(
+                        "moe_shared_gate", nn.initializers.lecun_normal(),
+                        (d, 1), cfg.param_dtype)
+                    shared = shared * jax.nn.sigmoid(jnp.dot(
+                        x.astype(jnp.float32), w_gate.astype(jnp.float32)))
+            y = combine_shared(shared, routed,
                                cfg.moe_routed_scaling_factor)
         else:
             y = cfg.moe_routed_scaling_factor * routed

@@ -277,7 +277,12 @@ class FullAttention(nn.Module):
 
 
 class GatedDeltaNet(nn.Module):
-    cfg: OlmoHybridConfig
+    """The mixer of this family and of every other whose config has its
+    keys (models/qwen3_next.py: 16 key heads under 32 value heads — key
+    head j serves value heads 2j and 2j + 1, q and k repeated to the
+    value heads' number behind their normalisation — and beta in (0, 1),
+    `linear_allow_neg_eigval` false)."""
+    cfg: Any
 
     @nn.compact
     def __call__(self, x, cache=None):
@@ -286,8 +291,9 @@ class GatedDeltaNet(nn.Module):
         cfg = self.cfg
         f32 = jnp.float32
         b, s = x.shape[0], x.shape[1]
-        heads, dk, dv = (cfg.linear_num_key_heads, cfg.linear_key_head_dim,
-                         cfg.linear_value_head_dim)
+        key_heads, heads, dk, dv = (
+            cfg.linear_num_key_heads, cfg.linear_num_value_heads,
+            cfg.linear_key_head_dim, cfg.linear_value_head_dim)
         kd, vd, taps = cfg.key_dim, cfg.value_dim, cfg.linear_conv_kernel_dim
         with jax.named_scope("gdn_proj"):
             u = _dense(cfg, cfg.conv_dim, "qkv_proj")(x)
@@ -313,13 +319,16 @@ class GatedDeltaNet(nn.Module):
         def split(u):
             """silu, the three parts by head, q and k to their lengths."""
             u = nn.silu(u).astype(f32)
-            q = u[..., :kd].reshape(*u.shape[:2], heads, dk)
-            k = u[..., kd:2 * kd].reshape(*u.shape[:2], heads, dk)
+            q = u[..., :kd].reshape(*u.shape[:2], key_heads, dk)
+            k = u[..., kd:2 * kd].reshape(*u.shape[:2], key_heads, dk)
             v = u[..., 2 * kd:].reshape(*u.shape[:2], heads, dv)
             q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True)
                                   + L2_EPS) * dk ** -0.5
             k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True)
                                   + L2_EPS)
+            if heads != key_heads:
+                q, k = (jnp.repeat(t, heads // key_heads, axis=2)
+                        for t in (q, k))
             return q, k, v.astype(cfg.dtype)
 
         counts = None

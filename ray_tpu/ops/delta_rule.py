@@ -61,6 +61,16 @@ are block-diagonal (half their arithmetic is on zeros, on a matrix unit
 that a 64-row product would leave as idle); the decode kernel spreads
 each head's k and q over its own half of the lanes.
 
+**A second head shape** (models/qwen3_next.py): 16 key heads under 32
+value heads of 128 x 128, key head j serving value heads 2j and 2j + 1.
+The caller repeats q and k to the value heads' number, so the kernels see
+32 heads and nothing in this file changes but that map: the state is
+paired to (16, 128, 256), 256 lanes, whole tiles by itself.  A PAIR of
+value heads then holds the same key head twice: the chunk kernel's
+stacked `K K^T` and `Q K^T` compute one 64 x 64 block two times over
+(a kernel that read a shared key head once is not written: PERF.md
+section 7).
+
 `gated_delta_chunk_xla` and `gated_delta_update_xla` are the plain XLA
 twins the kernels are tested against (per-head layout, a triangular
 solve).  Interpreted on the CPU as the other kernels are.
@@ -78,7 +88,9 @@ CHUNK = 64        # tokens a chunk (the published kernels'); a power of 2
 PAIR_BLOCK = 15   # pairs of heads one grid step of the decode kernel
 # covers, where the heads divide by it: the published 30 heads are ONE
 # step a lane, 2.2 MB in and 2.2 MB out (`ssm.HEAD_BLOCK` has the
-# measurement this follows)
+# measurement this follows).  15 does not divide the second shape's 16
+# pairs (32 value heads): those get ALL their pairs in one step a lane,
+# 2.1 MB in and 2.1 MB out, the same one step a lane
 _VMEM_BYTES = 32 * 1024 * 1024
 F32 = jnp.float32
 

@@ -55,6 +55,13 @@ of the block sees every row below `context_lens[b]`, the block's own
 rows among them (the caller wrote them first), so there is no mask
 among the last S positions and nothing else changes.  The kernel is
 then named `paged_attention_block`.
+
+A FLAT pool `[T, Hkv x D]` (models/cache.py, `FlatKVCache`: few wide
+heads, 2 x 256, whose `[T, 2, 256]` pool the chip would store sixteen
+heads tall) is the same walk: a page is copied `[page_size, Hkv x D]`,
+whole tiles, and a head's keys are a D-lane slice of the block instead
+of a row of its transpose.  Nothing else changes, the kernel's names
+included.
 """
 
 from __future__ import annotations
@@ -95,11 +102,13 @@ def pages_per_step(width: int, page_size: int, row: int) -> int:
 
 
 def _paged_kernel(bt_ref, cl_ref, *refs, page_size: int, pages: int,
-                  scale: float, window: Optional[int] = None):
+                  scale: float, window: Optional[int] = None,
+                  flat_heads: int = 0):
     """`refs`: with a window the scalar `starts`, then q, the k and v
     pools (in HBM), o, the k and v page buffers `[2, pages, page_size,
-    Hkv, D]`, their DMA semaphores `[2 (k, v), 2 (buffer half)]` and
-    the three softmax scratch buffers."""
+    Hkv, D]` (`flat_heads` > 0: `[2, pages, page_size, Hkv x D]`, that
+    many heads side by side), their DMA semaphores `[2 (k, v), 2
+    (buffer half)]` and the three softmax scratch buffers."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -166,10 +175,17 @@ def _paged_kernel(bt_ref, cl_ref, *refs, page_size: int, pages: int,
         wait(half)
         rows = (keys,) + k_buf.shape[3:]
         q = q_ref[0].astype(jnp.float32)               # [Hkv, G, D]
-        k = k_buf[half].reshape(rows).transpose(1, 0, 2).astype(
-            jnp.float32)                                # [Hkv, keys, D]
-        v = v_buf[half].reshape(rows).transpose(1, 0, 2).astype(
-            jnp.float32)
+
+        def head_major(buf):                            # [Hkv, keys, D]
+            block = buf[half].reshape(rows)
+            if not flat_heads:
+                return block.transpose(1, 0, 2).astype(jnp.float32)
+            d = rows[1] // flat_heads
+            return jnp.stack([block[:, i * d:(i + 1) * d]
+                              for i in range(flat_heads)]
+                             ).astype(jnp.float32)
+
+        k, v = head_major(k_buf), head_major(v_buf)
         s = jax.lax.dot_general(
             q, k, dimension_numbers=(((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale  # [Hkv, G, keys]
@@ -211,7 +227,8 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     block of S that all see the same rows (the module's text).
 
     q: [B, 1, H, D] (or [B, S, H, D]) post-rope queries (the current token's k/v must
-    already be written into the pools); pool_k/pool_v: [T, Hkv, D];
+    already be written into the pools); pool_k/pool_v: [T, Hkv, D] (or
+    flat, [T, Hkv x D]: the module's text);
     block_tables: [B, W] physical page of each logical page; and
     context_lens: [B] live tokens per lane (position < context_lens[b]
     attends — causality for decode, since the query sits at position
@@ -254,7 +271,9 @@ def _paged_call(q, pool_k, pool_v, block_tables, context_lens, starts, *,
 
     b, s, h, d = q.shape
     assert s == 1 or window is None, "a block of queries has no window"
-    num_slots, hkv, _ = pool_k.shape
+    num_slots, stored = pool_k.shape[0], pool_k.shape[1:]
+    flat_row = len(stored) == 1
+    hkv = stored[0] // d if flat_row else stored[0]
     assert num_slots % page_size == 0, "pool not page-aligned"
     num_pages = num_slots // page_size
     g = h // hkv
@@ -270,8 +289,8 @@ def _paged_call(q, pool_k, pool_v, block_tables, context_lens, starts, *,
         qr = q.reshape(b, s, hkv, g, d).transpose(0, 2, 1, 3, 4).reshape(
             b, hkv, s * g, d)
         g = s * g
-    kp = pool_k.reshape(num_pages, page_size, hkv, d)
-    vp = pool_v.reshape(num_pages, page_size, hkv, d)
+    kp = pool_k.reshape(num_pages, page_size, *stored)
+    vp = pool_v.reshape(num_pages, page_size, *stored)
     bt = block_tables.astype(jnp.int32)
     cl = context_lens.astype(jnp.int32)
 
@@ -298,8 +317,9 @@ def _paged_call(q, pool_k, pool_v, block_tables, context_lens, starts, *,
 
     pages = pages_per_step(w, page_size, hkv * d)
     kernel = functools.partial(_paged_kernel, page_size=page_size,
-                               pages=pages, scale=scale, window=window)
-    page_buf = pltpu.VMEM((2, pages, page_size, hkv, d), pool_k.dtype)
+                               pages=pages, scale=scale, window=window,
+                               flat_heads=hkv if flat_row else 0)
+    page_buf = pltpu.VMEM((2, pages, page_size, *stored), pool_k.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(b, -(-w // pages)),

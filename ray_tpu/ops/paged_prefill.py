@@ -42,12 +42,21 @@ in flash form.
   a real score from the first block on, so a masked score's probability
   is exp(-1e30 - m) = 0 by itself.
 
+- A FLAT POOL `[T, Hkv x D]` (models/cache.py, `FlatKVCache`: 2 heads of
+  256 side by side, whole tiles where `[T, 2, 256]` is stored sixteen
+  heads tall) takes the same walk: a page arrives `[page_size, Hkv x D]`
+  and a head's keys are a D-lane slice of the block, copied to its row of
+  the head-major scratch without a transpose.  With 8 query heads a KV
+  head a lane's 256 queries are 2,048 rows a head, and both heads ride in
+  one grid step (`_tile_heads(2, 2048)`).
+
 No `window=` and no `block=` yet: their callers still run the XLA form.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -96,7 +105,7 @@ def _block_pages(pool, table_width: int, page_size: int) -> int:
     heads x D) is so wide that the buffers (six rows a key: k and v, two
     halves, the head-major copy) would pass _BUFFER_BYTES — or the whole
     table if narrower."""
-    row_bytes = pool.shape[1] * pool.shape[2] * pool.dtype.itemsize
+    row_bytes = math.prod(pool.shape[1:]) * pool.dtype.itemsize
     keys = min(_BLOCK_KEYS, _BUFFER_BYTES // (6 * row_bytes))
     return min(table_width, max(1, keys // page_size))
 
@@ -113,11 +122,12 @@ def _lanes(x, width: int):
 def _prefill_kernel(bt_ref, seen_ref, lo_ref, q_ref, qpos_ref, k_hbm, v_hbm,
                     o_ref, k_buf, v_buf, k_sem, v_sem, kt_ref, vt_ref,
                     acc_ref, m_ref, l_ref, *, page_size: int, pages: int,
-                    scale: float, group: int):
+                    scale: float, group: int, flat: bool = False):
     """q [1, Ht, G x S, D]: a tile of Ht KV heads' query rows of one
     lane's chunk, group-major; qpos [1, S, 1]; the pools `[num_pages,
-    page_size, R, D]` in HBM; o as q; `k_buf`, `v_buf` [2, pages,
-    page_size, R, D] and their DMA semaphores [2] (a buffer half each);
+    page_size, R, D]` in HBM (`flat`: `[num_pages, page_size, R x D]`);
+    o as q; `k_buf`, `v_buf` [2, pages, page_size, R, D] (or R x D) and
+    their DMA semaphores [2] (a buffer half each);
     `kt_ref`, `vt_ref` [R, keys, D]: the block head-major; float32
     scratch by head: acc [Ht, G x S, D], running max and denominator
     [Ht, G x S, 128].  `seen_ref` [B]: the positions the lane's chunk
@@ -161,8 +171,14 @@ def _prefill_kernel(bt_ref, seen_ref, lo_ref, q_ref, qpos_ref, k_hbm, v_hbm,
             wait_k(half)
             wait_v(half)
             stored = (keys,) + k_buf.shape[3:]
-            kt_ref[:] = k_buf[half].reshape(stored).transpose(1, 0, 2)
-            vt_ref[:] = v_buf[half].reshape(stored).transpose(1, 0, 2)
+            if flat:
+                for buf, turned in ((k_buf, kt_ref), (v_buf, vt_ref)):
+                    rows_of = buf[half].reshape(stored)
+                    for h in range(turned.shape[0]):
+                        turned[h] = rows_of[:, h * d:(h + 1) * d]
+            else:
+                kt_ref[:] = k_buf[half].reshape(stored).transpose(1, 0, 2)
+                vt_ref[:] = v_buf[half].reshape(stored).transpose(1, 0, 2)
             if masked:
                 pos = ci * keys + jax.lax.broadcasted_iota(
                     jnp.int32, (1, keys), 1)
@@ -231,6 +247,8 @@ def paged_prefill_attention(q: jax.Array, pool_k: jax.Array,
     q: [B, S, H, D]; pool_k / pool_v: [T, R, D] flat slot pools (this
     call's rows already written), of whose R stored heads the first
     `kv_heads` are the model's (None: all of them); H = G x kv_heads.
+    A pool `[T, R x D]` holds a row's heads side by side (the module's
+    text).
     The context is what the engine's prefill pass hands a full layer:
     ctx [B, L] the slot of context position 0, 1, ... in order, ctx_mask
     [B, L] true on the lane's first n columns, q_pos [B, S] the queries'
@@ -243,7 +261,8 @@ def paged_prefill_attention(q: jax.Array, pool_k: jax.Array,
     # behind the last position any query sees
     table = (ctx[:, ::page_size] // page_size).astype(jnp.int32)
     seen = jnp.minimum(ctx_mask.sum(-1), q_pos.max(-1) + 1).astype(jnp.int32)
-    stored, d = pool_k.shape[1:]
+    d = q.shape[-1]
+    stored = pool_k.shape[1] // d if pool_k.ndim == 2 else pool_k.shape[1]
     kv_heads = stored if kv_heads is None else kv_heads
     group = q.shape[2] // kv_heads
     return _prefill_call(q, pool_k, pool_v, table, seen, q_pos,
@@ -267,12 +286,13 @@ def _prefill_call(q, pool_k, pool_v, table, seen, q_pos, *, page_size: int,
     from jax.experimental.pallas import tpu as pltpu
 
     b, s, h, d = q.shape
-    num_slots, stored, _ = pool_k.shape
+    num_slots, flat = pool_k.shape[0], pool_k.ndim == 2
+    stored = pool_k.shape[1] // d if flat else pool_k.shape[1]
     assert num_slots % page_size == 0, "pool not page-aligned"
     assert h % kv_heads == 0 and kv_heads <= stored, (h, kv_heads, stored)
     assert kv_heads % tile_heads == 0, (kv_heads, tile_heads)
     group, width = h // kv_heads, table.shape[1]
-    paged = (num_slots // page_size, page_size, stored, d)
+    paged = (num_slots // page_size, page_size, *pool_k.shape[1:])
     kp, vp = pool_k.reshape(paged), pool_v.reshape(paged)
     if interpret:
         # as `_paged_call`: the interpreter carries whole operands
@@ -288,8 +308,9 @@ def _prefill_call(q, pool_k, pool_v, table, seen, q_pos, *, page_size: int,
 
     rows, keys = group * s, block_pages * page_size
     kernel = functools.partial(_prefill_kernel, page_size=page_size,
-                               pages=block_pages, scale=scale, group=group)
-    page_buf = pltpu.VMEM((2, block_pages, page_size, stored, d),
+                               pages=block_pages, scale=scale, group=group,
+                               flat=flat)
+    page_buf = pltpu.VMEM((2, block_pages, page_size, *pool_k.shape[1:]),
                           pool_k.dtype)
     head_major = pltpu.VMEM((stored, keys, d), pool_k.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(

@@ -949,7 +949,7 @@ def test_the_new_readings_reach_the_benchmark_as_data(name, suffix):
     entry = next(m for m in bench["per_layer"] if m["name"] == metric)
     steady = [w["name"] for w in bench["workloads"]
               if w["name"].startswith("serve-") and "steady" in w["name"]]
-    assert len(steady) == 7
+    assert len(steady) == 8
     assert entry["workloads"] == (steady if suffix == "tail"
                                   else ["serve-chat-overload"])
     assert entry["moves"] == {

@@ -842,3 +842,139 @@ def test_a_row_the_benchmark_had_keeps_its_pages_a_step(width, row):
 
     assert pages_per_step(width, PAGE, row) \
         == min(width, max(8, min(32, width // 4)))
+
+
+# ------------------------------------------------- the hybrid expert cell
+# benchmarks/configs/qwen3-next-80b-a3b-serve.json: 128 lanes; a FLAT
+# cache row of 2 KV heads x 256 (pools [slots, 512]: `[slots, 2, 256]` is
+# stored sixteen heads tall), 8 query heads a KV head; 32,769 pages; a
+# state pool of 129 slots of 16 PAIRS of value heads x 128 x 256 float32
+# under 16 key heads (q and k arrive repeated to 32); 256 experts of
+# 2048 x 512 held of a router 512 wide, 10 a token
+
+QWEN = dict(lanes=128, heads=16, kv=2, d=256, value_heads=32, dk=128,
+            dv=128, slots=32769 * PAGE)
+
+
+@pytest.mark.parametrize("width", [4, 16, 64, 256, 1024])
+def test_paged_decode_compiles_over_a_flat_row_of_two_wide_heads(one_chip,
+                                                                 width):
+    from ray_tpu.ops.paged_attention import pages_per_step
+
+    spec = _spec(one_chip)
+    lanes, row = QWEN["lanes"], QWEN["kv"] * QWEN["d"]
+    pool = spec((QWEN["slots"], row), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, k, v, bt, cl: paged_attention(
+            q, k, v, bt, cl, page_size=PAGE, interpret=False)
+    ).lower(spec((lanes, 1, QWEN["heads"], QWEN["d"]), jnp.bfloat16),
+            pool, pool, spec((lanes, width), jnp.int32),
+            spec((lanes,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_attention_decode" in text
+    # a row of 512 numbers keeps the grid every row of 2,048 or fewer has
+    assert pages_per_step(width, PAGE, row) == min(
+        width, max(8, min(32, width // 4)))
+    # no copy of the pool beside it: a page is copied as it lies
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 24
+
+
+@pytest.mark.parametrize("lanes,chunk,width", [
+    (2, 64, 1024), (8, 64, 1024), (2, 256, 16384)],
+    ids=["narrow", "wide", "deep"])
+def test_paged_prefill_compiles_over_a_flat_row_of_two_wide_heads(
+        one_chip, lanes, chunk, width):
+    """8 query heads a KV head: a lane's chunk is 512 to 2,048 query rows
+    a head, both heads in one grid step."""
+    from ray_tpu.ops import paged_prefill as pp
+
+    spec = _spec(one_chip)
+    heads, kv, d = QWEN["heads"], QWEN["kv"], QWEN["d"]
+    pool = spec((QWEN["slots"], kv * d), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, k, v, ctx, mask, q_pos: pp.paged_prefill_attention(
+            q, k, v, ctx, mask, q_pos, page_size=PAGE, kv_heads=kv,
+            interpret=False)
+    ).lower(spec((lanes, chunk, heads, d), jnp.bfloat16), pool, pool,
+            spec((lanes, width), jnp.int32), spec((lanes, width), jnp.bool_),
+            spec((lanes, chunk), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_attention_prefill" in text
+    assert not re.search(r"\bwhile\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 27
+    assert pp._tile_heads(kv, chunk * heads // kv) == kv
+    assert pp._block_pages(pool, width // PAGE, PAGE) == 512 // PAGE
+
+
+@pytest.mark.parametrize("lanes,tokens", [(2, 64), (8, 64), (2, 256)],
+                         ids=["narrow", "wide", "deep"])
+def test_delta_chunk_kernel_compiles_at_the_second_head_shape(one_chip, lanes,
+                                                              tokens):
+    from ray_tpu.ops import delta_rule
+
+    spec, f32 = _spec(one_chip), jnp.float32
+    h, dk, dv = QWEN["value_heads"], QWEN["dk"], QWEN["dv"]
+    compiled = jax.jit(
+        lambda q, k, v, g, b, s0: delta_rule.gated_delta_chunk(
+            q, k, v, g, b, s0, interpret=False)
+    ).lower(spec((lanes, tokens, h, dk), f32),
+            spec((lanes, tokens, h, dk), f32),
+            spec((lanes, tokens, h, dv), jnp.bfloat16),
+            spec((lanes, tokens, h), f32), spec((lanes, tokens, h), f32),
+            spec((lanes, h // 2, dk, 2 * dv), f32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "gated_delta_chunk" in text
+
+
+def test_delta_update_compiles_in_place_at_the_second_head_shape(one_chip):
+    """16 pairs a lane in ONE grid step (`PAIR_BLOCK` does not divide
+    them); the 271 MB pool is updated where it lies."""
+    from ray_tpu.ops import delta_rule
+
+    spec, f32 = _spec(one_chip), jnp.float32
+    lanes, h, dk, dv = (QWEN[k] for k in ("lanes", "value_heads", "dk",
+                                          "dv"))
+    assert (h // 2) % delta_rule.PAIR_BLOCK
+    compiled = jax.jit(
+        lambda pool, slots, q, k, v, g, b: delta_rule.gated_delta_update(
+            pool, slots, q, k, v, g, b, interpret=False),
+        donate_argnums=(0,)
+    ).lower(spec((1 + lanes, h // 2, dk, 2 * dv), f32),
+            spec((lanes,), jnp.int32), spec((lanes, h, dk), f32),
+            spec((lanes, h, dk), f32), spec((lanes, h, dv), jnp.bfloat16),
+            spec((lanes, h), f32), spec((lanes, h), f32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "gated_delta_update" in text
+    memory = compiled.memory_analysis()
+    pool_bytes = (1 + lanes) * h * dk * dv * 4
+    assert memory.alias_size_in_bytes >= pool_bytes
+    # beside the pool: the lanes' k and q as COLUMNS, [lanes, 16, 128, 4]
+    # float32, which the chip stores 128 lanes wide (134 MB at 128 lanes;
+    # PERF.md section 7) — and no second copy of the pool
+    assert memory.temp_size_in_bytes < 2 * pool_bytes
+
+
+@pytest.mark.parametrize("tokens", [128, 512, 2048],
+                         ids=["decode", "prefill", "four-passes"])
+def test_expert_layer_compiles_at_the_hybrid_expert_cells_shapes(one_chip,
+                                                                 tokens):
+    """`ops.moe.moe_layer` as the cell runs it: 256 experts of 2048 x 512
+    held of 512 routed over, 10 a token of which 5 land here: a decode
+    pass of 128 lanes (2.5 rows an expert, tiles of 16) and a prefill
+    pass's 512 tokens."""
+    from ray_tpu.ops import moe
+
+    spec = _spec(one_chip)
+    compiled = jax.jit(
+        lambda x, wr, w1, w3, w2, valid: moe.moe_layer(
+            x, wr, w1, w3, w2, top_k=10, held=(0, 256), valid=valid,
+            interpret=False)
+    ).lower(spec((tokens, 2048), jnp.bfloat16),
+            spec((2048, 512), jnp.bfloat16),
+            spec((256, 2048, 512), jnp.bfloat16),
+            spec((256, 2048, 512), jnp.bfloat16),
+            spec((256, 512, 2048), jnp.bfloat16),
+            spec((tokens,), jnp.bool_)).compile()
+    assert compiled.as_text().count("moe_experts") >= 2
+    assert moe.hidden_tile(2048, 512, 2) == 512
+    assert moe.row_tile(tokens, 10, 512) == (16 if tokens < 1024 else 64)
