@@ -68,8 +68,11 @@ cache, a pass whose context bucket is no wider than `index_topk` is the
 dense pass above, unchanged, but for the index key it writes; a wider
 prefill pass scores its lanes' index rows, takes each query's threshold
 and hands both to the chunk kernel, which masks what was not selected;
-a wider decode pass takes each lane's `index_topk` positions, gathers
-their latent rows and runs the decode kernel over those.  What the
+a wider decode pass takes each lane's threshold too and walks the lane's
+own pages under that mask (ops/sparse_decode.py) while its table has no
+more pages than `index_topk` — a copy out of the pool costs its issue up
+to a page — and past that takes each lane's `index_topk` positions,
+gathers their latent rows and runs the decode kernel over those.  What the
 published code does besides (a Hadamard rotation of q^I and k^I, there
 for its float8 index cache) is left out: an orthogonal map of both
 leaves the products as they are, and the cache here is bfloat16.
@@ -416,8 +419,11 @@ class LatentAttention(nn.Module):
         cache: (out [B, S, H, r], the index pool with this pass's keys
         written, this layer's entries of SPARSE_COUNTERS).  A pass whose
         context bucket is no wider than `index_topk` reads every row it
-        sees, through the same two calls as a config with no indexer."""
+        sees, through the same two calls as a config with no indexer; the
+        counters are the selection's, whichever way its rows are
+        fetched."""
         from ray_tpu.ops import latent_attention as la
+        from ray_tpu.ops import sparse_decode as sd
         from ray_tpu.ops import sparse_index as si
 
         iq, ik, iw = indexer
@@ -433,23 +439,42 @@ class LatentAttention(nn.Module):
         if tables is not None:
             visible = lens[:, None]
             read = jnp.sum(jnp.minimum(lens, top_k))
-            if tables.shape[1] * ps > top_k:
-                # each lane's `top_k` positions, their rows gathered a
-                # lane after a lane, and the decode kernel over those
+            width = tables.shape[1]
+            if width * ps <= top_k:
+                # no lane can hold more than is selected: every row
+                out = la.latent_paged_attention(q_row, pool, tables, lens,
+                                                **kernel)
+            else:
                 with jax.named_scope("index_scores"):
                     marks = si.index_scores(iq, iw, index, tables, lens,
                                             positions, page_size=ps)
-                with jax.named_scope("index_select"):
-                    at = si.select_rows(marks[:, 0], top_k)
-                with jax.named_scope("sparse_gather"):
-                    pool = si.gather_rows(pool, tables, at, page_size=ps)
-                tables = jnp.arange(b * top_k // ps, dtype=jnp.int32
-                                    ).reshape(b, top_k // ps)
                 pages = jnp.sum(si.pages_read(lens, positions, ps))
-                lens = jnp.minimum(lens, top_k)
                 scored, gathered = jnp.sum(visible), read
-            out = la.latent_paged_attention(q_row, pool, tables, lens,
-                                            **kernel)
+                # one softmax over the selected rows; their cheapest
+                # fetch follows from the table's width, since a copy out
+                # of the pool costs its issue, not its bytes, up to a page
+                if width <= top_k:
+                    # no more pages than the selection has rows: the
+                    # lane's own pages where they lie, and the kernel
+                    # masks what was not selected, as the chunk's below
+                    with jax.named_scope("index_select"):
+                        chosen = si.select_threshold(marks[:, 0], top_k)
+                    out = sd.latent_selected_attention(
+                        q_row, pool, tables, lens, marks, *chosen, **kernel)
+                else:
+                    # each lane's `top_k` positions, their rows gathered
+                    # a lane after a lane, and the decode kernel over
+                    # those
+                    with jax.named_scope("index_select"):
+                        at = si.select_rows(marks[:, 0], top_k)
+                    with jax.named_scope("sparse_gather"):
+                        pool = si.gather_rows(pool, tables, at,
+                                              page_size=ps)
+                    tables = jnp.arange(b * top_k // ps, dtype=jnp.int32
+                                        ).reshape(b, top_k // ps)
+                    out = la.latent_paged_attention(
+                        q_row, pool, tables, jnp.minimum(lens, top_k),
+                        **kernel)
         else:
             real = cache["slots"] != 0
             lens = cache["ctx_mask"].sum(-1)

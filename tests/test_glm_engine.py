@@ -63,7 +63,8 @@ def test_chunks_then_decode_is_the_references_greedy_answer(alone, n):
 
 def test_widths_on_both_sides_of_index_topk(alone):
     # prefill: 64 columns take the dense path, 256 and 512 select;
-    # decode: a table of 4 pages is dense, 16 and 32 gather
+    # decode: a table of 4 pages is dense, 16 and 32 (no more pages
+    # than `index_topk` rows) walk the lane's pages under the mask
     assert alone._prefill_widths == [64, 256, 512]
     assert alone._paged_width_buckets() == [4, 16, 32]
     assert alone._model.counters[-6:] == SPARSE_COUNTERS

@@ -125,8 +125,9 @@ def test_chunked_prefill_then_decode_is_the_references_every_position(
     first four over a 64-column context (the dense path: it writes the
     index keys the later chunks score), the rest over 256 columns (index
     scores, thresholds, the masked chunk kernel) — then 30 tokens one at
-    a time over a table of 16 pages (top-k, gather, the decode kernel):
-    the reference's full-forward logits at every position."""
+    a time over a table of 16 pages (the threshold, the lane's own pages
+    under its mask: ops/sparse_decode.py): the reference's full-forward
+    logits at every position."""
     model = build(CFG, PAGE)
     pools = kv_cache.make_pools(CFG.cache_spec(), {"full": 17 * PAGE},
                                 CFG.dtype)

@@ -3,6 +3,10 @@ part of a row and the router's selection bias (PR 42): no index pool in
 their pool trees, no bias among their parameters, no indexer's operation
 in their programs, their counter vectors at the length they had."""
 
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -82,6 +86,49 @@ def test_the_latent_family_without_index_keys_takes_the_kernels_as_before():
     marks = jnp.zeros((1, 8, 2 * PAGE), jnp.float32)
     picked = (marks, jnp.zeros((1, 8)), jnp.zeros((1, 8), jnp.int32))
     assert kernel_operands(call(picked)) == [8]
+
+
+_DECODE_PASS = """
+import re, sys
+sys.path[:0] = [{tests!r}, {root!r}]
+import jax, jax.numpy as jnp, numpy as np
+from test_granite_other_families import PAGE, _pangu
+from ray_tpu.models import cache as kv_cache, resolve
+
+family, cfg = resolve(_pangu())
+model = family.build(cfg, PAGE)
+tokens = np.zeros((2, 1), np.int32)
+params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+pools = kv_cache.make_pools(cfg.cache_spec(), {{"full": 9 * PAGE}},
+                            jnp.bfloat16)
+# (4 pages of 16 rows: a table an indexer of 32 rows would select from)
+cache = {{**pools, "q_pos": np.full((2, 1), 40, np.int32),
+         "groups": {{"full": {{
+             "slots": np.asarray([[PAGE + 40], [5 * PAGE + 40]], np.int32),
+             "block_tables": 1 + np.arange(8, dtype=np.int32).reshape(2, 4),
+             "context_lens": np.full((2,), 41, np.int32)}}}}}}
+text = str(jax.make_jaxpr(
+    lambda p, c: model.apply(p, tokens, c))(params, cache))
+print("KERNELS", sorted(set(re.findall(r"name=(latent_attention_\\w+|"
+                                       r"sparse_\\w+|select_\\w+)", text))))
+print("LOADED", [m for m in sys.modules if m.endswith("sparse_decode")])
+"""
+
+
+def test_the_latent_family_without_an_indexer_decodes_as_before():
+    """Pangu's decode pass over a table wider than a GLM selection names
+    the dense decode kernel and nothing of the sparse setting, and its
+    process never loads `ops.sparse_decode` (PR 56)."""
+    tests = os.path.dirname(os.path.abspath(__file__))
+    done = subprocess.run(
+        [sys.executable, "-c", _DECODE_PASS.format(
+            tests=tests, root=os.path.dirname(tests))],
+        capture_output=True, text=True, timeout=280,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert done.returncode == 0, done.stderr[-3000:]
+    kernels, loaded = done.stdout.splitlines()[-2:]
+    assert kernels == "KERNELS ['latent_attention_decode']"
+    assert loaded == "LOADED []"
 
 
 def test_a_router_without_a_bias_routes_as_before():

@@ -76,7 +76,13 @@ PARENT = {
               (129570784.0, 151449728.0, 1165440.0), 83, 172032, 1528064),
     _pangu: ((2988864.0, 9711498.0, 2354.0),
              (94743312.0, 55280612.0, 234624.0), 53, 405504, 354496),
-    _glm: ((2954870.0, 9682180.0, 2060.0),
+    # (the sparse decode pass at 4 pages is PR 56's own change: no more
+    # pages than `index_topk` 32 rows, so the lane's pages are walked
+    # under the selection's mask — the threshold search and the masked
+    # kernel's interpreter program where the sort, the gather and the
+    # decode kernel's read (2954870.0, 9682180.0, 2060.0); its prefill
+    # pass, leaves and bytes are what they were)
+    _glm: ((3962594.0, 9842455.0, 2444.0),
            (103482016.0, 97393240.0, 240384.0), 64, 456192, 369536),
     _granite: ((13674598.0, 32869884.0, 9004.0),
                (699572736.0, 182604736.0, 1087120.0), 46, 594624, 4562432),

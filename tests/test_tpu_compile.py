@@ -545,6 +545,48 @@ def test_decode_over_gathered_rows_compiles_at_the_cells_shapes(one_chip):
     assert f"bf16[{lanes * k},{row}]" in text      # the gathered pool
 
 
+@pytest.mark.parametrize("pages", [256, 1024, 2048])
+def test_decode_under_the_selections_mask_compiles_at_the_cells_shapes(
+        one_chip, pages):
+    """The three table buckets of the sparse cell past `index_topk` rows
+    (models/pangu.py, `_selected`: no more pages than the selection has
+    rows): 16 lanes' one query of 64 heads against the lane's own pages,
+    the threshold search beside it.  No gathered pool, no sort; the
+    table and the lengths in SMEM at the widest bucket; the kernel's
+    double buffer, a lane's marks and the softmax's scratch inside the
+    VMEM the call asks for."""
+    from ray_tpu.ops import sparse_decode as sd
+    from ray_tpu.ops import sparse_index as si
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lanes, heads, k = 16, 64, 2048
+    slots, row = _glm_pools()
+
+    def call(q, pool, table, lens, marks):
+        return sd.latent_selected_attention(
+            q, pool, table, lens, marks,
+            *si.select_threshold(marks[:, 0], k), page_size=PAGE,
+            value_width=512, scale=256 ** -0.5, interpret=False)
+
+    compiled = jax.jit(call).lower(
+        spec((lanes, 1, heads, row), jnp.bfloat16),
+        spec((slots, row), jnp.bfloat16), spec((lanes, pages), jnp.int32),
+        spec((lanes,), jnp.int32),
+        spec((lanes, 1, pages * PAGE), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "latent_attention_decode_select" in text
+    assert f"bf16[{lanes * k},{row}]" not in text    # no gathered pool
+    assert " sort(" not in text and "sort." not in text
+    assert f"bf16[{slots // PAGE},{PAGE},{row}]" in text  # a page a tile
+    sizes = _scoped_vmem(text)
+    assert sizes and 0 < max(sizes) <= sd._VMEM_BYTES
+    # the program plans nothing of the pool's size beside the pool
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
 # benchmarks/configs/granite-4.0-h-micro-serve.json: 64 lanes; a state
 # pool of 65 slots of 64 heads x 64 x 128 float32 a state layer, updated
 # in place by slot; 32 query heads of 64 over 8 KV heads, which the cache
